@@ -170,18 +170,15 @@ kv-ha-smoke:
 perf-gate:
 	$(PYTHON) scripts/perf_gate.py --run \
 	    --baseline scripts/perf_baseline.json
-	$(PYTHON) -m horovod_tpu.observability.perfboard --gate
 
 # Cross-round trajectory (docs/benchmarks.md): the perfboard unit
-# suite (loader pins against the real checked-in rounds + the gate run
-# both ways — the real trajectory passes, a synthetically regressed
-# fixture round fails naming section AND dominant moved phase), then
-# the CLI itself on the checked-in rounds: report, dashboard, gate.
+# suite — loader pins against synthetic rounds of every format, the CLI,
+# and the gate run both ways (a clean trajectory passes, a synthetically
+# regressed fixture round fails naming section AND dominant moved
+# phase). The repository keeps no round files of its own; the driver's
+# record is PERF_LEDGER.jsonl.
 perfboard-smoke:
 	$(PYTEST) tests/test_perfboard.py
-	$(PYTHON) -m horovod_tpu.observability.perfboard > /dev/null
-	$(PYTHON) -m horovod_tpu.observability.perfboard --json > /dev/null
-	$(PYTHON) -m horovod_tpu.observability.perfboard --gate
 
 # Conv fast path (docs/perf.md): the fused-vs-reference equivalence
 # suite for the conv+BN+ReLU block kernels + the layout pass, then the
@@ -220,9 +217,8 @@ lint-baseline:
 	    --format json > scripts/hvdlint_baseline.json || true
 
 # hvdhlo compile-time program lint (docs/static_analysis.md,
-# docs/perf.md). The env forces the virtual CPU mesh in plain shells;
-# on images whose sitecustomize pins the platform, the analyzer forces
-# jax.config itself before touching the backend.
+# docs/perf.md). The env forces the virtual CPU mesh; the analyzer also
+# sets the CPU platform itself (a lint never takes a chip).
 hlo-lint:
 	env JAX_PLATFORMS=cpu \
 	    XLA_FLAGS="--xla_force_host_platform_device_count=8" \

@@ -1,0 +1,512 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the training path start, compile and step on the TPU?
+
+The quickest standing proof that the system still runs on the chip, through
+the entry points a user calls (`hvd.init`, the eager collectives,
+`tfm.build_train_step` on a `MeshSpec`, `hvd.DistributedOptimizer.step`, the
+launcher), at the full width of the models the repo is measured on:
+
+    python chip_smoke.py             one chip, one process
+    python chip_smoke.py --chips 4   only what exists across chips: the
+                                     launcher's one process per chip, then
+                                     one process over four chips
+
+It is a check, not a benchmark: the step times, rates and peak memory it
+prints are one run's information. It fails — non-zero exit, no `ok` line —
+when JAX finds no TPU, when x64 or rank emulation is on, or when any phase
+raises; nothing here catches a phase's exception. Each phase is a plain
+function of its sizes, so tests/test_chip_smoke.py can rehearse it on the CPU
+at a tiny size. The last line of stdout is
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import statistics
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+import horovod_tpu as hvd
+from horovod_tpu import native
+from horovod_tpu.core import topology
+from horovod_tpu.models import resnet, transformer as tfm
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
+from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+#: The flagship LM (bench.py bench_transformer): L12 D2048 F8192 H16 V32768,
+#: bf16, flash attention, remat — run at B=12 S=1024.
+FLAGSHIP = tfm.TransformerConfig(
+    vocab=32768, d_model=2048, n_heads=16, d_ff=8192, n_layers=12,
+    max_seq=1024, attn="flash", dtype=jnp.bfloat16, remat=True)
+
+#: bf16 carries 8 bits of mantissa: two runs of the same step that differ
+#: only in reduction order agree to a few units of it.
+BF16_RTOL = 4 * 2.0 ** -8
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+class CompileLog:
+    """Counts what JAX compiles, from its own monitoring events: every
+    compile request (a new trace reaching the backend, cached or not) and
+    the persistent cache's hits and misses."""
+
+    def __init__(self) -> None:
+        self.requests = self.hits = self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.requests += 1
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+
+def on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def peak_hbm_gib() -> float | None:
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "peak_bytes_in_use" not in stats:
+        return None  # the CPU backend reports none
+    return stats["peak_bytes_in_use"] / 2**30
+
+
+def check_losses(name: str, losses: list) -> None:
+    if not np.all(np.isfinite(losses)):
+        raise AssertionError(f"{name}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(
+            f"{name}: loss did not fall on a fixed batch: {losses}")
+
+
+def timed_steps(name: str, one_step, steps: int, log: CompileLog):
+    """Run `steps` steps and return (losses, seconds). Each step is timed
+    on the host clock behind `block_until_ready` (inside `one_step`, which
+    returns the loss). Step 1 may compile; any compile request after it
+    is a failure, and so is a loss that is not finite and falling."""
+    losses, secs = [], []
+    after_first = None
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        losses.append(float(one_step()))
+        secs.append(time.perf_counter() - t0)
+        if after_first is None:
+            after_first = log.requests
+    if log.requests != after_first:
+        raise AssertionError(
+            f"{name}: {log.requests - after_first} recompile(s) after "
+            "step 1")
+    check_losses(name, losses)
+    say(f"[{name}] {steps} steps, loss {losses[0]:.4f} -> {losses[-1]:.4f}, "
+        f"first step {secs[0]:.2f} s, then median "
+        f"{statistics.median(secs[1:]) * 1e3:.1f} ms/step, "
+        "0 recompiles after step 1")
+    return losses, secs
+
+
+def information(name: str, rate: str) -> None:
+    peak = peak_hbm_gib()
+    say(f"[{name}] information only (one run, not a benchmark): {rate}, "
+        "peak HBM of the process so far "
+        f"{'not reported' if peak is None else f'{peak:.2f} GiB'}")
+
+
+# ----------------------------------------------------------------- preflight
+
+def preflight(chips: int) -> None:
+    """Refuse anything that is not the real thing, then say what runs."""
+    if os.environ.get("HOROVOD_TPU_EMULATE_RANKS"):
+        raise SystemExit("chip_smoke: HOROVOD_TPU_EMULATE_RANKS is set — "
+                         "the smoke never emulates")
+    if jax.config.jax_enable_x64:
+        raise SystemExit("chip_smoke: jax_enable_x64 is on — the training "
+                         "path is a 32-bit path")
+    hvd.init()
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(f"chip_smoke: JAX came up on '{devs[0].platform}', "
+                         "not on a TPU — nothing to check")
+    if len(devs) < chips:
+        raise SystemExit(f"chip_smoke: --chips {chips} needs {chips} TPU "
+                         f"devices, {len(devs)} visible")
+    import jaxlib
+    import libtpu
+    say(f"[versions] jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {libtpu.__version__}")
+    say(f"[device] kind={devs[0].device_kind!r} count={len(devs)} "
+        f"hvd.size={hvd.size()}")
+    cache, set_here = topology.compile_cache_dir(topology.state().config,
+                                                 devs[0].platform)
+    say(f"[compile cache] {cache} "
+        f"({'set by hvd.init' if set_here else 'JAX_COMPILATION_CACHE_DIR'})")
+    say(f"[native control plane] {native.status()}")
+
+
+# ----------------------------------------------------------- one-chip phases
+
+def eager_api(log: CompileLog, n: int = 1 << 20) -> None:
+    """The eager collectives against numpy, on whatever `hvd.init()` set
+    up. Rank r's tensor is row r of a stacked array (a plain array where
+    this process owns one rank)."""
+    k = hvd.size()
+    rng = np.random.default_rng(0)
+    rows = rng.standard_normal((k, n)).astype(np.float32)
+
+    def mine(a):  # what this process passes for its rank(s)
+        return a if k > 1 else a[0]
+
+    def ranks(out):  # -> one row per rank
+        out = np.asarray(out)
+        return out if k > 1 else out[None]
+
+    for r in ranks(hvd.allreduce(mine(rows), op=hvd.Sum)):
+        np.testing.assert_allclose(r, rows.sum(0), rtol=1e-5, atol=1e-5)
+    small = rows[:, :1024].reshape(k, 32, 32)
+    g = hvd.grouped_allreduce([mine(rows), mine(small)], op=hvd.Average)
+    np.testing.assert_allclose(ranks(g[0])[0], rows.mean(0), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(ranks(g[1])[0], small.mean(0), rtol=1e-5,
+                               atol=1e-6)
+    for r in ranks(hvd.allgather(mine(small))):
+        np.testing.assert_array_equal(r, small.reshape(k * 32, 32))
+    for r in ranks(hvd.broadcast(mine(rows), root_rank=k - 1)):
+        np.testing.assert_array_equal(r, rows[k - 1])
+    per = n // k
+    rs = ranks(hvd.reducescatter(mine(rows), op=hvd.Sum))
+    for i, r in enumerate(rs):
+        np.testing.assert_allclose(r, rows.sum(0)[i * per:(i + 1) * per],
+                                   rtol=1e-5, atol=1e-5)
+    a2a = hvd.alltoall(mine(rows[:, :k * 4].reshape(k, k * 2, 2)))
+    for dst, (out, splits) in enumerate(a2a if k > 1 else [a2a]):
+        want = np.concatenate(
+            [rows[src, :k * 4].reshape(k * 2, 2)[dst * 2:(dst + 1) * 2]
+             for src in range(k)])
+        np.testing.assert_array_equal(np.asarray(out), want)
+        np.testing.assert_array_equal(np.asarray(splits), np.full(k, 2))
+    hvd.barrier()
+    say(f"[eager api] allreduce grouped_allreduce allgather broadcast "
+        f"reducescatter alltoall barrier agree with numpy "
+        f"({k} rank(s), {n} elements)")
+
+
+def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128)) -> None:
+    """Flash attention forward and gradients against the plain reference,
+    at the flagship's (B, H, S, dh). On the TPU the kernel must be
+    compiled by Mosaic, not interpreted, and the program must hold it."""
+    if _pallas.interpret() is on_tpu():
+        raise AssertionError(
+            f"Pallas interpret={_pallas.interpret()} on platform "
+            f"{jax.devices()[0].platform}: the kernel would not run as "
+            "compiled for this device")
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in ks)
+
+    # Everything big is an argument: an array a jitted function closes
+    # over is baked into the program as a constant (50 MB here), and into
+    # every persistent-cache entry made of it.
+    def loss(attn, q, k, v, w):
+        o = attn(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+    flash = jax.jit(jax.value_and_grad(
+        lambda q, k, v, w: loss(flash_attention, q, k, v, w),
+        argnums=(0, 1, 2), has_aux=True))
+    compiled = flash.lower(q, k, v, w).compile()
+    n_kernels = compiled.as_text().count("tpu_custom_call")
+    if on_tpu() and n_kernels < 3:  # forward, dk/dv, dq
+        raise AssertionError(
+            f"compiled flash program holds {n_kernels} Mosaic custom "
+            "calls, expected the forward and both backward kernels")
+    (_, o), grads = flash(q, k, v, w)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        (_, o_ref), g_ref = jax.jit(jax.value_and_grad(
+            lambda q, k, v, w: loss(blockwise_attention_reference,
+                                    q, k, v, w),
+            argnums=(0, 1, 2), has_aux=True))(*f32, w)
+    worst = 0.0
+    for name, got, want in (("o", o, o_ref), ("dq", grads[0], g_ref[0]),
+                            ("dk", grads[1], g_ref[1]),
+                            ("dv", grads[2], g_ref[2])):
+        got = np.asarray(got.astype(jnp.float32))
+        want = np.asarray(want)
+        if got.shape != shape or not np.all(np.isfinite(got)):
+            raise AssertionError(f"flash {name}: bad shape or non-finite")
+        err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+        worst = max(worst, err)
+        if err > BF16_RTOL:
+            raise AssertionError(
+                f"flash {name} differs from the reference by {err:.3g} of "
+                f"its range (tolerance {BF16_RTOL:.3g})")
+    say(f"[flash attention] shape {shape} bf16: fwd and dq/dk/dv agree "
+        f"with the reference (worst {worst:.2e} of range); "
+        f"interpret={_pallas.interpret()}, {n_kernels} tpu_custom_call "
+        "in the compiled program")
+
+
+def lm_steps(log: CompileLog, name: str, cfg, batch: int, seq: int,
+             steps: int, spec: MeshSpec, devices) -> dict:
+    """`steps` train steps of the LM over `spec` on `devices`, through
+    tfm.init / shard_params / init_opt_state / build_train_step, on a
+    fixed batch placed the way the step shards it. Returns losses, step
+    seconds, the compiled program's text, and the devices params and
+    batch sit on."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = build_mesh(spec, devices=devices)
+    tfm.validate_cfg_for_mesh(cfg, mesh)
+    params = tfm.shard_params(tfm.init(jax.random.PRNGKey(0), cfg), cfg,
+                              mesh)
+    opt = optax.adam(1e-3)
+    opt_state = tfm.init_opt_state(opt, params, mesh)
+    step = tfm.build_train_step(cfg, mesh, opt)
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
+                           cfg.vocab),
+        NamedSharding(mesh, P(("dp", "ep"), "sp")))
+    targets = jnp.roll(tokens, -1, axis=1)
+    placed = {"params": {s.device for leaf in jax.tree_util.tree_leaves(
+                  params) for s in leaf.addressable_shards},
+              "batch": {s.device for s in tokens.addressable_shards}}
+    t0 = time.perf_counter()
+    compiled = step.lower(params, opt_state, tokens, targets).compile()
+    compile_s = time.perf_counter() - t0
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    say(f"[{name}] compiled in {compile_s:.1f} s (persistent cache so far: "
+        f"{log.hits} hit(s), {log.misses} miss(es)); program needs "
+        f"{(mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30:.2f}"
+        f" GiB per device; {text.count('tpu_custom_call')} tpu_custom_call")
+    state = [params, opt_state]
+
+    def one_step():
+        state[0], state[1], loss = step(state[0], state[1], tokens, targets)
+        return jax.block_until_ready(loss)
+
+    losses, secs = timed_steps(name, one_step, steps, log)
+    return {"losses": losses, "secs": secs, "text": text, "placed": placed}
+
+
+def flagship_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
+                seq: int = 1024, steps: int = 6) -> None:
+    """The flagship LM at full width on one chip: compile, >= 5 steps,
+    finite falling loss on a fixed batch, no recompile after step 1."""
+    name = (f"lm L{cfg.n_layers} D{cfg.d_model} F{cfg.d_ff} "
+            f"H{cfg.n_heads} S{seq} B{batch} V{cfg.vocab}")
+    run = lm_steps(log, name, cfg, batch, seq, steps, MeshSpec(),
+                   jax.devices()[:1])
+    if on_tpu() and "tpu_custom_call" not in run["text"]:
+        raise AssertionError("the LM step holds no Mosaic custom call: "
+                             "flash attention was not compiled into it")
+    med = statistics.median(run["secs"][1:])
+    information(name, f"{batch * seq / med:,.0f} tokens/s")
+
+
+def resnet50_eager(log: CompileLog, batch: int = 128, image: int = 224,
+                   depth: int = 50, steps: int = 6) -> None:
+    """ResNet-50 B=128 bf16 (the paper's configuration) on the eager path:
+    a jitted value_and_grad, then `hvd.DistributedOptimizer.step`."""
+    name = f"resnet{depth} B{batch} {image}px bf16 eager"
+    params, stats = resnet.init(jax.random.PRNGKey(0), depth=depth,
+                                dtype=jnp.bfloat16)
+    # the Horovod idiom: rank 0's model state (BN statistics included)
+    # goes to every rank — which also commits it to the device, so that
+    # step 2 sees the same input placement as step 1
+    params, stats = hvd.broadcast_parameters((params, stats), root_rank=0)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((batch, image, image, 3)),
+                    jnp.bfloat16)
+    y = jnp.asarray(rng.integers(0, 1000, (batch,)), jnp.int32)
+    opt = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9))
+    state = [params, stats, opt.init(params)]
+    grad_fn = jax.jit(jax.value_and_grad(
+        lambda p, s, x, y: resnet.loss_fn(p, s, (x, y), depth=depth,
+                                          train=True), has_aux=True))
+
+    def one_step():
+        (loss, state[1]), grads = grad_fn(state[0], state[1], x, y)
+        state[0], state[2] = opt.step(grads, state[0], state[2])
+        jax.block_until_ready(state[0])
+        return loss
+
+    _, secs = timed_steps(name, one_step, steps, log)
+    if getattr(opt, "_apply_eager", False):
+        raise AssertionError("DistributedOptimizer fell back to the "
+                             "un-jitted optimizer apply")
+    information(name, f"{batch / statistics.median(secs[1:]):,.0f} images/s")
+
+
+# ---------------------------------------------------------- four-chip phases
+
+def launched_worker(platform: str) -> None:
+    """Body of one `launch -np N` worker: owns exactly one device of
+    `platform`, agrees with the other ranks on an allreduce and on a few
+    `DistributedOptimizer` steps over different data."""
+    hvd.init()
+    k, r = hvd.size(), hvd.rank()
+    local = jax.local_devices()
+    if k != int(os.environ["HOROVOD_SIZE"]) or len(local) != 1 \
+            or local[0].platform != platform:
+        raise AssertionError(
+            f"rank {r}: size {k}, local devices {local} — expected "
+            f"{os.environ['HOROVOD_SIZE']} ranks with one {platform} "
+            "device each")
+    total = np.asarray(hvd.allreduce(np.full(8, r + 1.0, np.float32),
+                                     op=hvd.Sum))
+    np.testing.assert_allclose(total, k * (k + 1) / 2)
+    # rank-addressed collectives: the launcher's rank r is row r / root r
+    mine = np.full(1, r, np.int32)
+    np.testing.assert_array_equal(np.asarray(hvd.allgather(mine)),
+                                  np.arange(k))
+    np.testing.assert_array_equal(
+        np.asarray(hvd.broadcast(mine, root_rank=k - 1)), [k - 1])
+    w = {"w": jax.random.normal(jax.random.PRNGKey(r), (64, 64)),
+         "b": jnp.zeros((64,))}
+    w = hvd.broadcast_parameters(w, root_rank=0)
+    data = jax.random.normal(jax.random.PRNGKey(100 + r), (32, 64))
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+    opt_state = opt.init(w)
+    grad_fn = jax.jit(jax.grad(
+        lambda p: jnp.mean((jnp.tanh(data @ p["w"]) + p["b"] - 1.0) ** 2)))
+    for _ in range(3):
+        w, opt_state = opt.step(grad_fn(w), w, opt_state)
+    sums = np.asarray(hvd.allgather(jnp.sum(w["w"]).reshape(1)))
+    if sums.shape != (k,) or not np.all(sums == sums[0]):
+        raise AssertionError(f"rank {r}: parameters diverged across "
+                             f"ranks after 3 steps: {sums}")
+    say(f"SMOKE_WORKER_OK rank={r}/{k} device={local[0]} "
+        f"kind={local[0].device_kind!r} param_sum={sums[0]:.6f}")
+    hvd.shutdown()
+
+
+def launcher_one_process_per_chip(log: CompileLog, np_: int = 4,
+                                  platform: str = "tpu") -> None:
+    """`python -m horovod_tpu.runner.launch -np N` — the README's launch.
+    Runs while this process has not touched a JAX backend: a chip belongs
+    to one process at a time."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise AssertionError("the parent already holds a JAX backend; "
+                             "launched workers could not open their chips")
+    say(f"[native control plane] {native.status()}")  # built once, here
+    cmd = [sys.executable, "-m", "horovod_tpu.runner.launch",
+           "-np", str(np_), "-H", f"localhost:{np_}", sys.executable, "-c",
+           f"import chip_smoke; chip_smoke.launched_worker({platform!r})"]
+    proc = subprocess.Popen(cmd, cwd=REPO, stdout=subprocess.PIPE,
+                            stderr=subprocess.PIPE, text=True,
+                            start_new_session=True)
+    try:
+        out, err = proc.communicate(timeout=300)
+    except subprocess.TimeoutExpired:
+        proc.terminate()  # the launcher stops its workers on the way out
+        try:
+            out, err = proc.communicate(timeout=30)
+        except subprocess.TimeoutExpired:
+            os.killpg(proc.pid, signal.SIGKILL)
+            out, err = proc.communicate()
+        raise AssertionError(f"launch -np {np_} hung\nstdout:\n"
+                             f"{out[-4000:]}\nstderr:\n{err[-4000:]}")
+    ok = [ln.strip() for ln in out.splitlines() if "SMOKE_WORKER_OK" in ln]
+    for ln in ok:
+        say(f"[launch -np {np_}] {ln}")
+    if proc.returncode != 0 or len(ok) != np_:
+        raise AssertionError(
+            f"launch -np {np_} exited {proc.returncode} with {len(ok)} "
+            f"worker(s) ok\nstdout:\n{out[-4000:]}\nstderr:\n{err[-4000:]}")
+    devices = {ln.split("device=")[1].split(" kind=")[0] for ln in ok}
+    if platform == "tpu" and len(devices) != np_:
+        raise AssertionError(f"workers did not hold {np_} distinct chips: "
+                             f"{sorted(devices)}")
+    say(f"[launch -np {np_}] {np_} workers, one {platform} device each, "
+        "agree on an allreduce and on 3 DistributedOptimizer steps")
+
+
+def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
+                         seq: int = 1024, steps: int = 4,
+                         specs=(MeshSpec(dp=4), MeshSpec(dp=2, tp=2))
+                         ) -> None:
+    """One process over all chips: the LM over each mesh in `specs`,
+    against the one-device run of the same global batch and seed."""
+    devices = topology.state().devices
+    tag = f"L{cfg.n_layers} D{cfg.d_model} S{seq} B{batch}"
+    ref = lm_steps(log, f"lm {tag} one device", cfg, batch, seq, steps,
+                   MeshSpec(), devices[:1])["losses"]
+    for spec in specs:
+        name = f"lm {tag} {spec.describe()}"
+        run = lm_steps(log, name, cfg, batch, seq, steps, spec,
+                       devices[:spec.total])
+        np.testing.assert_allclose(
+            run["losses"], ref, rtol=BF16_RTOL,
+            err_msg=f"{name}: losses leave the one-device run's")
+        for what, devs in run["placed"].items():
+            if len(devs) != spec.total:
+                raise AssertionError(
+                    f"{name}: the {what} sit on {len(devs)} device(s), "
+                    f"not {spec.total}")
+        if "all-reduce" not in run["text"]:
+            raise AssertionError(f"{name}: no all-reduce in the compiled "
+                                 "program")
+        say(f"[{name}] losses match the one-device run within "
+            f"{BF16_RTOL:.3g} at each step; params and batch on "
+            f"{spec.total} distinct devices; all-reduce in the compiled "
+            "program")
+
+
+# ---------------------------------------------------------------------- main
+
+#: chips -> (phases run before this process touches JAX, phases run after
+#: `hvd.init()`). With --chips 4 only what exists across chips runs, and what
+#: it is compared with.
+PHASES = {
+    1: ((), (eager_api, flash_kernel, flagship_lm, resnet50_eager)),
+    4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
+}
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=sorted(PHASES), default=1,
+                    help="4: run only the cross-chip paths (default 1)")
+    chips = ap.parse_args(argv).chips
+    t0 = time.perf_counter()
+    log = CompileLog()
+    off_jax, on_device = PHASES[chips]
+    for phase in off_jax:
+        phase(log)
+    preflight(chips)
+    for phase in on_device:
+        phase(log)
+    dev = jax.devices()[0]
+    say(f"[done] {time.perf_counter() - t0:.0f} s; {log.requests} compile "
+        f"request(s), persistent cache {log.hits} hit(s) / {log.misses} "
+        "miss(es)")
+    say(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices())}}))
+
+
+if __name__ == "__main__":
+    main()

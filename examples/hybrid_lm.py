@@ -6,7 +6,7 @@ tied-embedding LM trained model-sharded through
 the HOROVOD_MESH knob declares (docs/parallelism.md). Run it on the
 8-device virtual CPU mesh:
 
-    HOROVOD_TPU_EMULATE_RANKS=8 HOROVOD_MESH="dp=2,tp=4" \
+    JAX_PLATFORMS=cpu HOROVOD_TPU_EMULATE_RANKS=8 HOROVOD_MESH="dp=2,tp=4" \
         python examples/hybrid_lm.py
 
 or leave HOROVOD_MESH unset for the pure data-parallel twin

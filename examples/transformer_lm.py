@@ -52,7 +52,7 @@ def main():
     params = tfm.shard_params(tfm.init(jax.random.PRNGKey(0), cfg), cfg,
                               mesh)
     opt = optax.adamw(3e-4)
-    opt_state = opt.init(params)
+    opt_state = tfm.init_opt_state(opt, params, mesh)
     step = tfm.build_train_step(cfg, mesh, opt)
 
     rng = np.random.default_rng(0)

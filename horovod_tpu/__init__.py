@@ -10,11 +10,7 @@ Public API mirrors `horovod.torch` / `horovod.tensorflow`
 (reference: horovod/torch/__init__.py, horovod/tensorflow/__init__.py).
 """
 
-from horovod_tpu.common.compat import ensure_jax_api
-
-ensure_jax_api()  # before any module builds a jit(shard_map(...)) program
-
-from horovod_tpu.common.types import (  # noqa: F401, E402
+from horovod_tpu.common.types import (  # noqa: F401
     Adasum, Average, Max, Min, Product, ReduceOp, Status, Sum,
 )
 from horovod_tpu.common.exceptions import (  # noqa: F401

@@ -1,172 +1,46 @@
-"""jaxlib private-API compatibility shims.
+"""jaxlib private-API access, in one place.
 
 The elastic control plane leans on jax's distributed-runtime service and
-client, which live behind `jax._src` internals that jaxlib renames across
-releases: the extension module moved from `jax._src.lib.xla_extension`
-(≤0.4.x) to `jax._src.lib._jax` (≥0.5), and the service factory's
-keepalive knobs changed from (heartbeat_interval, max_missing_heartbeats)
-to a single heartbeat_timeout. Resolving the module and signature in ONE
-place keeps every call site working across that drift — and keeps the
-degradation story (topology.recoverable_client_contract) honest: a moved
-import must read as "renamed, adapted" rather than "gone".
+client, which live behind `jax._src` internals (`jax._src.lib._jax` in
+the installed jaxlib 0.9.0). Touching them through this one module keeps
+the degradation story (topology.recoverable_client_contract) honest:
+when a jaxlib bump moves or re-signs them, there is one place to repair
+and the callers' documented fallbacks take over meanwhile.
 """
 
 from __future__ import annotations
 
 
-def ensure_jax_api() -> None:
-    """Alias public jax symbols this codebase uses that older jax keeps
-    under experimental names. Today: `jax.shard_map`, promoted out of
-    `jax.experimental.shard_map` in jax 0.5 — every collective here is a
-    jit(shard_map(...)) program, so without the alias an old jax fails at
-    the first collective build. Idempotent; a no-op on new jax.
-    """
-    import jax
-    if not hasattr(jax, "shard_map"):
-        import functools
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @functools.wraps(_shard_map)
-        def shard_map(f, *args, **kwargs):
-            # jax 0.5 renamed check_rep -> check_vma along with the move.
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _shard_map(f, *args, **kwargs)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax.lax, "axis_size"):
-        # jax.lax.axis_size arrived with the shard_map promotion; old jax
-        # spells it psum(1, axis) — special-cased to resolve statically
-        # at trace time, so this is an alias, not an added collective.
-        jax.lax.axis_size = lambda axis_name: jax.lax.psum(1, axis_name)
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        if not hasattr(pltpu, "CompilerParams"):
-            # renamed from TPUCompilerParams when pallas de-prefixed its
-            # per-backend params classes
-            pltpu.CompilerParams = pltpu.TPUCompilerParams
-    except (ImportError, AttributeError):
-        pass  # no pallas TPU backend in this jax: kernels gate on it
-
-
-def cpu_collectives_implementation():
-    """Current `jax_cpu_collectives_implementation` value ('none' / 'gloo'
-    / 'mpi'), or None if this jax has no such flag.
-
-    The flag drifted: new jax exposes it as a `jax.config` attribute; jax
-    0.4.x registers it lazily from `jax._src.xla_bridge` as a holder that
-    `jax.config.update` accepts but attribute reads do NOT see. Reading
-    through the holder keeps "is gloo active?" answerable everywhere —
-    the elastic scale-down-to-1 reset depends on it (core/topology.py).
-    """
-    import jax
-    try:
-        return jax.config.jax_cpu_collectives_implementation
-    except AttributeError:
-        pass
-    try:
-        from jax._src import xla_bridge as xb
-        return xb.CPU_COLLECTIVES_IMPLEMENTATION.value
-    except (ImportError, AttributeError):
-        return None
-
-
-def set_cpu_collectives_implementation(value: str) -> bool:
-    """Set `jax_cpu_collectives_implementation`; returns False if this jax
-    has no such flag. Imports xla_bridge first: on jax 0.4.x the flag only
-    registers with `jax.config` when that module loads, so an early call
-    would otherwise silently AttributeError inside update()."""
-    import jax
-    try:
-        import jax._src.xla_bridge  # noqa: F401  (registers the flag)
-    except ImportError:
-        pass
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", value)
-        return True
-    except (AttributeError, ValueError):
-        return False
-
-
 def jaxlib_extension():
-    """The jaxlib extension module under whichever name this jaxlib uses.
-
-    Raises ImportError only if NEITHER name resolves (a jaxlib newer than
-    both naming schemes) — callers keep their own documented fallbacks.
-    """
-    try:
-        from jax._src.lib import _jax as ext  # jaxlib >= 0.5
-        return ext
-    except ImportError:
-        from jax._src.lib import xla_extension as ext  # jaxlib <= 0.4.x
-        return ext
+    """The jaxlib extension module. Raises ImportError if a jaxlib bump
+    moved it — callers keep their own documented fallbacks."""
+    from jax._src.lib import _jax
+    return _jax
 
 
 def make_distributed_service(address: str, num_nodes: int,
                              heartbeat_timeout: int,
                              shutdown_timeout: int):
-    """Start a jax distributed-runtime (coordination) service.
-
-    Adapts the keepalive knobs: new jaxlib takes heartbeat_timeout
-    directly; old jaxlib takes an interval and a missed-beat count whose
-    product is the effective timeout.
-    """
-    ext = jaxlib_extension()
-    try:
-        return ext.get_distributed_runtime_service(
-            address, num_nodes, heartbeat_timeout=heartbeat_timeout,
-            shutdown_timeout=shutdown_timeout)
-    except TypeError:
-        missing = 10
-        return ext.get_distributed_runtime_service(
-            address, num_nodes,
-            heartbeat_interval=max(1, heartbeat_timeout // missing),
-            max_missing_heartbeats=missing,
-            shutdown_timeout=shutdown_timeout)
+    """Start a jax distributed-runtime (coordination) service."""
+    return jaxlib_extension().get_distributed_runtime_service(
+        address, num_nodes, heartbeat_timeout=heartbeat_timeout,
+        shutdown_timeout=shutdown_timeout)
 
 
 def make_distributed_client(coord: str, rank: int, init_timeout: int,
                             heartbeat_timeout: int, shutdown_timeout: int):
-    """Construct (don't connect) a distributed-runtime client for `coord`.
+    """Construct (don't connect) the RECOVERABLE distributed-runtime
+    client the elastic path wants: in-process reconnect after a peer
+    failure, no all-task shutdown barrier, no destructor-time RPC.
 
-    Returns (client, recoverable): new jaxlib gives the recoverable client
-    the elastic path wants (in-process reconnect after a peer failure);
-    old jaxlib lacks the `recoverable` kwarg, so the client is standard —
-    still correct for elastic, because every round gets a FRESH
-    launcher-side service and therefore a fresh client, just without
-    reconnect-to-the-same-service semantics.
-
-    This exists because old jax.distributed.initialize() cannot be used
-    here at all: on process 0 it auto-starts a SECOND coordination
-    service on the coordinator port, racing the launcher-owned one —
-    registration then deadlocks on whichever service lost the bind.
+    `jax.distributed.initialize()` cannot stand in for it while a
+    launcher-owned service is up: on process 0 it starts a SECOND
+    coordination service on the coordinator port. A TypeError here means
+    the factory's signature drifted (see recoverable_client_contract).
     """
-    ext = jaxlib_extension()
-    factory = ext.get_distributed_runtime_client
-    try:
-        return factory(coord, rank, init_timeout=init_timeout,
-                       heartbeat_timeout=heartbeat_timeout,
-                       shutdown_timeout=shutdown_timeout,
-                       use_compression=True, recoverable=True,
-                       shutdown_on_destruction=False), True
-    except TypeError:
-        pass
-    try:
-        # middle range: heartbeat_timeout exists, `recoverable` not yet
-        return factory(coord, rank, init_timeout=init_timeout,
-                       heartbeat_timeout=heartbeat_timeout,
-                       shutdown_timeout=shutdown_timeout,
-                       use_compression=True,
-                       shutdown_on_destruction=False), False
-    except TypeError:
-        missing = 10
-        return factory(coord, rank, init_timeout=init_timeout,
-                       heartbeat_interval=max(
-                           1, heartbeat_timeout // missing),
-                       max_missing_heartbeats=missing,
-                       shutdown_timeout=shutdown_timeout,
-                       use_compression=True,
-                       shutdown_on_destruction=False), False
+    return jaxlib_extension().get_distributed_runtime_client(
+        coord, rank, init_timeout=init_timeout,
+        heartbeat_timeout=heartbeat_timeout,
+        shutdown_timeout=shutdown_timeout,
+        use_compression=True, recoverable=True,
+        shutdown_on_destruction=False)

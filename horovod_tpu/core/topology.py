@@ -28,7 +28,7 @@ from __future__ import annotations
 import atexit
 import os
 import threading
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -82,14 +82,32 @@ class _GlobalState:
 _state = _GlobalState()
 
 
-def _canonical_devices() -> List[jax.Device]:
-    """All devices in rank order: sorted by (process_index, device id).
+def _canonical_devices(cfg: Config) -> List[jax.Device]:
+    """All devices in rank order: sorted by (owning process, device id),
+    processes in the order of the ranks the launcher gave them.
 
     This makes each process's devices contiguous in rank space, so
     local_rank arithmetic matches the reference launcher's slot model
-    (horovod/runner/gloo_run.py host allocation).
+    (horovod/runner/gloo_run.py host allocation), and puts the process
+    the launcher calls rank r at mesh position r — the position every
+    rank-addressed collective (broadcast root, allgather row) means.
     """
-    return sorted(jax.devices(), key=lambda d: (d.process_index, d.id))
+    devs = jax.devices()
+    rank_of = {}
+    if cfg.rank is not None and jax.process_count() > 1 \
+            and devs[0].platform != "cpu":
+        # On the CPU backend a device's process_index IS the rank we hand
+        # jax.distributed. On a TPU it is the process's place in the
+        # slice's topology, whatever the launcher called it: on a 2x2
+        # host split one chip per process, the launcher's ranks 0,1,2,3
+        # were processes 0,2,3,1 in one run and 3,2,0,1 in the next. So
+        # ask every process.
+        from jax.experimental import multihost_utils
+        pairs = multihost_utils.process_allgather(
+            np.array([jax.process_index(), cfg.rank], np.int32))
+        rank_of = {int(p): int(r) for p, r in pairs}
+    return sorted(devs, key=lambda d: (
+        rank_of.get(d.process_index, d.process_index), d.id))
 
 
 def _maybe_distributed_init(cfg: Config) -> None:
@@ -101,28 +119,8 @@ def _maybe_distributed_init(cfg: Config) -> None:
     jax.distributed (the TPU-native control plane over DCN).
     """
     if cfg.size is None or cfg.size <= 1:
-        # An earlier multi-process round set the gloo CPU collectives; a
-        # single-process re-init (elastic scale-down to 1) has no
-        # distributed client, and old jaxlib refuses to build a CPU
-        # backend with gloo + a None client. Reset to the default.
-        # (compat accessors: on jax 0.4.x the flag is invisible to
-        # jax.config attribute reads, only its xla_bridge holder works.)
-        from horovod_tpu.common.compat import (
-            cpu_collectives_implementation,
-            set_cpu_collectives_implementation)
-        try:
-            if (cpu_collectives_implementation() == "gloo"
-                    and jax._src.distributed.global_state.client is None):
-                set_cpu_collectives_implementation("none")
-        except Exception:
-            pass
         return
-    try:
-        already = jax._src.distributed.global_state.client is not None
-    except AttributeError:  # private API moved: use the public probe
-        already = bool(getattr(jax.distributed, "is_initialized",
-                               lambda: False)())
-    if already:
+    if jax.distributed.is_initialized():
         return
     # The jax.distributed coordinator must be BOUND BY RANK 0 on rank 0's
     # host. An explicit HOROVOD_COORDINATOR_ADDR env wins (single-host
@@ -148,9 +146,6 @@ def _maybe_distributed_init(cfg: Config) -> None:
                     "timed out waiting for rank 0 to publish the "
                     "jax.distributed coordinator address")
             coord = data.decode()
-    # Cross-process CPU collectives need the gloo impl (no-op flagless).
-    from horovod_tpu.common.compat import set_cpu_collectives_implementation
-    set_cpu_collectives_implementation("gloo")
     if cfg.elastic:
         _elastic_distributed_init(coord, cfg)
     else:
@@ -204,16 +199,9 @@ def _elastic_distributed_init(coord: str, cfg: Config) -> None:
         sd = int(os.environ.get("HOROVOD_ELASTIC_SHUTDOWN_SECONDS", "10"))
         try:
             from horovod_tpu.common.compat import make_distributed_client
-            client, recoverable = make_distributed_client(
+            client = make_distributed_client(
                 coord, rank, init_timeout=300, heartbeat_timeout=hb,
                 shutdown_timeout=sd)
-            if not recoverable:
-                get_logger().warning(
-                    "recoverable jax.distributed client unavailable in "
-                    "this jaxlib; elastic uses a standard client — each "
-                    "round still gets a fresh coordinator, but a peer "
-                    "failure may require a full backend re-init instead "
-                    "of an in-place reconnect")
             client.connect()
             state.num_processes = cfg.size
             state.process_id = rank
@@ -225,16 +213,15 @@ def _elastic_distributed_init(coord: str, cfg: Config) -> None:
     get_logger().warning(
         "jax distributed-runtime client unavailable in this jaxlib "
         "(private API moved); elastic falls back to "
-        "jax.distributed.initialize — NOTE: on jaxlib <= 0.4.x this "
-        "auto-starts a competing coordination service on process 0 and "
-        "must not be combined with a launcher-owned coordinator")
+        "jax.distributed.initialize and worker-restart recovery")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=cfg.size, process_id=rank)
 
 
-# jaxlib versions whose private distributed-runtime API this elastic
-# path has been verified against (see recoverable_client_contract).
-RECOVERABLE_CLIENT_TESTED_JAXLIB = ("0.7", "0.9")
+# The jaxlib whose private distributed-runtime API this elastic path has
+# been verified against: the installed one (see
+# recoverable_client_contract).
+RECOVERABLE_CLIENT_TESTED_JAXLIB = "0.9"
 
 
 def recoverable_client_contract():
@@ -318,39 +305,84 @@ def _apply_cpu_emulation(n: int) -> None:
     """HOROVOD_TPU_EMULATE_RANKS=N: emulate an N-chip slice with XLA's
     host-platform device count (dev/test mode; mirrors how the reference's
     parallel suites run real collectives over loopback, SURVEY.md §4).
-    Must run before the first JAX backend touch; env vars alone are not
-    enough when a site plugin pins the platform, so jax.config is set too.
+
+    Emulation is a CPU-backend affair (run it under JAX_PLATFORMS=cpu): a
+    process whose JAX came up on an accelerator is refused, never switched
+    to the CPU behind the user's back.
     """
     import re
 
-    try:
-        if jax.devices()[0].platform == "cpu" and len(jax.devices()) >= n:
-            return
-    except Exception:
-        pass
-    try:  # discard any live backend (e.g. a 1-chip TPU client) first —
-        # XLA_FLAGS/jax_num_cpu_devices are consumed at client creation.
-        import jax.extend.backend as _jeb
-        _jeb.clear_backends()
-    except Exception:
-        pass
+    devs = jax.devices()
+    if devs[0].platform != "cpu":
+        raise HorovodTpuError(
+            f"CPU emulation of {n} ranks was asked for, but JAX came up "
+            f"on '{devs[0].platform}': set JAX_PLATFORMS=cpu to emulate; "
+            "a live accelerator backend is never replaced by the CPU")
+    if len(devs) >= n:
+        return
+    # XLA_FLAGS/jax_num_cpu_devices are consumed at client creation:
+    # discard the too-small CPU client first.
+    import jax.extend.backend as _jeb
+    _jeb.clear_backends()
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    os.environ.get("XLA_FLAGS", ""))
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={n}").strip()
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except Exception:
-        pass  # older jax: the XLA_FLAGS path above handles it
+    jax.config.update("jax_num_cpu_devices", n)
     if len(jax.devices()) < n:
         raise HorovodTpuError(
             f"CPU emulation failed: need {n} devices, have "
-            f"{len(jax.devices())} (a JAX backend may already be "
-            "initialized in a way that cannot be reset)")
+            f"{len(jax.devices())}")
+
+
+#: Where compiled programs are kept when the environment names no other
+#: place: a FIXED, git-ignored path inside the checkout — never a temp
+#: name, pid or time, because a cache directory that moves never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def compile_cache_dir(cfg: Config, platform: str
+                      ) -> Tuple[Optional[str], bool]:
+    """The persistent compile cache this process uses, and whether
+    `init()` has to set it: `(directory, set_in_code)`.
+
+    JAX_COMPILATION_CACHE_DIR wins and is left to JAX (nothing is set in
+    code); else HOROVOD_TPU_COMPILE_CACHE; else the fixed
+    `<checkout>/.jax_cache`. Launcher children inherit the environment
+    and the checkout, so every rank resolves the same directory.
+
+    The default is for accelerators, where a cold train step costs tens
+    of seconds: on the CPU backend nothing is cached unless the
+    environment asks (its compiles are cheap, and XLA:CPU logs a
+    spurious machine-feature error on every cached executable it
+    reloads)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env, False
+    if cfg.compile_cache_dir:
+        return cfg.compile_cache_dir, True
+    if platform == "cpu":
+        return None, False
+    return DEFAULT_COMPILE_CACHE_DIR, True
+
+
+def _check_one_chip_per_process(cfg: Config) -> None:
+    """The launcher's one-process-per-chip contract, enforced: a TPU
+    worker launched into a multi-slot host (HOROVOD_LOCAL_SIZE > 1) owns
+    exactly the chip its slot was assigned (runner/hosts.py chip env).
+    A worker that opened several chips would fight its neighbours for
+    them — a failure, not a warning."""
+    local = jax.local_devices()
+    if (cfg.local_size or 1) > 1 and local[0].platform == "tpu" \
+            and len(local) != 1:
+        raise HorovodTpuError(
+            f"rank {cfg.rank}: launched as one of {cfg.local_size} "
+            f"workers on this host but sees {len(local)} local TPU chips; "
+            "one process per chip needs the slot's chip assignment "
+            "(TPU_VISIBLE_CHIPS etc., injected by the launcher for 4- and "
+            "8-chip single-host jobs — runner/hosts.py)")
 
 
 def init(process_sets: Optional[Sequence] = None,
@@ -370,8 +402,10 @@ def init(process_sets: Optional[Sequence] = None,
         if cfg.emulate_ranks > 0:
             _apply_cpu_emulation(cfg.emulate_ranks)
         _maybe_distributed_init(cfg)
+        _check_one_chip_per_process(cfg)
 
-        devs = list(devices) if devices is not None else _canonical_devices()
+        devs = list(devices) if devices is not None \
+            else _canonical_devices(cfg)
         if not devs:
             raise HorovodTpuError("no JAX devices visible")
         _state.devices = devs
@@ -411,8 +445,9 @@ def init(process_sets: Optional[Sequence] = None,
         _state.cross_size = cfg.cross_size if cfg.cross_size is not None else pcount
         _state.cross_rank = cfg.cross_rank if cfg.cross_rank is not None else pidx
 
-        if cfg.compile_cache_dir:
-            jax.config.update("jax_compilation_cache_dir", cfg.compile_cache_dir)
+        cache_dir, set_here = compile_cache_dir(cfg, devs[0].platform)
+        if set_here:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
 
         # Register the global process set (+ user sets) now that mesh exists.
         from horovod_tpu.core import process_sets as ps_mod

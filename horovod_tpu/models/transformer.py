@@ -350,7 +350,11 @@ def build_train_step(cfg: TransformerConfig, mesh: Mesh,
                      optimizer: optax.GradientTransformation):
     """Full jitted train step over the mesh. Forward/backward/gradient
     collectives run inside shard_map; the optax update runs under GSPMD,
-    which propagates param shardings through the elementwise update."""
+    which propagates param shardings through the elementwise update.
+
+    Create the optimizer state with `init_opt_state`, not a bare
+    `optimizer.init(params)` (chip_smoke.py checks that nothing compiles
+    after step 1)."""
     lg = build_loss_and_grads(cfg, mesh)
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -368,6 +372,22 @@ def shard_params(params, cfg: TransformerConfig, mesh: Mesh):
     specs = param_specs(cfg)
     return jax.tree_util.tree_map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs)
+
+
+def init_opt_state(optimizer: optax.GradientTransformation, params,
+                   mesh: Mesh):
+    """`optimizer.init(params)` with every leaf on the mesh.
+
+    Moments inherit the params' shardings, but an optax state also holds
+    scalars created from nothing (adam's `count`), which land off the
+    mesh and come back mesh-replicated from step 1: step 2 then sees new
+    input types and traces and compiles the whole train step a second
+    time. Replicating them over the mesh up front keeps the step's
+    signature fixed from the first call."""
+    replicated = NamedSharding(mesh, P())
+    return jax.tree_util.tree_map(
+        lambda x: x if isinstance(x.sharding, NamedSharding)
+        else jax.device_put(x, replicated), optimizer.init(params))
 
 
 def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:

@@ -20,18 +20,32 @@ _LIB_PATH = os.path.join(_DIR, "libhorovod_tpu_native.so")
 _lib: Optional[ctypes.CDLL] = None
 _lock = threading.Lock()
 _build_failed = False
+_built_here = False  # make compiled the library in this process
 
 # KV protocol ops (must match kv_store.cc).
 OP_PUT, OP_GET, OP_ADD, OP_AND, OP_OR, OP_GETC, OP_DEL, OP_PING = range(1, 9)
 
 
+def _lib_mtime() -> Optional[float]:
+    try:
+        return os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return None
+
+
 def _build() -> bool:
+    """Run make; True when it left a library that is current with
+    src/*.cc (freshly compiled, or already newer than the sources)."""
+    global _built_here
+    before = _lib_mtime()
     try:
         subprocess.run(["make", "-s"], cwd=_DIR, check=True,
                        capture_output=True, timeout=300)
-        return os.path.exists(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
+    after = _lib_mtime()
+    _built_here = after is not None and after != before
+    return after is not None
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -44,9 +58,10 @@ def load() -> Optional[ctypes.CDLL]:
             return None
         # Always invoke make (it no-ops when the .so is newer than the
         # sources) so edits to src/*.cc are never silently ignored by a
-        # stale binary; fall back to a pre-existing .so if the toolchain is
-        # missing.
-        if not _build() and not os.path.exists(_LIB_PATH):
+        # stale binary. When make cannot vouch for the library (no
+        # toolchain, failed build) a pre-existing .so is NOT loaded: the
+        # pure-Python fallbacks run instead.
+        if not _build():
             _build_failed = True
             return None
         try:
@@ -75,13 +90,10 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_char, ctypes.c_longlong, ctypes.c_longlong,
             ctypes.c_int, ctypes.c_int]
         lib.hvdn_timeline_close.argtypes = [ctypes.c_void_p]
-        try:  # stale prebuilt .so without counter-track support
-            lib.hvdn_timeline_emit_counter.restype = ctypes.c_int
-            lib.hvdn_timeline_emit_counter.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
-                ctypes.c_double, ctypes.c_longlong]
-        except AttributeError:
-            pass
+        lib.hvdn_timeline_emit_counter.restype = ctypes.c_int
+        lib.hvdn_timeline_emit_counter.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_double, ctypes.c_longlong]
         lib.hvdn_stall_new.restype = ctypes.c_void_p
         lib.hvdn_stall_new.argtypes = [ctypes.c_double, ctypes.c_double]
         lib.hvdn_stall_free.argtypes = [ctypes.c_void_p]
@@ -97,6 +109,15 @@ def load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return load() is not None
+
+
+def status() -> str:
+    """How the control plane runs in this process: "built" (make compiled
+    the library from src/*.cc just now), "up to date" (make found it
+    current) or "absent" (the pure-Python fallbacks)."""
+    if load() is None:
+        return "absent"
+    return "built" if _built_here else "up to date"
 
 
 class NativeKVServer:
@@ -242,10 +263,8 @@ class NativeTimeline:
     def emit_counter(self, name: str, series: str, value: float,
                      ts_us: int) -> None:
         """Chrome `"ph":"C"` counter sample (timeline counter tracks)."""
-        fn = getattr(self._lib, "hvdn_timeline_emit_counter", None)
-        if fn is not None:
-            fn(self._h, name.encode(), series.encode(), float(value),
-               ts_us)
+        self._lib.hvdn_timeline_emit_counter(
+            self._h, name.encode(), series.encode(), float(value), ts_us)
 
     def close(self) -> None:
         if self._h:

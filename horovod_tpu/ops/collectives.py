@@ -62,11 +62,7 @@ _AXIS = "hvd"
 
 # Runtime (not trace-time) failure types: a dead peer / aborted transport
 # surfaces as one of these from XLA or the distributed client.
-try:
-    _COMM_ERRORS: tuple = (jax.errors.JaxRuntimeError,)
-except AttributeError:  # older jax spelling
-    from jax._src.lib import xla_client as _xc
-    _COMM_ERRORS = (_xc.XlaRuntimeError,)
+_COMM_ERRORS: tuple = (jax.errors.JaxRuntimeError,)
 
 # A dead peer does NOT always surface as a typed runtime error: the CPU
 # collectives backend raises plain ValueError("UNKNOWN: Gloo all-reduce
@@ -433,8 +429,8 @@ def _lift_group(tensors: Sequence[Any], ps: ProcessSet):
 
     Single-process, for eligible tensors: ONE compiled program raises
     the whole group to its row-sharded form (out_shardings does the
-    placement), collapsing 2N+1 dispatches to ~2 — the dominant cost of
-    eager grouped ops on remote/tunneled devices. COMMITTED arrays
+    placement), collapsing 2N+1 dispatches to ~2 (per-dispatch host
+    overhead is the dominant cost of eager grouped ops). COMMITTED arrays
     (outputs of previous collectives via _from_global, or user
     device_put-pinned inputs) cannot enter a jit whose out_shardings
     spans other devices ("incompatible devices"), so they take the
@@ -541,8 +537,8 @@ def _replicated_reduce_one(x: jax.Array, op: T.ReduceOp, k: int,
     rank contributes the same tensor, so the collective has a closed
     form: sum = k·x, average/min/max = x, product = x^k. Computing it
     directly skips the per-tensor lift (broadcast + device_put — two
-    dispatches EACH, which dominates eager-optimizer steps on
-    remote/tunneled devices) and the fused psum program entirely.
+    dispatches EACH, which dominates eager-optimizer steps) and the
+    fused psum program entirely.
     Semantics match _apply_reduce exactly, including integer-average
     flooring and pre/post scaling order.
     """

@@ -40,9 +40,9 @@ activation before the residual add).
 A plain `jax.lax` reference (`conv_block_reference`) defines the ground
 truth; tests/test_conv_block.py pins fused-vs-reference equivalence for
 forward, gradients, batch-stat cotangents, and the bf16 path. On
-non-TPU backends the kernels run in Pallas interpret mode (same
-fallback as flash_attention / conv_bn_backward), so tier-1 exercises
-the real pallas_call path on CPU.
+non-TPU backends the kernels run in Pallas interpret mode (the shared
+decision in ops/_pallas.py), so tier-1 exercises the real pallas_call
+path on CPU.
 
 Model wiring: HOROVOD_CONV_BLOCK=1 routes models/resnet.py's profitable
 1x1 sites through this family (docs/perf.md "conv fast path"); it
@@ -60,17 +60,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops import conv_bn_backward as _cbb
+from horovod_tpu.ops._pallas import pallas_call
 from horovod_tpu.ops.conv_bn_backward import (_axis_size, _pick_block_m,
                                               _pmean)
 
-
-def _interpret() -> bool:
-    # Resolved through the conv_bn_backward MODULE (not a from-import
-    # binding) so the TPU compile-only probe's monkeypatch of
-    # conv_bn_backward._interpret flips BOTH kernel families to the
-    # real Mosaic lowering (tests/tpu_probe.py).
-    return _cbb._interpret()
 
 CONV_BLOCK_ENV = "HOROVOD_CONV_BLOCK"
 
@@ -189,7 +182,7 @@ def conv1x1_fwd_fused(x: jax.Array, w: jax.Array
     mp = m + m_pad
     bc = _lane_block(c)
     bm = _pick_fwd_block_m(mp, bc, cin, c)
-    y, ssum, ssq = pl.pallas_call(
+    y, ssum, ssq = pallas_call(
         _fwd_kernel,
         grid=(mp // bm, c // bc),
         in_specs=[
@@ -210,7 +203,6 @@ def conv1x1_fwd_fused(x: jax.Array, w: jax.Array
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),  # sequential
-        interpret=_interpret(),
     )(x, w)
     return (y[:m] if m_pad else y), ssum.ravel(), ssq.ravel()
 
@@ -320,7 +312,7 @@ def conv1x1_bn_act_bwd_fused(dz: jax.Array, y: jax.Array,
     else:  # mask all-true: zpre = xhat*0 + 1 > 0 everywhere
         s_row = jnp.zeros((c,), jnp.float32)
         b_row = jnp.ones((c,), jnp.float32)
-    dx, dw = pl.pallas_call(
+    dx, dw = pallas_call(
         _bwd_kernel,
         grid=(mp // bm, c // bc),
         in_specs=[
@@ -349,7 +341,6 @@ def conv1x1_bn_act_bwd_fused(dz: jax.Array, y: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, cin), jnp.float32)],  # dx accum
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),  # sequential
-        interpret=_interpret(),
     )(dz, y, x_in, w, row(g), row(mean), row(inv), row(a_vec),
       row(b_vec), row(s_row), row(b_row))
     return (dx[:m] if m_pad else dx), dw

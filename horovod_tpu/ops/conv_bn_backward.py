@@ -60,9 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from horovod_tpu.ops._pallas import pallas_call
 
 
 def _pick_block_m(m: int, bc: int, cin: int, c_full: int,
@@ -183,7 +181,7 @@ def conv1x1_bn_bwd_fused(dz: jax.Array, y: jax.Array, x_in: jax.Array,
                 f"a 128-multiple block (got C % 128 == {c % 128})")
     bm = _pick_block_m(mp, bc, cin, c)
     row = lambda v: v.reshape(1, c).astype(jnp.float32)  # noqa: E731
-    dx, dw = pl.pallas_call(
+    dx, dw = pallas_call(
         _bwd_kernel,
         grid=(mp // bm, c // bc),
         in_specs=[
@@ -210,7 +208,6 @@ def conv1x1_bn_bwd_fused(dz: jax.Array, y: jax.Array, x_in: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, cin), jnp.float32)],  # dx accum
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),  # sequential
-        interpret=_interpret(),
     )(dz, y, x_in, w, row(g), row(mean), row(inv), row(a_vec), row(b_vec))
     return (dx[:m] if m_pad else dx), dw
 

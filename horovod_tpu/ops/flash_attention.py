@@ -14,8 +14,9 @@ kernels). Numerics are validated against
 `parallel.ring_attention.blockwise_attention_reference` (forward AND
 gradients) in tests/test_flash_attention.py.
 
-Falls back to interpret mode off-TPU so the same code path is testable on
-the CPU mesh (tests/conftest.py).
+Off the TPU the kernels run in Pallas interpret mode, so the same code
+path is testable on the CPU mesh (tests/conftest.py); the decision is
+`ops/_pallas.interpret`.
 """
 
 from __future__ import annotations
@@ -28,12 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.ops._pallas import pallas_call
+
 _NEG_INF = -1e30
 _LANES = 128  # lane-replication width for row statistics
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _rep(x):
@@ -102,7 +101,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
     nk = sk // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k)
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
@@ -126,7 +125,6 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        interpret=_interpret(),
     )(q, k, v)
     return o, lse
 
@@ -271,7 +269,7 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     if has_dlse:
         in_specs.append(lse_by_j)
         operands.append(dlse)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_dlse=has_dlse),
@@ -289,7 +287,6 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
             pltpu.VMEM((block_k, dh), jnp.float32),
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
-        interpret=_interpret(),
     )(*operands)
 
     q_by_i = pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0))
@@ -300,7 +297,7 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     if has_dlse:
         in_specs.append(lse_by_i)
         operands.append(dlse)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_dlse=has_dlse),
@@ -309,7 +306,6 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
         out_specs=pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        interpret=_interpret(),
     )(*operands)
     return dq, dk, dv
 

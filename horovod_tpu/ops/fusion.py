@@ -11,9 +11,10 @@ concat into ≤-threshold buckets, run ONE collective per bucket, split back.
 XLA fuses the reshapes/concats into the collective's prologue/epilogue, which
 is exactly what the hand-written memcpy kernels were approximating.
 
-Two properties the original greedy packer lacked, both measured to matter
-(BENCH_r05 fusion sweep: 16-64 MB buckets ~2x slower than 1-4 MB on the
-8-device mesh):
+Two properties the original greedy packer lacked. (Their only timing
+evidence is a sweep on the 8-device virtual CPU mesh — 16-64 MB buckets
+~2x slower than 1-4 MB there; on the chip it is not measured, ROADMAP
+S4):
 
 * **Oversize chunking** — a tensor larger than the threshold used to form
   its own oversized bucket (``max(threshold, nbytes)``), so one 64 MB

@@ -610,9 +610,9 @@ class DistributedOptimizer:
             if getattr(self, "_apply_traced_ok", False):
                 raise
             from horovod_tpu.common.hvd_logging import get_logger
-            get_logger().info(
-                "optimizer apply not jittable (%s); running eagerly",
-                type(e).__name__)
+            get_logger().warning(
+                "optimizer apply not jittable (%s); running the update "
+                "un-jitted from now on", type(e).__name__)
             self._apply_eager = True
             with scope.phase("optimizer"):
                 updates, new_state = self.inner.update(avg, opt_state,
@@ -623,8 +623,8 @@ class DistributedOptimizer:
         """The optax update + apply as ONE compiled program.
 
         Run eagerly, an adam update is ~6 small XLA ops per tensor —
-        hundreds of dispatches per step that dominate wall clock on
-        remote/tunneled devices and waste fusion on local ones. jit
+        hundreds of dispatches per step that dominate wall clock and
+        waste fusion. jit
         re-traces per (treedef, shapes) signature automatically; the
         cache is invalidated if `self.inner` is reassigned.
         """

@@ -203,12 +203,18 @@ def build_mesh(spec: MeshSpec,
                devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build a Mesh with all five named axes from a flat device list.
 
-    Device order follows the same canonical (process_index, id) sort as the
-    global topology (core/topology.py:_canonical_devices) so innermost axes
-    land on devices that are ICI neighbours on real hardware.
+    Device order defaults to the global topology's rank order
+    (core/topology.py:_canonical_devices) — before `hvd.init()`, to the
+    (process_index, id) sort it is built on — so innermost axes land on
+    devices that are ICI neighbours on real hardware.
     """
-    devs = list(devices) if devices is not None else sorted(
-        jax.devices(), key=lambda d: (d.process_index, d.id))
+    if devices is not None:
+        devs = list(devices)
+    else:
+        from horovod_tpu.core import topology
+        st = topology.raw_state()
+        devs = list(st.devices) if st.initialized else sorted(
+            jax.devices(), key=lambda d: (d.process_index, d.id))
     if spec.total != len(devs):
         raise HorovodTpuError(
             f"mesh spec {spec.sizes()} needs {spec.total} devices, "

@@ -288,8 +288,7 @@ def run_elastic(fn, args=(), kwargs=None,
         "HOROVOD_ELASTIC_TIMEOUT": str(elastic_timeout),
         # agents share the launch host in tests; workers must own one CPU
         # device each unless the caller overrides
-        "HOROVOD_WORKER_PLATFORM": base_env.get(
-            "HOROVOD_WORKER_PLATFORM", "cpu"),
+        "JAX_PLATFORMS": base_env.get("JAX_PLATFORMS", "cpu"),
     })
 
     def spawn(slot, round_id: int):

@@ -36,7 +36,6 @@ import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-import horovod_tpu  # noqa: E402, F401  (ensure_jax_api: jax.shard_map)
 from horovod_tpu.optim.optimizer import (  # noqa: E402
     reduce_gradients_in_jit)
 

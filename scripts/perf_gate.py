@@ -21,7 +21,7 @@ Usage::
     python scripts/perf_gate.py --emit /tmp/cur.json
     python scripts/perf_gate.py /tmp/cur.json --baseline scripts/perf_baseline.json
     python scripts/perf_gate.py --run --baseline scripts/perf_baseline.json --update
-    python scripts/perf_gate.py BENCH_r06.json --bench
+    python scripts/perf_gate.py /tmp/bench.json --bench   # a saved bench.py JSON line
 
 ``--emit`` runs two small synthetic workloads under perfscope on the CPU
 backend (seconds of wall clock): an eager-``DistributedOptimizer`` MLP

@@ -1,5 +1,5 @@
-"""Per-component ResNet-50 step breakdown with latency-cancelling slope
-timing (see bench.py _scan_timed). Establishes where the step time goes
+"""Per-component ResNet-50 step breakdown (timed by bench.py
+_scan_timed). Establishes where the step time goes
 before attacking the ~50%-MFU HBM roofline (docs/benchmarks.md).
 
 Usage: python scripts/profile_resnet.py [batch ...]
@@ -12,7 +12,7 @@ import numpy as np
 import optax
 from jax import lax
 
-from bench import _scan_timed  # ONE copy of the slope-timing logic
+from bench import _scan_timed  # ONE copy of the timing logic
 from horovod_tpu.models import resnet
 from horovod_tpu.profiler import flops as F
 
@@ -23,7 +23,7 @@ RESNET50_TRAIN_FLOPS = F.resnet_train_flops_per_image(50, "macs")
 RESNET50_FWD_FLOPS = F.RESNET_FWD_GMACS[50] * 1e9
 
 
-def slope_timed(body, state, chain=10, reps=3, warmup=2):
+def timed(body, state, chain=10, reps=3, warmup=2):
     return _scan_timed(body, state, chain=chain, reps=reps, warmup=warmup)
 
 
@@ -83,14 +83,14 @@ def main():
                     lambda x, init, op, wd, ws, pad: x[:, ::2, ::2, :]
             try:
                 body, state = make_step(b)
-                t = slope_timed(body, state)
+                t = timed(body, state)
                 ips = b / t
                 print(f"B={b} {label} full: {t*1e3:6.1f} ms, {ips:6.0f} "
                       f"img/s, MFU {ips*RESNET50_TRAIN_FLOPS/PEAK:.1%}",
                       flush=True)
                 if patch is None:
                     body, state = make_step(b, fwd_only=True)
-                    t = slope_timed(body, state)
+                    t = timed(body, state)
                     print(f"B={b} {label} fwd:  {t*1e3:6.1f} ms "
                           f"(fwd MFU {b/t*RESNET50_FWD_FLOPS/PEAK:.1%})",
                           flush=True)

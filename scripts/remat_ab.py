@@ -8,8 +8,8 @@ at the flagship config instead of asserting it. Candidates:
           outputs, recompute batched dots (the shipping default)
   full  - policy=None: save nothing, recompute whole layers
 
-Each is slope-timed (docs/benchmarks.md) at its own feasibility: a
-policy that OOMs at B=12 reports so instead of a number.
+Each is timed (host clock behind block_until_ready) at its own
+feasibility: a policy that OOMs at B=12 reports so instead of a number.
 """
 
 import time
@@ -47,32 +47,16 @@ def time_policy(remat, policy, batch=12, steps=18, chain=6):
         lambda c, _: (body(c), ()), s, None, length=chain)[0],
         donate_argnums=(0,))
 
-    def sync(s):
-        jax.block_until_ready(s)
-        leaf = jax.tree_util.tree_leaves(s)[0]
-        float(jnp.sum(leaf.ravel()[:2].astype(jnp.float32)))
-
     state = (params, opt_state, tokens, targets, jnp.zeros(()))
-    for _ in range(2):
+    for _ in range(2):  # the first call compiles
         state = scan(state)
-    sync(state)
-
-    def run(n, s):
-        t0 = time.perf_counter()
-        for _ in range(n):
-            s = scan(s)
-        sync(s)
-        return time.perf_counter() - t0, s
-
-    best, fb = float("inf"), float("inf")
-    for _ in range(2):
-        t1, state = run(1, state)
-        tn, state = run(4, state)
-        slope = (tn - t1) / (3 * chain)
-        if slope > 0:
-            best = min(best, slope)
-        fb = min(fb, tn / (4 * chain))
-    sec = best if best != float("inf") else fb
+    jax.block_until_ready(state)
+    calls = max(steps // chain, 1)
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        state = scan(state)
+    jax.block_until_ready(state)
+    sec = (time.perf_counter() - t0) / (calls * chain)
     return batch * 1024 / sec, sec * 1e3
 
 

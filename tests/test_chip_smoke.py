@@ -1,0 +1,336 @@
+"""chip_smoke.py rehearsed on the CPU (on-chip-measurement guide §2,
+rehearsals 1 and 2): every phase is a plain function of its sizes and
+runs here at a tiny size, the one-chip ones on one virtual device, the
+cross-chip ones on four. What only the chip can show — Mosaic-compiled
+kernels, HBM, times — is asserted by the script itself, which refuses
+to report success off the TPU; that refusal is tested here too.
+"""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+import horovod_tpu as hvd  # noqa: E402
+from horovod_tpu.models import resnet, transformer as tfm  # noqa: E402
+from horovod_tpu.parallel.mesh import MeshSpec  # noqa: E402
+
+TINY_LM = tfm.TransformerConfig(
+    vocab=128, d_model=32, n_heads=2, d_ff=64, n_layers=1, max_seq=64,
+    attn="flash", dtype=jnp.bfloat16, remat=True)
+
+
+@pytest.fixture()
+def smoke(request):
+    """hvd over the first N virtual devices, 32-bit mode (the script
+    refuses x64), one CompileLog."""
+    hvd.shutdown()
+    with jax.enable_x64(False):
+        hvd.init(devices=jax.devices()[:request.param])
+        yield chip_smoke.CompileLog()
+        hvd.shutdown()
+
+
+one_chip = pytest.mark.parametrize("smoke", [1], indirect=True)
+four_chips = pytest.mark.parametrize("smoke", [4], indirect=True)
+
+
+# ------------------------------------------------------- phases, tiny sizes
+
+@one_chip
+def test_phase_eager_api_one_rank(smoke, capsys):
+    chip_smoke.eager_api(smoke, n=4096)
+    assert "agree with numpy (1 rank(s)" in capsys.readouterr().out
+
+
+@four_chips
+def test_phase_eager_api_stacked_ranks(smoke, capsys):
+    chip_smoke.eager_api(smoke, n=4096)
+    assert "agree with numpy (4 rank(s)" in capsys.readouterr().out
+
+
+@one_chip
+def test_phase_flash_kernel(smoke, capsys):
+    chip_smoke.flash_kernel(smoke, shape=(1, 2, 64, 32))
+    out = capsys.readouterr().out
+    # off the TPU the kernel is interpreted, and the phase says so
+    assert "interpret=True, 0 tpu_custom_call" in out
+
+
+@one_chip
+def test_phase_flagship_lm(smoke, capsys):
+    chip_smoke.flagship_lm(smoke, cfg=TINY_LM, batch=4, seq=64, steps=5)
+    out = capsys.readouterr().out
+    assert "0 recompiles after step 1" in out
+    assert "tokens/s" in out and "not reported" in out  # CPU: no HBM stat
+
+
+@one_chip
+def test_phase_resnet_eager(smoke, capsys, monkeypatch):
+    monkeypatch.setitem(resnet.STAGE_BLOCKS, 5, (1, 0, 0, 0))
+    chip_smoke.resnet50_eager(smoke, batch=4, image=32, depth=5, steps=5)
+    out = capsys.readouterr().out
+    assert "resnet5 B4 32px bf16 eager" in out and "images/s" in out
+
+
+@four_chips
+def test_phase_single_controller_lm(smoke, capsys):
+    # meshes and sharding rules are what this rehearses: plain attention
+    # (the interpreted flash kernel ran in the one-device test above)
+    chip_smoke.single_controller_lm(
+        smoke, cfg=dataclasses.replace(TINY_LM, attn="local"), batch=4,
+        seq=64, steps=3)
+    out = capsys.readouterr().out
+    for mesh in ("dp=4", "dp=2,tp=2"):
+        assert f"{mesh}] losses match the one-device run" in out
+
+
+def test_phase_launcher_one_process_per_device(monkeypatch, capsys):
+    """The README launch, -np 2 on the CPU. The phase insists on a parent
+    that has not touched JAX (a chip belongs to one process); this parent
+    has, and on the CPU it does not matter — so the test says it has not."""
+    from jax._src import xla_bridge
+
+    log = chip_smoke.CompileLog()
+    with pytest.raises(AssertionError, match="already holds a JAX backend"):
+        chip_smoke.launcher_one_process_per_chip(log, np_=2, platform="cpu")
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: False)
+    monkeypatch.setenv("XLA_FLAGS", "")  # workers: one CPU device each
+    chip_smoke.launcher_one_process_per_chip(log, np_=2, platform="cpu")
+    out = capsys.readouterr().out
+    assert out.count("SMOKE_WORKER_OK") == 2
+    assert "2 workers, one cpu device each" in out
+
+
+def test_losses_must_be_finite_and_falling():
+    chip_smoke.check_losses("x", [2.0, 1.5, 1.0])
+    for bad in ([1.0, 1.0], [1.0, float("nan")], [1.0, 2.0]):
+        with pytest.raises(AssertionError):
+            chip_smoke.check_losses("x", bad)
+
+
+# ------------------------------------------------- the script as a whole
+
+def test_script_fails_without_a_tpu():
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
+                         capture_output=True, text=True, timeout=300,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "not on a TPU" in out.stderr
+
+
+def test_preflight_refuses_emulation(monkeypatch):
+    monkeypatch.setenv("HOROVOD_TPU_EMULATE_RANKS", "4")
+    with jax.enable_x64(False), \
+            pytest.raises(SystemExit, match="never emulates"):
+        chip_smoke.preflight(1)
+
+
+def test_preflight_refuses_x64_and_the_cpu():
+    with jax.enable_x64(True), pytest.raises(SystemExit, match="x64"):
+        chip_smoke.preflight(1)
+    with jax.enable_x64(False), \
+            pytest.raises(SystemExit, match="not on a TPU"):
+        chip_smoke.preflight(1)
+    hvd.shutdown()
+
+
+def _fake_run(monkeypatch, *phases):
+    """main() with the chip pretended there and stand-in phases."""
+    monkeypatch.setattr(chip_smoke, "preflight", lambda chips: None)
+    monkeypatch.setattr(chip_smoke, "PHASES", {1: ((), phases)})
+    chip_smoke.main([])
+
+
+def _good(log):
+    print("phase ran")
+
+
+def _bad(log):
+    raise RuntimeError("synthetic phase failure")
+
+
+def test_main_prints_ok_last_when_every_phase_passes(monkeypatch, capsys):
+    _fake_run(monkeypatch, _good, _good)
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": len(jax.devices())}}
+
+
+def test_main_fails_when_one_phase_raises(monkeypatch, capsys):
+    """Nothing catches a phase's exception: it leaves main() (so the
+    script exits non-zero), nothing runs after it, no ok line."""
+    with pytest.raises(RuntimeError, match="synthetic phase failure"):
+        _fake_run(monkeypatch, _good, _bad, _good)
+    out = capsys.readouterr().out
+    assert out.count("phase ran") == 1
+    assert '"ok"' not in out
+
+
+def test_four_chip_run_holds_no_single_chip_phase():
+    def flat(chips):
+        return {p for group in chip_smoke.PHASES[chips] for p in group}
+    assert flat(4) and flat(1)
+    assert flat(4).isdisjoint(flat(1))
+    # the launcher phase runs before this process touches JAX
+    assert chip_smoke.PHASES[4][0] == (
+        chip_smoke.launcher_one_process_per_chip,)
+    assert chip_smoke.PHASES[1][0] == ()
+
+
+# ------------------------------------------------------- what it rests on
+
+def test_compile_cache_resolver(monkeypatch, tmp_path):
+    from horovod_tpu.common.config import Config
+    from horovod_tpu.core import topology
+
+    for var in ("JAX_COMPILATION_CACHE_DIR", "HOROVOD_TPU_COMPILE_CACHE"):
+        monkeypatch.delenv(var, raising=False)
+    def resolve(platform):
+        return topology.compile_cache_dir(Config.from_env(), platform)
+
+    assert resolve("tpu") == (os.path.join(REPO, ".jax_cache"), True)
+    assert resolve("cpu") == (None, False)  # the default is for chips
+    monkeypatch.setenv("HOROVOD_TPU_COMPILE_CACHE", str(tmp_path / "h"))
+    for platform in ("tpu", "cpu"):
+        assert resolve(platform) == (str(tmp_path / "h"), True)
+    # JAX's own variable wins and is left to JAX: nothing to set in code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "j"))
+    for platform in ("tpu", "cpu"):
+        assert resolve(platform) == (str(tmp_path / "j"), False)
+
+
+def test_init_sets_the_cache_only_where_the_resolver_says(monkeypatch,
+                                                         tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, hvd.init() makes no
+    jax_compilation_cache_dir update of its own; with only
+    HOROVOD_TPU_COMPILE_CACHE set, it makes exactly that one."""
+    seen = []
+    real = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: seen.append((k, v)) if k == "jax_compilation_cache_dir"
+        else real(k, v))
+    hvd.shutdown()
+    monkeypatch.setenv("HOROVOD_TPU_COMPILE_CACHE", str(tmp_path / "h"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "j"))
+    hvd.init()
+    hvd.shutdown()
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    hvd.init()
+    hvd.shutdown()
+    assert seen == [("jax_compilation_cache_dir", str(tmp_path / "h"))]
+
+
+def test_launcher_gives_each_slot_of_a_host_its_own_chip():
+    from horovod_tpu.runner.hosts import get_host_assignments, parse_hosts
+
+    envs = [s.to_env() for s in
+            get_host_assignments(parse_hosts("localhost:4"), 4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_ADDRESSES"].count(",") == 3
+        assert f"localhost:{e['TPU_PROCESS_PORT']}" in \
+            e["TPU_PROCESS_ADDRESSES"].split(",")
+        assert e["CLOUD_TPU_TASK_ID"] == e["HOROVOD_LOCAL_RANK"]
+    # one worker on a host owns the whole host; layouts the recipe does
+    # not cover get no assignment (a TPU worker then fails hvd.init())
+    for spec, n in (("localhost:1", 1), ("localhost:3", 3),
+                    ("a:4,b:4", 8)):
+        for s in get_host_assignments(parse_hosts(spec), n):
+            assert "TPU_VISIBLE_CHIPS" not in s.to_env()
+
+
+def test_worker_that_sees_several_chips_is_refused(monkeypatch):
+    """hvd.init() under a multi-slot launch on a TPU with more than one
+    local chip is a failure, not a warning."""
+    from horovod_tpu.common.config import Config
+    from horovod_tpu.common.exceptions import HorovodTpuError
+    from horovod_tpu.core import topology
+
+    class Chip:
+        platform = "tpu"
+
+    cfg = Config(rank=1, size=4, local_rank=1, local_size=4)
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()] * 4)
+    with pytest.raises(HorovodTpuError, match="4 local TPU chips"):
+        topology._check_one_chip_per_process(cfg)
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
+    topology._check_one_chip_per_process(cfg)  # its own chip: fine
+    # a single worker per host owns every chip of the host
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()] * 4)
+    topology._check_one_chip_per_process(Config(rank=0, size=1,
+                                                local_size=1))
+
+
+def test_native_library_is_never_loaded_stale(monkeypatch):
+    """When make cannot vouch for the library, a pre-existing .so is not
+    loaded: the Python fallbacks run and status() says "absent"."""
+    from horovod_tpu import native
+
+    assert native.status() in ("built", "up to date")  # toolchain is here
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+    monkeypatch.setattr(native, "_build", lambda: False)
+    assert os.path.exists(native._LIB_PATH)
+    assert native.load() is None
+    assert native.status() == "absent"
+
+
+def test_cpu_emulation_never_replaces_an_accelerator(monkeypatch):
+    from horovod_tpu.common.exceptions import HorovodTpuError
+    from horovod_tpu.core import topology
+
+    class Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda: [Chip()])
+    with pytest.raises(HorovodTpuError, match="JAX_PLATFORMS=cpu"):
+        topology._apply_cpu_emulation(8)
+
+
+def test_mesh_position_follows_the_launchers_rank(monkeypatch):
+    """On a TPU a device's process_index is its process's place in the
+    slice topology, not the launcher's rank (seen on the 2x2 host: ranks
+    0,1,2,3 are processes 0,2,3,1). The mesh is ordered by the rank, so
+    that rank r's tensor is row r of every rank-addressed collective."""
+    from jax.experimental import multihost_utils
+
+    from horovod_tpu.common.config import Config
+    from horovod_tpu.core import topology
+
+    class Chip:
+        platform = "tpu"
+
+        def __init__(self, id, process_index):
+            self.id, self.process_index = id, process_index
+
+    process_of_rank = [0, 2, 3, 1]
+    chips = [Chip(i, i) for i in range(4)]
+    monkeypatch.setattr(jax, "devices", lambda: chips)
+    monkeypatch.setattr(jax, "process_count", lambda: 4)
+    monkeypatch.setattr(jax, "process_index", lambda: 2)
+    monkeypatch.setattr(
+        multihost_utils, "process_allgather",
+        lambda x: [(p, r) for r, p in enumerate(process_of_rank)])
+    devs = topology._canonical_devices(Config(rank=1, size=4))
+    assert [d.process_index for d in devs] == process_of_rank
+    # no launcher identity (single controller): topology order, no exchange
+    monkeypatch.setattr(multihost_utils, "process_allgather", None)
+    devs = topology._canonical_devices(Config())
+    assert [d.process_index for d in devs] == [0, 1, 2, 3]

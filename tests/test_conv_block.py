@@ -278,18 +278,14 @@ def test_resnet_block_path_matches_unfused(monkeypatch):
 
 def test_kernels_lower_through_real_tpu_compiler(monkeypatch):
     """Both new kernels compile for a real v5e topology (compile-only
-    client, zero chips) at a representative ResNet site — probe/skip
-    logic shared with the conv_bn_backward suite (tests/tpu_probe.py)."""
+    client, zero chips) at a representative ResNet site — probe shared
+    with the conv_bn_backward suite (tests/tpu_probe.py, which also
+    switches the kernels from the interpreter to Mosaic)."""
     from tpu_probe import compile_kernel_text, tpu_topology
 
-    from horovod_tpu.ops import conv_bn_backward as cbb
     from horovod_tpu.ops.conv_block import (conv1x1_bn_act_bwd_fused,
                                             conv1x1_fwd_fused)
 
-    # conftest pins the CPU backend, which flips the kernels to
-    # interpret mode — force the real Mosaic lowering (both modules
-    # share conv_bn_backward._interpret)
-    monkeypatch.setattr(cbb, "_interpret", lambda: False)
     topo = tpu_topology(monkeypatch)
     m, cin, c = 128 * 28 * 28, 128, 512
 
@@ -297,11 +293,10 @@ def test_kernels_lower_through_real_tpu_compiler(monkeypatch):
         return jax.ShapeDtypeStruct(shape, dt)
     vec = lambda: st((c,), jnp.float32)  # noqa: E731
     compile_kernel_text(topo, conv1x1_fwd_fused,
-                        (st((m, cin)), st((cin, c))), "_fwd_kernel")
+                        (st((m, cin)), st((cin, c))))
     compile_kernel_text(
         topo,
         lambda dz, y, x, w, s, b, mean, inv, db, dg:
         conv1x1_bn_act_bwd_fused(dz, y, x, w, s, b, mean, inv, db, dg),
         (st((m, c)), st((m, c)), st((m, cin)), st((cin, c)),
-         vec(), vec(), vec(), vec(), vec(), vec()),
-        "_bwd_kernel")
+         vec(), vec(), vec(), vec(), vec(), vec()))

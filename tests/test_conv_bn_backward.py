@@ -201,15 +201,11 @@ def test_kernel_lowers_through_real_tpu_compiler(monkeypatch):
     for a real v5e topology (compile-only client, zero chips) at a
     representative site AND at the VMEM-tightest site that OOM'd during
     development (Cin=512, C=2048 — the resident f32 dW accumulator).
-    Probe/skip logic shared with the conv_block suite
-    (tests/tpu_probe.py); skips where the compile-only client is
-    unavailable."""
+    Probe shared with the conv_block suite (tests/tpu_probe.py, which
+    also switches the kernels from the interpreter to Mosaic); skips
+    only where the topology cannot be described."""
     from tpu_probe import compile_kernel_text, tpu_topology
 
-    # conftest pins the CPU backend, which flips the kernel to interpret
-    # mode — force the real Mosaic lowering for this TPU-target compile
-    from horovod_tpu.ops import conv_bn_backward as cbb
-    monkeypatch.setattr(cbb, "_interpret", lambda: False)
     topo = tpu_topology(monkeypatch)
     from horovod_tpu.ops.conv_bn_backward import conv1x1_bn_bwd_fused
 
@@ -220,5 +216,4 @@ def test_kernel_lowers_through_real_tpu_compiler(monkeypatch):
         compile_kernel_text(
             topo, conv1x1_bn_bwd_fused,
             (st((m, c)), st((m, c)), st((m, cin)), st((cin, c)),
-             vec(), vec(), vec(), vec(), vec()),
-            "conv1x1_bn_bwd_fused")
+             vec(), vec(), vec(), vec(), vec()))

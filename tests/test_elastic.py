@@ -276,7 +276,7 @@ def test_elastic_init_survives_missing_private_api(monkeypatch):
     monkeypatch.setattr(jax.distributed, "initialize", fake_initialize)
 
     # 1) factory vanished entirely (resolve the extension through compat,
-    # like the production path — the module name drifts across jaxlibs)
+    # like the production path)
     from horovod_tpu.common.compat import jaxlib_extension
     _jaxlib = jaxlib_extension()
     monkeypatch.delattr(_jaxlib, "get_distributed_runtime_client")
@@ -298,30 +298,27 @@ def test_elastic_init_survives_missing_private_api(monkeypatch):
 
 def test_recoverable_client_contract_pinned():
     """The elastic in-process recovery path leans on jax._src internals
-    (core/topology.py _elastic_distributed_init). On a jaxlib inside the
-    tested range this must NOT have silently decayed to the
-    worker-restart fallback; outside the range, a broken contract is a
+    (core/topology.py _elastic_distributed_init). On the jaxlib the
+    repository is written for it must NOT have silently decayed to the
+    worker-restart fallback; on any other jaxlib a broken contract is a
     documented degradation (skip, visibly)."""
     import jaxlib
 
     from horovod_tpu.core.topology import (
         RECOVERABLE_CLIENT_TESTED_JAXLIB, recoverable_client_contract)
 
-    lo, hi = RECOVERABLE_CLIENT_TESTED_JAXLIB
-    ver = tuple(int(x) for x in jaxlib.__version__.split(".")[:2])
-    in_range = tuple(int(x) for x in lo.split(".")) <= ver <= \
-        tuple(int(x) for x in hi.split("."))
+    tested = RECOVERABLE_CLIENT_TESTED_JAXLIB
     ok, reason = recoverable_client_contract()
-    if not in_range:
+    if not jaxlib.__version__.startswith(tested + "."):
         if not ok:
-            pytest.skip(f"jaxlib {jaxlib.__version__} outside tested "
-                        f"range {lo}-{hi}; contract broken: {reason} — "
+            pytest.skip(f"jaxlib {jaxlib.__version__} is not the tested "
+                        f"{tested}; contract broken: {reason} — "
                         f"elastic degrades to worker-restart recovery")
         return
     assert ok, (
-        f"jaxlib {jaxlib.__version__} is INSIDE the tested range "
-        f"{lo}-{hi} but the recoverable-client contract broke: {reason}. "
-        "Fix _elastic_distributed_init or extend the tested range.")
+        f"jaxlib {jaxlib.__version__} is the tested {tested} but the "
+        f"recoverable-client contract broke: {reason}. "
+        "Fix _elastic_distributed_init.")
 
 
 def test_elastic_reset_warm_compile_cache(tmp_path):

@@ -1,0 +1,66 @@
+"""Flash attention compiled by the chip's own compiler, without a chip.
+
+The flagship LM's default attention is the Pallas flash kernel
+(ops/flash_attention.py). The interpreter runs of
+tests/test_flash_attention.py cover its numerics; these cases cover
+what the interpreter cannot: that Mosaic accepts the forward and the
+two backward kernels for a described v5e at the shapes the main path
+uses — the flagship LM's (B*H, S, dh) = (12*16, 1024, 128) and the
+long-context S=8192 — and that `jax_enable_x64` (which this suite's
+conftest turns on) does not matter to a kernel compiled for the chip.
+About two seconds each; skipped only where the topology cannot be
+described (tests/tpu_probe.py).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.ops.flash_attention import flash_attention
+
+FLAGSHIP = (12, 16, 1024, 128)   # bench.py / chip_smoke.py flagship LM
+LONG = (1, 16, 8192, 128)
+
+
+def _fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True)
+
+
+def _bwd(q, k, v):
+    return jax.grad(
+        lambda q, k, v: _fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+CASES = [
+    pytest.param(fn, n, shape, False, id=f"{name}-{sid}")
+    for shape, sid in ((FLAGSHIP, "S1024"), (LONG, "S8192"))
+    for name, fn, n in (("fwd", _fwd, 1), ("bwd", _bwd, 3))
+] + [
+    # x64 on: one case, the one that compiles all three kernels
+    pytest.param(_bwd, 3, FLAGSHIP, True, id="bwd-S1024-x64"),
+]
+
+
+@pytest.mark.parametrize("fn,n_calls,shape,x64", CASES)
+def test_flash_attention_compiles_for_v5e(monkeypatch, fn, n_calls, shape,
+                                          x64):
+    from tpu_probe import compile_kernel_text, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    with jax.enable_x64(x64):
+        compile_kernel_text(topo, fn, (q, q, q), n_calls=n_calls)
+
+
+def test_interpret_decision_is_shared_and_visible(monkeypatch):
+    """One helper decides interpreter-vs-Mosaic for every kernel family;
+    on the CPU suite it says "interpret", and flipping it flips the
+    flash and the conv kernels together (what tpu_probe relies on)."""
+    from horovod_tpu.ops import (_pallas, conv_block, conv_bn_backward,
+                                 flash_attention as fa)
+
+    assert _pallas.interpret() is True
+    for mod in (fa, conv_block, conv_bn_backward):
+        assert mod.pallas_call is _pallas.pallas_call
+        assert not hasattr(mod, "_interpret")

@@ -741,6 +741,11 @@ def test_flops_peak_env_override(fresh, monkeypatch):
     monkeypatch.delenv("HOROVOD_BENCH_PEAK_TFLOPS")
     assert F.peak_flops_per_chip("TPU v5 lite") == pytest.approx(197e12)
     assert F.peak_flops_per_chip("Unknown Chip") is None
+    assert F.peak_flops_per_chip("cpu") is None
+    # a TPU the table does not know is an error where an MFU is computed
+    from horovod_tpu.common.exceptions import HorovodTpuError
+    with pytest.raises(HorovodTpuError, match="TPU v99"):
+        F.peak_flops_per_chip("TPU v99")
     # garbage must fail LOUDLY: a silent spec-table fallback would skew
     # every MFU in exactly the runs that set the override
     monkeypatch.setenv("HOROVOD_BENCH_PEAK_TFLOPS", "157,0")
