@@ -77,13 +77,19 @@ class KVServer {
       ::close(listen_fd_);
     }
     if (accept_thread_.joinable()) accept_thread_.join();
-    std::lock_guard<std::mutex> g(conn_mu_);
-    // Serve threads may be blocked in recv() on idle client connections;
-    // shutdown their fds so the joins below cannot hang (Serve still owns
+    // The accept thread is gone, so nothing adds a connection from here on.
+    // Rule: conn_mu_ guards conn_fds_ and conn_threads_ and is never held
+    // across a join(): every Serve thread takes it on its way out, so a
+    // join under it waits for a thread that waits for the lock. shutdown()
+    // wakes the threads blocked in recv() on idle clients (Serve still owns
     // the close()).
-    for (int fd : conn_fds_) ::shutdown(fd, SHUT_RDWR);
-    for (auto& t : conn_threads_)
-      if (t.joinable()) t.join();
+    std::vector<std::thread> threads;
+    {
+      std::lock_guard<std::mutex> g(conn_mu_);
+      for (int fd : conn_fds_) ::shutdown(fd, SHUT_RDWR);
+      threads.swap(conn_threads_);
+    }
+    for (auto& t : threads) t.join();
   }
 
  private:

@@ -13,6 +13,7 @@ with a chip does not take the chip. Pallas kernels run interpreted
 not by this suite.
 """
 
+import faulthandler
 import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -26,6 +27,31 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
 import pytest  # noqa: E402
+
+# Every test has this long for set-up, call and teardown together. A test
+# that overruns is not interrupted, it is ended: faulthandler's watchdog
+# thread writes every thread's stack to stderr and exits the process. Only
+# that reaches a thread blocked inside a C call with the GIL released (a
+# pthread_join, a futex), which a SIGALRM handler cannot. Under xdist the
+# worker dies, the test is reported failed, a new worker takes over the
+# queue; without xdist the run ends there with the stack on the screen.
+# It shares faulthandler's single timer with pytest's `faulthandler_timeout`,
+# which stays at its default of 0.
+TEST_LIMIT_S = 300
+_STDERR_FD = pytest.StashKey[int]()
+
+
+def arm_test_limit(seconds, stderr_fd=2):
+    faulthandler.dump_traceback_later(seconds, exit=True, file=stderr_fd)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    arm_test_limit(TEST_LIMIT_S, item.config.stash[_STDERR_FD])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_addoption(parser):
@@ -42,6 +68,9 @@ def pytest_addoption(parser):
 
 
 def pytest_configure(config):
+    # Output capture is suspended while plugins configure, so fd 2 is the
+    # real stderr here; during a test it is the capture's temporary file.
+    config.stash[_STDERR_FD] = os.dup(2)
     config.addinivalue_line(
         "markers",
         "faults: end-to-end chaos tests driving elastic jobs under injected "

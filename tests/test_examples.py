@@ -117,7 +117,7 @@ def test_scaling_report():
 
     out = _run_example("synthetic_benchmark.py", "--scaling-report", "8",
                        "--batch-size", "2", "--image-size", "32",
-                       "--num-iters", "2", "--num-batches-per-iter", "2",
+                       "--num-iters", "1", "--num-batches-per-iter", "1",
                        "--dtype", "float32")
     line = [ln for ln in out.splitlines()
             if ln.startswith("{")][-1]

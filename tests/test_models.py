@@ -122,8 +122,10 @@ def test_vgg16_forward_backward():
              for a in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0
     # VGG-16 @224/1000 classes is the classic 138M-parameter model
-    p224 = vgg.init(jax.random.PRNGKey(0), depth=16, num_classes=1000,
-                    dtype=jnp.float32, image_size=224)
+    # (counted from shapes: nothing of that size is allocated)
+    p224 = jax.eval_shape(lambda k: vgg.init(
+        k, depth=16, num_classes=1000, dtype=jnp.float32, image_size=224),
+        jax.random.PRNGKey(0))
     n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(p224))
     assert abs(n - 138_357_544) < 1e6, n
 
@@ -134,10 +136,10 @@ def test_inception_v3_forward_backward():
     update, gradient step."""
     from horovod_tpu.models import inception
 
-    params, stats = inception.init(jax.random.PRNGKey(0), num_classes=1000,
-                                   dtype=jnp.float32)
+    shapes, _ = jax.eval_shape(lambda k: inception.init(
+        k, num_classes=1000, dtype=jnp.float32), jax.random.PRNGKey(0))
     n = sum(int(np.prod(a.shape))
-            for a in jax.tree_util.tree_leaves(params))
+            for a in jax.tree_util.tree_leaves(shapes))
     # torchvision inception_v3 (aux_logits excluded): 23,834,568
     assert abs(n - 23_834_568) < 5e5, n
 
@@ -148,9 +150,11 @@ def test_inception_v3_forward_backward():
         jnp.float32)
     y = jnp.asarray([1, 4])
     # ONE 299x299 pass covers loss, gradients, logits path, and the BN
-    # stats refresh (aux) — a separate apply() would double the test cost
-    (l, ns), g = jax.value_and_grad(
-        lambda p: inception.loss_fn(p, stats, (x, y)), has_aux=True)(params)
+    # stats refresh (aux) — a separate apply() would double the test cost.
+    # Jitted: op by op the same pass compiles each of its ~100 distinct
+    # convolutions on its own and takes 4x as long.
+    (l, ns), g = jax.jit(jax.value_and_grad(
+        lambda p: inception.loss_fn(p, stats, (x, y)), has_aux=True))(params)
     assert np.isfinite(float(l))
     assert not np.allclose(np.asarray(ns["stem"]["c0"]["mean"]),
                            np.asarray(stats["stem"]["c0"]["mean"]))

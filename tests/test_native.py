@@ -8,6 +8,7 @@ Chrome-trace JSON), test_stall.py.
 """
 
 import json
+import os
 import threading
 import time
 
@@ -140,3 +141,91 @@ def test_kv_get_larger_than_buffer_refetches(kv):
     c.bitwise("bigc", big, op="or")
     assert c.get_when("bigc", expected=1, timeout=5.0, maxlen=1024) == big
     c.close()
+
+
+# -- KVServer::Stop() must return whatever the clients are doing. Each
+# stop() runs on a helper thread joined with a timeout, so a deadlock is a
+# failed assertion here and not a worker lost to the suite's time limit.
+
+def _stop_within(srv, seconds=10.0):
+    t = threading.Thread(target=srv.stop, daemon=True)
+    t.start()
+    t.join(seconds)
+    if t.is_alive():
+        # Leak the handle: a second stop() from __del__ would delete a
+        # server whose threads still run, and that aborts the process.
+        srv._h = None
+        pytest.fail(f"NativeKVServer.stop() still blocked after {seconds} s")
+
+
+def _connected_clients(port, n):
+    clients = [native.NativeKVClient("127.0.0.1", port) for _ in range(n)]
+    assert all(c.ping() for c in clients)
+    return clients
+
+
+def test_kv_stop_returns_with_idle_clients_connected():
+    srv = native.NativeKVServer()
+    clients = _connected_clients(srv.port, 3)
+    _stop_within(srv)
+    for c in clients:
+        c.close()
+
+
+def test_kv_stop_racing_client_close_leaks_no_thread():
+    """The launcher's case: the workers' connections close at the moment
+    the server stops (runner/launch.py stops it right after the workers
+    died)."""
+    rounds, n_clients = 200, 8
+    n_threads_before = len(os.listdir("/proc/self/task"))
+    port = [0]
+    gate = threading.Barrier(n_clients + 1, timeout=30.0)
+
+    def client_loop():
+        for _ in range(rounds):
+            gate.wait()  # the round's server is up
+            c = native.NativeKVClient("127.0.0.1", port[0])
+            c.ping()
+            gate.wait()  # every client is connected
+            c.close()
+
+    threads = [threading.Thread(target=client_loop, daemon=True)
+               for _ in range(n_clients)]
+    for t in threads:
+        t.start()
+    for _ in range(rounds):
+        srv = native.NativeKVServer()
+        port[0] = srv.port
+        gate.wait()
+        gate.wait()
+        _stop_within(srv)
+    for t in threads:
+        t.join(30.0)
+    assert not any(t.is_alive() for t in threads)
+    # Stop() joined every thread it started. Thread.join() returns a moment
+    # before the OS thread is gone, hence the short wait; <= because a
+    # thread of some other library may have ended meanwhile (a leak would
+    # show as up to 200 x 9 more).
+    deadline = time.monotonic() + 5.0
+    while (len(os.listdir("/proc/self/task")) > n_threads_before
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    assert len(os.listdir("/proc/self/task")) <= n_threads_before
+
+
+def test_kv_request_after_stop_fails_cleanly():
+    srv = native.NativeKVServer()
+    clients = _connected_clients(srv.port, 3)
+    clients[0].put("k", b"v")
+    _stop_within(srv)
+    answers = []
+    t = threading.Thread(
+        target=lambda: answers.extend(
+            (c.ping(), c.get("k"), c.add("n", 1)) for c in clients),
+        daemon=True)
+    t.start()
+    t.join(10.0)
+    assert not t.is_alive(), "a request to a stopped server blocked"
+    assert answers == [(False, None, -100)] * 3
+    for c in clients:
+        c.close()
