@@ -225,6 +225,127 @@ def test_transformer_pipeline_loss_matches():
         grads, grads1)
 
 
+@pytest.mark.parametrize("remat,policy", [(True, "dots"), (True, "full"),
+                                          (False, "dots")])
+def test_transformer_grads_reduced_in_backward_match(remat, policy):
+    """The layers' gradients leave the backward loop reduce-scattered
+    (halving over dp x sp, the /tp rescale inside) whatever the layer scan
+    saves: same loss and gradients as one device, leaf by leaf."""
+    cfg = dataclasses_replace(CFG, remat=remat, remat_policy=policy)
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg)
+    loss1, grads1 = _loss_single(cfg, params, tokens, targets)
+    m = mesh_of(dp=2, tp=2, sp=2)
+    assert tfm._reduces_in_backward(cfg, m)
+    loss, grads = jax.jit(tfm.build_loss_and_grads(cfg, m))(
+        params, tokens, targets)
+    np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-4)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4),
+        grads, grads1)
+
+
+def _collectives(jaxpr):
+    """(primitive, axes, operand shape, result shape) of every collective
+    of `jaxpr`, those of nested jaxprs (shard_map, scan, remat) included;
+    one in a scan body counts once."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("psum", "ppermute", "all_gather", "reduce_scatter"):
+            axes = eqn.params.get("axes", eqn.params.get("axis_name"))
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            found.extend((name, axes, x.aval.shape, y.aval.shape)
+                         for x, y in zip(eqn.invars, eqn.outvars))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_collectives(sub))
+    return found
+
+
+@pytest.mark.parametrize("spec,micro", [
+    (dict(dp=8), 1),
+    (dict(dp=2, tp=2, sp=2), 1),
+    (dict(pp=2, dp=2, sp=2), 2),
+])
+def test_every_gradient_leaf_is_reduced_once_per_axis(spec, micro):
+    """In the traced program every gradient leaf meets exactly one
+    reduction per reduce axis of more than one rank: none twice, none
+    missed. A psum covers its axes; so does the one all-gather that
+    completes a leaf reduce-scattered in the backward loop, which also has
+    its log2(n) halving exchanges and no psum. Leaves are told by their
+    per-shard shapes: sizes at which no activation, and no piece of a
+    scattered leaf, has the shape of another leaf."""
+    from collections import Counter
+    from horovod_tpu.parallel.mesh import mesh_axis_sizes
+
+    cfg = dataclasses_replace(CFG, microbatches=micro, n_layers=6, vocab=80,
+                              max_seq=128)
+    m = mesh_of(**spec)
+    tfm.validate_cfg_for_mesh(cfg, m)
+    sizes = mesh_axis_sizes(m)
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg)
+    jaxpr = jax.make_jaxpr(tfm.build_loss_and_grads(cfg, m))(
+        params, tokens, targets).jaxpr
+
+    def shard_shape(x, pspec):
+        names = tuple(pspec) + (None,) * (x.ndim - len(pspec))
+        return tuple(d // (sizes[a] if a else 1)
+                     for d, a in zip(x.shape, names))
+
+    shapes = jax.tree_util.tree_map(shard_shape, params,
+                                    tfm.param_specs(cfg))
+    is_axes = lambda x: isinstance(x, tuple)   # noqa: E731
+    leaves = list(zip(
+        jax.tree_util.tree_leaves(shapes, is_leaf=is_axes),
+        jax.tree_util.tree_leaves(tfm.grad_reduce_axes(cfg),
+                                  is_leaf=is_axes)))
+    want = Counter((shape, a) for shape, axes in leaves for a in axes
+                   if sizes[a] > 1)
+
+    collectives = _collectives(jaxpr)
+    got, exchanges = Counter(), Counter()
+    for name, axes, operand, result in collectives:
+        covered = {"psum": operand, "all_gather": result}.get(name)
+        for a in axes:
+            if sizes[a] > 1 and covered is not None:
+                got[(covered, a)] += 1
+        if name == "ppermute":
+            exchanges[operand] += 1
+    leaf_shapes = {shape for shape, _ in leaves}
+    got = Counter({k: v for k, v in got.items() if k[0] in leaf_shapes})
+    assert got == want
+
+    # the halving exchanges of the leaves scattered in the backward loop
+    halves = Counter()
+    if tfm._reduces_in_backward(cfg, m):
+        for shape, axes in leaves:
+            axes = [a for a in axes if sizes[a] > 1]
+            n = int(np.prod([sizes[a] for a in axes]))
+            layer = shape[1:]
+            dim = next((d for d, s in enumerate(layer) if s % n == 0), None)
+            if len(shape) < 3 or dim is None:      # not a stacked matrix
+                continue
+            pieces = {layer}
+            while n > 1:
+                layer = layer[:dim] + (layer[dim] // 2,) + layer[dim + 1:]
+                halves[layer] += 1
+                pieces.add(layer)
+                n //= 2
+            # ... and is psum'd nowhere on its way through the loop
+            assert not [c for c in collectives if c[0] == "psum"
+                        and c[2] in pieces
+                        and any(sizes[a] > 1 for a in c[1])]
+        assert halves, "no leaf is reduced inside the backward loop"
+    assert {s: exchanges[s] for s in halves} == dict(halves)
+    if micro > 1:
+        assert not any(name == "all_gather" for name, *_ in collectives)
+
+
 def test_transformer_moe_train_step_runs():
     cfg = dataclasses_replace(CFG, num_experts=4, attn="ring")
     params = tfm.init(jax.random.PRNGKey(0), cfg)

@@ -33,7 +33,7 @@ def _v5e_devices():
 
 
 def _compile(spec, cfg, seq, batch):
-    mesh = build_mesh(spec, devices=_v5e_devices())
+    mesh = build_mesh(spec, devices=_v5e_devices()[:spec.total])
     tfm.validate_cfg_for_mesh(cfg, mesh)
     params = tfm.init(jax.random.PRNGKey(0), cfg)
     opt = optax.adam(1e-3)
@@ -68,3 +68,58 @@ def test_pp_ep_moe_train_step_lowers_on_tpu():
     # pipeline microbatch loop
     assert "while(" in txt
     assert "all-reduce" in txt
+
+
+def _computations(txt):
+    """name -> body text of every computation of a compiled program; the
+    entry computation under "ENTRY"."""
+    import re
+    found = {}
+    for block in re.split(r"\n(?=(?:ENTRY\s+)?%?[\w.\-]+\s*\([^\n]*->[^\n]*\{\n)",
+                          txt):
+        head = block.split("(", 1)[0].strip()
+        found["ENTRY" if head.startswith("ENTRY") else head.lstrip("%")] = \
+            block
+    return found
+
+
+DP_CFG = tfm.TransformerConfig(vocab=256, d_model=128, n_heads=4, d_ff=512,
+                               n_layers=6, max_seq=64, attn="local",
+                               dtype=jnp.bfloat16, remat=True)
+
+
+def test_dp_gradients_are_reduced_inside_the_backward_loop_on_tpu():
+    """MeshSpec(dp=4): the layers' gradient reduction sits in a while body
+    (the backward loop) in asynchronous form, as collective-permute
+    `-start`/`-done` pairs, and the entry computation all-reduces no
+    stacked (n_layers, ...) leaf: what is left for after the loop are the
+    all-gathers that complete the shards and the leaves outside the scan.
+    That it compiles at all says the installed compiler knows the option
+    `build_train_step` gives it."""
+    import re
+    comps = _computations(_compile(MeshSpec(dp=4), DP_CFG, seq=32, batch=8))
+    bodies = re.findall(r"while\([^\n]*body=%?([\w.\-]+)", comps["ENTRY"])
+    assert bodies, "the layer scans were not compiled to loops"
+    in_loop = [b for b in bodies
+               if "collective-permute-start" in comps[b]
+               and "collective-permute-done" in comps[b]]
+    assert in_loop, ("no backward loop issues the layers' gradient "
+                     "reduction asynchronously")
+    for b in in_loop:
+        # issued and awaited inside the loop, and not by an all-reduce or a
+        # reduce-scatter, which the core would wait for
+        assert " all-reduce(" not in comps[b]
+        assert " reduce-scatter(" not in comps[b]
+    stacked = re.compile(r"= \(?bf16\[%d,\d+,\d+[^\n]* all-reduce\("
+                         % DP_CFG.n_layers)
+    assert not stacked.search(comps["ENTRY"]), \
+        "a stacked layer gradient is all-reduced after the backward loop"
+    assert "all-gather" in "".join(comps.values())
+    assert "all-reduce" in comps["ENTRY"]      # loss, embed, unembed, norms
+
+
+def test_single_device_train_step_holds_no_collective_on_tpu():
+    txt = _compile(MeshSpec(), DP_CFG, seq=32, batch=8)
+    for kind in ("all-reduce", "all-gather", "reduce-scatter",
+                 "collective-permute", "all-to-all"):
+        assert kind not in txt, f"{kind} in the one-device train step"
