@@ -14,6 +14,12 @@ framework supports, in one compiled XLA program per train step:
   ep    — MoE FFN experts sharded; all_to_all token dispatch
           (parallel/moe.py). When num_experts == 0 the FFN is dense.
 
+One block, whose architecture `TransformerConfig` states: the defaults are
+the GPT-2 block (LayerNorm, learned positions, GELU MLP); `norm="rmsnorm"`,
+`positions="rope"`, `qk_norm=True`, `mlp="swiglu"` and top-k experts with
+their auxiliary losses make it OLMoE's (arXiv:2409.02060). No biases except
+the GPT-2 block's LayerNorm and MLP ones.
+
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
 
@@ -49,7 +55,23 @@ class TransformerConfig:
     n_layers: int = 4
     max_seq: int = 2048
     num_experts: int = 0          # 0 → dense FFN; >0 → MoE every layer
+    experts_per_token: int = 1    # the k of top-k routing
+    # rows one rank may send one expert, as a multiple of an even share;
+    # means something only across ranks (ep > 1): see parallel/moe.py
     capacity_factor: float = 2.0
+    # loss = cross-entropy + load_balance_coef * load balance
+    #        + router_z_coef * router z-loss, each averaged over the layers
+    load_balance_coef: float = 0.0
+    router_z_coef: float = 0.0
+    norm: str = "layernorm"       # "layernorm" (scale and bias) | "rmsnorm"
+    positions: str = "learned"    # "learned" (a table added to the
+    #                               embedding) | "rope" (rotate-half pairs)
+    rope_theta: float = 10000.0
+    # RMSNorm on the projected queries and keys, over the whole projected
+    # vector (all heads), before it is split into heads and rotated
+    qk_norm: bool = False
+    # "gelu" (biased when dense) | "swiglu" (gated SiLU; experts only)
+    mlp: str = "gelu"
     attn: str = "ring"            # "ring" | "ulysses" | "flash" | "local"
     microbatches: int = 1         # pipeline microbatches (≥ pp size ideal)
     dtype: Any = jnp.float32
@@ -70,45 +92,69 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
+def _present(tree: Dict[str, Any], cfg: TransformerConfig):
+    """`tree` (parameters, specs or reduce axes, laid out as `init` lays the
+    parameters out, with every leaf any architecture has) without the leaves
+    `cfg`'s architecture does not have."""
+    if cfg.mlp == "swiglu" and not cfg.num_experts:
+        raise HorovodTpuError("mlp='swiglu' needs num_experts > 0: the "
+                              "dense MLP is the GPT-2 block's")
+    absent = set()
+    if cfg.norm == "rmsnorm":
+        absent |= {"ln1_bias", "ln2_bias", "lnf_bias"}
+    if cfg.positions == "rope":
+        absent.add("pos")
+    if not cfg.qk_norm:
+        absent |= {"q_scale", "k_scale"}
+    if cfg.num_experts:
+        absent |= {"w1", "b1", "w2", "b2"}
+    else:
+        absent |= {"router", "we1", "we2"}
+    if cfg.mlp != "swiglu":
+        absent.add("we_gate")
+    return {k: _present(v, cfg) if k == "layers" else v
+            for k, v in tree.items() if k not in absent}
+
+
 def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Global (unsharded) parameter pytree."""
-    D, H, dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
-                         cfg.n_layers, cfg.vocab)
+    D, H, dh, F, L, V, E = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
+                            cfg.n_layers, cfg.vocab, cfg.num_experts)
     dt = cfg.dtype
     ks = jax.random.split(key, 12)
 
     def norm(k, shape, fan_in):
-        return jax.random.normal(k, shape, dt) * fan_in ** -0.5
+        return lambda: jax.random.normal(k, shape, dt) * fan_in ** -0.5
 
-    layers: Dict[str, Any] = {
-        "ln1_scale": jnp.ones((L, D), dt), "ln1_bias": jnp.zeros((L, D), dt),
-        "wq": norm(ks[0], (L, D, H, dh), D),
-        "wk": norm(ks[1], (L, D, H, dh), D),
-        "wv": norm(ks[2], (L, D, H, dh), D),
-        "wo": norm(ks[3], (L, H, dh, D), H * dh),
-        "ln2_scale": jnp.ones((L, D), dt), "ln2_bias": jnp.zeros((L, D), dt),
-    }
-    if cfg.num_experts:
-        E = cfg.num_experts
-        layers.update({
+    def ones(*shape):
+        return lambda: jnp.ones(shape, dt)
+
+    def zeros(*shape):
+        return lambda: jnp.zeros(shape, dt)
+
+    # every leaf any architecture has, each made only if this one has it
+    make = {
+        "embed": lambda: norm(ks[7], (V, D), 1.0)() * 0.02 * D ** 0.5,
+        "pos": lambda: norm(ks[8], (cfg.max_seq, D), 1.0)() * 0.02,
+        "layers": {
+            "ln1_scale": ones(L, D), "ln1_bias": zeros(L, D),
+            "wq": norm(ks[0], (L, D, H, dh), D),
+            "wk": norm(ks[1], (L, D, H, dh), D),
+            "wv": norm(ks[2], (L, D, H, dh), D),
+            "wo": norm(ks[3], (L, H, dh, D), H * dh),
+            "q_scale": ones(L, H, dh), "k_scale": ones(L, H, dh),
+            "ln2_scale": ones(L, D), "ln2_bias": zeros(L, D),
             "router": norm(ks[4], (L, D, E), D),
             "we1": norm(ks[5], (L, E, D, F), D),
             "we2": norm(ks[6], (L, E, F, D), F),
-        })
-    else:
-        layers.update({
-            "w1": norm(ks[4], (L, D, F), D),
-            "b1": jnp.zeros((L, F), dt),
-            "w2": norm(ks[5], (L, F, D), F),
-            "b2": jnp.zeros((L, D), dt),
-        })
-    return {
-        "embed": norm(ks[7], (V, D), 1.0) * 0.02 * D ** 0.5,
-        "pos": norm(ks[8], (cfg.max_seq, D), 1.0) * 0.02,
-        "layers": layers,
-        "lnf_scale": jnp.ones((D,), dt), "lnf_bias": jnp.zeros((D,), dt),
+            "we_gate": norm(ks[10], (L, E, D, F), D),
+            "w1": norm(ks[4], (L, D, F), D), "b1": zeros(L, F),
+            "w2": norm(ks[5], (L, F, D), F), "b2": zeros(L, D),
+        },
+        "lnf_scale": ones(D), "lnf_bias": zeros(D),
         "unembed": norm(ks[9], (D, V), D),
     }
+    return jax.tree_util.tree_map(lambda f: f(), _present(make, cfg))
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -120,23 +166,19 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "wk": P("pp", None, "tp", None),
         "wv": P("pp", None, "tp", None),
         "wo": P("pp", "tp", None, None),
+        "q_scale": P("pp", "tp", None), "k_scale": P("pp", "tp", None),
         "ln2_scale": P("pp", None), "ln2_bias": P("pp", None),
+        "router": P("pp", None, None),
+        "we1": P("pp", "ep", None, None),
+        "we2": P("pp", "ep", None, None),
+        "we_gate": P("pp", "ep", None, None),
+        "w1": P("pp", None, "tp"), "b1": P("pp", "tp"),
+        "w2": P("pp", "tp", None), "b2": P("pp", None),
     }
-    if cfg.num_experts:
-        lp.update({
-            "router": P("pp", None, None),
-            "we1": P("pp", "ep", None, None),
-            "we2": P("pp", "ep", None, None),
-        })
-    else:
-        lp.update({
-            "w1": P("pp", None, "tp"), "b1": P("pp", "tp"),
-            "w2": P("pp", "tp", None), "b2": P("pp", None),
-        })
-    return {
+    return _present({
         "embed": P(), "pos": P(), "layers": lp,
         "lnf_scale": P(), "lnf_bias": P(), "unembed": P(),
-    }
+    }, cfg)
 
 
 def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -153,19 +195,18 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     data_axes = ("dp", "ep", "sp", "tp")    # replicated-over-tp layer params
     glob = ("dp", "ep", "sp", "pp", "tp")   # replicated-over-everything
     tp_sharded = ("dp", "ep", "sp")         # tp-sharded weights: no tp psum
+    experts = ("dp", "sp", "tp")            # expert-sharded over ep
     lp = {"ln1_scale": data_axes, "ln1_bias": data_axes,
           "ln2_scale": data_axes, "ln2_bias": data_axes,
           "wq": tp_sharded, "wk": tp_sharded, "wv": tp_sharded,
-          "wo": tp_sharded}
-    if cfg.num_experts:
-        lp.update({"router": data_axes,
-                   "we1": ("dp", "sp", "tp"),   # expert-sharded over ep
-                   "we2": ("dp", "sp", "tp")})
-    else:
-        lp.update({"w1": tp_sharded, "b1": tp_sharded, "w2": tp_sharded,
-                   "b2": data_axes})
-    return {"embed": glob, "pos": glob, "layers": lp,
-            "lnf_scale": glob, "lnf_bias": glob, "unembed": glob}
+          "wo": tp_sharded, "q_scale": tp_sharded, "k_scale": tp_sharded,
+          "router": data_axes, "we1": experts, "we2": experts,
+          "we_gate": experts,
+          "w1": tp_sharded, "b1": tp_sharded, "w2": tp_sharded,
+          "b2": data_axes}
+    return _present({"embed": glob, "pos": glob, "layers": lp,
+                     "lnf_scale": glob, "lnf_bias": glob, "unembed": glob},
+                    cfg)
 
 
 def _ln(x, scale, bias, eps=1e-5):
@@ -175,12 +216,62 @@ def _ln(x, scale, bias, eps=1e-5):
     return ((xf - mu) * lax.rsqrt(var + eps)).astype(x.dtype) * scale + bias
 
 
-def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig):
-    """One transformer block on per-shard activations x: (B, S_loc, D)."""
-    h = _ln(x, lp["ln1_scale"], lp["ln1_bias"])
+def _rms(x, scale, eps=1e-5):
+    """RMSNorm: x * rsqrt(mean(x^2) + eps) * scale, statistics in float32."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(ms + eps)).astype(x.dtype) * scale
+
+
+def _norm(x, p, name, cfg: TransformerConfig):
+    if cfg.norm == "rmsnorm":
+        return _rms(x, p[name + "_scale"])
+    return _ln(x, p[name + "_scale"], p[name + "_bias"])
+
+
+def _qk_norm(x, scale, eps=1e-5):
+    """RMSNorm of the projected queries or keys x: (B, H_loc, S, dh) over
+    the whole projected vector, all heads of all `tp` ranks; scale: (H_loc,
+    dh)."""
+    xf = x.astype(jnp.float32)
+    ss = lax.psum(jnp.sum(jnp.square(xf), axis=(1, 3), keepdims=True), "tp")
+    width = x.shape[1] * x.shape[3] * lax.axis_size("tp")
+    return (xf * lax.rsqrt(ss / width + eps)).astype(x.dtype) \
+        * scale[None, :, None, :]
+
+
+def _rope_angles(positions, head_dim: int, theta: float):
+    """(cos, sin), each (S, head_dim / 2) float32, of the rotary embedding
+    at `positions`: pair i turns by position * theta^(-2i / head_dim)."""
+    half = head_dim // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rope(x, angles):
+    """Rotates x: (B, H, S, dh) in the rotate-half pairing (i, i + dh/2)."""
+    cos, sin = angles
+    half = x.shape[-1] // 2
+    a = x[..., :half].astype(jnp.float32)
+    b = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
+           rope=None):
+    """One transformer block on per-shard activations x: (B, S_loc, D).
+    Returns (x, aux): aux is None for a dense MLP, and for experts the
+    layer's [load balance, router z] of this shard's tokens."""
+    h = _norm(x, lp, "ln1", cfg)
     q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
     k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
     v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
+    if cfg.qk_norm:
+        q, k = _qk_norm(q, lp["q_scale"]), _qk_norm(k, lp["k_scale"])
+    if rope is not None:
+        q, k = _rope(q, rope), _rope(k, rope)
     if cfg.attn == "ring":
         a = ring_attention(q, k, v, "sp", causal=True)
     elif cfg.attn == "ulysses":
@@ -201,27 +292,31 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig):
     o = lax.psum(o, "tp")                    # row-parallel combine
     x = x + o
 
-    h2 = _ln(x, lp["ln2_scale"], lp["ln2_bias"])
+    h2 = _norm(x, lp, "ln2", cfg)
+    aux = None
     if cfg.num_experts:
         B, S, D = h2.shape
-        flat = h2.reshape(B * S, D)
-        out = moe_mod.moe_ffn(flat, lp["router"], lp["we1"], lp["we2"],
-                              axis_name="ep",
-                              capacity_factor=cfg.capacity_factor)
+        out, aux, _ = moe_mod.moe_ffn(
+            h2.reshape(B * S, D), lp["router"], lp["we1"], lp["we2"],
+            lp.get("we_gate"), top_k=cfg.experts_per_token, axis_name="ep",
+            capacity_factor=cfg.capacity_factor)
         f = out.reshape(B, S, D)
     else:
         u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
         u = jax.nn.gelu(u)
         f = jnp.einsum("bsf,fd->bsd", u, lp["w2"])
         f = lax.psum(f, "tp") + lp["b2"]
-    return x + f
+    return x + f, aux
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig,
-                   grad_slots=None, scatter=None) -> jax.Array:
-    """Per-shard forward to logits. tokens: (B_loc, S_loc) int32, batch
-    sharded over (dp, ep), sequence over sp, run under shard_map. With
-    pp > 1 only the last stage's logits are real (zeros elsewhere).
+                   grad_slots=None, scatter=None):
+    """Per-shard forward to (logits, aux). tokens: (B_loc, S_loc) int32,
+    batch sharded over (dp, ep), sequence over sp, run under shard_map. With
+    pp > 1 only the last stage's logits are real (zeros elsewhere). aux is
+    None for a dense MLP; for experts it is the [load balance, router z] of
+    each of this stage's layers on this shard's tokens, (L_loc, 2), averaged
+    over the microbatches where there are any.
     `grad_slots` (stacked per layer like the layers' parameters) and
     `scatter` are what `build_loss_and_grads` reduces the layers' gradients
     through inside the backward loop: see `_scattered_in_backward`."""
@@ -230,13 +325,19 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     D = cfg.d_model
 
     x = params["embed"][tokens]
-    pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S, axis=0)
-    x = (x + pos[None]).astype(cfg.dtype)
+    rope = None
+    if cfg.positions == "rope":
+        rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.head_dim,
+                            cfg.rope_theta)
+        x = x.astype(cfg.dtype)
+    else:
+        pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S, axis=0)
+        x = (x + pos[None]).astype(cfg.dtype)
 
     def stage_fn(stage_params, act):
         def body(a, xs):
             lp = _scattered_in_backward(*xs, scatter) if grad_slots else xs
-            return _layer(a, lp, cfg), None
+            return _layer(a, lp, cfg, rope)
         if cfg.remat:
             # "dots": save projection/FFN matmul outputs (small, expensive
             # to recompute); recompute batched-dot products — exactly the
@@ -253,9 +354,8 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
                     f"{sorted(policies)} (remat=False turns remat off)")
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=policies[cfg.remat_policy])
-        out, _ = lax.scan(body, act, (stage_params, grad_slots)
-                          if grad_slots else stage_params)
-        return out
+        return lax.scan(body, act, (stage_params, grad_slots)
+                        if grad_slots else stage_params)
 
     M = cfg.microbatches
     if lax.axis_size("pp") > 1 and M <= 1:
@@ -267,13 +367,15 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
             raise HorovodTpuError(f"local batch {B} not divisible by "
                                   f"microbatches {M}")
         xm = x.reshape(M, B // M, S, D)
-        ym = pp_mod.pipeline_apply(stage_fn, params["layers"], xm, "pp")
+        ym, aux = pp_mod.pipeline_apply(stage_fn, params["layers"], xm, "pp",
+                                        has_aux=True)
         x = ym.reshape(B, S, D)
+        aux = None if aux is None else aux / M
     else:
-        x = stage_fn(params["layers"], x)
+        x, aux = stage_fn(params["layers"], x)
 
-    x = _ln(x, params["lnf_scale"], params["lnf_bias"])
-    return jnp.einsum("bsd,dv->bsv", x, params["unembed"])
+    x = _norm(x, params, "lnf", cfg)
+    return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
 
 
 def _local_loss(params, tokens, targets, cfg: TransformerConfig,
@@ -281,7 +383,7 @@ def _local_loss(params, tokens, targets, cfg: TransformerConfig,
     """Per-shard loss contribution (see NOTE below on psum placement)."""
     pp_size = lax.axis_size("pp")
     B, S = tokens.shape
-    logits = _forward_local(params, tokens, cfg, grad_slots, scatter)
+    logits, aux = _forward_local(params, tokens, cfg, grad_slots, scatter)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     local_sum = jnp.sum(nll)
@@ -305,7 +407,18 @@ def _local_loss(params, tokens, targets, cfg: TransformerConfig,
     # the psum transposes complete the chain rule — but (b) overcounts
     # gradients of tp-SHARDED leaves by tp, since a shard feeds every
     # redundant loss copy. build_loss_and_grads rescales the sharded leaves.
-    return local_sum / n_tokens
+    local = local_sum / n_tokens
+    if aux is not None and (cfg.load_balance_coef or cfg.router_z_coef):
+        # Each shard's layers give the auxiliary terms of its own tokens;
+        # the loss takes their mean over the layers (every pipeline stage
+        # adds its own) and over the shards of the batch and the sequence,
+        # not the terms of the global batch's statistics.
+        coefs = jnp.array([cfg.load_balance_coef, cfg.router_z_coef],
+                          jnp.float32)
+        shards = (lax.axis_size("dp") * lax.axis_size("ep")
+                  * lax.axis_size("sp"))
+        local = local + jnp.sum(aux @ coefs) / (cfg.n_layers * shards)
+    return local
 
 
 def psum_axes(x, axes):
@@ -460,7 +573,7 @@ def build_forward(cfg: TransformerConfig, mesh: Mesh):
     bspec = P(("dp", "ep"), "sp")
 
     def fn(params, tokens):
-        logits = _forward_local(params, tokens, cfg)
+        logits, _ = _forward_local(params, tokens, cfg)
         # With pp > 1 only the last stage holds real logits (zeros
         # elsewhere); psum over pp collapses them to the real values.
         return lax.psum(logits, "pp")

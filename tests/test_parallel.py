@@ -155,7 +155,8 @@ def test_moe_sharded_matches_single():
 
     m = mesh_of(ep=EP)
     out = jax.jit(jax.shard_map(
-        lambda xx, r, a, b: moe_ffn(xx, r, a, b, "ep", capacity_factor=64.0),
+        lambda xx, r, a, b: moe_ffn(xx, r, a, b, top_k=1, axis_name="ep",
+                                    capacity_factor=64.0)[0],
         mesh=m,
         in_specs=(P("ep"), P(), P("ep"), P("ep")),
         out_specs=P("ep"), check_vma=False))(x, router, w1, w2)

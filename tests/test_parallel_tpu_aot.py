@@ -70,6 +70,24 @@ def test_pp_ep_moe_train_step_lowers_on_tpu():
     assert "all-reduce" in txt
 
 
+def test_olmoe_block_train_step_lowers_on_tpu():
+    """The OLMoE block on one rank (dropless routing), bf16 with remat: the
+    compiler turns `lax.ragged_dot` into grouped-matmul kernels of its own
+    (three forward, three under remat, six backward), and the four parts of
+    the expert layer keep their scopes in the compiled text."""
+    import re
+    cfg = tfm.TransformerConfig(
+        vocab=512, d_model=256, n_heads=2, d_ff=128, n_layers=2, max_seq=256,
+        num_experts=8, experts_per_token=2, load_balance_coef=0.01,
+        router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
+        mlp="swiglu", attn="local", dtype=jnp.bfloat16, remat=True)
+    txt = _compile(MeshSpec(), cfg, seq=256, batch=2)
+    assert len(re.findall(r'op_name="ragged-dot-none"', txt)) == 12
+    for part in ("route", "dispatch", "experts", "combine"):
+        assert f"/moe.{part}/" in txt, part
+    assert "all-to-all" not in txt and "all-reduce" not in txt
+
+
 def _computations(txt):
     """name -> body text of every computation of a compiled program; the
     entry computation under "ENTRY"."""
