@@ -41,7 +41,8 @@ from horovod_tpu import native
 from horovod_tpu.core import topology
 from horovod_tpu.models import resnet, transformer as tfm
 from horovod_tpu.ops import _pallas
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.flash_attention import (causal_tile_share,
+                                             flash_attention)
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 
@@ -263,6 +264,8 @@ def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128)) -> None:
                 f"its range (tolerance {BF16_RTOL:.3g})")
     say(f"[flash attention] shape {shape} bf16: fwd and dq/dk/dv agree "
         f"with the reference (worst {worst:.2e} of range); "
+        f"causal_tile_share {causal_tile_share(shape[2]):.4f} (score "
+        "entries computed over the causal half's); "
         f"interpret={_pallas.interpret()}, {n_kernels} tpu_custom_call "
         "in the compiled program")
 

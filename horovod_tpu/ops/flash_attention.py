@@ -10,7 +10,11 @@ No reference counterpart exists (the reference is a DP framework with no
 attention ops); the kernel follows the standard FlashAttention-2
 recurrence. Row statistics ride in lane-replicated (block_q, 128) buffers
 to satisfy the TPU's (8, 128) tiling (same convention as stock Pallas TPU
-kernels). Numerics are validated against
+kernels). A causal kernel multiplies only what the causal half holds:
+blocks above the diagonal are skipped, blocks under it run unmasked, and
+a block on it is walked in row strips that stop at the diagonal's tile
+(`_for_each_strip`; docs/kernels.md has the timings that chose the form).
+Numerics are validated against
 `parallel.ring_attention.blockwise_attention_reference` (forward AND
 gradients) in tests/test_flash_attention.py.
 
@@ -35,20 +39,94 @@ _NEG_INF = -1e30
 _LANES = 128  # lane-replication width for row statistics
 
 
-def _rep(x):
-    """Replicate a (bq, 1) column across the 128-lane minor dim."""
-    return jnp.broadcast_to(x, (x.shape[0], _LANES))
+# --------------------------------------------------------------------------
+# Which part of a block causal attention holds
+# --------------------------------------------------------------------------
+
+def _causal_tile(block_q: int, block_k: int) -> Optional[int]:
+    """Edge of the tiles a block on the diagonal is walked in: the larger of
+    256 and 128 that divides it. None where it runs as one masked product:
+    blocks that are not square (the diagonal then enters them anywhere) and
+    blocks neither divides (S <= 1024 is one block of any size)."""
+    if block_q != block_k:
+        return None
+    for t in (256, 128):
+        if t < block_q and block_q % t == 0:
+            return t
+    return None
+
+
+def _crossed_strips(block_q: int, block_k: int):
+    """The part of a block the diagonal crosses that causal attention holds,
+    as static (row0, rows, cols) strips: rows [row0, row0 + rows) against
+    the block's first `cols` columns. Per row of t x t tiles one strip that
+    ends with the tile on the diagonal, so the tiles above it are in no
+    product: 10 of a 1024² block's 16 at t = 256. No tile: the whole block."""
+    t = _causal_tile(block_q, block_k)
+    if t is None:
+        return [(0, block_q, block_k)]
+    return [(i * t, t, (i + 1) * t) for i in range(block_q // t)]
+
+
+def _for_each_strip(step, *, causal, iq, ik, block_q, block_k):
+    """Run `step(row0, rows, cols, masked)` over what attention holds of
+    block (iq, ik): all of it, unmasked, when not causal or wholly under the
+    diagonal; nothing when wholly above; `_crossed_strips`, masked, when the
+    diagonal crosses it."""
+    if not causal:
+        step(0, block_q, block_k, False)
+        return
+    q_lo = iq * block_q
+    k_lo = ik * block_k
+    under = k_lo + block_k - 1 <= q_lo
+    above = k_lo > q_lo + block_q - 1
+
+    @pl.when(under)
+    def _under():
+        step(0, block_q, block_k, False)
+
+    @pl.when(jnp.logical_not(jnp.logical_or(under, above)))
+    def _crossed():
+        for row0, rows, cols in _crossed_strips(block_q, block_k):
+            step(row0, rows, cols, True)
+
+
+def _scores(q_ref, k_ref, row0, rows, cols, masked, *,
+            scale, iq, ik, block_q, block_k):
+    """s = q·kᵀ·scale of one strip, its entries above the diagonal at
+    `_NEG_INF` where the strip is `masked`."""
+    q = q_ref[0, row0:row0 + rows, :].astype(jnp.float32)
+    k = k_ref[0, :cols, :].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if masked:
+        row = iq * block_q + row0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, cols), 0)
+        col = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, cols), 1)
+        s = jnp.where(col <= row, s, _NEG_INF)
+    return s
 
 
 # --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
 
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic at n lanes. Whole vregs are
+    repeated, which moves nothing across lanes; other widths broadcast."""
+    if n % _LANES == 0:
+        return pltpu.repeat(x, n // _LANES, axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
 
     @pl.when(ik == 0)
     def _init():
@@ -56,35 +134,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: blocks strictly above the diagonal contribute nothing.
-    needed = jnp.logical_or(
-        jnp.logical_not(causal),
-        ik * block_k <= iq * block_q + block_q - 1)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)              # (bq, dh)
-        k = k_ref[0].astype(jnp.float32)              # (bk, dh)
-        v = v_ref[0].astype(jnp.float32)              # (bk, dh)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        if causal:
-            rows = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(cols <= rows, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                          # (bq, 1)
+    def step(row0, rows, cols, masked):
+        """Online-softmax update of the strip's rows. m and l stay
+        lane-replicated as values too: sliced to a column and broadcast
+        again, each update cost more than the tiles a strip skips."""
+        r = slice(row0, row0 + rows)
+        s = _scores(q_ref, k_ref, row0, rows, cols, masked,
+                    scale=scale, **block)              # (rows, cols)
+        v = v_ref[0, :cols, :].astype(jnp.float32)
+        m_prev = m_ref[r, :]                           # (rows, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)                # (bq, 1)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = _rep(m_new)
-        l_ref[:] = _rep(l_new)
+        p = jnp.exp(s - _lanes(m_new, cols))           # (rows, cols)
+        alpha = jnp.exp(m_prev - m_new)                # (rows, 128)
+        l_ref[r, :] = alpha * l_ref[r, :] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[r, :] = m_new
+        acc_ref[r, :] = (
+            acc_ref[r, :] * _lanes(alpha, acc_ref.shape[1])
+            + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32))
+
+    _for_each_strip(step, causal=causal, **block)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -133,74 +202,58 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
 # Backward
 # --------------------------------------------------------------------------
 
-def _attn_block(q_ref, k_ref, lse_ref, *, scale, causal,
-                iq, ik, block_q, block_k):
-    """Recompute the probability block p = exp(s·scale − lse)."""
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if causal:
-        rows = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(cols <= rows, s, _NEG_INF)
-    return jnp.exp(s - lse_ref[0][:, :1])
-
-
-def _delta_block(o_ref, do_ref):
-    """delta = rowsum(do ∘ o): the softmax-jacobian correction term."""
-    return jnp.sum(do_ref[0].astype(jnp.float32)
-                   * o_ref[0].astype(jnp.float32), axis=-1, keepdims=True)
+def _bwd_strip(refs, row0, rows, cols, masked, *, scale, **block):
+    """(p, ds) of one strip: the probabilities recomputed from the saved
+    logsumexp, p = exp(s·scale − lse), and the score gradient
+    ds = p ∘ (do·vᵀ − delta [+ dlse]) · scale, with
+    delta = rowsum(do ∘ o) the softmax-jacobian correction term.
+    (dlse: ∂lse/∂s = p — the lse output is differentiable so block
+    results can be merged OUTSIDE the kernel, e.g. per ring hop.)"""
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dlse_ref = refs
+    r = slice(row0, row0 + rows)
+    s = _scores(q_ref, k_ref, row0, rows, cols, masked, scale=scale, **block)
+    p = jnp.exp(s - lse_ref[0, r, :])                  # (rows, cols)
+    do = do_ref[0, r, :].astype(jnp.float32)           # (rows, dh)
+    v = v_ref[0, :cols, :].astype(jnp.float32)
+    delta = jnp.sum(do * o_ref[0, r, :].astype(jnp.float32),
+                    axis=-1, keepdims=True)            # (rows, 1)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)            # (rows, cols)
+    bracket = dp - delta
+    if dlse_ref is not None:
+        bracket = bracket + dlse_ref[0, r, :]
+    return p, p * bracket * scale
 
 
 def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
-    if has_dlse:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dlse_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        dlse_ref = None
+    # q, k, v, o, do, lse, dlse (or None) as `_bwd_strip` takes them
+    ins = refs[:6] + (refs[6] if has_dlse else None,)
+    dk_ref, dv_ref, dk_acc, dv_acc = refs[6 + has_dlse:]
+    q_ref, do_ref = ins[0], ins[4]
     ik = pl.program_id(1)
     iq = pl.program_id(2)
     nq = pl.num_programs(2)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed = jnp.logical_or(
-        jnp.logical_not(causal),
-        iq * block_q + block_q - 1 >= ik * block_k)
+    def step(row0, rows, cols, masked):
+        p, ds = _bwd_strip(ins, row0, rows, cols, masked,
+                           scale=scale, **block)
+        r = slice(row0, row0 + rows)
+        # dv += pᵀ · do ;  dk += dsᵀ · q   (the strip's columns only)
+        dv_acc[:cols, :] = dv_acc[:cols, :] + jax.lax.dot_general(
+            p, do_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[:cols, :] = dk_acc[:cols, :] + jax.lax.dot_general(
+            ds, q_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(needed)
-    def _step():
-        p = _attn_block(q_ref, k_ref, lse_ref, scale=scale, causal=causal,
-                        iq=iq, ik=ik, block_q=block_q, block_k=block_k)
-        do = do_ref[0].astype(jnp.float32)             # (bq, dh)
-        v = v_ref[0].astype(jnp.float32)               # (bk, dh)
-        delta = _delta_block(o_ref, do_ref)            # (bq, 1)
-        # dv += pᵀ · do
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # ds = p ∘ (do·vᵀ − delta [+ dlse]) · scale ;  dk += dsᵀ · q
-        # (dlse: ∂lse/∂s = p — the lse output is differentiable so block
-        # results can be merged OUTSIDE the kernel, e.g. per ring hop.)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, bk)
-        bracket = dp - delta
-        if dlse_ref is not None:
-            bracket = bracket + dlse_ref[0][:, :1]
-        ds = p * bracket * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_each_strip(step, causal=causal, **block)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -209,43 +262,29 @@ def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
-    if has_dlse:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dlse_ref,
-         dq_ref, dq_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-         dq_ref, dq_acc) = refs
-        dlse_ref = None
+    ins = refs[:6] + (refs[6] if has_dlse else None,)
+    dq_ref, dq_acc = refs[6 + has_dlse:]
+    k_ref = ins[1]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    needed = jnp.logical_or(
-        jnp.logical_not(causal),
-        ik * block_k <= iq * block_q + block_q - 1)
+    def step(row0, rows, cols, masked):
+        _, ds = _bwd_strip(ins, row0, rows, cols, masked,
+                           scale=scale, **block)
+        r = slice(row0, row0 + rows)
+        # dq += ds · k
+        dq_acc[r, :] = dq_acc[r, :] + jax.lax.dot_general(
+            ds, k_ref[0, :cols, :].astype(jnp.float32),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(needed)
-    def _step():
-        p = _attn_block(q_ref, k_ref, lse_ref, scale=scale, causal=causal,
-                        iq=iq, ik=ik, block_q=block_q, block_k=block_k)
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        delta = _delta_block(o_ref, do_ref)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        bracket = dp - delta
-        if dlse_ref is not None:
-            bracket = bracket + dlse_ref[0][:, :1]
-        ds = p * bracket * scale                       # (bq, bk)
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_each_strip(step, causal=causal, **block)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -404,13 +443,30 @@ def flash_attention_chunk(q, k, v, causal: bool = False,
 def _auto_block(S: int) -> Optional[int]:
     """Largest legal block for a sequence length (measured on v5e: big
     blocks win — 1024² blocks are ~2x naive XLA attention at S=8192;
-    128² blocks lose to grid overhead)."""
+    128² blocks lose to grid overhead). A causal kernel walks the blocks
+    on the diagonal in tiles (`_crossed_strips`), so the big block no
+    longer means computing the half of it the mask throws away."""
     if S <= 1024:
         return S  # block == full dim is always a legal TPU tiling
     for b in (1024, 512, 256, 128):
         if S % b == 0:
             return b
     return None
+
+
+def causal_tile_share(S: int, block: Optional[int] = None,
+                      t: Optional[int] = None) -> float:
+    """Score entries the causal kernels compute over the S²/2 the causal
+    half holds (1.0: none wasted): blocks under the diagonal whole, blocks
+    on it in tiles of edge `t` (`t == block`: whole, as before the walk).
+    The defaults are the kernels' own choice for S."""
+    if block is None:
+        block = _auto_block(S)
+    if t is None:
+        t = _causal_tile(block, block) or block
+    n, m = S // block, block // t
+    computed = n * (n - 1) // 2 * block * block + n * m * (m + 1) // 2 * t * t
+    return computed / (S * S / 2)
 
 
 def flash_attention(q, k, v, causal: bool = True,

@@ -64,6 +64,8 @@ def test_phase_flash_kernel(smoke, capsys):
     out = capsys.readouterr().out
     # off the TPU the kernel is interpreted, and the phase says so
     assert "interpret=True, 0 tpu_custom_call" in out
+    # S=64 is one block no tile divides: all of it computed, half of it used
+    assert "causal_tile_share 2.0000" in out
 
 
 @one_chip

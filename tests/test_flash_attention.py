@@ -2,6 +2,8 @@
 the exact score-materializing oracle. Runs in interpret mode on the CPU
 mesh; the identical kernel compiles on TPU."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,3 +76,120 @@ def test_flash_smaller_blocks():
     want = blockwise_attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# What each case runs (ops/flash_attention.py `_for_each_strip`):
+#   lm-blocks        S=2048 in 1024 blocks, the LM cells': one block under the
+#                    diagonal (unmasked), two on it (walked in 256-tiles),
+#                    one above (skipped); head_dim 128
+#   one-diagonal     S=1024 as a single block, walked in 256-tiles
+#   tile-divides     S=512: one block, 256-tiles
+#   small-tile       S=256: one block, the smaller tile (128)
+#   no-tile-100/640  blocks no tile divides: one masked product
+#   not-square       block_q != block_k: every crossed block one masked product
+#   non-causal       one unmasked product a block
+#   chunk-dlse       flash_attention_chunk, causal, with an lse cotangent
+_PATHS = {
+    "lm-blocks": dict(S=2048, dh=128),
+    "one-diagonal": dict(S=1024),
+    "tile-divides": dict(S=512),
+    "small-tile": dict(S=256),
+    "no-tile-100": dict(S=100),
+    "no-tile-640": dict(S=640),
+    "not-square": dict(S=512, block_q=256, block_k=128),
+    "non-causal": dict(S=512, causal=False),
+    "chunk-dlse": dict(S=512, chunk=True),
+}
+
+
+def _causal_chunk_reference(q, k, v):
+    """(o, lse) of causal attention with the score matrix written out."""
+    S, dh = q.shape[2:]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") * dh ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v,
+                   precision="highest")
+    return o, lse
+
+
+@pytest.mark.parametrize("case", list(_PATHS))
+def test_flash_paths_match_reference_forward_and_gradients(case):
+    from horovod_tpu.ops.flash_attention import flash_attention_chunk
+
+    kw = dict(_PATHS[case])
+    S, dh = kw.pop("S"), kw.pop("dh", 64)
+    causal, chunk = kw.pop("causal", True), kw.pop("chunk", False)
+    q, k, v = _qkv(jax.random.PRNGKey(S), B=1, H=1, S=S, dh=dh)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    wl = jax.random.normal(jax.random.PRNGKey(8), q.shape[:3], jnp.float32)
+
+    def loss_and_o(attn, q, k, v):
+        if chunk:   # a non-zero cotangent for lse as well as for o
+            o, lse = attn(q, k, v)
+            return jnp.sum(o * w) + jnp.sum(lse * wl), o
+        o = attn(q, k, v)
+        return jnp.sum(o * w), o
+
+    if chunk:
+        flash = lambda q, k, v: flash_attention_chunk(  # noqa: E731
+            q, k, v, causal=True)
+        ref = _causal_chunk_reference
+    else:
+        flash = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, causal=causal, **kw)
+        ref = lambda q, k, v: blockwise_attention_reference(  # noqa: E731
+            q, k, v, causal=causal)
+
+    def both(attn):
+        return jax.jit(jax.value_and_grad(
+            functools.partial(loss_and_o, attn),
+            argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, o), grads = both(flash)
+    (_, o_ref), grads_ref = both(ref)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-5, atol=2e-5)
+    for got, want, name in zip(grads, grads_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{case}: d{name} mismatch")
+
+
+def test_crossed_strips_cover_the_causal_half_exactly_once():
+    """The strips of a diagonal block hold every (row, col <= row) once and,
+    where a tile divides the block, nothing beyond the tile on the diagonal:
+    what `causal_tile_share` counts is what the kernels multiply."""
+    from horovod_tpu.ops.flash_attention import (_causal_tile,
+                                                 _crossed_strips,
+                                                 causal_tile_share)
+    for bq, bk in ((1024, 1024), (512, 512), (256, 256), (640, 640),
+                   (100, 100), (256, 128)):
+        seen = np.zeros((bq, bk), np.int32)
+        for row0, rows, cols in _crossed_strips(bq, bk):
+            seen[row0:row0 + rows, :cols] += 1
+        lower = np.tril(np.ones((bq, bk), bool))
+        assert np.all(seen[lower] == 1) and seen.max() == 1, (bq, bk)
+        t = _causal_tile(bq, bk)
+        if t is None:
+            assert seen.sum() == bq * bk
+        else:   # one diagonal block is S = block
+            assert seen.sum() == causal_tile_share(bq, bq, t) * bq * bq / 2
+            above = np.triu(np.ones((bq, bk), bool), k=t)
+            assert not seen[above].any()
+
+
+def test_causal_tile_share_at_the_cells_shapes():
+    from horovod_tpu.ops.flash_attention import causal_tile_share
+    # what the kernels computed before the walk: whole blocks
+    assert causal_tile_share(2048, 1024, 1024) == 1.5
+    assert causal_tile_share(4096, 1024, 1024) == 1.25
+    assert causal_tile_share(2048, 1024, 256) == 1.125
+    assert causal_tile_share(4096, 1024, 256) == 1.0625
+    # what they choose themselves: lm-1chip / lm-dp4, olmoe-1chip, the smoke
+    assert causal_tile_share(2048) <= 1.125
+    assert causal_tile_share(4096) <= 1.07
+    assert causal_tile_share(1024) <= 1.25
+    for S in (100, 128, 256, 512, 640, 1024, 2048, 4096, 8192, 32768):
+        assert 1.0 <= causal_tile_share(S) <= 2.0, S

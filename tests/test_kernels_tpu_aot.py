@@ -5,9 +5,14 @@ The flagship LM's default attention is the Pallas flash kernel
 tests/test_flash_attention.py cover its numerics; these cases cover
 what the interpreter cannot: that Mosaic accepts the forward and the
 two backward kernels for a described v5e at the shapes the main path
-uses — the flagship LM's (B*H, S, dh) = (12*16, 1024, 128) and the
-long-context S=8192 — and that `jax_enable_x64` (which this suite's
-conftest turns on) does not matter to a kernel compiled for the chip.
+uses — the flagship LM's (B*H, S, dh) = (12*16, 1024, 128), the
+benchmark's LM cells' (4*16, 2048, 128) and `olmoe-1chip`'s
+(2*16, 4096, 128), whose diagonal blocks are walked in tiles (a tile
+shape Mosaic refuses fails here, not first on the chip), and the
+long-context S=8192 — that the three custom calls keep the operands and
+results the benchmark's readers know them by, and that `jax_enable_x64`
+(which this suite's conftest turns on) does not matter to a kernel
+compiled for the chip.
 About two seconds each; skipped only where the topology cannot be
 described (tests/tpu_probe.py).
 """
@@ -19,7 +24,13 @@ import pytest
 from horovod_tpu.ops.flash_attention import flash_attention
 
 FLAGSHIP = (12, 16, 1024, 128)   # bench.py / chip_smoke.py flagship LM
+LM_CELLS = (4, 16, 2048, 128)    # lm-1chip, lm-dp4 per chip
+OLMOE_CELL = (2, 16, 4096, 128)  # olmoe-1chip
 LONG = (1, 16, 8192, 128)
+
+#: (operands, results) of the forward (q, k, v -> o, lse), dk/dv and dq
+#: (q, k, v, o, do, lse -> ...) custom calls.
+SIGNATURES = {"fwd": [(3, 2)], "bwd": [(3, 2), (6, 1), (6, 2)]}
 
 
 def _fwd(q, k, v):
@@ -33,24 +44,28 @@ def _bwd(q, k, v):
 
 
 CASES = [
-    pytest.param(fn, n, shape, False, id=f"{name}-{sid}")
-    for shape, sid in ((FLAGSHIP, "S1024"), (LONG, "S8192"))
-    for name, fn, n in (("fwd", _fwd, 1), ("bwd", _bwd, 3))
+    pytest.param(name, shape, False, id=f"{name}-{sid}")
+    for shape, sid in ((FLAGSHIP, "S1024"), (LM_CELLS, "S2048"),
+                       (OLMOE_CELL, "S4096"), (LONG, "S8192"))
+    for name in ("fwd", "bwd")
 ] + [
     # x64 on: one case, the one that compiles all three kernels
-    pytest.param(_bwd, 3, FLAGSHIP, True, id="bwd-S1024-x64"),
+    pytest.param("bwd", FLAGSHIP, True, id="bwd-S1024-x64"),
 ]
 
 
-@pytest.mark.parametrize("fn,n_calls,shape,x64", CASES)
-def test_flash_attention_compiles_for_v5e(monkeypatch, fn, n_calls, shape,
-                                          x64):
-    from tpu_probe import compile_kernel_text, tpu_topology
+@pytest.mark.parametrize("name,shape,x64", CASES)
+def test_flash_attention_compiles_for_v5e(monkeypatch, name, shape, x64):
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
 
     topo = tpu_topology(monkeypatch)
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    want = SIGNATURES[name]
     with jax.enable_x64(x64):
-        compile_kernel_text(topo, fn, (q, q, q), n_calls=n_calls)
+        txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
+                                  (q, q, q), n_calls=len(want))
+    assert mosaic_signatures(txt) == want
 
 
 def test_interpret_decision_is_shared_and_visible(monkeypatch):

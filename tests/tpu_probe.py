@@ -79,3 +79,12 @@ def compile_kernel_text(topo, fn, avals, n_calls: int = 1) -> str:
         txt = jax.jit(fn).lower(*shaped).compile().as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == n_calls, txt
     return txt
+
+
+def mosaic_signatures(txt: str) -> list:
+    """Sorted (operands, results) of every Mosaic custom call in a compiled
+    module's text, read by the parser the benchmark's readers tell the flash
+    kernels with (benchmark/layer_metrics/flash_roofline.py `SIGNATURES`)."""
+    from benchmark.harness import hlo
+    return sorted((i.n_operands, len(i.results))
+                  for i in hlo.index(txt).values() if i.is_mosaic_kernel)
