@@ -45,11 +45,6 @@
 #                    COMMITTED step (not epoch start), finish with a
 #                    final state bit-identical to the uninterrupted
 #                    twin, and leave a doctor-readable [ckpt] trail
-#   make perf-gate   perfscope CI sentinel: emit StepProfiles from the
-#                    synthetic workloads and gate them against the
-#                    checked-in scripts/perf_baseline.json (structure
-#                    assertions on CPU hosts; numeric tolerances only
-#                    under HOROVOD_PERF_GATE_NUMERIC=1 — docs/perf.md)
 #   make lint        hvdlint static analysis: collective-consistency +
 #                    concurrency rules + env-knob docs drift, gating on
 #                    findings NEW relative to the checked-in baseline
@@ -84,14 +79,13 @@
 #                    (HOROVOD_RACE_CHECK=1); any guarded-by violation
 #                    fails the run (docs/static_analysis.md)
 #   make native      build the native control-plane library
-#   make bench       one-line JSON benchmark (real accelerator if present)
 
 PYTHON ?= python
 PYTEST ?= $(PYTHON) -m pytest -q
 
-.PHONY: test test-fast test-unit test-multiprocess test-e2e chaos entry native bench lint lint-baseline hlo-lint hlo-lint-baseline shard-lint shard-lint-baseline sched-lint sched-lint-baseline num-lint num-lint-baseline gspmd-smoke metrics race doctor-smoke serve-smoke trace-smoke watch-smoke ckpt-smoke kv-ha-smoke fusion-smoke conv-smoke perf-gate perfboard-smoke
+.PHONY: test test-fast test-unit test-multiprocess test-e2e chaos entry native lint lint-baseline hlo-lint hlo-lint-baseline shard-lint shard-lint-baseline sched-lint sched-lint-baseline num-lint num-lint-baseline gspmd-smoke metrics race doctor-smoke serve-smoke trace-smoke watch-smoke ckpt-smoke kv-ha-smoke fusion-smoke conv-smoke
 
-test: lint hlo-lint shard-lint sched-lint num-lint gspmd-smoke test-unit test-multiprocess test-e2e chaos doctor-smoke serve-smoke trace-smoke watch-smoke ckpt-smoke kv-ha-smoke fusion-smoke conv-smoke perf-gate perfboard-smoke entry
+test: lint hlo-lint shard-lint sched-lint num-lint gspmd-smoke test-unit test-multiprocess test-e2e chaos doctor-smoke serve-smoke trace-smoke watch-smoke ckpt-smoke kv-ha-smoke fusion-smoke conv-smoke entry
 
 test-fast:
 	$(PYTEST) tests/ --ignore=tests/test_multiprocess.py \
@@ -162,23 +156,6 @@ ckpt-smoke:
 kv-ha-smoke:
 	$(PYTEST) tests/test_kv_ha.py
 	$(PYTEST) tests/test_kv_ha_e2e.py --run-faults -m faults
-
-# perfscope CI sentinel (docs/perf.md): emit StepProfiles from the
-# synthetic CPU workloads and compare against the checked-in baseline.
-# Structure-only on CPU hosts; arm HOROVOD_PERF_GATE_NUMERIC=1 on a
-# dedicated perf host to enforce the step-time tolerance bands too.
-perf-gate:
-	$(PYTHON) scripts/perf_gate.py --run \
-	    --baseline scripts/perf_baseline.json
-
-# Cross-round trajectory (docs/benchmarks.md): the perfboard unit
-# suite — loader pins against synthetic rounds of every format, the CLI,
-# and the gate run both ways (a clean trajectory passes, a synthetically
-# regressed fixture round fails naming section AND dominant moved
-# phase). The repository keeps no round files of its own; the driver's
-# record is PERF_LEDGER.jsonl.
-perfboard-smoke:
-	$(PYTEST) tests/test_perfboard.py
 
 # Conv fast path (docs/perf.md): the fused-vs-reference equivalence
 # suite for the conv+BN+ReLU block kernels + the layout pass, then the
@@ -340,7 +317,7 @@ race:
 	    tests/test_hvdlint.py tests/test_hvdnum.py \
 	    tests/test_group_axis_label.py \
 	    tests/test_serve.py tests/test_ckpt.py \
-	    tests/test_kv_ha.py tests/test_perfboard.py \
+	    tests/test_kv_ha.py \
 	    --deselect tests/test_elastic.py::test_elastic_reset_warm_compile_cache
 
 entry:
@@ -348,6 +325,3 @@ entry:
 
 native:
 	$(MAKE) -C horovod_tpu/native
-
-bench:
-	$(PYTHON) bench.py

@@ -48,7 +48,7 @@ from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-#: The flagship LM (bench.py bench_transformer): L12 D2048 F8192 H16 V32768,
+#: The flagship LM: L12 D2048 F8192 H16 V32768,
 #: bf16, flash attention, remat — run at B=12 S=1024.
 FLAGSHIP = tfm.TransformerConfig(
     vocab=32768, d_model=2048, n_heads=16, d_ff=8192, n_layers=12,

@@ -51,8 +51,8 @@ package moves that detection LEFT of the job launch:
   chains that are not unions of disjoint cycles (the 1F1B hazard),
   inconsistently-ordered overlapping subset collectives, flat
   cross-slice all-reduces where ICI/DCN staging is available, and
-  predicted exposed comms from the analytic per-axis cost model that
-  bench.py stamps beside the measured ``comms_by_axis``.
+  predicted exposed comms from the analytic per-axis cost model, to
+  read beside the measured ``comms_by_axis``.
 
 * ``verifier`` is the runtime companion (``HOROVOD_CHECK_COLLECTIVES=1``):
   each rank hashes its rolling sequence of

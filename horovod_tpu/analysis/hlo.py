@@ -561,7 +561,7 @@ def record_metrics(findings: Sequence[Finding]) -> None:
 # ------------------------------------------------- canonical step lower
 
 def _force_cpu_mesh(min_devices: int = 2):
-    """CPU backend with a multi-device virtual mesh (the perf_gate
+    """CPU backend with a multi-device virtual mesh (the conftest
     recipe). The lint lowers on the CPU by design — it needs no chip and
     must not take one — so the platform is set here, not left to the
     environment."""

@@ -445,9 +445,9 @@ def stamp(text: str,
     gradient-scale table, off the SAME compiled text the comms stamps
     read, replica groups classified by the SAME shard.group_axis_label
     helper — so scale attribution and comms attribution can never
-    disagree on what a group means. perf_gate requires this stamp
-    structurally on every gspmd section; perfboard carries its finding
-    count across rounds."""
+    disagree on what a group means. No caller stamps it since PR 28
+    removed the old benchmark script (ROADMAP D2e): it goes, or
+    `benchmark.aot_check` calls it."""
     np_ = analyze_text(text, path)
     accum = set()
     for op in np_.prog.ops:

@@ -76,7 +76,6 @@ DEFAULT_MODULES: Tuple[str, ...] = (
     "horovod_tpu.serve.batching",
     "horovod_tpu.serve.pool",
     "horovod_tpu.ckpt.async_ckpt",
-    "horovod_tpu.observability.perfboard",
     "horovod_tpu.analysis.schedule",
     "horovod_tpu.analysis.numerics",
 )

@@ -20,9 +20,9 @@ hop for permute/send/recv — over a two-tier link table (fast
 intra-slice ICI vs slow inter-slice DCN, the slice boundary declared
 by ``HOROVOD_MESH_SLICES``; parallel/mesh.slice_groups). Constants
 follow the flops.py policy: documented fallbacks, env-overridable
-(``HOROVOD_SCHED_LINK_GBPS``), loud ValueError on garbage. bench.py
-stamps :func:`comms_model` beside the measured ``comms_by_axis`` so
-perfboard can track predicted-vs-measured across rounds, and both
+(``HOROVOD_SCHED_LINK_GBPS``), loud ValueError on garbage.
+:func:`comms_model` is the stamp to read beside the measured
+``comms_by_axis`` (no caller since PR 28: ROADMAP D2e), and both
 attributions share ONE group classifier (shard.group_axis_label) so
 they can never disagree on what a replica group means.
 

@@ -76,8 +76,8 @@ def _min_sharded_bytes() -> int:
 
 
 def hbm_budget_bytes() -> Optional[int]:
-    """HVD303 gate; None (rule silent) when unset. Also the budget the
-    bench memory stamp reports against (bench.py, docs/perf.md)."""
+    """HVD303 gate: the per-device HBM budget the static peak estimate
+    is judged against; None (rule silent) when unset."""
     return S._bytes_env("HOROVOD_HLO_LINT_HBM_BUDGET", None)
 
 

@@ -1268,30 +1268,6 @@ def _group_label(g: Dict[str, Any]) -> str:
     return base if g["round"] == 0 else f"round {g['round']} · {base}"
 
 
-def _trajectory_lines(traj: Dict[str, Any]) -> List[str]:
-    """The [trajectory] body: latest round + flagged moves, pointing at
-    the full perfboard report for the attribution detail."""
-    out: List[str] = []
-    latest = traj["latest"]
-    meta = "meta provenance" if latest["meta"] else "no meta (legacy)"
-    out.append(f"  {traj['rounds']} bench round(s) in {traj['dir']}; "
-               f"latest r{latest['n']:02d} ({latest['format']}, "
-               f"platform {latest['platform'] or '?'}, {meta})")
-    for reg in traj["regressions"]:
-        phase = (f" — dominant moved phase: {reg['dominant_phase']}"
-                 if reg.get("dominant_phase") else "")
-        out.append(f"  REGRESSED {reg['section']}.{reg['metric']} "
-                   f"{reg['delta_pct']:+.1f}% vs trajectory{phase}")
-    if not traj["regressions"]:
-        out.append("  no flagged moves vs the trajectory")
-    if traj["config_drift"]:
-        out.append(f"  {traj['config_drift']} series moved with a "
-                   "platform change (config drift, not gated)")
-    out.append("  full report: python -m "
-               "horovod_tpu.observability.perfboard")
-    return out
-
-
 def _trace_split(e: Dict[str, Any]) -> str:
     """'queue X ms, dispatch Y ms, device Z ms' with '?' for hops the
     joined fragments did not cover."""
@@ -1482,13 +1458,6 @@ def render(report: Dict[str, Any], tail: int = 8) -> str:
         if ck.get("stall_rearms"):
             add(f"  stall deadline re-armed {ck['stall_rearms']} "
                 f"time(s) while a peer restored")
-        add("")
-    traj = report.get("trajectory")
-    if traj:
-        add("[trajectory] cross-round perf trajectory (perfboard; "
-            "docs/benchmarks.md)")
-        for ln in _trajectory_lines(traj):
-            add(ln)
         add("")
     perf = report.get("perf")
     if perf:
@@ -1692,10 +1661,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-ranks", type=int, default=256,
                    help="KV scrape probe ceiling when no dump names the "
                         "job size")
-    p.add_argument("--rounds", default="", metavar="DIR",
-                   help="also cross-link the perfboard trajectory from "
-                        "this rounds directory (BENCH_rXX.json) as a "
-                        "[trajectory] section")
     return p
 
 
@@ -1732,27 +1697,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         perf.extend(load_perf_kv(addr, port, max_ranks=args.max_ranks))
         watch.extend(load_watch_kv(addr, port, max_ranks=args.max_ranks))
         traces.extend(load_trace_kv(addr, port, max_ranks=args.max_ranks))
-    trajectory = None
-    if args.rounds:
-        # Lazy import: doctor must stay usable on hosts without the
-        # bench/perfboard stack having ever run.
-        from horovod_tpu.observability.perfboard import doctor_summary
-        trajectory = doctor_summary(args.rounds)
-        if trajectory is None:
-            print(f"doctor: no loadable BENCH_rXX.json rounds in "
-                  f"{args.rounds}", file=sys.stderr)
     if not args.dir and not args.kv:
-        if trajectory is not None:
-            # Trajectory-only invocation: render just that section.
-            if args.json:
-                json.dump({"trajectory": trajectory}, sys.stdout,
-                          indent=2)
-                print()
-            else:
-                print("[trajectory] cross-round perf trajectory "
-                      "(perfboard; docs/benchmarks.md)")
-                print("\n".join(_trajectory_lines(trajectory)))
-            return 0
         build_parser().print_help(sys.stderr)
         return 2
     dumps = dedupe(loaded)
@@ -1763,8 +1708,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 2
     report = merge(dumps, tail=args.tail, perf=perf, watch=watch,
                    traces=traces)
-    if trajectory is not None:
-        report["trajectory"] = trajectory
     if args.trace:
         export_trace(dumps, args.trace, traces=traces)
         print(f"doctor: wrote merged trace to {args.trace}",

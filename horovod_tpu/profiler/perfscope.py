@@ -12,8 +12,7 @@ rolling per-rank summary that feeds four sinks:
   ``perf``) on the metrics-exporter cadence, persisted by the launcher at
   job end so ``hvddoctor`` gains a perf section that names stragglers
   *and their dominant phase*,
-* structured ``StepProfile`` dicts per bench section (``bench.py``),
-  gated in CI by ``scripts/perf_gate.py`` against a checked-in baseline,
+* a structured ``StepProfile`` dict on request (``step_profile``),
 * ``hvd.perfscope()`` for ad-hoc inspection.
 
 Phases
@@ -61,9 +60,9 @@ comms/optimizer split out and everything else under ``dispatch``.
 
 MFU is computed as in the PaLM paper (Chowdhery et al., 2022): model
 FLOPs per step over wall time, divided by chip peak. Model FLOPs come
-from XLA cost analysis when available (``profiler/flops.py``), the hand
-constants demoted to documented fallbacks — `set_model_flops` records
-both the value and its source.
+from XLA cost analysis (``profiler/flops.py``: 2 FLOPs a multiply-add,
+as the peak counts) — `set_model_flops` records both the value and its
+source.
 
 Knobs: ``HOROVOD_PERFSCOPE=0`` swaps the scope for a no-op shell (same
 pattern as ``HOROVOD_METRICS=0``); ``HOROVOD_PERFSCOPE_WINDOW`` sizes
@@ -238,8 +237,8 @@ class PerfScope:
     # ------------------------------------------------------------ steps
     def step(self, weight: float = 1.0) -> Any:
         """Context manager delimiting one training step. `weight=N`
-        declares the body covers N identical steps (bench's device-side
-        scan chains): wall and phases are divided by N on record."""
+        declares the body covers N identical steps (a device-side scan
+        chain): wall and phases are divided by N on record."""
         return _StepCtx(self, weight)
 
     def _step_begin(self, implicit: bool, weight: float = 1.0) -> bool:
@@ -383,17 +382,17 @@ class PerfScope:
         """Declare the model FLOPs one step performs (feeds the
         `horovod_mfu` gauge and summary MFU). `source` is "xla" when the
         number came from XLA cost analysis (profiler/flops.py), else
-        "fallback"."""
+        "fallback" (the caller's own count)."""
         with self._lock:
             self._model_flops = float(flops_per_step) \
                 if flops_per_step else None
             self._flops_source = source if self._model_flops else "none"
 
     def reset(self) -> None:
-        """Drop accumulated stats (bench reuses the process-global scope
-        across sections). Also abandons the calling thread's in-flight
+        """Drop accumulated stats (an elastic reset reuses the
+        process-global scope). Also abandons the calling thread's in-flight
         step, so a stale implicit step left open by earlier optimizer
-        calls cannot pollute the next section's first sample."""
+        calls cannot pollute the next first sample."""
         self._tls.step = None
         from horovod_tpu.observability import tracing
         tracing.step_end()
@@ -487,8 +486,7 @@ class PerfScope:
         return out
 
     def step_profile(self, name: str, **extra: Any) -> Dict[str, Any]:
-        """The structured ``StepProfile`` record bench emits per section
-        and ``scripts/perf_gate.py`` gates on."""
+        """The summary as one structured ``StepProfile`` record."""
         prof = {"name": name, "perfscope": SUMMARY_VERSION}
         prof.update(self.summary())
         prof.update(extra)

@@ -4,7 +4,7 @@ One executable per (bucket, item shape, dtype): the batch assembler
 pads every batch to a bucket (serve/batching.py), so after `warmup()`
 the serving hot path NEVER traces or compiles — each request shape hits
 a `lower().compile()` executable built ahead of time (the same AOT
-discipline bench.py uses for its cost-analysis compiles).
+discipline `benchmark.aot_check` uses for its compiles).
 
 Observability hooks:
 

@@ -19,8 +19,7 @@ Four contracts on the 8-device CPU mesh:
   the perfscope summary.
 * **Gates** — the runtime `lm_runtime` step lints HVD2xx+HVD3xx clean
   (slow; also `make shard-lint`/`gspmd-smoke`), its forced-replicated
-  twin trips HVD301, and scripts/perf_gate.py structurally requires
-  the mesh/scaling/comms stamps on sharded bench sections.
+  twin trips HVD301.
 """
 
 import jax
@@ -461,95 +460,6 @@ def test_sharded_reduction_stamps_comms_axes_in_perfscope():
     assert "comms_axes" in s and s["comms_axes"].get("dp", 0) > 0
     ps.reset()
     assert "comms_axes" not in (ps.summary() or {})
-
-
-# ---------------------------------------------------- gate plumbing
-
-def test_perf_gate_sharded_section_checks():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(os.path.dirname(__file__), "..",
-                                  "scripts", "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-
-    good = {
-        "mesh": {"spec": "dp=2,tp=4", "devices": 8,
-                 "shape": {"dp": 2, "tp": 4}},
-        "scaling": {"efficiency_vs_dp": 1.05,
-                    "dp_tokens_per_sec": 8000.0,
-                    "hybrid_tokens_per_sec": 8400.0},
-        "comms_by_axis": {"dp": {"bytes_per_step": 8 << 20},
-                          "tp": {"bytes_per_step": 25 << 20}},
-        "comms_model": {
-            "link_gbps": {"ici": 90.0, "dcn": 12.5},
-            "per_axis": {"dp": {"bytes_per_step": 8 << 20,
-                                "wire_bytes_per_step": 14 << 20,
-                                "predicted_s": 1.6e-4, "ops": 3,
-                                "tier": "ici"}},
-            "predicted_vs_measured": 1.37,
-        },
-        "numerics": {
-            "accum_dtypes": ["f32"],
-            "grad_scale": [{"opcode": "all_reduce", "dtype": "f32",
-                            "group_size": 2, "bytes": 8 << 20,
-                            "divisor": 2.0, "multiplier": 1.0,
-                            "axis": "dp"}],
-            "findings": 0, "clean": True,
-        },
-    }
-    assert pg._check_sharded_section("gspmd_hybrid", good) == []
-    for missing in ("mesh", "scaling", "comms_by_axis", "comms_model",
-                    "numerics"):
-        bad = {k: v for k, v in good.items() if k != missing}
-        errs = pg._check_sharded_section("gspmd_hybrid", bad)
-        assert errs and missing in " ".join(errs)
-    bad = dict(good)
-    bad["scaling"] = {"efficiency_vs_dp": 0}
-    assert pg._check_sharded_section("gspmd_hybrid", bad)
-    # ISSUE 18: the analytic stamp is STRUCTURALLY required, and its
-    # predicted-vs-measured ratio is gated to [0.5, 2.0]
-    bad = dict(good)
-    bad["comms_model"] = {"per_axis": {}, "predicted_vs_measured": 1.0}
-    errs = pg._check_sharded_section("gspmd_hybrid", bad)
-    assert any("per_axis missing/empty" in e for e in errs)
-    bad = dict(good)
-    bad["comms_model"] = dict(good["comms_model"],
-                              predicted_vs_measured=3.1)
-    errs = pg._check_sharded_section("gspmd_hybrid", bad)
-    assert any("outside [0.5, 2.0]" in e for e in errs)
-    bad = dict(good)
-    bad["comms_model"] = {
-        "per_axis": {"dp": {"bytes_per_step": 1}},
-        "predicted_vs_measured": 1.0}
-    errs = pg._check_sharded_section("gspmd_hybrid", bad)
-    assert any("wire_bytes_per_step" in e for e in errs)
-    # ISSUE 19: the hvdnum stamp is STRUCTURALLY required too — accum
-    # dtypes, a non-empty gradient-scale table, and the finding count
-    bad = dict(good)
-    bad["numerics"] = {"accum_dtypes": [], "grad_scale": [],
-                       "findings": 0}
-    errs = pg._check_sharded_section("gspmd_hybrid", bad)
-    assert any("accum_dtypes missing/empty" in e for e in errs)
-    assert any("grad_scale missing/empty" in e for e in errs)
-    bad = dict(good)
-    bad["numerics"] = {"accum_dtypes": ["f32"],
-                       "grad_scale": [{"opcode": "all_reduce"}],
-                       "findings": "n/a"}
-    errs = pg._check_sharded_section("gspmd_hybrid", bad)
-    assert any("group_size" in e for e in errs)
-    assert any("numerics.findings" in e for e in errs)
-    # check_bench routes gspmd sections through the sharded checks
-    doc = {"extra": {"gspmd_hybrid": {k: v for k, v in good.items()
-                                      if k != "scaling"}}}
-    errs = pg.check_bench(doc)
-    assert any("scaling" in e for e in errs)
-    # ... and a MISSING (crashed/dropped) sharded section fails too —
-    # absence must not skip the structural contract
-    errs = pg.check_bench({"extra": {"gspmd_hybrid": None}})
-    assert any("missing" in e and "gspmd_hybrid" in e for e in errs)
 
 
 def test_dryrun_timed_steps_schema():

@@ -342,58 +342,3 @@ def test_hlo_step_resnet_block_unpadded_trips_hvd204(monkeypatch):
     assert hvd204, [f.render() for f in findings]
     assert any("= 64 " in f.message and "50.0%" in f.message
                for f in hvd204), [f.render() for f in hvd204]
-
-
-# ----------------------------------------------------- bench stamping
-
-def test_bench_scan_timed_stamps_hlo_lint(monkeypatch):
-    """bench._scan_timed lints the section's already-lowered program
-    and the stamp lands in the section JSON via _perf_stamp."""
-    import jax.numpy as jnp
-    import sys
-    sys.path.insert(0, os.path.dirname(HERE))
-    import bench
-
-    a = jnp.eye(128, dtype=jnp.float32)  # lane-aligned: stamp is clean
-
-    def body(c):
-        m, acc = c
-        return (m, jnp.tanh(acc @ m))
-
-    hlo_info, flops_info = {}, {}
-    bench._scan_timed(body, (a, a * 2.0), chain=2, reps=2, warmup=1,
-                      flops_out=flops_info, hlo_out=hlo_info)
-    assert hlo_info.get("clean") is True and hlo_info["count"] == 0
-    r = bench._perf_stamp({}, "sec", {}, {}, None, hlo_info=hlo_info)
-    assert r["hlo_lint"]["clean"] is True
-
-
-def test_bench_hlo_stamp_disabled(monkeypatch):
-    import sys
-    sys.path.insert(0, os.path.dirname(HERE))
-    import bench
-
-    monkeypatch.setenv("HOROVOD_HLO_LINT", "0")
-
-    class _Lowered:
-        def as_text(self):
-            raise AssertionError("must not lower text when disabled")
-
-    assert bench._hlo_lint_lowered(_Lowered()) == {}
-    # the gate is checked BEFORE lowering: disabled + no-XLA-flops must
-    # not trace the program at all
-    assert bench._hlo_lint_enabled() is False
-    monkeypatch.setenv("HOROVOD_PERFSCOPE_XLA_FLOPS", "0")
-    import jax.numpy as jnp
-
-    calls = []
-
-    def body(c):
-        calls.append(1)
-        return c
-
-    bench._scan_timed(body, (jnp.zeros(()),), chain=1, reps=2, warmup=1,
-                      flops_out={}, hlo_out={})
-    # body traced exactly once (the jit itself), not a second time for
-    # a discarded lowering
-    assert len(calls) == 1

@@ -374,8 +374,7 @@ def test_peak_memory_stablehlo_returns_none():
 
 def test_peak_memory_real_compiled_module_vs_xla():
     """The estimate on a real compiled module must land within 1.5x of
-    XLA's own buffer-assignment numbers (the acceptance band the bench
-    stamp is judged against on hardware)."""
+    XLA's own buffer-assignment numbers."""
     import jax
     import jax.numpy as jnp
 
@@ -697,107 +696,6 @@ def test_lm_sharded_uses_parallel_mesh(monkeypatch):
     monkeypatch.setattr(mesh_mod, "build_mesh", boom)
     with pytest.raises(RuntimeError, match="mesh_used"):
         shard.lower_sharded_step_texts(replicated=False)
-
-
-# ------------------------------------------------- bench memory stamp
-
-def test_bench_scan_timed_memory_stamp():
-    """bench._scan_timed stamps the static per-device peak-HBM estimate
-    from the same compile the cost analysis rides, and _perf_stamp
-    lands it in the section JSON as `memory`."""
-    import sys
-    sys.path.insert(0, os.path.dirname(HERE))
-    import bench
-    import jax.numpy as jnp
-
-    a = jnp.eye(128, dtype=jnp.float32)
-
-    def body(c):
-        m, acc = c
-        return (m, jnp.tanh(acc @ m))
-
-    flops_info, mem_info = {}, {}
-    bench._scan_timed(body, (a, a * 2.0), chain=2, reps=2, warmup=1,
-                      flops_out=flops_info, mem_out=mem_info)
-    assert mem_info.get("static_peak_device_bytes", 0) > 0
-    assert "model" in mem_info
-    r = bench._perf_stamp({}, "sec", flops_info, {}, None,
-                          mem_info=mem_info)
-    assert r["memory"]["static_peak_device_bytes"] > 0
-
-
-def test_bench_memory_stamp_budget(monkeypatch):
-    """With a chip budget known (HOROVOD_BENCH_HBM_GB), the stamp
-    reports it and the within_budget verdict."""
-    import sys
-    sys.path.insert(0, os.path.dirname(HERE))
-    import bench
-    import jax
-
-    monkeypatch.setenv("HOROVOD_BENCH_HBM_GB", "16")
-
-    class _Compiled:
-        def as_text(self):
-            return _mini_hlo(donated=True)
-
-    stamp = bench._memory_stamp(_Compiled())
-    assert stamp["static_peak_device_bytes"] == 8 * _MB
-    assert stamp["hbm_budget_bytes"] == 16 * (1 << 30)
-    assert stamp["within_budget"] is True
-
-
-def test_bench_memory_stamp_measured_ratio(monkeypatch):
-    """On a device that exposes memory_stats (TPU), the stamp carries
-    the measured peak and the static/measured ratio — the acceptance
-    comparison the real bench rounds publish."""
-    import sys
-    sys.path.insert(0, os.path.dirname(HERE))
-    import bench
-
-    class _Dev:
-        def memory_stats(self):
-            return {"bytes_in_use": 5 * _MB,
-                    "peak_bytes_in_use": 10 * _MB}
-
-    monkeypatch.setattr(bench.jax, "local_devices", lambda: [_Dev()])
-
-    class _Compiled:
-        def as_text(self):
-            return _mini_hlo(donated=True)  # static peak: 8 MB
-
-    stamp = bench._memory_stamp(_Compiled())
-    assert stamp["measured_peak_device_bytes"] == 10 * _MB
-    assert stamp["static_vs_measured_ratio"] == 0.8
-
-
-def test_perf_gate_memory_checks():
-    import importlib
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(HERE), "scripts"))
-    pg = importlib.import_module("perf_gate")
-
-    # present + under budget: clean
-    ok = {"perfscope": {"mfu_source": "xla"},
-          "memory": {"static_peak_device_bytes": 8 * _MB,
-                     "hbm_budget_bytes": 16 * (1 << 30)}}
-    assert pg._check_memory("s", ok) == []
-    # over budget: fails
-    over = {"perfscope": {"mfu_source": "xla"},
-            "memory": {"static_peak_device_bytes": 32 * (1 << 30),
-                       "hbm_budget_bytes": 16 * (1 << 30)}}
-    errs = pg._check_memory("s", over)
-    assert errs and "exceeds the chip budget" in errs[0]
-    # stamp missing despite a compiled program: fails structurally
-    missing = {"perfscope": {"mfu_source": "xla"}}
-    errs = pg._check_memory("s", missing)
-    assert errs and "memory stamp missing" in errs[0]
-    # stamp legitimately absent when the compile never happened
-    assert pg._check_memory(
-        "s", {"perfscope": {"mfu_source": "fallback"}}) == []
-    # garbage stamp
-    errs = pg._check_memory(
-        "s", {"memory": {"static_peak_device_bytes": 0}})
-    assert errs and "no positive" in errs[0]
 
 
 # ---------------------------------------------- parallel/mesh hardening

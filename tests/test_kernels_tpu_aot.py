@@ -23,7 +23,7 @@ import pytest
 
 from horovod_tpu.ops.flash_attention import flash_attention
 
-FLAGSHIP = (12, 16, 1024, 128)   # bench.py / chip_smoke.py flagship LM
+FLAGSHIP = (12, 16, 1024, 128)   # chip_smoke.py flagship LM
 LM_CELLS = (4, 16, 2048, 128)    # lm-1chip, lm-dp4 per chip
 OLMOE_CELL = (2, 16, 4096, 128)  # olmoe-1chip
 LONG = (1, 16, 8192, 128)

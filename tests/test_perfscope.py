@@ -5,14 +5,13 @@ switching timer, re-attribution with the sum-to-wall invariant, weight
 scaling, implicit optimizer-driven steps); further tests cover the NOOP
 shell + its overhead, the rolling summary/percentiles, MFU accounting,
 the KV-summary plumbing, the launcher-side persistence, the doctor's
-perf straggler attribution, the `scripts/perf_gate.py` checks, and the
-flops.py constant dedupe. The 2-process slow-input e2e lives in
+perf straggler attribution, and profiler/flops.py's peak and XLA
+cost-analysis readers. The 2-process slow-input e2e lives in
 tests/test_perfscope_e2e.py (`make doctor-smoke`).
 """
 
 import json
 import os
-import sys
 import time
 
 import pytest
@@ -20,12 +19,6 @@ import pytest
 from horovod_tpu.observability import doctor
 from horovod_tpu.profiler import flops as F
 from horovod_tpu.profiler import perfscope
-
-HERE = os.path.dirname(__file__)
-REPO = os.path.dirname(HERE)
-sys.path.insert(0, os.path.join(REPO, "scripts"))
-
-import perf_gate  # noqa: E402  (scripts/perf_gate.py)
 
 
 class FakeClock:
@@ -467,273 +460,7 @@ def test_doctor_load_perf_dir_and_main_json(fresh, tmp_path, capsys):
         "input_wait"
 
 
-# ---------------------------------------------------------- perf_gate
-
-def _gate_profile(**over):
-    prof = {
-        "name": "sec", "perfscope": 1, "steps": 8, "window_steps": 8,
-        "wall": {"mean_s": 0.01, "p50_s": 0.01, "p95_s": 0.012,
-                 "max_s": 0.02},
-        "phases_s": {"dispatch": 0.008, "device_compute": 0.002},
-        "coverage": 1.0, "mfu_source": "xla",
-    }
-    prof.update(over)
-    return prof
-
-
-def test_perf_gate_structure_pass_and_failures(fresh):
-    base = {"sections": {"sec": {
-        "require_phases": ["dispatch", "device_compute"],
-        "mfu_source": ["xla", "fallback"],
-        "wall_mean_s": 0.01, "tolerance": 1.0}}}
-    cur = {"sections": {"sec": _gate_profile()}}
-    assert perf_gate.compare(cur, base, numeric=False) == []
-    # missing section
-    assert perf_gate.compare({"sections": {}}, base, numeric=False)
-    # broken coverage
-    bad = {"sections": {"sec": _gate_profile(coverage=0.4)}}
-    errs = perf_gate.compare(bad, base, numeric=False)
-    assert any("coverage" in e for e in errs)
-    # missing required phase
-    bad = {"sections": {"sec": _gate_profile(
-        phases_s={"dispatch": 0.01})}}
-    assert any("device_compute" in e
-               for e in perf_gate.compare(bad, base, numeric=False))
-    # bad mfu_source
-    bad = {"sections": {"sec": _gate_profile(mfu_source="vibes")}}
-    assert any("mfu_source" in e
-               for e in perf_gate.compare(bad, base, numeric=False))
-
-
-def test_perf_gate_numeric_tolerance(fresh):
-    base = {"sections": {"sec": {"wall_mean_s": 0.01, "tolerance": 0.5}}}
-    ok = {"sections": {"sec": _gate_profile(
-        wall={"mean_s": 0.012, "p50_s": 0.012, "p95_s": 0.012,
-              "max_s": 0.012})}}
-    assert perf_gate.compare(ok, base, numeric=True) == []
-    slow = {"sections": {"sec": _gate_profile(
-        wall={"mean_s": 0.10, "p50_s": 0.1, "p95_s": 0.1,
-              "max_s": 0.1})}}
-    errs = perf_gate.compare(slow, base, numeric=True)
-    assert any("outside" in e for e in errs)
-    # numeric off: the same regression passes structure-only
-    assert perf_gate.compare(slow, base, numeric=False) == []
-
-
-def test_perf_gate_baseline_from_roundtrip(fresh):
-    cur = {"platform": "cpu", "sections": {"sec": _gate_profile()}}
-    base = perf_gate.baseline_from(cur)
-    assert perf_gate.compare(cur, base, numeric=True) == []
-    assert base["sections"]["sec"]["require_phases"] == \
-        ["device_compute", "dispatch"]
-
-
-def test_perf_gate_checked_in_baseline_is_valid(fresh):
-    """The committed baseline must parse and demand the committed
-    emitter's sections (guards against baseline/emitter drift)."""
-    path = os.path.join(REPO, "scripts", "perf_baseline.json")
-    base = json.load(open(path))
-    assert base["perf_gate"] == 1
-    assert set(base["sections"]) == {"eager_mlp", "scan_matmul"}
-    for spec in base["sections"].values():
-        assert spec["require_phases"]
-
-
-def _conv_stamps(mode="nhwc_padded"):
-    """The conv-fast-path stamps bench sections carry (docs/perf.md)."""
-    return {"layout": {"mode": mode},
-            "input_pipeline": {"mode": "device_double_buffered",
-                               "depth": 2},
-            **_memory_stamp()}
-
-
-def _memory_stamp(static=64 << 20):
-    """The per-section static peak-HBM stamp (ISSUE 13): required
-    whenever the section's XLA cost analysis ran (mfu_source=xla)."""
-    return {"memory": {"static_peak_device_bytes": static}}
-
-
-def _ckpt_section(overhead=0.01):
-    """A minimal valid checkpointing section (ISSUE 15): check_bench
-    requires its PRESENCE with the overhead/phase-split stamps."""
-    return {"checkpointing": {
-        "overhead_fraction": overhead, "snapshot_ms": 1.0,
-        "persist_ms": 5.0, "plain_step_ms": 10.0,
-        "ckpt_step_ms": 10.1, "bytes": 2 << 20,
-        "generations_committed": 6, "save_every": 4,
-        "skipped_saves": 0,
-    }}
-
-
-def _serving_section():
-    """A minimal valid serving section (ISSUE 20): check_bench
-    requires its PRESENCE with the hvdtrace `trace` stamp carrying the
-    slowest request's queue/dispatch/device split."""
-    return {"serving": {
-        "requests": 100, "requests_per_sec": 50.0,
-        "trace": {"version": 1, "sampled": 100, "finished": 100,
-                  "requests_joined": 8, "complete": 8,
-                  "slowest": {"trace_id": "ab" * 8, "rid": 7,
-                              "total_ms": 12.0, "queue_ms": 3.0,
-                              "dispatch_ms": 8.5, "device_ms": 4.0}},
-    }}
-
-
-def _gspmd_section():
-    """A minimal valid sharded section (ISSUE 14) plus the ISSUE 15
-    checkpointing and ISSUE 20 serving sections: check_bench requires
-    the PRESENCE of all three with their stamps, so the synthetic docs
-    below carry them to isolate what each test actually checks."""
-    return {"gspmd_hybrid": {
-        "mesh": {"spec": "dp=2,tp=4", "devices": 8,
-                 "shape": {"dp": 2, "tp": 4}},
-        "scaling": {"efficiency_vs_dp": 1.0,
-                    "dp_tokens_per_sec": 1.0,
-                    "hybrid_tokens_per_sec": 1.0},
-        "comms_by_axis": {"dp": {"bytes_per_step": 1}},
-        "comms_model": {
-            "link_gbps": {"ici": 90.0, "dcn": 12.5},
-            "per_axis": {"dp": {"bytes_per_step": 1,
-                                "wire_bytes_per_step": 1,
-                                "predicted_s": 1e-9, "ops": 1,
-                                "tier": "ici"}},
-            "predicted_vs_measured": 1.0,
-        },
-        "numerics": {
-            "accum_dtypes": ["f32"],
-            "grad_scale": [{"opcode": "all_reduce", "dtype": "f32",
-                            "group_size": 2, "bytes": 1,
-                            "divisor": None, "multiplier": 2.0,
-                            "axis": "dp"}],
-            "findings": 0, "clean": True,
-        },
-    }, **_ckpt_section(), **_serving_section()}
-
-
-def test_perf_gate_bench_mode(fresh):
-    doc = {"extra": {"resnet50": {"perfscope": _gate_profile(),
-                                  **_conv_stamps()},
-                     "vgg16": None, "autotune": {"frozen": True},
-                     **_gspmd_section()}}
-    assert perf_gate.check_bench(doc) == []
-    assert perf_gate.check_bench({"extra": {}})  # nothing stamped
-
-
-def test_perf_gate_conv_section_requires_stamps(fresh):
-    """ISSUE 12 satellite: a conv section without the layout /
-    input_pipeline stamps fails the gate STRUCTURALLY."""
-    doc = {"extra": {"resnet50": {"perfscope": _gate_profile()}}}
-    errs = perf_gate.check_bench(doc)
-    assert any("layout stamp missing" in e for e in errs)
-    assert any("input_pipeline" in e for e in errs)
-    # ...and without a memory stamp (ISSUE 13): also structural
-    assert any("memory stamp missing" in e for e in errs)
-    # non-conv sections carry the memory obligation but no conv stamps
-    doc = {"extra": {"transformer_lm": {"perfscope": _gate_profile(),
-                                        **_memory_stamp()},
-                     **_gspmd_section()}}
-    assert perf_gate.check_bench(doc) == []
-
-
-def test_perf_gate_conv_section_unpadded_resnet_fails(fresh):
-    """A ResNet section measured under the as-declared (unpadded)
-    layout is a structural regression; inception may legitimately run
-    as-declared (no conv_stack declaration yet)."""
-    doc = {"extra": {"resnet50": {"perfscope": _gate_profile(),
-                                  **_conv_stamps("as_declared")}}}
-    errs = perf_gate.check_bench(doc)
-    assert any("nhwc_padded" in e for e in errs)
-    doc = {"extra": {"inception_v3": {"perfscope": _gate_profile(),
-                                      **_conv_stamps("as_declared")},
-                     **_gspmd_section()}}
-    assert perf_gate.check_bench(doc) == []
-
-
-def test_perf_gate_conv_section_input_wait_bar(fresh):
-    """Measured input_wait above 5% of the step wall fails — the
-    device-resident pipeline acceptance (docs/perf.md)."""
-    prof = _gate_profile()
-    prof["phase_fractions"] = {"input_wait": 0.2}
-    doc = {"extra": {"resnet50": {"perfscope": prof, **_conv_stamps()},
-                     **_gspmd_section()}}
-    errs = perf_gate.check_bench(doc)
-    assert any("starving" in e for e in errs)
-    prof["phase_fractions"] = {"input_wait": 0.01}
-    assert perf_gate.check_bench(doc) == []
-
-
-def test_perf_gate_ckpt_section_overhead_and_stamps(fresh):
-    """ISSUE 15 satellite: the checkpointing section is structurally
-    required, its stamps must be present, and measured overhead above
-    the 5% budget fails the gate on ANY host."""
-    base = {"transformer_lm": {"perfscope": _gate_profile(),
-                               **_memory_stamp()}}
-    doc = {"extra": {**base, **_gspmd_section()}}
-    assert perf_gate.check_bench(doc) == []
-    # overhead above budget: numeric fail everywhere
-    doc["extra"]["checkpointing"]["overhead_fraction"] = 0.09
-    errs = perf_gate.check_bench(doc)
-    assert any("overhead" in e and "5%" in e for e in errs)
-    # a missing phase-split stamp: structural fail
-    doc["extra"].update(_ckpt_section())
-    del doc["extra"]["checkpointing"]["snapshot_ms"]
-    errs = perf_gate.check_bench(doc)
-    assert any("snapshot_ms" in e for e in errs)
-    # zero commits: the save path never reached a marker
-    doc["extra"].update(_ckpt_section())
-    doc["extra"]["checkpointing"]["generations_committed"] = 0
-    assert any("commit" in e for e in perf_gate.check_bench(doc))
-    # absent section: fail, not skip
-    doc["extra"].pop("checkpointing")
-    errs = perf_gate.check_bench(doc)
-    assert any("checkpointing" in e and "missing" in e for e in errs)
-
-
-def test_perf_gate_conv_section_mfu_presence(fresh):
-    """With a known chip peak the StepProfile must carry an actual
-    `mfu` number (the conv-MFU acceptance metric); without a peak
-    (CPU hosts) its absence is fine."""
-    prof = _gate_profile()
-    prof["peak_flops_per_chip"] = 197e12
-    doc = {"extra": {"vgg16": {"perfscope": prof, **_conv_stamps()},
-                     **_gspmd_section()}}
-    errs = perf_gate.check_bench(doc)
-    assert any("mfu missing" in e for e in errs)
-    prof["mfu"] = 0.41
-    assert perf_gate.check_bench(doc) == []
-
-
 # ------------------------------------------------------------- flops
-
-def test_flops_fallbacks_match_legacy_constants(fresh):
-    """The dedupe satellite: the constants bench/scripts used inline
-    must survive the move byte-for-byte (MAC convention)."""
-    assert F.resnet_train_flops_per_image(50, "macs") == \
-        pytest.approx(12.3e9)
-    assert F.resnet_train_flops_per_image(101, "macs") == \
-        pytest.approx(23.4e9)
-    assert F.inception_v3_train_flops_per_image("macs") == \
-        pytest.approx(17.2e9, rel=1e-3)
-    assert F.vgg16_train_flops_per_image("macs") == \
-        pytest.approx(46.5e9, rel=2e-3)
-    assert F.PEAK_TFLOPS["TPU v5 lite"] == 197.0
-    # the mul+add convention is exactly 2x (XLA comparability)
-    assert F.resnet_train_flops_per_image(50, "flops") == \
-        pytest.approx(2 * 12.3e9)
-    with pytest.raises(ValueError):
-        F.resnet_train_flops_per_image(50, "bogus")
-
-
-def test_flops_transformer_formula_matches_legacy_inline(fresh):
-    """The exact expression bench.py used to inline for the TPU LM
-    config (L12 D2048 F8192 V32768 S1024)."""
-    D, Fd, L, V, S = 2048, 8192, 12, 32768, 1024
-    n_matmul = L * (4 * D * D + 2 * D * Fd)
-    legacy = 6 * n_matmul + 6 * L * S * D + 6 * D * V
-    assert F.transformer_train_flops_per_token(D, Fd, L, V, S) == legacy
-    assert F.transformer_matmul_params(D, Fd, L, V) == \
-        n_matmul + 2 * D * V
-
 
 def test_flops_peak_env_override(fresh, monkeypatch):
     monkeypatch.setenv("HOROVOD_BENCH_PEAK_TFLOPS", "123")
@@ -751,12 +478,6 @@ def test_flops_peak_env_override(fresh, monkeypatch):
     monkeypatch.setenv("HOROVOD_BENCH_PEAK_TFLOPS", "157,0")
     with pytest.raises(ValueError):
         F.peak_flops_per_chip("TPU v5 lite")
-
-
-def test_flops_pick(fresh):
-    assert F.pick_flops(10.0, 5.0) == (10.0, "xla")
-    assert F.pick_flops(None, 5.0) == (5.0, "fallback")
-    assert F.pick_flops(None, None) == (None, "none")
 
 
 def test_flops_xla_cost_on_cpu(fresh):
@@ -879,42 +600,3 @@ def test_flops_cost_analysis_failure_paths(fresh):
     assert F.compiled_cost_flops(
         _FakeCompiled(RuntimeError("no cost model"))) is None
     assert F.compiled_cost_flops(_FakeCompiled("not a dict")) is None
-
-
-# ------------------------- perf_gate --update refusal (ISSUE 8
-# satellite: a broken run must not silently become the new baseline)
-
-def test_perf_gate_update_errors_refuse_broken_runs(fresh):
-    good = {"sections": {"sec": _gate_profile()}}
-    assert perf_gate.update_errors(good) == []
-    low_cov = {"sections": {"sec": _gate_profile(coverage=0.5)}}
-    assert any("coverage" in e
-               for e in perf_gate.update_errors(low_cov))
-    fb = {"sections": {"sec": _gate_profile(mfu_source="fallback")}}
-    assert any("fallback" in e for e in perf_gate.update_errors(fb))
-    assert perf_gate.update_errors({"sections": {}})  # nothing profiled
-
-
-def test_perf_gate_update_cli_refuses_and_preserves_baseline(
-        fresh, tmp_path):
-    cur = tmp_path / "cur.json"
-    cur.write_text(json.dumps(
-        {"platform": "cpu",
-         "sections": {"sec": _gate_profile(mfu_source="fallback")}}))
-    base = tmp_path / "base.json"
-    base.write_text("{\"sentinel\": true}")
-    rc = perf_gate.main([str(cur), "--baseline", str(base), "--update"])
-    assert rc == 1
-    # the refusal must not have touched the existing baseline
-    assert json.loads(base.read_text()) == {"sentinel": True}
-
-
-def test_perf_gate_update_cli_accepts_healthy_run(fresh, tmp_path):
-    cur = tmp_path / "cur.json"
-    cur.write_text(json.dumps(
-        {"platform": "cpu", "sections": {"sec": _gate_profile()}}))
-    base = tmp_path / "base.json"
-    rc = perf_gate.main([str(cur), "--baseline", str(base), "--update"])
-    assert rc == 0
-    doc = json.loads(base.read_text())
-    assert "sec" in doc["sections"]

@@ -6,9 +6,8 @@ error capture), head sampling and the tail-based always-keep rules
 its eviction order, trace-context propagation across the data-service
 frame boundary, the serving Request lifecycle stamps + queue-wait
 histogram (satellite 1), the KV-tail push/persist plumbing, and the
-doctor's cross-process join — the [traces] section, the Perfetto
-flow-event export (satellite 2), and the perf_gate `trace` stamp
-contract. The live 2-process serving paths are e2e-pinned in
+doctor's cross-process join — the [traces] section and the Perfetto
+flow-event export (satellite 2). The live 2-process serving paths are e2e-pinned in
 tests/test_serve_e2e.py (`make trace-smoke`).
 """
 
@@ -22,12 +21,6 @@ import numpy as np
 import pytest
 
 from horovod_tpu.observability import doctor, tracing
-
-HERE = os.path.dirname(__file__)
-REPO = os.path.dirname(HERE)
-sys.path.insert(0, os.path.join(REPO, "scripts"))
-
-import perf_gate  # noqa: E402  (scripts/perf_gate.py)
 
 
 @pytest.fixture()
@@ -638,46 +631,3 @@ def test_export_trace_flows_fall_back_to_batch_slice(fresh, tmp_path):
     finishes = [e for e in evs if e.get("ph") == "f"]
     assert {e["id"] for e in finishes} == {"B1:T1", "B1:T2"}
     assert all(e["pid"] == 0 for e in finishes)  # same-process fallback
-
-
-# ------------------------------------- perf_gate `trace` stamp contract
-
-def _serving_section_ok():
-    return {"requests": 64, "requests_per_sec": 50.0,
-            "trace": {"version": 1, "sampled": 64, "finished": 64,
-                      "requests_joined": 8, "complete": 8,
-                      "slowest": {"trace_id": "ab" * 8, "rid": 7,
-                                  "total_ms": 12.0, "queue_ms": 3.0,
-                                  "dispatch_ms": 8.5,
-                                  "device_ms": 4.0}}}
-
-
-def test_perf_gate_accepts_complete_trace_stamp(fresh):
-    assert perf_gate._check_serving_section(
-        "serving", _serving_section_ok()) == []
-
-
-def test_perf_gate_rejects_missing_or_partial_trace_stamp(fresh):
-    sec = _serving_section_ok()
-    del sec["trace"]
-    errs = perf_gate._check_serving_section("serving", sec)
-    assert any("trace stamp missing" in e for e in errs)
-    sec = _serving_section_ok()
-    del sec["trace"]["slowest"]["device_ms"]
-    sec["trace"]["sampled"] = 0
-    errs = perf_gate._check_serving_section("serving", sec)
-    assert any("trace.slowest.device_ms" in e for e in errs)
-    assert any("trace.sampled" in e for e in errs)
-    sec = _serving_section_ok()
-    del sec["trace"]["slowest"]
-    errs = perf_gate._check_serving_section("serving", sec)
-    assert any("trace.slowest missing" in e for e in errs)
-
-
-def test_perf_gate_requires_serving_section_presence(fresh):
-    errs = perf_gate.check_bench({"extra": {}})
-    assert any("serving bench section missing" in e for e in errs)
-    errs = perf_gate.check_bench(
-        {"extra": {"serving": _serving_section_ok()}})
-    assert not any("serving" in e and "missing" in e.lower()
-                   for e in errs if "section" in e)
