@@ -16,9 +16,25 @@ On one rank of the expert axis (`ep` = 1) routing is dropless and
 static-shaped: the T*k (token, expert) pairs are sorted by expert, their rows
 gathered once, the experts applied as grouped matmuls (`lax.ragged_dot`, which
 the TPU compiler lowers to its own Mosaic kernel) over the sorted rows with
-the E group sizes, and the results gathered back and summed with the weights.
-No token is dropped however uneven the load, and `capacity_factor` means
-nothing.
+the E group sizes, and the results gathered back and summed over k. No token
+is dropped however uneven the load, and `capacity_factor` means nothing.
+
+Where the weights multiply. The down product is linear, so `sum_c p_c *
+(h_c W_down) = sum_c (p_c h_c) W_down`. On one rank the weights, in sorted
+order, multiply the hidden rows `h_c` in float32 before their one rounding.
+This is for the backward pass: a product after the down product would make
+the gathered result a residual (the weights' gradient would be its inner
+product with the cotangent), and under `jax.checkpoint` every layer's
+backward would run the down product and a (T*k, D) gather again for a (T, k)
+gradient. Weighted in front, nothing after the down product is kept or
+recomputed, and the weights' gradient is a row sum over the hidden rows the
+gate's backward needs anyway. The combine is then a plain gather and sum, the
+transpose of the dispatch (`_sum_rows`, `_take_rows`): each one's backward
+pass is the other, and the cotangent is gathered from the (T, D) array, never
+from a (T*k, D) copy of it. Across ranks the weights still multiply the
+returned rows: they come back in slot layout, where dropped pairs are
+masked, and weighting in front would send the weights through an exchange of
+their own.
 
 Across ranks (`ep` > 1) the experts are sharded one group per rank and the
 rows are exchanged by `lax.all_to_all` (compiled onto ICI), which needs a
@@ -69,31 +85,81 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int):
 def _take_rows(x, rows, back, k):
     """`x[rows]`, where `back` says which k rows of the result each row of
     `x` went to (row t to rows back[t*k:(t+1)*k]): the backward pass is then
-    a gather and a sum over k, not a scatter-add."""
+    a gather and a sum over k (`_sum_rows`), not a scatter-add."""
     return x[rows]
 
 
 def _take_rows_fwd(x, rows, back, k):
-    return x[rows], back
+    return _take_rows(x, rows, back, k), (rows, back)
 
 
-def _take_rows_bwd(k, back, g):
-    return g[back].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+def _take_rows_bwd(k, indices, g):
+    rows, back = indices
+    return _sum_rows(g, back, rows, k), None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _experts(rows, group_sizes, w_up, w_down, w_gate):
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_rows(x, back, rows, k):
+    """The transpose of `_take_rows`: row t of the result is the sum, in
+    float32, of the k rows x[back[t*k:(t+1)*k]], and row i of `x` went into
+    row rows[i] alone: the backward pass is `g[rows]`, a gather from the
+    small array."""
+    return jnp.sum(x[back].reshape(-1, k, x.shape[-1]), axis=1,
+                   dtype=jnp.promote_types(x.dtype, jnp.float32)
+                   ).astype(x.dtype)
+
+
+def _sum_rows_fwd(x, back, rows, k):
+    return _sum_rows(x, back, rows, k), (rows, back)
+
+
+def _sum_rows_bwd(k, indices, g):
+    rows, back = indices
+    return _take_rows(g, rows, back, k), None, None
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute(v, perm, inverse):
+    """`v[perm]` for a vector `v` and a permutation whose inverse is
+    `inverse`, as a sort by `inverse`: on the TPU an element gather takes
+    ten times as long as the sort (0.56 against 0.06 ms for 65,536 floats,
+    PERF.md, PR 29). The backward pass is the same with the two exchanged."""
+    return lax.sort((inverse, v), num_keys=1)[1]
+
+
+def _permute_fwd(v, perm, inverse):
+    return _permute(v, perm, inverse), (perm, inverse)
+
+
+def _permute_bwd(indices, g):
+    perm, inverse = indices
+    return _permute(g, inverse, perm), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
     """The experts on rows sorted by expert: three grouped matmuls (two for
-    an ungated expert)."""
+    an ungated expert). `row_weights` (rows, 1) float32, where given, scale
+    each row's hidden activations, in float32 from the products' results to
+    the one rounding the down product's input has either way."""
     with jax.named_scope("moe.experts"):
-        up = lax.ragged_dot(rows, w_up, group_sizes)
+        wide = rows.dtype if row_weights is None else jnp.float32
+        hidden = lax.ragged_dot(rows, w_up, group_sizes).astype(wide)
         if w_gate is None:
-            hidden = jax.nn.gelu(up)
+            hidden = jax.nn.gelu(hidden)
         else:
-            hidden = jax.nn.silu(lax.ragged_dot(rows, w_gate,
-                                                group_sizes)) * up
+            gate = lax.ragged_dot(rows, w_gate, group_sizes).astype(wide)
+            hidden = jax.nn.silu(gate) * hidden
+        if row_weights is not None:
+            hidden = (hidden * row_weights).astype(rows.dtype)
         return lax.ragged_dot(hidden, w_down, group_sizes)
 
 
@@ -127,8 +193,13 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         order = jnp.argsort(experts.reshape(-1), stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
         if ranks == 1:
-            rows = _take_rows(x, order // k, inverse, k)
+            token_of = order // k
+            rows = _take_rows(x, token_of, inverse, k)
             sizes = counts
+            # the down product is linear: weighting its input rows leaves
+            # nothing after it for the backward pass to keep or recompute
+            row_weights = _permute(weights.reshape(-1), order,
+                                   inverse)[:, None]
         else:
             # (plain indexing here: this path's backward pass may scatter)
             cap = max(1, math.ceil(capacity_factor * T * k / n_experts))
@@ -146,12 +217,13 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
                                   concat_axis=1, tiled=True)
             rows = rows.reshape(n_local * ranks * cap, D)
             sizes = jnp.full((n_local,), ranks * cap, jnp.int32)
+            row_weights = None
 
-    ys = _experts(rows, sizes, w_up, w_down, w_gate)
+    ys = _experts(rows, sizes, w_up, w_down, w_gate, row_weights)
 
     with jax.named_scope("moe.combine"):
         if ranks == 1:
-            ys = _take_rows(ys, inverse, order, 1)
+            out = _sum_rows(ys, inverse, token_of, k)
         else:
             # Inverse re-shard: capacity segment s returns to rank s;
             # received expert groups stack along axis 0 in rank (= global
@@ -163,6 +235,6 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             ys = jnp.where((place < cap)[:, None],
                            back[expert_of, jnp.minimum(place, cap - 1)],
                            jnp.zeros((), ys.dtype))[inverse]
-        out = jnp.sum(ys.reshape(T, k, D).astype(jnp.float32)
-                      * weights[..., None], axis=1)
-        return out.astype(x.dtype), aux, experts
+            out = jnp.sum(ys.reshape(T, k, D).astype(jnp.float32)
+                          * weights[..., None], axis=1).astype(x.dtype)
+        return out, aux, experts

@@ -70,19 +70,28 @@ def test_pp_ep_moe_train_step_lowers_on_tpu():
     assert "all-reduce" in txt
 
 
-def test_olmoe_block_train_step_lowers_on_tpu():
-    """The OLMoE block on one rank (dropless routing), bf16 with remat: the
-    compiler turns `lax.ragged_dot` into grouped-matmul kernels of its own
-    (three forward, three under remat, six backward), and the four parts of
-    the expert layer keep their scopes in the compiled text."""
+@pytest.mark.parametrize("experts,kernels", [
+    (dict(experts_per_token=2, mlp="swiglu", norm="rmsnorm", positions="rope",
+          qk_norm=True, load_balance_coef=0.01, router_z_coef=0.001), 11),
+    (dict(experts_per_token=1), 7)], ids=["olmoe-top2-swiglu", "top1-gelu"])
+def test_olmoe_block_train_step_lowers_on_tpu(experts, kernels):
+    """An expert block on one rank (dropless routing), bf16 with remat: the
+    compiler turns `lax.ragged_dot` into grouped-matmul kernels of its own,
+    and the four parts of the expert layer keep their scopes in the compiled
+    text. A gated expert has eleven a block: three forward, two under remat
+    (gate and up, for the hidden rows the backward pass needs) and six
+    backward. The down product is not among the replayed ones: the router
+    weights multiply its input rows, so nothing after it is a residual
+    (twelve until PR 29, when they multiplied its gathered result). An
+    ungated top-1 expert has seven: two forward, one under remat, four
+    backward."""
     import re
     cfg = tfm.TransformerConfig(
         vocab=512, d_model=256, n_heads=2, d_ff=128, n_layers=2, max_seq=256,
-        num_experts=8, experts_per_token=2, load_balance_coef=0.01,
-        router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
-        mlp="swiglu", attn="local", dtype=jnp.bfloat16, remat=True)
+        num_experts=8, attn="local", dtype=jnp.bfloat16, remat=True,
+        **experts)
     txt = _compile(MeshSpec(), cfg, seq=256, batch=2)
-    assert len(re.findall(r'op_name="ragged-dot-none"', txt)) == 12
+    assert len(re.findall(r'op_name="ragged-dot-none"', txt)) == kernels
     for part in ("route", "dispatch", "experts", "combine"):
         assert f"/moe.{part}/" in txt, part
     assert "all-to-all" not in txt and "all-reduce" not in txt
