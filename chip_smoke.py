@@ -213,17 +213,64 @@ def eager_api(log: CompileLog, n: int = 1 << 20) -> None:
         f"({k} rank(s), {n} elements)")
 
 
-def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128)) -> None:
+def _kernels_alone_ms(q, k, v, repeats: int = 10) -> dict:
+    """Milliseconds an execution of each flash kernel takes alone (best of
+    three batches of `repeats`), causal, at q, k: (B, H, S, dqk) and
+    v: (B, H, S, dv). Information only: alone the kernels read ~10% over
+    their time inside a train step (PERF.md, PR 27)."""
+    from horovod_tpu.ops import flash_attention as fa
+    seq = q.shape[2]
+    block = fa._auto_block(seq)
+    flat = [x.reshape(-1, seq, x.shape[-1]) for x in (q, k, v)]
+    scale = float(q.shape[-1]) ** -0.5
+    fwd = jax.jit(lambda q, k, v: fa._fwd(q, k, v, True, scale, block,
+                                          block))
+
+    def bwd(q, k, v, o, lse, do):
+        return fa._bwd(q, k, v, o, lse, do, None, True, scale, block, block)
+
+    o, lse = fwd(*flat)
+    runs = {"forward": (fwd, flat),
+            "dq": (jax.jit(lambda *a: bwd(*a)[0]), (*flat, o, lse, o)),
+            "dk/dv": (jax.jit(lambda *a: bwd(*a)[1:]), (*flat, o, lse, o))}
+    best = {}
+    for name, (fn, args) in runs.items():
+        jax.block_until_ready(fn(*args))
+        batches = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(repeats):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            batches.append((time.perf_counter() - t0) / repeats)
+        best[name] = min(batches) * 1e3
+    return best
+
+
+def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128),
+                 wide=(2, 16, 4096, 192, 128)) -> None:
     """Flash attention forward and gradients against the plain reference,
-    at the flagship's (B, H, S, dh). On the TPU the kernel must be
-    compiled by Mosaic, not interpreted, and the program must hold it."""
+    at the flagship's (B, H, S, dh) and at `wide` (B, H, S, dqk, dv): keys
+    wider than values, the latent attention's shape (`dsv2lite-1chip`). On
+    the TPU the kernel must be compiled by Mosaic, not interpreted, and the
+    program must hold it. Then each of the three kernels alone, at both."""
     if _pallas.interpret() is on_tpu():
         raise AssertionError(
             f"Pallas interpret={_pallas.interpret()} on platform "
             f"{jax.devices()[0].platform}: the kernel would not run as "
             "compiled for this device")
+    for widths in (shape + shape[-1:], tuple(wide)):
+        _flash_against_reference(widths)
+
+
+def _flash_against_reference(widths) -> None:
+    *lead, dqk, dv = widths
+    shape = (*lead, dqk) if dqk == dv else widths
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q, k, v, w = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in ks)
+    q, k = (jax.random.normal(kk, (*lead, dqk), jnp.bfloat16)
+            for kk in ks[:2])
+    v, w = (jax.random.normal(kk, (*lead, dv), jnp.bfloat16)
+            for kk in ks[2:])
 
     # Everything big is an argument: an array a jitted function closes
     # over is baked into the program as a constant (50 MB here), and into
@@ -249,12 +296,12 @@ def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128)) -> None:
                                     q, k, v, w),
             argnums=(0, 1, 2), has_aux=True))(*f32, w)
     worst = 0.0
-    for name, got, want in (("o", o, o_ref), ("dq", grads[0], g_ref[0]),
-                            ("dk", grads[1], g_ref[1]),
-                            ("dv", grads[2], g_ref[2])):
+    for name, got, want, like in (
+            ("o", o, o_ref, v), ("dq", grads[0], g_ref[0], q),
+            ("dk", grads[1], g_ref[1], k), ("dv", grads[2], g_ref[2], v)):
         got = np.asarray(got.astype(jnp.float32))
         want = np.asarray(want)
-        if got.shape != shape or not np.all(np.isfinite(got)):
+        if got.shape != like.shape or not np.all(np.isfinite(got)):
             raise AssertionError(f"flash {name}: bad shape or non-finite")
         err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
         worst = max(worst, err)
@@ -262,12 +309,15 @@ def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128)) -> None:
             raise AssertionError(
                 f"flash {name} differs from the reference by {err:.3g} of "
                 f"its range (tolerance {BF16_RTOL:.3g})")
+    alone = _kernels_alone_ms(q, k, v)
     say(f"[flash attention] shape {shape} bf16: fwd and dq/dk/dv agree "
         f"with the reference (worst {worst:.2e} of range); "
         f"causal_tile_share {causal_tile_share(shape[2]):.4f} (score "
         "entries computed over the causal half's); "
         f"interpret={_pallas.interpret()}, {n_kernels} tpu_custom_call "
-        "in the compiled program")
+        "in the compiled program; the kernels alone (information only), "
+        "ms an execution: "
+        + ", ".join(f"{name} {ms:.3f}" for name, ms in alone.items()))
 
 
 def lm_steps(log: CompileLog, name: str, cfg, batch: int, seq: int,

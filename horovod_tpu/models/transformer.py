@@ -12,13 +12,21 @@ framework supports, in one compiled XLA program per train step:
   pp    — layer stack sharded into stages; GPipe microbatch schedule
           (parallel/pipeline.py).
   ep    — MoE FFN experts sharded; all_to_all token dispatch
-          (parallel/moe.py). When num_experts == 0 the FFN is dense.
+          (parallel/moe.py).
 
 One block, whose architecture `TransformerConfig` states: the defaults are
 the GPT-2 block (LayerNorm, learned positions, GELU MLP); `norm="rmsnorm"`,
 `positions="rope"`, `qk_norm=True`, `mlp="swiglu"` and top-k experts with
-their auxiliary losses make it OLMoE's (arXiv:2409.02060). No biases except
-the GPT-2 block's LayerNorm and MLP ones.
+their auxiliary losses make it OLMoE's (arXiv:2409.02060);
+`attention="mla"` with its four widths, `yarn`, `shared_experts`, a share of
+the routed experts (`experts_held`, `first_expert`), a per-sequence balance
+loss and `first_k_dense` leading dense layers make it DeepSeek-V2's
+(arXiv:2405.04434). The FFN of a layer is dense (GELU with biases, or gated
+SiLU without) where the layer has no experts: every layer when
+`num_experts == 0`, the first `first_k_dense` otherwise, which are a stack
+of their own (`params["dense_layers"]`) in front of the expert stack
+(`params["layers"]`). No biases except the GPT-2 block's LayerNorm and MLP
+ones.
 
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
@@ -47,6 +55,51 @@ from horovod_tpu.parallel.mesh import AXIS_ORDER, mesh_axis_sizes
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's correction of the rotary frequencies (arXiv:2309.00071) with
+    the keys a `deepseek_v2` config.json gives it under `rope_scaling`."""
+    factor: float
+    original_max: int             # positions the frequencies were trained at
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _m(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def score_factor(self) -> float:
+        """What the softmax scale is multiplied by: m(mscale_all_dim)^2."""
+        return self._m(self.factor, self.mscale_all_dim) ** 2
+
+    @property
+    def rotation_factor(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._m(self.factor, self.mscale) \
+            / self._m(self.factor, self.mscale_all_dim)
+
+    def frequencies(self, rope_dim: int, theta: float):
+        """The rope_dim / 2 frequencies theta^(-2i / rope_dim), each blended
+        with itself / factor: untouched below the pair that turns
+        `beta_fast` times over `original_max` positions, divided from the
+        pair that turns `beta_slow` times on, a linear ramp between."""
+        half = rope_dim // 2
+
+        def pair_turning(rotations):
+            return rope_dim * math.log(self.original_max / (
+                rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(pair_turning(self.beta_fast)), 0)
+        high = min(math.ceil(pair_turning(self.beta_slow)), rope_dim - 1)
+        freq = theta ** (-np.arange(half, dtype=np.float64) / half)
+        ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+        return (freq * (1 - ramp) + freq / self.factor * ramp).astype(
+            np.float32)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
     d_model: int = 512
@@ -54,8 +107,22 @@ class TransformerConfig:
     d_ff: int = 2048
     n_layers: int = 4
     max_seq: int = 2048
-    num_experts: int = 0          # 0 → dense FFN; >0 → MoE every layer
+    # 0 → dense FFN; >0 → the router's width: experts in every layer but
+    # the first `first_k_dense`
+    num_experts: int = 0
     experts_per_token: int = 1    # the k of top-k routing
+    # The share of a layer's routed experts this program holds: experts
+    # [first_expert, first_expert + experts_held) of the num_experts the
+    # router scores (0: all of them). One chip of an expert-parallel group
+    # without its exchange: pairs routed elsewhere add nothing here.
+    experts_held: int = 0
+    first_expert: int = 0
+    # experts every token goes through beside the routed ones: one gated
+    # MLP of width shared_experts * d_ff, tp-sharded like a dense MLP
+    shared_experts: int = 0
+    # leading layers with a dense MLP of width d_ff_dense in place of experts
+    first_k_dense: int = 0
+    d_ff_dense: int = 0
     # rows one rank may send one expert, as a multiple of an even share;
     # means something only across ranks (ep > 1): see parallel/moe.py
     capacity_factor: float = 2.0
@@ -63,14 +130,30 @@ class TransformerConfig:
     #        + router_z_coef * router z-loss, each averaged over the layers
     load_balance_coef: float = 0.0
     router_z_coef: float = 0.0
+    # the load balance of each sequence, averaged (DeepSeek-V2's `seq_aux`),
+    # not of all of a shard's tokens at once
+    balance_per_sequence: bool = False
     norm: str = "layernorm"       # "layernorm" (scale and bias) | "rmsnorm"
+    rms_norm_eps: float = 1e-5
     positions: str = "learned"    # "learned" (a table added to the
     #                               embedding) | "rope" (rotate-half pairs)
     rope_theta: float = 10000.0
+    yarn: Optional[Yarn] = None   # needs positions="rope"
+    # "mha": wq, wk, wv of one head width, d_model / n_heads.
+    # "mla": DeepSeek-V2's latent attention. Queries (qk_nope_dim +
+    # qk_rope_dim) a head; one down-projection to kv_latent + qk_rope_dim a
+    # token, RMSNorm on the latent, an up-projection to (qk_nope_dim +
+    # v_head_dim) a head; the rotary key is one per token, shared by the
+    # heads. Keys and values then differ in width: attn "flash" or "local".
+    attention: str = "mha"
+    kv_latent: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # RMSNorm on the projected queries and keys, over the whole projected
     # vector (all heads), before it is split into heads and rotated
     qk_norm: bool = False
-    # "gelu" (biased when dense) | "swiglu" (gated SiLU; experts only)
+    # "gelu" (biased when dense) | "swiglu" (gated SiLU, no biases)
     mlp: str = "gelu"
     attn: str = "ring"            # "ring" | "ulysses" | "flash" | "local"
     microbatches: int = 1         # pipeline microbatches (≥ pp size ideal)
@@ -91,37 +174,85 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def rope_dim(self) -> int:
+        """Width of what the rotary embedding turns, per head."""
+        return self.qk_rope_dim if self.attention == "mla" else self.head_dim
+
+    @property
+    def score_scale(self) -> Optional[float]:
+        """The softmax scale, where it is not the kernels' default
+        (the keys' width)^-1/2."""
+        if self.yarn is None:
+            return None
+        width = self.qk_nope_dim + self.qk_rope_dim \
+            if self.attention == "mla" else self.head_dim
+        return width ** -0.5 * self.yarn.score_factor
+
+
+#: the stacks of layers a parameter tree may hold, in the order they run
+STACKS = ("dense_layers", "layers")
+
+
+def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
+    """`cfg` as the layers of `stack` see it: the leading dense layers are
+    layers of a model without experts whose MLP has their width."""
+    if stack == "layers":
+        return cfg
+    return dataclasses.replace(
+        cfg, num_experts=0, shared_experts=0, experts_held=0, first_expert=0,
+        d_ff=cfg.d_ff_dense, n_layers=cfg.first_k_dense, first_k_dense=0)
+
 
 def _present(tree: Dict[str, Any], cfg: TransformerConfig):
     """`tree` (parameters, specs or reduce axes, laid out as `init` lays the
     parameters out, with every leaf any architecture has) without the leaves
     `cfg`'s architecture does not have."""
-    if cfg.mlp == "swiglu" and not cfg.num_experts:
-        raise HorovodTpuError("mlp='swiglu' needs num_experts > 0: the "
-                              "dense MLP is the GPT-2 block's")
     absent = set()
     if cfg.norm == "rmsnorm":
         absent |= {"ln1_bias", "ln2_bias", "lnf_bias"}
     if cfg.positions == "rope":
         absent.add("pos")
+    if cfg.attention == "mla":
+        absent |= {"wk", "wv", "q_scale", "k_scale"}
+    else:
+        absent |= {"wkv_a", "kv_scale", "wkv_b"}
     if not cfg.qk_norm:
         absent |= {"q_scale", "k_scale"}
     if cfg.num_experts:
-        absent |= {"w1", "b1", "w2", "b2"}
+        absent |= {"w1", "b1", "w2", "b2", "w_gate"}
     else:
-        absent |= {"router", "we1", "we2"}
-    if cfg.mlp != "swiglu":
-        absent.add("we_gate")
-    return {k: _present(v, cfg) if k == "layers" else v
+        absent |= {"router", "we1", "we2", "we_gate"}
+    if not (cfg.num_experts and cfg.shared_experts):
+        absent |= {"ws1", "ws2", "ws_gate"}
+    if cfg.mlp == "swiglu":
+        absent |= {"b1", "b2"}
+    else:
+        absent |= {"we_gate", "w_gate", "ws_gate"}
+    if not cfg.first_k_dense:
+        absent.add("dense_layers")
+    return {k: _present(v, _stack_cfg(cfg, k)) if k in STACKS else v
             for k, v in tree.items() if k not in absent}
 
 
-def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
-    """Global (unsharded) parameter pytree."""
-    D, H, dh, F, L, V, E = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
-                            cfg.n_layers, cfg.vocab, cfg.num_experts)
+def _stack_depth(cfg: TransformerConfig) -> int:
+    return cfg.n_layers - cfg.first_k_dense
+
+
+def _layer_makers(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
+    """One stack's layers: every leaf a layer of any architecture has, as a
+    function that makes it, stacked on a leading axis."""
+    D, H, F, L, E = (cfg.d_model, cfg.n_heads, cfg.d_ff, _stack_depth(cfg),
+                     cfg.num_experts)
     dt = cfg.dtype
+    if cfg.attention == "mla":
+        dq, dvo = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
+    else:
+        dq = dvo = cfg.head_dim
+    held = cfg.experts_held or E
+    shared = cfg.shared_experts * F
     ks = jax.random.split(key, 12)
+    xs = jax.random.split(jax.random.fold_in(key, 1), 6)
 
     def norm(k, shape, fan_in):
         return lambda: jax.random.normal(k, shape, dt) * fan_in ** -0.5
@@ -132,51 +263,89 @@ def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     def zeros(*shape):
         return lambda: jnp.zeros(shape, dt)
 
+    return {
+        "ln1_scale": ones(L, D), "ln1_bias": zeros(L, D),
+        "wq": norm(ks[0], (L, D, H, dq), D),
+        "wk": norm(ks[1], (L, D, H, dq), D),
+        "wv": norm(ks[2], (L, D, H, dvo), D),
+        "wkv_a": norm(xs[0], (L, D, cfg.kv_latent + cfg.qk_rope_dim), D),
+        "kv_scale": ones(L, cfg.kv_latent),
+        "wkv_b": norm(xs[1], (L, cfg.kv_latent, H, cfg.qk_nope_dim + dvo),
+                      cfg.kv_latent),
+        "wo": norm(ks[3], (L, H, dvo, D), H * dvo),
+        "q_scale": ones(L, H, dq), "k_scale": ones(L, H, dq),
+        "ln2_scale": ones(L, D), "ln2_bias": zeros(L, D),
+        "router": norm(ks[4], (L, D, E), D),
+        "we1": norm(ks[5], (L, held, D, F), D),
+        "we2": norm(ks[6], (L, held, F, D), F),
+        "we_gate": norm(ks[10], (L, held, D, F), D),
+        "ws1": norm(xs[3], (L, D, shared), D),
+        "ws2": norm(xs[4], (L, shared, D), shared),
+        "ws_gate": norm(xs[5], (L, D, shared), D),
+        "w1": norm(ks[4], (L, D, F), D), "b1": zeros(L, F),
+        "w2": norm(ks[5], (L, F, D), F), "b2": zeros(L, D),
+        "w_gate": norm(xs[2], (L, D, F), D),
+    }
+
+
+def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
+    """Global (unsharded) parameter pytree."""
+    D, V = cfg.d_model, cfg.vocab
+    dt = cfg.dtype
+    ks = jax.random.split(key, 12)
+
+    def norm(k, shape):
+        return jax.random.normal(k, shape, dt)
+
     # every leaf any architecture has, each made only if this one has it
     make = {
-        "embed": lambda: norm(ks[7], (V, D), 1.0)() * 0.02 * D ** 0.5,
-        "pos": lambda: norm(ks[8], (cfg.max_seq, D), 1.0)() * 0.02,
-        "layers": {
-            "ln1_scale": ones(L, D), "ln1_bias": zeros(L, D),
-            "wq": norm(ks[0], (L, D, H, dh), D),
-            "wk": norm(ks[1], (L, D, H, dh), D),
-            "wv": norm(ks[2], (L, D, H, dh), D),
-            "wo": norm(ks[3], (L, H, dh, D), H * dh),
-            "q_scale": ones(L, H, dh), "k_scale": ones(L, H, dh),
-            "ln2_scale": ones(L, D), "ln2_bias": zeros(L, D),
-            "router": norm(ks[4], (L, D, E), D),
-            "we1": norm(ks[5], (L, E, D, F), D),
-            "we2": norm(ks[6], (L, E, F, D), F),
-            "we_gate": norm(ks[10], (L, E, D, F), D),
-            "w1": norm(ks[4], (L, D, F), D), "b1": zeros(L, F),
-            "w2": norm(ks[5], (L, F, D), F), "b2": zeros(L, D),
-        },
-        "lnf_scale": ones(D), "lnf_bias": zeros(D),
-        "unembed": norm(ks[9], (D, V), D),
+        "embed": lambda: norm(ks[7], (V, D)) * 0.02 * D ** 0.5,
+        "pos": lambda: norm(ks[8], (cfg.max_seq, D)) * 0.02,
+        "dense_layers": _layer_makers(jax.random.fold_in(key, 2),
+                                      _stack_cfg(cfg, "dense_layers")),
+        "layers": _layer_makers(key, cfg),
+        "lnf_scale": lambda: jnp.ones((D,), dt),
+        "lnf_bias": lambda: jnp.zeros((D,), dt),
+        "unembed": lambda: norm(ks[9], (D, V)) * D ** -0.5,
     }
     return jax.tree_util.tree_map(lambda f: f(), _present(make, cfg))
 
 
+def _layer_specs(lead: Optional[str]) -> Dict[str, Any]:
+    """PartitionSpecs of one stack's leaves, its leading (layer) axis over
+    the mesh axis `lead`."""
+    return {
+        "ln1_scale": P(lead, None), "ln1_bias": P(lead, None),
+        "wq": P(lead, None, "tp", None),
+        "wk": P(lead, None, "tp", None),
+        "wv": P(lead, None, "tp", None),
+        # the latent's down-projection and norm belong to no head: they are
+        # replicated over tp as the router is
+        "wkv_a": P(lead, None, None), "kv_scale": P(lead, None),
+        "wkv_b": P(lead, None, "tp", None),
+        "wo": P(lead, "tp", None, None),
+        "q_scale": P(lead, "tp", None), "k_scale": P(lead, "tp", None),
+        "ln2_scale": P(lead, None), "ln2_bias": P(lead, None),
+        "router": P(lead, None, None),
+        "we1": P(lead, "ep", None, None),
+        "we2": P(lead, "ep", None, None),
+        "we_gate": P(lead, "ep", None, None),
+        "ws1": P(lead, None, "tp"), "ws2": P(lead, "tp", None),
+        "ws_gate": P(lead, None, "tp"),
+        "w1": P(lead, None, "tp"), "b1": P(lead, "tp"),
+        "w2": P(lead, "tp", None), "b2": P(lead, None),
+        "w_gate": P(lead, None, "tp"),
+    }
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec tree matching init()'s structure (in_specs for
-    shard_map; also the NamedSharding layout for device_put)."""
-    lp = {
-        "ln1_scale": P("pp", None), "ln1_bias": P("pp", None),
-        "wq": P("pp", None, "tp", None),
-        "wk": P("pp", None, "tp", None),
-        "wv": P("pp", None, "tp", None),
-        "wo": P("pp", "tp", None, None),
-        "q_scale": P("pp", "tp", None), "k_scale": P("pp", "tp", None),
-        "ln2_scale": P("pp", None), "ln2_bias": P("pp", None),
-        "router": P("pp", None, None),
-        "we1": P("pp", "ep", None, None),
-        "we2": P("pp", "ep", None, None),
-        "we_gate": P("pp", "ep", None, None),
-        "w1": P("pp", None, "tp"), "b1": P("pp", "tp"),
-        "w2": P("pp", "tp", None), "b2": P("pp", None),
-    }
+    shard_map; also the NamedSharding layout for device_put). The leading
+    dense layers lie on every pipeline stage (`validate_cfg_for_mesh`
+    refuses pp > 1 with them)."""
     return _present({
-        "embed": P(), "pos": P(), "layers": lp,
+        "embed": P(), "pos": P(), "dense_layers": _layer_specs(None),
+        "layers": _layer_specs("pp"),
         "lnf_scale": P(), "lnf_bias": P(), "unembed": P(),
     }, cfg)
 
@@ -199,12 +368,15 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     lp = {"ln1_scale": data_axes, "ln1_bias": data_axes,
           "ln2_scale": data_axes, "ln2_bias": data_axes,
           "wq": tp_sharded, "wk": tp_sharded, "wv": tp_sharded,
+          "wkv_a": data_axes, "kv_scale": data_axes, "wkv_b": tp_sharded,
           "wo": tp_sharded, "q_scale": tp_sharded, "k_scale": tp_sharded,
           "router": data_axes, "we1": experts, "we2": experts,
           "we_gate": experts,
+          "ws1": tp_sharded, "ws2": tp_sharded, "ws_gate": tp_sharded,
           "w1": tp_sharded, "b1": tp_sharded, "w2": tp_sharded,
-          "b2": data_axes}
-    return _present({"embed": glob, "pos": glob, "layers": lp,
+          "b2": data_axes, "w_gate": tp_sharded}
+    return _present({"embed": glob, "pos": glob, "dense_layers": dict(lp),
+                     "layers": lp,
                      "lnf_scale": glob, "lnf_bias": glob, "unembed": glob},
                     cfg)
 
@@ -225,7 +397,7 @@ def _rms(x, scale, eps=1e-5):
 
 def _norm(x, p, name, cfg: TransformerConfig):
     if cfg.norm == "rmsnorm":
-        return _rms(x, p[name + "_scale"])
+        return _rms(x, p[name + "_scale"], cfg.rms_norm_eps)
     return _ln(x, p[name + "_scale"], p[name + "_bias"])
 
 
@@ -240,13 +412,22 @@ def _qk_norm(x, scale, eps=1e-5):
         * scale[None, :, None, :]
 
 
-def _rope_angles(positions, head_dim: int, theta: float):
+def _rope_angles(positions, head_dim: int, theta: float,
+                 yarn: Optional[Yarn] = None):
     """(cos, sin), each (S, head_dim / 2) float32, of the rotary embedding
-    at `positions`: pair i turns by position * theta^(-2i / head_dim)."""
+    at `positions`: pair i turns by position * theta^(-2i / head_dim), or by
+    position * `yarn`'s corrected frequency, cos and sin then times its
+    `rotation_factor`."""
     half = head_dim // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        factor = 1.0
+    else:
+        freq = jnp.asarray(yarn.frequencies(head_dim, theta))
+        factor = yarn.rotation_factor
     angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
 
 
 def _rope(x, angles):
@@ -259,24 +440,23 @@ def _rope(x, angles):
                            axis=-1).astype(x.dtype)
 
 
-def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
-           rope=None):
-    """One transformer block on per-shard activations x: (B, S_loc, D).
-    Returns (x, aux): aux is None for a dense MLP, and for experts the
-    layer's [load balance, router z] of this shard's tokens."""
-    h = _norm(x, lp, "ln1", cfg)
-    q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
-    if cfg.qk_norm:
-        q, k = _qk_norm(q, lp["q_scale"]), _qk_norm(k, lp["k_scale"])
-    if rope is not None:
-        q, k = _rope(q, rope), _rope(k, rope)
+def _attend(q, k, v, cfg: TransformerConfig):
+    """Causal attention of q, k: (B, H_loc, S_loc, dq) and v: (B, H_loc,
+    S_loc, dv) by the algorithm `cfg.attn` names."""
+    # the default scale, (the keys' width)^-1/2, is left to each algorithm
+    scale = {} if cfg.score_scale is None else {"scale": cfg.score_scale}
+    if cfg.attention == "mla" and cfg.attn not in ("flash", "local"):
+        # ring and Ulysses attention build their buffers and exchanges from
+        # one head width
+        raise HorovodTpuError(
+            f"attention='mla' has keys and values of different widths: "
+            f"attn={cfg.attn!r} cannot run it; use 'flash' or 'local'")
     if cfg.attn == "ring":
-        a = ring_attention(q, k, v, "sp", causal=True)
-    elif cfg.attn == "ulysses":
-        a = ulysses_mod.ulysses_attention(q, k, v, "sp", causal=True)
-    elif cfg.attn == "flash":
+        return ring_attention(q, k, v, "sp", causal=True, **scale)
+    if cfg.attn == "ulysses":
+        return ulysses_mod.ulysses_attention(q, k, v, "sp", causal=True,
+                                             **scale)
+    if cfg.attn == "flash":
         # Pallas flash kernel (ops/flash_attention.py) computes
         # shard-LOCAL attention; silently wrong under a sequence-sharded
         # mesh, so refuse — sharded sequences ride ring/Ulysses.
@@ -285,10 +465,68 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
                 "attn='flash' requires sp=1 (shard-local attention); use "
                 "attn='ring' or 'ulysses' for sequence parallelism")
         from horovod_tpu.ops.flash_attention import flash_attention
-        a = flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=True, **scale)
+    return blockwise_attention_reference(q, k, v, causal=True, **scale)
+
+
+def _mla(h, lp: Dict[str, Any], cfg: TransformerConfig, rope):
+    """DeepSeek-V2's latent attention on the normed residual h: (B, S_loc,
+    D); returns this rank's heads' part of the output, (B, S_loc, D)."""
+    if rope is None:
+        raise HorovodTpuError("attention='mla' has a rotary part of its "
+                              "keys: it needs positions='rope'")
+    nope, latent = cfg.qk_nope_dim, cfg.kv_latent
+    with jax.named_scope("mla.project"):
+        q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
+        down = jnp.einsum("bsd,dc->bsc", h, lp["wkv_a"])
+        c = _rms(down[..., :latent], lp["kv_scale"], cfg.rms_norm_eps)
+        kv = jnp.einsum("bsc,chk->bhsk", c, lp["wkv_b"])
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+    with jax.named_scope("mla.rope"):
+        # one rotary key a token, shared by all heads
+        k_pe = _rope(down[:, None, :, latent:], rope)
+        q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], rope)],
+                            axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3]
+                                      + k_pe.shape[3:])], axis=-1)
+    with jax.named_scope("mla.attend"):
+        a = _attend(q, k, v, cfg)
+    with jax.named_scope("mla.out"):
+        return jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
+
+
+def _mlp(h, w_gate, w_up, w_down):
+    """W_down (silu(W_gate h) * W_up h), or W_down gelu(W_up h) without a
+    gate; no biases. The hidden width is sharded over tp: this rank's part
+    of the sum."""
+    hidden = jnp.einsum("bsd,df->bsf", h, w_up)
+    hidden = jax.nn.gelu(hidden) if w_gate is None else \
+        jax.nn.silu(jnp.einsum("bsd,df->bsf", h, w_gate)) * hidden
+    return jnp.einsum("bsf,fd->bsd", hidden, w_down)
+
+
+def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
+           rope=None):
+    """One transformer block on per-shard activations x: (B, S_loc, D).
+    Returns (x, aux): aux is None for a dense MLP, and for experts the
+    layer's [load balance, router z] of this shard's tokens, with the count
+    of held pairs that found no room as a third where the layer holds a
+    share of its experts (see `parallel/moe.py`)."""
+    h = _norm(x, lp, "ln1", cfg)
+    if cfg.attention == "mla":
+        o = _mla(h, lp, cfg, rope)
     else:
-        a = blockwise_attention_reference(q, k, v, causal=True)
-    o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
+        q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
+        if cfg.qk_norm:
+            q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
+            k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
+        if rope is not None:
+            q, k = _rope(q, rope), _rope(k, rope)
+        a = _attend(q, k, v, cfg)
+        o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
     o = lax.psum(o, "tp")                    # row-parallel combine
     x = x + o
 
@@ -299,8 +537,16 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
         out, aux, _ = moe_mod.moe_ffn(
             h2.reshape(B * S, D), lp["router"], lp["we1"], lp["we2"],
             lp.get("we_gate"), top_k=cfg.experts_per_token, axis_name="ep",
-            capacity_factor=cfg.capacity_factor)
+            capacity_factor=cfg.capacity_factor,
+            first_expert=cfg.first_expert,
+            sequences=B if cfg.balance_per_sequence else 0)
         f = out.reshape(B, S, D)
+        if cfg.shared_experts:
+            with jax.named_scope("moe.shared"):
+                f = f + lax.psum(_mlp(h2, lp.get("ws_gate"), lp["ws1"],
+                                      lp["ws2"]), "tp")
+    elif cfg.mlp == "swiglu":
+        f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"]), "tp")
     else:
         u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
         u = jax.nn.gelu(u)
@@ -315,11 +561,13 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     batch sharded over (dp, ep), sequence over sp, run under shard_map. With
     pp > 1 only the last stage's logits are real (zeros elsewhere). aux is
     None for a dense MLP; for experts it is the [load balance, router z] of
-    each of this stage's layers on this shard's tokens, (L_loc, 2), averaged
-    over the microbatches where there are any.
-    `grad_slots` (stacked per layer like the layers' parameters) and
-    `scatter` are what `build_loss_and_grads` reduces the layers' gradients
-    through inside the backward loop: see `_scattered_in_backward`."""
+    each of this stage's expert layers on this shard's tokens, (L_loc, 2),
+    averaged over the microbatches where there are any ((L_loc, 3) where
+    the layers hold a share of their experts: `_layer`).
+    `grad_slots` (per stack, stacked per layer like the stack's parameters)
+    and `scatter` are what `build_loss_and_grads` reduces the layers'
+    gradients through inside the backward loop: see
+    `_scattered_in_backward`."""
     sp_idx = lax.axis_index("sp")
     B, S = tokens.shape
     D = cfg.d_model
@@ -327,17 +575,22 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     x = params["embed"][tokens]
     rope = None
     if cfg.positions == "rope":
-        rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.head_dim,
-                            cfg.rope_theta)
+        rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
+                            cfg.rope_theta, cfg.yarn)
         x = x.astype(cfg.dtype)
     else:
         pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S, axis=0)
         x = (x + pos[None]).astype(cfg.dtype)
 
-    def stage_fn(stage_params, act):
+    def run_stack(stack, stage_params, act):
+        """`act` through the layers of one stack: (act, the layers' aux)."""
+        slots = (grad_slots or {}).get(stack)
+        layer_cfg = _stack_cfg(cfg, stack)
+
         def body(a, xs):
-            lp = _scattered_in_backward(*xs, scatter) if grad_slots else xs
-            return _layer(a, lp, cfg, rope)
+            lp = _scattered_in_backward(*xs, partial(scatter, stack)) \
+                if slots else xs
+            return _layer(a, lp, layer_cfg, rope)
         if cfg.remat:
             # "dots": save projection/FFN matmul outputs (small, expensive
             # to recompute); recompute batched-dot products — exactly the
@@ -354,8 +607,14 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
                     f"{sorted(policies)} (remat=False turns remat off)")
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=policies[cfg.remat_policy])
-        return lax.scan(body, act, (stage_params, grad_slots)
-                        if grad_slots else stage_params)
+        return lax.scan(body, act, (stage_params, slots)
+                        if slots else stage_params)
+
+    stage_fn = partial(run_stack, "layers")
+    if cfg.first_k_dense:
+        # the leading dense layers see every sequence alike, so they run
+        # on the whole local batch, in front of any microbatching
+        x, _ = run_stack("dense_layers", params["dense_layers"], x)
 
     M = cfg.microbatches
     if lax.axis_size("pp") > 1 and M <= 1:
@@ -380,7 +639,9 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
 
 def _local_loss(params, tokens, targets, cfg: TransformerConfig,
                 grad_slots=None, scatter=None):
-    """Per-shard loss contribution (see NOTE below on psum placement)."""
+    """(Per-shard loss contribution, see NOTE below on psum placement; the
+    held (token, expert) pairs this shard's expert layers left out of their
+    row buffers, 0 where every expert is held: `parallel/moe.py`)."""
     pp_size = lax.axis_size("pp")
     B, S = tokens.shape
     logits, aux = _forward_local(params, tokens, cfg, grad_slots, scatter)
@@ -417,8 +678,16 @@ def _local_loss(params, tokens, targets, cfg: TransformerConfig,
                           jnp.float32)
         shards = (lax.axis_size("dp") * lax.axis_size("ep")
                   * lax.axis_size("sp"))
-        local = local + jnp.sum(aux @ coefs) / (cfg.n_layers * shards)
-    return local
+        terms = aux[:, :2] if aux.shape[-1] > 2 else aux
+        local = local + jnp.sum(terms @ coefs) / (
+            _stack_depth(cfg) * shards)
+    # a share of the experts on one rank is dropless only while its pairs
+    # fit the row buffer: the held pairs this shard's layers left out
+    # (aux is a mean over the microbatches; the count is their sum)
+    dropped = jnp.sum(aux[:, 2]) * max(cfg.microbatches, 1) \
+        if aux is not None and aux.shape[-1] > 2 \
+        else jnp.zeros((), jnp.float32)
+    return local, lax.stop_gradient(dropped)
 
 
 def psum_axes(x, axes):
@@ -499,10 +768,17 @@ def _reduces_in_backward(cfg: TransformerConfig, mesh: Mesh) -> bool:
         sizes[a] > 1 for a in ("dp", "ep", "sp", "tp"))
 
 
-def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh):
+def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
+                         metrics: bool = False):
     """shard_map'd (params, tokens, targets) -> (loss, grads) with the
     gradient reduction compiled in. The multi-axis generalisation of
-    optim/optimizer.py:reduce_gradients_in_jit.
+    optim/optimizer.py:reduce_gradients_in_jit. With `metrics` a third
+    result, `{"experts_dropped": int32}`: the held (token, expert) pairs
+    that found no room in a row buffer, over all layers and shards; not 0
+    only where a rank holds a share of the experts (`experts_held`) and the
+    routing sends it over twice its even share (`parallel/moe.py`). Those
+    pairs added nothing in this step: the loss and the gradients are the
+    model's without them.
 
     Every gradient leaf is divided by tp and summed over its
     `grad_reduce_axes`, once. Where and how is read from the mesh and the
@@ -533,9 +809,10 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh):
         return psum_axes(g / tp_size, axes)
 
     def fn(params, tokens, targets):
-        plans = {k: plan for k, w in params["layers"].items() if (
-            plan := _scatter_plan(w.shape[1:], raxes["layers"][k]))
-        } if in_backward else {}
+        # per stack of layers, the leaves that are scattered in the loop
+        plans = {stack: {k: plan for k, w in params[stack].items() if (
+            plan := _scatter_plan(w.shape[1:], raxes[stack][k]))}
+            for stack in STACKS if stack in params} if in_backward else {}
 
         def slot(w, plan):
             _, n, dim = plan
@@ -543,28 +820,40 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh):
             shape[dim + 1] //= n
             return jnp.zeros(shape, w.dtype)
 
-        slots = {k: slot(params["layers"][k], plan)
-                 for k, plan in plans.items()}
+        slots = {stack: {k: slot(params[stack][k], plan)
+                         for k, plan in of_stack.items()}
+                 for stack, of_stack in plans.items()}
 
-        def scatter(k, g):
-            return _scatter_sum(g / tp_size, *plans[k])
+        def scatter(stack, k, g):
+            return _scatter_sum(g / tp_size, *plans[stack][k])
 
-        local_mean, (grads, shards) = jax.value_and_grad(
+        (local_mean, dropped), (grads, shards) = jax.value_and_grad(
             lambda p, slots: _local_loss(p, tokens, targets, cfg, slots,
                                          scatter),
-            argnums=(0, 1))(params, slots)
-        layers = {k: lax.all_gather(shards[k], plans[k][0],
-                                    axis=plans[k][2] + 1, tiled=True)
-                  if k in plans else reduce_late(g, raxes["layers"][k])
-                  for k, g in grads["layers"].items()}
-        grads = {k: layers if k == "layers" else
+            argnums=(0, 1), has_aux=True)(params, slots)
+
+        def completed(stack):
+            plan = plans.get(stack, {})
+            return {k: lax.all_gather(shards[stack][k], plan[k][0],
+                                      axis=plan[k][2] + 1, tiled=True)
+                    if k in plan else reduce_late(g, raxes[stack][k])
+                    for k, g in grads[stack].items()}
+
+        stacks = {stack: completed(stack) for stack in STACKS
+                  if stack in grads}
+        grads = {k: stacks[k] if k in stacks else
                  jax.tree_util.tree_map(reduce_late, g, raxes[k])
                  for k, g in grads.items()}
         loss = psum_axes(local_mean, ("dp", "ep", "sp", "pp"))
-        return loss, grads
+        if not metrics:
+            return loss, grads
+        dropped = psum_axes(dropped, ("dp", "ep", "sp", "pp"))
+        return loss, grads, {"experts_dropped": dropped.astype(jnp.int32)}
 
+    out_specs = (P(), specs, {"experts_dropped": P()}) if metrics \
+        else (P(), specs)
     return jax.shard_map(fn, mesh=mesh, in_specs=(specs, bspec, bspec),
-                         out_specs=(P(), specs), check_vma=False)
+                         out_specs=out_specs, check_vma=False)
 
 
 def build_forward(cfg: TransformerConfig, mesh: Mesh):
@@ -599,8 +888,12 @@ def _step_compiler_options(cfg: TransformerConfig, mesh: Mesh):
 
 
 def build_train_step(cfg: TransformerConfig, mesh: Mesh,
-                     optimizer: optax.GradientTransformation):
-    """Full jitted train step over the mesh. Forward, backward and the
+                     optimizer: optax.GradientTransformation, *,
+                     metrics: bool = False):
+    """Full jitted train step over the mesh: (params, opt_state, tokens,
+    targets) -> (params, opt_state, loss), and with `metrics` a fourth
+    result, `build_loss_and_grads`'s `{"experts_dropped": ...}`, for whoever
+    trains a share of the experts: watch it. Forward, backward and the
     gradient reduction run inside shard_map (`build_loss_and_grads` says
     where the reduction is issued and in what form); the optax update runs
     under GSPMD, which propagates param shardings through the elementwise
@@ -610,15 +903,15 @@ def build_train_step(cfg: TransformerConfig, mesh: Mesh,
     Create the optimizer state with `init_opt_state`, not a bare
     `optimizer.init(params)` (chip_smoke.py checks that nothing compiles
     after step 1)."""
-    lg = build_loss_and_grads(cfg, mesh)
+    lg = build_loss_and_grads(cfg, mesh, metrics=metrics)
 
     @partial(jax.jit, donate_argnums=(0, 1),
              compiler_options=_step_compiler_options(cfg, mesh))
     def step(params, opt_state, tokens, targets):
-        loss, grads = lg(params, tokens, targets)
+        loss, grads, *counts = lg(params, tokens, targets)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        return (params, opt_state, loss, *counts)
 
     return step
 
@@ -649,11 +942,28 @@ def init_opt_state(optimizer: optax.GradientTransformation, params,
 def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
     ax = mesh_axis_sizes(mesh)
     checks = [
-        (cfg.n_layers % (ax["pp"],)[0] == 0, "n_layers % pp"),
+        (_stack_depth(cfg) % ax["pp"] == 0, "n_layers % pp"),
         (cfg.n_heads % ax["tp"] == 0, "n_heads % tp"),
         (cfg.d_ff % ax["tp"] == 0, "d_ff % tp"),
+        (cfg.d_ff_dense % ax["tp"] == 0, "d_ff_dense % tp"),
         (cfg.num_experts % ax["ep"] == 0 if cfg.num_experts else True,
          "num_experts % ep"),
+        # every rank of the expert axis holds an equal part of ALL the
+        # experts the router scores, or one rank holds a share of them
+        # (parallel/moe.py): a share across ranks would need a second
+        # exchange for the pairs that no rank here holds
+        (ax["ep"] == 1 or cfg.experts_held in (0, cfg.num_experts),
+         "ep > 1 with experts_held < num_experts (a share of the experts "
+         "is one rank's)"),
+        # the leading dense layers are a stack of their own that no
+        # pipeline stage owns: the schedule has no place for them yet
+        (ax["pp"] == 1 or not cfg.first_k_dense,
+         "pp > 1 with first_k_dense > 0 (the leading dense layers belong "
+         "to no pipeline stage)"),
+        (cfg.attention != "mla" or cfg.attn in ("flash", "local"),
+         "attention='mla' needs attn 'flash' or 'local'"),
+        (cfg.attention != "mla" or ax["sp"] == 1,
+         "attention='mla' requires sp=1"),
         # pp > 1 REQUIRES the microbatch pipeline: without it stages never
         # exchange activations and each stage silently trains only its own
         # layer slice on raw embeddings.

@@ -109,6 +109,19 @@ def _scores(q_ref, k_ref, row0, rows, cols, masked, *,
     return s
 
 
+def _compiler_params(dh: int, dhv: int) -> dict:
+    """`pallas_call` arguments that give a kernel the VMEM its blocks need.
+    Up to 128 lanes a head the 1024² blocks fit the compiler's default
+    scope (16 MiB). A wider head takes two lane tiles a row in every q/k
+    block and accumulator: at (192 | 128) the dk/dv kernel needs 17.2 MiB
+    inside a train step (the TPU compiler's own count, PR 30), so such
+    kernels are given twice the default; v5e has 128 MiB."""
+    if max(dh, dhv) <= _LANES:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=32 * 2 ** 20)}
+
+
 # --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
@@ -164,8 +177,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, causal, scale, block_q, block_k):
-    bh, sq, dh = q.shape
-    sk = k.shape[1]
+    bh, sq, dh = q.shape            # dh: the queries' and keys' width
+    sk, dhv = v.shape[1:]           # dhv: the values' (o follows v)
     nq = sq // block_q
     nk = sk // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -176,24 +189,25 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dhv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dhv), lambda b, i, j: (b, i, 0)),
             # lse rides a (bh, S, 1) array: the (block_q, 1) block is legal
             # tiling (minor dim equals the array dim) and 128x smaller than
             # lane-replicating a VJP residual that lives fwd->bwd.
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dhv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, dh), jnp.float32),
+            pltpu.VMEM((block_q, dhv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
+        **_compiler_params(dh, dhv),
     )(q, k, v)
     return o, lse
 
@@ -294,16 +308,21 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
 def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     """dlse=None compiles lse-cotangent-free kernels (the plain
     flash_attention path never pays for a zero dlse buffer)."""
-    bh, sq, dh = q.shape
-    sk = k.shape[1]
+    bh, sq, dh = q.shape            # dq and dk follow q's width,
+    sk, dhv = v.shape[1:]           # o, do and dv follow v's
     nq = sq // block_q
     nk = sk // block_k
     has_dlse = dlse is not None
 
-    q_by_j = pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, j, 0))
-    kv_by_i = pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0))
-    lse_by_j = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
-    in_specs = [q_by_j, kv_by_i, kv_by_i, q_by_j, q_by_j, lse_by_j]
+    def by_i(block, width):
+        return pl.BlockSpec((1, block, width), lambda b, i, j: (b, i, 0))
+
+    def by_j(block, width):
+        return pl.BlockSpec((1, block, width), lambda b, i, j: (b, j, 0))
+
+    lse_by_j = by_j(block_q, 1)
+    in_specs = [by_j(block_q, dh), by_i(block_k, dh), by_i(block_k, dhv),
+                by_j(block_q, dhv), by_j(block_q, dhv), lse_by_j]
     operands = [q, k, v, o, do, lse]
     if has_dlse:
         in_specs.append(lse_by_j)
@@ -314,24 +333,21 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
                           has_dlse=has_dlse),
         grid=(bh, nk, nq),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=[by_i(block_k, dh), by_i(block_k, dhv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, dh), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dh), q.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dhv), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dh), jnp.float32),
-            pltpu.VMEM((block_k, dh), jnp.float32),
+            pltpu.VMEM((block_k, dhv), jnp.float32),
         ],
+        **_compiler_params(dh, dhv),
     )(*operands)
 
-    q_by_i = pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0))
-    kv_by_j = pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0))
-    lse_by_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    in_specs = [q_by_i, kv_by_j, kv_by_j, q_by_i, q_by_i, lse_by_i]
+    lse_by_i = by_i(block_q, 1)
+    in_specs = [by_i(block_q, dh), by_j(block_k, dh), by_j(block_k, dhv),
+                by_i(block_q, dhv), by_i(block_q, dhv), lse_by_i]
     operands = [q, k, v, o, do, lse]
     if has_dlse:
         in_specs.append(lse_by_i)
@@ -342,9 +358,10 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
                           has_dlse=has_dlse),
         grid=(bh, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
+        out_specs=by_i(block_q, dh),
         out_shape=jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
+        **_compiler_params(dh, dhv),
     )(*operands)
     return dq, dk, dv
 
@@ -414,15 +431,16 @@ def flash_attention_chunk(q, k, v, causal: bool = False,
                           block_k: Optional[int] = None):
     """One attention chunk with mergeable outputs.
 
-    q: (B, H, Sq, dh); k, v: (B, H, Sk, dh) — Sq and Sk may differ (ring
-    hops attend local queries against a circulating K/V block). Returns
-    (o, lse) with o: (B, H, Sq, dh) normalized within the chunk and
+    q: (B, H, Sq, dh); k: (B, H, Sk, dh); v: (B, H, Sk, dv) — Sq and Sk may
+    differ (ring hops attend local queries against a circulating K/V
+    block), and so may the values' width and the keys'. Returns
+    (o, lse) with o: (B, H, Sq, dv) normalized within the chunk and
     lse: (B, H, Sq) float32; merge chunks with
     L = logaddexp(L1, L2), o = e^{L1−L}·o1 + e^{L2−L}·o2. Differentiable
     through BOTH outputs.
     """
     B, H, Sq, dh = q.shape
-    Sk = k.shape[2]
+    Sk, dv = v.shape[2:]
     if scale is None:
         scale = dh ** -0.5
     bq = min(block_q, Sq) if block_q else _auto_block(Sq)
@@ -434,9 +452,9 @@ def flash_attention_chunk(q, k, v, causal: bool = False,
             f"(blocks {bq}, {bk}); causal chunks must be square")
     o, lse = _flash_chunk(q.reshape(B * H, Sq, dh),
                           k.reshape(B * H, Sk, dh),
-                          v.reshape(B * H, Sk, dh),
+                          v.reshape(B * H, Sk, dv),
                           causal, float(scale), bq, bk)
-    return (o.reshape(B, H, Sq, dh),
+    return (o.reshape(B, H, Sq, dv),
             lse[..., 0].reshape(B, H, Sq))  # drop the unit minor dim
 
 
@@ -475,12 +493,16 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: Optional[int] = None) -> jax.Array:
     """Exact attention via the Pallas flash kernel.
 
-    q, k, v: (B, H, S, dh). Returns (B, H, S, dh). Differentiable
-    (custom VJP with flash backward kernels). Block sizes default to a
-    measured heuristic; falls back to the score-materializing reference
-    for shapes the kernel cannot tile.
+    q, k: (B, H, S, dh); v: (B, H, S, dv). Returns (B, H, S, dv): the
+    values may be narrower or wider than the queries and keys (latent
+    attention: 192-wide keys, 128-wide values), and then o, do and dv have
+    the values' width, dq and dk the keys'. `scale` defaults to dh^-1/2.
+    Differentiable (custom VJP with flash backward kernels). Block sizes
+    default to a measured heuristic; falls back to the score-materializing
+    reference for shapes the kernel cannot tile.
     """
     B, H, S, dh = q.shape
+    dv = v.shape[-1]
     if scale is None:
         scale = dh ** -0.5
     block_q = min(block_q, S) if block_q else _auto_block(S)
@@ -493,6 +515,6 @@ def flash_attention(q, k, v, causal: bool = True,
                                              scale=scale)
     qf = q.reshape(B * H, S, dh)
     kf = k.reshape(B * H, S, dh)
-    vf = v.reshape(B * H, S, dh)
+    vf = v.reshape(B * H, S, dv)
     o = _flash(qf, kf, vf, causal, float(scale), block_q, block_k)
-    return o.reshape(B, H, S, dh)
+    return o.reshape(B, H, S, dv)

@@ -10,7 +10,9 @@ it exposes for users to build this). For a token `h` with router weights
 
 The k weights are not renormalised. `expert_e(h)` is `W_down,e (silu(W_gate,e
 h) * (W_up,e h))` where gate weights are given and `W_down,e gelu(W_up,e h)`
-where they are not.
+where they are not. Experts that every token goes through beside these
+(DeepSeek's shared experts) are a dense MLP of the caller's, added to this
+layer's result (`models/transformer.py`, scope `moe.shared`).
 
 On one rank of the expert axis (`ep` = 1) routing is dropless and
 static-shaped: the T*k (token, expert) pairs are sorted by expert, their rows
@@ -36,6 +38,26 @@ returned rows: they come back in slot layout, where dropped pairs are
 masked, and weighting in front would send the weights through an exchange of
 their own.
 
+A share of the experts. The router's width E is the model's; the expert
+weights say how many are held, and `first_expert` which: experts
+[first_expert, first_expert + n_local). Where one rank holds fewer than E
+(one chip of an expert-parallel deployment, without the exchange) it routes
+over all E and computes its own experts' part of the result for its own
+tokens: pairs routed to held experts are sorted first, gathered, multiplied
+and summed back as above; a pair routed elsewhere adds nothing, and its
+weight gets no gradient through the experts. Nothing stands in for the
+absent ranks. The row buffer is static and sized from the shapes alone:
+`held_rows`, twice the rows an even routing sends to the held experts. Its
+free rows are zero and lie in the last held expert's group, where they add
+nothing: the grouped matmuls' work is the buffer's, not the routing's, so a
+step takes the same time whatever the router has learnt (a chip of the
+deployment is held to a fixed budget too, arXiv:2405.04434 section 2.2.4).
+Dropless stays the promise only while the held pairs fit: the count of those
+beyond the buffer, which add nothing, is returned as a third auxiliary
+number, and the train step hands it on (`models/transformer.py`
+`build_train_step(..., metrics=True)`: `experts_dropped`). Across ranks every
+rank holds E / ranks experts: a share there is refused.
+
 Across ranks (`ep` > 1) the experts are sharded one group per rank and the
 rows are exchanged by `lax.all_to_all` (compiled onto ICI), which needs a
 static shape: each rank sends every expert at most `cap = ceil(capacity_factor
@@ -51,6 +73,11 @@ holds (OLMoE, arXiv:2409.02060 section 2):
     load balance = E * sum_e f_e * P_e    f_e: share of the T*k pairs sent to e
                                           P_e: mean of p_e over the tokens
     router z     = mean over tokens of logsumexp(h Wr)^2
+
+or, where `route` is handed the number of sequences the tokens are
+(DeepSeek-V2's `seq_aux`, arXiv:2405.04434 section 2.1.3), the load balance
+is each sequence's own, f_e and P_e over that sequence's tokens, averaged
+over the sequences. Either way it is over all E experts, held or not.
 """
 
 from __future__ import annotations
@@ -63,62 +90,92 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.common.exceptions import HorovodTpuError
 
-def route(x: jax.Array, router_w: jax.Array, top_k: int):
+
+def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0):
     """(weights (T, k) float32, experts (T, k) int32, rows per expert (E,)
-    int32, [load balance, router z] float32) for the tokens x: (T, D)."""
+    int32, [load balance, router z] float32) for the tokens x: (T, D), which
+    are `sequences` sequences of equal length where the load balance is to
+    be each sequence's own (0: of all T tokens at once)."""
     with jax.named_scope("moe.route"):
         n_experts = router_w.shape[1]
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = lax.top_k(probs, top_k)
-        counts = jnp.sum(jax.nn.one_hot(experts, n_experts, dtype=jnp.int32),
-                         axis=(0, 1), dtype=jnp.int32)
-        share = counts.astype(jnp.float32) / experts.size
-        load_balance = n_experts * jnp.sum(share * jnp.mean(probs, axis=0))
+        if sequences:
+            per_seq = jnp.sum(
+                jax.nn.one_hot(experts, n_experts, dtype=jnp.int32).reshape(
+                    sequences, -1, n_experts), axis=1, dtype=jnp.int32)
+            counts = jnp.sum(per_seq, axis=0, dtype=jnp.int32)
+            share = per_seq.astype(jnp.float32) / (experts.size // sequences)
+            mean_probs = jnp.mean(probs.reshape(sequences, -1, n_experts),
+                                  axis=1)
+            load_balance = n_experts * jnp.mean(
+                jnp.sum(share * mean_probs, axis=-1))
+        else:
+            counts = jnp.sum(
+                jax.nn.one_hot(experts, n_experts, dtype=jnp.int32),
+                axis=(0, 1), dtype=jnp.int32)
+            share = counts.astype(jnp.float32) / experts.size
+            load_balance = n_experts * jnp.sum(
+                share * jnp.mean(probs, axis=0))
         z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
         return weights, experts.astype(jnp.int32), counts, \
             jnp.stack([load_balance, z])
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, rows, back, k):
+def _take_rows(x, rows, back, k, n_valid=None):
     """`x[rows]`, where `back` says which k rows of the result each row of
     `x` went to (row t to rows back[t*k:(t+1)*k]): the backward pass is then
-    a gather and a sum over k (`_sum_rows`), not a scatter-add."""
-    return x[rows]
+    a gather and a sum over k (`_sum_rows`), not a scatter-add. With
+    `n_valid` the result is a row buffer of which the first n_valid rows
+    count: the others are zero, and `back` may point past its end."""
+    taken = x[rows]
+    if n_valid is None:
+        return taken
+    return jnp.where((jnp.arange(rows.size) < n_valid)[:, None], taken,
+                     jnp.zeros((), x.dtype))
 
 
-def _take_rows_fwd(x, rows, back, k):
-    return _take_rows(x, rows, back, k), (rows, back)
+def _take_rows_fwd(x, rows, back, k, n_valid=None):
+    return _take_rows(x, rows, back, k, n_valid), (rows, back, n_valid)
 
 
 def _take_rows_bwd(k, indices, g):
-    rows, back = indices
-    return _sum_rows(g, back, rows, k), None, None
+    rows, back, n_valid = indices
+    return _sum_rows(g, back, rows, k, n_valid), None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_rows(x, back, rows, k):
+def _sum_rows(x, back, rows, k, n_valid=None):
     """The transpose of `_take_rows`: row t of the result is the sum, in
     float32, of the k rows x[back[t*k:(t+1)*k]], and row i of `x` went into
     row rows[i] alone: the backward pass is `g[rows]`, a gather from the
-    small array."""
-    return jnp.sum(x[back].reshape(-1, k, x.shape[-1]), axis=1,
+    small array. With `n_valid`, a row of `back` at or past it adds
+    nothing."""
+    if n_valid is None:
+        picked = x[back]
+    else:
+        picked = jnp.where((back < n_valid)[:, None],
+                           x[jnp.minimum(back, x.shape[0] - 1)],
+                           jnp.zeros((), x.dtype))
+    return jnp.sum(picked.reshape(-1, k, x.shape[-1]), axis=1,
                    dtype=jnp.promote_types(x.dtype, jnp.float32)
                    ).astype(x.dtype)
 
 
-def _sum_rows_fwd(x, back, rows, k):
-    return _sum_rows(x, back, rows, k), (rows, back)
+def _sum_rows_fwd(x, back, rows, k, n_valid=None):
+    return _sum_rows(x, back, rows, k, n_valid), (rows, back, n_valid)
 
 
 def _sum_rows_bwd(k, indices, g):
-    rows, back = indices
-    return _take_rows(g, rows, back, k), None, None
+    rows, back, n_valid = indices
+    return _take_rows(g, rows, back, k, n_valid), None, None, None
 
 
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
@@ -145,11 +202,27 @@ def _permute_bwd(indices, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+#: rows a grouped matmul's kernel takes at a time: the row buffer of a share
+#: of the experts is a multiple of it
+ROW_TILE = 512
+
+
+def held_rows(pairs: int, n_local: int, n_experts: int) -> int:
+    """Rows of the buffer that one rank holding `n_local` of `n_experts`
+    experts sorts its held (token, expert) pairs into, of `pairs` routed:
+    twice what an even routing sends it, in whole row tiles, and never more
+    than all the pairs."""
+    even = pairs * n_local / n_experts
+    return min(pairs, math.ceil(2 * even / ROW_TILE) * ROW_TILE)
+
+
 def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
     """The experts on rows sorted by expert: three grouped matmuls (two for
     an ungated expert). `row_weights` (rows, 1) float32, where given, scale
     each row's hidden activations, in float32 from the products' results to
-    the one rounding the down product's input has either way."""
+    the one rounding the down product's input has either way. Every row
+    lies in a group (a grouped matmul says nothing of the others'
+    results)."""
     with jax.named_scope("moe.experts"):
         wide = rows.dtype if row_weights is None else jnp.float32
         hidden = lax.ragged_dot(rows, w_up, group_sizes).astype(wide)
@@ -166,40 +239,81 @@ def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             w_down: jax.Array, w_gate: Optional[jax.Array] = None, *,
             top_k: int = 1, axis_name: str = "ep",
-            capacity_factor: float = 1.25
+            capacity_factor: float = 1.25, first_expert: int = 0,
+            sequences: int = 0
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k mixture-of-experts feed-forward on one shard's tokens.
 
     Per-shard shapes:
       x: (T, D) local tokens (flatten batch*seq before calling)
-      router_w: (D, E) with E = total experts across the axis
-      w_up, w_gate: (E_local, D, F), w_down: (E_local, F, D) — this rank's
-        experts
+      router_w: (D, E), E the experts the model has
+      w_up, w_gate: (E_local, D, F), w_down: (E_local, F, D) — the experts
+        this rank holds: E / ranks of them across ranks, and on one rank
+        any E_local <= E, experts [first_expert, first_expert + E_local)
+      sequences: how many sequences the T tokens are, where the load
+        balance is each sequence's own (`route`)
     Returns ((T, D), [load balance, router z] of these tokens, the (T, k)
-    experts of each token in the order of their weights).
+    experts of each token in the order of their weights). Where one rank
+    holds a share (E_local < E) the second has a third number: the held
+    pairs that found no room in the row buffer (`held_rows`) and were left
+    out, 0 in a sound step.
     """
     ranks = lax.axis_size(axis_name)
     T, D = x.shape
     k = top_k
     n_local = w_up.shape[0]
-    n_experts = n_local * ranks
-    assert router_w.shape[1] == n_experts, \
-        "router width must equal total experts"
+    n_experts = router_w.shape[1]
+    if ranks > 1 and n_local * ranks != n_experts:
+        raise HorovodTpuError(
+            f"{ranks} ranks of {n_local} experts each under a router over "
+            f"{n_experts}: across ranks every expert is held by some rank, "
+            "a share of the experts is one rank's")
+    if first_expert < 0 or first_expert + n_local > n_experts or (
+            ranks > 1 and first_expert):
+        raise HorovodTpuError(
+            f"first_expert={first_expert} with {n_local} of {n_experts} "
+            f"experts on {ranks} rank(s)")
+    # one rank, holding some of the experts
+    share = ranks == 1 and n_local < n_experts
 
-    weights, experts, counts, aux = route(x, router_w, k)
+    weights, experts, counts, aux = route(x, router_w, k, sequences)
 
     with jax.named_scope("moe.dispatch"):
+        key = experts.reshape(-1)
+        if share:
+            # held pairs first, by expert; the others behind them
+            key = key - first_expert
+            key = jnp.where((key >= 0) & (key < n_local), key, n_local)
         # sorted position -> pair (t*k + c), and back
-        order = jnp.argsort(experts.reshape(-1), stable=True).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
+        n_valid = None
         if ranks == 1:
-            token_of = order // k
-            rows = _take_rows(x, token_of, inverse, k)
-            sizes = counts
+            sizes, held_order = counts, order
+            if share:
+                room = held_rows(T * k, n_local, n_experts)
+                sizes = lax.slice(counts, (first_expert,),
+                                  (first_expert + n_local,))
+                start = jnp.cumsum(sizes, dtype=jnp.int32) - sizes
+                held = jnp.sum(sizes, dtype=jnp.int32)
+                # a group that crosses the buffer's end keeps what fits
+                sizes = jnp.clip(room - start, 0, sizes)
+                n_valid = jnp.minimum(held, room)
+                # the buffer's free rows, which are zero, go through the
+                # last held expert: the products' work is the buffer's,
+                # whatever the routing
+                sizes = sizes.at[-1].add(room - n_valid)
+                aux = jnp.concatenate(
+                    [aux, (held - n_valid).astype(aux.dtype)[None]])
+                held_order = order[:room]
+            token_of = held_order // k
+            rows = _take_rows(x, token_of, inverse, k, n_valid)
             # the down product is linear: weighting its input rows leaves
             # nothing after it for the backward pass to keep or recompute
             row_weights = _permute(weights.reshape(-1), order,
                                    inverse)[:, None]
+            if share:
+                row_weights = row_weights[:room]
         else:
             # (plain indexing here: this path's backward pass may scatter)
             cap = max(1, math.ceil(capacity_factor * T * k / n_experts))
@@ -223,7 +337,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
     with jax.named_scope("moe.combine"):
         if ranks == 1:
-            out = _sum_rows(ys, inverse, token_of, k)
+            out = _sum_rows(ys, inverse, token_of, k, n_valid)
         else:
             # Inverse re-shard: capacity segment s returns to rank s;
             # received expert groups stack along axis 0 in rank (= global

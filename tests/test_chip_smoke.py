@@ -60,8 +60,13 @@ def test_phase_eager_api_stacked_ranks(smoke, capsys):
 
 @one_chip
 def test_phase_flash_kernel(smoke, capsys):
-    chip_smoke.flash_kernel(smoke, shape=(1, 2, 64, 32))
+    chip_smoke.flash_kernel(smoke, shape=(1, 2, 64, 32),
+                            wide=(1, 2, 64, 48, 32))
     out = capsys.readouterr().out
+    # both shapes, each with its three kernels alone
+    assert "shape (1, 2, 64, 32) bf16" in out
+    assert "shape (1, 2, 64, 48, 32) bf16" in out
+    assert out.count(", dk/dv ") == out.count(": forward ") == 2
     # off the TPU the kernel is interpreted, and the phase says so
     assert "interpret=True, 0 tpu_custom_call" in out
     # S=64 is one block no tile divides: all of it computed, half of it used

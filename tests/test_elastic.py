@@ -369,7 +369,9 @@ def test_elastic_reset_warm_compile_cache(tmp_path):
     cold = round_time()
     assert os.listdir(str(tmp_path)), \
         "init did not wire the persistent compile cache"
-    warm = round_time()
+    # two warm rounds, the faster counts: the other tests' load on the
+    # host's cores can lengthen a round and never shortens one
+    warm = min(round_time(), round_time())
     # generous bound: warm resets measured ~10x faster; flag anything
     # that did a full recompile
     assert warm < cold * 0.6, (

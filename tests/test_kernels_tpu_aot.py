@@ -68,6 +68,26 @@ def test_flash_attention_compiles_for_v5e(monkeypatch, name, shape, x64):
     assert mosaic_signatures(txt) == want
 
 
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_flash_attention_at_unequal_widths_compiles_for_v5e(monkeypatch,
+                                                            name):
+    """`dsv2lite-1chip`'s latent attention: 192-wide queries and keys,
+    128-wide values (PR 30). The kernels take two lane tiles a row and are
+    given 32 MiB of VMEM (inside the train step the dk/dv kernel needs 17.2,
+    which `benchmark.aot_check` of the cell guards; alone it fits 16)."""
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    wide = jax.ShapeDtypeStruct(OLMOE_CELL[:3] + (192,), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(OLMOE_CELL, jnp.bfloat16)
+    want = SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
+                              (wide, wide, v), n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    assert "vmem_limit_bytes" in txt or "33554432" in txt
+
+
 def test_interpret_decision_is_shared_and_visible(monkeypatch):
     """One helper decides interpreter-vs-Mosaic for every kernel family;
     on the CPU suite it says "interpret", and flipping it flips the

@@ -155,15 +155,21 @@ def test_pipeline_stages_carry_the_auxiliary_losses(params):
     np.testing.assert_allclose(float(losses[1]), float(want), rtol=1e-5)
 
 
-def test_a_gated_mlp_without_experts_is_refused():
-    """The gated SiLU MLP exists as an expert only: no configuration has a
-    dense one yet."""
+def test_a_gated_mlp_without_experts_is_a_dense_one():
+    """PR 30: the gated SiLU MLP is no longer an expert only. Without
+    experts the block has a dense one, `tp`-sharded like the GELU MLP and
+    without its biases (tests/test_deepseek_v2.py runs it)."""
     cfg = dataclasses.replace(CFG, num_experts=0)
-    for build in (lambda: tfm.init(jax.random.PRNGKey(2), cfg),
-                  lambda: tfm.param_specs(cfg),
-                  lambda: tfm.grad_reduce_axes(cfg)):
-        with pytest.raises(HorovodTpuError, match="swiglu"):
-            build()
+    layers = tfm.init(jax.random.PRNGKey(2), cfg)["layers"]
+    mlp = {"w_gate", "w1", "w2"}
+    assert mlp <= set(layers)
+    assert not set(layers) & {"b1", "b2", "router", "we1", "we2", "we_gate"}
+    assert layers["w_gate"].shape == layers["w1"].shape == \
+        (cfg.n_layers, cfg.d_model, cfg.d_ff)
+    for tree in (tfm.param_specs(cfg), tfm.grad_reduce_axes(cfg)):
+        assert set(tree["layers"]) == set(layers)
+    assert tfm.param_specs(cfg)["layers"]["w_gate"] == \
+        tfm.param_specs(cfg)["layers"]["w1"]
 
 
 # ------------------------------------------------------------ routing
@@ -380,8 +386,8 @@ def test_the_loss_limit_refuses_a_planted_fault(params, fault, monkeypatch):
         return
     sound = moe.route
 
-    def faulty(x, router_w, top_k):
-        weights, *rest = sound(x, router_w, top_k)
+    def faulty(x, router_w, top_k, *sequences):
+        weights, *rest = sound(x, router_w, top_k, *sequences)
         return (change(weights), *rest)
 
     monkeypatch.setattr(moe, "route", faulty)
