@@ -37,10 +37,12 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
+from benchmark.harness import peaks
 from horovod_tpu import native
 from horovod_tpu.core import topology
 from horovod_tpu.models import resnet, transformer as tfm
 from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              flash_attention)
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
@@ -213,6 +215,23 @@ def eager_api(log: CompileLog, n: int = 1 << 20) -> None:
         f"({k} rank(s), {n} elements)")
 
 
+def _best_ms(runs: dict, repeats: int = 10) -> dict:
+    """name -> milliseconds an execution of `fn(*args)` takes, the best of
+    three batches of `repeats`, for `runs` name -> (fn, args)."""
+    best = {}
+    for name, (fn, args) in runs.items():
+        jax.block_until_ready(fn(*args))
+        batches = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(repeats):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            batches.append((time.perf_counter() - t0) / repeats)
+        best[name] = min(batches) * 1e3
+    return best
+
+
 def _kernels_alone_ms(q, k, v, repeats: int = 10) -> dict:
     """Milliseconds an execution of each flash kernel takes alone (best of
     three batches of `repeats`), causal, at q, k: (B, H, S, dqk) and
@@ -233,18 +252,7 @@ def _kernels_alone_ms(q, k, v, repeats: int = 10) -> dict:
     runs = {"forward": (fwd, flat),
             "dq": (jax.jit(lambda *a: bwd(*a)[0]), (*flat, o, lse, o)),
             "dk/dv": (jax.jit(lambda *a: bwd(*a)[1:]), (*flat, o, lse, o))}
-    best = {}
-    for name, (fn, args) in runs.items():
-        jax.block_until_ready(fn(*args))
-        batches = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                out = fn(*args)
-            jax.block_until_ready(out)
-            batches.append((time.perf_counter() - t0) / repeats)
-        best[name] = min(batches) * 1e3
-    return best
+    return _best_ms(runs, repeats)
 
 
 def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128),
@@ -318,6 +326,86 @@ def _flash_against_reference(widths) -> None:
         "in the compiled program; the kernels alone (information only), "
         "ms an execution: "
         + ", ".join(f"{name} {ms:.3f}" for name, ms in alone.items()))
+
+
+def grouped_kernel(log: CompileLog,
+                   shapes=((65536, 2048, 1024, 64, 65536),
+                           (12288, 2048, 1408, 8, 6144))) -> None:
+    """The grouped matmul (ops/grouped_matmul.py) against `lax.ragged_dot`
+    and its `jax.vjp`, then its three products alone, at the expert cells'
+    (rows, D, F, experts, rows routed): `olmoe-1chip`'s 64 experts over
+    every pair, `dsv2lite-1chip`'s held eighth in its row buffer, the free
+    rows zero and in the last group. Uneven seeded group sizes."""
+    for n_rows, d, f, experts, routed in shapes:
+        rng = np.random.default_rng(0)
+        sizes = rng.multinomial(routed, rng.dirichlet(np.full(experts, 8.0)))
+        sizes[-1] += n_rows - routed
+        ks = jax.random.split(jax.random.PRNGKey(0), 3)
+        rows = jax.random.normal(ks[0], (n_rows, d), jnp.bfloat16)
+        rows = rows * (jnp.arange(n_rows) < routed)[:, None].astype(rows.dtype)
+        weights = jax.random.normal(ks[1], (experts, d, f),
+                                    jnp.bfloat16) * d ** -0.5
+        cot = jax.random.normal(ks[2], (n_rows, f), jnp.bfloat16)
+        group_sizes = jnp.asarray(sizes, jnp.int32)
+
+        def three(product):
+            def run(rows, weights, cot, group_sizes):
+                out, vjp = jax.vjp(
+                    lambda r, w: product(r, w, group_sizes), rows, weights)
+                return (out,) + vjp(cot)
+            return jax.jit(run)
+
+        mine = three(gm.grouped_matmul)
+        n_kernels = mine.lower(rows, weights, cot, group_sizes).compile(
+            ).as_text().count("tpu_custom_call")
+        if on_tpu() and n_kernels != 3:
+            raise AssertionError(
+                f"compiled grouped matmul and its backward pass hold "
+                f"{n_kernels} Mosaic custom calls, expected three")
+        got = mine(rows, weights, cot, group_sizes)
+        want = three(jax.lax.ragged_dot)(rows, weights, cot, group_sizes)
+        worst = 0.0
+        for name, g, w in zip(("rows x weights", "towards the rows",
+                               "towards the weights"), got, want):
+            g, w = (np.asarray(x.astype(jnp.float32)) for x in (g, w))
+            if g.shape != w.shape or not np.all(np.isfinite(g)):
+                raise AssertionError(
+                    f"grouped matmul {name}: bad shape or non-finite")
+            err = float(np.max(np.abs(g - w)) / np.max(np.abs(w)))
+            worst = max(worst, err)
+            if err > BF16_RTOL:
+                raise AssertionError(
+                    f"grouped matmul {name} differs from lax.ragged_dot by "
+                    f"{err:.3g} of its range (tolerance {BF16_RTOL:.3g})")
+        plan = gm.visits(group_sizes, n_rows)
+        alone = _best_ms({
+            "rows x weights": (jax.jit(
+                lambda r, w, c, p: gm._rows_product(r, w, p, False)),
+                (rows, weights, cot, plan)),
+            "towards the rows": (jax.jit(
+                lambda r, w, c, p: gm._rows_product(c, w, p, True)),
+                (rows, weights, cot, plan)),
+            "towards the weights": (jax.jit(
+                lambda r, w, c, p: gm._weights_product(
+                    r, c, p, w.shape[0])),
+                (rows, weights, cot, plan))})
+        # the share is of the benchmark's table of peaks: none off the TPU
+        least_ms = 2e3 * n_rows * d * f / peaks.for_kind(
+            jax.devices()[0].device_kind).bf16_flops if on_tpu() else None
+        say(f"[grouped matmul] {n_rows} rows x ({experts}, {d}, {f}) bf16, "
+            f"{routed} rows routed: the three products agree with "
+            f"lax.ragged_dot and its vjp (worst {worst:.2e} of range); "
+            f"visit_share {gm.visit_share(sizes.tolist()):.4f} (tile visits "
+            "over row tiles), strip_share "
+            f"{gm.strip_share(sizes.tolist()):.4f} (strips multiplied over "
+            f"the rows' strips); interpret={_pallas.interpret()}, "
+            f"{n_kernels} tpu_custom_call in the compiled program; the "
+            "kernels alone (information only), ms an execution and share "
+            "of the bf16 peak for all the rows: "
+            + ", ".join(
+                f"{name} {ms:.3f}" + (f" ({100 * least_ms / ms:.1f}%)"
+                                      if least_ms else "")
+                for name, ms in alone.items()))
 
 
 def lm_steps(log: CompileLog, name: str, cfg, batch: int, seq: int,
@@ -534,7 +622,8 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: `hvd.init()`). With --chips 4 only what exists across chips runs, and what
 #: it is compared with.
 PHASES = {
-    1: ((), (eager_api, flash_kernel, flagship_lm, resnet50_eager)),
+    1: ((), (eager_api, flash_kernel, grouped_kernel, flagship_lm,
+             resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }
 

@@ -16,10 +16,11 @@ layer's result (`models/transformer.py`, scope `moe.shared`).
 
 On one rank of the expert axis (`ep` = 1) routing is dropless and
 static-shaped: the T*k (token, expert) pairs are sorted by expert, their rows
-gathered once, the experts applied as grouped matmuls (`lax.ragged_dot`, which
-the TPU compiler lowers to its own Mosaic kernel) over the sorted rows with
-the E group sizes, and the results gathered back and summed over k. No token
-is dropped however uneven the load, and `capacity_factor` means nothing.
+gathered once, the experts applied as grouped matmuls (the Pallas kernels of
+`ops/grouped_matmul.py`, the only grouped matmul there is) over the sorted
+rows with the E group sizes, and the results gathered back and summed over k.
+No token is dropped however uneven the load, and `capacity_factor` means
+nothing.
 
 Where the weights multiply. The down product is linear, so `sum_c p_c *
 (h_c W_down) = sum_c (p_c h_c) W_down`. On one rank the weights, in sorted
@@ -91,6 +92,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.common.exceptions import HorovodTpuError
+from horovod_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, visits
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0):
@@ -202,16 +204,12 @@ def _permute_bwd(indices, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-#: rows a grouped matmul's kernel takes at a time: the row buffer of a share
-#: of the experts is a multiple of it
-ROW_TILE = 512
-
-
 def held_rows(pairs: int, n_local: int, n_experts: int) -> int:
     """Rows of the buffer that one rank holding `n_local` of `n_experts`
     experts sorts its held (token, expert) pairs into, of `pairs` routed:
     twice what an even routing sends it, in whole row tiles, and never more
-    than all the pairs."""
+    than all the pairs. (`ROW_TILE`: the rows a grouped matmul's kernel takes
+    at a time.)"""
     even = pairs * n_local / n_experts
     return min(pairs, math.ceil(2 * even / ROW_TILE) * ROW_TILE)
 
@@ -221,19 +219,20 @@ def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
     an ungated expert). `row_weights` (rows, 1) float32, where given, scale
     each row's hidden activations, in float32 from the products' results to
     the one rounding the down product's input has either way. Every row
-    lies in a group (a grouped matmul says nothing of the others'
-    results)."""
+    lies in a group. The kernels' tile visits are made here once, for the
+    products and their backward passes alike."""
     with jax.named_scope("moe.experts"):
         wide = rows.dtype if row_weights is None else jnp.float32
-        hidden = lax.ragged_dot(rows, w_up, group_sizes).astype(wide)
+        plan = visits(group_sizes, rows.shape[0])
+        hidden = grouped_matmul(rows, w_up, plan).astype(wide)
         if w_gate is None:
             hidden = jax.nn.gelu(hidden)
         else:
-            gate = lax.ragged_dot(rows, w_gate, group_sizes).astype(wide)
+            gate = grouped_matmul(rows, w_gate, plan).astype(wide)
             hidden = jax.nn.silu(gate) * hidden
         if row_weights is not None:
             hidden = (hidden * row_weights).astype(rows.dtype)
-        return lax.ragged_dot(hidden, w_down, group_sizes)
+        return grouped_matmul(hidden, w_down, plan)
 
 
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,

@@ -74,6 +74,20 @@ def test_phase_flash_kernel(smoke, capsys):
 
 
 @one_chip
+def test_phase_grouped_kernel(smoke, capsys):
+    chip_smoke.grouped_kernel(smoke, shapes=((96, 16, 24, 4, 96),
+                                             (64, 16, 24, 2, 30)))
+    out = capsys.readouterr().out
+    assert "96 rows x (4, 16, 24) bf16, 96 rows routed" in out
+    assert "64 rows x (2, 16, 24) bf16, 30 rows routed" in out
+    assert out.count("agree with lax.ragged_dot and its vjp") == 2
+    assert out.count("towards the weights ") == 2
+    # fewer rows than a row tile are one tile, visited once a group
+    assert "visit_share 4.0000" in out and "visit_share 2.0000" in out
+    assert "interpret=True, 0 tpu_custom_call" in out
+
+
+@one_chip
 def test_phase_flagship_lm(smoke, capsys):
     chip_smoke.flagship_lm(smoke, cfg=TINY_LM, batch=4, seq=64, steps=5)
     out = capsys.readouterr().out

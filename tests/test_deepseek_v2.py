@@ -20,6 +20,7 @@ from benchmark.reference import deepseek_v2 as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops.grouped_matmul import ROW_TILE
 from horovod_tpu.parallel import MeshSpec, build_mesh, moe, moe_ffn
 from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 
@@ -508,8 +509,8 @@ def test_the_row_buffer_is_bounded_and_counts_what_it_leaves_out():
 @pytest.mark.parametrize("to_held", [None, 0.0, 30.0],
                          ids=["as-routed", "none-held", "all-held"])
 def test_the_grouped_products_take_the_whole_buffer(to_held, monkeypatch):
-    """A step's work does not follow the routing: the group sizes of every
-    grouped matmul of a share add up to the row buffer, the free rows in the
+    """A step's work does not follow the routing: the rows that every
+    grouped matmul of a share multiplies add up to the row buffer, the free rows in the
     last held expert's group, and what they add is nothing (the result is
     the reference's, the gradients are finite and the free rows' are 0)."""
     x = jax.random.normal(jax.random.PRNGKey(3), (512, 8), jnp.float32)
@@ -523,13 +524,22 @@ def test_the_grouped_products_take_the_whole_buffer(to_held, monkeypatch):
     room = moe.held_rows(1024, 2, 8)
     assert room == 512
     seen = []
-    ragged_dot = jax.lax.ragged_dot
+    grouped_matmul = moe.grouped_matmul
 
-    def recording(a, w, group_sizes, **kw):
-        jax.debug.callback(lambda g: seen.append(np.asarray(g)), group_sizes)
-        return ragged_dot(a, w, group_sizes, **kw)
+    def recording(rows, w, plan):
+        edge = min(ROW_TILE, rows.shape[0])
 
-    monkeypatch.setattr(jax.lax, "ragged_dot", recording)
+        def rows_multiplied(first, end, tile, count):
+            # every visit made multiplies its group's rows in its row tile
+            own = np.clip(np.minimum(end, (tile + 1) * edge)
+                          - np.maximum(first, tile * edge), 0, None)
+            seen.append(int(own[:int(count[0])].sum()))
+
+        jax.debug.callback(rows_multiplied, plan.first_row, plan.end_row,
+                           plan.tile, plan.count)
+        return grouped_matmul(rows, w, plan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", recording)
 
     def loss(xx, u, d, g):
         out, aux, _ = _run(xx, router, u, d, g, 2, first=2, whole=True)
@@ -539,7 +549,7 @@ def test_the_grouped_products_take_the_whole_buffer(to_held, monkeypatch):
                                                 has_aux=True)(
         x, up[2:4], down[2:4], gate[2:4])
     jax.effects_barrier()
-    assert len(seen) >= 3 and all(int(g.sum()) == room for g in seen)
+    assert len(seen) >= 3 and all(n == room for n in seen)
     held = {None: None, 0.0: 0, 30.0: 1024}[to_held]
     if held is not None:
         assert float(aux[2]) == max(0, held - room)
@@ -598,8 +608,8 @@ def test_a_step_that_leaves_a_held_pair_out_counts_it(monkeypatch):
 def test_two_ranks_with_every_expert_held_equal_the_parent_bit_for_bit():
     """`ep` = 2, full coverage: outputs, auxiliary terms, routes and every
     gradient equal what the parent of PR 30 (commit 2baf953) gave for the
-    same seeds on this CPU mesh, in float32 and in bf16
-    (tests/fixtures/moe_ep2_parent_pr29.npz)."""
+    same seeds on this CPU mesh, in bf16, and in float32 but for the order
+    its grouped matmul added in (tests/fixtures/moe_ep2_parent_pr29.npz)."""
     golden = np.load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                   "fixtures", "moe_ep2_parent_pr29.npz"))
 
@@ -633,10 +643,16 @@ def test_two_ranks_with_every_expert_held_equal_the_parent_bit_for_bit():
         args = (x, router, up, down, gate)
         return (*jax.jit(sharded)(*args), *jax.jit(jax.grad(loss))(args))
 
-    for name, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
-        for i, got in enumerate(run(dtype)):
-            assert np.array_equal(np.asarray(got.astype(jnp.float32)),
-                                  golden[f"{name}_{i}"]), (name, i)
+    for i, got in enumerate(run(jnp.bfloat16)):
+        assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                              golden[f"bf16_{i}"]), i
+    # float32: the parent's grouped matmul was `lax.ragged_dot`, the
+    # kernels of ops/grouped_matmul.py add in another order (4e-7 here)
+    for i, got in enumerate(run(jnp.float32)):
+        want = golden[f"f32_{i}"]
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                                   atol=2e-6 * np.abs(want).max(),
+                                   err_msg=str(i))
 
 
 # ------------------------------------------------------------- the limits

@@ -1,4 +1,5 @@
-"""Flash attention compiled by the chip's own compiler, without a chip.
+"""Flash attention and the grouped matmul compiled by the chip's own
+compiler, without a chip.
 
 The flagship LM's default attention is the Pallas flash kernel
 (ops/flash_attention.py). The interpreter runs of
@@ -13,8 +14,12 @@ long-context S=8192 — that the three custom calls keep the operands and
 results the benchmark's readers know them by, and that `jax_enable_x64`
 (which this suite's conftest turns on) does not matter to a kernel
 compiled for the chip.
-About two seconds each; skipped only where the topology cannot be
-described (tests/tpu_probe.py).
+The expert layer's grouped matmuls (ops/grouped_matmul.py) likewise: the
+three products at the two expert cells' shapes, each custom call with the
+operands and the result `benchmark/harness/scopes.py` tells a grouped
+matmul by.
+About two seconds each (the grouped kernels five); skipped only where the
+topology cannot be described (tests/tpu_probe.py).
 """
 
 import jax
@@ -22,6 +27,7 @@ import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.grouped_matmul import grouped_matmul
 
 FLAGSHIP = (12, 16, 1024, 128)   # chip_smoke.py flagship LM
 LM_CELLS = (4, 16, 2048, 128)    # lm-1chip, lm-dp4 per chip
@@ -88,14 +94,60 @@ def test_flash_attention_at_unequal_widths_compiles_for_v5e(monkeypatch,
     assert "vmem_limit_bytes" in txt or "33554432" in txt
 
 
+#: (rows, D, F, experts) of the expert cells: all 64 experts and every
+#: (token, expert) pair; the held eighth and its row buffer
+OLMOE_EXPERTS = (65536, 2048, 1024, 64)      # olmoe-1chip
+DSV2LITE_EXPERTS = (12288, 2048, 1408, 8)    # dsv2lite-1chip
+
+#: (operands, results) of a grouped matmul's custom call: the five int32
+#: arrays of `grouped_matmul.visits`, the two matrices; one array. The
+#: contract with `benchmark/harness/scopes.py` `GROUPED_MATMUL`, whose
+#: readers sum the kernels' time and count their executions by it.
+GROUPED_SIGNATURE = (7, 1)
+
+
+def _grouped_fwd(rows, weights, sizes):
+    return grouped_matmul(rows, weights, sizes)
+
+
+def _grouped_bwd(rows, weights, sizes):
+    return jax.grad(
+        lambda r, w: grouped_matmul(r, w, sizes).astype(jnp.float32).sum(),
+        argnums=(0, 1))(rows, weights)
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("shape", [OLMOE_EXPERTS, DSV2LITE_EXPERTS],
+                         ids=["olmoe-1chip", "dsv2lite-1chip"])
+def test_grouped_matmul_compiles_for_v5e(monkeypatch, shape, product):
+    """The forward kernel, and the two of the backward pass (towards the
+    rows, with the weights read transposed; towards the weights), of the up
+    and gate products (D -> F) and of the down product (F -> D). DeepSeek's
+    F = 1,408 is eleven lane tiles and is taken whole."""
+    from benchmark.harness import scopes
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    assert GROUPED_SIGNATURE == scopes.GROUPED_MATMUL
+    topo = tpu_topology(monkeypatch)
+    n_rows, d, f, experts = shape
+    k, n = (d, f) if product == "up" else (f, d)
+    avals = (jax.ShapeDtypeStruct((n_rows, k), jnp.bfloat16),
+             jax.ShapeDtypeStruct((experts, k, n), jnp.bfloat16),
+             jax.ShapeDtypeStruct((experts,), jnp.int32))
+    for fn, calls in ((_grouped_fwd, 1), (_grouped_bwd, 2)):
+        txt = compile_kernel_text(topo, fn, avals, n_calls=calls)
+        assert mosaic_signatures(txt) == [GROUPED_SIGNATURE] * calls
+
+
 def test_interpret_decision_is_shared_and_visible(monkeypatch):
     """One helper decides interpreter-vs-Mosaic for every kernel family;
     on the CPU suite it says "interpret", and flipping it flips the
     flash and the conv kernels together (what tpu_probe relies on)."""
     from horovod_tpu.ops import (_pallas, conv_block, conv_bn_backward,
-                                 flash_attention as fa)
+                                 flash_attention as fa, grouped_matmul as gm)
 
     assert _pallas.interpret() is True
-    for mod in (fa, conv_block, conv_bn_backward):
+    for mod in (fa, conv_block, conv_bn_backward, gm):
         assert mod.pallas_call is _pallas.pallas_call
         assert not hasattr(mod, "_interpret")

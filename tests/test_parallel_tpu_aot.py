@@ -74,24 +74,31 @@ def test_pp_ep_moe_train_step_lowers_on_tpu():
     (dict(experts_per_token=2, mlp="swiglu", norm="rmsnorm", positions="rope",
           qk_norm=True, load_balance_coef=0.01, router_z_coef=0.001), 11),
     (dict(experts_per_token=1), 7)], ids=["olmoe-top2-swiglu", "top1-gelu"])
-def test_olmoe_block_train_step_lowers_on_tpu(experts, kernels):
+def test_olmoe_block_train_step_lowers_on_tpu(experts, kernels, monkeypatch):
     """An expert block on one rank (dropless routing), bf16 with remat: the
-    compiler turns `lax.ragged_dot` into grouped-matmul kernels of its own,
-    and the four parts of the expert layer keep their scopes in the compiled
-    text. A gated expert has eleven a block: three forward, two under remat
-    (gate and up, for the hidden rows the backward pass needs) and six
-    backward. The down product is not among the replayed ones: the router
-    weights multiply its input rows, so nothing after it is a residual
-    (twelve until PR 29, when they multiplied its gathered result). An
-    ungated top-1 expert has seven: two forward, one under remat, four
-    backward."""
-    import re
+    grouped matmuls are the Mosaic kernels of ops/grouped_matmul.py, told as
+    the benchmark's readers tell them (benchmark/harness/scopes.py): custom
+    calls of seven operands, five of metadata and two matrices, and one
+    result. The four parts of the expert layer keep their scopes in the
+    compiled text. A gated expert has eleven a block: three forward, two
+    under remat (gate and up, for the hidden rows the backward pass needs)
+    and six backward. The down product is not among the replayed ones: the
+    router weights multiply its input rows, so nothing after it is a
+    residual (twelve until PR 29, when they multiplied its gathered result).
+    An ungated top-1 expert has seven: two forward, one under remat, four
+    backward. (Until PR 31 they were the kernels the compiler made of
+    `lax.ragged_dot`, with a metadata kernel beside each.)"""
+    from benchmark.harness import hlo, scopes
+    from horovod_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
     cfg = tfm.TransformerConfig(
         vocab=512, d_model=256, n_heads=2, d_ff=128, n_layers=2, max_seq=256,
         num_experts=8, attn="local", dtype=jnp.bfloat16, remat=True,
         **experts)
     txt = _compile(MeshSpec(), cfg, seq=256, batch=2)
-    assert len(re.findall(r'op_name="ragged-dot-none"', txt)) == kernels
+    found = scopes.grouped_kernels(hlo.index(txt))
+    assert list(found.values()) == [scopes.GROUPED_MATMUL] * kernels
+    assert txt.count('custom_call_target="tpu_custom_call"') == kernels
     for part in ("route", "dispatch", "experts", "combine"):
         assert f"/moe.{part}/" in txt, part
     assert "all-to-all" not in txt and "all-reduce" not in txt
