@@ -37,11 +37,14 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
+from benchmark.families import olmo_hybrid
 from benchmark.harness import peaks
+from benchmark.layer_metrics import gdn_scan_roofline
 from horovod_tpu import native
 from horovod_tpu.core import topology
 from horovod_tpu.models import resnet, transformer as tfm
 from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import gated_delta as gd
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              flash_attention)
@@ -408,6 +411,74 @@ def grouped_kernel(log: CompileLog,
                 for name, ms in alone.items()))
 
 
+def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
+                     checked=256) -> None:
+    """The chunked gated delta rule (ops/gated_delta.py) alone at
+    `olmohybrid-1chip`'s (batch, heads, tokens, key width, value width):
+    its first `checked` tokens against the token-by-token recurrence, then
+    forward and forward + backward times against the least time the
+    benchmark's `gdn_scan_roofline` counts (the family's `rule_work`)."""
+    batch, heads, seq, dk, dv = shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    bf16 = jnp.bfloat16
+
+    def unit(x):
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    q = (unit(jax.random.normal(ks[0], (batch, heads, seq, dk)))
+         * dk ** -0.5).astype(bf16)
+    k = unit(jax.random.normal(ks[1], (batch, heads, seq, dk))).astype(bf16)
+    v = jax.random.normal(ks[2], (batch, heads, seq, dv), bf16)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[3], (batch, heads, seq)))
+    # decays as the model's initialisation draws them: A ~ U(0, 16) a head,
+    # a step of 0.001 to 0.1 a token
+    rate = jax.random.uniform(ks[4], (1, heads, 1), minval=1e-3, maxval=16.0)
+    g = -rate * jnp.exp(jax.random.uniform(
+        ks[5], (batch, heads, seq), minval=np.log(1e-3), maxval=np.log(0.1)))
+    args = (q, k, v, g, beta)
+    head = tuple(x[:, :, :checked] for x in args)
+    got = np.asarray(jax.jit(gd.gated_delta_rule)(*head).astype(jnp.float32))
+    want = np.asarray(jax.jit(gd.recurrent_gated_delta_rule)(
+        *(x.astype(jnp.float32) for x in head)))
+    if not np.all(np.isfinite(got)):
+        raise AssertionError("gated delta rule: non-finite output")
+    err = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+    if err > BF16_RTOL:
+        raise AssertionError(
+            f"the chunked gated delta rule is {err:.3g} of its rms from "
+            f"the recurrence over {checked} tokens (tolerance "
+            f"{BF16_RTOL:.3g})")
+    cot = jax.random.normal(ks[0], v.shape, bf16)
+
+    def both(*args):
+        out, vjp = jax.vjp(gd.gated_delta_rule, *args)
+        return (out,) + vjp(cot)
+
+    ms = _best_ms({"forward": (jax.jit(gd.gated_delta_rule), args),
+                   "forward + backward": (jax.jit(both), args)}, repeats=5)
+    shares = ""
+    if on_tpu():   # the shares are of the benchmark's table of peaks
+        p = peaks.for_kind(jax.devices()[0].device_kind)
+        fwd, bwd = (gdn_scan_roofline.least_seconds((1, *work), p)[0] * 1e3
+                    for work in olmo_hybrid.rule_work(batch * seq * heads,
+                                                      dk, dv))
+        shares = (f"; least time forward {fwd:.3f} ms "
+                  f"({100 * fwd / ms['forward']:.2f}% of it), forward + "
+                  f"backward {fwd + bwd:.3f} ms "
+                  f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
+    say(f"[gated delta rule] {batch} x {seq} tokens x {heads} heads, "
+        f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "
+        f"{gd.chunks_of(seq)} chunks a sequence, the chunked form's "
+        "multiply-adds "
+        f"{gd.chunked_over_recurrent_macs(dk, dv):.2f} x the recurrent "
+        f"form's; the first {checked} tokens are {err:.2e} of their rms "
+        "from the token-by-token recurrence; alone (information only), ms "
+        "an execution: "
+        + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)
+
+
 def lm_steps(log: CompileLog, name: str, cfg, batch: int, seq: int,
              steps: int, spec: MeshSpec, devices) -> dict:
     """`steps` train steps of the LM over `spec` on `devices`, through
@@ -622,8 +693,8 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: `hvd.init()`). With --chips 4 only what exists across chips runs, and what
 #: it is compared with.
 PHASES = {
-    1: ((), (eager_api, flash_kernel, grouped_kernel, flagship_lm,
-             resnet50_eager)),
+    1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
+             flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }
 

@@ -28,11 +28,22 @@ of their own (`params["dense_layers"]`) in front of the expert stack
 (`params["layers"]`). No biases except the GPT-2 block's LayerNorm and MLP
 ones.
 
+A model whose layers are not all of one kind states one period of its
+`layer_pattern`, which the stack repeats: "full" layers mix tokens as
+`attention` says, "linear" layers through a gated delta rule
+(`attention="gdn"`: Gated DeltaNet, arXiv:2412.06464, by
+`ops/gated_delta.py`). With `post_norm` (each sub-layer's norm on its output,
+inside the residual) and `positions="none"` that is Olmo-Hybrid's block. The
+parameters of a patterned stack lie per kind, `params["layers"][kind][leaf]`,
+stacked over (periods, the layers of that kind in a period), and one scan
+body runs a period's layers in order.
+
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -137,9 +148,12 @@ class TransformerConfig:
     rms_norm_eps: float = 1e-5
     positions: str = "learned"    # "learned" (a table added to the
     #                               embedding) | "rope" (rotate-half pairs)
+    #                               | "none" (order comes from elsewhere:
+    #                               the causal mask, recurrent layers)
     rope_theta: float = 10000.0
     yarn: Optional[Yarn] = None   # needs positions="rope"
     # "mha": wq, wk, wv of one head width, d_model / n_heads.
+    # "gdn": see gdn_heads below.
     # "mla": DeepSeek-V2's latent attention. Queries (qk_nope_dim +
     # qk_rope_dim) a head; one down-projection to kv_latent + qk_rope_dim a
     # token, RMSNorm on the latent, an up-projection to (qk_nope_dim +
@@ -153,6 +167,24 @@ class TransformerConfig:
     # RMSNorm on the projected queries and keys, over the whole projected
     # vector (all heads), before it is split into heads and rotated
     qk_norm: bool = False
+    # attention="gdn": no attention but a gated delta rule (`_gdn`):
+    # gdn_heads heads with keys and queries gdn_key_dim wide and values
+    # gdn_value_dim, a depthwise causal convolution of gdn_conv taps on
+    # each; gdn_neg_eigval lets beta reach 2, so that a state's eigenvalue
+    # 1 - beta may be negative. Its state does not cross shards yet:
+    # sp = tp = pp = 1 (`validate_cfg_for_mesh`).
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_neg_eigval: bool = False
+    # One period of layer kinds, repeated n_layers / len(layer_pattern)
+    # times; () is a stack of one kind. "full": a layer as the other fields
+    # state it; "linear": the same layer with attention="gdn".
+    layer_pattern: Tuple[str, ...] = ()
+    # x + Norm(f(x)) in place of x + f(Norm(x)): each sub-layer's norm on
+    # its output, inside the residual (Olmo 2's arrangement)
+    post_norm: bool = False
     # "gelu" (biased when dense) | "swiglu" (gated SiLU, no biases)
     mlp: str = "gelu"
     attn: str = "ring"            # "ring" | "ulysses" | "flash" | "local"
@@ -193,6 +225,11 @@ class TransformerConfig:
 #: the stacks of layers a parameter tree may hold, in the order they run
 STACKS = ("dense_layers", "layers")
 
+#: the leaves only a gated-delta-rule layer has
+GDN_LEAVES = frozenset({
+    "gdn_wq", "gdn_wk", "gdn_wv", "gdn_wz", "gdn_wa", "gdn_wb", "gdn_a_log",
+    "gdn_dt_bias", "gdn_conv_q", "gdn_conv_k", "gdn_conv_v", "gdn_o_scale"})
+
 
 def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
     """`cfg` as the layers of `stack` see it: the leading dense layers are
@@ -204,6 +241,54 @@ def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
         d_ff=cfg.d_ff_dense, n_layers=cfg.first_k_dense, first_k_dense=0)
 
 
+#: what a layer of each kind of a `layer_pattern` changes of the configuration
+LAYER_KINDS = {"full": {}, "linear": {"attention": "gdn"}}
+
+
+def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
+    """`cfg` as a layer of `kind` in its pattern sees it."""
+    if kind not in LAYER_KINDS:
+        raise HorovodTpuError(f"layer_pattern names the kind {kind!r}: "
+                              f"choose from {sorted(LAYER_KINDS)}")
+    return dataclasses.replace(cfg, **LAYER_KINDS[kind])
+
+
+def _kinds(cfg: TransformerConfig) -> Dict[str, int]:
+    """The kinds of `cfg`'s layer pattern, each with how many layers of a
+    period are of it."""
+    return {kind: cfg.layer_pattern.count(kind)
+            for kind in sorted(set(cfg.layer_pattern))}
+
+
+def _periods(cfg: TransformerConfig) -> int:
+    depth, period = _stack_depth(cfg), len(cfg.layer_pattern)
+    if depth % period:
+        raise HorovodTpuError(
+            f"{depth} layers are no whole number of periods of the layer "
+            f"pattern {cfg.layer_pattern}")
+    return depth // period
+
+
+def _layer_groups(cfg: TransformerConfig, tree: Dict[str, Any]):
+    """Where `tree` (laid out as `init` lays the parameters out) holds its
+    layers' leaves as flat {leaf: stacked array} dicts: the path to each,
+    with how many leading axes stack the layers: 1, or 2 (periods, the
+    layers of the kind in a period) under a layer pattern."""
+    groups = {}
+    for stack in STACKS:
+        if stack == "layers" and cfg.layer_pattern:
+            groups.update({(stack, kind): 2 for kind in tree[stack]})
+        elif stack in tree:
+            groups[(stack,)] = 1
+    return groups
+
+
+def _at(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
 def _present(tree: Dict[str, Any], cfg: TransformerConfig):
     """`tree` (parameters, specs or reduce axes, laid out as `init` lays the
     parameters out, with every leaf any architecture has) without the leaves
@@ -211,12 +296,16 @@ def _present(tree: Dict[str, Any], cfg: TransformerConfig):
     absent = set()
     if cfg.norm == "rmsnorm":
         absent |= {"ln1_bias", "ln2_bias", "lnf_bias"}
-    if cfg.positions == "rope":
+    if cfg.positions != "learned":
         absent.add("pos")
     if cfg.attention == "mla":
         absent |= {"wk", "wv", "q_scale", "k_scale"}
     else:
         absent |= {"wkv_a", "kv_scale", "wkv_b"}
+    if cfg.attention == "gdn":
+        absent |= {"wq", "wk", "wv", "q_scale", "k_scale"}
+    else:
+        absent |= GDN_LEAVES
     if not cfg.qk_norm:
         absent |= {"q_scale", "k_scale"}
     if cfg.num_experts:
@@ -231,7 +320,14 @@ def _present(tree: Dict[str, Any], cfg: TransformerConfig):
         absent |= {"we_gate", "w_gate", "ws_gate"}
     if not cfg.first_k_dense:
         absent.add("dense_layers")
-    return {k: _present(v, _stack_cfg(cfg, k)) if k in STACKS else v
+    def of_stack(stack, leaves):
+        stack_cfg = _stack_cfg(cfg, stack)
+        if stack == "layers" and cfg.layer_pattern:
+            return {kind: _present(leaves[kind], _kind_cfg(stack_cfg, kind))
+                    for kind in _kinds(cfg)}
+        return _present(leaves, stack_cfg)
+
+    return {k: of_stack(k, v) if k in STACKS else v
             for k, v in tree.items() if k not in absent}
 
 
@@ -239,52 +335,81 @@ def _stack_depth(cfg: TransformerConfig) -> int:
     return cfg.n_layers - cfg.first_k_dense
 
 
-def _layer_makers(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
+def _layer_makers(key: jax.Array, cfg: TransformerConfig,
+                  lead: Optional[Tuple[int, ...]] = None) -> Dict[str, Any]:
     """One stack's layers: every leaf a layer of any architecture has, as a
-    function that makes it, stacked on a leading axis."""
-    D, H, F, L, E = (cfg.d_model, cfg.n_heads, cfg.d_ff, _stack_depth(cfg),
-                     cfg.num_experts)
+    function that makes it, stacked on the leading axes `lead` (one, the
+    stack's depth, where none are given)."""
+    D, H, F, E = cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.num_experts
+    L = (_stack_depth(cfg),) if lead is None else tuple(lead)
     dt = cfg.dtype
     if cfg.attention == "mla":
         dq, dvo = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
+    elif cfg.attention == "gdn":
+        H, dq, dvo = cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
     else:
         dq = dvo = cfg.head_dim
     held = cfg.experts_held or E
     shared = cfg.shared_experts * F
     ks = jax.random.split(key, 12)
     xs = jax.random.split(jax.random.fold_in(key, 1), 6)
+    gs = jax.random.split(jax.random.fold_in(key, 3), 11)
+    Hg, dk, dv, taps = (cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
+                        cfg.gdn_conv)
 
     def norm(k, shape, fan_in):
-        return lambda: jax.random.normal(k, shape, dt) * fan_in ** -0.5
+        return lambda: jax.random.normal(k, L + shape, dt) * fan_in ** -0.5
 
     def ones(*shape):
-        return lambda: jnp.ones(shape, dt)
+        return lambda: jnp.ones(L + shape, dt)
 
     def zeros(*shape):
-        return lambda: jnp.zeros(shape, dt)
+        return lambda: jnp.zeros(L + shape, dt)
+
+    def decay_rate():
+        # A ~ U(0, 16), held as its logarithm (Gated DeltaNet's own draw)
+        return jnp.log(jax.random.uniform(
+            gs[9], L + (Hg,), jnp.float32, 1e-3, 16.0)).astype(dt)
+
+    def step_bias():
+        # dt ~ log-U(0.001, 0.1), held as softplus^-1(dt)
+        step = jnp.exp(jax.random.uniform(
+            gs[10], L + (Hg,), jnp.float32, math.log(1e-3), math.log(0.1)))
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
 
     return {
-        "ln1_scale": ones(L, D), "ln1_bias": zeros(L, D),
-        "wq": norm(ks[0], (L, D, H, dq), D),
-        "wk": norm(ks[1], (L, D, H, dq), D),
-        "wv": norm(ks[2], (L, D, H, dvo), D),
-        "wkv_a": norm(xs[0], (L, D, cfg.kv_latent + cfg.qk_rope_dim), D),
-        "kv_scale": ones(L, cfg.kv_latent),
-        "wkv_b": norm(xs[1], (L, cfg.kv_latent, H, cfg.qk_nope_dim + dvo),
+        "ln1_scale": ones(D), "ln1_bias": zeros(D),
+        "wq": norm(ks[0], (D, H, dq), D),
+        "wk": norm(ks[1], (D, H, dq), D),
+        "wv": norm(ks[2], (D, H, dvo), D),
+        "wkv_a": norm(xs[0], (D, cfg.kv_latent + cfg.qk_rope_dim), D),
+        "kv_scale": ones(cfg.kv_latent),
+        "wkv_b": norm(xs[1], (cfg.kv_latent, H, cfg.qk_nope_dim + dvo),
                       cfg.kv_latent),
-        "wo": norm(ks[3], (L, H, dvo, D), H * dvo),
-        "q_scale": ones(L, H, dq), "k_scale": ones(L, H, dq),
-        "ln2_scale": ones(L, D), "ln2_bias": zeros(L, D),
-        "router": norm(ks[4], (L, D, E), D),
-        "we1": norm(ks[5], (L, held, D, F), D),
-        "we2": norm(ks[6], (L, held, F, D), F),
-        "we_gate": norm(ks[10], (L, held, D, F), D),
-        "ws1": norm(xs[3], (L, D, shared), D),
-        "ws2": norm(xs[4], (L, shared, D), shared),
-        "ws_gate": norm(xs[5], (L, D, shared), D),
-        "w1": norm(ks[4], (L, D, F), D), "b1": zeros(L, F),
-        "w2": norm(ks[5], (L, F, D), F), "b2": zeros(L, D),
-        "w_gate": norm(xs[2], (L, D, F), D),
+        "gdn_wq": norm(gs[0], (D, Hg, dk), D),
+        "gdn_wk": norm(gs[1], (D, Hg, dk), D),
+        "gdn_wv": norm(gs[2], (D, Hg, dv), D),
+        "gdn_wz": norm(gs[3], (D, Hg, dv), D),
+        "gdn_wa": norm(gs[4], (D, Hg), D),
+        "gdn_wb": norm(gs[5], (D, Hg), D),
+        "gdn_a_log": decay_rate, "gdn_dt_bias": step_bias,
+        "gdn_conv_q": norm(gs[6], (Hg, dk, taps), taps),
+        "gdn_conv_k": norm(gs[7], (Hg, dk, taps), taps),
+        "gdn_conv_v": norm(gs[8], (Hg, dv, taps), taps),
+        "gdn_o_scale": ones(dv),
+        "wo": norm(ks[3], (H, dvo, D), H * dvo),
+        "q_scale": ones(H, dq), "k_scale": ones(H, dq),
+        "ln2_scale": ones(D), "ln2_bias": zeros(D),
+        "router": norm(ks[4], (D, E), D),
+        "we1": norm(ks[5], (held, D, F), D),
+        "we2": norm(ks[6], (held, F, D), F),
+        "we_gate": norm(ks[10], (held, D, F), D),
+        "ws1": norm(xs[3], (D, shared), D),
+        "ws2": norm(xs[4], (shared, D), shared),
+        "ws_gate": norm(xs[5], (D, shared), D),
+        "w1": norm(ks[4], (D, F), D), "b1": zeros(F),
+        "w2": norm(ks[5], (F, D), F), "b2": zeros(D),
+        "w_gate": norm(xs[2], (D, F), D),
     }
 
 
@@ -297,13 +422,21 @@ def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     def norm(k, shape):
         return jax.random.normal(k, shape, dt)
 
+    def layers():
+        if not cfg.layer_pattern:
+            return _layer_makers(key, cfg)
+        # each kind's layers, stacked over (periods, its layers in a period)
+        return {kind: _layer_makers(jax.random.fold_in(key, 4 + i),
+                                    _kind_cfg(cfg, kind), (_periods(cfg), n))
+                for i, (kind, n) in enumerate(_kinds(cfg).items())}
+
     # every leaf any architecture has, each made only if this one has it
     make = {
         "embed": lambda: norm(ks[7], (V, D)) * 0.02 * D ** 0.5,
         "pos": lambda: norm(ks[8], (cfg.max_seq, D)) * 0.02,
         "dense_layers": _layer_makers(jax.random.fold_in(key, 2),
                                       _stack_cfg(cfg, "dense_layers")),
-        "layers": _layer_makers(key, cfg),
+        "layers": layers(),
         "lnf_scale": lambda: jnp.ones((D,), dt),
         "lnf_bias": lambda: jnp.zeros((D,), dt),
         "unembed": lambda: norm(ks[9], (D, V)) * D ** -0.5,
@@ -311,41 +444,63 @@ def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     return jax.tree_util.tree_map(lambda f: f(), _present(make, cfg))
 
 
-def _layer_specs(lead: Optional[str]) -> Dict[str, Any]:
-    """PartitionSpecs of one stack's leaves, its leading (layer) axis over
-    the mesh axis `lead`."""
+def _layer_specs(*lead: Optional[str]) -> Dict[str, Any]:
+    """PartitionSpecs of one stack's leaves, its leading (layer) axes over
+    the mesh axes `lead`."""
+    def spec(*rest):
+        return P(*lead, *rest)
+
     return {
-        "ln1_scale": P(lead, None), "ln1_bias": P(lead, None),
-        "wq": P(lead, None, "tp", None),
-        "wk": P(lead, None, "tp", None),
-        "wv": P(lead, None, "tp", None),
+        "ln1_scale": spec(None), "ln1_bias": spec(None),
+        "wq": spec(None, "tp", None),
+        "wk": spec(None, "tp", None),
+        "wv": spec(None, "tp", None),
         # the latent's down-projection and norm belong to no head: they are
         # replicated over tp as the router is
-        "wkv_a": P(lead, None, None), "kv_scale": P(lead, None),
-        "wkv_b": P(lead, None, "tp", None),
-        "wo": P(lead, "tp", None, None),
-        "q_scale": P(lead, "tp", None), "k_scale": P(lead, "tp", None),
-        "ln2_scale": P(lead, None), "ln2_bias": P(lead, None),
-        "router": P(lead, None, None),
-        "we1": P(lead, "ep", None, None),
-        "we2": P(lead, "ep", None, None),
-        "we_gate": P(lead, "ep", None, None),
-        "ws1": P(lead, None, "tp"), "ws2": P(lead, "tp", None),
-        "ws_gate": P(lead, None, "tp"),
-        "w1": P(lead, None, "tp"), "b1": P(lead, "tp"),
-        "w2": P(lead, "tp", None), "b2": P(lead, None),
-        "w_gate": P(lead, None, "tp"),
+        "wkv_a": spec(None, None), "kv_scale": spec(None),
+        "wkv_b": spec(None, "tp", None),
+        # a gated-delta-rule layer's own leaves are whole on every rank
+        # (`validate_cfg_for_mesh` refuses tp > 1 with such a layer)
+        "gdn_wq": spec(None, None, None), "gdn_wk": spec(None, None, None),
+        "gdn_wv": spec(None, None, None), "gdn_wz": spec(None, None, None),
+        "gdn_wa": spec(None, None), "gdn_wb": spec(None, None),
+        "gdn_a_log": spec(None), "gdn_dt_bias": spec(None),
+        "gdn_conv_q": spec(None, None, None),
+        "gdn_conv_k": spec(None, None, None),
+        "gdn_conv_v": spec(None, None, None), "gdn_o_scale": spec(None),
+        "wo": spec("tp", None, None),
+        "q_scale": spec("tp", None), "k_scale": spec("tp", None),
+        "ln2_scale": spec(None), "ln2_bias": spec(None),
+        "router": spec(None, None),
+        "we1": spec("ep", None, None),
+        "we2": spec("ep", None, None),
+        "we_gate": spec("ep", None, None),
+        "ws1": spec(None, "tp"), "ws2": spec("tp", None),
+        "ws_gate": spec(None, "tp"),
+        "w1": spec(None, "tp"), "b1": spec("tp"),
+        "w2": spec("tp", None), "b2": spec(None),
+        "w_gate": spec(None, "tp"),
     }
+
+
+def _per_kind(cfg: TransformerConfig, leaves):
+    """A patterned stack's tree: `leaves()` under each kind of the pattern;
+    `leaves()` itself for a stack of one kind."""
+    if not cfg.layer_pattern:
+        return leaves()
+    return {kind: leaves() for kind in _kinds(cfg)}
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec tree matching init()'s structure (in_specs for
     shard_map; also the NamedSharding layout for device_put). The leading
     dense layers lie on every pipeline stage (`validate_cfg_for_mesh`
-    refuses pp > 1 with them)."""
+    refuses pp > 1 with them); of a patterned stack the periods would lie
+    over the stages."""
+    lead = ("pp", None) if cfg.layer_pattern else ("pp",)
     return _present({
         "embed": P(), "pos": P(), "dense_layers": _layer_specs(None),
-        "layers": _layer_specs("pp"),
+        "layers": _per_kind(cfg, lambda: _layer_specs(*lead)),
         "lnf_scale": P(), "lnf_bias": P(), "unembed": P(),
     }, cfg)
 
@@ -369,6 +524,7 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
           "ln2_scale": data_axes, "ln2_bias": data_axes,
           "wq": tp_sharded, "wk": tp_sharded, "wv": tp_sharded,
           "wkv_a": data_axes, "kv_scale": data_axes, "wkv_b": tp_sharded,
+          **dict.fromkeys(GDN_LEAVES, data_axes),
           "wo": tp_sharded, "q_scale": tp_sharded, "k_scale": tp_sharded,
           "router": data_axes, "we1": experts, "we2": experts,
           "we_gate": experts,
@@ -376,7 +532,7 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
           "w1": tp_sharded, "b1": tp_sharded, "w2": tp_sharded,
           "b2": data_axes, "w_gate": tp_sharded}
     return _present({"embed": glob, "pos": glob, "dense_layers": dict(lp),
-                     "layers": lp,
+                     "layers": _per_kind(cfg, lambda: dict(lp)),
                      "lnf_scale": glob, "lnf_bias": glob, "unembed": glob},
                     cfg)
 
@@ -496,6 +652,58 @@ def _mla(h, lp: Dict[str, Any], cfg: TransformerConfig, rope):
         return jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
 
 
+def _causal_conv(u, w):
+    """SiLU of the depthwise causal convolution of u: (B, H, S, d) over S
+    with the taps w: (H, d, K): y_t = sum_j w_j u_(t-K+1+j), zeros before
+    the sequence's start; float32 inside, u's type out."""
+    taps, seq = w.shape[-1], u.shape[2]
+    padded = jnp.pad(u.astype(jnp.float32),
+                     ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    y = sum(padded[:, :, j:j + seq] * wf[None, :, None, :, j]
+            for j in range(taps))
+    return jax.nn.silu(y).astype(u.dtype)
+
+
+def _l2_normed(x, eps=1e-6):
+    xf = x.astype(jnp.float32)
+    return xf * lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True)
+                          + eps)
+
+
+def _gdn(h, lp: Dict[str, Any], cfg: TransformerConfig):
+    """A Gated DeltaNet mixer (arXiv:2412.06464) on h: (B, S, D): the gated
+    delta rule of `ops/gated_delta.py` on convolved, normalised queries and
+    keys, its output normed per head, gated and projected; (B, S, D)."""
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+    with jax.named_scope("gdn.project"):
+        q = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wq"])
+        k = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wk"])
+        v = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wv"])
+        z = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wz"])
+        a = jnp.einsum("bsd,dh->bhs", h, lp["gdn_wa"],
+                       preferred_element_type=jnp.float32)
+        b = jnp.einsum("bsd,dh->bhs", h, lp["gdn_wb"],
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("gdn.conv"):
+        q = _causal_conv(q, lp["gdn_conv_q"])
+        k = _causal_conv(k, lp["gdn_conv_k"])
+        v = _causal_conv(v, lp["gdn_conv_v"])
+    with jax.named_scope("gdn.scan"):
+        q = (_l2_normed(q) * cfg.gdn_key_dim ** -0.5).astype(h.dtype)
+        k = _l2_normed(k).astype(h.dtype)
+        beta = jax.nn.sigmoid(b) * (2.0 if cfg.gdn_neg_eigval else 1.0)
+        rate = jnp.exp(lp["gdn_a_log"].astype(jnp.float32))[None, :, None]
+        g = -rate * jax.nn.softplus(
+            a + lp["gdn_dt_bias"].astype(jnp.float32)[None, :, None])
+        o = gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope("gdn.gate"):
+        o = (_rms(o, lp["gdn_o_scale"], cfg.rms_norm_eps).astype(jnp.float32)
+             * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
+    with jax.named_scope("gdn.out"):
+        return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"])
+
+
 def _mlp(h, w_gate, w_up, w_down):
     """W_down (silu(W_gate h) * W_up h), or W_down gelu(W_up h) without a
     gate; no biases. The hidden width is sharded over tp: this rank's part
@@ -513,24 +721,35 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     layer's [load balance, router z] of this shard's tokens, with the count
     of held pairs that found no room as a third where the layer holds a
     share of its experts (see `parallel/moe.py`)."""
-    h = _norm(x, lp, "ln1", cfg)
+    # a patterned model's attention runs under scopes as its other mixer
+    # does; a stack of one kind keeps the program it had
+    scope = jax.named_scope if cfg.layer_pattern else \
+        (lambda name: contextlib.nullcontext())
+    h = x if cfg.post_norm else _norm(x, lp, "ln1", cfg)
     if cfg.attention == "mla":
         o = _mla(h, lp, cfg, rope)
+    elif cfg.attention == "gdn":
+        o = _gdn(h, lp, cfg)
     else:
-        q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
-        if cfg.qk_norm:
-            q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
-            k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
-        if rope is not None:
-            q, k = _rope(q, rope), _rope(k, rope)
-        a = _attend(q, k, v, cfg)
-        o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
+        with scope("attn.project"):
+            q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
+            k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
+            v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
+            if cfg.qk_norm:
+                q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
+                k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
+            if rope is not None:
+                q, k = _rope(q, rope), _rope(k, rope)
+        with scope("attn.attend"):
+            a = _attend(q, k, v, cfg)
+        with scope("attn.out"):
+            o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
     o = lax.psum(o, "tp")                    # row-parallel combine
+    if cfg.post_norm:
+        o = _norm(o, lp, "ln1", cfg)
     x = x + o
 
-    h2 = _norm(x, lp, "ln2", cfg)
+    h2 = x if cfg.post_norm else _norm(x, lp, "ln2", cfg)
     aux = None
     if cfg.num_experts:
         B, S, D = h2.shape
@@ -552,6 +771,8 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
         u = jax.nn.gelu(u)
         f = jnp.einsum("bsf,fd->bsd", u, lp["w2"])
         f = lax.psum(f, "tp") + lp["b2"]
+    if cfg.post_norm:
+        f = _norm(f, lp, "ln2", cfg)
     return x + f, aux
 
 
@@ -578,20 +799,29 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
                             cfg.rope_theta, cfg.yarn)
         x = x.astype(cfg.dtype)
+    elif cfg.positions == "none":
+        x = x.astype(cfg.dtype)
     else:
         pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S, axis=0)
         x = (x + pos[None]).astype(cfg.dtype)
 
     def run_stack(stack, stage_params, act):
         """`act` through the layers of one stack: (act, the layers' aux)."""
-        slots = (grad_slots or {}).get(stack)
         layer_cfg = _stack_cfg(cfg, stack)
+        patterned = stack == "layers" and bool(cfg.layer_pattern)
+        if patterned:
+            slots = {kind: of_kind for kind in stage_params if (
+                of_kind := (grad_slots or {}).get((stack, kind)))}
+        else:
+            slots = (grad_slots or {}).get((stack,))
 
-        def body(a, xs):
-            lp = _scattered_in_backward(*xs, partial(scatter, stack)) \
-                if slots else xs
-            return _layer(a, lp, layer_cfg, rope)
-        if cfg.remat:
+        def remat(fn, prevent_cse=False):
+            """`fn` (one layer) under `jax.checkpoint` where `cfg.remat`.
+            A scan's body needs no barrier against common-subexpression
+            elimination: the forward and the backward loop keep the layer
+            and its repeat apart."""
+            if not cfg.remat:
+                return fn
             # "dots": save projection/FFN matmul outputs (small, expensive
             # to recompute); recompute batched-dot products — exactly the
             # (B,H,S,S) attention matrices that blow up HBM. "full": save
@@ -605,10 +835,37 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
                 raise HorovodTpuError(
                     f"remat_policy={cfg.remat_policy!r}: choose from "
                     f"{sorted(policies)} (remat=False turns remat off)")
-            body = jax.checkpoint(body, prevent_cse=False,
+            return jax.checkpoint(fn, prevent_cse=prevent_cse,
                                   policy=policies[cfg.remat_policy])
-        return lax.scan(body, act, (stage_params, slots)
-                        if slots else stage_params)
+
+        def one_kind(a, xs):
+            lp = _scattered_in_backward(*xs, partial(scatter, (stack,))) \
+                if slots else xs
+            return _layer(a, lp, layer_cfg, rope)
+
+        def one_period(a, xs):
+            """A period's layers in the pattern's order; xs holds each
+            kind's layers of this period, stacked. Each layer is its own
+            checkpoint: the backward pass then holds one layer's
+            recomputed residuals at a time, not the period's. They do need
+            the barrier: the compiler removes a loop of one period, and
+            would then merge each layer's repeat with its forward pass and
+            keep every residual alive (measured: PERF.md, PR 32)."""
+            lp, slot = xs if slots else (xs, {})
+            lp = {kind: _scattered_in_backward(
+                leaves, slot[kind], partial(scatter, (stack, kind)))
+                if kind in slot else leaves for kind, leaves in lp.items()}
+            seen = dict.fromkeys(lp, 0)
+            for kind in cfg.layer_pattern:
+                i = seen[kind]
+                seen[kind] += 1
+                a, _ = remat(partial(_layer, cfg=_kind_cfg(layer_cfg, kind),
+                                     rope=rope), prevent_cse=True)(
+                    a, {k: w[i] for k, w in lp[kind].items()})
+            return a, None
+
+        return lax.scan(one_period if patterned else remat(one_kind), act,
+                        (stage_params, slots) if slots else stage_params)
 
     stage_fn = partial(run_stack, "layers")
     if cfg.first_k_dense:
@@ -809,37 +1066,49 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
         return psum_axes(g / tp_size, axes)
 
     def fn(params, tokens, targets):
-        # per stack of layers, the leaves that are scattered in the loop
-        plans = {stack: {k: plan for k, w in params[stack].items() if (
-            plan := _scatter_plan(w.shape[1:], raxes[stack][k]))}
-            for stack in STACKS if stack in params} if in_backward else {}
+        # per group of layers (a stack, or a kind of a patterned stack:
+        # `_layer_groups`), the leaves that are scattered in the loop; a
+        # plan's dimension counts behind the group's `lead` stacking axes
+        groups = _layer_groups(cfg, params)
+        plans = {path: {k: plan for k, w in _at(params, path).items() if (
+            plan := _scatter_plan(w.shape[lead:], _at(raxes, path)[k]))}
+            for path, lead in groups.items()} if in_backward else {}
 
-        def slot(w, plan):
+        def slot(w, plan, lead):
             _, n, dim = plan
             shape = list(w.shape)
-            shape[dim + 1] //= n
+            shape[dim + lead] //= n
             return jnp.zeros(shape, w.dtype)
 
-        slots = {stack: {k: slot(params[stack][k], plan)
-                         for k, plan in of_stack.items()}
-                 for stack, of_stack in plans.items()}
+        slots = {path: {k: slot(_at(params, path)[k], plan, groups[path])
+                        for k, plan in of_group.items()}
+                 for path, of_group in plans.items()}
 
-        def scatter(stack, k, g):
-            return _scatter_sum(g / tp_size, *plans[stack][k])
+        def scatter(path, k, g):
+            # inside the scan body the first stacking axis is gone
+            axes, n, dim = plans[path][k]
+            return _scatter_sum(g / tp_size, axes, n,
+                                dim + groups[path] - 1)
 
         (local_mean, dropped), (grads, shards) = jax.value_and_grad(
             lambda p, slots: _local_loss(p, tokens, targets, cfg, slots,
                                          scatter),
             argnums=(0, 1), has_aux=True)(params, slots)
 
-        def completed(stack):
-            plan = plans.get(stack, {})
-            return {k: lax.all_gather(shards[stack][k], plan[k][0],
-                                      axis=plan[k][2] + 1, tiled=True)
-                    if k in plan else reduce_late(g, raxes[stack][k])
-                    for k, g in grads[stack].items()}
+        def completed(path):
+            plan = plans.get(path, {})
+            return {k: lax.all_gather(shards[path][k], plan[k][0],
+                                      axis=plan[k][2] + groups[path],
+                                      tiled=True)
+                    if k in plan else reduce_late(g, _at(raxes, path)[k])
+                    for k, g in _at(grads, path).items()}
 
-        stacks = {stack: completed(stack) for stack in STACKS
+        def of_stack(stack):
+            if (stack,) in groups:
+                return completed((stack,))
+            return {kind: completed((stack, kind)) for kind in grads[stack]}
+
+        stacks = {stack: of_stack(stack) for stack in STACKS
                   if stack in grads}
         grads = {k: stacks[k] if k in stacks else
                  jax.tree_util.tree_map(reduce_late, g, raxes[k])
@@ -941,7 +1210,26 @@ def init_opt_state(optimizer: optax.GradientTransformation, params,
 
 def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
     ax = mesh_axis_sizes(mesh)
+    if cfg.layer_pattern:
+        _periods(cfg)
+        for kind in cfg.layer_pattern:
+            _kind_cfg(cfg, kind)
+    linear = cfg.attention == "gdn" or "linear" in cfg.layer_pattern
     checks = [
+        # a gated-delta-rule layer carries a state along the whole sequence
+        # and holds all its heads: neither crosses shards yet
+        (not linear or ax["sp"] == 1,
+         "linear-attention layers require sp=1 (the state of the gated "
+         "delta rule would have to cross the sequence's shards)"),
+        (not linear or ax["tp"] == 1,
+         "linear-attention layers require tp=1 (their heads are not "
+         "sharded)"),
+        (not cfg.layer_pattern or ax["pp"] == 1,
+         "a layer pattern requires pp=1 (the pipeline schedule places "
+         "layers, not periods)"),
+        (not cfg.layer_pattern or not cfg.num_experts,
+         "a layer pattern with experts (the periods' auxiliary terms are "
+         "not gathered)"),
         (_stack_depth(cfg) % ax["pp"] == 0, "n_layers % pp"),
         (cfg.n_heads % ax["tp"] == 0, "n_heads % tp"),
         (cfg.d_ff % ax["tp"] == 0, "d_ff % tp"),

@@ -88,6 +88,17 @@ def test_phase_grouped_kernel(smoke, capsys):
 
 
 @one_chip
+def test_phase_gated_delta_scan(smoke, capsys):
+    chip_smoke.gated_delta_scan(smoke, shape=(1, 2, 200, 16, 32), checked=100)
+    out = capsys.readouterr().out
+    assert "[gated delta rule] 1 x 200 tokens x 2 heads, 16 | 32" in out
+    assert "chunk 64, 4 chunks a sequence" in out
+    assert "from the token-by-token recurrence" in out
+    assert "forward + backward" in out
+    assert "least time" not in out     # no share of a peak off the TPU
+
+
+@one_chip
 def test_phase_flagship_lm(smoke, capsys):
     chip_smoke.flagship_lm(smoke, cfg=TINY_LM, batch=4, seq=64, steps=5)
     out = capsys.readouterr().out

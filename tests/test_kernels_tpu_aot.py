@@ -140,6 +140,71 @@ def test_grouped_matmul_compiles_for_v5e(monkeypatch, shape, product):
         assert mosaic_signatures(txt) == [GROUPED_SIGNATURE] * calls
 
 
+HYBRID_CELL = (1, 30, 8192, 128)   # olmohybrid-1chip's full layer
+HYBRID_RULE = (1, 30, 8192, 96, 192)   # its linear layers: keys | values
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_flash_attention_at_the_hybrid_cells_shape_compiles_for_v5e(
+        monkeypatch, name):
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    q = jax.ShapeDtypeStruct(HYBRID_CELL, jnp.bfloat16)
+    want = SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
+                              (q, q, q), n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_gated_delta_rule_compiles_for_v5e(monkeypatch, name):
+    """`ops/gated_delta.py` at `olmohybrid-1chip`'s shapes: plain `jnp` (no
+    Mosaic kernel: that is a `perf_opt` PR's), so what is held here is that
+    the chip's compiler takes the batched triangular solve and the scan of
+    128 chunks, forward and through JAX's backward pass."""
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+    from tpu_probe import compile_kernel_text, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    b, h, s, dk, dv = HYBRID_RULE
+    wide = jax.ShapeDtypeStruct((b, h, s, dk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, h, s, dv), jnp.bfloat16)
+    gate = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
+
+    def bwd(*args):
+        return jax.grad(lambda *a: gated_delta_rule(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*args)
+
+    txt = compile_kernel_text(topo, {"fwd": gated_delta_rule, "bwd": bwd}[
+        name], (wide, wide, v, gate, gate), n_calls=0)
+    assert "while" in txt      # the scan over the chunks is a loop
+
+
+def test_the_hybrid_cells_step_compiles_at_full_size_and_fits(monkeypatch):
+    """`benchmark.aot_check olmohybrid-1chip` as a test: the whole train
+    step at the published widths, 1 x 8,192 tokens, for a described v5e: it
+    compiles, leaves `HEADROOM_GIB` of the chip's memory and holds the
+    full layer's flash kernels (half a minute here)."""
+    from benchmark import aot_check
+    from benchmark.harness import peaks, spec
+    from tpu_probe import _no_persistent_cache, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    cell = spec.load_cell("olmohybrid-1chip")
+    hbm = peaks.for_kind(aot_check.DEVICE_KIND).hbm_bytes
+    with jax.enable_x64(False), _no_persistent_cache():
+        found, problems = aot_check.check_cell(cell, topo.devices, hbm)
+    assert problems == [], found
+    # the flash forward, its remat repeat (each layer of a period is its own
+    # checkpoint behind a barrier), dk/dv and dq
+    assert "4 tpu_custom_call" in found and "(1 chip(s))" in found
+    need = float(found.split("needs ")[1].split(" GiB")[0])
+    # over a quarter of the chip's memory, and what PERF.md says
+    assert 0.25 * hbm / 2 ** 30 < need == pytest.approx(14.29, abs=0.15)
+
+
 def test_interpret_decision_is_shared_and_visible(monkeypatch):
     """One helper decides interpreter-vs-Mosaic for every kernel family;
     on the CPU suite it says "interpret", and flipping it flips the

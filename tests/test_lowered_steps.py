@@ -1,0 +1,90 @@
+"""The train step of three tiny configurations of the kinds the benchmark's
+LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's), lowered on the CPU and
+compared, as text, with what the commit before the layer pattern (PR 31's,
+a31c4fe) lowered for them: `tests/fixtures/hlo/lowered_steps.json.gz`. One
+rank, where nothing is reduced, and `dp` = 2, where the layers' gradients
+are reduce-scattered inside the backward loop. A change to
+`models/transformer.py` that is not meant to touch these models' programs
+leaves the text as it is; one that is meant to takes the fixture anew
+(`write_fixture()` below, on the tree whose programs are the new truth) and
+says so.
+
+The text is JAX's StableHLO without locations, so it does not depend on
+where the checkout lies; it does depend on the JAX version (0.9.0)."""
+
+import gzip
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
+
+FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "fixtures", "hlo", "lowered_steps.json.gz")
+
+CONFIGS = {
+    "gpt2": tfm.TransformerConfig(
+        vocab=96, d_model=64, n_heads=4, d_ff=128, n_layers=2, max_seq=32,
+        attn="flash", dtype=jnp.bfloat16, remat=True),
+    "olmoe": tfm.TransformerConfig(
+        vocab=96, d_model=64, n_heads=4, d_ff=32, n_layers=2, max_seq=32,
+        num_experts=4, experts_per_token=2, load_balance_coef=0.01,
+        router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
+        mlp="swiglu", attn="flash", dtype=jnp.bfloat16, remat=True),
+    "deepseek_v2": tfm.TransformerConfig(
+        vocab=96, d_model=64, n_heads=4, d_ff=32, n_layers=3, max_seq=32,
+        num_experts=8, experts_per_token=2, experts_held=2, first_expert=2,
+        shared_experts=2, first_k_dense=1, d_ff_dense=96,
+        load_balance_coef=0.002, balance_per_sequence=True, norm="rmsnorm",
+        rms_norm_eps=1e-6, positions="rope",
+        yarn=tfm.Yarn(factor=40, original_max=4096, beta_fast=32,
+                      beta_slow=1, mscale=0.707, mscale_all_dim=0.707),
+        attention="mla", kv_latent=24, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, mlp="swiglu", attn="flash", dtype=jnp.bfloat16,
+        remat=True),
+}
+CASES = [(name, dp) for name in CONFIGS for dp in (1, 2)
+         if not (name == "deepseek_v2" and dp == 2)]   # a share is one rank's
+
+
+def lowered(name: str, dp: int) -> str:
+    cfg = CONFIGS[name]
+    mesh = build_mesh(MeshSpec(dp=dp), devices=jax.devices()[:dp])
+    opt = optax.adamw(1e-3)
+    with jax.enable_x64(False):   # as the benchmark runs
+        params = jax.eval_shape(lambda k: tfm.init(k, cfg),
+                                jax.random.PRNGKey(0))
+        state = jax.eval_shape(opt.init, params)
+        tokens = jax.ShapeDtypeStruct((2 * dp, 32), jnp.int32)
+        return tfm.build_train_step(cfg, mesh, opt).lower(
+            params, state, tokens, tokens).as_text()
+
+
+def write_fixture() -> None:
+    texts = {f"{name}-dp{dp}": lowered(name, dp) for name, dp in CASES}
+    with gzip.open(FIXTURE, "wt") as f:
+        json.dump(texts, f)
+
+
+@pytest.fixture(scope="module")
+def parents():
+    with gzip.open(FIXTURE, "rt") as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name, dp", CASES)
+def test_the_lowered_step_is_the_parents(parents, name, dp):
+    got, want = lowered(name, dp), parents[f"{name}-dp{dp}"]
+    if got != want:
+        a, b = got.splitlines(), want.splitlines()
+        first = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                     min(len(a), len(b)))
+        pytest.fail(f"{name} dp={dp}: {len(a)} lines against the parent's "
+                    f"{len(b)}; first difference at line {first + 1}:\n"
+                    f"  now:    {a[first][:300] if first < len(a) else ''}\n"
+                    f"  parent: {b[first][:300] if first < len(b) else ''}")
