@@ -413,10 +413,11 @@ def grouped_kernel(log: CompileLog,
 
 def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                      checked=256) -> None:
-    """The chunked gated delta rule (ops/gated_delta.py) alone at
+    """The gated delta rule's kernels (ops/gated_delta.py) alone at
     `olmohybrid-1chip`'s (batch, heads, tokens, key width, value width):
-    its first `checked` tokens against the token-by-token recurrence, then
-    forward and forward + backward times against the least time the
+    its first `checked` tokens against the token-by-token recurrence, the
+    Mosaic kernels the compiled forward and forward + backward hold (on the
+    TPU: one, and two), then their times against the least time the
     benchmark's `gdn_scan_roofline` counts (the family's `rule_work`)."""
     batch, heads, seq, dk, dv = shape
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
@@ -456,8 +457,18 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
         out, vjp = jax.vjp(gd.gated_delta_rule, *args)
         return (out,) + vjp(cot)
 
-    ms = _best_ms({"forward": (jax.jit(gd.gated_delta_rule), args),
-                   "forward + backward": (jax.jit(both), args)}, repeats=5)
+    runs = {"forward": (jax.jit(gd.gated_delta_rule).lower(*args).compile(),
+                        args),
+            "forward + backward": (jax.jit(both).lower(*args).compile(),
+                                   args)}
+    kernels = {name: fn.as_text().count('custom_call_target="tpu_custom_call"')
+               for name, (fn, _) in runs.items()}
+    if on_tpu() and kernels != {"forward": 1, "forward + backward": 2}:
+        raise AssertionError(
+            f"the compiled gated delta rule holds {kernels} Mosaic custom "
+            "calls, expected the forward kernel, and with it the backward "
+            "kernel where a gradient is asked")
+    ms = _best_ms(runs, repeats=5)
     shares = ""
     if on_tpu():   # the shares are of the benchmark's table of peaks
         p = peaks.for_kind(jax.devices()[0].device_kind)
@@ -468,12 +479,18 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                   f"({100 * fwd / ms['forward']:.2f}% of it), forward + "
                   f"backward {fwd + bwd:.3f} ms "
                   f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
+    a_step = gd.heads_a_step(heads, dk, dv)
     say(f"[gated delta rule] {batch} x {seq} tokens x {heads} heads, "
         f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "
         f"{gd.chunks_of(seq)} chunks a sequence, the chunked form's "
         "multiply-adds "
         f"{gd.chunked_over_recurrent_macs(dk, dv):.2f} x the recurrent "
-        f"form's; the first {checked} tokens are {err:.2e} of their rms "
+        f"form's; {a_step} heads a grid step "
+        f"({a_step * gd.step_bytes(dk, dv) / 2 ** 20:.2f} MiB of VMEM asked "
+        "for its blocks and state); interpret="
+        f"{_pallas.interpret()}, tpu_custom_call in the compiled "
+        + ", ".join(f"{name} {n}" for name, n in kernels.items())
+        + f"; the first {checked} tokens are {err:.2e} of their rms "
         "from the token-by-token recurrence; alone (information only), ms "
         "an execution: "
         + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)

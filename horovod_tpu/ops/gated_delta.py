@@ -1,5 +1,6 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464) over a sequence,
-in its chunked form: the one scan over the sequence in this package.
+in its chunked form, as Pallas TPU kernels (forward + backward): the one
+scan over the sequence in this package.
 
 Per head, with keys k_t (dk wide), values v_t (dv wide), queries q_t, a log
 decay g_t <= 0 and a write strength beta_t, a float32 state S (dk x dv) that
@@ -14,40 +15,79 @@ of the paper, the WY form). With b_i the running sum of g inside a chunk
 and S_0 the state the chunk starts from,
 
     A_ij = beta_i exp(b_i - b_j) k_i.k_j   for j < i, else 0
-    (I + A) U_0 = beta * V                  one unit-lower-triangular solve,
-    (I + A) W   = beta * exp(b) * K         both right-hand sides at once
+    T    = (I + A)^-1                       unit lower triangular
+    W    = T (beta * exp(b) * K),  U_0 = T (beta * V)
     u   = U_0 - W S_0
     O   = (exp(b) * Q) S_0 + (Q K^T * M) u  M_ij = exp(b_i - b_j), j <= i
     S_C = exp(b_C) S_0 + (exp(b_C - b) * K)^T u
 
-so everything inside a chunk is matrix products, computed for all chunks at
-once, and a `lax.scan` over the chunks carries S alone. Every exponent is of
-a difference b_i - b_j with j <= i, or of b itself: none is positive, and
-nothing is divided by a decay, so a strong decay underflows to zero and
-does nothing worse.
+Two kernels, each a grid of (blocks of heads, chunks) that walks a head's
+chunks in sequence (the grid's last, "arbitrary" axis) with the state, or
+its cotangent, resident in VMEM: a scratch cleared at a head's first chunk.
+A grid step takes `heads_a_step` heads, whose chains of small dependent
+products are independent and interleave; no operand of a chunk goes through
+HBM between its products.
+
+* `_forward_kernel`. First what does not depend on the state: K K^T, the
+  decays, A, T, W and U_0. T is made by forward substitution by rows, in
+  float32 on the vector unit (row j of T is final once rows 0..j-1 are
+  subtracted from it: C - 1 steps, each one column of A times one row of
+  T), never by the product (I - A)(I + A^2)(I + A^4)..: beta reaches 2, so
+  A's powers grow before they vanish and float32 loses the result. T then
+  multiplies K and V exactly: its columns are scaled (float32), and where
+  the inputs are bf16 the scaled matrix is split into three bf16 pieces
+  that sum to it bit for bit, each a product with the bf16 K or V,
+  accumulated in float32. Then the walk: u, O and the next state, four
+  products, two of them on the chain from state to state.
+* `_backward_kernel`, the reverse walk with the state's cotangent resident,
+  and in the same grid step everything else of the chunk's gradient:
+  through the scores, the decays and T (the cotangent of A is
+  -(T^T dW) W^T - (T^T dU_0) U_0^T below the diagonal: products only, no
+  second solve). It writes dq, dk, dv, dbeta and the cotangent of b; g's is
+  the reverse running sum of b's inside a chunk, in `jnp`.
+
+Every exponent is of a difference b_i - b_j with j <= i, or of b itself,
+masked before `exp`: none is positive, and nothing is divided by a decay,
+so a strong decay underflows to zero and does nothing worse.
 
 Precision: products take their operands in the inputs' type (bf16 in the
 benchmark's cells) and accumulate in float32; g, b, beta, every decay, the
-matrix A, the solve and the carried state are float32; u and the state are
-rounded to the inputs' type only as operands of a product.
+matrix A, its inverse T, the carried state and its cotangent are float32; u
+and the state are rounded to the inputs' type only as operands of a
+product. float32 inputs multiply at the MXU's full float32 precision.
 
-The backward pass is JAX's own, through the products, the solve and the scan
-over chunks: per chunk the scan saves its state on entry (dk x dv float32 a
-head) and u; nothing is saved per token.
+For the backward pass the forward kernel writes, besides o, W (inputs'
+type), U_0, T and each chunk's entry state (float32: dk x dv a chunk and
+head); the plain forward (no gradient asked, or the first pass under remat)
+writes o alone. Off the TPU the same kernels run in the
+Pallas interpreter (`ops/_pallas.interpret`).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops._pallas import pallas_call
 
 CHUNK = 64
+#: rows of a float32 vector register: a chunk is whole registers of rows
+_SUBLANES = 8
+#: what the blocks and the resident state of a grid step may take of VMEM,
+#: double-buffered: it decides how many heads a grid step takes
+_VMEM_BUDGET = 4 * 2 ** 20
+_F32 = jnp.float32
 
 
 def _chunk_size(chunk: int) -> int:
-    if chunk < 1:
-        raise ValueError(f"gated_delta_rule: chunk {chunk}")
+    if chunk < _SUBLANES or chunk % _SUBLANES:
+        raise ValueError(f"gated_delta_rule: chunk {chunk} (a positive "
+                         f"multiple of {_SUBLANES})")
     return chunk
 
 
@@ -67,70 +107,353 @@ def chunked_over_recurrent_macs(key_dim: int, value_dim: int,
     return chunked / (3 * dk * dv)
 
 
+def step_bytes(key_dim: int, value_dim: int, chunk: int = CHUNK,
+               itemsize: int = 2) -> int:
+    """VMEM a head takes of the backward kernel's grid step, the larger of
+    the two: its blocks (q, k, W, dq, dk; v, dO, dv; U_0, T and the entry
+    state in float32), each twice for the pipeline, and the resident
+    cotangent of the state."""
+    dk, dv, c = key_dim, value_dim, _chunk_size(chunk)
+    blocks = (5 * c * dk + 3 * c * dv) * itemsize \
+        + 4 * (c * dv + c * c + dk * dv)
+    return 2 * blocks + 4 * dk * dv
+
+
+def heads_a_step(heads: int, key_dim: int, value_dim: int,
+                 chunk: int = CHUNK, itemsize: int = 2) -> int:
+    """Heads a grid step takes: as many as `_VMEM_BUDGET` holds of
+    `step_bytes`, at least one, at most all; a divisor of `heads` where one
+    lies in the upper half of that range (no head is padded), else the most
+    (the heads are padded to whole blocks with rows that do nothing)."""
+    most = max(1, min(heads, _VMEM_BUDGET // step_bytes(
+        key_dim, value_dim, chunk, itemsize)))
+    for h in range(most, most // 2, -1):
+        if heads % h == 0:
+            return h
+    return most
+
+
+# --------------------------------------------------------------------------
+# What the kernels share
+# --------------------------------------------------------------------------
+
+def _mm(a, b, contract):
+    """Per head, a x b contracting `contract` = (a's axis, b's axis) of the
+    (rows, columns) matrices of a, b: (heads, ., .); float32 accumulation,
+    and float32 operands at float32 precision."""
+    exact = lax.Precision.HIGHEST if a.dtype == _F32 else None
+    dims = (((contract[0],), (contract[1],)), ((), ()))
+    return jnp.stack([
+        lax.dot_general(a[h], b[h], dims, precision=exact,
+                        preferred_element_type=_F32)
+        for h in range(a.shape[0])])
+
+
+def _pieces(x, dt):
+    """The float32 x as arrays of type `dt` that sum to it exactly: itself,
+    or three bf16 (8 + 8 + 8 bits of its 24)."""
+    if dt == _F32:
+        return [x]
+    pieces = []
+    for _ in range(3):
+        piece = x.astype(dt)
+        pieces.append(piece)
+        x = x - piece.astype(_F32)
+    return pieces
+
+
+def _mm_exact(x, y, contract):
+    """`_mm` of the float32 x with y of the inputs' type, x not rounded."""
+    parts = [_mm(piece, y, contract) for piece in _pieces(x, y.dtype)]
+    return functools.reduce(jnp.add, parts)
+
+
+def _masks(c: int):
+    rows = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return rows == cols, rows > cols, rows >= cols
+
+
+def _as_column(row, eye):
+    """(heads, 1, c) -> (heads, c, 1): the diagonal of the row broadcast
+    over rows, summed over lanes (exact: one term a row)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=2, keepdims=True)
+
+
+def _as_row(col, eye):
+    """(heads, c, 1) -> (heads, 1, c), the same way."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=1, keepdims=True)
+
+
+def _decay(exponent, mask):
+    """exp of the exponents `mask` holds, 0 elsewhere: masked before `exp`,
+    where the exponent may be positive."""
+    return jnp.exp(jnp.where(mask, exponent, -jnp.inf))
+
+
+def _unit_lower_inverse(a, eye):
+    """(I + a)^-1 for a: (heads, c, c) float32, zero on and above the
+    diagonal, by forward substitution on the rows of the identity: after
+    step j, which takes a's column j times row j from every later row, row
+    j + 1 is final. Rows are held in registers of `_SUBLANES`: a step
+    touches the rows from row j's register on, or from the next one where
+    row j is its register's last."""
+    c = a.shape[-1]
+    done, first = [], 0        # the registers that are final; the next row
+    rest = jnp.broadcast_to(eye.astype(_F32), a.shape)
+    for j in range(c - 1):
+        row = rest[:, j - first:j - first + 1, :]
+        if (j + 1) % _SUBLANES == 0:
+            done.append(rest[:, :_SUBLANES])
+            rest, first = rest[:, _SUBLANES:], j + 1
+        rest = rest - a[:, first:, j:j + 1] * row
+    return jnp.concatenate(done + [rest], axis=1)
+
+
+def _chunk_terms(b, b_col, mask):
+    """Of a chunk, per head, from b as a row and as a column: exp(b) and
+    exp(b_C - b) as columns, exp(b_C), and exp(b_i - b_j) where `mask`
+    holds."""
+    last = b[:, :, -1:]
+    return (jnp.exp(b_col), jnp.exp(last - b_col), jnp.exp(last),
+            _decay(b_col - b, mask))
+
+
+# --------------------------------------------------------------------------
+# The kernels
+# --------------------------------------------------------------------------
+
+def _forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref, *rest):
+    *saved, state = rest
+    dt = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first_chunk():
+        state[...] = jnp.zeros_like(state)
+
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]
+    b, beta = b_ref[:, 0], beta_ref[:, 0]            # (heads, 1, c)
+    eye, below, upto = _masks(q.shape[1])
+    # what does not depend on the state
+    b_col = _as_column(b, eye)
+    a = _as_column(beta, eye) * _decay(b_col - b, below) * _mm(k, k, (1, 1))
+    t = _unit_lower_inverse(a, eye)
+    w = _mm_exact(t * (beta * jnp.exp(b)), k, (1, 0)).astype(dt)
+    u0 = _mm_exact(t * beta, v, (1, 0))
+    grow, shrink, whole, decay = _chunk_terms(b, b_col, upto)
+    p = (_mm(q, k, (1, 1)) * decay).astype(dt)
+    q_in = (q.astype(_F32) * grow).astype(dt)
+    k_out = (k.astype(_F32) * shrink).astype(dt)
+    # the walk: from the state the chunk starts from to the next chunk's
+    s = state[...]
+    s_op = s.astype(dt)
+    u = (u0 - _mm(w, s_op, (1, 0))).astype(dt)
+    o_ref[...] = (_mm(q_in, s_op, (1, 0)) + _mm(p, u, (1, 0))).astype(dt)
+    state[...] = whole * s + _mm(k_out, u, (0, 0))
+    if saved:
+        w_ref, u0_ref, t_ref, s0_ref = saved
+        w_ref[...], u0_ref[...], t_ref[...], s0_ref[:, 0] = w, u0, t, s
+
+
+def _backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, t_ref, s0_ref,
+                     b_ref, beta_ref, do_ref,
+                     dq_ref, dk_ref, dv_ref, db_ref, dbeta_ref, d_state):
+    dt = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _last_chunk():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    q, k, v, w = q_ref[...], k_ref[...], v_ref[...], w_ref[...]
+    b, beta = b_ref[:, 0], beta_ref[:, 0]
+    c = q.shape[1]
+    eye, below, upto = _masks(c)
+    grow, shrink, whole, decay = _chunk_terms(b, _as_column(b, eye), upto)
+    beta_col = _as_column(beta, eye)
+    qf, kf, vf = (x.astype(_F32) for x in (q, k, v))
+    kk = _mm(k, k, (1, 1))
+    strict = jnp.where(below, decay, 0.0)            # exp(b_i - b_j), j < i
+    scores = strict * kk                             # A over beta
+    p32 = _mm(q, k, (1, 1)) * decay
+    p = p32.astype(dt)
+    q_in32, k_out32 = qf * grow, kf * shrink
+    q_in, k_out = q_in32.astype(dt), k_out32.astype(dt)
+    s0 = s0_ref[:, 0]
+    s_op = s0.astype(dt)
+    u0 = u0_ref[...]
+    u = (u0 - _mm(w, s_op, (1, 0))).astype(dt)
+    ds = d_state[...]
+    ds_op = ds.astype(dt)
+    do = do_ref[...]
+
+    # the walk: what meets the state and its cotangent
+    du32 = _mm(p, do, (0, 0)) + _mm(k_out, ds_op, (1, 0))
+    du = du32.astype(dt)
+    dp = jnp.where(upto, _mm(do, u, (1, 1)), 0.0)
+    dq_in = _mm(do, s_op, (1, 1))
+    dw = -_mm(du, s_op, (1, 1))
+    dk_out = _mm(u, ds_op, (1, 1))
+    d_state[...] = whole * ds + _mm(q_in, do, (0, 0)) - _mm(w, du, (0, 0))
+
+    # through W = T (beta exp(b) K), U_0 = T (beta V) and T = (I + A)^-1
+    t = t_ref[...]
+    dkb = _mm_exact(t, dw.astype(dt), (0, 0))
+    dvb = _mm_exact(t, du, (0, 0))
+    da = -jnp.where(below, _mm(dkb.astype(dt), w, (1, 1))
+                    + _mm(dvb.astype(dt), u0.astype(dt), (1, 1)), 0.0)
+
+    # through the scores and the decays
+    dqk = (dp * decay).astype(dt)
+    dkk = (da * beta_col * strict).astype(dt)
+    dq_ref[...] = (grow * dq_in + _mm(dqk, k, (1, 0))).astype(dt)
+    dk_ref[...] = (shrink * dk_out + _mm(dqk, q, (0, 0))
+                   + _mm(dkk, k, (1, 0)) + _mm(dkk, k, (0, 0))
+                   + beta_col * grow * dkb).astype(dt)
+    dv_ref[...] = (beta_col * dvb).astype(dt)
+
+    def rows_of(x):
+        return jnp.sum(x, axis=2, keepdims=True)
+
+    through_kb = grow * rows_of(dkb * kf)
+    dbeta_ref[:, 0] = _as_row(
+        rows_of(da * scores) + through_kb + rows_of(dvb * vf), eye)
+    # b: exp(b_i - b_j) in A and in the scores, exp(b) on K and Q, and
+    # exp(b_C - b) on K with exp(b_C) on the state, which are b_C's
+    e = da * beta_col * scores + dp * p32
+    leaving = rows_of(dk_out * k_out32)
+    db = _as_row(rows_of(e) + beta_col * through_kb
+                 + rows_of(dq_in * q_in32) - leaving, eye) \
+        - jnp.sum(e, axis=1, keepdims=True)
+    at_last = jnp.sum(leaving, axis=1, keepdims=True) + whole * jnp.sum(
+        rows_of(ds * s0), axis=1, keepdims=True)
+    lane = lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    db_ref[:, 0] = db + jnp.where(lane == c - 1, at_last, 0.0)
+
+
+# --------------------------------------------------------------------------
+# The calls
+# --------------------------------------------------------------------------
+
+def _specs(heads: int, blocks: int, c: int, n: int, reverse: bool = False):
+    """Block specs of a grid (blocks of `heads` heads, chunks) over the
+    caller's arrays (batch, all heads, tokens, width), `blocks` blocks to a
+    batch entry, and the kernels' own, which hold batch and heads as one
+    axis: (., tokens, width), (., chunks, 1, c) and (., chunks, dk, dv).
+    `reverse` walks the chunks from the last."""
+    def chunk(j):
+        return n - 1 - j if reverse else j
+
+    def given(width):
+        return pl.BlockSpec((None, heads, c, width), lambda i, j: (
+            i // blocks, i % blocks, chunk(j), 0))
+
+    def tokens(width):
+        return pl.BlockSpec((heads, c, width), lambda i, j: (i, chunk(j), 0))
+
+    gates = pl.BlockSpec((heads, 1, 1, c), lambda i, j: (i, chunk(j), 0, 0))
+
+    def states(dk, dv):
+        return pl.BlockSpec((heads, 1, dk, dv),
+                            lambda i, j: (i, chunk(j), 0, 0))
+
+    return given, tokens, gates, states
+
+
+_PARAMS = {"compiler_params": pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))}
+
+
+def _forward(q, k, v, b, beta, *, c: int, heads: int, save: bool):
+    """q, k: (B, H, S, dk), v: (B, H, S, dv), b, beta: (B H, n, 1, c), H a
+    multiple of `heads` and S = n c. Returns o, and with `save` the
+    residuals of the backward pass: W, U_0, T and the entry states, batch
+    and heads one axis."""
+    batch, n_heads, seq, dk = q.shape
+    dv, dt, n, n_all = v.shape[-1], v.dtype, seq // c, batch * n_heads
+    given, tokens, gates, states = _specs(heads, n_heads // heads, c, n)
+    tall = jax.ShapeDtypeStruct
+    o, *saved = pallas_call(
+        _forward_kernel, grid=(n_all // heads, n),
+        in_specs=[given(dk), given(dk), given(dv), gates, gates],
+        out_specs=[given(dv)] + [tokens(dk), tokens(dv), tokens(c),
+                                 states(dk, dv)] * save,
+        out_shape=[tall(v.shape, dt)] + [
+            tall((n_all, seq, dk), dt), tall((n_all, seq, dv), _F32),
+            tall((n_all, seq, c), _F32), tall((n_all, n, dk, dv), _F32)
+        ] * save,
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
+        **_PARAMS)(q, k, v, b, beta)
+    return (o, tuple(saved)) if save else o
+
+
+def _backward(q, k, v, b, beta, w, u0, t, s0, do, *, c: int, heads: int):
+    dk, dv, n = q.shape[-1], v.shape[-1], q.shape[2] // c
+    given, tokens, gates, states = _specs(heads, q.shape[1] // heads, c, n,
+                                          reverse=True)
+    tall = jax.ShapeDtypeStruct
+    return pallas_call(
+        _backward_kernel, grid=(b.shape[0] // heads, n),
+        in_specs=[given(dk), given(dk), given(dv), tokens(dk), tokens(dv),
+                  tokens(c), states(dk, dv), gates, gates, given(dv)],
+        out_specs=[given(dk), given(dk), given(dv), gates, gates],
+        out_shape=[tall(q.shape, q.dtype), tall(k.shape, k.dtype),
+                   tall(v.shape, v.dtype), tall(b.shape, _F32),
+                   tall(b.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
+        **_PARAMS)(q, k, v, w, u0, t, s0, b, beta, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, c, heads):
+    return _forward(q, k, v, jnp.cumsum(g, axis=-1), beta, c=c, heads=heads,
+                    save=False)
+
+
+def _rule_fwd(q, k, v, g, beta, c, heads):
+    b = jnp.cumsum(g, axis=-1)
+    o, saved = _forward(q, k, v, b, beta, c=c, heads=heads, save=True)
+    return o, (q, k, v, b, beta, *saved)
+
+
+def _rule_bwd(c, heads, saved, do):
+    dq, dk, dv, db, dbeta = _backward(*saved, do, c=c, heads=heads)
+    # b is g's running sum inside a chunk: g_j gets every b_i with i >= j
+    dg = lax.cumsum(db, axis=db.ndim - 1, reverse=True)
+    return dq, dk, dv, dg, dbeta
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
     """o_t = S_t^T q_t of the gated delta rule, S_0 = 0, in chunks of
-    `chunk` tokens.
+    `chunk` tokens (a multiple of 8).
 
     q, k: (B, H, S, dk); v: (B, H, S, dv); g (log decay, <= 0) and beta:
     (B, H, S), float32. q and k come normalised and scaled as the caller
     wants them. Returns (B, H, S, dv) in v's type. A length that is no
     multiple of the chunk is padded with rows of g = 0, beta = 0, which
-    leave the state alone."""
+    leave the state alone; heads that do not fill a grid step's block are
+    padded the same way."""
     c = _chunk_size(chunk)
     B, H, S, dk = q.shape
-    dv = v.shape[-1]
     dt = v.dtype
+    if dt not in (jnp.bfloat16, _F32):
+        return gated_delta_rule(
+            *(x.astype(_F32) for x in (q, k, v)), g, beta,
+            chunk=chunk).astype(dt)
     n = chunks_of(S, c)
-    pad = n * c - S
-    if pad:
-        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                   for x in (q, k, v))
-        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
-    q, k, v = (x.reshape(B, H, n, c, x.shape[-1]) for x in (q, k, v))
-    g = g.astype(jnp.float32).reshape(B, H, n, c)
-    beta = beta.astype(jnp.float32).reshape(B, H, n, c)
+    heads = heads_a_step(H, dk, v.shape[-1], c, dt.itemsize)
+    pad = ((0, 0), (0, -H % heads), (0, n * c - S))
 
-    def mm(spec, a, b):
-        return jnp.einsum(spec, a.astype(dt), b.astype(dt),
-                          preferred_element_type=jnp.float32)
+    def whole(x):
+        return jnp.pad(x, pad + ((0, 0),) * (x.ndim - 3)) \
+            if pad[1][1] or pad[2][1] else x
 
-    b = jnp.cumsum(g, axis=-1)                         # (B, H, n, c)
-    # exp(b_i - b_j) where j <= i; the exponent is masked, not the result:
-    # above the diagonal it is positive and may overflow
-    rows, cols = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    decay = jnp.exp(jnp.where(rows >= cols,
-                              b[..., :, None] - b[..., None, :], -jnp.inf))
-    kk = mm("bhnik,bhnjk->bhnij", k, k)
-    a = jnp.where(rows > cols, beta[..., None] * decay * kk, 0.0)
-    rhs = jnp.concatenate(
-        [beta[..., None] * jnp.exp(b)[..., None] * k.astype(jnp.float32),
-         beta[..., None] * v.astype(jnp.float32)], axis=-1)
-    solved = lax.linalg.triangular_solve(
-        a + jnp.eye(c, dtype=jnp.float32), rhs, left_side=True, lower=True,
-        unit_diagonal=True)
-    w, u0 = solved[..., :dk], solved[..., dk:]
-    attn = mm("bhnik,bhnjk->bhnij", q, k) * decay      # Q K^T * M
-    q_in = q.astype(jnp.float32) * jnp.exp(b)[..., None]
-    last = b[..., -1:]                                 # b_C
-    k_out = k.astype(jnp.float32) * jnp.exp(last - b)[..., None]
-
-    def step(state, xs):
-        w, u0, attn, q_in, k_out, last = xs
-        u = u0 - mm("bhik,bhkv->bhiv", w, state)
-        o = mm("bhik,bhkv->bhiv", q_in, state) + mm("bhij,bhjv->bhiv",
-                                                    attn, u)
-        state = jnp.exp(last)[..., None] * state \
-            + mm("bhik,bhiv->bhkv", k_out, u)
-        return state, o.astype(dt)
-
-    # the products' operands are rounded to the inputs' type once, here,
-    # so that the scan saves them for its backward pass in that type
-    w, attn, q_in, k_out = (x.astype(dt) for x in (w, attn, q_in, k_out))
-    per_chunk = tuple(jnp.moveaxis(x, 2, 0)
-                      for x in (w, u0, attn, q_in, k_out, last))
-    _, o = lax.scan(step, jnp.zeros((B, H, dk, dv), jnp.float32), per_chunk)
-    o = jnp.moveaxis(o, 0, 2).reshape(B, H, n * c, dv)
-    return o[:, :, :S] if pad else o
+    q, k, v = (whole(x.astype(dt)) for x in (q, k, v))
+    g, beta = (whole(x.astype(_F32)).reshape(-1, n, 1, c)
+               for x in (g, beta))
+    return _rule(q, k, v, g, beta, c, heads)[:, :H, :S]
 
 
 def recurrent_gated_delta_rule(q, k, v, g, beta):

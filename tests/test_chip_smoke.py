@@ -93,6 +93,9 @@ def test_phase_gated_delta_scan(smoke, capsys):
     out = capsys.readouterr().out
     assert "[gated delta rule] 1 x 200 tokens x 2 heads, 16 | 32" in out
     assert "chunk 64, 4 chunks a sequence" in out
+    assert "2 heads a grid step (0.19 MiB of VMEM asked" in out
+    assert "interpret=True, tpu_custom_call in the compiled forward 0, " \
+        "forward + backward 0" in out
     assert "from the token-by-token recurrence" in out
     assert "forward + backward" in out
     assert "least time" not in out     # no share of a peak off the TPU
