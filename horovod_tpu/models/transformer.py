@@ -64,6 +64,17 @@ from horovod_tpu.parallel.ring_attention import (
     blockwise_attention_reference, ring_attention)
 from horovod_tpu.parallel.mesh import AXIS_ORDER, mesh_axis_sizes
 
+#: The `jax.named_scope`s of the train step outside its three mixers'
+#: (`moe.*` of `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*` below):
+#: a scope reaches the compiled program as a component of an instruction's
+#: `op_name`, through `jit`, remat, the layer scan and differentiation, and a
+#: profile shows it in the op's name. The tests hold the program to this
+#: list and the benchmark's `harness/step_scopes.py` partitions the step's
+#: device time by it (docs/observability.md, "Scopes of the compiled step").
+STEP_SCOPES = ("attn.project", "attn.attend", "attn.out", "mlp.dense",
+               "vocab.embed", "vocab.head", "vocab.loss", "grad.reduce",
+               "opt.update")
+
 
 @dataclasses.dataclass(frozen=True)
 class Yarn:
@@ -721,17 +732,13 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     layer's [load balance, router z] of this shard's tokens, with the count
     of held pairs that found no room as a third where the layer holds a
     share of its experts (see `parallel/moe.py`)."""
-    # a patterned model's attention runs under scopes as its other mixer
-    # does; a stack of one kind keeps the program it had
-    scope = jax.named_scope if cfg.layer_pattern else \
-        (lambda name: contextlib.nullcontext())
     h = x if cfg.post_norm else _norm(x, lp, "ln1", cfg)
     if cfg.attention == "mla":
         o = _mla(h, lp, cfg, rope)
     elif cfg.attention == "gdn":
         o = _gdn(h, lp, cfg)
     else:
-        with scope("attn.project"):
+        with jax.named_scope("attn.project"):
             q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
             k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
             v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
@@ -740,9 +747,9 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
                 k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
             if rope is not None:
                 q, k = _rope(q, rope), _rope(k, rope)
-        with scope("attn.attend"):
+        with jax.named_scope("attn.attend"):
             a = _attend(q, k, v, cfg)
-        with scope("attn.out"):
+        with jax.named_scope("attn.out"):
             o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
     o = lax.psum(o, "tp")                    # row-parallel combine
     if cfg.post_norm:
@@ -765,12 +772,14 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
                 f = f + lax.psum(_mlp(h2, lp.get("ws_gate"), lp["ws1"],
                                       lp["ws2"]), "tp")
     elif cfg.mlp == "swiglu":
-        f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"]), "tp")
+        with jax.named_scope("mlp.dense"):
+            f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"]), "tp")
     else:
-        u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
-        u = jax.nn.gelu(u)
-        f = jnp.einsum("bsf,fd->bsd", u, lp["w2"])
-        f = lax.psum(f, "tp") + lp["b2"]
+        with jax.named_scope("mlp.dense"):
+            u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
+            u = jax.nn.gelu(u)
+            f = jnp.einsum("bsf,fd->bsd", u, lp["w2"])
+            f = lax.psum(f, "tp") + lp["b2"]
     if cfg.post_norm:
         f = _norm(f, lp, "ln2", cfg)
     return x + f, aux
@@ -793,17 +802,20 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     B, S = tokens.shape
     D = cfg.d_model
 
-    x = params["embed"][tokens]
+    # the scope is entered twice: the rotation's angles are no part of it,
+    # and the lowered program keeps the order it had
+    with jax.named_scope("vocab.embed"):
+        x = params["embed"][tokens]
     rope = None
     if cfg.positions == "rope":
         rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
                             cfg.rope_theta, cfg.yarn)
+    with jax.named_scope("vocab.embed"):
+        if cfg.positions == "learned":
+            pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S,
+                                           axis=0)
+            x = x + pos[None]
         x = x.astype(cfg.dtype)
-    elif cfg.positions == "none":
-        x = x.astype(cfg.dtype)
-    else:
-        pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S, axis=0)
-        x = (x + pos[None]).astype(cfg.dtype)
 
     def run_stack(stack, stage_params, act):
         """`act` through the layers of one stack: (act, the layers' aux)."""
@@ -890,18 +902,27 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     else:
         x, aux = stage_fn(params["layers"], x)
 
-    x = _norm(x, params, "lnf", cfg)
-    return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
+    with jax.named_scope("vocab.head"):
+        x = _norm(x, params, "lnf", cfg)
+        return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
 
 
 def _local_loss(params, tokens, targets, cfg: TransformerConfig,
                 grad_slots=None, scatter=None):
-    """(Per-shard loss contribution, see NOTE below on psum placement; the
-    held (token, expert) pairs this shard's expert layers left out of their
-    row buffers, 0 where every expert is held: `parallel/moe.py`)."""
-    pp_size = lax.axis_size("pp")
-    B, S = tokens.shape
+    """(Per-shard loss contribution, see NOTE in `_loss_of_logits` on psum
+    placement; the held (token, expert) pairs this shard's expert layers
+    left out of their row buffers, 0 where every expert is held:
+    `parallel/moe.py`)."""
     logits, aux = _forward_local(params, tokens, cfg, grad_slots, scatter)
+    with jax.named_scope("vocab.loss"):
+        return _loss_of_logits(logits, aux, targets, cfg)
+
+
+def _loss_of_logits(logits, aux, targets, cfg: TransformerConfig):
+    """`_local_loss` from the logits on: log-softmax, the targets' gather,
+    the sums, the experts' auxiliary terms."""
+    pp_size = lax.axis_size("pp")
+    B, S = targets.shape
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     local_sum = jnp.sum(nll)
@@ -1060,6 +1081,11 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
     tp_size = mesh_axis_sizes(mesh)["tp"]
     in_backward = _reduces_in_backward(cfg, mesh)
 
+    # One rank reduces nothing, and what the calls below may leave in its
+    # program (the CPU compiler's casts around a bf16 sum) is no reduction.
+    reducing = partial(jax.named_scope, "grad.reduce") if mesh.size > 1 \
+        else contextlib.nullcontext
+
     # See grad_reduce_axes: /tp everywhere (redundant loss copies), sum over
     # per-leaf axes (includes 'tp' for replicated-over-tp leaves).
     def reduce_late(g, axes):
@@ -1087,8 +1113,9 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
         def scatter(path, k, g):
             # inside the scan body the first stacking axis is gone
             axes, n, dim = plans[path][k]
-            return _scatter_sum(g / tp_size, axes, n,
-                                dim + groups[path] - 1)
+            with reducing():
+                return _scatter_sum(g / tp_size, axes, n,
+                                    dim + groups[path] - 1)
 
         (local_mean, dropped), (grads, shards) = jax.value_and_grad(
             lambda p, slots: _local_loss(p, tokens, targets, cfg, slots,
@@ -1108,11 +1135,12 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
                 return completed((stack,))
             return {kind: completed((stack, kind)) for kind in grads[stack]}
 
-        stacks = {stack: of_stack(stack) for stack in STACKS
-                  if stack in grads}
-        grads = {k: stacks[k] if k in stacks else
-                 jax.tree_util.tree_map(reduce_late, g, raxes[k])
-                 for k, g in grads.items()}
+        with reducing():
+            stacks = {stack: of_stack(stack) for stack in STACKS
+                      if stack in grads}
+            grads = {k: stacks[k] if k in stacks else
+                     jax.tree_util.tree_map(reduce_late, g, raxes[k])
+                     for k, g in grads.items()}
         loss = psum_axes(local_mean, ("dp", "ep", "sp", "pp"))
         if not metrics:
             return loss, grads
@@ -1178,8 +1206,9 @@ def build_train_step(cfg: TransformerConfig, mesh: Mesh,
              compiler_options=_step_compiler_options(cfg, mesh))
     def step(params, opt_state, tokens, targets):
         loss, grads, *counts = lg(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("opt.update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, opt_state, loss, *counts)
 
     return step

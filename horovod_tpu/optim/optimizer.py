@@ -47,6 +47,13 @@ from horovod_tpu.profiler import perfscope as _pscope
 
 _AXIS = "hvd"
 
+#: `DistributedOptimizer.step`'s two phases as host spans of the JAX
+#: profiler's trace, on the clock a device trace has (docs/observability.md,
+#: "Scopes of the compiled step"); outside a profiler session a
+#: `TraceAnnotation` is one flag test
+_REDUCE_SPAN = partial(jax.profiler.TraceAnnotation, "hvd.opt.reduce")
+_APPLY_SPAN = partial(jax.profiler.TraceAnnotation, "hvd.opt.apply")
+
 #: Mesh axes over which the shard-local loss formulations compute the
 #: loss REDUNDANTLY (every member ends holding the same scalar, each
 #: copy differentiated per rank): per-shard reverse AD then scales
@@ -583,18 +590,18 @@ class DistributedOptimizer:
             self._accum = None
             self._accum_count = 0
 
-        with scope.phase("comms"):
+        with scope.phase("comms"), _REDUCE_SPAN():
             avg = self._allreduce_grads(grads)
         if update_extra or getattr(self, "_apply_eager", False):
             # extra kwargs (e.g. loss for lookahead-style transforms) are
             # rare and may not be jit-stable — eager fallback; also used
             # permanently for inner transforms that cannot trace
-            with scope.phase("optimizer"):
+            with scope.phase("optimizer"), _APPLY_SPAN():
                 updates, new_state = self.inner.update(
                     avg, opt_state, params, **update_extra)
                 return optax.apply_updates(params, updates), new_state
         try:
-            with scope.phase("optimizer"):
+            with scope.phase("optimizer"), _APPLY_SPAN():
                 out = self._jitted_apply()(avg, opt_state, params)
             # success means tracing worked; later errors of the caught
             # types are runtime failures, not traceability, and re-raise
@@ -614,7 +621,7 @@ class DistributedOptimizer:
                 "optimizer apply not jittable (%s); running the update "
                 "un-jitted from now on", type(e).__name__)
             self._apply_eager = True
-            with scope.phase("optimizer"):
+            with scope.phase("optimizer"), _APPLY_SPAN():
                 updates, new_state = self.inner.update(avg, opt_state,
                                                        params)
                 return optax.apply_updates(params, updates), new_state

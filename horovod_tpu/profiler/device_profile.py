@@ -42,7 +42,13 @@ _DEFAULT_BUCKETS: List[Tuple[str, str]] = [
     (r"reduce.window|reduce_window", "pool forward"),
     (r"all.reduce|all.gather|reduce.scatter|all.to.all|collective",
      "collective"),
-    (r"jvp|conv1x1_bn|flash|pallas", "pallas kernel"),
+    # A Mosaic kernel's instruction takes the name of the scope it was
+    # called under (models/transformer.py, parallel/moe.py: attention's
+    # flash kernels, latent attention's, the gated delta rule's, the
+    # experts' grouped matmul); the conv kernels name themselves. A bare
+    # "jvp" says only that something was differentiated.
+    (r"^%?(attn\.attend|mla\.attend|gdn\.scan|moe\.experts)\b"
+     r"|conv1x1_bn|flash|pallas", "pallas kernel"),
     # before the conv bucket: r"conv" substring-matches "convert_*"
     (r"multiply_reduce|reduce_fusion|convert_reduce",
      "reduce fusion (stats/grads)"),

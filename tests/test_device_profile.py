@@ -80,7 +80,22 @@ def test_classify_buckets():
     assert classify("%copy-done.5") == "layout/copy"
     assert classify("%bitcast.601") == "layout/copy"
     assert classify("%transpose.12") == "layout/copy"
-    # pallas custom-vjp kernels carry jvp/op names
-    assert classify("%transpose_jvp___.48") == "pallas kernel"
     assert classify("%conv1x1_bn_bwd_fused.1") == "pallas kernel"
+
+
+@pytest.mark.parametrize("name, category", [
+    # a Mosaic kernel is named after the scope it was called under
+    ("%attn.attend.35", "pallas kernel"),
+    ("mla.attend.61", "pallas kernel"),
+    ("%gdn.scan.6", "pallas kernel"),
+    ("%moe.experts.12", "pallas kernel"),
+    # anchored: a fusion named after its root is not a kernel
+    ("%fusion.attn.attend", "fused elementwise/compute"),
+    ("%attn.attendant.1", "other"),
+    # a differentiated fusion is no kernel for carrying "jvp" in its name
+    ("%jvp_multiply_fusion.3", "fused elementwise/compute"),
+    ("%transpose_jvp___.48", "other"),
+])
+def test_classify_tells_kernels_by_their_scopes_names(name, category):
+    assert classify(name) == category
     assert classify("%custom-call.62") == "convolution/custom-call"
