@@ -23,6 +23,7 @@ at a tiny size. The last line of stdout is
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import signal
@@ -44,6 +45,7 @@ from horovod_tpu import native
 from horovod_tpu.core import topology
 from horovod_tpu.models import resnet, transformer as tfm
 from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import causal_conv as cc
 from horovod_tpu.ops import gated_delta as gd
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
@@ -216,6 +218,12 @@ def eager_api(log: CompileLog, n: int = 1 << 20) -> None:
     say(f"[eager api] allreduce grouped_allreduce allgather broadcast "
         f"reducescatter alltoall barrier agree with numpy "
         f"({k} rank(s), {n} elements)")
+
+
+def _rms_off(got, want) -> float:
+    """The rms of got - want as a share of want's rms, in float32."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
 
 
 def _best_ms(runs: dict, repeats: int = 10) -> dict:
@@ -445,7 +453,7 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
         *(x.astype(jnp.float32) for x in head)))
     if not np.all(np.isfinite(got)):
         raise AssertionError("gated delta rule: non-finite output")
-    err = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+    err = _rms_off(got, want)
     if err > BF16_RTOL:
         raise AssertionError(
             f"the chunked gated delta rule is {err:.3g} of its rms from "
@@ -494,6 +502,91 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
         "from the token-by-token recurrence; alone (information only), ms "
         "an execution: "
         + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)
+
+
+def causal_conv_pass(log: CompileLog, shape=(1, 30, 8192),
+                     widths=((96, 96 ** -0.5), (192, None))) -> None:
+    """The convolution's kernels (ops/causal_conv.py) alone at
+    `olmohybrid-1chip`'s (batch, heads, tokens) and (width, L2 scale) of its
+    queries and of its values: y, du and dw against the `jnp` form, the
+    Mosaic kernels the compiled forward and forward + backward hold (on the
+    TPU: one, and two), no compile request after a first call, then their
+    times beside the bytes they must move over the HBM rate. The arrays are
+    laid out tokens-major, width-minor, as the kernels and the rule take
+    them, so the times hold no copy of the phase's own."""
+    from jax.experimental.layout import Format, Layout
+    batch, heads, seq = shape
+    rows_major = Format(Layout(major_to_minor=(0, 1, 2, 3)),
+                        jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+    told = []
+    for width, l2_scale in widths:
+        ks = jax.random.split(jax.random.PRNGKey(width), 3)
+        u, cot = (jax.device_put(jax.random.normal(
+            k, (batch, heads, seq, width), jnp.bfloat16), rows_major)
+            for k in ks[:2])
+        w = (0.5 * jax.random.normal(ks[2], (heads, width, 4))).astype(
+            jnp.bfloat16)
+
+        def both(fn, u, w, cot):
+            out, vjp = jax.vjp(lambda u, w: fn(u, w, l2_scale=l2_scale), u, w)
+            return (out,) + vjp(cot.astype(out.dtype))
+
+        f32 = [x.astype(jnp.float32) for x in (u, w, cot)]
+        want = jax.jit(functools.partial(
+            both, cc.reference_causal_conv_silu))(*f32)
+        runs = {"forward": (jax.jit(
+            lambda u, w: cc.causal_conv_silu(u, w, l2_scale=l2_scale),
+            out_shardings=rows_major).lower(u, w).compile(), (u, w)),
+            "forward + backward": (jax.jit(
+                functools.partial(both, cc.causal_conv_silu),
+                out_shardings=(rows_major, rows_major, None)).lower(
+                    u, w, cot).compile(), (u, w, cot))}
+        got = runs["forward + backward"][0](u, w, cot)
+        errs = {}
+        for name, g, r in zip(("y", "du", "dw"), got, want):
+            errs[name] = _rms_off(g, r)
+            if not errs[name] <= BF16_RTOL:       # a NaN fails too
+                raise AssertionError(
+                    f"causal conv, width {width}: {name} is {errs[name]:.3g} "
+                    f"of its rms from the jnp form (tolerance "
+                    f"{BF16_RTOL:.3g})")
+        kernels = {name: fn.as_text().count(
+            'custom_call_target="tpu_custom_call"')
+            for name, (fn, _) in runs.items()}
+        if on_tpu() and kernels != {"forward": 1, "forward + backward": 2}:
+            raise AssertionError(
+                f"the compiled causal conv holds {kernels} Mosaic custom "
+                "calls, expected the forward kernel, and with it the "
+                "backward kernel where a gradient is asked")
+        before = log.requests
+        ms = _best_ms(runs, repeats=10)
+        if log.requests != before:
+            raise AssertionError(
+                f"causal conv: {log.requests - before} recompile(s) after a "
+                "first call")
+        least = ""
+        if on_tpu():   # a share of the benchmark's table of peaks
+            rate = peaks.for_kind(jax.devices()[0].device_kind).hbm_bytes_per_s
+            fwd, bwd = (1e3 * b * batch * heads * seq / rate
+                        for b in cc.least_bytes(width))
+            least = (f"; its bytes at the HBM rate forward {fwd:.3f} ms "
+                     f"({100 * fwd / ms['forward']:.1f}% of it), forward + "
+                     f"backward {fwd + bwd:.3f} ms "
+                     f"({100 * (fwd + bwd) / ms['forward + backward']:.1f}%)")
+        tile = cc.tile_of(seq)
+        told.append(
+            f"width {width}" + (" normed" if l2_scale else "")
+            + f": tiles of {tile} tokens, "
+            f"{cc.heads_a_step(heads, width, tile)} heads a grid step, "
+            "tpu_custom_call in the compiled "
+            + ", ".join(f"{name} {n}" for name, n in kernels.items())
+            + ", from the jnp form "
+            + " ".join(f"{name} {e:.2e}" for name, e in errs.items())
+            + "; alone (information only), ms an execution: "
+            + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + least)
+    say(f"[causal conv] {batch} x {seq} tokens x {heads} heads, bf16, "
+        f"interpret={_pallas.interpret()}, 0 recompiles after a first call; "
+        + "; ".join(told))
 
 
 def lm_steps(log: CompileLog, name: str, cfg, batch: int, seq: int,
@@ -711,7 +804,7 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: it is compared with.
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
-             flagship_lm, resnet50_eager)),
+             causal_conv_pass, flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }
 

@@ -663,29 +663,11 @@ def _mla(h, lp: Dict[str, Any], cfg: TransformerConfig, rope):
         return jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
 
 
-def _causal_conv(u, w):
-    """SiLU of the depthwise causal convolution of u: (B, H, S, d) over S
-    with the taps w: (H, d, K): y_t = sum_j w_j u_(t-K+1+j), zeros before
-    the sequence's start; float32 inside, u's type out."""
-    taps, seq = w.shape[-1], u.shape[2]
-    padded = jnp.pad(u.astype(jnp.float32),
-                     ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
-    wf = w.astype(jnp.float32)
-    y = sum(padded[:, :, j:j + seq] * wf[None, :, None, :, j]
-            for j in range(taps))
-    return jax.nn.silu(y).astype(u.dtype)
-
-
-def _l2_normed(x, eps=1e-6):
-    xf = x.astype(jnp.float32)
-    return xf * lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True)
-                          + eps)
-
-
 def _gdn(h, lp: Dict[str, Any], cfg: TransformerConfig):
     """A Gated DeltaNet mixer (arXiv:2412.06464) on h: (B, S, D): the gated
     delta rule of `ops/gated_delta.py` on convolved, normalised queries and
     keys, its output normed per head, gated and projected; (B, S, D)."""
+    from horovod_tpu.ops.causal_conv import causal_conv_silu
     from horovod_tpu.ops.gated_delta import gated_delta_rule
     with jax.named_scope("gdn.project"):
         q = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wq"])
@@ -697,12 +679,11 @@ def _gdn(h, lp: Dict[str, Any], cfg: TransformerConfig):
         b = jnp.einsum("bsd,dh->bhs", h, lp["gdn_wb"],
                        preferred_element_type=jnp.float32)
     with jax.named_scope("gdn.conv"):
-        q = _causal_conv(q, lp["gdn_conv_q"])
-        k = _causal_conv(k, lp["gdn_conv_k"])
-        v = _causal_conv(v, lp["gdn_conv_v"])
+        q = causal_conv_silu(q, lp["gdn_conv_q"],
+                             l2_scale=cfg.gdn_key_dim ** -0.5)
+        k = causal_conv_silu(k, lp["gdn_conv_k"], l2_scale=1.0)
+        v = causal_conv_silu(v, lp["gdn_conv_v"])
     with jax.named_scope("gdn.scan"):
-        q = (_l2_normed(q) * cfg.gdn_key_dim ** -0.5).astype(h.dtype)
-        k = _l2_normed(k).astype(h.dtype)
         beta = jax.nn.sigmoid(b) * (2.0 if cfg.gdn_neg_eigval else 1.0)
         rate = jnp.exp(lp["gdn_a_log"].astype(jnp.float32))[None, :, None]
         g = -rate * jax.nn.softplus(

@@ -102,6 +102,20 @@ def test_phase_gated_delta_scan(smoke, capsys):
 
 
 @one_chip
+def test_phase_causal_conv_pass(smoke, capsys):
+    chip_smoke.causal_conv_pass(smoke, shape=(2, 3, 150),
+                                widths=((24, 24 ** -0.5), (7, None)))
+    out = capsys.readouterr().out
+    assert "[causal conv] 2 x 150 tokens x 3 heads, bf16, interpret=True, " \
+        "0 recompiles after a first call; width 24 normed: tiles of 256 " \
+        "tokens, 3 heads a grid step, tpu_custom_call in the compiled " \
+        "forward 0, forward + backward 0, from the jnp form y " in out
+    assert "; width 7: tiles of 256 tokens" in out
+    assert out.count(" du ") == 2 and out.count(" dw ") == 2
+    assert "HBM rate" not in out     # no share of a peak off the TPU
+
+
+@one_chip
 def test_phase_flagship_lm(smoke, capsys):
     chip_smoke.flagship_lm(smoke, cfg=TINY_LM, batch=4, seq=64, steps=5)
     out = capsys.readouterr().out

@@ -1,5 +1,6 @@
-"""Flash attention and the grouped matmul compiled by the chip's own
-compiler, without a chip.
+"""Flash attention, the grouped matmul, the gated delta rule and the
+convolution in front of it compiled by the chip's own compiler, without a
+chip.
 
 The flagship LM's default attention is the Pallas flash kernel
 (ops/flash_attention.py). The interpreter runs of
@@ -200,6 +201,52 @@ def test_gated_delta_rule_compiles_for_v5e(monkeypatch, name):
                                              HYBRID_CELL) == {}
 
 
+#: (operands, results) of the convolution's Mosaic kernels: the forward (the
+#: rows of u before a tile, u, the taps -> y) and the backward (the rows
+#: before, u, the taps, dy -> du and the partial sums of dw)
+CONV_SIGNATURES = {"fwd": [(3, 1)], "bwd": [(4, 2)]}
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+@pytest.mark.parametrize("width,l2_scale", [(96, 96 ** -0.5), (192, None)],
+                         ids=["keys-96-normed", "values-192"])
+def test_causal_conv_compiles_for_v5e(monkeypatch, width, l2_scale, name):
+    """`ops/causal_conv.py` at `olmohybrid-1chip`'s shapes, queries and keys
+    96 wide with the norm and values 192 wide without: forward and backward
+    are one Mosaic kernel each, nothing else walks the sequence, no float32
+    array of u's size is made around them, and neither can be taken for a
+    flash kernel, a grouped matmul or a kernel of the rule."""
+    from benchmark.harness import hlo
+    from benchmark.layer_metrics import attn_flash_roofline, flash_roofline
+    from horovod_tpu.ops.causal_conv import causal_conv_silu
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    b, h, s = HYBRID_RULE[:3]
+    u = jax.ShapeDtypeStruct((b, h, s, width), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((h, width, 4), jnp.bfloat16)
+
+    def fwd(u, w):
+        return causal_conv_silu(u, w, l2_scale=l2_scale)
+
+    def bwd(u, w):
+        return jax.grad(lambda u, w: fwd(u, w).astype(jnp.float32).sum(),
+                        argnums=(0, 1))(u, w)
+
+    want = CONV_SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": fwd, "bwd": bwd}[name], (u, w),
+                              n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    assert " while(" not in txt
+    assert f"f32[{b},{h},{s},{width}]" not in txt
+    taken = set(flash_roofline.SIGNATURES) | {GROUPED_SIGNATURE} \
+        | set(sum(GDN_SIGNATURES.values(), []))
+    assert not set(want) & taken
+    assert attn_flash_roofline.flash_kernels(hlo.index(txt),
+                                             HYBRID_CELL) == {}
+
+
 def test_the_hybrid_cells_step_compiles_at_full_size_and_fits(monkeypatch):
     """`benchmark.aot_check olmohybrid-1chip` as a test: the whole train
     step at the published widths, 1 x 8,192 tokens, for a described v5e: it
@@ -216,15 +263,19 @@ def test_the_hybrid_cells_step_compiles_at_full_size_and_fits(monkeypatch):
     with jax.enable_x64(False), _no_persistent_cache():
         found, problems = aot_check.check_cell(cell, topo.devices, hbm)
     assert problems == [], found
-    # the flash forward, its remat repeat (each layer of a period is its own
-    # checkpoint behind a barrier), dk/dv and dq; and for each of the three
-    # linear layers the gated delta rule's forward, its remat repeat (which
-    # writes the backward's residuals) and its backward kernel
-    assert "13 tpu_custom_call" in found and "(1 chip(s))" in found
+    # 4: the flash forward, its remat repeat (each layer of a period is its
+    # own checkpoint behind a barrier), dk/dv and dq; 9: for each of the
+    # three linear layers the gated delta rule's forward, its remat repeat
+    # (which writes the backward's residuals) and its backward kernel; 27:
+    # for each of them the convolution of q, of k and of v, each forward,
+    # repeated under remat and backward (`ops/causal_conv.py`, PR 35)
+    assert "40 tpu_custom_call" in found and "(1 chip(s))" in found
     need = float(found.split("needs ")[1].split(" GiB")[0])
     # over a quarter of the chip's memory, and what PERF.md says (14.29
-    # while the "dots" policy kept the `jnp` rule's products, until PR 33)
-    assert 0.25 * hbm / 2 ** 30 < need == pytest.approx(10.95, abs=0.15)
+    # while the "dots" policy kept the `jnp` rule's products, until PR 33;
+    # 10.95 while the convolution's float32 passes went through HBM, until
+    # PR 35)
+    assert 0.25 * hbm / 2 ** 30 < need == pytest.approx(10.53, abs=0.15)
 
 
 def test_interpret_decision_is_shared_and_visible(monkeypatch):

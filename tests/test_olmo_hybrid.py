@@ -18,6 +18,7 @@ from benchmark.reference import olmo_hybrid as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops.causal_conv import causal_conv_silu
 from horovod_tpu.ops.gated_delta import (chunked_over_recurrent_macs,
                                          chunks_of, gated_delta_rule,
                                          heads_a_step,
@@ -260,8 +261,8 @@ def test_the_convolution_is_causal():
     and so in the model's logits."""
     u = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 20, 8), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(3), (3, 8, 4), jnp.float32)
-    base = tfm._causal_conv(u, w)
-    moved = tfm._causal_conv(u.at[:, :, 11].add(1.0), w)
+    base = causal_conv_silu(u, w)
+    moved = causal_conv_silu(u.at[:, :, 11].add(1.0), w)
     changed = np.flatnonzero(np.any(np.asarray(base != moved),
                                     axis=(0, 1, 3)))
     assert changed.tolist() == [11, 12, 13, 14]       # four taps
