@@ -38,7 +38,7 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
-from benchmark.families import olmo_hybrid
+from benchmark.families import olmo_hybrid, phi4_flash
 from benchmark.harness import peaks
 from benchmark.layer_metrics import gdn_scan_roofline
 from horovod_tpu import native
@@ -48,8 +48,11 @@ from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import causal_conv as cc
 from horovod_tpu.ops import gated_delta as gd
 from horovod_tpu.ops import grouped_matmul as gm
+from horovod_tpu.ops import selective_scan as ss
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
-                                             flash_attention)
+                                             flash_attention,
+                                             masked_attention_reference,
+                                             window_tile_share)
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 
@@ -419,6 +422,29 @@ def grouped_kernel(log: CompileLog,
                 for name, ms in alone.items()))
 
 
+def _counted(runs: dict, want: dict, what: str) -> dict:
+    """The Mosaic custom calls each compiled program of `runs` holds; on the
+    TPU they must be `want`."""
+    kernels = {name: fn.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+        for name, (fn, _) in runs.items()}
+    if on_tpu() and kernels != want:
+        raise AssertionError(
+            f"the compiled {what} holds {kernels} Mosaic custom calls, "
+            f"expected {want}")
+    return kernels
+
+
+def _timed_without_recompiles(log: CompileLog, runs: dict, what: str,
+                              repeats: int) -> dict:
+    before = log.requests
+    ms = _best_ms(runs, repeats=repeats)
+    if log.requests != before:
+        raise AssertionError(f"{what}: {log.requests - before} recompile(s) "
+                             "after a first call")
+    return ms
+
+
 def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                      checked=256) -> None:
     """The gated delta rule's kernels (ops/gated_delta.py) alone at
@@ -469,13 +495,8 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                         args),
             "forward + backward": (jax.jit(both).lower(*args).compile(),
                                    args)}
-    kernels = {name: fn.as_text().count('custom_call_target="tpu_custom_call"')
-               for name, (fn, _) in runs.items()}
-    if on_tpu() and kernels != {"forward": 1, "forward + backward": 2}:
-        raise AssertionError(
-            f"the compiled gated delta rule holds {kernels} Mosaic custom "
-            "calls, expected the forward kernel, and with it the backward "
-            "kernel where a gradient is asked")
+    kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
+                       "gated delta rule")
     ms = _best_ms(runs, repeats=5)
     shares = ""
     if on_tpu():   # the shares are of the benchmark's table of peaks
@@ -550,20 +571,9 @@ def causal_conv_pass(log: CompileLog, shape=(1, 30, 8192),
                     f"causal conv, width {width}: {name} is {errs[name]:.3g} "
                     f"of its rms from the jnp form (tolerance "
                     f"{BF16_RTOL:.3g})")
-        kernels = {name: fn.as_text().count(
-            'custom_call_target="tpu_custom_call"')
-            for name, (fn, _) in runs.items()}
-        if on_tpu() and kernels != {"forward": 1, "forward + backward": 2}:
-            raise AssertionError(
-                f"the compiled causal conv holds {kernels} Mosaic custom "
-                "calls, expected the forward kernel, and with it the "
-                "backward kernel where a gradient is asked")
-        before = log.requests
-        ms = _best_ms(runs, repeats=10)
-        if log.requests != before:
-            raise AssertionError(
-                f"causal conv: {log.requests - before} recompile(s) after a "
-                "first call")
+        kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
+                           "causal conv")
+        ms = _timed_without_recompiles(log, runs, "causal conv", 10)
         least = ""
         if on_tpu():   # a share of the benchmark's table of peaks
             rate = peaks.for_kind(jax.devices()[0].device_kind).hbm_bytes_per_s
@@ -585,6 +595,148 @@ def causal_conv_pass(log: CompileLog, shape=(1, 30, 8192),
             + "; alone (information only), ms an execution: "
             + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + least)
     say(f"[causal conv] {batch} x {seq} tokens x {heads} heads, bf16, "
+        f"interpret={_pallas.interpret()}, 0 recompiles after a first call; "
+        + "; ".join(told))
+
+
+def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
+                        checked=512) -> None:
+    """The selective scan's kernels (ops/selective_scan.py) alone at
+    `phi4flash-1chip`'s (batch, tokens, channels, states): the output and
+    the six gradients over the first `checked` tokens against the
+    token-by-token `jnp` form, the Mosaic kernels the compiled forward and
+    forward + backward hold (on the TPU: one, and two), no compile request
+    after a first call, then their times against the least time the
+    benchmark's `ssm_scan_roofline` counts (the family's `scan_work`)."""
+    batch, seq, channels, states = shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    bf16 = jnp.bfloat16
+    c, cot = (jax.random.normal(k, (batch, seq, channels), bf16)
+              for k in ks[:2])
+    # steps and rates as the model's initialisation draws them
+    delta = jnp.exp(jax.random.uniform(
+        ks[2], (batch, seq, channels), minval=np.log(1e-3),
+        maxval=np.log(0.1)))
+    rates = -jnp.broadcast_to(jnp.arange(1, states + 1, dtype=jnp.float32),
+                              (channels, states))
+    b_in, c_out = (jax.random.normal(k, (batch, seq, states), bf16)
+                   for k in ks[3:])
+    args = (c, delta, rates, b_in, c_out, jnp.ones((channels,), jnp.float32))
+
+    def both(fn, cot, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(cot.astype(out.dtype))
+
+    def head(x):
+        return x[:, :checked] if x.ndim == 3 else x
+
+    short = tuple(head(x) for x in args)
+    got = jax.jit(functools.partial(both, ss.selective_scan))(
+        head(cot), *short)
+    want = jax.jit(functools.partial(both, ss.reference_selective_scan))(
+        head(cot), *(x.astype(jnp.float32) for x in short))
+    errs = {}
+    for name, g, w in zip(("y", "dc", "ddelta", "dA", "dB", "dC", "dD"),
+                          got, want):
+        errs[name] = _rms_off(g, w)
+        if not errs[name] <= BF16_RTOL:           # a NaN fails too
+            raise AssertionError(
+                f"selective scan: {name} is {errs[name]:.3g} of its rms "
+                f"from the token-by-token form over {checked} tokens "
+                f"(tolerance {BF16_RTOL:.3g})")
+    runs = {"forward": (jax.jit(ss.selective_scan).lower(*args).compile(),
+                        args),
+            "forward + backward": (jax.jit(functools.partial(
+                both, ss.selective_scan)).lower(cot, *args).compile(),
+                (cot, *args))}
+    kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
+                       "selective scan")
+    ms = _timed_without_recompiles(log, runs, "selective scan", 5)
+    shares = ""
+    if on_tpu():   # the shares are of the benchmark's table of peaks
+        p = peaks.for_kind(jax.devices()[0].device_kind)
+        fwd, bwd = (gdn_scan_roofline.least_seconds((1, *work), p)[0] * 1e3
+                    for work in phi4_flash.scan_work(batch * seq, channels,
+                                                     states))
+        shares = (f"; least time forward {fwd:.3f} ms "
+                  f"({100 * fwd / ms['forward']:.2f}% of it), forward + "
+                  f"backward {fwd + bwd:.3f} ms "
+                  f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
+    chunk = ss.chunk_of(seq)
+    say(f"[selective scan] {batch} x {seq} tokens x {channels} channels x "
+        f"{states} states, bf16 (delta float32): tiles of {chunk} tokens, "
+        f"{seq // chunk} a sequence; interpret={_pallas.interpret()}, 0 "
+        "recompiles after a first call, tpu_custom_call in the compiled "
+        + ", ".join(f"{name} {n}" for name, n in kernels.items())
+        + f"; the first {checked} tokens from the token-by-token form "
+        + " ".join(f"{name} {e:.2e}" for name, e in errs.items())
+        + "; alone (information only), ms an execution: "
+        + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)
+
+
+def windowed_grouped_flash(log: CompileLog, shape=(1, 20, 10, 8192, 64, 128),
+                           window=512, checked=2048) -> None:
+    """One softmax of `phi4flash-1chip`'s differential attention alone:
+    (batch, query heads, K/V heads, tokens, keys' width, values' width) with
+    and without the window. Output and the three gradients over the first
+    `checked` tokens against the mask written out, the Mosaic kernels the
+    compiled programs hold (forward one, with the gradients three), no
+    compile request after a first call, and the times of the windowed call
+    beside the full one's."""
+    batch, heads, kv_heads, seq, dk, dv = shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    bf16 = jnp.bfloat16
+    q = jax.random.normal(ks[0], (batch, heads, seq, dk), bf16)
+    k = jax.random.normal(ks[1], (batch, kv_heads, seq, dk), bf16)
+    v, cot = (jax.random.normal(kk, (batch, n, seq, dv), bf16)
+              for kk, n in ((ks[2], kv_heads), (ks[3], heads)))
+
+    def both(attn, banded, cot, q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, banded), q, k, v)
+        return (out,) + vjp(cot.astype(out.dtype))
+
+    def flash(q, k, v, banded):
+        return flash_attention(q, k, v, causal=True, window=banded)
+
+    def plain(q, k, v, banded):
+        return masked_attention_reference(q, k, v, True, None, banded)
+
+    told = []
+    for banded in (window, None):
+        short = tuple(x[:, :, :checked] for x in (cot, q, k, v))
+        got = jax.jit(functools.partial(both, flash, banded))(*short)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(functools.partial(both, plain, banded))(
+                *(x.astype(jnp.float32) for x in short))
+        errs = {}
+        for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+            errs[name] = _rms_off(g, w)
+            if g.shape != w.shape or not errs[name] <= BF16_RTOL:
+                raise AssertionError(
+                    f"flash, window {banded}, {heads} heads over "
+                    f"{kv_heads}: {name} is {errs[name]:.3g} of its rms "
+                    f"from the mask written out (tolerance {BF16_RTOL:.3g})")
+        runs = {"forward": (jax.jit(functools.partial(
+            flash, banded=banded)).lower(q, k, v).compile(), (q, k, v)),
+            "forward + backward": (jax.jit(functools.partial(
+                both, flash, banded)).lower(cot, q, k, v).compile(),
+                (cot, q, k, v))}
+        kernels = _counted(runs, {"forward": 1, "forward + backward": 3},
+                           "grouped flash attention")
+        ms = _timed_without_recompiles(log, runs, "grouped flash", 10)
+        share = window_tile_share(seq, banded) if banded and banded < seq \
+            else causal_tile_share(seq)
+        told.append(
+            (f"window {banded}: window_tile_share {share:.4f}" if banded else
+             f"no window: causal_tile_share {share:.4f}")
+            + ", tpu_custom_call in the compiled "
+            + ", ".join(f"{name} {n}" for name, n in kernels.items())
+            + f", the first {checked} tokens from the mask written out "
+            + " ".join(f"{name} {e:.2e}" for name, e in errs.items())
+            + "; alone (information only), ms an execution: "
+            + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()))
+    say(f"[grouped flash] {batch} x {seq} tokens, {heads} query heads over "
+        f"{kv_heads} K/V heads, {dk} | {dv}, bf16, "
         f"interpret={_pallas.interpret()}, 0 recompiles after a first call; "
         + "; ".join(told))
 
@@ -804,7 +956,8 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: it is compared with.
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
-             causal_conv_pass, flagship_lm, resnet50_eager)),
+             causal_conv_pass, selective_scan_pass, windowed_grouped_flash,
+             flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }
 

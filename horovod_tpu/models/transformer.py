@@ -38,6 +38,18 @@ parameters of a patterned stack lie per kind, `params["layers"][kind][leaf]`,
 stacked over (periods, the layers of that kind in a period), and one scan
 body runs a period's layers in order.
 
+A model whose layers hand results on to later layers states `segments`: a
+sequence of (pattern, periods), each a scan of its own over its periods
+(`params["segments"][i][kind][leaf]`). Beside "full" and "linear" a pattern
+may name "window" (attention over the last `window` keys), "ssm" (a Mamba-1
+selective state-space mixer, arXiv:2312.00752, by `ops/selective_scan.py`),
+"gmu" (a Gated Memory Unit: a gate on the memory the last "ssm" layer of the
+segment with the "full" layer handed on) and "cross" (attention whose keys
+and values are that "full" layer's). With `diff_attention` (the difference
+of two softmaxes over paired heads, arXiv:2410.05258), `n_kv_heads` fewer
+key and value heads than query heads, `attention_bias` and `tied_head` that
+is SambaY's decoder-hybrid-decoder (arXiv:2507.06607), Phi-4-mini-flash's.
+
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
 
@@ -64,16 +76,17 @@ from horovod_tpu.parallel.ring_attention import (
     blockwise_attention_reference, ring_attention)
 from horovod_tpu.parallel.mesh import AXIS_ORDER, mesh_axis_sizes
 
-#: The `jax.named_scope`s of the train step outside its three mixers'
-#: (`moe.*` of `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*` below):
+#: The `jax.named_scope`s of the train step outside its mixers' (`moe.*` of
+#: `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`
+#: below):
 #: a scope reaches the compiled program as a component of an instruction's
 #: `op_name`, through `jit`, remat, the layer scan and differentiation, and a
 #: profile shows it in the op's name. The tests hold the program to this
 #: list and the benchmark's `harness/step_scopes.py` partitions the step's
 #: device time by it (docs/observability.md, "Scopes of the compiled step").
-STEP_SCOPES = ("attn.project", "attn.attend", "attn.out", "mlp.dense",
-               "vocab.embed", "vocab.head", "vocab.loss", "grad.reduce",
-               "opt.update")
+STEP_SCOPES = ("attn.project", "attn.attend", "attn.window", "attn.out",
+               "mlp.dense", "vocab.embed", "vocab.head", "vocab.loss",
+               "grad.reduce", "opt.update")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,8 +204,36 @@ class TransformerConfig:
     gdn_neg_eigval: bool = False
     # One period of layer kinds, repeated n_layers / len(layer_pattern)
     # times; () is a stack of one kind. "full": a layer as the other fields
-    # state it; "linear": the same layer with attention="gdn".
+    # state it; "linear": the same layer with attention="gdn"; the other
+    # kinds: `LAYER_KINDS`.
     layer_pattern: Tuple[str, ...] = ()
+    # A stack in several parts, each ((kinds of a period), periods), run in
+    # order, each a scan of its own; () is one part, `layer_pattern`'s. The
+    # part with the "full" layer hands its last "ssm" layer's scan output
+    # and the "full" layer's keys and values on to the "gmu" and "cross"
+    # layers of the parts behind it.
+    segments: Tuple[Tuple[Tuple[str, ...], int], ...] = ()
+    # key and value heads (0: as many as n_heads); n_heads / n_kv_heads
+    # query heads read one: attn "flash" or "local"
+    n_kv_heads: int = 0
+    # keys a query of a "window" layer sees, its own the last (0: every
+    # layer sees the whole causal half); a stack without a pattern is
+    # windowed throughout
+    window: int = 0
+    # the head is the embedding's transpose: one leaf read twice
+    tied_head: bool = False
+    # biases on wq, wk, wv and wo
+    attention_bias: bool = False
+    # differential attention: heads pair up as (2i, 2i + 1); a pair's
+    # output is (1 - l0) RMSNorm(softmax(q1 k1^T) vv - lam softmax(q2 k2^T)
+    # vv), vv the pair's two value heads side by side (`_diff_attention`)
+    diff_attention: bool = False
+    # an "ssm" layer: ssm_expand * d_model channels, each with ssm_state
+    # states, a depthwise causal convolution of ssm_conv taps (with bias),
+    # the step's projection through a rank of ceil(d_model / 16)
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
     # x + Norm(f(x)) in place of x + f(Norm(x)): each sub-layer's norm on
     # its output, inside the residual (Olmo 2's arrangement)
     post_norm: bool = False
@@ -218,6 +259,18 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
     @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def ssm_channels(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return -(-self.d_model // 16)
+
+    @property
     def rope_dim(self) -> int:
         """Width of what the rotary embedding turns, per head."""
         return self.qk_rope_dim if self.attention == "mla" else self.head_dim
@@ -240,6 +293,17 @@ STACKS = ("dense_layers", "layers")
 GDN_LEAVES = frozenset({
     "gdn_wq", "gdn_wk", "gdn_wv", "gdn_wz", "gdn_wa", "gdn_wb", "gdn_a_log",
     "gdn_dt_bias", "gdn_conv_q", "gdn_conv_k", "gdn_conv_v", "gdn_o_scale"})
+#: ... only a state-space layer, a Gated Memory Unit
+SSM_LEAVES = frozenset({
+    "ssm_w_in", "ssm_conv", "ssm_conv_bias", "ssm_w_x", "ssm_w_dt",
+    "ssm_dt_bias", "ssm_a_log", "ssm_d_skip", "ssm_w_out"})
+GMU_LEAVES = frozenset({"gmu_w1", "gmu_w2"})
+#: ... only attention with biases, differential attention
+BIAS_LEAVES = frozenset({"bq", "bk", "bv", "bo"})
+DIFF_LEAVES = frozenset({"lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
+                         "subln_scale"})
+#: the mixers that have no attention of their own
+_NO_ATTENTION = ("ssm", "gmu")
 
 
 def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
@@ -252,8 +316,26 @@ def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
         d_ff=cfg.d_ff_dense, n_layers=cfg.first_k_dense, first_k_dense=0)
 
 
-#: what a layer of each kind of a `layer_pattern` changes of the configuration
-LAYER_KINDS = {"full": {}, "linear": {"attention": "gdn"}}
+#: what a layer of each kind of a pattern changes of the configuration
+LAYER_KINDS = {"full": {"window": 0},
+               "linear": {"attention": "gdn", "window": 0},
+               "window": {},
+               "ssm": {"attention": "ssm"},
+               "gmu": {"attention": "gmu"},
+               "cross": {"attention": "cross", "window": 0}}
+
+
+def _reads_are_handed_on(cfg: TransformerConfig) -> bool:
+    """Whether every "gmu" layer has a memory to read and every "cross"
+    layer keys and values: a segment before theirs hands them on."""
+    handed = set()
+    for at, (pattern, _) in enumerate(cfg.segments):
+        if ("gmu" in pattern and "memory" not in handed) or \
+                ("cross" in pattern and "kv" not in handed):
+            return False
+        if _hands_on(cfg, at):
+            handed = {"kv"} | ({"memory"} if "ssm" in pattern else set())
+    return True
 
 
 def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
@@ -264,11 +346,19 @@ def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
     return dataclasses.replace(cfg, **LAYER_KINDS[kind])
 
 
-def _kinds(cfg: TransformerConfig) -> Dict[str, int]:
-    """The kinds of `cfg`'s layer pattern, each with how many layers of a
-    period are of it."""
-    return {kind: cfg.layer_pattern.count(kind)
-            for kind in sorted(set(cfg.layer_pattern))}
+def _kinds(cfg: TransformerConfig, pattern=None) -> Dict[str, int]:
+    """The kinds of `cfg`'s layer pattern (or of `pattern`), each with how
+    many layers of a period are of it."""
+    pattern = cfg.layer_pattern if pattern is None else pattern
+    return {kind: pattern.count(kind) for kind in sorted(set(pattern))}
+
+
+def _hands_on(cfg: TransformerConfig, index: int) -> bool:
+    """Whether segment `index` hands a memory and keys and values on: it has
+    the "full" layer, and a later segment reads them."""
+    return "full" in cfg.segments[index][0] and any(
+        kind in ("gmu", "cross") for pattern, _ in cfg.segments[index + 1:]
+        for kind in pattern)
 
 
 def _periods(cfg: TransformerConfig) -> int:
@@ -317,6 +407,21 @@ def _present(tree: Dict[str, Any], cfg: TransformerConfig):
         absent |= {"wq", "wk", "wv", "q_scale", "k_scale"}
     else:
         absent |= GDN_LEAVES
+    if cfg.attention != "ssm":
+        absent |= SSM_LEAVES
+    if cfg.attention != "gmu":
+        absent |= GMU_LEAVES
+    if cfg.attention in _NO_ATTENTION:
+        absent |= {"wq", "wk", "wv", "wo", "q_scale", "k_scale"}
+    if cfg.attention == "cross":
+        absent |= {"wk", "wv", "bk", "bv", "k_scale"}
+    if not cfg.attention_bias or cfg.attention in _NO_ATTENTION + ("gdn",):
+        absent |= BIAS_LEAVES
+    if not cfg.diff_attention or cfg.attention in _NO_ATTENTION + ("gdn",):
+        absent |= DIFF_LEAVES
+    if cfg.tied_head:
+        absent.add("unembed")
+    absent.add("layers" if cfg.segments else "segments")
     if not cfg.qk_norm:
         absent |= {"q_scale", "k_scale"}
     if cfg.num_experts:
@@ -338,7 +443,13 @@ def _present(tree: Dict[str, Any], cfg: TransformerConfig):
                     for kind in _kinds(cfg)}
         return _present(leaves, stack_cfg)
 
-    return {k: of_stack(k, v) if k in STACKS else v
+    def of_segments(segments):
+        return [{kind: _present(leaves[kind], _kind_cfg(cfg, kind))
+                 for kind in _kinds(cfg, pattern)}
+                for (pattern, _), leaves in zip(cfg.segments, segments)]
+
+    return {k: of_stack(k, v) if k in STACKS
+            else of_segments(v) if k == "segments" else v
             for k, v in tree.items() if k not in absent}
 
 
@@ -354,12 +465,17 @@ def _layer_makers(key: jax.Array, cfg: TransformerConfig,
     D, H, F, E = cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.num_experts
     L = (_stack_depth(cfg),) if lead is None else tuple(lead)
     dt = cfg.dtype
+    G = H
     if cfg.attention == "mla":
         dq, dvo = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
     elif cfg.attention == "gdn":
-        H, dq, dvo = cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        G = H = cfg.gdn_heads
+        dq, dvo = cfg.gdn_key_dim, cfg.gdn_value_dim
     else:
         dq = dvo = cfg.head_dim
+        G = cfg.kv_heads
+    Es, N, R = cfg.ssm_channels, cfg.ssm_state, cfg.dt_rank
+    ms = jax.random.split(jax.random.fold_in(key, 5), 16)
     held = cfg.experts_held or E
     shared = cfg.shared_experts * F
     ks = jax.random.split(key, 12)
@@ -388,11 +504,42 @@ def _layer_makers(key: jax.Array, cfg: TransformerConfig,
             gs[10], L + (Hg,), jnp.float32, math.log(1e-3), math.log(0.1)))
         return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
 
+    def small(k, *shape, deviation=0.02):
+        return lambda: jax.random.normal(k, L + shape, dt) * deviation
+
+    def ssm_rates():
+        # A = -(1 .. N) per channel, held as its logarithm (Mamba's own)
+        return jnp.broadcast_to(jnp.log(jnp.arange(
+            1, N + 1, dtype=jnp.float32)), L + (Es, N)).astype(dt)
+
+    def ssm_step_bias():
+        # dt ~ log-U(0.001, 0.1), held as softplus^-1(dt)
+        step = jnp.exp(jax.random.uniform(
+            ms[6], L + (Es,), jnp.float32, math.log(1e-3), math.log(0.1)))
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+
     return {
         "ln1_scale": ones(D), "ln1_bias": zeros(D),
         "wq": norm(ks[0], (D, H, dq), D),
-        "wk": norm(ks[1], (D, H, dq), D),
-        "wv": norm(ks[2], (D, H, dvo), D),
+        "wk": norm(ks[1], (D, G, dq), D),
+        "wv": norm(ks[2], (D, G, dvo), D),
+        "bq": small(ms[7], H, dq), "bk": small(ms[8], G, dq),
+        "bv": small(ms[9], G, dvo), "bo": small(ms[10], D),
+        "lambda_q1": small(ms[11], dq, deviation=0.1),
+        "lambda_k1": small(ms[12], dq, deviation=0.1),
+        "lambda_q2": small(ms[13], dq, deviation=0.1),
+        "lambda_k2": small(ms[14], dq, deviation=0.1),
+        "subln_scale": ones(2 * dvo),
+        "ssm_w_in": norm(ms[0], (D, 2 * Es), D),
+        "ssm_conv": norm(ms[1], (Es, cfg.ssm_conv), cfg.ssm_conv),
+        "ssm_conv_bias": small(ms[2], Es),
+        "ssm_w_x": norm(ms[3], (Es, R + 2 * N), Es),
+        "ssm_w_dt": norm(ms[4], (R, Es), R),
+        "ssm_dt_bias": ssm_step_bias, "ssm_a_log": ssm_rates,
+        "ssm_d_skip": ones(Es),
+        "ssm_w_out": norm(ms[5], (Es, D), Es),
+        "gmu_w1": norm(ms[0], (D, Es), D),
+        "gmu_w2": norm(ms[5], (Es, D), Es),
         "wkv_a": norm(xs[0], (D, cfg.kv_latent + cfg.qk_rope_dim), D),
         "kv_scale": ones(cfg.kv_latent),
         "wkv_b": norm(xs[1], (cfg.kv_latent, H, cfg.qk_nope_dim + dvo),
@@ -441,13 +588,24 @@ def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                                     _kind_cfg(cfg, kind), (_periods(cfg), n))
                 for i, (kind, n) in enumerate(_kinds(cfg).items())}
 
+    def segments():
+        # per segment, each kind's layers over (periods, its layers there)
+        return [{kind: _layer_makers(
+            jax.random.fold_in(key, 100 + 10 * at + i), _kind_cfg(cfg, kind),
+            (periods, n)) for i, (kind, n) in enumerate(
+                _kinds(cfg, pattern).items())}
+            for at, (pattern, periods) in enumerate(cfg.segments)]
+
+    # a tied embedding is drawn as a head is: logits of unit deviation
+    embed_deviation = 0.02 if cfg.tied_head else 0.02 * D ** 0.5
     # every leaf any architecture has, each made only if this one has it
     make = {
-        "embed": lambda: norm(ks[7], (V, D)) * 0.02 * D ** 0.5,
+        "embed": lambda: norm(ks[7], (V, D)) * embed_deviation,
         "pos": lambda: norm(ks[8], (cfg.max_seq, D)) * 0.02,
         "dense_layers": _layer_makers(jax.random.fold_in(key, 2),
                                       _stack_cfg(cfg, "dense_layers")),
         "layers": layers(),
+        "segments": segments(),
         "lnf_scale": lambda: jnp.ones((D,), dt),
         "lnf_bias": lambda: jnp.zeros((D,), dt),
         "unembed": lambda: norm(ks[9], (D, V)) * D ** -0.5,
@@ -479,6 +637,16 @@ def _layer_specs(*lead: Optional[str]) -> Dict[str, Any]:
         "gdn_conv_q": spec(None, None, None),
         "gdn_conv_k": spec(None, None, None),
         "gdn_conv_v": spec(None, None, None), "gdn_o_scale": spec(None),
+        # ... and so are a state-space layer's, a Gated Memory Unit's and
+        # differential attention's
+        **{k: spec(None, None) for k in (
+            "ssm_w_in", "ssm_conv", "ssm_w_x", "ssm_w_dt", "ssm_a_log",
+            "ssm_w_out", "gmu_w1", "gmu_w2")},
+        **{k: spec(None) for k in (
+            "ssm_conv_bias", "ssm_dt_bias", "ssm_d_skip", "bo",
+            *sorted(DIFF_LEAVES))},
+        "bq": spec("tp", None), "bk": spec("tp", None),
+        "bv": spec("tp", None),
         "wo": spec("tp", None, None),
         "q_scale": spec("tp", None), "k_scale": spec("tp", None),
         "ln2_scale": spec(None), "ln2_bias": spec(None),
@@ -502,6 +670,13 @@ def _per_kind(cfg: TransformerConfig, leaves):
     return {kind: leaves() for kind in _kinds(cfg)}
 
 
+def _per_segment(cfg: TransformerConfig, leaves):
+    """A segmented stack's tree: per segment, `leaves()` under each kind of
+    its pattern."""
+    return [{kind: leaves() for kind in _kinds(cfg, pattern)}
+            for pattern, _ in cfg.segments]
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec tree matching init()'s structure (in_specs for
     shard_map; also the NamedSharding layout for device_put). The leading
@@ -512,6 +687,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     return _present({
         "embed": P(), "pos": P(), "dense_layers": _layer_specs(None),
         "layers": _per_kind(cfg, lambda: _layer_specs(*lead)),
+        "segments": _per_segment(cfg, lambda: _layer_specs(None, None)),
         "lnf_scale": P(), "lnf_bias": P(), "unembed": P(),
     }, cfg)
 
@@ -535,7 +711,10 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
           "ln2_scale": data_axes, "ln2_bias": data_axes,
           "wq": tp_sharded, "wk": tp_sharded, "wv": tp_sharded,
           "wkv_a": data_axes, "kv_scale": data_axes, "wkv_b": tp_sharded,
-          **dict.fromkeys(GDN_LEAVES, data_axes),
+          **dict.fromkeys(GDN_LEAVES | SSM_LEAVES | GMU_LEAVES | DIFF_LEAVES,
+                          data_axes),
+          "bq": tp_sharded, "bk": tp_sharded, "bv": tp_sharded,
+          "bo": data_axes,
           "wo": tp_sharded, "q_scale": tp_sharded, "k_scale": tp_sharded,
           "router": data_axes, "we1": experts, "we2": experts,
           "we_gate": experts,
@@ -544,6 +723,7 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
           "b2": data_axes, "w_gate": tp_sharded}
     return _present({"embed": glob, "pos": glob, "dense_layers": dict(lp),
                      "layers": _per_kind(cfg, lambda: dict(lp)),
+                     "segments": _per_segment(cfg, lambda: dict(lp)),
                      "lnf_scale": glob, "lnf_bias": glob, "unembed": glob},
                     cfg)
 
@@ -608,10 +788,16 @@ def _rope(x, angles):
 
 
 def _attend(q, k, v, cfg: TransformerConfig):
-    """Causal attention of q, k: (B, H_loc, S_loc, dq) and v: (B, H_loc,
-    S_loc, dv) by the algorithm `cfg.attn` names."""
+    """Causal attention of q: (B, H_loc, S_loc, dq), k: (B, G_loc, S_loc,
+    dq) and v: (B, G_loc, S_loc, dv) by the algorithm `cfg.attn` names;
+    over the last `cfg.window` keys where there is a window."""
     # the default scale, (the keys' width)^-1/2, is left to each algorithm
     scale = {} if cfg.score_scale is None else {"scale": cfg.score_scale}
+    banded = cfg.window or q.shape[1] != k.shape[1]
+    if banded and cfg.attn not in ("flash", "local"):
+        raise HorovodTpuError(
+            f"a window or fewer key heads than query heads: attn="
+            f"{cfg.attn!r} cannot run them; use 'flash' or 'local'")
     if cfg.attention == "mla" and cfg.attn not in ("flash", "local"):
         # ring and Ulysses attention build their buffers and exchanges from
         # one head width
@@ -632,7 +818,19 @@ def _attend(q, k, v, cfg: TransformerConfig):
                 "attn='flash' requires sp=1 (shard-local attention); use "
                 "attn='ring' or 'ulysses' for sequence parallelism")
         from horovod_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True, **scale)
+        if not cfg.window:
+            return flash_attention(q, k, v, causal=True, **scale)
+        # a scope of their own inside `attn.attend`: a windowed layer's
+        # kernels have the shapes of a full layer's, and a reader of the
+        # compiled step tells them apart by this name alone
+        with jax.named_scope("attn.window"):
+            return flash_attention(q, k, v, causal=True, window=cfg.window,
+                                   **scale)
+    if banded:
+        from horovod_tpu.ops.flash_attention import (
+            masked_attention_reference)
+        return masked_attention_reference(
+            q, k, v, True, cfg.score_scale, cfg.window or None)
     return blockwise_attention_reference(q, k, v, causal=True, **scale)
 
 
@@ -696,6 +894,118 @@ def _gdn(h, lp: Dict[str, Any], cfg: TransformerConfig):
         return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"])
 
 
+def _conv_silu(u, taps, bias):
+    """SiLU of the depthwise causal convolution of u: (B, S, E) over S with
+    taps: (E, K) and a bias: (E,), zeros before the sequence's start; K
+    shifted multiply-adds in float32 that the compiler fuses into one pass.
+    (`ops/causal_conv.py` holds heads-major (B, H, S, d) arrays; a
+    state-space layer's channels are token-major, and two transposes of the
+    array would cost more than the convolution.)"""
+    taps_n, seq = taps.shape[-1], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (taps_n - 1, 0), (0, 0)))
+    y = bias.astype(jnp.float32) + sum(
+        padded[:, j:j + seq].astype(jnp.float32)
+        * taps[:, j].astype(jnp.float32) for j in range(taps_n))
+    return jax.nn.silu(y).astype(u.dtype)
+
+
+def _ssm(h, lp: Dict[str, Any], cfg: TransformerConfig):
+    """A Mamba-1 mixer (arXiv:2312.00752) on h: (B, S, D): the selective
+    scan of `ops/selective_scan.py` on the convolved input, gated and
+    projected. Returns ((B, S, D), the scan's output y before the gate,
+    (B, S, E): what a Gated Memory Unit reads)."""
+    from horovod_tpu.ops.selective_scan import selective_scan
+    E, N, R = cfg.ssm_channels, cfg.ssm_state, cfg.dt_rank
+    f32 = jnp.float32
+    with jax.named_scope("ssm.project"):
+        xz = jnp.einsum("bsd,dte->tbse", h,
+                        lp["ssm_w_in"].reshape(-1, 2, E))
+    with jax.named_scope("ssm.conv"):
+        c = _conv_silu(xz[0], lp["ssm_conv"], lp["ssm_conv_bias"])
+    with jax.named_scope("ssm.project"):
+        low = jnp.einsum("bse,er->bsr", c, lp["ssm_w_x"])
+        step = jnp.einsum("bsr,re->bse", low[..., :R], lp["ssm_w_dt"],
+                          preferred_element_type=f32)
+    with jax.named_scope("ssm.scan"):
+        delta = jax.nn.softplus(step + lp["ssm_dt_bias"].astype(f32))
+        y = selective_scan(c, delta, -jnp.exp(lp["ssm_a_log"].astype(f32)),
+                           low[..., R:R + N], low[..., R + N:],
+                           lp["ssm_d_skip"].astype(f32))
+    with jax.named_scope("ssm.gate"):
+        gated = (y.astype(f32) * jax.nn.silu(xz[1].astype(f32))).astype(
+            h.dtype)
+    with jax.named_scope("ssm.out"):
+        return jnp.einsum("bse,ed->bsd", gated, lp["ssm_w_out"]), y
+
+
+def _gmu(h, lp: Dict[str, Any], memory):
+    """A Gated Memory Unit (arXiv:2507.06607) on h: (B, S, D): the memory
+    (B, S, E) an earlier state-space layer handed on, gated by h and
+    projected."""
+    with jax.named_scope("gmu.project"):
+        gate = jnp.einsum("bsd,de->bse", h, lp["gmu_w1"])
+    with jax.named_scope("gmu.gate"):
+        gated = (jax.nn.silu(gate.astype(jnp.float32))
+                 * memory.astype(jnp.float32)).astype(h.dtype)
+    with jax.named_scope("gmu.out"):
+        return jnp.einsum("bse,ed->bsd", gated, lp["gmu_w2"])
+
+
+def _projected(h, lp: Dict[str, Any], w: str, b: str):
+    """h through the heads of lp[w], with the bias lp[b] where there is
+    one: (B, heads, S, width)."""
+    y = jnp.einsum("bsd,dhk->bhsk", h, lp[w])
+    return y + lp[b][None, :, None, :] if b in lp else y
+
+
+def _paired(x):
+    """The even and the odd heads of x: (B, 2P, S, d), each (B, P, S, d)."""
+    batch, heads, seq, width = x.shape
+    x = x.reshape(batch, heads // 2, 2, seq, width)
+    return x[:, :, 0], x[:, :, 1]
+
+
+def _diff_keys_values(h, lp: Dict[str, Any]):
+    """What differential attention reads of a layer: the even key heads, the
+    odd ones, (B, Q, S, d) each, and each pair's two value heads side by
+    side, (B, Q, S, 2d)."""
+    k1, k2 = _paired(_projected(h, lp, "wk", "bk"))
+    return k1, k2, jnp.concatenate(_paired(_projected(h, lp, "wv", "bv")),
+                                   axis=-1)
+
+
+def _diff_attention(h, lp: Dict[str, Any], cfg: TransformerConfig, depth,
+                    kv=None):
+    """Differential attention (arXiv:2410.05258) on h: (B, S, D): query pair
+    i = heads (2i, 2i + 1) reads K/V pair i // (P / Q); the pair's output is
+    (1 - l0) RMSNorm(a1 - lam a2) over its 2d values, lam = exp(lq1 . lk1)
+    - exp(lq2 . lk2) + l0 with l0 = 0.8 - 0.6 exp(-0.3 depth), `depth` the
+    layer's index in the model. `kv`: another layer's `_diff_keys_values`
+    (a "cross" layer); None: this layer's own. Returns ((B, S, D) before the
+    output bias, the keys and values read)."""
+    f32 = jnp.float32
+    with jax.named_scope("attn.project"):
+        q1, q2 = _paired(_projected(h, lp, "wq", "bq"))
+        if kv is None:
+            kv = _diff_keys_values(h, lp)
+    with jax.named_scope("attn.attend"):
+        k1, k2, vv = kv
+        a1, a2 = _attend(q1, k1, vv, cfg), _attend(q2, k2, vv, cfg)
+        l0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, f32))
+        lam = jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                              * lp["lambda_k1"].astype(f32))) \
+            - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                              * lp["lambda_k2"].astype(f32))) + l0
+        a = a1.astype(f32) - lam * a2.astype(f32)
+        a = (a * lax.rsqrt(jnp.mean(jnp.square(a), axis=-1, keepdims=True)
+                           + cfg.rms_norm_eps)
+             * lp["subln_scale"].astype(f32) * (1.0 - l0)).astype(h.dtype)
+    with jax.named_scope("attn.out"):
+        # pair i is heads 2i and 2i + 1 of the output projection
+        wo = lp["wo"].reshape(a.shape[1], a.shape[3], -1)
+        return jnp.einsum("bpsk,pkd->bsd", a, wo), kv
+
+
 def _mlp(h, w_gate, w_up, w_down):
     """W_down (silu(W_gate h) * W_up h), or W_down gelu(W_up h) without a
     gate; no biases. The hidden width is sharded over tp: this rank's part
@@ -707,22 +1017,35 @@ def _mlp(h, w_gate, w_up, w_down):
 
 
 def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
-           rope=None):
+           rope=None, shared=None, depth=0):
     """One transformer block on per-shard activations x: (B, S_loc, D).
     Returns (x, aux): aux is None for a dense MLP, and for experts the
     layer's [load balance, router z] of this shard's tokens, with the count
     of held pairs that found no room as a third where the layer holds a
-    share of its experts (see `parallel/moe.py`)."""
+    share of its experts (see `parallel/moe.py`). A state-space layer's aux
+    is its scan's output and a differential-attention layer's the keys and
+    values it read: what a segment may hand on. `shared` is what an earlier
+    segment handed on, {"memory", "kv"}; `depth` the layer's index in the
+    model."""
     h = x if cfg.post_norm else _norm(x, lp, "ln1", cfg)
+    handed = None
     if cfg.attention == "mla":
         o = _mla(h, lp, cfg, rope)
     elif cfg.attention == "gdn":
         o = _gdn(h, lp, cfg)
+    elif cfg.attention == "ssm":
+        o, handed = _ssm(h, lp, cfg)
+    elif cfg.attention == "gmu":
+        o = _gmu(h, lp, shared["memory"])
+    elif cfg.diff_attention:
+        o, handed = _diff_attention(
+            h, lp, cfg, depth,
+            shared["kv"] if cfg.attention == "cross" else None)
     else:
         with jax.named_scope("attn.project"):
-            q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
-            k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"])
-            v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"])
+            q = _projected(h, lp, "wq", "bq")
+            k = _projected(h, lp, "wk", "bk")
+            v = _projected(h, lp, "wv", "bv")
             if cfg.qk_norm:
                 q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
                 k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
@@ -733,6 +1056,8 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
         with jax.named_scope("attn.out"):
             o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
     o = lax.psum(o, "tp")                    # row-parallel combine
+    if "bo" in lp:
+        o = o + lp["bo"]
     if cfg.post_norm:
         o = _norm(o, lp, "ln1", cfg)
     x = x + o
@@ -763,7 +1088,68 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
             f = lax.psum(f, "tp") + lp["b2"]
     if cfg.post_norm:
         f = _norm(f, lp, "ln2", cfg)
-    return x + f, aux
+    return x + f, aux if handed is None else handed
+
+
+def _remat(cfg: TransformerConfig, fn, prevent_cse=False):
+    """`fn` (one layer) under `jax.checkpoint` where `cfg.remat`. A scan's
+    body needs no barrier against common-subexpression elimination: the
+    forward and the backward loop keep the layer and its repeat apart."""
+    if not cfg.remat:
+        return fn
+    # "dots": save projection/FFN matmul outputs (small, expensive to
+    # recompute); recompute batched-dot products — exactly the (B,H,S,S)
+    # attention matrices that blow up HBM. "full": save nothing, recompute
+    # the whole layer in backward.
+    policies = {
+        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        "full": None,
+    }
+    if cfg.remat_policy not in policies:
+        raise HorovodTpuError(
+            f"remat_policy={cfg.remat_policy!r}: choose from "
+            f"{sorted(policies)} (remat=False turns remat off)")
+    return jax.checkpoint(fn, prevent_cse=prevent_cse,
+                          policy=policies[cfg.remat_policy])
+
+
+def _layer_of(cfg: TransformerConfig, x, lp, shared, depth):
+    return _layer(x, lp, cfg, None, shared, depth)
+
+
+def _run_segments(segments, x, cfg: TransformerConfig):
+    """x through the segments of a segmented stack, in order. Each is a scan
+    over its periods whose body runs the period's layers in the pattern's
+    order, each layer its own checkpoint behind a barrier (`one_period` of
+    `_forward_local` says why). The segment with the "full" layer returns,
+    beside the residual stream, its last "ssm" layer's scan output and the
+    "full" layer's keys and values (of its last period); the segments behind
+    it take them as loop-invariant inputs of their scans, so each is
+    computed once and the cotangents of all its readers add up."""
+    shared, first = {}, 0
+    for at, ((pattern, periods), stacks) in enumerate(zip(cfg.segments,
+                                                          segments)):
+        hands_on = _hands_on(cfg, at)
+
+        def one_period(a, xs):     # traced at once, by the scan below
+            lp, period = xs
+            seen, handed = dict.fromkeys(lp, 0), {}
+            for place, kind in enumerate(pattern):
+                i = seen[kind]
+                seen[kind] += 1
+                a, out = _remat(cfg, partial(_layer_of, _kind_cfg(cfg, kind)),
+                                prevent_cse=True)(
+                    a, {k: w[i] for k, w in lp[kind].items()}, shared,
+                    first + period * len(pattern) + place)
+                if hands_on and kind in ("ssm", "full"):
+                    handed["memory" if kind == "ssm" else "kv"] = out
+            return a, handed
+
+        x, handed = lax.scan(one_period, x, (stacks, jnp.arange(periods)))
+        if hands_on:
+            shared = jax.tree_util.tree_map(lambda y: y[-1], handed)
+        first += periods * len(pattern)
+    return x
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig,
@@ -808,28 +1194,7 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         else:
             slots = (grad_slots or {}).get((stack,))
 
-        def remat(fn, prevent_cse=False):
-            """`fn` (one layer) under `jax.checkpoint` where `cfg.remat`.
-            A scan's body needs no barrier against common-subexpression
-            elimination: the forward and the backward loop keep the layer
-            and its repeat apart."""
-            if not cfg.remat:
-                return fn
-            # "dots": save projection/FFN matmul outputs (small, expensive
-            # to recompute); recompute batched-dot products — exactly the
-            # (B,H,S,S) attention matrices that blow up HBM. "full": save
-            # nothing, recompute the whole layer in backward.
-            policies = {
-                "dots":
-                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                "full": None,
-            }
-            if cfg.remat_policy not in policies:
-                raise HorovodTpuError(
-                    f"remat_policy={cfg.remat_policy!r}: choose from "
-                    f"{sorted(policies)} (remat=False turns remat off)")
-            return jax.checkpoint(fn, prevent_cse=prevent_cse,
-                                  policy=policies[cfg.remat_policy])
+        remat = partial(_remat, cfg)
 
         def one_kind(a, xs):
             lp = _scattered_in_backward(*xs, partial(scatter, (stack,))) \
@@ -860,6 +1225,12 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         return lax.scan(one_period if patterned else remat(one_kind), act,
                         (stage_params, slots) if slots else stage_params)
 
+    if cfg.segments:
+        x = _run_segments(params["segments"], x, cfg)
+        with jax.named_scope("vocab.head"):
+            x = _norm(x, params, "lnf", cfg)
+            return _head(x, params, cfg), None
+
     stage_fn = partial(run_stack, "layers")
     if cfg.first_k_dense:
         # the leading dense layers see every sequence alike, so they run
@@ -885,7 +1256,15 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
 
     with jax.named_scope("vocab.head"):
         x = _norm(x, params, "lnf", cfg)
-        return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
+        return _head(x, params, cfg), aux
+
+
+def _head(x, params, cfg: TransformerConfig):
+    """The logits of the normed x: through `unembed`, or with `tied_head`
+    through the embedding's transpose."""
+    if cfg.tied_head:
+        return jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    return jnp.einsum("bsd,dv->bsv", x, params["unembed"])
 
 
 def _local_loss(params, tokens, targets, cfg: TransformerConfig,
@@ -1023,7 +1402,9 @@ def _reduces_in_backward(cfg: TransformerConfig, mesh: Mesh) -> bool:
     the layer scan runs once per step, and some reduce axis of the mesh
     spans more than one rank."""
     sizes = mesh_axis_sizes(mesh)
-    return cfg.microbatches <= 1 and any(
+    # (a segmented stack's gradients are psum'd after `value_and_grad`: its
+    # scans have no slots for the shards yet)
+    return cfg.microbatches <= 1 and not cfg.segments and any(
         sizes[a] > 1 for a in ("dp", "ep", "sp", "tp"))
 
 
@@ -1225,7 +1606,43 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
         for kind in cfg.layer_pattern:
             _kind_cfg(cfg, kind)
     linear = cfg.attention == "gdn" or "linear" in cfg.layer_pattern
+    kinds = [kind for pattern, _ in cfg.segments for kind in pattern]
+    for kind in kinds:
+        _kind_cfg(cfg, kind)
+    whole = ax["sp"] == ax["tp"] == ax["pp"] == 1
     checks = [
+        (not cfg.segments or not cfg.layer_pattern,
+         "segments and a layer pattern (a pattern is one segment)"),
+        (not cfg.segments or sum(len(pattern) * periods for pattern, periods
+                                 in cfg.segments) == cfg.n_layers,
+         "the segments' layers do not add up to n_layers"),
+        (not cfg.segments or not (cfg.num_experts or cfg.first_k_dense),
+         "segments with experts or leading dense layers"),
+        (not cfg.segments or whole,
+         "segments require sp=tp=pp=1 (what a segment hands on, a "
+         "state-space layer's state and channels, and differential "
+         "attention's paired heads do not cross shards or stages)"),
+        (not {"ssm", "gmu", "cross", "window"} & set(cfg.layer_pattern),
+         "the kinds 'ssm', 'gmu', 'cross' and 'window' need segments"),
+        (_reads_are_handed_on(cfg),
+         "'gmu' and 'cross' layers need an earlier segment with an 'ssm' "
+         "and the 'full' layer"),
+        ("cross" not in kinds or cfg.diff_attention,
+         "'cross' layers are differential attention's (diff_attention)"),
+        ("window" not in kinds or cfg.window > 0,
+         "'window' layers need window > 0"),
+        (cfg.n_heads % cfg.kv_heads == 0, "n_heads % n_kv_heads"),
+        (not cfg.diff_attention or (
+            cfg.n_heads % 2 == 0 and cfg.kv_heads % 2 == 0
+            and cfg.n_heads // 2 % (cfg.kv_heads // 2) == 0
+            and cfg.attention in ("mha", "cross")
+            and not cfg.qk_norm and cfg.positions != "rope"),
+         "diff_attention pairs the heads of plain attention: even head "
+         "counts, no QK-norm, no rotary embedding"),
+        (not (cfg.n_kv_heads or cfg.window or cfg.diff_attention)
+         or (cfg.attn in ("flash", "local") and whole),
+         "n_kv_heads, window and diff_attention need attn 'flash' or "
+         "'local' and sp=tp=pp=1"),
         # a gated-delta-rule layer carries a state along the whole sequence
         # and holds all its heads: neither crosses shards yet
         (not linear or ax["sp"] == 1,

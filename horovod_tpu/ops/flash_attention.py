@@ -14,6 +14,14 @@ kernels). A causal kernel multiplies only what the causal half holds:
 blocks above the diagonal are skipped, blocks under it run unmasked, and
 a block on it is walked in row strips that stop at the diagonal's tile
 (`_for_each_strip`; docs/kernels.md has the timings that chose the form).
+With a `window` a query sees the `window` keys that end with its own: blocks
+wholly under the band are skipped as those above the diagonal are, and a
+block the band's lower edge crosses is walked in the same strips, each cut
+to the tiles the band holds of it (`_windowed_strips`). Keys and values may
+have fewer heads than the queries (`group` query heads read one K/V head):
+the K/V blocks are found by the index map `head // group`, and the dk/dv
+kernel walks a group's query heads in turn and sums them. Without a window
+and with one head count the kernels are what they were.
 Numerics are validated against
 `parallel.ring_attention.blockwise_attention_reference` (forward AND
 gradients) in tests/test_flash_attention.py.
@@ -68,11 +76,51 @@ def _crossed_strips(block_q: int, block_k: int):
     return [(i * t, t, (i + 1) * t) for i in range(block_q // t)]
 
 
-def _for_each_strip(step, *, causal, iq, ik, block_q, block_k):
-    """Run `step(row0, rows, cols, masked)` over what attention holds of
-    block (iq, ik): all of it, unmasked, when not causal or wholly under the
-    diagonal; nothing when wholly above; `_crossed_strips`, masked, when the
-    diagonal crosses it."""
+def window_back(block: int, window: int) -> int:
+    """How many blocks before the diagonal's the band of `window` keys
+    reaches: a query's first key lies at most this many blocks back."""
+    return (window + block - 2) // block
+
+
+def _windowed_strips(block_q: int, block_k: int, window: int, d: int,
+                     t: Optional[int] = None):
+    """What causal attention over the last `window` keys holds of a square
+    block `d` blocks before the diagonal's (d = 0: on it), as static (row0,
+    rows, col0, cols, masked) strips: per row of t x t tiles the tiles from
+    the one the band's lower edge crosses to the one its upper edge (the
+    diagonal) crosses; `masked` unless every entry of the strip is in the
+    band. Key c of the block is seen by its query r iff
+    d b - window < c - r <= d b."""
+    b = block_q
+    t = t or _causal_tile(block_q, block_k) or b
+    strips = []
+    for row0 in range(0, b, t):
+        c_hi = min(row0 + t - 1 + d * b, b - 1)
+        c_lo = max(row0 + d * b - window + 1, 0)
+        if c_lo > c_hi:
+            continue
+        col0, end = c_lo // t * t, (c_hi // t + 1) * t
+        whole = end - 1 - row0 <= d * b and \
+            col0 - (row0 + t - 1) > d * b - window
+        strips.append((row0, t, col0, end - col0, not whole))
+    return strips
+
+
+def _for_each_strip(step, *, causal, iq, ik, block_q, block_k, window=None):
+    """Run `step(row0, rows, cols, masked[, col0])` over what attention
+    holds of block (iq, ik): all of it, unmasked, when not causal or wholly
+    under the diagonal; nothing when wholly above; `_crossed_strips`,
+    masked, when the diagonal crosses it. With a `window` (causal, square
+    blocks): nothing of a block wholly under the band, `_windowed_strips` of
+    the others."""
+    if window is not None:
+        for d in range(window_back(block_q, window) + 1):
+            def _at_distance(d=d):
+                for row0, rows, col0, cols, masked in _windowed_strips(
+                        block_q, block_k, window, d):
+                    step(row0, rows, cols, masked, col0)
+            pl.when(iq - ik == d)(_at_distance)
+        return
     if not causal:
         step(0, block_q, block_k, False)
         return
@@ -91,21 +139,26 @@ def _for_each_strip(step, *, causal, iq, ik, block_q, block_k):
             step(row0, rows, cols, True)
 
 
-def _scores(q_ref, k_ref, row0, rows, cols, masked, *,
-            scale, iq, ik, block_q, block_k):
-    """s = q·kᵀ·scale of one strip, its entries above the diagonal at
+def _scores(q_ref, k_ref, row0, rows, cols, masked, col0=0, *,
+            scale, iq, ik, block_q, block_k, window=None):
+    """s = q·kᵀ·scale of one strip (the block's columns from `col0`), its
+    entries above the diagonal, and with a `window` those under the band, at
     `_NEG_INF` where the strip is `masked`."""
     q = q_ref[0, row0:row0 + rows, :].astype(jnp.float32)
-    k = k_ref[0, :cols, :].astype(jnp.float32)
+    k = k_ref[0, col0:col0 + cols, :].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if masked:
         row = iq * block_q + row0 + jax.lax.broadcasted_iota(
             jnp.int32, (rows, cols), 0)
-        col = ik * block_k + jax.lax.broadcasted_iota(
+        first = ik * block_k + col0 if col0 else ik * block_k
+        col = first + jax.lax.broadcasted_iota(
             jnp.int32, (rows, cols), 1)
-        s = jnp.where(col <= row, s, _NEG_INF)
+        seen = col <= row
+        if window is not None:
+            seen = jnp.logical_and(seen, col > row - window)
+        s = jnp.where(seen, s, _NEG_INF)
     return s
 
 
@@ -135,11 +188,13 @@ def _lanes(x, n):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k):
+                acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
+                window=None):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
-    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k,
+                 window=window)
 
     @pl.when(ik == 0)
     def _init():
@@ -147,14 +202,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def step(row0, rows, cols, masked):
+    def step(row0, rows, cols, masked, col0=0):
         """Online-softmax update of the strip's rows. m and l stay
         lane-replicated as values too: sliced to a column and broadcast
         again, each update cost more than the tiles a strip skips."""
         r = slice(row0, row0 + rows)
-        s = _scores(q_ref, k_ref, row0, rows, cols, masked,
+        s = _scores(q_ref, k_ref, row0, rows, cols, masked, col0,
                     scale=scale, **block)              # (rows, cols)
-        v = v_ref[0, :cols, :].astype(jnp.float32)
+        v = v_ref[0, col0:col0 + cols, :].astype(jnp.float32)
         m_prev = m_ref[r, :]                           # (rows, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, cols))           # (rows, cols)
@@ -176,20 +231,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_ref[:, :1] + jnp.log(safe_l)   # (bq, 1)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
+def _kv_block(b, i, j, *, group, window, block):
+    """Which K/V block the grid step of query head b, query block i and key
+    block j reads: the head's group's; and under a window the nearest block
+    the band holds, so that a step that computes nothing fetches nothing
+    new."""
+    if window is not None:
+        j = jnp.clip(j, jnp.maximum(i - window_back(block, window), 0), i)
+    return (b // group if group > 1 else b, j, 0)
+
+
+def _fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     bh, sq, dh = q.shape            # dh: the queries' and keys' width
     sk, dhv = v.shape[1:]           # dhv: the values' (o follows v)
     nq = sq // block_q
     nk = sk // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
+    kv = functools.partial(_kv_block, group=bh // k.shape[0], window=window,
+                           block=block_k)
     o, lse = pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dhv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dh), kv),
+            pl.BlockSpec((1, block_k, dhv), kv),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dhv), lambda b, i, j: (b, i, 0)),
@@ -216,7 +284,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
 # Backward
 # --------------------------------------------------------------------------
 
-def _bwd_strip(refs, row0, rows, cols, masked, *, scale, **block):
+def _bwd_strip(refs, row0, rows, cols, masked, col0=0, *, scale, **block):
     """(p, ds) of one strip: the probabilities recomputed from the saved
     logsumexp, p = exp(s·scale − lse), and the score gradient
     ds = p ∘ (do·vᵀ − delta [+ dlse]) · scale, with
@@ -225,10 +293,11 @@ def _bwd_strip(refs, row0, rows, cols, masked, *, scale, **block):
     results can be merged OUTSIDE the kernel, e.g. per ring hop.)"""
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dlse_ref = refs
     r = slice(row0, row0 + rows)
-    s = _scores(q_ref, k_ref, row0, rows, cols, masked, scale=scale, **block)
+    s = _scores(q_ref, k_ref, row0, rows, cols, masked, col0, scale=scale,
+                **block)
     p = jnp.exp(s - lse_ref[0, r, :])                  # (rows, cols)
     do = do_ref[0, r, :].astype(jnp.float32)           # (rows, dh)
-    v = v_ref[0, :cols, :].astype(jnp.float32)
+    v = v_ref[0, col0:col0 + cols, :].astype(jnp.float32)
     delta = jnp.sum(do * o_ref[0, r, :].astype(jnp.float32),
                     axis=-1, keepdims=True)            # (rows, 1)
     dp = jax.lax.dot_general(
@@ -240,61 +309,70 @@ def _bwd_strip(refs, row0, rows, cols, masked, *, scale, **block):
     return p, p * bracket * scale
 
 
-def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
+def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, has_dlse,
+                     window=None, q_blocks=None):
     # q, k, v, o, do, lse, dlse (or None) as `_bwd_strip` takes them
     ins = refs[:6] + (refs[6] if has_dlse else None,)
     dk_ref, dv_ref, dk_acc, dv_acc = refs[6 + has_dlse:]
     q_ref, do_ref = ins[0], ins[4]
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
-    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
+    at = pl.program_id(2)
+    steps = pl.num_programs(2)
+    # with `q_blocks` the grid's last axis walks a group's query heads in
+    # turn, each over its `q_blocks` blocks of queries: one sum for the K/V
+    # head
+    iq = at if q_blocks is None else at % q_blocks
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k,
+                 window=window)
 
-    @pl.when(iq == 0)
+    @pl.when(at == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def step(row0, rows, cols, masked):
-        p, ds = _bwd_strip(ins, row0, rows, cols, masked,
+    def step(row0, rows, cols, masked, col0=0):
+        p, ds = _bwd_strip(ins, row0, rows, cols, masked, col0,
                            scale=scale, **block)
         r = slice(row0, row0 + rows)
+        c = slice(col0, col0 + cols)
         # dv += pᵀ · do ;  dk += dsᵀ · q   (the strip's columns only)
-        dv_acc[:cols, :] = dv_acc[:cols, :] + jax.lax.dot_general(
+        dv_acc[c, :] = dv_acc[c, :] + jax.lax.dot_general(
             p, do_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[:cols, :] = dk_acc[:cols, :] + jax.lax.dot_general(
+        dk_acc[c, :] = dk_acc[c, :] + jax.lax.dot_general(
             ds, q_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _for_each_strip(step, causal=causal, **block)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(at == steps - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
+def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse,
+                   window=None):
     ins = refs[:6] + (refs[6] if has_dlse else None,)
     dq_ref, dq_acc = refs[6 + has_dlse:]
     k_ref = ins[1]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
-    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k,
+                 window=window)
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def step(row0, rows, cols, masked):
-        _, ds = _bwd_strip(ins, row0, rows, cols, masked,
+    def step(row0, rows, cols, masked, col0=0):
+        _, ds = _bwd_strip(ins, row0, rows, cols, masked, col0,
                            scale=scale, **block)
         r = slice(row0, row0 + rows)
         # dq += ds · k
         dq_acc[r, :] = dq_acc[r, :] + jax.lax.dot_general(
-            ds, k_ref[0, :cols, :].astype(jnp.float32),
+            ds, k_ref[0, col0:col0 + cols, :].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -305,7 +383,8 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse):
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
+def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k,
+         window=None):
     """dlse=None compiles lse-cotangent-free kernels (the plain
     flash_attention path never pays for a zero dlse buffer)."""
     bh, sq, dh = q.shape            # dq and dk follow q's width,
@@ -313,12 +392,25 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     nq = sq // block_q
     nk = sk // block_k
     has_dlse = dlse is not None
+    group = bh // k.shape[0]
 
     def by_i(block, width):
         return pl.BlockSpec((1, block, width), lambda b, i, j: (b, i, 0))
 
+    def q_block(b, i, j):
+        """Which block of a query-shaped array the dk/dv kernel's grid step
+        (K/V head b, key block i, step j) reads: with a group, step j is
+        query head j // nq of the group at its block j % nq; under a window
+        the nearest block the band holds."""
+        if group > 1:
+            b, j = b * group + j // nq, j % nq
+        if window is not None:
+            j = jnp.clip(j, i, jnp.minimum(
+                i + window_back(block_q, window), nq - 1))
+        return (b, j, 0)
+
     def by_j(block, width):
-        return pl.BlockSpec((1, block, width), lambda b, i, j: (b, j, 0))
+        return pl.BlockSpec((1, block, width), q_block)
 
     lse_by_j = by_j(block_q, 1)
     in_specs = [by_j(block_q, dh), by_i(block_k, dh), by_i(block_k, dhv),
@@ -330,13 +422,14 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     dk, dv = pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          has_dlse=has_dlse),
-        grid=(bh, nk, nq),
+                          has_dlse=has_dlse, window=window,
+                          q_blocks=nq if group > 1 else None),
+        grid=(bh // group, nk, nq * group),
         in_specs=in_specs,
         out_specs=[by_i(block_k, dh), by_i(block_k, dhv)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, dh), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dhv), q.dtype),
+            jax.ShapeDtypeStruct((bh // group, sk, dh), q.dtype),
+            jax.ShapeDtypeStruct((bh // group, sk, dhv), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dh), jnp.float32),
@@ -345,8 +438,11 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
         **_compiler_params(dh, dhv),
     )(*operands)
 
+    kv = functools.partial(_kv_block, group=group, window=window,
+                           block=block_k)
     lse_by_i = by_i(block_q, 1)
-    in_specs = [by_i(block_q, dh), by_j(block_k, dh), by_j(block_k, dhv),
+    in_specs = [by_i(block_q, dh), pl.BlockSpec((1, block_k, dh), kv),
+                pl.BlockSpec((1, block_k, dhv), kv),
                 by_i(block_q, dhv), by_i(block_q, dhv), lse_by_i]
     operands = [q, k, v, o, do, lse]
     if has_dlse:
@@ -355,7 +451,7 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k):
     dq = pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          has_dlse=has_dlse),
+                          has_dlse=has_dlse, window=window),
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=by_i(block_q, dh),
@@ -391,21 +487,22 @@ def _flash_chunk_bwd(causal, scale, block_q, block_k, res, cot):
 _flash_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, block_k):
-    o, _ = _fwd(q, k, v, causal, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, block_q, block_k, window):
+    o, _ = _fwd(q, k, v, causal, scale, block_q, block_k, window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k)
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window):
+    o, lse = _fwd(q, k, v, causal, scale, block_q, block_k, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
     # dlse=None: the o-only API never pays for a zero lse cotangent.
-    return _bwd(q, k, v, o, lse, do, None, causal, scale, block_q, block_k)
+    return _bwd(q, k, v, o, lse, do, None, causal, scale, block_q, block_k,
+                window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -487,34 +584,97 @@ def causal_tile_share(S: int, block: Optional[int] = None,
     return computed / (S * S / 2)
 
 
+def window_tile_share(S: int, window: int, block: Optional[int] = None,
+                      t: Optional[int] = None) -> float:
+    """Score entries the windowed kernels compute over the entries the band
+    holds (a query's last `window` keys, fewer at the sequence's start):
+    1.0 would be none wasted. A block's strips are whole tiles of edge `t`,
+    and both of the band's edges cross tiles: 1.5 at (8,192, 512) with the
+    kernels' own 1024-blocks and 256-tiles. The defaults are the kernels'
+    own choice for S."""
+    if block is None:
+        block = _auto_block(S)
+    computed = 0
+    for iq in range(S // block):
+        for d in range(min(window_back(block, window), iq) + 1):
+            computed += sum(
+                rows * cols for _, rows, _, cols, _ in _windowed_strips(
+                    block, block, window, d, t))
+    w = min(window, S)
+    return computed / (w * S - w * (w - 1) / 2)
+
+
+def masked_attention_reference(q, k, v, causal: bool = True,
+                               scale: Optional[float] = None,
+                               window: Optional[int] = None):
+    """Attention with the scores materialised and the mask written out, in
+    float32: q: (B, H, S, dh) against k: (B, G, S, dh), v: (B, G, S, dv)
+    with H / G query heads reading one K/V head. For the tests of the
+    kernels and for lengths they cannot tile."""
+    B, H, S, dh = q.shape
+    group = H // k.shape[1]
+    if scale is None:
+        scale = dh ** -0.5
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    kf, vf = (jnp.repeat(x, group, axis=1) for x in (kf, vf))
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
+    row = jnp.arange(S)[:, None]
+    col = jnp.arange(S)[None, :]
+    seen = jnp.ones((S, S), bool)
+    if causal:
+        seen = col <= row
+    if window is not None:
+        seen = jnp.logical_and(seen, col > row - window)
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None) -> jax.Array:
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Exact attention via the Pallas flash kernel.
 
-    q, k: (B, H, S, dh); v: (B, H, S, dv). Returns (B, H, S, dv): the
+    q: (B, H, S, dh); k: (B, G, S, dh); v: (B, G, S, dv), G a divisor of H:
+    query head h reads K/V head h // (H / G). Returns (B, H, S, dv): the
     values may be narrower or wider than the queries and keys (latent
     attention: 192-wide keys, 128-wide values), and then o, do and dv have
     the values' width, dq and dk the keys'. `scale` defaults to dh^-1/2.
-    Differentiable (custom VJP with flash backward kernels). Block sizes
-    default to a measured heuristic; falls back to the score-materializing
+    With a `window` (causal only) a query sees the `window` keys that end
+    with its own. Differentiable (custom VJP with flash backward kernels;
+    dk and dv are summed over a group's query heads). Block sizes default
+    to a measured heuristic; falls back to the score-materializing
     reference for shapes the kernel cannot tile.
     """
     B, H, S, dh = q.shape
-    dv = v.shape[-1]
+    G, dv = k.shape[1], v.shape[-1]
+    if H % G or v.shape[1] != G:
+        raise ValueError(f"flash_attention: {H} query heads over {G} key "
+                         f"and {v.shape[1]} value heads")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash_attention: a window needs causal=True and "
+                         f"at least one key (window={window})")
+    if window is not None and window >= S:
+        window = None       # the band is the whole causal half
     if scale is None:
         scale = dh ** -0.5
     block_q = min(block_q, S) if block_q else _auto_block(S)
     block_k = min(block_k, S) if block_k else _auto_block(S)
+    if window is not None and block_q != block_k:
+        raise ValueError("flash_attention: a window is walked in square "
+                         f"blocks (block_q={block_q}, block_k={block_k})")
     if (block_q is None or block_k is None
             or S % block_q or S % block_k):
+        if window is not None or G != H:
+            return masked_attention_reference(q, k, v, causal, scale, window)
         from horovod_tpu.parallel.ring_attention import (
             blockwise_attention_reference)
         return blockwise_attention_reference(q, k, v, causal=causal,
                                              scale=scale)
     qf = q.reshape(B * H, S, dh)
-    kf = k.reshape(B * H, S, dh)
-    vf = v.reshape(B * H, S, dv)
-    o = _flash(qf, kf, vf, causal, float(scale), block_q, block_k)
+    kf = k.reshape(B * G, S, dh)
+    vf = v.reshape(B * G, S, dv)
+    o = _flash(qf, kf, vf, causal, float(scale), block_q, block_k,
+               None if window is None else int(window))
     return o.reshape(B, H, S, dv)

@@ -116,6 +116,33 @@ def test_phase_causal_conv_pass(smoke, capsys):
 
 
 @one_chip
+def test_phase_selective_scan_pass(smoke, capsys):
+    chip_smoke.selective_scan_pass(smoke, shape=(1, 64, 48, 8), checked=32)
+    out = capsys.readouterr().out
+    assert "[selective scan] 1 x 64 tokens x 48 channels x 8 states" in out
+    assert "tiles of 64 tokens, 1 a sequence; interpret=True, 0 recompiles " \
+        "after a first call, tpu_custom_call in the compiled forward 0, " \
+        "forward + backward 0" in out
+    assert "the first 32 tokens from the token-by-token form y " in out
+    assert all(f" {name} " in out for name in
+               ("dc", "ddelta", "dA", "dB", "dC", "dD"))
+    assert "least time" not in out     # no share of a peak off the TPU
+
+
+@one_chip
+def test_phase_windowed_grouped_flash(smoke, capsys):
+    chip_smoke.windowed_grouped_flash(smoke, shape=(1, 4, 2, 128, 8, 16),
+                                      window=24, checked=64)
+    out = capsys.readouterr().out
+    assert "[grouped flash] 1 x 128 tokens, 4 query heads over 2 K/V heads, " \
+        "8 | 16, bf16, interpret=True, 0 recompiles after a first call; " \
+        "window 24: window_tile_share " in out
+    assert "; no window: causal_tile_share " in out
+    assert out.count("forward 0, forward + backward 0") == 2
+    assert out.count(" dq ") == 2 and out.count(" dv ") == 2
+
+
+@one_chip
 def test_phase_flagship_lm(smoke, capsys):
     chip_smoke.flagship_lm(smoke, cfg=TINY_LM, batch=4, seq=64, steps=5)
     out = capsys.readouterr().out

@@ -193,3 +193,119 @@ def test_causal_tile_share_at_the_cells_shapes():
     assert causal_tile_share(1024) <= 1.25
     for S in (100, 128, 256, 512, 640, 1024, 2048, 4096, 8192, 32768):
         assert 1.0 <= causal_tile_share(S) <= 2.0, S
+
+
+# ------------------------------------------------- a window, grouped K/V
+
+def _banded_reference(q, k, v, window):
+    """`blockwise_attention_reference` cannot leave keys out, so the mask is
+    written out here: scores of every (query, key), the keys over the
+    diagonal and under the band at -inf, a group's query heads against one
+    repeated K/V head."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    row = jnp.arange(q.shape[2])[:, None]
+    col = jnp.arange(q.shape[2])[None, :]
+    seen = col <= row
+    if window is not None:
+        seen = seen & (col > row - window)
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+#: (S, block, window, query heads, K/V heads, dv): one block masked whole;
+#: square blocks walked in tiles with the band's lower edge inside the
+#: diagonal's block, in the block before it, and two blocks back; a group of
+#: two and of four; values wider than keys (differential attention's)
+_BANDED = {
+    "one-block-w24-g2": (64, None, 24, 4, 2, 16),
+    "one-block-w5-g4": (64, None, 5, 4, 1, 8),
+    "no-window-g2": (256, 128, None, 4, 2, 8),
+    "tiles-w300-g1": (2048, 512, 300, 2, 2, 16),
+    "tiles-w700-g2": (1536, 512, 700, 2, 1, 8),
+    "tiles-w100-t128": (1024, 256, 100, 2, 2, 8),
+    "tiles-w1100-two-back": (2048, 512, 1100, 2, 1, 8),
+    "window-wider-than-S": (128, 64, 500, 2, 1, 8),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _banded_case(case):
+    S, block, window, H, G, dv = _BANDED[case]
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (1, H, S, 8), jnp.float32)
+    k = jax.random.normal(ks[1], (1, G, S, 8), jnp.float32)
+    v = jax.random.normal(ks[2], (1, G, S, dv), jnp.float32)
+    w = jax.random.normal(ks[3], (1, H, S, dv), jnp.float32)
+
+    def ours(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=block, block_k=block)
+
+    def theirs(q, k, v):
+        return _banded_reference(q, k, v, window)
+
+    out = [f(q, k, v) for f in (ours, theirs)]
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                      argnums=(0, 1, 2))(q, k, v) for f in (ours, theirs)]
+    return out, grads
+
+
+@pytest.mark.parametrize("what", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", list(_BANDED))
+def test_window_and_group_match_the_mask_written_out(case, what):
+    (got_o, want_o), (got_g, want_g) = _banded_case(case)
+    at = "o dq dk dv".split().index(what)
+    got, want = (got_o, want_o) if at == 0 else (got_g[at - 1],
+                                                 want_g[at - 1])
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
+def test_windowed_strips_cover_the_band_exactly_once():
+    """Every score entry the band holds lies in exactly one strip of exactly
+    one block, and every strip marked unmasked lies wholly in the band."""
+    from horovod_tpu.ops.flash_attention import (_windowed_strips,
+                                                 window_back)
+    for S, block, window in ((2048, 512, 300), (2048, 512, 1100),
+                             (1024, 256, 100), (1024, 1024, 512),
+                             (4096, 1024, 512)):
+        seen = np.zeros((S, S), np.int32)
+        row, col = np.arange(S)[:, None], np.arange(S)[None, :]
+        band = (col <= row) & (col > row - window)
+        for iq in range(S // block):
+            for d in range(min(window_back(block, window), iq) + 1):
+                ik = iq - d
+                for r0, rows, c0, cols, masked in _windowed_strips(
+                        block, block, window, d):
+                    r = slice(iq * block + r0, iq * block + r0 + rows)
+                    c = slice(ik * block + c0, ik * block + c0 + cols)
+                    seen[r, c] += 1
+                    if not masked:
+                        assert band[r, c].all()
+        assert seen.max() == 1 and (seen[band] == 1).all()
+
+
+def test_window_tile_share_at_the_cells_shape():
+    from horovod_tpu.ops.flash_attention import window_tile_share
+    # 8 diagonal blocks of 9 tiles and 7 blocks before one of 3, 256² each,
+    # over the band's 8,192 x 512 - 512 x 511 / 2 entries
+    assert window_tile_share(8192, 512) == pytest.approx(
+        (8 * 9 + 7 * 3) * 256 * 256 / (8192 * 512 - 512 * 511 / 2))
+    assert window_tile_share(8192, 512) == pytest.approx(1.49991, abs=1e-5)
+    # blocks walked whole: the band costs a quarter of all there is
+    assert window_tile_share(8192, 512, t=1024) == pytest.approx(
+        15 * 1024 * 1024 / (8192 * 512 - 512 * 511 / 2))
+
+
+def test_a_window_needs_causal_attention_and_the_heads_must_divide():
+    q, k, v = _qkv(jax.random.PRNGKey(3), H=4, S=64, dh=8)
+    with pytest.raises(ValueError, match="a window needs causal"):
+        flash_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError, match="query heads over"):
+        flash_attention(q, k[:, :3], v[:, :3], causal=True)
+    with pytest.raises(ValueError, match="square blocks"):
+        flash_attention(q, k, v, causal=True, window=8, block_q=32,
+                        block_k=64)

@@ -1,6 +1,6 @@
-"""Flash attention, the grouped matmul, the gated delta rule and the
-convolution in front of it compiled by the chip's own compiler, without a
-chip.
+"""Flash attention (with a window and grouped K/V too), the grouped matmul,
+the gated delta rule, the convolution in front of it and the selective scan
+compiled by the chip's own compiler, without a chip.
 
 The flagship LM's default attention is the Pallas flash kernel
 (ops/flash_attention.py). The interpreter runs of
@@ -289,3 +289,89 @@ def test_interpret_decision_is_shared_and_visible(monkeypatch):
     for mod in (fa, conv_block, conv_bn_backward, gm):
         assert mod.pallas_call is _pallas.pallas_call
         assert not hasattr(mod, "_interpret")
+
+
+#: `phi4flash-1chip`: (batch, seq, channels, states) of a state-space
+#: layer's scan, and one softmax of differential attention: (batch, query
+#: pairs, K/V pairs, seq, keys' width, values' width), a window of 512
+PHI4_SCAN = (1, 8192, 5120, 16)
+PHI4_FLASH = (1, 20, 10, 8192, 64, 128)
+PHI4_WINDOW = 512
+
+#: (operands, results) of the selective scan's Mosaic kernels: the forward
+#: (c, delta, A, B and C along the lanes, D -> y), with a gradient asked the
+#: forward with the tiles' entry states and the backward kernel (those, dy
+#: and the entry states -> dc, ddelta, dA and the partial sums of dB, dC)
+SCAN_SIGNATURES = {"fwd": [(6, 1)], "bwd": [(6, 2), (8, 5)]}
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_selective_scan_compiles_for_v5e(monkeypatch, name):
+    """`ops/selective_scan.py` at `phi4flash-1chip`'s shapes: 5,120 channels
+    in five blocks of 1,024, sixteen states on the sublanes, 64 tiles of
+    128 tokens; forward and backward are Mosaic kernels and nothing else
+    walks the sequence. Its forward kernels share (operands, results) with
+    flash's dq and dk/dv: the cell's flash reader tells its own by shape as
+    well, and takes none of these."""
+    from benchmark.harness import hlo
+    from benchmark.layer_metrics import diff_flash_roofline
+    from horovod_tpu.ops.selective_scan import selective_scan
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    b, s, e, n = PHI4_SCAN
+    avals = (jax.ShapeDtypeStruct((b, s, e), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, s, e), jnp.float32),
+             jax.ShapeDtypeStruct((e, n), jnp.float32),
+             jax.ShapeDtypeStruct((b, s, n), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, s, n), jnp.bfloat16),
+             jax.ShapeDtypeStruct((e,), jnp.float32))
+
+    def bwd(*args):
+        return jax.grad(lambda *a: selective_scan(*a).astype(
+            jnp.float32).sum(), argnums=range(6))(*args)
+
+    want = SCAN_SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": selective_scan, "bwd": bwd}[
+        name], avals, n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    assert " while(" not in txt
+    assert diff_flash_roofline.flash_kernels(hlo.index(txt),
+                                             PHI4_FLASH) == {}
+
+
+@pytest.mark.parametrize("window", [None, PHI4_WINDOW],
+                         ids=["full", "window512"])
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_grouped_windowed_flash_compiles_for_v5e(monkeypatch, name, window):
+    """One softmax of `phi4flash-1chip`'s differential attention: 20 query
+    pairs of 64 over 10 K/V pairs, values 128 wide, 8,192 tokens, with and
+    without the 512-key window. The three custom calls keep the operands and
+    results the readers know a flash kernel by, dk and dv come out at the
+    K/V heads' count, and the cell's reader finds all of them by shape."""
+    from benchmark.harness import hlo
+    from benchmark.layer_metrics import diff_flash_roofline
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    b, h, g, s, dk, dv = PHI4_FLASH
+    avals = (jax.ShapeDtypeStruct((b, h, s, dk), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, g, s, dk), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, g, s, dv), jnp.bfloat16))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    want = SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": fwd, "bwd": bwd}[name], avals,
+                              n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    kinds = diff_flash_roofline.flash_kernels(hlo.index(txt), PHI4_FLASH)
+    assert sorted(kinds.values()) == {"fwd": ["forward"], "bwd": [
+        "dkdv", "dq", "forward"]}[name]

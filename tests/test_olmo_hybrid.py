@@ -449,8 +449,11 @@ def test_the_train_step_learns_the_fixed_batch(params):
      "a layer pattern with experts"),
     ({}, dataclasses.replace(CFG, n_layers=6),
      "no whole number of periods"),
+    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "sparse")),
+     "names the kind 'sparse'"),
+    # a kind since PR 36, which a pattern alone cannot hold
     ({}, dataclasses.replace(CFG, layer_pattern=("linear", "window")),
-     "names the kind 'window'"),
+     "need segments"),
     ({"sp": 2}, dataclasses.replace(CFG, layer_pattern=(), attention="gdn"),
      "linear-attention layers require sp=1"),
 ])

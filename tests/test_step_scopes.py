@@ -1,6 +1,6 @@
 """The scopes of the compiled train step (`transformer.STEP_SCOPES` and the
-three mixers' `moe.*`, `mla.*`, `gdn.*`): for tiny configurations of the four
-kinds the benchmark's LM cells run, compiled on the CPU, every scope the
+mixers' `moe.*`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`): for tiny configurations
+of the five kinds the benchmark's LM cells run, compiled on the CPU, every scope the
 model has is in the compiled text's `op_name`s, in the forward pass and in
 the backward pass; the gradient reduction's only where something is
 reduced; and `DistributedOptimizer.step` records its two phases as spans of
@@ -22,8 +22,9 @@ from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from test_lowered_steps import CONFIGS
 from test_olmo_hybrid import CFG as HYBRID
+from test_phi4_flash import CFG as PHI4_FLASH
 
-CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID)
+CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID, phi4_flash=PHI4_FLASH)
 
 ATTN = ("attn.project", "attn.attend", "attn.out")
 VOCAB = ("vocab.embed", "vocab.head", "vocab.loss")
@@ -37,6 +38,9 @@ HAS = {
                     "mlp.dense", "moe.shared") + MOE + VOCAB,
     "olmo_hybrid": ATTN + ("gdn.project", "gdn.conv", "gdn.scan", "gdn.gate",
                            "gdn.out", "mlp.dense") + VOCAB,
+    "phi4_flash": ATTN + ("attn.window", "ssm.project", "ssm.conv",
+                          "ssm.scan", "ssm.gate", "ssm.out", "gmu.project",
+                          "gmu.gate", "gmu.out", "mlp.dense") + VOCAB,
 }
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -64,7 +68,7 @@ def scopes_of(op_name: str) -> list:
     bare = (re.sub(r"^(?:[\w\-]+\()+", "", c).rstrip(")")
             for c in op_name.split("/"))
     return [c for c in bare if re.match(
-        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn)\.", c)]
+        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|ssm|gmu)\.", c)]
 
 
 def under(names, scope: str, backward: bool) -> list:
@@ -87,20 +91,24 @@ def test_the_step_has_its_scopes_and_no_other(name):
     assert under(op_names(name, 1), "opt.update", backward=False)
 
 
-@pytest.mark.parametrize("name", ["gpt2", "olmoe", "olmo_hybrid"])
+@pytest.mark.parametrize("name", ["gpt2", "olmoe", "olmo_hybrid",
+                                  "phi4_flash"])
 def test_the_reduction_has_its_scope_where_something_is_reduced(name):
     """On one rank nothing is reduced and the scope is absent; at `dp` = 2
-    the halving inside the backward loop and the sums after it have it."""
+    the halving inside the backward loop and the sums after it have it (a
+    segmented stack's gradients are all summed after it)."""
     assert not [n for n in op_names(name, 1) if "grad.reduce" in n]
     names = op_names(name, 2)
-    assert under(names, "grad.reduce", backward=True)    # in the loop
+    if not CONFIGS[name].segments:
+        assert under(names, "grad.reduce", backward=True)    # in the loop
     assert under(names, "grad.reduce", backward=False)   # after it
     found = {s for n in names for s in scopes_of(n)}
     assert found == set(HAS[name]) | {"opt.update", "grad.reduce"}
 
 
 @pytest.mark.parametrize("name, dp", [
-    ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2)])
+    ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
+    ("phi4_flash", 2)])
 def test_no_instruction_lies_under_two_layers_scopes(name, dp):
     """`mlp.dense` is entered at `_layer`'s dense branches and not in
     `_mlp`, which the shared experts run under `moe.shared`; the reduction
@@ -113,10 +121,12 @@ def test_no_instruction_lies_under_two_layers_scopes(name, dp):
 
 def test_the_vocabulary_is_what_the_source_enters():
     """`STEP_SCOPES` is every scope `models/transformer.py` enters outside
-    its mixers' (`moe.shared`, `mla.*`, `gdn.*`), no more and no less."""
+    its mixers' (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`), no more
+    and no less."""
     entered = set(re.findall(r'named_scope[(,]\s*"([^"]+)"',
                              inspect.getsource(tfm)))
-    mixers = {s for s in entered if s.startswith(("moe.", "mla.", "gdn."))}
+    mixers = {s for s in entered
+              if s.startswith(("moe.", "mla.", "gdn.", "ssm.", "gmu."))}
     assert entered - mixers == set(tfm.STEP_SCOPES)
     assert len(set(tfm.STEP_SCOPES)) == len(tfm.STEP_SCOPES)
     assert "layer_pattern else" not in inspect.getsource(tfm._layer)
