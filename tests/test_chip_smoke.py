@@ -71,6 +71,8 @@ def test_phase_flash_kernel(smoke, capsys):
     assert "interpret=True, 0 tpu_custom_call" in out
     # S=64 is one block no tile divides: all of it computed, half of it used
     assert "causal_tile_share 2.0000" in out
+    # and no grid step that computes nothing, at either shape
+    assert out.count("grid_step_share 1.0000 (grid steps a head runs") == 2
 
 
 @one_chip
@@ -138,6 +140,7 @@ def test_phase_windowed_grouped_flash(smoke, capsys):
         "8 | 16, bf16, interpret=True, 0 recompiles after a first call; " \
         "window 24: window_tile_share " in out
     assert "; no window: causal_tile_share " in out
+    assert out.count(", grid_step_share 1.0000, tpu_custom_call") == 2
     assert out.count("forward 0, forward + backward 0") == 2
     assert out.count(" dq ") == 2 and out.count(" dv ") == 2
 

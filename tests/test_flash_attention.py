@@ -102,18 +102,6 @@ _PATHS = {
 }
 
 
-def _causal_chunk_reference(q, k, v):
-    """(o, lse) of causal attention with the score matrix written out."""
-    S, dh = q.shape[2:]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   precision="highest") * dh ** -0.5
-    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
-    lse = jax.nn.logsumexp(s, axis=-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v,
-                   precision="highest")
-    return o, lse
-
-
 @pytest.mark.parametrize("case", list(_PATHS))
 def test_flash_paths_match_reference_forward_and_gradients(case):
     from horovod_tpu.ops.flash_attention import flash_attention_chunk
@@ -135,7 +123,8 @@ def test_flash_paths_match_reference_forward_and_gradients(case):
     if chunk:
         flash = lambda q, k, v: flash_attention_chunk(  # noqa: E731
             q, k, v, causal=True)
-        ref = _causal_chunk_reference
+        ref = lambda q, k, v: _written_out(  # noqa: E731
+            q, k, v, True, None)
     else:
         flash = lambda q, k, v: flash_attention(  # noqa: E731
             q, k, v, causal=causal, **kw)
@@ -309,3 +298,173 @@ def test_a_window_needs_causal_attention_and_the_heads_must_divide():
     with pytest.raises(ValueError, match="square blocks"):
         flash_attention(q, k, v, causal=True, window=8, block_q=32,
                         block_k=64)
+
+
+# ------------------------------ a grid of only the blocks the mask holds
+
+def _mask_holds(i, j, block_q, block_k, causal, window):
+    """Whether any (query, key) of block pair (i, j) is in the mask, from
+    the mask's own definition: key <= query, and with a window key > query -
+    window. query - key of the block's entries spans [lo, hi]."""
+    if not causal:
+        return True
+    lo = i * block_q - (j + 1) * block_k + 1
+    hi = (i + 1) * block_q - 1 - j * block_k
+    return hi >= 0 and (window is None or lo <= window - 1)
+
+
+#: (S, block_q, block_k, causal, window, group): the cells' lengths in the
+#: kernels' own 1,024-blocks, with the window of `phi4flash-1chip`, one that
+#: reaches two blocks back, and a group of two; blocks that are not square
+#: (the diagonal then leaves a row's last block anywhere); small blocks (63
+#: compares a step); not causal (every pair: a rectangle, rows of one length)
+_GRIDS = [
+    pytest.param(S, 1024, 1024, True, window, group,
+                 id=f"S{S}-w{window}-g{group}")
+    for S in (2048, 4096, 8192) for window in (None, 512, 1500)
+    for group in (1, 2)
+] + [
+    pytest.param(2048, 256, 128, True, None, 1, id="wide-query-blocks"),
+    pytest.param(2048, 128, 512, True, None, 2, id="wide-key-blocks"),
+    pytest.param(8192, 128, 128, True, 512, 1, id="small-blocks-w512"),
+    pytest.param(4096, 1024, 1024, False, None, 2, id="not-causal"),
+    pytest.param(2048, 1024, 512, False, None, 1, id="not-causal-not-square"),
+    pytest.param(1024, 1024, 1024, True, None, 1, id="one-block"),
+]
+
+
+@pytest.mark.parametrize("S,block_q,block_k,causal,window,group", _GRIDS)
+def test_the_grid_steps_are_the_block_pairs_the_mask_holds_each_once(
+        S, block_q, block_k, causal, window, group):
+    """The enumeration alone (`held_blocks`, `_Walk`: pure Python here). The
+    forward and dq kernels' steps are the held pairs row-major, the dk/dv
+    kernel's column-major with a group's query heads in turn: the order the
+    whole rectangle had, so every sum adds in the parent's order. `_init`
+    fires at a row's (column's) first held step and `_finish` at its last."""
+    from horovod_tpu.ops.flash_attention import (_by_key_block, _Walk,
+                                                 grid_step_share,
+                                                 held_blocks)
+    nq, nk = S // block_q, S // block_k
+    held = [(i, j) for i in range(nq) for j in range(nk)
+            if _mask_holds(i, j, block_q, block_k, causal, window)]
+    rows = held_blocks(nq, nk, block_q, block_k, causal, window)
+
+    walk = _Walk(rows)
+    steps = [(int(walk.run(s)[0]), int(walk.at(s)))
+             for s in map(np.int32, range(walk.steps))]   # as program_id is
+    assert steps == held            # each once, row-major
+    for s, (i, j) in enumerate(steps):
+        first, last = (bool(x) for x in walk.where(np.int32(s))[2:])
+        assert first == (s == 0 or steps[s - 1][0] != i)
+        assert last == (s == len(steps) - 1 or steps[s + 1][0] != i)
+    assert {i for i, _ in steps} == set(range(nq))     # every o block
+
+    walk = _Walk(_by_key_block(rows, nk), heads=group)
+    steps = [(*map(int, walk.run(s)), int(walk.at(s)))
+             for s in map(np.int32, range(walk.steps))]
+    for s, (j, _, _) in enumerate(steps):
+        first, last = (bool(x) for x in walk.where(np.int32(s))[2:])
+        assert first == (s == 0 or steps[s - 1][0] != j)
+        assert last == (s == len(steps) - 1 or steps[s + 1][0] != j)
+    assert steps == sorted((j, g, i) for i, j in held for g in range(group))
+    assert {j for j, _, _ in steps} == set(range(nk))  # every dk, dv block
+
+    if causal and block_q == block_k:
+        assert grid_step_share(S, window, block_q) == 1.0
+
+
+def test_grid_step_share_is_one_where_the_rectangle_ran_idle_steps():
+    """What the whole rectangle of block pairs cost (docs/kernels.md keeps
+    the parent's values): steps a head ran over steps that computed."""
+    from horovod_tpu.ops.flash_attention import grid_step_share, window_back
+    for S, window, was in ((2048, None, 4 / 3), (4096, None, 1.6),
+                           (8192, None, 64 / 36), (8192, 512, 64 / 15)):
+        n = S // 1024
+        back = n if window is None else window_back(1024, window)
+        computing = sum(min(i, back) + 1 for i in range(n))
+        assert n * n / computing == pytest.approx(was)
+        assert grid_step_share(S, window) == 1.0
+    assert grid_step_share(1024) == 1.0 and grid_step_share(100) == 1.0
+
+
+def _written_out(q, k, v, causal, window):
+    """(o, lse) with the scores materialised: `masked_attention_reference`
+    for o, the same mask's logsumexp beside it."""
+    from horovod_tpu.ops.flash_attention import masked_attention_reference
+    Sq, Sk = q.shape[2], k.shape[2]
+    group = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, axis=1),
+                   precision="highest") * q.shape[-1] ** -0.5
+    row, col = jnp.arange(Sq)[:, None], jnp.arange(Sk)[None, :]
+    seen = jnp.ones((Sq, Sk), bool)
+    if causal:
+        seen = col <= row
+    if window is not None:
+        seen = seen & (col > row - window)
+    lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+    if Sq != Sk:    # a ring hop's off-diagonal chunk: no mask to write out
+        p = jnp.exp(s - lse[..., None])
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest"), lse
+    return masked_attention_reference(q, k, v, causal, None, window), lse
+
+
+#: (Sq, Sk, block_q, block_k, causal, window, query heads, K/V heads, dlse):
+#: three or more blocks a side, so a row has a first, a middle and a last
+#: held step and rows differ in length
+_MANY_BLOCKS = {
+    "causal-3": (384, 384, 128, 128, True, None, 2, 2, False),
+    "causal-5-g2": (320, 320, 64, 64, True, None, 4, 2, False),
+    "window-one-back-g2": (512, 512, 128, 128, True, 100, 4, 2, False),
+    "window-two-back": (512, 512, 128, 128, True, 200, 2, 2, False),
+    "wide-query-blocks": (384, 384, 128, 64, True, None, 2, 2, False),
+    "wide-key-blocks-g2": (384, 384, 64, 128, True, None, 2, 1, False),
+    "not-causal-3x3": (384, 384, 128, 128, False, None, 2, 2, False),
+    "chunk-causal-dlse": (384, 384, 128, 128, True, None, 2, 2, True),
+    "chunk-3x4-dlse": (384, 512, 128, 128, False, None, 2, 2, True),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _many_blocks_case(case):
+    from horovod_tpu.ops import flash_attention as fa
+    Sq, Sk, bq, bk, causal, window, H, G, with_dlse = _MANY_BLOCKS[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    q = jax.random.normal(ks[0], (1, H, Sq, 8), jnp.float32)
+    k = jax.random.normal(ks[1], (1, G, Sk, 8), jnp.float32)
+    v = jax.random.normal(ks[2], (1, G, Sk, 16), jnp.float32)
+    w = jax.random.normal(ks[3], (1, H, Sq, 16), jnp.float32)
+    wl = jax.random.normal(ks[4], (1, H, Sq), jnp.float32) * with_dlse
+
+    def ours(q, k, v):
+        if with_dlse:
+            return fa.flash_attention_chunk(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk)
+        return fa.flash_attention(q, k, v, causal=causal, window=window,
+                                  block_q=bq, block_k=bk), 0.0
+
+    def theirs(q, k, v):
+        return _written_out(q, k, v, causal, window)
+
+    def loss(f, q, k, v):
+        o, lse = f(q, k, v)
+        return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+    # the forward kernel's own second result, whichever entry point ran it
+    o, lse = fa._fwd(q[0], k[0], v[0], causal, 8 ** -0.5, bq, bk, window)
+    want_o, want_lse = theirs(q, k, v)
+    got = [o[None], lse[None, ..., 0]]
+    want = [want_o, want_lse]
+    for f, into in ((ours, got), (theirs, want)):
+        into.extend(jax.grad(functools.partial(loss, f),
+                             argnums=(0, 1, 2))(q, k, v))
+    return got, want
+
+
+@pytest.mark.parametrize("what", ["o", "lse", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", list(_MANY_BLOCKS))
+def test_three_and_more_blocks_a_side_match_the_mask_written_out(case, what):
+    got, want = _many_blocks_case(case)
+    at = "o lse dq dk dv".split().index(what)
+    assert got[at].shape == want[at].shape
+    np.testing.assert_allclose(np.asarray(got[at]), np.asarray(want[at]),
+                               rtol=2e-4, atol=2e-5)

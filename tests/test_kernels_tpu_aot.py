@@ -375,3 +375,61 @@ def test_grouped_windowed_flash_compiles_for_v5e(monkeypatch, name, window):
     kinds = diff_flash_roofline.flash_kernels(hlo.index(txt), PHI4_FLASH)
     assert sorted(kinds.values()) == {"fwd": ["forward"], "bwd": [
         "dkdv", "dq", "forward"]}[name]
+
+
+#: The six shapes the benchmark's cells run the flash kernels at: (query
+#: heads, K/V heads, seq, keys' width, values' width, window), a chip's batch
+#: folded into the heads as the kernels see it
+CELL_FLASH = {
+    "lm-1chip": (64, 64, 2048, 128, 128, None),           # and lm-dp4
+    "olmoe-1chip": (32, 32, 4096, 128, 128, None),
+    "dsv2lite-1chip": (32, 32, 4096, 192, 128, None),
+    "olmohybrid-1chip": (30, 30, 8192, 128, 128, None),
+    "phi4flash-1chip-full": PHI4_FLASH[1:] + (None,),
+    "phi4flash-1chip-window": PHI4_FLASH[1:] + (PHI4_WINDOW,),
+}
+
+
+def _pallas_grids(jaxpr, found=None):
+    """The grid of every `pallas_call` in a jaxpr, in the order met."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(tuple(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)    # a ClosedJaxpr's
+                if hasattr(sub, "eqns"):
+                    _pallas_grids(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("cell", list(CELL_FLASH))
+def test_the_cells_flash_grids_hold_only_the_blocks_the_mask_holds(
+        monkeypatch, cell):
+    """At each cell's shape the three kernels compile for v5e with the
+    operands and results the benchmark's readers find them by (3 | 6 | 6
+    and 2 | 2 | 1: the grid of held blocks took no scalar-prefetch operand),
+    and their grids are (heads, held pairs) and (K/V heads, held pairs x
+    group): 3 of 4 block pairs at 2,048, 10 of 16 at 4,096, 36 of 64 at
+    8,192, 15 of 64 under the 512-token window."""
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    h, g, s, dk, dv, window = CELL_FLASH[cell]
+    avals = (jax.ShapeDtypeStruct((1, h, s, dk), jnp.bfloat16),
+             jax.ShapeDtypeStruct((1, g, s, dk), jnp.bfloat16),
+             jax.ShapeDtypeStruct((1, g, s, dv), jnp.bfloat16))
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, causal=True, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    txt = compile_kernel_text(topo, bwd, avals, n_calls=3)
+    assert mosaic_signatures(txt) == SIGNATURES["bwd"]
+    held = {(2048, None): 3, (4096, None): 10, (8192, None): 36,
+            (8192, PHI4_WINDOW): 15}[s, window]
+    assert sorted(_pallas_grids(jax.make_jaxpr(bwd)(*avals).jaxpr)) == \
+        sorted([(h, held), (h, held), (g, held * (h // g))])
