@@ -50,6 +50,16 @@ of two softmaxes over paired heads, arXiv:2410.05258), `n_kv_heads` fewer
 key and value heads than query heads, `attention_bias` and `tied_head` that
 is SambaY's decoder-hybrid-decoder (arXiv:2507.06607), Phi-4-mini-flash's.
 
+A `layer_pattern` may name "window" layers too, and its layers may be expert
+layers (their auxiliary numbers are gathered period by period). With `d_head`
+(a head width that is not d_model / n_heads), `unrotated` (kinds of the
+pattern that take no rotary embedding: NoPE), `router_input="layer"` (the
+router scores the layer's input, before its first norm and attention, while
+the experts read the normed post-attention state), `mlp="reglu"` (ReLU-gated)
+and `norm_topk` (a token's k expert weights renormalised) that is
+SmallThinker's block (arXiv:2507.20984): one NoPE full-attention layer to
+three rotating windowed ones.
+
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
 
@@ -168,6 +178,13 @@ class TransformerConfig:
     # the load balance of each sequence, averaged (DeepSeek-V2's `seq_aux`),
     # not of all of a shard's tokens at once
     balance_per_sequence: bool = False
+    # a token's k expert weights divided by their sum (`norm_topk_prob`)
+    norm_topk: bool = False
+    # what the router scores: "mlp", the experts' own input (the normed
+    # post-attention state), or "layer", the layer's input as it arrives,
+    # before its first norm and attention (SmallThinker's router, "placed
+    # before attention"); the experts' rows are the former either way
+    router_input: str = "mlp"
     norm: str = "layernorm"       # "layernorm" (scale and bias) | "rmsnorm"
     rms_norm_eps: float = 1e-5
     positions: str = "learned"    # "learned" (a table added to the
@@ -176,7 +193,13 @@ class TransformerConfig:
     #                               the causal mask, recurrent layers)
     rope_theta: float = 10000.0
     yarn: Optional[Yarn] = None   # needs positions="rope"
-    # "mha": wq, wk, wv of one head width, d_model / n_heads.
+    # kinds of the layer pattern whose layers take no rotation under
+    # positions="rope" (NoPE layers); () rotates every kind
+    unrotated: Tuple[str, ...] = ()
+    # the width of a head of "mha" attention (0: d_model / n_heads); with it
+    # n_heads * d_head need not be d_model: attn "flash" or "local"
+    d_head: int = 0
+    # "mha": wq, wk, wv of one head width, `head_dim`.
     # "gdn": see gdn_heads below.
     # "mla": DeepSeek-V2's latent attention. Queries (qk_nope_dim +
     # qk_rope_dim) a head; one down-projection to kv_latent + qk_rope_dim a
@@ -238,6 +261,7 @@ class TransformerConfig:
     # its output, inside the residual (Olmo 2's arrangement)
     post_norm: bool = False
     # "gelu" (biased when dense) | "swiglu" (gated SiLU, no biases)
+    # | "reglu" (gated ReLU, no biases)
     mlp: str = "gelu"
     attn: str = "ring"            # "ring" | "ulysses" | "flash" | "local"
     microbatches: int = 1         # pipeline microbatches (≥ pp size ideal)
@@ -256,7 +280,12 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def gate(self) -> Optional[str]:
+        """What a gated MLP applies to its gate's product; None: ungated."""
+        return {"swiglu": "silu", "reglu": "relu"}.get(self.mlp)
 
     @property
     def kv_heads(self) -> int:
@@ -339,11 +368,15 @@ def _reads_are_handed_on(cfg: TransformerConfig) -> bool:
 
 
 def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
-    """`cfg` as a layer of `kind` in its pattern sees it."""
+    """`cfg` as a layer of `kind` in its pattern sees it: `LAYER_KINDS`'s
+    row, and no positions for a kind `cfg.unrotated` names."""
     if kind not in LAYER_KINDS:
         raise HorovodTpuError(f"layer_pattern names the kind {kind!r}: "
                               f"choose from {sorted(LAYER_KINDS)}")
-    return dataclasses.replace(cfg, **LAYER_KINDS[kind])
+    changes = dict(LAYER_KINDS[kind])
+    if kind in cfg.unrotated:
+        changes["positions"] = "none"
+    return dataclasses.replace(cfg, **changes)
 
 
 def _kinds(cfg: TransformerConfig, pattern=None) -> Dict[str, int]:
@@ -430,7 +463,7 @@ def _present(tree: Dict[str, Any], cfg: TransformerConfig):
         absent |= {"router", "we1", "we2", "we_gate"}
     if not (cfg.num_experts and cfg.shared_experts):
         absent |= {"ws1", "ws2", "ws_gate"}
-    if cfg.mlp == "swiglu":
+    if cfg.gate:
         absent |= {"b1", "b2"}
     else:
         absent |= {"we_gate", "w_gate", "ws_gate"}
@@ -1006,13 +1039,13 @@ def _diff_attention(h, lp: Dict[str, Any], cfg: TransformerConfig, depth,
         return jnp.einsum("bpsk,pkd->bsd", a, wo), kv
 
 
-def _mlp(h, w_gate, w_up, w_down):
-    """W_down (silu(W_gate h) * W_up h), or W_down gelu(W_up h) without a
-    gate; no biases. The hidden width is sharded over tp: this rank's part
-    of the sum."""
+def _mlp(h, w_gate, w_up, w_down, gate="silu"):
+    """W_down (act(W_gate h) * W_up h), act the `gate` of `moe.GATES`, or
+    W_down gelu(W_up h) without a gate; no biases. The hidden width is
+    sharded over tp: this rank's part of the sum."""
     hidden = jnp.einsum("bsd,df->bsf", h, w_up)
     hidden = jax.nn.gelu(hidden) if w_gate is None else \
-        jax.nn.silu(jnp.einsum("bsd,df->bsf", h, w_gate)) * hidden
+        moe_mod.GATES[gate](jnp.einsum("bsd,df->bsf", h, w_gate)) * hidden
     return jnp.einsum("bsf,fd->bsd", hidden, w_down)
 
 
@@ -1027,6 +1060,9 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     values it read: what a segment may hand on. `shared` is what an earlier
     segment handed on, {"memory", "kv"}; `depth` the layer's index in the
     model."""
+    if cfg.positions != "rope":
+        rope = None         # a kind the pattern leaves unrotated
+    arrived = x
     h = x if cfg.post_norm else _norm(x, lp, "ln1", cfg)
     handed = None
     if cfg.attention == "mla":
@@ -1071,15 +1107,19 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
             lp.get("we_gate"), top_k=cfg.experts_per_token, axis_name="ep",
             capacity_factor=cfg.capacity_factor,
             first_expert=cfg.first_expert,
-            sequences=B if cfg.balance_per_sequence else 0)
+            sequences=B if cfg.balance_per_sequence else 0,
+            router_input=arrived.reshape(B * S, D)
+            if cfg.router_input == "layer" else None,
+            renormalise=cfg.norm_topk, gate=cfg.gate or "silu")
         f = out.reshape(B, S, D)
         if cfg.shared_experts:
             with jax.named_scope("moe.shared"):
                 f = f + lax.psum(_mlp(h2, lp.get("ws_gate"), lp["ws1"],
-                                      lp["ws2"]), "tp")
-    elif cfg.mlp == "swiglu":
+                                      lp["ws2"], cfg.gate), "tp")
+    elif cfg.gate:
         with jax.named_scope("mlp.dense"):
-            f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"]), "tp")
+            f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"],
+                              cfg.gate), "tp")
     else:
         with jax.named_scope("mlp.dense"):
             u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
@@ -1213,17 +1253,23 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
             lp = {kind: _scattered_in_backward(
                 leaves, slot[kind], partial(scatter, (stack, kind)))
                 if kind in slot else leaves for kind, leaves in lp.items()}
-            seen = dict.fromkeys(lp, 0)
+            seen, auxes = dict.fromkeys(lp, 0), []
             for kind in cfg.layer_pattern:
                 i = seen[kind]
                 seen[kind] += 1
-                a, _ = remat(partial(_layer, cfg=_kind_cfg(layer_cfg, kind),
-                                     rope=rope), prevent_cse=True)(
+                a, aux = remat(partial(_layer, cfg=_kind_cfg(layer_cfg, kind),
+                                       rope=rope), prevent_cse=True)(
                     a, {k: w[i] for k, w in lp[kind].items()})
-            return a, None
+                auxes.append(aux)
+            # expert layers: the period's auxiliary numbers, layer by layer
+            return a, jnp.stack(auxes) if layer_cfg.num_experts else None
 
-        return lax.scan(one_period if patterned else remat(one_kind), act,
-                        (stage_params, slots) if slots else stage_params)
+        act, aux = lax.scan(one_period if patterned else remat(one_kind),
+                            act,
+                            (stage_params, slots) if slots else stage_params)
+        if patterned and aux is not None:
+            aux = aux.reshape(-1, aux.shape[-1])     # (periods x kinds, ...)
+        return act, aux
 
     if cfg.segments:
         x = _run_segments(params["segments"], x, cfg)
@@ -1622,15 +1668,38 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
          "segments require sp=tp=pp=1 (what a segment hands on, a "
          "state-space layer's state and channels, and differential "
          "attention's paired heads do not cross shards or stages)"),
-        (not {"ssm", "gmu", "cross", "window"} & set(cfg.layer_pattern),
-         "the kinds 'ssm', 'gmu', 'cross' and 'window' need segments"),
+        (not {"ssm", "gmu", "cross"} & set(cfg.layer_pattern),
+         "the kinds 'ssm', 'gmu' and 'cross' need segments"),
         (_reads_are_handed_on(cfg),
          "'gmu' and 'cross' layers need an earlier segment with an 'ssm' "
          "and the 'full' layer"),
         ("cross" not in kinds or cfg.diff_attention,
          "'cross' layers are differential attention's (diff_attention)"),
-        ("window" not in kinds or cfg.window > 0,
+        ("window" not in kinds + list(cfg.layer_pattern) or cfg.window > 0,
          "'window' layers need window > 0"),
+        (cfg.mlp in ("gelu", "swiglu", "reglu"),
+         f"mlp={cfg.mlp!r}: choose 'gelu', 'swiglu' or 'reglu'"),
+        (cfg.router_input in ("mlp", "layer"),
+         f"router_input={cfg.router_input!r}: choose 'mlp' or 'layer'"),
+        (cfg.router_input == "mlp" or not cfg.post_norm,
+         "router_input='layer' with post_norm (the layer's input is the "
+         "attention's too)"),
+        # a head width of its own runs where it is tested: no test takes
+        # it through ring or Ulysses attention or shards such heads
+        (not cfg.d_head or cfg.d_head * cfg.n_heads == cfg.d_model
+         or cfg.attn in ("flash", "local"),
+         f"d_head * n_heads != d_model needs attn 'flash' or 'local', not "
+         f"{cfg.attn!r}"),
+        (not cfg.d_head or cfg.d_head * cfg.n_heads == cfg.d_model or whole,
+         "d_head * n_heads != d_model requires sp=tp=pp=1 (no mesh test "
+         "shards heads of a width of their own)"),
+        (not cfg.d_head or cfg.attention == "mha",
+         f"d_head is plain attention's head width: attention="
+         f"{cfg.attention!r} has widths of its own"),
+        (not cfg.unrotated or cfg.positions == "rope",
+         "unrotated names kinds that take no rotation: positions='rope'"),
+        (set(cfg.unrotated) <= set(cfg.layer_pattern) | set(kinds),
+         f"unrotated names a kind the pattern lacks: {cfg.unrotated}"),
         (cfg.n_heads % cfg.kv_heads == 0, "n_heads % n_kv_heads"),
         (not cfg.diff_attention or (
             cfg.n_heads % 2 == 0 and cfg.kv_heads % 2 == 0
@@ -1654,9 +1723,6 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
         (not cfg.layer_pattern or ax["pp"] == 1,
          "a layer pattern requires pp=1 (the pipeline schedule places "
          "layers, not periods)"),
-        (not cfg.layer_pattern or not cfg.num_experts,
-         "a layer pattern with experts (the periods' auxiliary terms are "
-         "not gathered)"),
         (_stack_depth(cfg) % ax["pp"] == 0, "n_layers % pp"),
         (cfg.n_heads % ax["tp"] == 0, "n_heads % tp"),
         (cfg.d_ff % ax["tp"] == 0, "d_ff % tp"),

@@ -5,14 +5,19 @@ Not present in the reference (SURVEY.md §2.6 — `alltoall` is the substrate
 it exposes for users to build this). For a token `h` with router weights
 `Wr`:
 
-    p   = softmax(h Wr)                 over all E experts, in float32
-    out = sum over the k largest p_e of p_e * expert_e(h)
+    p   = softmax(r Wr)                 over all E experts, in float32
+    out = sum over the k largest p_e of w_e * expert_e(h)
 
-The k weights are not renormalised. `expert_e(h)` is `W_down,e (silu(W_gate,e
-h) * (W_up,e h))` where gate weights are given and `W_down,e gelu(W_up,e h)`
-where they are not. Experts that every token goes through beside these
-(DeepSeek's shared experts) are a dense MLP of the caller's, added to this
-layer's result (`models/transformer.py`, scope `moe.shared`).
+`r` is `h` itself unless the caller hands the router an input of its own
+(`router_input`: SmallThinker, arXiv:2507.20984, scores the layer's input,
+before attention, and its experts read the normed post-attention state). The
+k weights `w_e` are the `p_e` as they are, or with `renormalise` divided by
+their sum over the k chosen (`norm_topk_prob`), the gradient through the sum
+included. `expert_e(h)` is `W_down,e (act(W_gate,e h) * (W_up,e h))` where
+gate weights are given, `act` being `gate`: "silu" or "relu"; and `W_down,e
+gelu(W_up,e h)` where they are not. Experts that every token goes through
+beside these (DeepSeek's shared experts) are a dense MLP of the caller's,
+added to this layer's result (`models/transformer.py`, scope `moe.shared`).
 
 On one rank of the expert axis (`ep` = 1) routing is dropless and
 static-shaped: the T*k (token, expert) pairs are sorted by expert, their rows
@@ -95,16 +100,20 @@ from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, visits
 
 
-def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0):
+def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
+          renormalise: bool = False):
     """(weights (T, k) float32, experts (T, k) int32, rows per expert (E,)
     int32, [load balance, router z] float32) for the tokens x: (T, D), which
     are `sequences` sequences of equal length where the load balance is to
-    be each sequence's own (0: of all T tokens at once)."""
+    be each sequence's own (0: of all T tokens at once). With `renormalise`
+    a token's k weights add up to 1."""
     with jax.named_scope("moe.route"):
         n_experts = router_w.shape[1]
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = lax.top_k(probs, top_k)
+        if renormalise:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         if sequences:
             per_seq = jnp.sum(
                 jax.nn.one_hot(experts, n_experts, dtype=jnp.int32).reshape(
@@ -214,9 +223,15 @@ def held_rows(pairs: int, n_local: int, n_experts: int) -> int:
     return min(pairs, math.ceil(2 * even / ROW_TILE) * ROW_TILE)
 
 
-def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
+#: what a gated expert applies to its gate's product
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None,
+             gate="silu"):
     """The experts on rows sorted by expert: three grouped matmuls (two for
-    an ungated expert). `row_weights` (rows, 1) float32, where given, scale
+    an ungated expert), the gate's product through `GATES[gate]`.
+    `row_weights` (rows, 1) float32, where given, scale
     each row's hidden activations, in float32 from the products' results to
     the one rounding the down product's input has either way. Every row
     lies in a group. The kernels' tile visits are made here once, for the
@@ -228,8 +243,8 @@ def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None):
         if w_gate is None:
             hidden = jax.nn.gelu(hidden)
         else:
-            gate = grouped_matmul(rows, w_gate, plan).astype(wide)
-            hidden = jax.nn.silu(gate) * hidden
+            gated = grouped_matmul(rows, w_gate, plan).astype(wide)
+            hidden = GATES[gate](gated) * hidden
         if row_weights is not None:
             hidden = (hidden * row_weights).astype(rows.dtype)
         return grouped_matmul(hidden, w_down, plan)
@@ -239,7 +254,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             w_down: jax.Array, w_gate: Optional[jax.Array] = None, *,
             top_k: int = 1, axis_name: str = "ep",
             capacity_factor: float = 1.25, first_expert: int = 0,
-            sequences: int = 0
+            sequences: int = 0, router_input: Optional[jax.Array] = None,
+            renormalise: bool = False, gate: str = "silu"
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k mixture-of-experts feed-forward on one shard's tokens.
 
@@ -251,6 +267,9 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         any E_local <= E, experts [first_expert, first_expert + E_local)
       sequences: how many sequences the T tokens are, where the load
         balance is each sequence's own (`route`)
+      router_input: (T, D), what the router scores where that is not x
+      renormalise: a token's k weights divided by their sum
+      gate: what a gated expert applies to its gate's product (`GATES`)
     Returns ((T, D), [load balance, router z] of these tokens, the (T, k)
     experts of each token in the order of their weights). Where one rank
     holds a share (E_local < E) the second has a third number: the held
@@ -272,10 +291,14 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         raise HorovodTpuError(
             f"first_expert={first_expert} with {n_local} of {n_experts} "
             f"experts on {ranks} rank(s)")
+    if gate not in GATES:
+        raise HorovodTpuError(f"gate={gate!r}: choose from {sorted(GATES)}")
     # one rank, holding some of the experts
     share = ranks == 1 and n_local < n_experts
 
-    weights, experts, counts, aux = route(x, router_w, k, sequences)
+    weights, experts, counts, aux = route(
+        x if router_input is None else router_input, router_w, k, sequences,
+        renormalise)
 
     with jax.named_scope("moe.dispatch"):
         key = experts.reshape(-1)
@@ -332,7 +355,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             sizes = jnp.full((n_local,), ranks * cap, jnp.int32)
             row_weights = None
 
-    ys = _experts(rows, sizes, w_up, w_down, w_gate, row_weights)
+    ys = _experts(rows, sizes, w_up, w_down, w_gate, row_weights, gate)
 
     with jax.named_scope("moe.combine"):
         if ranks == 1:

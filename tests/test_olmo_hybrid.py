@@ -445,14 +445,16 @@ def test_the_train_step_learns_the_fixed_batch(params):
     ({"tp": 3}, CFG, "linear-attention layers require tp=1"),
     ({"pp": 2}, dataclasses.replace(CFG, microbatches=2),
      "a layer pattern requires pp=1"),
-    ({}, dataclasses.replace(CFG, num_experts=4),
-     "a layer pattern with experts"),
+    # a pattern's layers may be expert layers since PR 38, and may be
+    # windowed: what is still refused is a window of no keys
+    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "window")),
+     "'window' layers need window > 0"),
     ({}, dataclasses.replace(CFG, n_layers=6),
      "no whole number of periods"),
     ({}, dataclasses.replace(CFG, layer_pattern=("linear", "sparse")),
      "names the kind 'sparse'"),
     # a kind since PR 36, which a pattern alone cannot hold
-    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "window")),
+    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "ssm")),
      "need segments"),
     ({"sp": 2}, dataclasses.replace(CFG, layer_pattern=(), attention="gdn"),
      "linear-attention layers require sp=1"),

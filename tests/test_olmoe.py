@@ -184,14 +184,20 @@ def _experts(key, n_experts, d, f, gated):
     return router, up, down, gate
 
 
-def _oracle(x, router, up, down, gate, top_k, keep=None):
+def _oracle(x, router, up, down, gate, top_k, keep=None, *, scored=None,
+            renormalise=False, act="silu"):
     """Every expert on every token, summed over the token's top-k with their
-    softmax weights; `keep` (T, k) drops pairs."""
-    probs = jax.nn.softmax(x @ router, axis=-1)
+    softmax weights; `keep` (T, k) drops pairs. `scored`: what the router
+    reads where that is not x; `renormalise`: the k weights over their sum;
+    `act`: a gated expert's activation."""
+    probs = jax.nn.softmax((x if scored is None else scored) @ router,
+                           axis=-1)
     weights, experts = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     hidden = jnp.einsum("td,edf->tef", x, up)
     hidden = jax.nn.gelu(hidden) if gate is None else \
-        jax.nn.silu(jnp.einsum("td,edf->tef", x, gate)) * hidden
+        getattr(jax.nn, act)(jnp.einsum("td,edf->tef", x, gate)) * hidden
     every = jnp.einsum("tef,efd->ted", hidden, down)
     chosen = jnp.take_along_axis(every, experts[..., None], axis=1)
     if keep is not None:
@@ -199,15 +205,17 @@ def _oracle(x, router, up, down, gate, top_k, keep=None):
     return jnp.sum(chosen * weights[..., None], axis=1), experts
 
 
-def _run(x, router, up, down, gate, top_k, ep, capacity_factor):
+def _run(x, router, up, down, w_gate, top_k, ep, capacity_factor,
+         scored=None, **options):
     spec = P("ep")
-    gspec = None if gate is None else spec
+    gspec = None if w_gate is None else spec
+    sspec = None if scored is None else spec
     return jax.jit(jax.shard_map(
-        lambda xx, r, u, d, g: moe_ffn(
+        lambda xx, r, u, d, g, s: moe_ffn(
             xx, r, u, d, g, top_k=top_k, axis_name="ep",
-            capacity_factor=capacity_factor)[0],
-        mesh=mesh_of(ep=ep), in_specs=(spec, P(), spec, spec, gspec),
-        out_specs=spec, check_vma=False))(x, router, up, down, gate)
+            capacity_factor=capacity_factor, router_input=s, **options)[0],
+        mesh=mesh_of(ep=ep), in_specs=(spec, P(), spec, spec, gspec, sspec),
+        out_specs=spec, check_vma=False))(x, router, up, down, w_gate, scored)
 
 
 @pytest.mark.parametrize("capacity_factor", [1e-3, 1.25])
@@ -228,27 +236,56 @@ def test_one_rank_drops_nothing_when_every_token_takes_one_expert(
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("top_k,gated", [(1, False), (2, True)],
-                         ids=["top1-gelu", "top2-swiglu"])
-def test_two_ranks_match_the_single_rank_oracle(top_k, gated):
+#: SmallThinker's three (PR 38): the k weights renormalised, a ReLU gate,
+#: the router reading an input of its own
+_THREE = {"renormalise": True, "gate": "relu", "router_input": True}
+
+
+def _options(options):
+    """(`moe_ffn`'s keywords, `_oracle`'s, whether the router has an input
+    of its own) of a case's options."""
+    options = dict(options)
+    own = options.pop("router_input", False)
+    theirs = {"act" if k == "gate" else k: v for k, v in options.items()}
+    return options, theirs, own
+
+
+@pytest.mark.parametrize("top_k,gated,options", [
+    (1, False, {}), (2, True, {}), (3, True, _THREE)],
+    ids=["top1-gelu", "top2-swiglu", "top3-reglu-renormalised-own-input"])
+def test_two_ranks_match_the_single_rank_oracle(top_k, gated, options):
+    """Across ranks the same router, gate and weights as on one rank: the
+    exchange carries rows, and the weights multiply what comes back."""
+    ours, theirs, own = _options(options)
     x = jax.random.normal(jax.random.PRNGKey(5), (32, 8), jnp.float32)
+    scored = jax.random.normal(jax.random.PRNGKey(9), (32, 8), jnp.float32) \
+        if own else None
     weights = _experts(jax.random.PRNGKey(6), 8, 8, 16, gated)
-    want, _ = _oracle(x, *weights, top_k)
-    got = _run(x, *weights, top_k, 2, 64.0)
+    want, _ = _oracle(x, *weights, top_k, scored=scored, **theirs)
+    got = _run(x, *weights, top_k, 2, 64.0, scored, **ours)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
                                atol=2e-4)
-    one = _run(x, *weights, top_k, 1, 64.0)
+    one = _run(x, *weights, top_k, 1, 64.0, scored, **ours)
     np.testing.assert_allclose(np.asarray(one), np.asarray(want), rtol=2e-4,
                                atol=2e-4)
 
 
-@pytest.mark.parametrize("top_k,gated", [(1, False), (2, True), (8, True)],
-                         ids=["top1-gelu", "top2-swiglu", "top8-swiglu"])
-def test_one_rank_and_its_gradients_match_the_dense_oracle(top_k, gated):
+@pytest.mark.parametrize("top_k,gated,options", [
+    (1, False, {}), (2, True, {}), (8, True, {}),
+    (3, True, {"renormalise": True}), (3, True, {"gate": "relu"}),
+    (3, True, {"router_input": True}), (3, True, _THREE)],
+    ids=["top1-gelu", "top2-swiglu", "top8-swiglu", "top3-renormalised",
+         "top3-reglu", "top3-own-router-input", "top3-all-three"])
+def test_one_rank_and_its_gradients_match_the_dense_oracle(top_k, gated,
+                                                           options):
     """`moe_ffn` on one rank weights the experts' hidden rows before the
     down product; the oracle runs every expert on every token and weights
     the results after it. Output and the gradient of every argument agree
-    in float32, with expert 0 receiving most rows and expert 11 none."""
+    in float32, with expert 0 receiving most rows and expert 11 none.
+    Renormalised weights take their gradient through the sum; with an input
+    of its own the router's gradient reaches that input through the scores
+    alone, and the rows' input through the experts alone."""
+    ours, theirs, own = _options(options)
     n_experts, d, f = 12, 8, 16
     x = jax.random.normal(jax.random.PRNGKey(11), (48, d), jnp.float32)
     router, up, down, gate = _experts(jax.random.PRNGKey(12), n_experts, d,
@@ -257,14 +294,21 @@ def test_one_rank_and_its_gradients_match_the_dense_oracle(top_k, gated):
     router = router.at[0].set(0.0).at[0, 0].set(6.0).at[0, 11].set(-60.0)
     probe = jax.random.normal(jax.random.PRNGKey(13), x.shape, jnp.float32)
     leaves = (x, router, up, down) + ((gate,) if gated else ())
+    if own:
+        # the router reads this, with the column that skews the load; the
+        # experts read other rows
+        leaves += (x,)
+        leaves = (jax.random.normal(jax.random.PRNGKey(14), x.shape,
+                                    jnp.float32),) + leaves[1:]
 
-    def program(xx, r, u, dn, g=None):
+    def program(xx, r, u, dn, g=None, s=None):
         return jax.shard_map(
-            lambda *a: moe_ffn(*a, top_k=top_k)[0], mesh=mesh_of(),
-            in_specs=P(), out_specs=P(), check_vma=False)(xx, r, u, dn, g)
+            lambda *a: moe_ffn(*a[:5], top_k=top_k, router_input=a[5],
+                               **ours)[0], mesh=mesh_of(),
+            in_specs=P(), out_specs=P(), check_vma=False)(xx, r, u, dn, g, s)
 
-    def oracle(xx, r, u, dn, g=None):
-        return _oracle(xx, r, u, dn, g, top_k)[0]
+    def oracle(xx, r, u, dn, g=None, s=None):
+        return _oracle(xx, r, u, dn, g, top_k, scored=s, **theirs)[0]
 
     def probed(ffn):
         def loss(*a):
@@ -273,15 +317,16 @@ def test_one_rank_and_its_gradients_match_the_dense_oracle(top_k, gated):
         return jax.jit(jax.value_and_grad(loss, tuple(range(len(leaves))),
                                           has_aux=True))
 
-    rows = np.bincount(np.asarray(_oracle(*leaves[:4], gate, top_k)[1])
-                       .reshape(-1), minlength=n_experts)
+    rows = np.bincount(np.asarray(_oracle(
+        *leaves[:4], gate, top_k, scored=leaves[5] if own else None)[1])
+        .reshape(-1), minlength=n_experts)
     assert rows[11] == 0 and rows[0] == max(rows) >= len(x) // 2, rows
     (_, got), got_grads = probed(program)(*leaves)
     (_, want), want_grads = probed(oracle)(*leaves)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
-    for name, g, w in zip(("x", "router_w", "w_up", "w_down", "w_gate"),
-                          got_grads, want_grads):
+    for name, g, w in zip(("x", "router_w", "w_up", "w_down", "w_gate",
+                           "router_input"), got_grads, want_grads):
         scale = float(jnp.max(jnp.abs(w)))
         assert scale > 0.0, name
         assert float(jnp.max(jnp.abs(g - w))) <= 2e-5 * scale, name

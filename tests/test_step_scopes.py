@@ -1,12 +1,13 @@
 """The scopes of the compiled train step (`transformer.STEP_SCOPES` and the
 mixers' `moe.*`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`): for tiny configurations
-of the five kinds the benchmark's LM cells run, compiled on the CPU, every scope the
+of the six kinds the benchmark's LM cells run, compiled on the CPU, every scope the
 model has is in the compiled text's `op_name`s, in the forward pass and in
 the backward pass; the gradient reduction's only where something is
 reduced; and `DistributedOptimizer.step` records its two phases as spans of
 the JAX profiler. That the scopes change nothing but names is
 `tests/test_lowered_steps.py`'s to show: its fixture is untouched."""
 
+import dataclasses
 import functools
 import glob
 import inspect
@@ -23,8 +24,10 @@ from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from test_lowered_steps import CONFIGS
 from test_olmo_hybrid import CFG as HYBRID
 from test_phi4_flash import CFG as PHI4_FLASH
+from test_smallthinker import CFG as SMALLTHINKER
 
-CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID, phi4_flash=PHI4_FLASH)
+CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID, phi4_flash=PHI4_FLASH,
+               smallthinker=dataclasses.replace(SMALLTHINKER, attn="flash"))
 
 ATTN = ("attn.project", "attn.attend", "attn.out")
 VOCAB = ("vocab.embed", "vocab.head", "vocab.loss")
@@ -41,6 +44,7 @@ HAS = {
     "phi4_flash": ATTN + ("attn.window", "ssm.project", "ssm.conv",
                           "ssm.scan", "ssm.gate", "ssm.out", "gmu.project",
                           "gmu.gate", "gmu.out", "mlp.dense") + VOCAB,
+    "smallthinker": ATTN + ("attn.window",) + MOE + VOCAB,
 }
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -92,7 +96,7 @@ def test_the_step_has_its_scopes_and_no_other(name):
 
 
 @pytest.mark.parametrize("name", ["gpt2", "olmoe", "olmo_hybrid",
-                                  "phi4_flash"])
+                                  "phi4_flash", "smallthinker"])
 def test_the_reduction_has_its_scope_where_something_is_reduced(name):
     """On one rank nothing is reduced and the scope is absent; at `dp` = 2
     the halving inside the backward loop and the sums after it have it (a
@@ -108,7 +112,7 @@ def test_the_reduction_has_its_scope_where_something_is_reduced(name):
 
 @pytest.mark.parametrize("name, dp", [
     ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
-    ("phi4_flash", 2)])
+    ("phi4_flash", 2), ("smallthinker", 2)])
 def test_no_instruction_lies_under_two_layers_scopes(name, dp):
     """`mlp.dense` is entered at `_layer`'s dense branches and not in
     `_mlp`, which the shared experts run under `moe.shared`; the reduction
