@@ -48,6 +48,7 @@ from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import causal_conv as cc
 from horovod_tpu.ops import gated_delta as gd
 from horovod_tpu.ops import grouped_matmul as gm
+from horovod_tpu.ops import row_gather as rg
 from horovod_tpu.ops import selective_scan as ss
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              flash_attention,
@@ -55,6 +56,7 @@ from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              masked_attention_reference,
                                              window_tile_share)
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
+from horovod_tpu.parallel.moe import held_rows
 from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 
 REPO = os.path.dirname(os.path.abspath(__file__))
@@ -602,6 +604,67 @@ def causal_conv_pass(log: CompileLog, shape=(1, 30, 8192),
         + "; ".join(told))
 
 
+def row_sum_pass(log: CompileLog,
+                 shapes=((16384, 2560, 6, 64, 16), (8192, 2048, 6, 64, 8),
+                         (8192, 2048, 8, 64, 64))) -> None:
+    """The expert layer's sum of rows (ops/row_gather.py) alone at the three
+    expert cells' (tokens, width, k, experts, experts held): `back` and
+    `n_valid` of a seeded routing to k distinct experts a token, the row
+    buffer `parallel/moe.py` sizes for it. The kernel against the `jnp` form,
+    bit for bit; the Mosaic kernels the compiled sum holds (on the TPU two:
+    the row form and the sum); no compile request after a first call; then
+    both forms' times, the kernel's per row that counts, `moved_share`, and
+    the bytes that must move over the HBM rate. Where every expert is held
+    (`olmoe-1chip`) the program keeps the `jnp` sum: the kernel is timed at
+    that shape with the whole buffer as the limit, which is what keeps it
+    out."""
+    told = []
+    for n_tokens, width, k, n_experts, n_local in shapes:
+        rng = np.random.default_rng(0)
+        experts = np.argsort(rng.random((n_tokens, n_experts)),
+                             axis=1)[:, :k].reshape(-1)
+        key = np.where(experts < n_local, experts, n_local)
+        back = np.argsort(np.argsort(key, kind="stable")).astype(np.int32)
+        room = held_rows(n_tokens * k, n_local, n_experts)
+        n_valid = min(int((key < n_local).sum()), room)
+        ys = jax.random.normal(jax.random.PRNGKey(width), (room, width),
+                               jnp.bfloat16)
+        operands = (ys, jnp.asarray(back), jnp.int32(n_valid))
+        runs = {"kernel": jax.jit(
+                    lambda ys, back, n: rg._kernel_sum(ys, back, k, n)),
+                "jnp": jax.jit(
+                    lambda ys, back, n: rg.reference_sum(ys, back, k, n))}
+        runs = {name: (fn.lower(*operands).compile(), operands)
+                for name, fn in runs.items()}
+        got, want = (fn(*args) for fn, args in runs.values())
+        if not bool(jnp.array_equal(got.astype(jnp.float32),
+                                    want.astype(jnp.float32))):
+            raise AssertionError(
+                f"row sum, {n_tokens} tokens x {width}, k {k}: the kernel's "
+                "sum is not the jnp form's, bit for bit")
+        kernels = _counted(runs, {"kernel": 2, "jnp": 0}, "row sum")
+        ms = _timed_without_recompiles(log, runs, "row sum", 10)
+        least = ""
+        if on_tpu():   # a share of the benchmark's table of peaks
+            rate = peaks.for_kind(jax.devices()[0].device_kind).hbm_bytes_per_s
+            bound = 1e3 * 2 * width * (n_valid + n_tokens) / rate
+            least = (f"; the counted rows read and the tokens written at the "
+                     f"HBM rate {bound:.3f} ms "
+                     f"({100 * bound / ms['kernel']:.1f}% of the kernel's)")
+        told.append(
+            f"{n_tokens} tokens x {width}, k {k}, {n_local} of {n_experts} "
+            f"experts held: {n_valid} of {n_tokens * k} entries count into "
+            f"a buffer of {room} rows, moved_share "
+            f"{rg.moved_share(back.tolist(), n_valid):.4f}, "
+            f"tpu_custom_call in the compiled kernel {kernels['kernel']}, "
+            "the same bits as the jnp form; alone (information only), ms an "
+            f"execution: kernel {ms['kernel']:.3f} "
+            f"({1e6 * ms['kernel'] / max(n_valid, 1):.1f} ns a counted row), "
+            f"jnp {ms['jnp']:.3f}" + least)
+    say(f"[row sum] bf16, interpret={_pallas.interpret()}, 0 recompiles "
+        "after a first call; " + "; ".join(told))
+
+
 def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
                         checked=512) -> None:
     """The selective scan's kernels (ops/selective_scan.py) alone at
@@ -961,6 +1024,7 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
              causal_conv_pass, selective_scan_pass, windowed_grouped_flash,
+             row_sum_pass,
              flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }

@@ -37,12 +37,12 @@ backward would run the down product and a (T*k, D) gather again for a (T, k)
 gradient. Weighted in front, nothing after the down product is kept or
 recomputed, and the weights' gradient is a row sum over the hidden rows the
 gate's backward needs anyway. The combine is then a plain gather and sum, the
-transpose of the dispatch (`_sum_rows`, `_take_rows`): each one's backward
-pass is the other, and the cotangent is gathered from the (T, D) array, never
-from a (T*k, D) copy of it. Across ranks the weights still multiply the
-returned rows: they come back in slot layout, where dropped pairs are
-masked, and weighting in front would send the weights through an exchange of
-their own.
+transpose of the dispatch (`sum_rows`, `take_rows` of `ops/row_gather.py`):
+each one's backward pass is the other, and the cotangent is gathered from the
+(T, D) array, never from a (T*k, D) copy of it. Across ranks the weights
+still multiply the returned rows: they come back in slot layout, where
+dropped pairs are masked, and weighting in front would send the weights
+through an exchange of their own.
 
 A share of the experts. The router's width E is the model's; the expert
 weights say how many are held, and `first_expert` which: experts
@@ -51,7 +51,8 @@ weights say how many are held, and `first_expert` which: experts
 over all E and computes its own experts' part of the result for its own
 tokens: pairs routed to held experts are sorted first, gathered, multiplied
 and summed back as above; a pair routed elsewhere adds nothing, and its
-weight gets no gradient through the experts. Nothing stands in for the
+weight gets no gradient through the experts (the sum back copies only the
+rows that count: `ops/row_gather.py`'s kernel). Nothing stands in for the
 absent ranks. The row buffer is static and sized from the shapes alone:
 `held_rows`, twice the rows an even routing sends to the held experts. Its
 free rows are zero and lie in the last held expert's group, where they add
@@ -89,7 +90,6 @@ over the sequences. Either way it is over all E experts, held or not.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -98,6 +98,7 @@ from jax import lax
 
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, visits
+from horovod_tpu.ops.row_gather import sum_rows, take_rows
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
@@ -134,62 +135,6 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
         z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
         return weights, experts.astype(jnp.int32), counts, \
             jnp.stack([load_balance, z])
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, rows, back, k, n_valid=None):
-    """`x[rows]`, where `back` says which k rows of the result each row of
-    `x` went to (row t to rows back[t*k:(t+1)*k]): the backward pass is then
-    a gather and a sum over k (`_sum_rows`), not a scatter-add. With
-    `n_valid` the result is a row buffer of which the first n_valid rows
-    count: the others are zero, and `back` may point past its end."""
-    taken = x[rows]
-    if n_valid is None:
-        return taken
-    return jnp.where((jnp.arange(rows.size) < n_valid)[:, None], taken,
-                     jnp.zeros((), x.dtype))
-
-
-def _take_rows_fwd(x, rows, back, k, n_valid=None):
-    return _take_rows(x, rows, back, k, n_valid), (rows, back, n_valid)
-
-
-def _take_rows_bwd(k, indices, g):
-    rows, back, n_valid = indices
-    return _sum_rows(g, back, rows, k, n_valid), None, None, None
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_rows(x, back, rows, k, n_valid=None):
-    """The transpose of `_take_rows`: row t of the result is the sum, in
-    float32, of the k rows x[back[t*k:(t+1)*k]], and row i of `x` went into
-    row rows[i] alone: the backward pass is `g[rows]`, a gather from the
-    small array. With `n_valid`, a row of `back` at or past it adds
-    nothing."""
-    if n_valid is None:
-        picked = x[back]
-    else:
-        picked = jnp.where((back < n_valid)[:, None],
-                           x[jnp.minimum(back, x.shape[0] - 1)],
-                           jnp.zeros((), x.dtype))
-    return jnp.sum(picked.reshape(-1, k, x.shape[-1]), axis=1,
-                   dtype=jnp.promote_types(x.dtype, jnp.float32)
-                   ).astype(x.dtype)
-
-
-def _sum_rows_fwd(x, back, rows, k, n_valid=None):
-    return _sum_rows(x, back, rows, k, n_valid), (rows, back, n_valid)
-
-
-def _sum_rows_bwd(k, indices, g):
-    rows, back, n_valid = indices
-    return _take_rows(g, rows, back, k, n_valid), None, None, None
-
-
-_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
 @jax.custom_vjp
@@ -329,7 +274,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
                     [aux, (held - n_valid).astype(aux.dtype)[None]])
                 held_order = order[:room]
             token_of = held_order // k
-            rows = _take_rows(x, token_of, inverse, k, n_valid)
+            rows = take_rows(x, token_of, inverse, k, n_valid)
             # the down product is linear: weighting its input rows leaves
             # nothing after it for the backward pass to keep or recompute
             row_weights = _permute(weights.reshape(-1), order,
@@ -359,7 +304,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
     with jax.named_scope("moe.combine"):
         if ranks == 1:
-            out = _sum_rows(ys, inverse, token_of, k, n_valid)
+            out = sum_rows(ys, inverse, token_of, k, n_valid)
         else:
             # Inverse re-shard: capacity segment s returns to rank s;
             # received expert groups stack along axis 0 in rank (= global

@@ -118,6 +118,24 @@ def test_phase_causal_conv_pass(smoke, capsys):
 
 
 @one_chip
+def test_phase_row_sum_pass(smoke, capsys):
+    chip_smoke.row_sum_pass(smoke, shapes=((64, 256, 2, 8, 2),
+                                           (32, 256, 2, 4, 4)))
+    out = capsys.readouterr().out
+    assert "[row sum] bf16, interpret=True, 0 recompiles after a first " \
+        "call; 64 tokens x 256, k 2, 2 of 8 experts held: " in out
+    assert " of 128 entries count into a buffer of 128 rows, moved_share 0." \
+        in out
+    # every expert held: the buffer is all the pairs, every entry counts
+    assert "32 tokens x 256, k 2, 4 of 4 experts held: 64 of 64 entries " \
+        "count into a buffer of 64 rows, moved_share 1.0000" in out
+    assert out.count("the same bits as the jnp form") == 2
+    assert out.count(" ns a counted row), jnp ") == 2
+    assert "tpu_custom_call in the compiled kernel 0" in out
+    assert "HBM rate" not in out     # no share of a peak off the TPU
+
+
+@one_chip
 def test_phase_selective_scan_pass(smoke, capsys):
     chip_smoke.selective_scan_pass(smoke, shape=(1, 64, 48, 8), checked=32)
     out = capsys.readouterr().out

@@ -18,7 +18,10 @@ compiled for the chip.
 The expert layer's grouped matmuls (ops/grouped_matmul.py) likewise: the
 three products at the two expert cells' shapes, each custom call with the
 operands and the result `benchmark/harness/scopes.py` tells a grouped
-matmul by.
+matmul by. The sum of rows that count (ops/row_gather.py) at the three
+expert cells' shapes, and a one-rank expert layer's forward and backward
+pass: its row kernels carry `moe.dispatch` or `moe.combine` and none of the
+(operands, results) a reader of the benchmark tells another kernel by.
 About two seconds each (the grouped kernels five); skipped only where the
 topology cannot be described (tests/tpu_probe.py).
 """
@@ -139,6 +142,118 @@ def test_grouped_matmul_compiles_for_v5e(monkeypatch, shape, product):
     for fn, calls in ((_grouped_fwd, 1), (_grouped_bwd, 2)):
         txt = compile_kernel_text(topo, fn, avals, n_calls=calls)
         assert mosaic_signatures(txt) == [GROUPED_SIGNATURE] * calls
+
+
+#: (entries of `back`, rows of the source, width, k) of a combine: the
+#: tokens' T * k pairs into the row buffer (all the pairs in `olmoe-1chip`,
+#: whose program keeps the `jnp` sum: the kernel compiles there all the same)
+ROW_SUMS = {"smallthinker-1chip": (98304, 49152, 2560, 6),
+            "dsv2lite-1chip": (49152, 12288, 2048, 6),
+            "olmoe-1chip": (65536, 65536, 2048, 8)}
+
+#: (operands, results) of `row_gather._row_form` (the limit, the rows) and of
+#: `row_gather._sum_kernel` (rows, filled, deepest, limit; the row form)
+ROW_SIGNATURES = [(2, 1), (5, 1)]
+
+
+def _readers_signatures():
+    from benchmark.harness import scopes
+    from benchmark.layer_metrics import flash_roofline
+    return set(flash_roofline.SIGNATURES) | {scopes.GROUPED_MATMUL,
+                                             scopes.GROUPED_METADATA}
+
+
+@pytest.mark.parametrize("cell", sorted(ROW_SUMS))
+def test_row_sum_compiles_for_v5e(monkeypatch, cell):
+    """`row_gather._kernel_sum` at a cell's shape: the row form and the sum
+    are one Mosaic kernel each (the 393 KB of a cell's entries fit SMEM, the
+    copy buffer VMEM), no sort is made for them (a sort of 98,304 keys takes
+    the chip's compiler 20 s), and neither kernel has (operands, results) a
+    reader of the benchmark knows a flash kernel or a grouped matmul by."""
+    from horovod_tpu.ops import row_gather
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    entries, n_rows, width, k = ROW_SUMS[cell]
+    avals = (jax.ShapeDtypeStruct((n_rows, width), jnp.bfloat16),
+             jax.ShapeDtypeStruct((entries,), jnp.int32),
+             jax.ShapeDtypeStruct((), jnp.int32))
+    txt = compile_kernel_text(
+        topo, lambda x, back, n: row_gather._kernel_sum(x, back, k, n), avals,
+        n_calls=2)
+    assert mosaic_signatures(txt) == ROW_SIGNATURES
+    assert not set(ROW_SIGNATURES) & _readers_signatures()
+    assert " sort(" not in txt
+    # the result has the tokens' shape: no padded copy is cut to size
+    assert f"bf16[{entries // k},{width}]" in txt.split("ENTRY")[1].split(
+        "ROOT")[1].split("custom-call")[0]
+
+
+def test_an_expert_layers_row_kernels_carry_their_scopes(monkeypatch):
+    """Two one-rank expert layers that hold 2 of 8 experts, a scan of
+    checkpoints as the model's, forward and backward, compiled for the
+    chip: the row kernels run in the combine and in the dispatch's backward
+    pass, every one's `op_name`
+    carries its scope (so `moe_dispatch_ms_per_step` reads them and
+    `step_scopes.partition` books them), and none has a signature by which
+    `flash_roofline`, `mla_flash_roofline` or `scopes.moe_parts` would take
+    it for a flash kernel or a grouped matmul."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from benchmark.harness import hlo, scopes, step_scopes
+    from benchmark.layer_metrics import mla_flash_roofline
+    from horovod_tpu.parallel.moe import moe_ffn
+    from tpu_probe import compile_kernel_text, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    n_tokens, d, f, n_experts, n_local, k = 512, 256, 128, 8, 2, 2
+    mesh = Mesh(np.array(topo.devices[:1]), ("ep",))
+
+    def layer(x, weights):
+        out, aux, _ = moe_ffn(x, weights["router"], weights["up"],
+                              weights["down"], weights["gate"], top_k=k,
+                              first_expert=2)
+        return x + out, aux.sum()
+
+    def loss(x, weights):    # two layers, as the model runs them
+        x, aux = jax.lax.scan(jax.checkpoint(layer, prevent_cse=False), x,
+                              weights)
+        return x.astype(jnp.float32).sum() + aux.sum()
+
+    def step(x, weights):
+        return jax.shard_map(jax.value_and_grad(loss, argnums=(0, 1)),
+                             mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(x, weights)
+
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    avals = (bf16(n_tokens, d),
+             {"router": bf16(2, d, n_experts), "up": bf16(2, n_local, d, f),
+              "gate": bf16(2, n_local, d, f), "down": bf16(2, n_local, f, d)})
+    # 11 grouped matmuls (3 forward, 2 of them again under remat, 6
+    # backward); a row form and a sum in the combine, and in the dispatch's
+    # backward pass
+    txt = compile_kernel_text(topo, step, avals, n_calls=15)
+    table = hlo.index(txt)
+    parts = scopes.moe_parts(txt, table)
+    booked = step_scopes.partition(txt, table)
+    kernels = {name: (i.n_operands, len(i.results))
+               for name, i in table.items() if i.is_mosaic_kernel}
+    rows = {name: sig for name, sig in kernels.items()
+            if parts.get(name) in ("dispatch", "combine")}
+    assert sorted(rows.values()) == sorted(ROW_SIGNATURES * 2)
+    assert {parts[name] for name in rows} == {"dispatch", "combine"}
+    for name in rows:
+        assert booked[name] == "moe." + parts[name]
+    assert not set(rows.values()) & _readers_signatures()
+    assert not set(rows) & set(mla_flash_roofline.flash_kernels(table))
+    assert not set(rows) & set(scopes.grouped_kernels(table))
+    # and the other eleven are what they were: the experts' products
+    assert sorted(sig for name, sig in kernels.items()
+                  if name not in rows) == [scopes.GROUPED_MATMUL] * 11
 
 
 HYBRID_CELL = (1, 30, 8192, 128)   # olmohybrid-1chip's full layer

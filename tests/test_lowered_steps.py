@@ -7,7 +7,12 @@ are reduce-scattered inside the backward loop. A change to
 `models/transformer.py` that is not meant to touch these models' programs
 leaves the text as it is; one that is meant to takes the fixture anew
 (`write_fixture()` below, on the tree whose programs are the new truth) and
-says so.
+says so. PR 43 took the two expert models' anew: the row movers' two
+`custom_vjp`s moved from `parallel/moe.py` to `ops/row_gather.py` (the
+order in which the layer scan's constants are handed to its body changed),
+and where a share of the experts is held (`deepseek_v2`) a take's free rows
+are gathered from zero rows behind the source where a select cleared them;
+the GPT-2 block's text is a31c4fe's still.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""

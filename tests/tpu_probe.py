@@ -67,14 +67,16 @@ def _no_persistent_cache():
 
 
 def compile_kernel_text(topo, fn, avals, n_calls: int = 1) -> str:
-    """AOT-compile `fn` at `avals` (ShapeDtypeStructs WITHOUT sharding —
-    it is pinned to topo's device 0 here) through the real TPU compiler
+    """AOT-compile `fn` at `avals` (ShapeDtypeStructs, or trees of them,
+    WITHOUT sharding — it is pinned to topo's device 0 here) through the
+    real TPU compiler
     and assert the compiled module holds exactly `n_calls` Mosaic custom
     calls (`tpu_custom_call`: the kernel was compiled, not interpreted
     or replaced). Returns the compiled text."""
     sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    shaped = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-              for a in avals]
+    shaped = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        tuple(avals))
     with _no_persistent_cache():
         txt = jax.jit(fn).lower(*shaped).compile().as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == n_calls, txt
