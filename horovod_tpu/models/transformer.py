@@ -331,6 +331,9 @@ GMU_LEAVES = frozenset({"gmu_w1", "gmu_w2"})
 BIAS_LEAVES = frozenset({"bq", "bk", "bv", "bo"})
 DIFF_LEAVES = frozenset({"lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
                          "subln_scale"})
+#: an expert layer's up, down and gate weights, (experts held, ., .) a layer,
+#: in the order `moe.moe_ffn` takes them
+EXPERT_LEAVES = ("we1", "we2", "we_gate")
 #: the mixers that have no attention of their own
 _NO_ATTENTION = ("ssm", "gmu")
 
@@ -1050,7 +1053,7 @@ def _mlp(h, w_gate, w_up, w_down, gate="silu"):
 
 
 def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
-           rope=None, shared=None, depth=0):
+           rope=None, shared=None, depth=0, stacked=None):
     """One transformer block on per-shard activations x: (B, S_loc, D).
     Returns (x, aux): aux is None for a dense MLP, and for experts the
     layer's [load balance, router z] of this shard's tokens, with the count
@@ -1059,7 +1062,8 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     is its scan's output and a differential-attention layer's the keys and
     values it read: what a segment may hand on. `shared` is what an earlier
     segment handed on, {"memory", "kv"}; `depth` the layer's index in the
-    model."""
+    model. `stacked` is (the stacks that `lp`'s expert leaves are a layer
+    of, which layer) where the caller has them: `run_stack`."""
     if cfg.positions != "rope":
         rope = None         # a kind the pattern leaves unrotated
     arrived = x
@@ -1102,15 +1106,18 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     aux = None
     if cfg.num_experts:
         B, S, D = h2.shape
+        stacks, layer = stacked or ({}, 0)
         out, aux, _ = moe_mod.moe_ffn(
-            h2.reshape(B * S, D), lp["router"], lp["we1"], lp["we2"],
-            lp.get("we_gate"), top_k=cfg.experts_per_token, axis_name="ep",
+            h2.reshape(B * S, D), lp["router"],
+            *(lp.get(k) for k in EXPERT_LEAVES),
+            top_k=cfg.experts_per_token, axis_name="ep",
             capacity_factor=cfg.capacity_factor,
             first_expert=cfg.first_expert,
             sequences=B if cfg.balance_per_sequence else 0,
             router_input=arrived.reshape(B * S, D)
             if cfg.router_input == "layer" else None,
-            renormalise=cfg.norm_topk, gate=cfg.gate or "silu")
+            renormalise=cfg.norm_topk, gate=cfg.gate or "silu",
+            stacks=tuple(stacks.get(k) for k in EXPERT_LEAVES), layer=layer)
         f = out.reshape(B, S, D)
         if cfg.shared_experts:
             with jax.named_scope("moe.shared"):
@@ -1235,11 +1242,25 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
             slots = (grad_slots or {}).get((stack,))
 
         remat = partial(_remat, cfg)
+        # The experts' products read a layer's matrices in place in the
+        # stacked leaf (`ops/grouped_matmul.py`): what the scan slices out
+        # of it for the body is then read by nothing, and the compiler
+        # makes no copy of it (three 268 MB leaves a layer and a pass in
+        # `olmoe-1chip`). The body's own slice is the same numbers and
+        # still takes the gradient, so the backward scan stacks it as ever.
+        # The gradient is stopped here and not in the body: a stack the
+        # scan has a tangent for costs its transpose a stack of zeros.
+        stacks = {} if patterned else {
+            k: lax.stop_gradient(stage_params[k]) for k in EXPERT_LEAVES
+            if k in stage_params}
 
         def one_kind(a, xs):
-            lp = _scattered_in_backward(*xs, partial(scatter, (stack,))) \
-                if slots else xs
-            return _layer(a, lp, layer_cfg, rope)
+            lp, slot, layer = xs
+            if slots:
+                lp = _scattered_in_backward(lp, slot,
+                                            partial(scatter, (stack,)))
+            return _layer(a, lp, layer_cfg, rope,
+                          stacked=(stacks, layer) if stacks else None)
 
         def one_period(a, xs):
             """A period's layers in the pattern's order; xs holds each
@@ -1264,9 +1285,15 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
             # expert layers: the period's auxiliary numbers, layer by layer
             return a, jnp.stack(auxes) if layer_cfg.num_experts else None
 
-        act, aux = lax.scan(one_period if patterned else remat(one_kind),
-                            act,
-                            (stage_params, slots) if slots else stage_params)
+        if patterned:
+            act, aux = lax.scan(
+                one_period, act,
+                (stage_params, slots) if slots else stage_params)
+        else:
+            layers = jnp.arange(len(stacks[EXPERT_LEAVES[0]]),
+                                dtype=jnp.int32) if stacks else None
+            act, aux = lax.scan(remat(one_kind), act,
+                                (stage_params, slots, layers))
         if patterned and aux is not None:
             aux = aux.reshape(-1, aux.shape[-1])     # (periods x kinds, ...)
         return act, aux

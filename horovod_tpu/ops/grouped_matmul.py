@@ -38,6 +38,14 @@ group: rows past the sum of `group_sizes` are taken as the last group's
 (`parallel/moe.py` puts its buffer's free rows, which are zero, there
 itself).
 
+Where the weights are one layer of a stack (L, E, K, N), as a layer scan
+keeps them, the two kernels that read weights read that layer in place: the
+stack is seen as its L * E matrices and the layer is added to the visits'
+group numbers (`_at_layer`), so nothing copies the layer out of the stack
+for the custom call, and the kernels' operands stay the seven they were.
+The weights' gradient is one layer's (E, K, N) all the same, and goes to
+the layer's own leaf (`grouped_matmul`).
+
 The contraction (K) is never tiled and the other width only where the
 blocks would not fit `_VMEM_BUDGET`: with whole widths every operand is
 read once and every weight matrix once a group. The benchmark tells these
@@ -343,37 +351,70 @@ def _weights_product(x, dy, plan: Visits, n_groups: int):
 # The product and its backward pass
 # --------------------------------------------------------------------------
 
+def _at_layer(stack, layer, plan: Visits):
+    """(`stack` (L, E, K, N) seen as its L * E matrices, `plan` with every
+    visit's group numbered among them): what the kernels that read weights
+    take for layer `layer`. The layer rides in the group numbers, so the
+    kernels keep the seven operands the benchmark tells them by."""
+    n_groups = stack.shape[1]
+    return (stack.reshape((-1,) + stack.shape[2:]),
+            plan._replace(group=plan.group + layer * n_groups))
+
+
 @jax.custom_vjp
-def _product(rows, weights, plan):
-    return _rows_product(rows, weights, plan, transposed=False)
+def _product(rows, weights, stack, layer, plan):
+    """`rows` by `stack[layer]`, whose numbers `weights` holds too: the
+    products read the stack in place, the gradient goes to `weights`."""
+    del weights
+    return _rows_product(rows, *_at_layer(stack, layer, plan),
+                         transposed=False)
 
 
-def _product_fwd(rows, weights, plan):
-    return _product(rows, weights, plan), (rows, weights, plan)
+def _product_fwd(rows, weights, stack, layer, plan):
+    return _product(rows, weights, stack, layer, plan), \
+        (rows, stack, layer, plan)
 
 
 def _product_bwd(kept, g):
-    rows, weights, plan = kept
-    return (_rows_product(g, weights, plan, transposed=True),
-            _weights_product(rows, g, plan, weights.shape[0]),
-            None)
+    rows, stack, layer, plan = kept
+    return (_rows_product(g, *_at_layer(stack, layer, plan), transposed=True),
+            _weights_product(rows, g, plan, stack.shape[1]),
+            None, None, None)
 
 
 _product.defvjp(_product_fwd, _product_bwd)
 
 
-def grouped_matmul(rows: jax.Array, weights: jax.Array,
-                   group_sizes) -> jax.Array:
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes,
+                   stack: Optional[jax.Array] = None, layer=0) -> jax.Array:
     """`rows` (rows, K) sorted by group times `weights` (E, K, N), the rows
     of group e by `weights[e]`: (rows, N) in the rows' dtype, accumulated
     in float32. `group_sizes` is the (E,) int32 rows of each group, or the
     `visits` made of them where several products share the groups; rows
     past their sum count as the last group's. Differentiable in `rows` and
-    `weights`; both have one dtype."""
+    `weights`; both have one dtype.
+
+    Where `weights` is one layer of a stack, `stack` (L, E, K, N) and
+    `layer` (an int32, traced or not) say which: the kernels then read
+    `stack[layer]` in place, and nothing has to copy the layer out of the
+    stack for them (a layer scan's `xs[l]` in front of a custom call is a
+    copy). The caller's contract: `stack[layer]` holds the numbers of
+    `weights`. The gradient goes to `weights` all the same, so a scan still
+    stacks it layer by layer, and `stack` gets none: hand it over under
+    `lax.stop_gradient`, taken outside the scan, or the scan's transpose
+    carries a stack of zeros for it. Without `stack` the weights are the
+    stack of depth one at layer 0 of the same code."""
     if rows.dtype != weights.dtype:
         raise ValueError(
             f"rows are {rows.dtype} and weights {weights.dtype}: a grouped "
             "matmul multiplies operands of one dtype")
+    if stack is None:
+        stack, layer = lax.stop_gradient(weights)[None], 0
+    elif stack.shape[1:] != weights.shape or stack.dtype != weights.dtype:
+        raise ValueError(
+            f"a stack of {stack.dtype}{list(stack.shape)} holds no layer of "
+            f"{weights.dtype}{list(weights.shape)}")
     if not isinstance(group_sizes, Visits):
         group_sizes = visits(group_sizes, rows.shape[0])
-    return _product(rows, weights, group_sizes)
+    return _product(rows, weights, stack, jnp.asarray(layer, jnp.int32),
+                    group_sizes)

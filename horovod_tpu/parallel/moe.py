@@ -173,26 +173,35 @@ GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def _experts(rows, group_sizes, w_up, w_down, w_gate, row_weights=None,
-             gate="silu"):
+             gate="silu", stacks=(None, None, None), layer=0):
     """The experts on rows sorted by expert: three grouped matmuls (two for
     an ungated expert), the gate's product through `GATES[gate]`.
     `row_weights` (rows, 1) float32, where given, scale
     each row's hidden activations, in float32 from the products' results to
     the one rounding the down product's input has either way. Every row
     lies in a group. The kernels' tile visits are made here once, for the
-    products and their backward passes alike."""
+    products and their backward passes alike. `stacks`, where given, are the
+    stacks that `w_up`, `w_down` and `w_gate` are layer `layer` of: the
+    products read them there (`grouped_matmul`)."""
     with jax.named_scope("moe.experts"):
         wide = rows.dtype if row_weights is None else jnp.float32
         plan = visits(group_sizes, rows.shape[0])
-        hidden = grouped_matmul(rows, w_up, plan).astype(wide)
+
+        def product(x, w, stack):
+            # without a stack, the call as it is made anywhere else
+            return grouped_matmul(x, w, plan) if stack is None else \
+                grouped_matmul(x, w, plan, stack, layer)
+
+        up, down, gates = stacks
+        hidden = product(rows, w_up, up).astype(wide)
         if w_gate is None:
             hidden = jax.nn.gelu(hidden)
         else:
-            gated = grouped_matmul(rows, w_gate, plan).astype(wide)
-            hidden = GATES[gate](gated) * hidden
+            hidden = GATES[gate](product(rows, w_gate, gates).astype(wide)) \
+                * hidden
         if row_weights is not None:
             hidden = (hidden * row_weights).astype(rows.dtype)
-        return grouped_matmul(hidden, w_down, plan)
+        return product(hidden, w_down, down)
 
 
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
@@ -200,7 +209,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             top_k: int = 1, axis_name: str = "ep",
             capacity_factor: float = 1.25, first_expert: int = 0,
             sequences: int = 0, router_input: Optional[jax.Array] = None,
-            renormalise: bool = False, gate: str = "silu"
+            renormalise: bool = False, gate: str = "silu",
+            stacks=(None, None, None), layer=0
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k mixture-of-experts feed-forward on one shard's tokens.
 
@@ -215,6 +225,11 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
       router_input: (T, D), what the router scores where that is not x
       renormalise: a token's k weights divided by their sum
       gate: what a gated expert applies to its gate's product (`GATES`)
+      stacks, layer: the (L, E_local, ...) stacks that w_up, w_down and
+        w_gate are layer `layer` of (an int32, traced in a layer scan),
+        where the caller has them: the experts' products then read the
+        stacks in place, under the contract of `ops/grouped_matmul.py`
+        `grouped_matmul` (the same numbers; no gradient to the stacks)
     Returns ((T, D), [load balance, router z] of these tokens, the (T, k)
     experts of each token in the order of their weights). Where one rank
     holds a share (E_local < E) the second has a third number: the held
@@ -300,7 +315,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             sizes = jnp.full((n_local,), ranks * cap, jnp.int32)
             row_weights = None
 
-    ys = _experts(rows, sizes, w_up, w_down, w_gate, row_weights, gate)
+    ys = _experts(rows, sizes, w_up, w_down, w_gate, row_weights, gate,
+                  stacks, layer)
 
     with jax.named_scope("moe.combine"):
         if ranks == 1:

@@ -21,7 +21,9 @@ operands and the result `benchmark/harness/scopes.py` tells a grouped
 matmul by. The sum of rows that count (ops/row_gather.py) at the three
 expert cells' shapes, and a one-rank expert layer's forward and backward
 pass: its row kernels carry `moe.dispatch` or `moe.combine` and none of the
-(operands, results) a reader of the benchmark tells another kernel by.
+(operands, results) a reader of the benchmark tells another kernel by. The
+model's own two scanned expert layers: the compiled step slices no expert
+leaf out of its stack for the grouped matmuls (PR 44).
 About two seconds each (the grouped kernels five); skipped only where the
 topology cannot be described (tests/tpu_probe.py).
 """
@@ -254,6 +256,83 @@ def test_an_expert_layers_row_kernels_carry_their_scopes(monkeypatch):
     # and the other eleven are what they were: the experts' products
     assert sorted(sig for name, sig in kernels.items()
                   if name not in rows) == [scopes.GROUPED_MATMUL] * 11
+
+
+def _stack_traffic(txt, leaf_shapes, depth):
+    """What a compiled step moves of the expert leaves around its layer
+    scan: how many instructions, fused or not, slice an array of a leaf's
+    shape out of a stack, add two arrays of a stack's shape, or fill one
+    (the static count of docs/observability.md)."""
+    import re
+
+    def shaped(shapes):
+        return "|".join(re.escape(",".join(map(str, s))) for s in shapes)
+
+    leaf = shaped(leaf_shapes) + "|" + shaped((1,) + s for s in leaf_shapes)
+    stack = shaped((depth,) + s for s in leaf_shapes)
+
+    def count(shapes, opcodes):
+        return len(re.findall(
+            rf"= bf16\[(?:{shapes})\]\S* (?:{opcodes})\(", txt))
+
+    return {"slices": count(leaf, "dynamic-slice"),
+            "adds": count(stack, "add"),
+            "fills": count(stack, "broadcast")}
+
+
+def test_the_layer_scan_copies_no_expert_leaf_out_of_its_stack(monkeypatch):
+    """The model's own loss and gradients over two scanned, checkpointed
+    expert layers, compiled for the chip: the grouped matmuls read each
+    layer's matrices in place in the stacked leaves, so no instruction
+    slices an array of a leaf's shape out of a stack (the scan's `xs[l]` in
+    front of a custom call was a copy: three leaves, the forward and the
+    backward loop), nothing of a stack's shape is added, and nothing of it
+    is filled but the three stacked gradients, as ever; the kernels are the
+    eleven they were, each (7, 1) under `moe.experts`. With the stacks
+    withheld from the products the same count finds the six copies."""
+    from benchmark.harness import hlo, scopes
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.parallel import MeshSpec, build_mesh, moe
+    from tpu_probe import compile_kernel_text, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    depth, d, f, n_experts = 2, 256, 128, 8
+    cfg = tfm.TransformerConfig(
+        vocab=512, d_model=d, n_heads=2, d_ff=f, n_layers=depth, max_seq=512,
+        num_experts=n_experts, experts_per_token=2, load_balance_coef=0.01,
+        router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
+        mlp="swiglu", attn="local", dtype=jnp.bfloat16, remat=True)
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    avals = (jax.eval_shape(lambda k: tfm.init(k, cfg),
+                            jax.random.PRNGKey(0)), tokens, tokens)
+
+    def compiled_text():
+        # 11 grouped matmuls: 3 forward, gate and up again under remat, 6
+        # backward
+        with jax.enable_x64(False):   # as the benchmark runs
+            return compile_kernel_text(
+                topo, tfm.build_loss_and_grads(cfg, mesh), avals, n_calls=11)
+
+    leaves = [(n_experts, d, f), (n_experts, f, d)]
+    txt = compiled_text()
+    assert _stack_traffic(txt, leaves, depth) == {
+        "slices": 0, "adds": 0, "fills": 3}
+    table = hlo.index(txt)
+    parts = scopes.moe_parts(txt, table)
+    kernels = {name: (i.n_operands, len(i.results))
+               for name, i in table.items() if i.is_mosaic_kernel}
+    assert sorted(kernels.values()) == [scopes.GROUPED_MATMUL] * 11
+    assert {parts.get(name) for name in kernels} == {"experts"}
+    assert all(name.startswith("moe.experts") for name in kernels)
+
+    grouped_matmul = moe.grouped_matmul
+    monkeypatch.setattr(
+        moe, "grouped_matmul",
+        lambda rows, weights, plan, stack=None, layer=0:
+        grouped_matmul(rows, weights, plan))
+    assert _stack_traffic(compiled_text(), leaves, depth) == {
+        "slices": 6, "adds": 0, "fills": 3}
 
 
 HYBRID_CELL = (1, 30, 8192, 128)   # olmohybrid-1chip's full layer

@@ -12,7 +12,11 @@ says so. PR 43 took the two expert models' anew: the row movers' two
 order in which the layer scan's constants are handed to its body changed),
 and where a share of the experts is held (`deepseek_v2`) a take's free rows
 are gathered from zero rows behind the source where a select cleared them;
-the GPT-2 block's text is a31c4fe's still.
+PR 44 took them anew again: the layer scan hands the experts' products
+their stacked leaves and the layer's number (`ops/grouped_matmul.py`), so the
+scan has the stacks as constants and the layers' numbers among its `xs`, and
+each product adds `layer * E` to its visits' groups; the GPT-2 block's text
+is a31c4fe's still.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
