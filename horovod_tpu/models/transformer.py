@@ -14,51 +14,34 @@ framework supports, in one compiled XLA program per train step:
   ep    — MoE FFN experts sharded; all_to_all token dispatch
           (parallel/moe.py).
 
-One block, whose architecture `TransformerConfig` states: the defaults are
-the GPT-2 block (LayerNorm, learned positions, GELU MLP); `norm="rmsnorm"`,
-`positions="rope"`, `qk_norm=True`, `mlp="swiglu"` and top-k experts with
-their auxiliary losses make it OLMoE's (arXiv:2409.02060);
-`attention="mla"` with its four widths, `yarn`, `shared_experts`, a share of
-the routed experts (`experts_held`, `first_expert`), a per-sequence balance
-loss and `first_k_dense` leading dense layers make it DeepSeek-V2's
-(arXiv:2405.04434). The FFN of a layer is dense (GELU with biases, or gated
-SiLU without) where the layer has no experts: every layer when
-`num_experts == 0`, the first `first_k_dense` otherwise, which are a stack
-of their own (`params["dense_layers"]`) in front of the expert stack
-(`params["layers"]`). No biases except the GPT-2 block's LayerNorm and MLP
-ones.
+One block, whose architecture `TransformerConfig` states, in parts: a
+layer is norm, mixer, residual, norm, FFN, residual (`_layer`). The mixers
+lie in `models/mixers.py` (`MIXERS`: plain attention with its biases,
+QK-norm, fewer key heads and the differential form; cross, latent (MLA),
+gated-delta-rule, state-space and Gated-Memory-Unit mixers), the FFNs in
+`models/ffns.py` (`FFNS`: dense GELU, dense gated, routed experts with their
+router and shared experts), each with the leaves it has, its `apply` and
+what it refuses; a leaf is declared once (`models/leaves.py`), and `init`,
+`param_specs` and `grad_reduce_axes` are maps over the declarations. This
+file holds the configuration, the stacks, the step and its gradient
+reduction's placement (the collectives: `parallel/grad_reduce.py`).
+
+The defaults are the GPT-2 block; the other fields make it OLMoE's
+(arXiv:2409.02060), DeepSeek-V2's (arXiv:2405.04434: its `first_k_dense`
+leading dense layers are a stack of their own, `params["dense_layers"]`, in
+front of `params["layers"]`), Olmo-Hybrid's, SmallThinker's
+(arXiv:2507.20984) and SambaY's (arXiv:2507.06607).
 
 A model whose layers are not all of one kind states one period of its
-`layer_pattern`, which the stack repeats: "full" layers mix tokens as
-`attention` says, "linear" layers through a gated delta rule
-(`attention="gdn"`: Gated DeltaNet, arXiv:2412.06464, by
-`ops/gated_delta.py`). With `post_norm` (each sub-layer's norm on its output,
-inside the residual) and `positions="none"` that is Olmo-Hybrid's block. The
-parameters of a patterned stack lie per kind, `params["layers"][kind][leaf]`,
-stacked over (periods, the layers of that kind in a period), and one scan
-body runs a period's layers in order.
-
-A model whose layers hand results on to later layers states `segments`: a
-sequence of (pattern, periods), each a scan of its own over its periods
-(`params["segments"][i][kind][leaf]`). Beside "full" and "linear" a pattern
-may name "window" (attention over the last `window` keys), "ssm" (a Mamba-1
-selective state-space mixer, arXiv:2312.00752, by `ops/selective_scan.py`),
-"gmu" (a Gated Memory Unit: a gate on the memory the last "ssm" layer of the
-segment with the "full" layer handed on) and "cross" (attention whose keys
-and values are that "full" layer's). With `diff_attention` (the difference
-of two softmaxes over paired heads, arXiv:2410.05258), `n_kv_heads` fewer
-key and value heads than query heads, `attention_bias` and `tied_head` that
-is SambaY's decoder-hybrid-decoder (arXiv:2507.06607), Phi-4-mini-flash's.
-
-A `layer_pattern` may name "window" layers too, and its layers may be expert
-layers (their auxiliary numbers are gathered period by period). With `d_head`
-(a head width that is not d_model / n_heads), `unrotated` (kinds of the
-pattern that take no rotary embedding: NoPE), `router_input="layer"` (the
-router scores the layer's input, before its first norm and attention, while
-the experts read the normed post-attention state), `mlp="reglu"` (ReLU-gated)
-and `norm_topk` (a token's k expert weights renormalised) that is
-SmallThinker's block (arXiv:2507.20984): one NoPE full-attention layer to
-three rotating windowed ones.
+`layer_pattern`, which the stack repeats (`LAYER_KINDS`); its parameters lie
+per kind, `params["layers"][kind][leaf]`, stacked over (periods, the layers
+of that kind in a period). A model whose layers hand results on to later
+layers states `segments`, a sequence of (pattern, periods)
+(`params["segments"][i][kind][leaf]`): the segment with the "full" layer
+hands its last "ssm" layer's scan output on to the "gmu" layers behind it
+and the "full" layer's keys and values to the "cross" layers. A pattern is
+one segment: one runner (`_run_segments`) scans both, a period's layers in
+order in its body.
 
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
@@ -79,12 +62,15 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.common.exceptions import HorovodTpuError
-from horovod_tpu.parallel import moe as moe_mod
+from horovod_tpu.models import ffns
+from horovod_tpu.models.ffns import FFNS
+from horovod_tpu.models.leaves import (
+    Leaf, fan_in, key_streams, normal, ones, zeros)
+from horovod_tpu.models.mixers import MIXERS, rms, rope_angles
 from horovod_tpu.parallel import pipeline as pp_mod
-from horovod_tpu.parallel import ulysses as ulysses_mod
-from horovod_tpu.parallel.ring_attention import (
-    blockwise_attention_reference, ring_attention)
-from horovod_tpu.parallel.mesh import AXIS_ORDER, mesh_axis_sizes
+from horovod_tpu.parallel.grad_reduce import (
+    psum_axes, scatter_plan, scatter_sum, scattered_in_backward)
+from horovod_tpu.parallel.mesh import mesh_axis_sizes
 
 #: The `jax.named_scope`s of the train step outside its mixers' (`moe.*` of
 #: `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`
@@ -214,7 +200,7 @@ class TransformerConfig:
     # RMSNorm on the projected queries and keys, over the whole projected
     # vector (all heads), before it is split into heads and rotated
     qk_norm: bool = False
-    # attention="gdn": no attention but a gated delta rule (`_gdn`):
+    # attention="gdn": no attention but a gated delta rule (`mixers.py`):
     # gdn_heads heads with keys and queries gdn_key_dim wide and values
     # gdn_value_dim, a depthwise causal convolution of gdn_conv taps on
     # each; gdn_neg_eigval lets beta reach 2, so that a state's eigenvalue
@@ -249,7 +235,7 @@ class TransformerConfig:
     attention_bias: bool = False
     # differential attention: heads pair up as (2i, 2i + 1); a pair's
     # output is (1 - l0) RMSNorm(softmax(q1 k1^T) vv - lam softmax(q2 k2^T)
-    # vv), vv the pair's two value heads side by side (`_diff_attention`)
+    # vv), vv the pair's two value heads side by side (`mixers.py`)
     diff_attention: bool = False
     # an "ssm" layer: ssm_expand * d_model channels, each with ssm_state
     # states, a depthwise causal convolution of ssm_conv taps (with bias),
@@ -318,25 +304,6 @@ class TransformerConfig:
 #: the stacks of layers a parameter tree may hold, in the order they run
 STACKS = ("dense_layers", "layers")
 
-#: the leaves only a gated-delta-rule layer has
-GDN_LEAVES = frozenset({
-    "gdn_wq", "gdn_wk", "gdn_wv", "gdn_wz", "gdn_wa", "gdn_wb", "gdn_a_log",
-    "gdn_dt_bias", "gdn_conv_q", "gdn_conv_k", "gdn_conv_v", "gdn_o_scale"})
-#: ... only a state-space layer, a Gated Memory Unit
-SSM_LEAVES = frozenset({
-    "ssm_w_in", "ssm_conv", "ssm_conv_bias", "ssm_w_x", "ssm_w_dt",
-    "ssm_dt_bias", "ssm_a_log", "ssm_d_skip", "ssm_w_out"})
-GMU_LEAVES = frozenset({"gmu_w1", "gmu_w2"})
-#: ... only attention with biases, differential attention
-BIAS_LEAVES = frozenset({"bq", "bk", "bv", "bo"})
-DIFF_LEAVES = frozenset({"lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
-                         "subln_scale"})
-#: an expert layer's up, down and gate weights, (experts held, ., .) a layer,
-#: in the order `moe.moe_ffn` takes them
-EXPERT_LEAVES = ("we1", "we2", "we_gate")
-#: the mixers that have no attention of their own
-_NO_ATTENTION = ("ssm", "gmu")
-
 
 def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
     """`cfg` as the layers of `stack` see it: the leading dense layers are
@@ -355,6 +322,10 @@ LAYER_KINDS = {"full": {"window": 0},
                "ssm": {"attention": "ssm"},
                "gmu": {"attention": "gmu"},
                "cross": {"attention": "cross", "window": 0}}
+#: what a layer of a kind hands on, where its segment does (`_hands_on`), and
+#: what a layer of a kind reads of what was handed on
+_HANDS_ON = {"ssm": "memory", "full": "kv"}
+_READS = {"gmu": "memory", "cross": "kv"}
 
 
 def _reads_are_handed_on(cfg: TransformerConfig) -> bool:
@@ -362,11 +333,10 @@ def _reads_are_handed_on(cfg: TransformerConfig) -> bool:
     layer keys and values: a segment before theirs hands them on."""
     handed = set()
     for at, (pattern, _) in enumerate(cfg.segments):
-        if ("gmu" in pattern and "memory" not in handed) or \
-                ("cross" in pattern and "kv" not in handed):
+        if not {_READS[k] for k in pattern if k in _READS} <= handed:
             return False
-        if _hands_on(cfg, at):
-            handed = {"kv"} | ({"memory"} if "ssm" in pattern else set())
+        if _hands_on(cfg.segments, at):
+            handed = {_HANDS_ON[k] for k in pattern if k in _HANDS_ON}
     return True
 
 
@@ -382,18 +352,17 @@ def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
     return dataclasses.replace(cfg, **changes)
 
 
-def _kinds(cfg: TransformerConfig, pattern=None) -> Dict[str, int]:
-    """The kinds of `cfg`'s layer pattern (or of `pattern`), each with how
-    many layers of a period are of it."""
-    pattern = cfg.layer_pattern if pattern is None else pattern
+def _kinds(pattern) -> Dict[str, int]:
+    """The kinds of a pattern, each with how many layers of a period are of
+    it."""
     return {kind: pattern.count(kind) for kind in sorted(set(pattern))}
 
 
-def _hands_on(cfg: TransformerConfig, index: int) -> bool:
+def _hands_on(segments, index: int) -> bool:
     """Whether segment `index` hands a memory and keys and values on: it has
     the "full" layer, and a later segment reads them."""
-    return "full" in cfg.segments[index][0] and any(
-        kind in ("gmu", "cross") for pattern, _ in cfg.segments[index + 1:]
+    return "full" in segments[index][0] and any(
+        kind in _READS for pattern, _ in segments[index + 1:]
         for kind in pattern)
 
 
@@ -426,306 +395,85 @@ def _at(tree, path):
     return tree
 
 
-def _present(tree: Dict[str, Any], cfg: TransformerConfig):
-    """`tree` (parameters, specs or reduce axes, laid out as `init` lays the
-    parameters out, with every leaf any architecture has) without the leaves
-    `cfg`'s architecture does not have."""
-    absent = set()
-    if cfg.norm == "rmsnorm":
-        absent |= {"ln1_bias", "ln2_bias", "lnf_bias"}
-    if cfg.positions != "learned":
-        absent.add("pos")
-    if cfg.attention == "mla":
-        absent |= {"wk", "wv", "q_scale", "k_scale"}
-    else:
-        absent |= {"wkv_a", "kv_scale", "wkv_b"}
-    if cfg.attention == "gdn":
-        absent |= {"wq", "wk", "wv", "q_scale", "k_scale"}
-    else:
-        absent |= GDN_LEAVES
-    if cfg.attention != "ssm":
-        absent |= SSM_LEAVES
-    if cfg.attention != "gmu":
-        absent |= GMU_LEAVES
-    if cfg.attention in _NO_ATTENTION:
-        absent |= {"wq", "wk", "wv", "wo", "q_scale", "k_scale"}
-    if cfg.attention == "cross":
-        absent |= {"wk", "wv", "bk", "bv", "k_scale"}
-    if not cfg.attention_bias or cfg.attention in _NO_ATTENTION + ("gdn",):
-        absent |= BIAS_LEAVES
-    if not cfg.diff_attention or cfg.attention in _NO_ATTENTION + ("gdn",):
-        absent |= DIFF_LEAVES
-    if cfg.tied_head:
-        absent.add("unembed")
-    absent.add("layers" if cfg.segments else "segments")
-    if not cfg.qk_norm:
-        absent |= {"q_scale", "k_scale"}
-    if cfg.num_experts:
-        absent |= {"w1", "b1", "w2", "b2", "w_gate"}
-    else:
-        absent |= {"router", "we1", "we2", "we_gate"}
-    if not (cfg.num_experts and cfg.shared_experts):
-        absent |= {"ws1", "ws2", "ws_gate"}
-    if cfg.gate:
-        absent |= {"b1", "b2"}
-    else:
-        absent |= {"we_gate", "w_gate", "ws_gate"}
-    if not cfg.first_k_dense:
-        absent.add("dense_layers")
-    def of_stack(stack, leaves):
-        stack_cfg = _stack_cfg(cfg, stack)
-        if stack == "layers" and cfg.layer_pattern:
-            return {kind: _present(leaves[kind], _kind_cfg(stack_cfg, kind))
-                    for kind in _kinds(cfg)}
-        return _present(leaves, stack_cfg)
-
-    def of_segments(segments):
-        return [{kind: _present(leaves[kind], _kind_cfg(cfg, kind))
-                 for kind in _kinds(cfg, pattern)}
-                for (pattern, _), leaves in zip(cfg.segments, segments)]
-
-    return {k: of_stack(k, v) if k in STACKS
-            else of_segments(v) if k == "segments" else v
-            for k, v in tree.items() if k not in absent}
-
-
 def _stack_depth(cfg: TransformerConfig) -> int:
     return cfg.n_layers - cfg.first_k_dense
 
 
-def _layer_makers(key: jax.Array, cfg: TransformerConfig,
-                  lead: Optional[Tuple[int, ...]] = None) -> Dict[str, Any]:
-    """One stack's layers: every leaf a layer of any architecture has, as a
-    function that makes it, stacked on the leading axes `lead` (one, the
-    stack's depth, where none are given)."""
-    D, H, F, E = cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.num_experts
-    L = (_stack_depth(cfg),) if lead is None else tuple(lead)
-    dt = cfg.dtype
-    G = H
-    if cfg.attention == "mla":
-        dq, dvo = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
-    elif cfg.attention == "gdn":
-        G = H = cfg.gdn_heads
-        dq, dvo = cfg.gdn_key_dim, cfg.gdn_value_dim
+def _norm_leaves(cfg: TransformerConfig, name: str) -> Dict[str, Leaf]:
+    """A norm's scale, and its bias where the norm is a LayerNorm."""
+    leaves = {name + "_scale": Leaf((cfg.d_model,), ones)}
+    if cfg.norm != "rmsnorm":
+        leaves[name + "_bias"] = Leaf((cfg.d_model,), zeros)
+    return leaves
+
+
+def _layer_parts(cfg: TransformerConfig) -> Tuple[Dict[str, Leaf], ...]:
+    """The leaves of one layer as `cfg` states it, part by part: its two
+    norms', its mixer's, its FFN's."""
+    return (_norm_leaves(cfg, "ln1"), MIXERS[cfg.attention].leaves(cfg),
+            _norm_leaves(cfg, "ln2"), FFNS[ffns.kind(cfg)].leaves(cfg))
+
+
+def _declared(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The parameter tree as its parts declare it, a `Leaf` at each leaf.
+    The leading dense layers lie on every pipeline stage
+    (`validate_cfg_for_mesh` refuses pp > 1 with them); of a patterned stack
+    the periods would lie over the stages."""
+    D, V = cfg.d_model, cfg.vocab
+
+    def layers(layer_cfg, *stack):
+        return {name: dataclasses.replace(leaf, stack=stack)
+                for part in _layer_parts(layer_cfg)
+                for name, leaf in part.items()}
+
+    def per_kind(pattern, periods, axes, fold):
+        # each kind's layers, stacked over (periods, its layers in a period)
+        return {kind: layers(_kind_cfg(cfg, kind), (periods, n), axes,
+                             fold + i)
+                for i, (kind, n) in enumerate(_kinds(pattern).items())}
+
+    # a tied embedding is drawn as a head is: logits of unit deviation
+    tree = {"embed": Leaf((V, D), normal(
+        "k", 7, 0.02 if cfg.tied_head else 0.02 * D ** 0.5)),
+        **_norm_leaves(cfg, "lnf")}
+    if cfg.positions == "learned":
+        tree["pos"] = Leaf((cfg.max_seq, D), normal("k", 8, 0.02))
+    if not cfg.tied_head:
+        tree["unembed"] = Leaf((D, V), fan_in("k", 9, D))
+    if cfg.first_k_dense:
+        tree["dense_layers"] = layers(_stack_cfg(cfg, "dense_layers"),
+                                      (cfg.first_k_dense,), (None,), 2)
+    if cfg.segments:
+        tree["segments"] = [
+            per_kind(pattern, periods, (None, None), 100 + 10 * at)
+            for at, (pattern, periods) in enumerate(cfg.segments)]
+    elif cfg.layer_pattern:
+        tree["layers"] = per_kind(cfg.layer_pattern, _periods(cfg),
+                                  ("pp", None), 4)
     else:
-        dq = dvo = cfg.head_dim
-        G = cfg.kv_heads
-    Es, N, R = cfg.ssm_channels, cfg.ssm_state, cfg.dt_rank
-    ms = jax.random.split(jax.random.fold_in(key, 5), 16)
-    held = cfg.experts_held or E
-    shared = cfg.shared_experts * F
-    ks = jax.random.split(key, 12)
-    xs = jax.random.split(jax.random.fold_in(key, 1), 6)
-    gs = jax.random.split(jax.random.fold_in(key, 3), 11)
-    Hg, dk, dv, taps = (cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
-                        cfg.gdn_conv)
-
-    def norm(k, shape, fan_in):
-        return lambda: jax.random.normal(k, L + shape, dt) * fan_in ** -0.5
-
-    def ones(*shape):
-        return lambda: jnp.ones(L + shape, dt)
-
-    def zeros(*shape):
-        return lambda: jnp.zeros(L + shape, dt)
-
-    def decay_rate():
-        # A ~ U(0, 16), held as its logarithm (Gated DeltaNet's own draw)
-        return jnp.log(jax.random.uniform(
-            gs[9], L + (Hg,), jnp.float32, 1e-3, 16.0)).astype(dt)
-
-    def step_bias():
-        # dt ~ log-U(0.001, 0.1), held as softplus^-1(dt)
-        step = jnp.exp(jax.random.uniform(
-            gs[10], L + (Hg,), jnp.float32, math.log(1e-3), math.log(0.1)))
-        return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
-
-    def small(k, *shape, deviation=0.02):
-        return lambda: jax.random.normal(k, L + shape, dt) * deviation
-
-    def ssm_rates():
-        # A = -(1 .. N) per channel, held as its logarithm (Mamba's own)
-        return jnp.broadcast_to(jnp.log(jnp.arange(
-            1, N + 1, dtype=jnp.float32)), L + (Es, N)).astype(dt)
-
-    def ssm_step_bias():
-        # dt ~ log-U(0.001, 0.1), held as softplus^-1(dt)
-        step = jnp.exp(jax.random.uniform(
-            ms[6], L + (Es,), jnp.float32, math.log(1e-3), math.log(0.1)))
-        return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
-
-    return {
-        "ln1_scale": ones(D), "ln1_bias": zeros(D),
-        "wq": norm(ks[0], (D, H, dq), D),
-        "wk": norm(ks[1], (D, G, dq), D),
-        "wv": norm(ks[2], (D, G, dvo), D),
-        "bq": small(ms[7], H, dq), "bk": small(ms[8], G, dq),
-        "bv": small(ms[9], G, dvo), "bo": small(ms[10], D),
-        "lambda_q1": small(ms[11], dq, deviation=0.1),
-        "lambda_k1": small(ms[12], dq, deviation=0.1),
-        "lambda_q2": small(ms[13], dq, deviation=0.1),
-        "lambda_k2": small(ms[14], dq, deviation=0.1),
-        "subln_scale": ones(2 * dvo),
-        "ssm_w_in": norm(ms[0], (D, 2 * Es), D),
-        "ssm_conv": norm(ms[1], (Es, cfg.ssm_conv), cfg.ssm_conv),
-        "ssm_conv_bias": small(ms[2], Es),
-        "ssm_w_x": norm(ms[3], (Es, R + 2 * N), Es),
-        "ssm_w_dt": norm(ms[4], (R, Es), R),
-        "ssm_dt_bias": ssm_step_bias, "ssm_a_log": ssm_rates,
-        "ssm_d_skip": ones(Es),
-        "ssm_w_out": norm(ms[5], (Es, D), Es),
-        "gmu_w1": norm(ms[0], (D, Es), D),
-        "gmu_w2": norm(ms[5], (Es, D), Es),
-        "wkv_a": norm(xs[0], (D, cfg.kv_latent + cfg.qk_rope_dim), D),
-        "kv_scale": ones(cfg.kv_latent),
-        "wkv_b": norm(xs[1], (cfg.kv_latent, H, cfg.qk_nope_dim + dvo),
-                      cfg.kv_latent),
-        "gdn_wq": norm(gs[0], (D, Hg, dk), D),
-        "gdn_wk": norm(gs[1], (D, Hg, dk), D),
-        "gdn_wv": norm(gs[2], (D, Hg, dv), D),
-        "gdn_wz": norm(gs[3], (D, Hg, dv), D),
-        "gdn_wa": norm(gs[4], (D, Hg), D),
-        "gdn_wb": norm(gs[5], (D, Hg), D),
-        "gdn_a_log": decay_rate, "gdn_dt_bias": step_bias,
-        "gdn_conv_q": norm(gs[6], (Hg, dk, taps), taps),
-        "gdn_conv_k": norm(gs[7], (Hg, dk, taps), taps),
-        "gdn_conv_v": norm(gs[8], (Hg, dv, taps), taps),
-        "gdn_o_scale": ones(dv),
-        "wo": norm(ks[3], (H, dvo, D), H * dvo),
-        "q_scale": ones(H, dq), "k_scale": ones(H, dq),
-        "ln2_scale": ones(D), "ln2_bias": zeros(D),
-        "router": norm(ks[4], (D, E), D),
-        "we1": norm(ks[5], (held, D, F), D),
-        "we2": norm(ks[6], (held, F, D), F),
-        "we_gate": norm(ks[10], (held, D, F), D),
-        "ws1": norm(xs[3], (D, shared), D),
-        "ws2": norm(xs[4], (shared, D), shared),
-        "ws_gate": norm(xs[5], (D, shared), D),
-        "w1": norm(ks[4], (D, F), D), "b1": zeros(F),
-        "w2": norm(ks[5], (F, D), F), "b2": zeros(D),
-        "w_gate": norm(xs[2], (D, F), D),
-    }
+        tree["layers"] = layers(cfg, (_stack_depth(cfg),), ("pp",), None)
+    return tree
 
 
 def init(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Global (unsharded) parameter pytree."""
-    D, V = cfg.d_model, cfg.vocab
-    dt = cfg.dtype
-    ks = jax.random.split(key, 12)
+    streams = {}    # a stack's, made once
 
-    def norm(k, shape):
-        return jax.random.normal(k, shape, dt)
+    def draw(leaf):
+        lead, _, fold = leaf.stack or ((), (), None)
+        if fold not in streams:
+            streams[fold] = key_streams(
+                key if fold is None else jax.random.fold_in(key, fold))
+        return leaf.draw(streams[fold], lead + leaf.shape, cfg.dtype)
 
-    def layers():
-        if not cfg.layer_pattern:
-            return _layer_makers(key, cfg)
-        # each kind's layers, stacked over (periods, its layers in a period)
-        return {kind: _layer_makers(jax.random.fold_in(key, 4 + i),
-                                    _kind_cfg(cfg, kind), (_periods(cfg), n))
-                for i, (kind, n) in enumerate(_kinds(cfg).items())}
-
-    def segments():
-        # per segment, each kind's layers over (periods, its layers there)
-        return [{kind: _layer_makers(
-            jax.random.fold_in(key, 100 + 10 * at + i), _kind_cfg(cfg, kind),
-            (periods, n)) for i, (kind, n) in enumerate(
-                _kinds(cfg, pattern).items())}
-            for at, (pattern, periods) in enumerate(cfg.segments)]
-
-    # a tied embedding is drawn as a head is: logits of unit deviation
-    embed_deviation = 0.02 if cfg.tied_head else 0.02 * D ** 0.5
-    # every leaf any architecture has, each made only if this one has it
-    make = {
-        "embed": lambda: norm(ks[7], (V, D)) * embed_deviation,
-        "pos": lambda: norm(ks[8], (cfg.max_seq, D)) * 0.02,
-        "dense_layers": _layer_makers(jax.random.fold_in(key, 2),
-                                      _stack_cfg(cfg, "dense_layers")),
-        "layers": layers(),
-        "segments": segments(),
-        "lnf_scale": lambda: jnp.ones((D,), dt),
-        "lnf_bias": lambda: jnp.zeros((D,), dt),
-        "unembed": lambda: norm(ks[9], (D, V)) * D ** -0.5,
-    }
-    return jax.tree_util.tree_map(lambda f: f(), _present(make, cfg))
-
-
-def _layer_specs(*lead: Optional[str]) -> Dict[str, Any]:
-    """PartitionSpecs of one stack's leaves, its leading (layer) axes over
-    the mesh axes `lead`."""
-    def spec(*rest):
-        return P(*lead, *rest)
-
-    return {
-        "ln1_scale": spec(None), "ln1_bias": spec(None),
-        "wq": spec(None, "tp", None),
-        "wk": spec(None, "tp", None),
-        "wv": spec(None, "tp", None),
-        # the latent's down-projection and norm belong to no head: they are
-        # replicated over tp as the router is
-        "wkv_a": spec(None, None), "kv_scale": spec(None),
-        "wkv_b": spec(None, "tp", None),
-        # a gated-delta-rule layer's own leaves are whole on every rank
-        # (`validate_cfg_for_mesh` refuses tp > 1 with such a layer)
-        "gdn_wq": spec(None, None, None), "gdn_wk": spec(None, None, None),
-        "gdn_wv": spec(None, None, None), "gdn_wz": spec(None, None, None),
-        "gdn_wa": spec(None, None), "gdn_wb": spec(None, None),
-        "gdn_a_log": spec(None), "gdn_dt_bias": spec(None),
-        "gdn_conv_q": spec(None, None, None),
-        "gdn_conv_k": spec(None, None, None),
-        "gdn_conv_v": spec(None, None, None), "gdn_o_scale": spec(None),
-        # ... and so are a state-space layer's, a Gated Memory Unit's and
-        # differential attention's
-        **{k: spec(None, None) for k in (
-            "ssm_w_in", "ssm_conv", "ssm_w_x", "ssm_w_dt", "ssm_a_log",
-            "ssm_w_out", "gmu_w1", "gmu_w2")},
-        **{k: spec(None) for k in (
-            "ssm_conv_bias", "ssm_dt_bias", "ssm_d_skip", "bo",
-            *sorted(DIFF_LEAVES))},
-        "bq": spec("tp", None), "bk": spec("tp", None),
-        "bv": spec("tp", None),
-        "wo": spec("tp", None, None),
-        "q_scale": spec("tp", None), "k_scale": spec("tp", None),
-        "ln2_scale": spec(None), "ln2_bias": spec(None),
-        "router": spec(None, None),
-        "we1": spec("ep", None, None),
-        "we2": spec("ep", None, None),
-        "we_gate": spec("ep", None, None),
-        "ws1": spec(None, "tp"), "ws2": spec("tp", None),
-        "ws_gate": spec(None, "tp"),
-        "w1": spec(None, "tp"), "b1": spec("tp"),
-        "w2": spec("tp", None), "b2": spec(None),
-        "w_gate": spec(None, "tp"),
-    }
-
-
-def _per_kind(cfg: TransformerConfig, leaves):
-    """A patterned stack's tree: `leaves()` under each kind of the pattern;
-    `leaves()` itself for a stack of one kind."""
-    if not cfg.layer_pattern:
-        return leaves()
-    return {kind: leaves() for kind in _kinds(cfg)}
-
-
-def _per_segment(cfg: TransformerConfig, leaves):
-    """A segmented stack's tree: per segment, `leaves()` under each kind of
-    its pattern."""
-    return [{kind: leaves() for kind in _kinds(cfg, pattern)}
-            for pattern, _ in cfg.segments]
+    return jax.tree_util.tree_map(draw, _declared(cfg))
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec tree matching init()'s structure (in_specs for
-    shard_map; also the NamedSharding layout for device_put). The leading
-    dense layers lie on every pipeline stage (`validate_cfg_for_mesh`
-    refuses pp > 1 with them); of a patterned stack the periods would lie
-    over the stages."""
-    lead = ("pp", None) if cfg.layer_pattern else ("pp",)
-    return _present({
-        "embed": P(), "pos": P(), "dense_layers": _layer_specs(None),
-        "layers": _per_kind(cfg, lambda: _layer_specs(*lead)),
-        "segments": _per_segment(cfg, lambda: _layer_specs(None, None)),
-        "lnf_scale": P(), "lnf_bias": P(), "unembed": P(),
-    }, cfg)
+    shard_map; also the NamedSharding layout for device_put)."""
+    return jax.tree_util.tree_map(lambda leaf: leaf.partition,
+                                  _declared(cfg))
 
 
 def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -738,30 +486,10 @@ def grad_reduce_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     # correction (verified leaf-by-leaf against a single-device oracle in
     # tests/test_parallel.py) is: divide EVERY gradient by tp, and
     # additionally pmean replicated-over-tp leaves — i.e. add 'tp' to their
-    # psum axes — to mix each rank's local-heads contribution.
-    data_axes = ("dp", "ep", "sp", "tp")    # replicated-over-tp layer params
-    glob = ("dp", "ep", "sp", "pp", "tp")   # replicated-over-everything
-    tp_sharded = ("dp", "ep", "sp")         # tp-sharded weights: no tp psum
-    experts = ("dp", "sp", "tp")            # expert-sharded over ep
-    lp = {"ln1_scale": data_axes, "ln1_bias": data_axes,
-          "ln2_scale": data_axes, "ln2_bias": data_axes,
-          "wq": tp_sharded, "wk": tp_sharded, "wv": tp_sharded,
-          "wkv_a": data_axes, "kv_scale": data_axes, "wkv_b": tp_sharded,
-          **dict.fromkeys(GDN_LEAVES | SSM_LEAVES | GMU_LEAVES | DIFF_LEAVES,
-                          data_axes),
-          "bq": tp_sharded, "bk": tp_sharded, "bv": tp_sharded,
-          "bo": data_axes,
-          "wo": tp_sharded, "q_scale": tp_sharded, "k_scale": tp_sharded,
-          "router": data_axes, "we1": experts, "we2": experts,
-          "we_gate": experts,
-          "ws1": tp_sharded, "ws2": tp_sharded, "ws_gate": tp_sharded,
-          "w1": tp_sharded, "b1": tp_sharded, "w2": tp_sharded,
-          "b2": data_axes, "w_gate": tp_sharded}
-    return _present({"embed": glob, "pos": glob, "dense_layers": dict(lp),
-                     "layers": _per_kind(cfg, lambda: dict(lp)),
-                     "segments": _per_segment(cfg, lambda: dict(lp)),
-                     "lnf_scale": glob, "lnf_bias": glob, "unembed": glob},
-                    cfg)
+    # psum axes (`Leaf.reduce_axes`) — to mix each rank's local-heads
+    # contribution.
+    return jax.tree_util.tree_map(lambda leaf: leaf.reduce_axes,
+                                  _declared(cfg))
 
 
 def _ln(x, scale, bias, eps=1e-5):
@@ -771,330 +499,28 @@ def _ln(x, scale, bias, eps=1e-5):
     return ((xf - mu) * lax.rsqrt(var + eps)).astype(x.dtype) * scale + bias
 
 
-def _rms(x, scale, eps=1e-5):
-    """RMSNorm: x * rsqrt(mean(x^2) + eps) * scale, statistics in float32."""
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * lax.rsqrt(ms + eps)).astype(x.dtype) * scale
-
-
 def _norm(x, p, name, cfg: TransformerConfig):
     if cfg.norm == "rmsnorm":
-        return _rms(x, p[name + "_scale"], cfg.rms_norm_eps)
+        return rms(x, p[name + "_scale"], cfg.rms_norm_eps)
     return _ln(x, p[name + "_scale"], p[name + "_bias"])
 
 
-def _qk_norm(x, scale, eps=1e-5):
-    """RMSNorm of the projected queries or keys x: (B, H_loc, S, dh) over
-    the whole projected vector, all heads of all `tp` ranks; scale: (H_loc,
-    dh)."""
-    xf = x.astype(jnp.float32)
-    ss = lax.psum(jnp.sum(jnp.square(xf), axis=(1, 3), keepdims=True), "tp")
-    width = x.shape[1] * x.shape[3] * lax.axis_size("tp")
-    return (xf * lax.rsqrt(ss / width + eps)).astype(x.dtype) \
-        * scale[None, :, None, :]
-
-
-def _rope_angles(positions, head_dim: int, theta: float,
-                 yarn: Optional[Yarn] = None):
-    """(cos, sin), each (S, head_dim / 2) float32, of the rotary embedding
-    at `positions`: pair i turns by position * theta^(-2i / head_dim), or by
-    position * `yarn`'s corrected frequency, cos and sin then times its
-    `rotation_factor`."""
-    half = head_dim // 2
-    if yarn is None:
-        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        factor = 1.0
-    else:
-        freq = jnp.asarray(yarn.frequencies(head_dim, theta))
-        factor = yarn.rotation_factor
-    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
-
-
-def _rope(x, angles):
-    """Rotates x: (B, H, S, dh) in the rotate-half pairing (i, i + dh/2)."""
-    cos, sin = angles
-    half = x.shape[-1] // 2
-    a = x[..., :half].astype(jnp.float32)
-    b = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _attend(q, k, v, cfg: TransformerConfig):
-    """Causal attention of q: (B, H_loc, S_loc, dq), k: (B, G_loc, S_loc,
-    dq) and v: (B, G_loc, S_loc, dv) by the algorithm `cfg.attn` names;
-    over the last `cfg.window` keys where there is a window."""
-    # the default scale, (the keys' width)^-1/2, is left to each algorithm
-    scale = {} if cfg.score_scale is None else {"scale": cfg.score_scale}
-    banded = cfg.window or q.shape[1] != k.shape[1]
-    if banded and cfg.attn not in ("flash", "local"):
-        raise HorovodTpuError(
-            f"a window or fewer key heads than query heads: attn="
-            f"{cfg.attn!r} cannot run them; use 'flash' or 'local'")
-    if cfg.attention == "mla" and cfg.attn not in ("flash", "local"):
-        # ring and Ulysses attention build their buffers and exchanges from
-        # one head width
-        raise HorovodTpuError(
-            f"attention='mla' has keys and values of different widths: "
-            f"attn={cfg.attn!r} cannot run it; use 'flash' or 'local'")
-    if cfg.attn == "ring":
-        return ring_attention(q, k, v, "sp", causal=True, **scale)
-    if cfg.attn == "ulysses":
-        return ulysses_mod.ulysses_attention(q, k, v, "sp", causal=True,
-                                             **scale)
-    if cfg.attn == "flash":
-        # Pallas flash kernel (ops/flash_attention.py) computes
-        # shard-LOCAL attention; silently wrong under a sequence-sharded
-        # mesh, so refuse — sharded sequences ride ring/Ulysses.
-        if lax.axis_size("sp") > 1:
-            raise HorovodTpuError(
-                "attn='flash' requires sp=1 (shard-local attention); use "
-                "attn='ring' or 'ulysses' for sequence parallelism")
-        from horovod_tpu.ops.flash_attention import flash_attention
-        if not cfg.window:
-            return flash_attention(q, k, v, causal=True, **scale)
-        # a scope of their own inside `attn.attend`: a windowed layer's
-        # kernels have the shapes of a full layer's, and a reader of the
-        # compiled step tells them apart by this name alone
-        with jax.named_scope("attn.window"):
-            return flash_attention(q, k, v, causal=True, window=cfg.window,
-                                   **scale)
-    if banded:
-        from horovod_tpu.ops.flash_attention import (
-            masked_attention_reference)
-        return masked_attention_reference(
-            q, k, v, True, cfg.score_scale, cfg.window or None)
-    return blockwise_attention_reference(q, k, v, causal=True, **scale)
-
-
-def _mla(h, lp: Dict[str, Any], cfg: TransformerConfig, rope):
-    """DeepSeek-V2's latent attention on the normed residual h: (B, S_loc,
-    D); returns this rank's heads' part of the output, (B, S_loc, D)."""
-    if rope is None:
-        raise HorovodTpuError("attention='mla' has a rotary part of its "
-                              "keys: it needs positions='rope'")
-    nope, latent = cfg.qk_nope_dim, cfg.kv_latent
-    with jax.named_scope("mla.project"):
-        q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
-        down = jnp.einsum("bsd,dc->bsc", h, lp["wkv_a"])
-        c = _rms(down[..., :latent], lp["kv_scale"], cfg.rms_norm_eps)
-        kv = jnp.einsum("bsc,chk->bhsk", c, lp["wkv_b"])
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-    with jax.named_scope("mla.rope"):
-        # one rotary key a token, shared by all heads
-        k_pe = _rope(down[:, None, :, latent:], rope)
-        q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], rope)],
-                            axis=-1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3]
-                                      + k_pe.shape[3:])], axis=-1)
-    with jax.named_scope("mla.attend"):
-        a = _attend(q, k, v, cfg)
-    with jax.named_scope("mla.out"):
-        return jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
-
-
-def _gdn(h, lp: Dict[str, Any], cfg: TransformerConfig):
-    """A Gated DeltaNet mixer (arXiv:2412.06464) on h: (B, S, D): the gated
-    delta rule of `ops/gated_delta.py` on convolved, normalised queries and
-    keys, its output normed per head, gated and projected; (B, S, D)."""
-    from horovod_tpu.ops.causal_conv import causal_conv_silu
-    from horovod_tpu.ops.gated_delta import gated_delta_rule
-    with jax.named_scope("gdn.project"):
-        q = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wq"])
-        k = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wk"])
-        v = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wv"])
-        z = jnp.einsum("bsd,dhk->bhsk", h, lp["gdn_wz"])
-        a = jnp.einsum("bsd,dh->bhs", h, lp["gdn_wa"],
-                       preferred_element_type=jnp.float32)
-        b = jnp.einsum("bsd,dh->bhs", h, lp["gdn_wb"],
-                       preferred_element_type=jnp.float32)
-    with jax.named_scope("gdn.conv"):
-        q = causal_conv_silu(q, lp["gdn_conv_q"],
-                             l2_scale=cfg.gdn_key_dim ** -0.5)
-        k = causal_conv_silu(k, lp["gdn_conv_k"], l2_scale=1.0)
-        v = causal_conv_silu(v, lp["gdn_conv_v"])
-    with jax.named_scope("gdn.scan"):
-        beta = jax.nn.sigmoid(b) * (2.0 if cfg.gdn_neg_eigval else 1.0)
-        rate = jnp.exp(lp["gdn_a_log"].astype(jnp.float32))[None, :, None]
-        g = -rate * jax.nn.softplus(
-            a + lp["gdn_dt_bias"].astype(jnp.float32)[None, :, None])
-        o = gated_delta_rule(q, k, v, g, beta)
-    with jax.named_scope("gdn.gate"):
-        o = (_rms(o, lp["gdn_o_scale"], cfg.rms_norm_eps).astype(jnp.float32)
-             * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
-    with jax.named_scope("gdn.out"):
-        return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"])
-
-
-def _conv_silu(u, taps, bias):
-    """SiLU of the depthwise causal convolution of u: (B, S, E) over S with
-    taps: (E, K) and a bias: (E,), zeros before the sequence's start; K
-    shifted multiply-adds in float32 that the compiler fuses into one pass.
-    (`ops/causal_conv.py` holds heads-major (B, H, S, d) arrays; a
-    state-space layer's channels are token-major, and two transposes of the
-    array would cost more than the convolution.)"""
-    taps_n, seq = taps.shape[-1], u.shape[1]
-    padded = jnp.pad(u, ((0, 0), (taps_n - 1, 0), (0, 0)))
-    y = bias.astype(jnp.float32) + sum(
-        padded[:, j:j + seq].astype(jnp.float32)
-        * taps[:, j].astype(jnp.float32) for j in range(taps_n))
-    return jax.nn.silu(y).astype(u.dtype)
-
-
-def _ssm(h, lp: Dict[str, Any], cfg: TransformerConfig):
-    """A Mamba-1 mixer (arXiv:2312.00752) on h: (B, S, D): the selective
-    scan of `ops/selective_scan.py` on the convolved input, gated and
-    projected. Returns ((B, S, D), the scan's output y before the gate,
-    (B, S, E): what a Gated Memory Unit reads)."""
-    from horovod_tpu.ops.selective_scan import selective_scan
-    E, N, R = cfg.ssm_channels, cfg.ssm_state, cfg.dt_rank
-    f32 = jnp.float32
-    with jax.named_scope("ssm.project"):
-        xz = jnp.einsum("bsd,dte->tbse", h,
-                        lp["ssm_w_in"].reshape(-1, 2, E))
-    with jax.named_scope("ssm.conv"):
-        c = _conv_silu(xz[0], lp["ssm_conv"], lp["ssm_conv_bias"])
-    with jax.named_scope("ssm.project"):
-        low = jnp.einsum("bse,er->bsr", c, lp["ssm_w_x"])
-        step = jnp.einsum("bsr,re->bse", low[..., :R], lp["ssm_w_dt"],
-                          preferred_element_type=f32)
-    with jax.named_scope("ssm.scan"):
-        delta = jax.nn.softplus(step + lp["ssm_dt_bias"].astype(f32))
-        y = selective_scan(c, delta, -jnp.exp(lp["ssm_a_log"].astype(f32)),
-                           low[..., R:R + N], low[..., R + N:],
-                           lp["ssm_d_skip"].astype(f32))
-    with jax.named_scope("ssm.gate"):
-        gated = (y.astype(f32) * jax.nn.silu(xz[1].astype(f32))).astype(
-            h.dtype)
-    with jax.named_scope("ssm.out"):
-        return jnp.einsum("bse,ed->bsd", gated, lp["ssm_w_out"]), y
-
-
-def _gmu(h, lp: Dict[str, Any], memory):
-    """A Gated Memory Unit (arXiv:2507.06607) on h: (B, S, D): the memory
-    (B, S, E) an earlier state-space layer handed on, gated by h and
-    projected."""
-    with jax.named_scope("gmu.project"):
-        gate = jnp.einsum("bsd,de->bse", h, lp["gmu_w1"])
-    with jax.named_scope("gmu.gate"):
-        gated = (jax.nn.silu(gate.astype(jnp.float32))
-                 * memory.astype(jnp.float32)).astype(h.dtype)
-    with jax.named_scope("gmu.out"):
-        return jnp.einsum("bse,ed->bsd", gated, lp["gmu_w2"])
-
-
-def _projected(h, lp: Dict[str, Any], w: str, b: str):
-    """h through the heads of lp[w], with the bias lp[b] where there is
-    one: (B, heads, S, width)."""
-    y = jnp.einsum("bsd,dhk->bhsk", h, lp[w])
-    return y + lp[b][None, :, None, :] if b in lp else y
-
-
-def _paired(x):
-    """The even and the odd heads of x: (B, 2P, S, d), each (B, P, S, d)."""
-    batch, heads, seq, width = x.shape
-    x = x.reshape(batch, heads // 2, 2, seq, width)
-    return x[:, :, 0], x[:, :, 1]
-
-
-def _diff_keys_values(h, lp: Dict[str, Any]):
-    """What differential attention reads of a layer: the even key heads, the
-    odd ones, (B, Q, S, d) each, and each pair's two value heads side by
-    side, (B, Q, S, 2d)."""
-    k1, k2 = _paired(_projected(h, lp, "wk", "bk"))
-    return k1, k2, jnp.concatenate(_paired(_projected(h, lp, "wv", "bv")),
-                                   axis=-1)
-
-
-def _diff_attention(h, lp: Dict[str, Any], cfg: TransformerConfig, depth,
-                    kv=None):
-    """Differential attention (arXiv:2410.05258) on h: (B, S, D): query pair
-    i = heads (2i, 2i + 1) reads K/V pair i // (P / Q); the pair's output is
-    (1 - l0) RMSNorm(a1 - lam a2) over its 2d values, lam = exp(lq1 . lk1)
-    - exp(lq2 . lk2) + l0 with l0 = 0.8 - 0.6 exp(-0.3 depth), `depth` the
-    layer's index in the model. `kv`: another layer's `_diff_keys_values`
-    (a "cross" layer); None: this layer's own. Returns ((B, S, D) before the
-    output bias, the keys and values read)."""
-    f32 = jnp.float32
-    with jax.named_scope("attn.project"):
-        q1, q2 = _paired(_projected(h, lp, "wq", "bq"))
-        if kv is None:
-            kv = _diff_keys_values(h, lp)
-    with jax.named_scope("attn.attend"):
-        k1, k2, vv = kv
-        a1, a2 = _attend(q1, k1, vv, cfg), _attend(q2, k2, vv, cfg)
-        l0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, f32))
-        lam = jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
-                              * lp["lambda_k1"].astype(f32))) \
-            - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
-                              * lp["lambda_k2"].astype(f32))) + l0
-        a = a1.astype(f32) - lam * a2.astype(f32)
-        a = (a * lax.rsqrt(jnp.mean(jnp.square(a), axis=-1, keepdims=True)
-                           + cfg.rms_norm_eps)
-             * lp["subln_scale"].astype(f32) * (1.0 - l0)).astype(h.dtype)
-    with jax.named_scope("attn.out"):
-        # pair i is heads 2i and 2i + 1 of the output projection
-        wo = lp["wo"].reshape(a.shape[1], a.shape[3], -1)
-        return jnp.einsum("bpsk,pkd->bsd", a, wo), kv
-
-
-def _mlp(h, w_gate, w_up, w_down, gate="silu"):
-    """W_down (act(W_gate h) * W_up h), act the `gate` of `moe.GATES`, or
-    W_down gelu(W_up h) without a gate; no biases. The hidden width is
-    sharded over tp: this rank's part of the sum."""
-    hidden = jnp.einsum("bsd,df->bsf", h, w_up)
-    hidden = jax.nn.gelu(hidden) if w_gate is None else \
-        moe_mod.GATES[gate](jnp.einsum("bsd,df->bsf", h, w_gate)) * hidden
-    return jnp.einsum("bsf,fd->bsd", hidden, w_down)
-
-
-def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
-           rope=None, shared=None, depth=0, stacked=None):
-    """One transformer block on per-shard activations x: (B, S_loc, D).
-    Returns (x, aux): aux is None for a dense MLP, and for experts the
-    layer's [load balance, router z] of this shard's tokens, with the count
-    of held pairs that found no room as a third where the layer holds a
-    share of its experts (see `parallel/moe.py`). A state-space layer's aux
-    is its scan's output and a differential-attention layer's the keys and
-    values it read: what a segment may hand on. `shared` is what an earlier
-    segment handed on, {"memory", "kv"}; `depth` the layer's index in the
-    model. `stacked` is (the stacks that `lp`'s expert leaves are a layer
-    of, which layer) where the caller has them: `run_stack`."""
+def _layer(x: jax.Array, lp: Dict[str, Any], shared=None, depth=0, *,
+           cfg: TransformerConfig, rope=None, stacked=None):
+    """One transformer block on per-shard activations x: (B, S_loc, D): the
+    mixer `cfg.attention` names in `MIXERS` and the FFN of `ffns.kind(cfg)`,
+    each in its residual. Returns (x, aux): aux is what the mixer hands on
+    (a state-space layer's scan output, a differential-attention layer's
+    keys and values: what a segment may hand on), else the FFN's (None for
+    a dense MLP, an expert layer's auxiliary numbers). `shared` is what an
+    earlier segment handed on, {"memory", "kv"}; `depth` the layer's index
+    in the model; `stacked` is `ffns`' (where the caller has the stacks:
+    `run_stack`)."""
     if cfg.positions != "rope":
         rope = None         # a kind the pattern leaves unrotated
     arrived = x
     h = x if cfg.post_norm else _norm(x, lp, "ln1", cfg)
-    handed = None
-    if cfg.attention == "mla":
-        o = _mla(h, lp, cfg, rope)
-    elif cfg.attention == "gdn":
-        o = _gdn(h, lp, cfg)
-    elif cfg.attention == "ssm":
-        o, handed = _ssm(h, lp, cfg)
-    elif cfg.attention == "gmu":
-        o = _gmu(h, lp, shared["memory"])
-    elif cfg.diff_attention:
-        o, handed = _diff_attention(
-            h, lp, cfg, depth,
-            shared["kv"] if cfg.attention == "cross" else None)
-    else:
-        with jax.named_scope("attn.project"):
-            q = _projected(h, lp, "wq", "bq")
-            k = _projected(h, lp, "wk", "bk")
-            v = _projected(h, lp, "wv", "bv")
-            if cfg.qk_norm:
-                q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
-                k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
-            if rope is not None:
-                q, k = _rope(q, rope), _rope(k, rope)
-        with jax.named_scope("attn.attend"):
-            a = _attend(q, k, v, cfg)
-        with jax.named_scope("attn.out"):
-            o = jnp.einsum("bhsk,hkd->bsd", a, lp["wo"])
+    o, handed = MIXERS[cfg.attention].apply(h, lp, cfg, rope, shared, depth)
     o = lax.psum(o, "tp")                    # row-parallel combine
     if "bo" in lp:
         o = o + lp["bo"]
@@ -1103,36 +529,7 @@ def _layer(x: jax.Array, lp: Dict[str, Any], cfg: TransformerConfig,
     x = x + o
 
     h2 = x if cfg.post_norm else _norm(x, lp, "ln2", cfg)
-    aux = None
-    if cfg.num_experts:
-        B, S, D = h2.shape
-        stacks, layer = stacked or ({}, 0)
-        out, aux, _ = moe_mod.moe_ffn(
-            h2.reshape(B * S, D), lp["router"],
-            *(lp.get(k) for k in EXPERT_LEAVES),
-            top_k=cfg.experts_per_token, axis_name="ep",
-            capacity_factor=cfg.capacity_factor,
-            first_expert=cfg.first_expert,
-            sequences=B if cfg.balance_per_sequence else 0,
-            router_input=arrived.reshape(B * S, D)
-            if cfg.router_input == "layer" else None,
-            renormalise=cfg.norm_topk, gate=cfg.gate or "silu",
-            stacks=tuple(stacks.get(k) for k in EXPERT_LEAVES), layer=layer)
-        f = out.reshape(B, S, D)
-        if cfg.shared_experts:
-            with jax.named_scope("moe.shared"):
-                f = f + lax.psum(_mlp(h2, lp.get("ws_gate"), lp["ws1"],
-                                      lp["ws2"], cfg.gate), "tp")
-    elif cfg.gate:
-        with jax.named_scope("mlp.dense"):
-            f = lax.psum(_mlp(h2, lp["w_gate"], lp["w1"], lp["w2"],
-                              cfg.gate), "tp")
-    else:
-        with jax.named_scope("mlp.dense"):
-            u = jnp.einsum("bsd,df->bsf", h2, lp["w1"]) + lp["b1"]
-            u = jax.nn.gelu(u)
-            f = jnp.einsum("bsf,fd->bsd", u, lp["w2"])
-            f = lax.psum(f, "tp") + lp["b2"]
+    f, aux = FFNS[ffns.kind(cfg)].apply(h2, lp, cfg, arrived, stacked)
     if cfg.post_norm:
         f = _norm(f, lp, "ln2", cfg)
     return x + f, aux if handed is None else handed
@@ -1160,43 +557,58 @@ def _remat(cfg: TransformerConfig, fn, prevent_cse=False):
                           policy=policies[cfg.remat_policy])
 
 
-def _layer_of(cfg: TransformerConfig, x, lp, shared, depth):
-    return _layer(x, lp, cfg, None, shared, depth)
+def _run_segments(cfg: TransformerConfig, segments, stacks, x, rope=None,
+                  slots=None, scatter=None):
+    """x through `segments` ((the kinds of a period, periods), ...) in
+    order; a layer pattern is one segment. `stacks[i]` holds segment i's
+    layers per kind, stacked over (periods, the kind's layers in a period).
+    Each segment is a scan over its periods whose body runs a period's
+    layers in the pattern's order, each layer its own checkpoint: the
+    backward pass then holds one layer's recomputed residuals at a time, not
+    the period's. They do need the barrier: the compiler removes a loop of
+    one period, and would then merge each layer's repeat with its forward
+    pass and keep every residual alive (measured: PERF.md, PR 32).
 
-
-def _run_segments(segments, x, cfg: TransformerConfig):
-    """x through the segments of a segmented stack, in order. Each is a scan
-    over its periods whose body runs the period's layers in the pattern's
-    order, each layer its own checkpoint behind a barrier (`one_period` of
-    `_forward_local` says why). The segment with the "full" layer returns,
-    beside the residual stream, its last "ssm" layer's scan output and the
-    "full" layer's keys and values (of its last period); the segments behind
-    it take them as loop-invariant inputs of their scans, so each is
-    computed once and the cotangents of all its readers add up."""
-    shared, first = {}, 0
-    for at, ((pattern, periods), stacks) in enumerate(zip(cfg.segments,
-                                                          segments)):
-        hands_on = _hands_on(cfg, at)
+    The segment with the "full" layer hands its last "ssm" layer's scan
+    output and the "full" layer's keys and values (of its last period) on
+    to the segments behind it, which take them as loop-invariant inputs of
+    their scans: each is computed once and the cotangents of all its
+    readers add up. `slots[i]` (per kind, stacked like its parameters) and
+    `scatter(i, kind)` are what `build_loss_and_grads` reduces segment i's
+    gradients through inside the backward loop. Returns (x, per segment its
+    expert layers' auxiliary numbers, (periods, a period's layers, .), or
+    None)."""
+    shared, first, auxes = {}, cfg.first_k_dense, []
+    for at, ((pattern, periods), of_kind) in enumerate(zip(segments, stacks)):
+        hands_on = _hands_on(segments, at)
+        kind_cfgs = {kind: _kind_cfg(cfg, kind) for kind in of_kind}
 
         def one_period(a, xs):     # traced at once, by the scan below
-            lp, period = xs
-            seen, handed = dict.fromkeys(lp, 0), {}
+            lp, slot, period = xs
+            lp = {kind: scattered_in_backward(leaves, slot[kind],
+                                              scatter(at, kind))
+                  if kind in slot else leaves for kind, leaves in lp.items()}
+            seen, handed, aux = dict.fromkeys(lp, 0), {}, []
             for place, kind in enumerate(pattern):
                 i = seen[kind]
                 seen[kind] += 1
-                a, out = _remat(cfg, partial(_layer_of, _kind_cfg(cfg, kind)),
-                                prevent_cse=True)(
+                a, out = _remat(cfg, partial(_layer, cfg=kind_cfgs[kind],
+                                             rope=rope), prevent_cse=True)(
                     a, {k: w[i] for k, w in lp[kind].items()}, shared,
                     first + period * len(pattern) + place)
-                if hands_on and kind in ("ssm", "full"):
-                    handed["memory" if kind == "ssm" else "kv"] = out
-            return a, handed
+                aux.append(out)
+                if hands_on and kind in _HANDS_ON:
+                    handed[_HANDS_ON[kind]] = out
+            # expert layers: the period's auxiliary numbers, layer by layer
+            return a, (jnp.stack(aux) if cfg.num_experts else None, handed)
 
-        x, handed = lax.scan(one_period, x, (stacks, jnp.arange(periods)))
+        x, (aux, handed) = lax.scan(one_period, x, (
+            of_kind, slots[at] if slots else {}, jnp.arange(periods)))
+        auxes.append(aux)
         if hands_on:
             shared = jax.tree_util.tree_map(lambda y: y[-1], handed)
         first += periods * len(pattern)
-    return x
+    return x, auxes
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig,
@@ -1210,8 +622,8 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     the layers hold a share of their experts: `_layer`).
     `grad_slots` (per stack, stacked per layer like the stack's parameters)
     and `scatter` are what `build_loss_and_grads` reduces the layers'
-    gradients through inside the backward loop: see
-    `_scattered_in_backward`."""
+    gradients through inside the backward loop
+    (`grad_reduce.scattered_in_backward`)."""
     sp_idx = lax.axis_index("sp")
     B, S = tokens.shape
     D = cfg.d_model
@@ -1222,8 +634,8 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         x = params["embed"][tokens]
     rope = None
     if cfg.positions == "rope":
-        rope = _rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
-                            cfg.rope_theta, cfg.yarn)
+        rope = rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
+                           cfg.rope_theta, cfg.yarn)
     with jax.named_scope("vocab.embed"):
         if cfg.positions == "learned":
             pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S,
@@ -1234,14 +646,17 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     def run_stack(stack, stage_params, act):
         """`act` through the layers of one stack: (act, the layers' aux)."""
         layer_cfg = _stack_cfg(cfg, stack)
-        patterned = stack == "layers" and bool(cfg.layer_pattern)
-        if patterned:
+        if stack == "layers" and cfg.layer_pattern:
             slots = {kind: of_kind for kind in stage_params if (
                 of_kind := (grad_slots or {}).get((stack, kind)))}
-        else:
-            slots = (grad_slots or {}).get((stack,))
+            act, (aux,) = _run_segments(
+                cfg, ((cfg.layer_pattern, _periods(cfg)),), [stage_params],
+                act, rope, [slots],
+                lambda at, kind: partial(scatter, (stack, kind)))
+            # (periods, the layers of a period, .) -> (layers, .)
+            return act, aux if aux is None else aux.reshape(-1, aux.shape[-1])
 
-        remat = partial(_remat, cfg)
+        slots = (grad_slots or {}).get((stack,))
         # The experts' products read a layer's matrices in place in the
         # stacked leaf (`ops/grouped_matmul.py`): what the scan slices out
         # of it for the body is then read by nothing, and the compiler
@@ -1250,59 +665,21 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         # still takes the gradient, so the backward scan stacks it as ever.
         # The gradient is stopped here and not in the body: a stack the
         # scan has a tangent for costs its transpose a stack of zeros.
-        stacks = {} if patterned else {
-            k: lax.stop_gradient(stage_params[k]) for k in EXPERT_LEAVES
-            if k in stage_params}
+        stacks = {k: lax.stop_gradient(stage_params[k])
+                  for k in ffns.EXPERT_LEAVES if k in stage_params}
 
         def one_kind(a, xs):
             lp, slot, layer = xs
             if slots:
-                lp = _scattered_in_backward(lp, slot,
-                                            partial(scatter, (stack,)))
-            return _layer(a, lp, layer_cfg, rope,
+                lp = scattered_in_backward(lp, slot,
+                                           partial(scatter, (stack,)))
+            return _layer(a, lp, cfg=layer_cfg, rope=rope,
                           stacked=(stacks, layer) if stacks else None)
 
-        def one_period(a, xs):
-            """A period's layers in the pattern's order; xs holds each
-            kind's layers of this period, stacked. Each layer is its own
-            checkpoint: the backward pass then holds one layer's
-            recomputed residuals at a time, not the period's. They do need
-            the barrier: the compiler removes a loop of one period, and
-            would then merge each layer's repeat with its forward pass and
-            keep every residual alive (measured: PERF.md, PR 32)."""
-            lp, slot = xs if slots else (xs, {})
-            lp = {kind: _scattered_in_backward(
-                leaves, slot[kind], partial(scatter, (stack, kind)))
-                if kind in slot else leaves for kind, leaves in lp.items()}
-            seen, auxes = dict.fromkeys(lp, 0), []
-            for kind in cfg.layer_pattern:
-                i = seen[kind]
-                seen[kind] += 1
-                a, aux = remat(partial(_layer, cfg=_kind_cfg(layer_cfg, kind),
-                                       rope=rope), prevent_cse=True)(
-                    a, {k: w[i] for k, w in lp[kind].items()})
-                auxes.append(aux)
-            # expert layers: the period's auxiliary numbers, layer by layer
-            return a, jnp.stack(auxes) if layer_cfg.num_experts else None
-
-        if patterned:
-            act, aux = lax.scan(
-                one_period, act,
-                (stage_params, slots) if slots else stage_params)
-        else:
-            layers = jnp.arange(len(stacks[EXPERT_LEAVES[0]]),
-                                dtype=jnp.int32) if stacks else None
-            act, aux = lax.scan(remat(one_kind), act,
-                                (stage_params, slots, layers))
-        if patterned and aux is not None:
-            aux = aux.reshape(-1, aux.shape[-1])     # (periods x kinds, ...)
-        return act, aux
-
-    if cfg.segments:
-        x = _run_segments(params["segments"], x, cfg)
-        with jax.named_scope("vocab.head"):
-            x = _norm(x, params, "lnf", cfg)
-            return _head(x, params, cfg), None
+        layers = jnp.arange(len(stacks[ffns.EXPERT_LEAVES[0]]),
+                            dtype=jnp.int32) if stacks else None
+        return lax.scan(_remat(cfg, one_kind), act,
+                        (stage_params, slots, layers))
 
     stage_fn = partial(run_stack, "layers")
     if cfg.first_k_dense:
@@ -1315,7 +692,10 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         raise HorovodTpuError(
             "pp > 1 requires microbatches > 1 (stages exchange activations "
             "only through the pipeline schedule)")
-    if M > 1:
+    if cfg.segments:
+        x, aux = _run_segments(cfg, cfg.segments, params["segments"], x,
+                               rope)[0], None
+    elif M > 1:
         if B % M:
             raise HorovodTpuError(f"local batch {B} not divisible by "
                                   f"microbatches {M}")
@@ -1329,15 +709,9 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
 
     with jax.named_scope("vocab.head"):
         x = _norm(x, params, "lnf", cfg)
-        return _head(x, params, cfg), aux
-
-
-def _head(x, params, cfg: TransformerConfig):
-    """The logits of the normed x: through `unembed`, or with `tied_head`
-    through the embedding's transpose."""
-    if cfg.tied_head:
-        return jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    return jnp.einsum("bsd,dv->bsv", x, params["unembed"])
+        if cfg.tied_head:   # the head is the embedding's transpose
+            return jnp.einsum("bsd,vd->bsv", x, params["embed"]), aux
+        return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
 
 
 def _local_loss(params, tokens, targets, cfg: TransformerConfig,
@@ -1401,75 +775,6 @@ def _loss_of_logits(logits, aux, targets, cfg: TransformerConfig):
     return local, lax.stop_gradient(dropped)
 
 
-def psum_axes(x, axes):
-    for a in axes:
-        x = lax.psum(x, a)
-    return x
-
-
-def _scatter_plan(shape, axes):
-    """How a gradient leaf of per-shard `shape` is reduce-scattered over its
-    reduce `axes`: (the axes of more than one rank, their product n, the
-    dimension cut into n chunks), or None where the leaf stays a psum: a
-    vector (norms and biases, 0.1% of the bytes), a leaf no dimension of
-    which divides by n, or nothing to reduce over."""
-    axes = tuple(a for a in axes if lax.axis_size(a) > 1)
-    n = math.prod(lax.axis_size(a) for a in axes)
-    dim = next((d for d, size in enumerate(shape) if size % n == 0), None)
-    if not axes or len(shape) < 2 or dim is None:
-        return None
-    return axes, n, dim
-
-
-def _scatter_sum(g, axes, n, dim):
-    """Reduce-scatter of `g` over `axes`: rank r of the n gets chunk r of
-    `dim` of the sum, as `lax.psum_scatter(..., tiled=True)` gives it.
-
-    For a power of two it is written as recursive halving over ppermutes:
-    log2(n) exchanges with the rank whose index differs in one bit, highest
-    bit first, each sending the half the partner keeps and adding the half
-    received. A collective-permute is a DMA that the TPU runs beside the
-    core's work (`-start`/`-done`), where the compiler's own reduce-scatter
-    and all-reduce hold the core from issue to result (docs/perf.md,
-    "overlap")."""
-    if n & (n - 1):
-        return lax.psum_scatter(g, axes, scatter_dimension=dim, tiled=True)
-    r = lax.axis_index(axes)
-    # the running sum is kept as its addends, so that the slices and the
-    # adds of one level fuse into one pass over the half that is left
-    terms = [g]
-    step = n // 2
-    while step:
-        half = terms[0].shape[dim] // 2
-        mine = (r // step) % 2       # which half this rank keeps
-        send = sum(lax.dynamic_slice_in_dim(t, (1 - mine) * half, half, dim)
-                   for t in terms)
-        terms = [lax.dynamic_slice_in_dim(t, mine * half, half, dim)
-                 for t in terms]
-        terms.append(lax.ppermute(send, axes,
-                                  [(i, i ^ step) for i in range(n)]))
-        step //= 2
-    return sum(terms)
-
-
-def _scattered_in_backward(lp, slots, scatter):
-    """`lp` (one layer's parameters), unchanged. In the backward pass the
-    cotangent of each leaf named in `slots` is handed to `scatter` and
-    leaves through the cotangent of its slot, a zero array of the
-    scattered shape; towards `lp` that leaf's cotangent is zero. The other
-    leaves' cotangents pass untouched."""
-    @jax.custom_vjp
-    def identity(lp, slots):
-        return lp
-
-    def bwd(_, g):
-        return ({k: None if k in slots else gk for k, gk in g.items()},
-                {k: scatter(k, g[k]) for k in slots})
-
-    identity.defvjp(lambda lp, slots: (lp, None), bwd)
-    return identity(lp, slots)
-
-
 def _reduces_in_backward(cfg: TransformerConfig, mesh: Mesh) -> bool:
     """Whether the layers' gradients are reduced inside the backward loop:
     the layer scan runs once per step, and some reduce axis of the mesh
@@ -1500,9 +805,10 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
     * the layer scan runs once per step (`microbatches` <= 1) and some
       reduce axis spans more than one rank: each layer's weight matrices
       are reduce-scattered inside the backward iteration that produces
-      them (`_scatter_sum`: DMAs that run beside the rest of the backward
-      pass), the scan stacks the shards (1/n of the stacked gradients'
-      HBM), and one all-gather per leaf after the loop completes the sum.
+      them (`grad_reduce.scatter_sum`: DMAs that run beside the rest of
+      the backward pass), the scan stacks the shards (1/n of the stacked
+      gradients' HBM), and one all-gather per leaf after the loop
+      completes the sum.
       Vectors, and the leaves outside the scan (`embed`, `pos`, the final
       norm, `unembed`), are psum'd after `value_and_grad`;
     * with microbatches the scan body runs once per pipeline tick, and a
@@ -1532,7 +838,7 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
         # plan's dimension counts behind the group's `lead` stacking axes
         groups = _layer_groups(cfg, params)
         plans = {path: {k: plan for k, w in _at(params, path).items() if (
-            plan := _scatter_plan(w.shape[lead:], _at(raxes, path)[k]))}
+            plan := scatter_plan(w.shape[lead:], _at(raxes, path)[k]))}
             for path, lead in groups.items()} if in_backward else {}
 
         def slot(w, plan, lead):
@@ -1549,7 +855,7 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
             # inside the scan body the first stacking axis is gone
             axes, n, dim = plans[path][k]
             with reducing():
-                return _scatter_sum(g / tp_size, axes, n,
+                return scatter_sum(g / tp_size, axes, n,
                                     dim + groups[path] - 1)
 
         (local_mean, dropped), (grads, shards) = jax.value_and_grad(
@@ -1672,16 +978,22 @@ def init_opt_state(optimizer: optax.GradientTransformation, params,
         else jax.device_put(x, replicated), optimizer.init(params))
 
 
+def _layer_cfgs(cfg: TransformerConfig):
+    """`cfg` as the layers of each kind and stack of the model see it."""
+    kinds = list(cfg.layer_pattern) + [
+        kind for pattern, _ in cfg.segments for kind in pattern]
+    return ([_kind_cfg(cfg, kind) for kind in dict.fromkeys(kinds)] or [cfg]) \
+        + [_stack_cfg(cfg, "dense_layers")] * bool(cfg.first_k_dense)
+
+
 def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
+    """Refuses, by name, what `cfg` cannot run on `mesh`: what the stack and
+    the mesh cannot do, here; what a mixer or an FFN cannot, in the
+    `checks` of the parts the configuration uses."""
     ax = mesh_axis_sizes(mesh)
     if cfg.layer_pattern:
         _periods(cfg)
-        for kind in cfg.layer_pattern:
-            _kind_cfg(cfg, kind)
-    linear = cfg.attention == "gdn" or "linear" in cfg.layer_pattern
     kinds = [kind for pattern, _ in cfg.segments for kind in pattern]
-    for kind in kinds:
-        _kind_cfg(cfg, kind)
     whole = ax["sp"] == ax["tp"] == ax["pp"] == 1
     checks = [
         (not cfg.segments or not cfg.layer_pattern,
@@ -1700,34 +1012,17 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
         (_reads_are_handed_on(cfg),
          "'gmu' and 'cross' layers need an earlier segment with an 'ssm' "
          "and the 'full' layer"),
-        ("cross" not in kinds or cfg.diff_attention,
-         "'cross' layers are differential attention's (diff_attention)"),
         ("window" not in kinds + list(cfg.layer_pattern) or cfg.window > 0,
          "'window' layers need window > 0"),
         (cfg.mlp in ("gelu", "swiglu", "reglu"),
          f"mlp={cfg.mlp!r}: choose 'gelu', 'swiglu' or 'reglu'"),
-        (cfg.router_input in ("mlp", "layer"),
-         f"router_input={cfg.router_input!r}: choose 'mlp' or 'layer'"),
-        (cfg.router_input == "mlp" or not cfg.post_norm,
-         "router_input='layer' with post_norm (the layer's input is the "
-         "attention's too)"),
-        # a head width of its own runs where it is tested: no test takes
-        # it through ring or Ulysses attention or shards such heads
-        (not cfg.d_head or cfg.d_head * cfg.n_heads == cfg.d_model
-         or cfg.attn in ("flash", "local"),
-         f"d_head * n_heads != d_model needs attn 'flash' or 'local', not "
-         f"{cfg.attn!r}"),
-        (not cfg.d_head or cfg.d_head * cfg.n_heads == cfg.d_model or whole,
-         "d_head * n_heads != d_model requires sp=tp=pp=1 (no mesh test "
-         "shards heads of a width of their own)"),
+        *(check for layer in _layer_cfgs(cfg)
+          for part in (MIXERS[layer.attention], FFNS[ffns.kind(layer)])
+          for check in part.checks(layer, ax)),
+        # what reads only plain attention's fields, whichever mixer runs
         (not cfg.d_head or cfg.attention == "mha",
          f"d_head is plain attention's head width: attention="
          f"{cfg.attention!r} has widths of its own"),
-        (not cfg.unrotated or cfg.positions == "rope",
-         "unrotated names kinds that take no rotation: positions='rope'"),
-        (set(cfg.unrotated) <= set(cfg.layer_pattern) | set(kinds),
-         f"unrotated names a kind the pattern lacks: {cfg.unrotated}"),
-        (cfg.n_heads % cfg.kv_heads == 0, "n_heads % n_kv_heads"),
         (not cfg.diff_attention or (
             cfg.n_heads % 2 == 0 and cfg.kv_heads % 2 == 0
             and cfg.n_heads // 2 % (cfg.kv_heads // 2) == 0
@@ -1739,14 +1034,10 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
          or (cfg.attn in ("flash", "local") and whole),
          "n_kv_heads, window and diff_attention need attn 'flash' or "
          "'local' and sp=tp=pp=1"),
-        # a gated-delta-rule layer carries a state along the whole sequence
-        # and holds all its heads: neither crosses shards yet
-        (not linear or ax["sp"] == 1,
-         "linear-attention layers require sp=1 (the state of the gated "
-         "delta rule would have to cross the sequence's shards)"),
-        (not linear or ax["tp"] == 1,
-         "linear-attention layers require tp=1 (their heads are not "
-         "sharded)"),
+        (not cfg.unrotated or cfg.positions == "rope",
+         "unrotated names kinds that take no rotation: positions='rope'"),
+        (set(cfg.unrotated) <= set(cfg.layer_pattern) | set(kinds),
+         f"unrotated names a kind the pattern lacks: {cfg.unrotated}"),
         (not cfg.layer_pattern or ax["pp"] == 1,
          "a layer pattern requires pp=1 (the pipeline schedule places "
          "layers, not periods)"),
@@ -1754,24 +1045,11 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
         (cfg.n_heads % ax["tp"] == 0, "n_heads % tp"),
         (cfg.d_ff % ax["tp"] == 0, "d_ff % tp"),
         (cfg.d_ff_dense % ax["tp"] == 0, "d_ff_dense % tp"),
-        (cfg.num_experts % ax["ep"] == 0 if cfg.num_experts else True,
-         "num_experts % ep"),
-        # every rank of the expert axis holds an equal part of ALL the
-        # experts the router scores, or one rank holds a share of them
-        # (parallel/moe.py): a share across ranks would need a second
-        # exchange for the pairs that no rank here holds
-        (ax["ep"] == 1 or cfg.experts_held in (0, cfg.num_experts),
-         "ep > 1 with experts_held < num_experts (a share of the experts "
-         "is one rank's)"),
         # the leading dense layers are a stack of their own that no
         # pipeline stage owns: the schedule has no place for them yet
         (ax["pp"] == 1 or not cfg.first_k_dense,
          "pp > 1 with first_k_dense > 0 (the leading dense layers belong "
          "to no pipeline stage)"),
-        (cfg.attention != "mla" or cfg.attn in ("flash", "local"),
-         "attention='mla' needs attn 'flash' or 'local'"),
-        (cfg.attention != "mla" or ax["sp"] == 1,
-         "attention='mla' requires sp=1"),
         # pp > 1 REQUIRES the microbatch pipeline: without it stages never
         # exchange activations and each stage silently trains only its own
         # layer slice on raw embeddings.

@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 from benchmark.families import deepseek_v2 as family
 from benchmark.reference import deepseek_v2 as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
-from horovod_tpu.models import transformer as tfm
+from horovod_tpu.models import mixers, transformer as tfm
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.grouped_matmul import ROW_TILE
 from horovod_tpu.parallel import MeshSpec, build_mesh, moe, moe_ffn
@@ -125,7 +125,7 @@ def test_every_gradient_leaf_matches_the_reference(params, sizes):
     """`build_loss_and_grads` against `jax.grad` of the reference's loss, the
     balance term included: it is each sequence's own, so a data-parallel
     mesh changes nothing. On a mesh that reduces, both stacks' gradients go
-    through `_scattered_in_backward`."""
+    through `grad_reduce.scattered_in_backward`."""
     tokens, targets = _data()
     mesh = mesh_of(**sizes)
     tfm.validate_cfg_for_mesh(CFG, mesh)
@@ -247,18 +247,18 @@ def test_yarn_frequencies_and_scale_by_hand():
     assert cfg.rope_dim == 64
     # without YaRN nothing is passed on: the kernels' own default holds
     assert dataclasses.replace(cfg, yarn=None).score_scale is None
-    cos, sin = tfm._rope_angles(jnp.arange(5), 64, 10000.0, YARN)
+    cos, sin = mixers.rope_angles(jnp.arange(5), 64, 10000.0, YARN)
     np.testing.assert_allclose(np.asarray(cos[3]), np.cos(3 * freq),
                                rtol=1e-5, atol=1e-6)
-    old = tfm._rope_angles(jnp.arange(5), 64, 10000.0)
+    old = mixers.rope_angles(jnp.arange(5), 64, 10000.0)
     np.testing.assert_allclose(np.asarray(old[1][3]), np.sin(3 * plain),
                                rtol=1e-5, atol=1e-6)
 
 
 def test_rms_norm_eps_is_read(params):
     x = jnp.full((1, 4), 1e-3, jnp.float32)
-    near = tfm._rms(x, jnp.ones(4), 1e-6)
-    far = tfm._rms(x, jnp.ones(4))
+    near = mixers.rms(x, jnp.ones(4), 1e-6)
+    far = mixers.rms(x, jnp.ones(4))
     assert float(near[0, 0]) == pytest.approx(1 / math.sqrt(2), rel=1e-4)
     assert float(far[0, 0]) == pytest.approx(1e-3 / math.sqrt(1.1e-5),
                                              rel=1e-4)
@@ -672,9 +672,9 @@ def _no_yarn_scale(cfg, weights, monkeypatch):
 
 def _key_unrotated(cfg, weights, monkeypatch):
     """The shared rotary key (one head) passes through unrotated."""
-    rope = tfm._rope
+    rope = mixers.rope
     monkeypatch.setattr(
-        tfm, "_rope", lambda x, a: x if x.shape[1] == 1 else rope(x, a))
+        mixers, "rope", lambda x, a: x if x.shape[1] == 1 else rope(x, a))
     return cfg, weights
 
 

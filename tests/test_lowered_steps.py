@@ -1,22 +1,30 @@
-"""The train step of three tiny configurations of the kinds the benchmark's
-LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's), lowered on the CPU and
-compared, as text, with what the commit before the layer pattern (PR 31's,
-a31c4fe) lowered for them: `tests/fixtures/hlo/lowered_steps.json.gz`. One
+"""The train step of six tiny configurations of the kinds the benchmark's
+LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's; since PR 45 the `CFG`s
+of `tests/test_olmo_hybrid.py`, a layer pattern, `tests/test_phi4_flash.py`,
+segments, and `tests/test_smallthinker.py`, a pattern with a share of the
+experts), lowered on the CPU and compared, as text, with what an earlier
+commit lowered for them: `tests/fixtures/hlo/lowered_steps.json.gz`. One
 rank, where nothing is reduced, and `dp` = 2, where the layers' gradients
-are reduce-scattered inside the backward loop. A change to
-`models/transformer.py` that is not meant to touch these models' programs
-leaves the text as it is; one that is meant to takes the fixture anew
-(`write_fixture()` below, on the tree whose programs are the new truth) and
-says so. PR 43 took the two expert models' anew: the row movers' two
-`custom_vjp`s moved from `parallel/moe.py` to `ops/row_gather.py` (the
-order in which the layer scan's constants are handed to its body changed),
-and where a share of the experts is held (`deepseek_v2`) a take's free rows
-are gathered from zero rows behind the source where a select cleared them;
-PR 44 took them anew again: the layer scan hands the experts' products
-their stacked leaves and the layer's number (`ops/grouped_matmul.py`), so the
-scan has the stacks as constants and the layers' numbers among its `xs`, and
-each product adds `layer * E` to its visits' groups; the GPT-2 block's text
-is a31c4fe's still.
+are reduce-scattered inside the backward loop (a segmented stack's are
+summed after it). A change to `models/` that is not meant to touch these
+models' programs leaves the text as it is; one that is meant to takes the
+fixture anew (`write_fixture()` below, on the tree whose programs are the
+new truth) and says so.
+
+Whose text each entry is: the GPT-2 block's is the commit's before the layer
+pattern (PR 31's, a31c4fe). PR 43 took the two expert models' anew: the row
+movers' two `custom_vjp`s moved from `parallel/moe.py` to
+`ops/row_gather.py` (the order in which the layer scan's constants are
+handed to its body changed), and where a share of the experts is held
+(`deepseek_v2`) a take's free rows are gathered from zero rows behind the
+source where a select cleared them; PR 44 took them anew again: the layer
+scan hands the experts' products their stacked leaves and the layer's number
+(`ops/grouped_matmul.py`), so the scan has the stacks as constants and the
+layers' numbers among its `xs`, and each product adds `layer * E` to its
+visits' groups. The three families of PR 45 were written on PR 44's commit
+(bf13d0e), before PR 45 moved the mixers, the FFNs and the gradient
+reduction out of `models/transformer.py` and gave patterns and segments one
+runner (`write_fixture(only_new=True)`: the older entries untouched).
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
@@ -32,6 +40,9 @@ import pytest
 
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
+from test_olmo_hybrid import CFG as HYBRID
+from test_phi4_flash import CFG as PHI4_FLASH
+from test_smallthinker import CFG as SMALLTHINKER
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "fixtures", "hlo", "lowered_steps.json.gz")
@@ -56,6 +67,10 @@ CONFIGS = {
         attention="mla", kv_latent=24, qk_nope_dim=16, qk_rope_dim=8,
         v_head_dim=16, mlp="swiglu", attn="flash", dtype=jnp.bfloat16,
         remat=True),
+    # a pattern, segments, a pattern with a share of the experts
+    "olmo_hybrid": HYBRID,
+    "phi4_flash": PHI4_FLASH,
+    "smallthinker": SMALLTHINKER,
 }
 CASES = [(name, dp) for name in CONFIGS for dp in (1, 2)
          if not (name == "deepseek_v2" and dp == 2)]   # a share is one rank's
@@ -74,8 +89,14 @@ def lowered(name: str, dp: int) -> str:
             params, state, tokens, tokens).as_text()
 
 
-def write_fixture() -> None:
-    texts = {f"{name}-dp{dp}": lowered(name, dp) for name, dp in CASES}
+def write_fixture(only_new: bool = False) -> None:
+    """Takes the fixture anew; with `only_new`, only the cases it lacks."""
+    texts = {}
+    if only_new:
+        with gzip.open(FIXTURE, "rt") as f:
+            texts = json.load(f)
+    texts.update({key: lowered(name, dp) for name, dp in CASES
+                  if (key := f"{name}-dp{dp}") not in texts})
     with gzip.open(FIXTURE, "wt") as f:
         json.dump(texts, f)
 

@@ -17,6 +17,7 @@ from benchmark.families import olmo_hybrid as family
 from benchmark.reference import olmo_hybrid as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.models.mixers import MIXERS
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.causal_conv import causal_conv_silu
 from horovod_tpu.ops.gated_delta import (chunked_over_recurrent_macs,
@@ -287,7 +288,9 @@ def test_the_pattern_has_the_leaves_each_kind_has(params):
     shared = {"ln1_scale", "ln2_scale", "w1", "w2", "w_gate", "wo"}
     assert set(layers["full"]) == shared | {"wq", "wk", "wv", "q_scale",
                                             "k_scale"}
-    assert set(layers["linear"]) == shared | set(tfm.GDN_LEAVES)
+    gdn = set(MIXERS["gdn"].leaves(CFG))
+    assert len(gdn) == 13 and {n for n in gdn if n[:4] != "gdn_"} == {"wo"}
+    assert set(layers["linear"]) == shared | gdn
     # (periods, layers of the kind in a period, ...)
     assert layers["full"]["wq"].shape == (2, 1, 48, 3, 16)
     assert layers["linear"]["gdn_wq"].shape == (2, 3, 48, 3, 8)
@@ -315,7 +318,7 @@ def test_a_stack_of_one_kind_keeps_its_leaves():
     plain = tfm.TransformerConfig(vocab=32, d_model=16, n_heads=2, d_ff=32,
                                   n_layers=2)
     p = tfm.init(jax.random.PRNGKey(0), plain)
-    assert not set(p["layers"]) & set(tfm.GDN_LEAVES)
+    assert not set(p["layers"]) & set(MIXERS["gdn"].leaves(CFG)) - {"wo"}
     assert p["layers"]["wq"].shape == (2, 16, 2, 8)
     # a whole stack of linear layers needs no pattern
     linear = dataclasses.replace(CFG, layer_pattern=(), attention="gdn",

@@ -18,6 +18,7 @@ from benchmark.families import phi4_flash as family
 from benchmark.reference import phi4_flash as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.models.mixers import MIXERS
 from horovod_tpu.parallel import MeshSpec, build_mesh
 
 SEGMENTS = ((("ssm", "window"), 2), (("ssm", "full"), 1),
@@ -76,9 +77,15 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
         sorted(middle) == ["full", "ssm"] and sorted(last) == ["cross", "gmu"]
     shared = {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias", "w1", "w2",
               "w_gate"}
-    assert set(first["ssm"]) == shared | tfm.SSM_LEAVES
-    assert set(last["gmu"]) == shared | tfm.GMU_LEAVES
-    cross = shared | tfm.DIFF_LEAVES | {"wq", "bq", "wo", "bo"}
+    ssm, gmu = set(MIXERS["ssm"].leaves(CFG)), set(MIXERS["gmu"].leaves(CFG))
+    assert len(ssm) == 9 and all(n[:4] == "ssm_" for n in ssm)
+    assert gmu == {"gmu_w1", "gmu_w2"}
+    assert set(first["ssm"]) == shared | ssm
+    assert set(last["gmu"]) == shared | gmu
+    cross = shared | {"wq", "bq", "wo", "bo", "lambda_q1", "lambda_k1",
+                      "lambda_q2", "lambda_k2", "subln_scale"}
+    assert set(MIXERS["cross"].leaves(tfm._kind_cfg(CFG, "cross"))) == \
+        cross - shared
     assert set(last["cross"]) == cross
     assert set(first["window"]) == set(middle["full"]) == \
         cross | {"wk", "bk", "wv", "bv"}

@@ -19,7 +19,7 @@ import optax
 import pytest
 from jax.profiler import ProfileData
 
-from horovod_tpu.models import transformer as tfm
+from horovod_tpu.models import ffns, mixers, transformer as tfm
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from test_lowered_steps import CONFIGS
 from test_olmo_hybrid import CFG as HYBRID
@@ -114,8 +114,8 @@ def test_the_reduction_has_its_scope_where_something_is_reduced(name):
     ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
     ("phi4_flash", 2), ("smallthinker", 2)])
 def test_no_instruction_lies_under_two_layers_scopes(name, dp):
-    """`mlp.dense` is entered at `_layer`'s dense branches and not in
-    `_mlp`, which the shared experts run under `moe.shared`; the reduction
+    """`mlp.dense` is entered by `ffns`' two dense rows and not in `_mlp`,
+    which the shared experts run under `moe.shared`; the reduction
     inside the backward loop is no part of the layer whose gradient it
     sums."""
     for n in op_names(name, dp):
@@ -124,16 +124,21 @@ def test_no_instruction_lies_under_two_layers_scopes(name, dp):
 
 
 def test_the_vocabulary_is_what_the_source_enters():
-    """`STEP_SCOPES` is every scope `models/transformer.py` enters outside
-    its mixers' (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`), no more
-    and no less."""
+    """`STEP_SCOPES` is every scope `models/transformer.py` and its layer
+    parts (`models/mixers.py`, `models/ffns.py`) enter outside the mixers'
+    own (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`), no more and no
+    less; and a part enters its scopes whatever the stack: the rows of
+    `MIXERS` and `FFNS` know of no pattern."""
+    parts = inspect.getsource(mixers) + inspect.getsource(ffns)
     entered = set(re.findall(r'named_scope[(,]\s*"([^"]+)"',
-                             inspect.getsource(tfm)))
-    mixers = {s for s in entered
-              if s.startswith(("moe.", "mla.", "gdn.", "ssm.", "gmu."))}
-    assert entered - mixers == set(tfm.STEP_SCOPES)
+                             inspect.getsource(tfm) + parts))
+    own = {s for s in entered
+           if s.startswith(("moe.", "mla.", "gdn.", "ssm.", "gmu."))}
+    assert entered - own == set(tfm.STEP_SCOPES)
     assert len(set(tfm.STEP_SCOPES)) == len(tfm.STEP_SCOPES)
-    assert "layer_pattern else" not in inspect.getsource(tfm._layer)
+    assert "layer_pattern" not in parts + inspect.getsource(tfm._layer)
+    assert own >= {"mla.project", "gdn.scan", "ssm.scan", "gmu.gate",
+                   "moe.shared"}
 
 
 def test_the_optimizers_phases_are_spans_of_the_profiler(hvd, tmp_path):
