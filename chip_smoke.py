@@ -38,7 +38,7 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
-from benchmark.families import olmo_hybrid, phi4_flash
+from benchmark.families import granite_hybrid, olmo_hybrid, phi4_flash
 from benchmark.harness import peaks
 from benchmark.layer_metrics import gdn_scan_roofline
 from horovod_tpu import native
@@ -50,6 +50,7 @@ from horovod_tpu.ops import gated_delta as gd
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops import row_gather as rg
 from horovod_tpu.ops import selective_scan as ss
+from horovod_tpu.ops import ssd_scan as ssd
 from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              flash_attention,
                                              grid_step_share,
@@ -665,6 +666,12 @@ def row_sum_pass(log: CompileLog,
         "after a first call; " + "; ".join(told))
 
 
+def _out_and_grads(fn, cot, *args):
+    """fn's output and, for the cotangent `cot`, every argument's gradient."""
+    out, vjp = jax.vjp(fn, *args)
+    return (out,) + vjp(cot.astype(out.dtype))
+
+
 def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
                         checked=512) -> None:
     """The selective scan's kernels (ops/selective_scan.py) alone at
@@ -689,17 +696,13 @@ def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
                    for k in ks[3:])
     args = (c, delta, rates, b_in, c_out, jnp.ones((channels,), jnp.float32))
 
-    def both(fn, cot, *args):
-        out, vjp = jax.vjp(fn, *args)
-        return (out,) + vjp(cot.astype(out.dtype))
-
     def head(x):
         return x[:, :checked] if x.ndim == 3 else x
 
     short = tuple(head(x) for x in args)
-    got = jax.jit(functools.partial(both, ss.selective_scan))(
+    got = jax.jit(functools.partial(_out_and_grads, ss.selective_scan))(
         head(cot), *short)
-    want = jax.jit(functools.partial(both, ss.reference_selective_scan))(
+    want = jax.jit(functools.partial(_out_and_grads, ss.reference_selective_scan))(
         head(cot), *(x.astype(jnp.float32) for x in short))
     errs = {}
     for name, g, w in zip(("y", "dc", "ddelta", "dA", "dB", "dC", "dD"),
@@ -713,7 +716,8 @@ def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
     runs = {"forward": (jax.jit(ss.selective_scan).lower(*args).compile(),
                         args),
             "forward + backward": (jax.jit(functools.partial(
-                both, ss.selective_scan)).lower(cot, *args).compile(),
+                _out_and_grads, ss.selective_scan)).lower(
+                    cot, *args).compile(),
                 (cot, *args))}
     kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
                        "selective scan")
@@ -732,6 +736,80 @@ def selective_scan_pass(log: CompileLog, shape=(1, 8192, 5120, 16),
     say(f"[selective scan] {batch} x {seq} tokens x {channels} channels x "
         f"{states} states, bf16 (delta float32): tiles of {chunk} tokens, "
         f"{seq // chunk} a sequence; interpret={_pallas.interpret()}, 0 "
+        "recompiles after a first call, tpu_custom_call in the compiled "
+        + ", ".join(f"{name} {n}" for name, n in kernels.items())
+        + f"; the first {checked} tokens from the token-by-token form "
+        + " ".join(f"{name} {e:.2e}" for name, e in errs.items())
+        + "; alone (information only), ms an execution: "
+        + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)
+
+
+def ssd_scan_pass(log: CompileLog, shape=(1, 4096, 64, 64, 128),
+                  checked=512) -> None:
+    """The state-space dual scan's kernels (ops/ssd_scan.py) alone at
+    `granite4h-1chip`'s (batch, tokens, heads held, head width, states): the
+    output and the six gradients over the first `checked` tokens against the
+    token-by-token `jnp` form, the Mosaic kernels the compiled forward and
+    forward + backward hold (on the TPU: one, and two), no compile request
+    after a first call, then their times against the least time the
+    benchmark's `ssd_scan_roofline` counts (the family's `scan_work`)."""
+    batch, seq, heads, width, states = shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    bf16 = jnp.bfloat16
+    x, cot = (jax.random.normal(k, (batch, seq, heads * width), bf16)
+              for k in ks[:2])
+    # steps and rates as the model's initialisation draws them
+    dt = jnp.exp(jax.random.uniform(ks[2], (batch, seq, heads),
+                                    minval=np.log(1e-3), maxval=np.log(0.1)))
+    a_log = jnp.log(jax.random.uniform(ks[3], (heads,), minval=1.0,
+                                       maxval=16.0))
+    b_in, c_out = (jax.random.normal(k, (batch, seq, states), bf16)
+                   for k in ks[4:])
+    args = (x, dt, a_log, b_in, c_out, jnp.ones((heads,), jnp.float32))
+
+    def head(v):
+        return v[:, :checked] if v.ndim == 3 else v
+
+    short = tuple(head(v) for v in args)
+    got = jax.jit(functools.partial(_out_and_grads, ssd.ssd_scan))(head(cot), *short)
+    want = jax.jit(functools.partial(_out_and_grads, ssd.recurrent_ssd_scan))(
+        head(cot), *(v.astype(jnp.float32) for v in short))
+    errs = {}
+    for name, g, w in zip(("y", "dx", "ddt", "da_log", "dB", "dC", "dD"),
+                          got, want):
+        errs[name] = _rms_off(g, w)
+        if not errs[name] <= BF16_RTOL:           # a NaN fails too
+            raise AssertionError(
+                f"ssd scan: {name} is {errs[name]:.3g} of its rms from the "
+                f"token-by-token form over {checked} tokens (tolerance "
+                f"{BF16_RTOL:.3g})")
+    runs = {"forward": (jax.jit(ssd.ssd_scan).lower(*args).compile(), args),
+            "forward + backward": (jax.jit(functools.partial(
+                _out_and_grads, ssd.ssd_scan)).lower(cot, *args).compile(),
+                (cot, *args))}
+    kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
+                       "ssd scan")
+    ms = _timed_without_recompiles(log, runs, "ssd scan", 5)
+    shares = ""
+    if on_tpu():   # the shares are of the benchmark's table of peaks
+        p = peaks.for_kind(jax.devices()[0].device_kind)
+        config = {"mamba_n_heads": heads, "mamba_d_head": width,
+                  "mamba_d_state": states, "mamba_chunk_size": ssd.CHUNK}
+        fwd, bwd = (gdn_scan_roofline.least_seconds((1, *work), p)[0] * 1e3
+                    for work in granite_hybrid.scan_work(batch * seq,
+                                                         config))
+        shares = (f"; least time forward {fwd:.3f} ms "
+                  f"({100 * fwd / ms['forward']:.2f}% of it), forward + "
+                  f"backward {fwd + bwd:.3f} ms "
+                  f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
+    chunk = min(ssd.CHUNK, seq)
+    say(f"[ssd scan] {batch} x {seq} tokens x {heads} heads of {width} x "
+        f"{states} states, bf16 (dt float32): chunk {chunk}, "
+        f"{ssd.chunks_of(seq, chunk)} chunks a sequence, "
+        f"{ssd.heads_a_step(heads, width)} heads a grid step, the chunked "
+        "form's multiply-adds "
+        f"{ssd.chunked_over_recurrent_macs(heads, width, states, chunk):.2f}"
+        f" x the recurrent form's; interpret={_pallas.interpret()}, 0 "
         "recompiles after a first call, tpu_custom_call in the compiled "
         + ", ".join(f"{name} {n}" for name, n in kernels.items())
         + f"; the first {checked} tokens from the token-by-token form "
@@ -1023,8 +1101,8 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: it is compared with.
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
-             causal_conv_pass, selective_scan_pass, windowed_grouped_flash,
-             row_sum_pass,
+             causal_conv_pass, selective_scan_pass, ssd_scan_pass,
+             windowed_grouped_flash, row_sum_pass,
              flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }

@@ -2,7 +2,8 @@
 the leaves it has under a configuration, its `apply`, and what it refuses of
 a configuration and a mesh. `MIXERS` holds them by the `attention` a layer's
 configuration states ("mha", "mla", "gdn" of `TransformerConfig`, and what
-the kinds of `transformer.LAYER_KINDS` make of it: "ssm", "gmu", "cross").
+the kinds of `transformer.LAYER_KINDS` make of it: "ssm", "mamba2", "gmu",
+"cross").
 
 An `apply` takes (h: the normed residual (B, S_loc, D), lp: the layer's
 leaves, cfg, rope: the rotation's (cos, sin) or None, shared: what an earlier
@@ -441,6 +442,79 @@ def _ssm(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
         return jnp.einsum("bse,ed->bsd", gated, lp["ssm_w_out"]), y
 
 
+# ---- "mamba2": a Mamba-2 mixer (arXiv:2405.21060), the chunked state-space
+# ---- dual scan of `ops/ssd_scan.py`
+
+def _mamba2_leaves(cfg) -> Dict[str, Leaf]:
+    """The input projection lies in two leaves, [z | x | B | C] and the
+    step's, so that the step's product stays in float32; a published
+    `in_proj` is the two side by side."""
+    D, H, N, taps = cfg.d_model, cfg.ssd_heads, cfg.ssd_state, cfg.ssd_conv
+    E = H * cfg.ssd_head_dim
+    mixed = E + 2 * N            # what the convolution runs over: x, B, C
+
+    def decay_rate(keys, shape, dtype):
+        # A ~ U(1, 16), held as its logarithm (Mamba-2's own draw)
+        return jnp.log(jax.random.uniform(
+            keys["m"][7], shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+    return {
+        "ssd_w_in": Leaf((D, E + mixed), fan_in("m", 0, D)),
+        "ssd_w_dt": Leaf((D, H), fan_in("m", 3, D)),
+        "ssd_conv": Leaf((mixed, taps), fan_in("m", 1, taps)),
+        "ssd_conv_bias": Leaf((mixed,), normal("m", 2, 0.02)),
+        "ssd_dt_bias": Leaf((H,), step_bias("m", 6)),
+        "ssd_a_log": Leaf((H,), decay_rate),
+        "ssd_d_skip": Leaf((H,), ones),
+        "ssd_norm_scale": Leaf((E,), ones),
+        "ssd_w_out": Leaf((E, D), fan_in("m", 5, E))}
+
+
+def _mamba2(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
+    """On h: (B, S, D): [z | xBC] = h W_in and the step h W_dt; SiLU of the
+    convolution over x, B and C together; the scan of `ops/ssd_scan.py`;
+    the gate first, then an RMSNorm over all the channels held (one group);
+    the output projection."""
+    from horovod_tpu.ops.ssd_scan import ssd_scan
+    N = cfg.ssd_state
+    E = cfg.ssd_heads * cfg.ssd_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("ssd.project"):
+        zx = jnp.einsum("bsd,de->bse", h, lp["ssd_w_in"])
+        step = jnp.einsum("bsd,dh->bsh", h, lp["ssd_w_dt"],
+                          preferred_element_type=f32)
+    with jax.named_scope("ssd.conv"):
+        mixed = _conv_silu(zx[..., E:], lp["ssd_conv"], lp["ssd_conv_bias"])
+    with jax.named_scope("ssd.scan"):
+        delta = jax.nn.softplus(step + lp["ssd_dt_bias"].astype(f32))
+        y = ssd_scan(mixed[..., :E], delta, lp["ssd_a_log"],
+                     mixed[..., E:E + N], mixed[..., E + N:],
+                     lp["ssd_d_skip"])
+    with jax.named_scope("ssd.gate"):
+        gated = y.astype(f32) * jax.nn.silu(zx[..., :E].astype(f32))
+        gated = (gated * lax.rsqrt(
+            jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+            + cfg.rms_norm_eps)).astype(h.dtype) * lp["ssd_norm_scale"]
+    with jax.named_scope("ssd.out"):
+        return jnp.einsum("bse,ed->bsd", gated, lp["ssd_w_out"]), None
+
+
+def _mamba2_checks(cfg, ax):
+    # the scan carries a state along the whole sequence, and the layer holds
+    # all its heads and B, C whole: neither crosses shards or stages yet
+    return [
+        (cfg.ssd_heads > 0, "'mamba2' layers need ssd_heads > 0"),
+        (ax["sp"] == 1,
+         "state-space dual layers require sp=1 (the scan's state would have "
+         "to cross the sequence's shards)"),
+        (ax["tp"] == 1,
+         "state-space dual layers require tp=1 (their heads are not "
+         "sharded, and the gated norm runs over all of them)"),
+        (ax["pp"] == 1,
+         "state-space dual layers require pp=1 (they come in a layer "
+         "pattern, which the pipeline schedule does not place)")]
+
+
 def _gmu_leaves(cfg) -> Dict[str, Leaf]:
     D, E = cfg.d_model, cfg.ssm_channels
     return {"gmu_w1": Leaf((D, E), fan_in("m", 0, D)),
@@ -465,5 +539,6 @@ MIXERS = {
     "mla": Part(_mla_leaves, _mla, _mla_checks),
     "gdn": Part(_gdn_leaves, _gdn, _gdn_checks),
     "ssm": Part(_ssm_leaves, _ssm),
+    "mamba2": Part(_mamba2_leaves, _mamba2, _mamba2_checks),
     "gmu": Part(_gmu_leaves, _gmu),
 }

@@ -18,7 +18,8 @@ One block, whose architecture `TransformerConfig` states, in parts: a
 layer is norm, mixer, residual, norm, FFN, residual (`_layer`). The mixers
 lie in `models/mixers.py` (`MIXERS`: plain attention with its biases,
 QK-norm, fewer key heads and the differential form; cross, latent (MLA),
-gated-delta-rule, state-space and Gated-Memory-Unit mixers), the FFNs in
+gated-delta-rule, state-space (Mamba-1 and Mamba-2) and Gated-Memory-Unit
+mixers), the FFNs in
 `models/ffns.py` (`FFNS`: dense GELU, dense gated, routed experts with their
 router and shared experts), each with the leaves it has, its `apply` and
 what it refuses; a leaf is declared once (`models/leaves.py`), and `init`,
@@ -30,7 +31,9 @@ The defaults are the GPT-2 block; the other fields make it OLMoE's
 (arXiv:2409.02060), DeepSeek-V2's (arXiv:2405.04434: its `first_k_dense`
 leading dense layers are a stack of their own, `params["dense_layers"]`, in
 front of `params["layers"]`), Olmo-Hybrid's, SmallThinker's
-(arXiv:2507.20984) and SambaY's (arXiv:2507.06607).
+(arXiv:2507.20984), SambaY's (arXiv:2507.06607) and the Granite 4.0 hybrids'
+(Mamba-2 layers, arXiv:2405.21060, to one attention layer, and four scalar
+multipliers).
 
 A model whose layers are not all of one kind states one period of its
 `layer_pattern`, which the stack repeats (`LAYER_KINDS`); its parameters lie
@@ -72,9 +75,9 @@ from horovod_tpu.parallel.grad_reduce import (
     psum_axes, scatter_plan, scatter_sum, scattered_in_backward)
 from horovod_tpu.parallel.mesh import mesh_axis_sizes
 
-#: The `jax.named_scope`s of the train step outside its mixers' (`moe.*` of
-#: `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`
-#: below):
+#: The `jax.named_scope`s of the train step outside its older mixers'
+#: (`moe.*` of `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `ssm.*`,
+#: `gmu.*` below; a Mamba-2 layer's `ssd.*` are listed):
 #: a scope reaches the compiled program as a component of an instruction's
 #: `op_name`, through `jit`, remat, the layer scan and differentiation, and a
 #: profile shows it in the op's name. The tests hold the program to this
@@ -82,7 +85,8 @@ from horovod_tpu.parallel.mesh import mesh_axis_sizes
 #: device time by it (docs/observability.md, "Scopes of the compiled step").
 STEP_SCOPES = ("attn.project", "attn.attend", "attn.window", "attn.out",
                "mlp.dense", "vocab.embed", "vocab.head", "vocab.loss",
-               "grad.reduce", "opt.update")
+               "grad.reduce", "opt.update",
+               "ssd.project", "ssd.conv", "ssd.scan", "ssd.gate", "ssd.out")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,6 +247,22 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    # a "mamba2" layer (`mixers.py`): ssd_heads heads of ssd_head_dim
+    # channels with one scalar decay a head and an (ssd_head_dim x
+    # ssd_state) state a head, B and C shared by all heads (one group), a
+    # depthwise causal convolution of ssd_conv taps (with bias) over x, B
+    # and C together, a gated RMSNorm over all the channels held
+    ssd_heads: int = 0
+    ssd_head_dim: int = 64
+    ssd_state: int = 128
+    ssd_conv: int = 4
+    # Scalar multipliers (the Granite family's): on the embedding, on each
+    # sub-layer's output before its residual add, on the attention scores
+    # in place of (the keys' width)^-1/2 (None: that), and on the logits
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: Optional[float] = None
+    logit_scale: float = 1.0
     # x + Norm(f(x)) in place of x + f(Norm(x)): each sub-layer's norm on
     # its output, inside the residual (Olmo 2's arrangement)
     post_norm: bool = False
@@ -293,12 +313,14 @@ class TransformerConfig:
     @property
     def score_scale(self) -> Optional[float]:
         """The softmax scale, where it is not the kernels' default
-        (the keys' width)^-1/2."""
+        (the keys' width)^-1/2: `attn_scale` where one is stated, and
+        either times YaRN's factor."""
         if self.yarn is None:
-            return None
+            return self.attn_scale
         width = self.qk_nope_dim + self.qk_rope_dim \
             if self.attention == "mla" else self.head_dim
-        return width ** -0.5 * self.yarn.score_factor
+        return (width ** -0.5 if self.attn_scale is None
+                else self.attn_scale) * self.yarn.score_factor
 
 
 #: the stacks of layers a parameter tree may hold, in the order they run
@@ -320,6 +342,7 @@ LAYER_KINDS = {"full": {"window": 0},
                "linear": {"attention": "gdn", "window": 0},
                "window": {},
                "ssm": {"attention": "ssm"},
+               "mamba2": {"attention": "mamba2"},
                "gmu": {"attention": "gmu"},
                "cross": {"attention": "cross", "window": 0}}
 #: what a layer of a kind hands on, where its segment does (`_hands_on`), and
@@ -505,6 +528,12 @@ def _norm(x, p, name, cfg: TransformerConfig):
     return _ln(x, p[name + "_scale"], p[name + "_bias"])
 
 
+def _scaled(branch, scale: float):
+    """A sub-layer's output times the residual multiplier, where there is
+    one."""
+    return branch if scale == 1 else branch * scale
+
+
 def _layer(x: jax.Array, lp: Dict[str, Any], shared=None, depth=0, *,
            cfg: TransformerConfig, rope=None, stacked=None):
     """One transformer block on per-shard activations x: (B, S_loc, D): the
@@ -526,13 +555,14 @@ def _layer(x: jax.Array, lp: Dict[str, Any], shared=None, depth=0, *,
         o = o + lp["bo"]
     if cfg.post_norm:
         o = _norm(o, lp, "ln1", cfg)
-    x = x + o
+    x = x + _scaled(o, cfg.residual_scale)
 
     h2 = x if cfg.post_norm else _norm(x, lp, "ln2", cfg)
     f, aux = FFNS[ffns.kind(cfg)].apply(h2, lp, cfg, arrived, stacked)
     if cfg.post_norm:
         f = _norm(f, lp, "ln2", cfg)
-    return x + f, aux if handed is None else handed
+    return x + _scaled(f, cfg.residual_scale), \
+        aux if handed is None else handed
 
 
 def _remat(cfg: TransformerConfig, fn, prevent_cse=False):
@@ -641,7 +671,7 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
             pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * S, S,
                                            axis=0)
             x = x + pos[None]
-        x = x.astype(cfg.dtype)
+        x = _scaled(x, cfg.embed_scale).astype(cfg.dtype)
 
     def run_stack(stack, stage_params, act):
         """`act` through the layers of one stack: (act, the layers' aux)."""
@@ -708,7 +738,8 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         x, aux = stage_fn(params["layers"], x)
 
     with jax.named_scope("vocab.head"):
-        x = _norm(x, params, "lnf", cfg)
+        # the logits' multiplier on the normed state: the same logits
+        x = _scaled(_norm(x, params, "lnf", cfg), cfg.logit_scale)
         if cfg.tied_head:   # the head is the embedding's transpose
             return jnp.einsum("bsd,vd->bsv", x, params["embed"]), aux
         return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux

@@ -106,6 +106,24 @@ _PINNED_TO_THE_LAST_METRICS = (
     "test_benchmark_step_scopes.py::"
     "test_the_eight_entries_stand_at_the_end_and_list_their_cells")
 
+#: Two more of the same kind, met by PR 46's cell `granite4h-1chip`, each
+#: with why it is expected to fail until a `benchmark` PR rewrites it. Every
+#: line of both runs, by name, in `test_benchmark_granite_hybrid.py`
+#: (`test_pr38s_entries_hold_what_their_pinned_test_held`,
+#: `test_the_prefixes_are_the_programs_vocabulary_but_the_newest_mixers`).
+_PINNED_BY_PR_46 = {
+    "test_benchmark_smallthinker.py::"
+    "test_the_entries_are_the_cells_found_by_name":
+        "holds the four moe_* metrics' `workloads` to end with "
+        "smallthinker-1chip; granite4h-1chip runs the same expert layer "
+        "and is appended after it",
+    "test_benchmark_step_scopes.py::"
+    "test_the_prefixes_are_the_programs_vocabulary_and_the_three_mixers":
+        "holds harness/step_scopes.PREFIXES to the prefixes of "
+        "transformer.STEP_SCOPES, which lists a Mamba-2 layer's ssd.* since "
+        "PR 46; the harness's file is no cell PR's to edit",
+}
+
 
 def pytest_collection_modifyitems(config, items):
     skips = []
@@ -130,6 +148,9 @@ def pytest_collection_modifyitems(config, items):
                 reason="pins BENCHMARK.json's last eight per-layer metrics "
                        "to PR 34's; PR 36 appended six after them",
                 strict=False))
+        for pinned, why in _PINNED_BY_PR_46.items():
+            if item.nodeid.endswith(pinned):
+                item.add_marker(pytest.mark.xfail(reason=why, strict=False))
 
 
 # hvdrace gate (`make race`, docs/static_analysis.md): when the suite

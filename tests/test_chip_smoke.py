@@ -136,6 +136,22 @@ def test_phase_row_sum_pass(smoke, capsys):
 
 
 @one_chip
+def test_phase_ssd_scan_pass(smoke, capsys):
+    chip_smoke.ssd_scan_pass(smoke, shape=(1, 48, 4, 8, 8), checked=32)
+    out = capsys.readouterr().out
+    assert "[ssd scan] 1 x 48 tokens x 4 heads of 8 x 8 states" in out
+    assert "chunk 48, 1 chunks a sequence, 4 heads a grid step, the " \
+        "chunked form's multiply-adds " in out
+    assert "x the recurrent form's; interpret=True, 0 recompiles after a " \
+        "first call, tpu_custom_call in the compiled forward 0, forward + " \
+        "backward 0" in out
+    assert "the first 32 tokens from the token-by-token form y " in out
+    assert all(f" {name} " in out for name in
+               ("dx", "ddt", "da_log", "dB", "dC", "dD"))
+    assert "least time" not in out     # no share of a peak off the TPU
+
+
+@one_chip
 def test_phase_selective_scan_pass(smoke, capsys):
     chip_smoke.selective_scan_pass(smoke, shape=(1, 64, 48, 8), checked=32)
     out = capsys.readouterr().out

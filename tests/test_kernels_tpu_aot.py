@@ -535,6 +535,49 @@ def test_selective_scan_compiles_for_v5e(monkeypatch, name):
                                              PHI4_FLASH) == {}
 
 
+#: `granite4h-1chip`'s scan: (batch, tokens, heads held, head width, states)
+GRANITE_SCAN = (1, 4096, 64, 64, 128)
+#: (operands, results) of the state-space dual scan's Mosaic kernels: the
+#: forward (x, [dt, l] by columns, l by rows, B, C, D along the lanes -> y),
+#: with a gradient asked the forward with the chunks' entry states and y in
+#: float32, and the backward kernel (those and dy -> dx, [ddt, dl], dB and dC
+#: a block of heads, dD a chunk)
+SSD_SIGNATURES = {"fwd": [(6, 1)], "bwd": [(6, 3), (9, 5)]}
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_ssd_scan_compiles_for_v5e(monkeypatch, name):
+    """`ops/ssd_scan.py` at `granite4h-1chip`'s shapes: 64 heads of 64 in
+    four blocks of 16, 128 states, 16 chunks of 256 tokens; forward and
+    backward are Mosaic kernels and nothing else walks the sequence. None
+    has a grouped matmul's (operands, results), by which the expert layer's
+    readers tell theirs in the same step."""
+    from benchmark.harness import hlo, scopes
+    from horovod_tpu.ops.ssd_scan import ssd_scan
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    b, s, h, p, n = GRANITE_SCAN
+    avals = (jax.ShapeDtypeStruct((b, s, h * p), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, s, h), jnp.float32),
+             jax.ShapeDtypeStruct((h,), jnp.float32),
+             jax.ShapeDtypeStruct((b, s, n), jnp.bfloat16),
+             jax.ShapeDtypeStruct((b, s, n), jnp.bfloat16),
+             jax.ShapeDtypeStruct((h,), jnp.float32))
+
+    def bwd(*args):
+        return jax.grad(lambda *a: ssd_scan(*a).astype(jnp.float32).sum(),
+                        argnums=tuple(range(6)))(*args)
+
+    want = SSD_SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": ssd_scan, "bwd": bwd}[name],
+                              avals, n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    assert " while(" not in txt
+    assert scopes.grouped_kernels(hlo.index(txt)) == {}
+
+
 @pytest.mark.parametrize("window", [None, PHI4_WINDOW],
                          ids=["full", "window512"])
 @pytest.mark.parametrize("name", ["fwd", "bwd"])

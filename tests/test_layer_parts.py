@@ -1,7 +1,8 @@
-"""The parameter tree of the six tiny family configurations of
+"""The parameter tree of the seven tiny family configurations of
 `tests/test_lowered_steps.py`: `init` draws, leaf by leaf, the numbers the
 commit before the layer parts (PR 44's, bf13d0e) drew
-(`tests/fixtures/init_digests.json`, written there by `write_fixture()`);
+(`tests/fixtures/init_digests.json`, written there by `write_fixture()`; the
+Granite family's entry on PR 46's own tree, `write_fixture(only_new=True)`);
 and what the parts of `models/mixers.py` and `models/ffns.py` declare is one
 tree, each leaf of a layer declared by one part."""
 
@@ -31,10 +32,17 @@ def digests(name: str) -> dict:
         for path, leaf in jax.tree_util.tree_leaves_with_path(params)}
 
 
-def write_fixture() -> None:
+def write_fixture(only_new: bool = False) -> None:
+    """Takes the fixture anew; with `only_new`, only the families it
+    lacks."""
+    kept = {}
+    if only_new:
+        with open(FIXTURE) as f:
+            kept = json.load(f)
+    kept.update({name: digests(name) for name in CONFIGS
+                 if name not in kept})
     with open(FIXTURE, "w") as f:
-        json.dump({name: digests(name) for name in CONFIGS}, f, indent=0,
-                  sort_keys=True)
+        json.dump(kept, f, indent=0, sort_keys=True)
 
 
 @pytest.fixture(scope="module")

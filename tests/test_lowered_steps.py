@@ -1,8 +1,9 @@
-"""The train step of six tiny configurations of the kinds the benchmark's
+"""The train step of seven tiny configurations of the kinds the benchmark's
 LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's; since PR 45 the `CFG`s
 of `tests/test_olmo_hybrid.py`, a layer pattern, `tests/test_phi4_flash.py`,
 segments, and `tests/test_smallthinker.py`, a pattern with a share of the
-experts), lowered on the CPU and compared, as text, with what an earlier
+experts; since PR 46 `tests/test_granite_hybrid.py`'s, Mamba-2 layers to one
+attention layer with the four multipliers), lowered on the CPU and compared, as text, with what an earlier
 commit lowered for them: `tests/fixtures/hlo/lowered_steps.json.gz`. One
 rank, where nothing is reduced, and `dp` = 2, where the layers' gradients
 are reduce-scattered inside the backward loop (a segmented stack's are
@@ -24,11 +25,14 @@ layers' numbers among its `xs`, and each product adds `layer * E` to its
 visits' groups. The three families of PR 45 were written on PR 44's commit
 (bf13d0e), before PR 45 moved the mixers, the FFNs and the gradient
 reduction out of `models/transformer.py` and gave patterns and segments one
-runner (`write_fixture(only_new=True)`: the older entries untouched).
+runner (`write_fixture(only_new=True)`: the older entries untouched). The
+Granite family's is PR 46's own, the PR that brought its mixer and the
+multipliers, whose defaults leave the six older texts as they were.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
 
+import dataclasses
 import gzip
 import json
 import os
@@ -40,6 +44,7 @@ import pytest
 
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
+from test_granite_hybrid import CFG as GRANITE
 from test_olmo_hybrid import CFG as HYBRID
 from test_phi4_flash import CFG as PHI4_FLASH
 from test_smallthinker import CFG as SMALLTHINKER
@@ -71,9 +76,16 @@ CONFIGS = {
     "olmo_hybrid": HYBRID,
     "phi4_flash": PHI4_FLASH,
     "smallthinker": SMALLTHINKER,
+    # a pattern of Mamba-2 layers and one attention layer, experts beside a
+    # shared MLP, a tied head, the four multipliers; as the cell runs it
+    "granite_hybrid": dataclasses.replace(
+        GRANITE, attn="flash", dtype=jnp.bfloat16, remat=True,
+        remat_policy="full"),
 }
+#: (a share of the experts is one rank's, with an expert axis; a data-parallel
+#: axis of two is the Granite and SmallThinker families' to show)
 CASES = [(name, dp) for name in CONFIGS for dp in (1, 2)
-         if not (name == "deepseek_v2" and dp == 2)]   # a share is one rank's
+         if not (name == "deepseek_v2" and dp == 2)]
 
 
 def lowered(name: str, dp: int) -> str:

@@ -1,6 +1,6 @@
 """The scopes of the compiled train step (`transformer.STEP_SCOPES` and the
 mixers' `moe.*`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`): for tiny configurations
-of the six kinds the benchmark's LM cells run, compiled on the CPU, every scope the
+of the seven kinds the benchmark's LM cells run, compiled on the CPU, every scope the
 model has is in the compiled text's `op_name`s, in the forward pass and in
 the backward pass; the gradient reduction's only where something is
 reduced; and `DistributedOptimizer.step` records its two phases as spans of
@@ -28,6 +28,7 @@ from test_smallthinker import CFG as SMALLTHINKER
 
 CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID, phi4_flash=PHI4_FLASH,
                smallthinker=dataclasses.replace(SMALLTHINKER, attn="flash"))
+SSD = ("ssd.project", "ssd.conv", "ssd.scan", "ssd.gate", "ssd.out")
 
 ATTN = ("attn.project", "attn.attend", "attn.out")
 VOCAB = ("vocab.embed", "vocab.head", "vocab.loss")
@@ -45,6 +46,7 @@ HAS = {
                           "ssm.scan", "ssm.gate", "ssm.out", "gmu.project",
                           "gmu.gate", "gmu.out", "mlp.dense") + VOCAB,
     "smallthinker": ATTN + ("attn.window",) + MOE + VOCAB,
+    "granite_hybrid": ATTN + SSD + ("moe.shared",) + MOE + VOCAB,
 }
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -72,7 +74,7 @@ def scopes_of(op_name: str) -> list:
     bare = (re.sub(r"^(?:[\w\-]+\()+", "", c).rstrip(")")
             for c in op_name.split("/"))
     return [c for c in bare if re.match(
-        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|ssm|gmu)\.", c)]
+        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|ssm|ssd|gmu)\.", c)]
 
 
 def under(names, scope: str, backward: bool) -> list:
@@ -96,7 +98,8 @@ def test_the_step_has_its_scopes_and_no_other(name):
 
 
 @pytest.mark.parametrize("name", ["gpt2", "olmoe", "olmo_hybrid",
-                                  "phi4_flash", "smallthinker"])
+                                  "phi4_flash", "smallthinker",
+                                  "granite_hybrid"])
 def test_the_reduction_has_its_scope_where_something_is_reduced(name):
     """On one rank nothing is reduced and the scope is absent; at `dp` = 2
     the halving inside the backward loop and the sums after it have it (a
@@ -112,7 +115,7 @@ def test_the_reduction_has_its_scope_where_something_is_reduced(name):
 
 @pytest.mark.parametrize("name, dp", [
     ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
-    ("phi4_flash", 2), ("smallthinker", 2)])
+    ("phi4_flash", 2), ("smallthinker", 2), ("granite_hybrid", 2)])
 def test_no_instruction_lies_under_two_layers_scopes(name, dp):
     """`mlp.dense` is entered by `ffns`' two dense rows and not in `_mlp`,
     which the shared experts run under `moe.shared`; the reduction
@@ -126,8 +129,8 @@ def test_no_instruction_lies_under_two_layers_scopes(name, dp):
 def test_the_vocabulary_is_what_the_source_enters():
     """`STEP_SCOPES` is every scope `models/transformer.py` and its layer
     parts (`models/mixers.py`, `models/ffns.py`) enter outside the mixers'
-    own (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`), no more and no
-    less; and a part enters its scopes whatever the stack: the rows of
+    own (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`; a Mamba-2
+    layer's `ssd.*` are listed in it), no more and no less; and a part enters its scopes whatever the stack: the rows of
     `MIXERS` and `FFNS` know of no pattern."""
     parts = inspect.getsource(mixers) + inspect.getsource(ffns)
     entered = set(re.findall(r'named_scope[(,]\s*"([^"]+)"',
@@ -139,6 +142,7 @@ def test_the_vocabulary_is_what_the_source_enters():
     assert "layer_pattern" not in parts + inspect.getsource(tfm._layer)
     assert own >= {"mla.project", "gdn.scan", "ssm.scan", "gmu.gate",
                    "moe.shared"}
+    assert set(tfm.STEP_SCOPES) >= set(SSD)
 
 
 def test_the_optimizers_phases_are_spans_of_the_profiler(hvd, tmp_path):
