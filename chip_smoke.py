@@ -666,6 +666,75 @@ def row_sum_pass(log: CompileLog,
         "after a first call; " + "; ".join(told))
 
 
+def embed_grad_pass(log: CompileLog,
+                    shapes=((16384, 2560, 37984), (8192, 3840, 12544))
+                    ) -> None:
+    """The embedding lookup's backward pass (ops/row_gather.py `lookup_rows`)
+    alone at `smallthinker-1chip`'s and `olmohybrid-1chip`'s (tokens, width,
+    rows of the table), ids drawn uniformly as the benchmark draws them: the
+    table's gradient against the float32 scatter-add rounded once, bit for
+    bit; the static counters of a mechanism that always engages, `scatters`
+    (scatter instructions in the compiled backward: 0) and `slot_share`
+    (slots of the sorted chunks that hold a sum over the tokens); no compile
+    request after a first call; then its time beside the compiler's
+    scatter-add of the same rows (plain indexing's backward) and the bytes
+    that must move over the HBM rate."""
+    told = []
+    for n_tokens, width, n_rows in shapes:
+        ids = jax.random.randint(jax.random.PRNGKey(n_rows), (1, n_tokens), 0,
+                                 n_rows, jnp.int32)
+        cot = jax.random.normal(jax.random.PRNGKey(width),
+                                (1, n_tokens, width), jnp.bfloat16)
+        table = jnp.zeros((n_rows, width), jnp.bfloat16)
+
+        def grad_of(lookup, table, ids, cot):
+            return jax.vjp(lambda t: lookup(t, ids)[0], table)[1](cot)[0]
+
+        operands = (table, ids, cot)
+        runs = {"lookup_rows": jax.jit(functools.partial(grad_of,
+                                                         rg.lookup_rows)),
+                "scatter-add": jax.jit(functools.partial(
+                    grad_of, lambda t, i: (t[i], t)))}
+        runs = {name: (fn.lower(*operands).compile(), operands)
+                for name, fn in runs.items()}
+        want = jax.jit(lambda ids, cot: jnp.zeros(
+            (n_rows, width), jnp.float32).at[ids[0]].add(
+                cot[0].astype(jnp.float32)).astype(jnp.bfloat16))(ids, cot)
+        got = runs["lookup_rows"][0](*operands)
+        if not bool(jnp.array_equal(got, want)):
+            raise AssertionError(
+                f"embed grad, {n_tokens} tokens x {width} into {n_rows}: not "
+                "the float32 scatter-add rounded once, bit for bit")
+        scatters = {name: fn.as_text().count(" scatter(")
+                    for name, (fn, _) in runs.items()}
+        if scatters["lookup_rows"] or not scatters["scatter-add"]:
+            raise AssertionError(
+                f"embed grad: scatter instructions in the compiled backward "
+                f"{scatters}, expected none in lookup_rows' and one in plain "
+                "indexing's")
+        _counted(runs, {"lookup_rows": 0, "scatter-add": 0}, "embed grad")
+        ms = _timed_without_recompiles(log, runs, "embed grad", 10)
+        least = ""
+        if on_tpu():   # a share of the benchmark's table of peaks
+            rate = peaks.for_kind(jax.devices()[0].device_kind).hbm_bytes_per_s
+            bound = 1e3 * 2 * width * (n_tokens + n_rows) / rate
+            least = (f"; the cotangent read and the gradient written at the "
+                     f"HBM rate {bound:.3f} ms "
+                     f"({100 * bound / ms['lookup_rows']:.1f}% of it)")
+        told.append(
+            f"{n_tokens} tokens x {width} into {n_rows} rows: chunks of "
+            f"{rg.LOOKUP_CHUNK}, scatters {scatters['lookup_rows']} (plain "
+            f"indexing's backward: {scatters['scatter-add']}), slot_share "
+            f"{rg.slot_share(np.asarray(ids[0]).tolist()):.4f}, the float32 "
+            "scatter-add rounded once, bit for bit; alone (information "
+            f"only), ms an execution: lookup_rows {ms['lookup_rows']:.3f} "
+            f"({1e6 * ms['lookup_rows'] / n_tokens:.1f} ns a token), "
+            f"scatter-add {ms['scatter-add']:.3f} "
+            f"({1e6 * ms['scatter-add'] / n_tokens:.1f})" + least)
+    say(f"[embed grad] bf16, 0 recompiles after a first call; "
+        + "; ".join(told))
+
+
 def _out_and_grads(fn, cot, *args):
     """fn's output and, for the cotangent `cot`, every argument's gradient."""
     out, vjp = jax.vjp(fn, *args)
@@ -1102,7 +1171,7 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
              causal_conv_pass, selective_scan_pass, ssd_scan_pass,
-             windowed_grouped_flash, row_sum_pass,
+             windowed_grouped_flash, row_sum_pass, embed_grad_pass,
              flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),
 }

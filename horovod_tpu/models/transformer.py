@@ -70,6 +70,7 @@ from horovod_tpu.models.ffns import FFNS
 from horovod_tpu.models.leaves import (
     Leaf, fan_in, key_streams, normal, ones, zeros)
 from horovod_tpu.models.mixers import MIXERS, rms, rope_angles
+from horovod_tpu.ops.row_gather import lookup_rows
 from horovod_tpu.parallel import pipeline as pp_mod
 from horovod_tpu.parallel.grad_reduce import (
     psum_axes, scatter_plan, scatter_sum, scattered_in_backward)
@@ -661,7 +662,9 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
     # the scope is entered twice: the rotation's angles are no part of it,
     # and the lowered program keeps the order it had
     with jax.named_scope("vocab.embed"):
-        x = params["embed"][tokens]
+        # a tied head reads the table the lookup hands on: its gradient
+        # then reaches the lookup's backward pass, which adds its own to it
+        x, table = lookup_rows(params["embed"], tokens)
     rope = None
     if cfg.positions == "rope":
         rope = rope_angles(sp_idx * S + jnp.arange(S), cfg.rope_dim,
@@ -741,7 +744,7 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         # the logits' multiplier on the normed state: the same logits
         x = _scaled(_norm(x, params, "lnf", cfg), cfg.logit_scale)
         if cfg.tied_head:   # the head is the embedding's transpose
-            return jnp.einsum("bsd,vd->bsv", x, params["embed"]), aux
+            return jnp.einsum("bsd,vd->bsv", x, table), aux
         return jnp.einsum("bsd,dv->bsv", x, params["unembed"]), aux
 
 

@@ -1,5 +1,6 @@
-"""The expert layer's two row movers, each the other's backward pass, and
-the Pallas TPU kernel that sums rows where most of them do not count.
+"""The expert layer's two row movers, each the other's backward pass, the
+Pallas TPU kernel that sums rows where most of them do not count, and the
+embedding's lookup, whose backward pass is a sort, a product and a gather.
 
 On one rank the expert layer (`parallel/moe.py`) moves rows twice a layer:
 
@@ -63,6 +64,42 @@ counts (`n_valid` None,
 `olmoe-1chip`: 2.7 ms against the kernel's 3.6); widths that are no whole
 lane tiles and element types other than bfloat16 and float32. Off the TPU
 the kernels run in the Pallas interpreter (`ops/_pallas.interpret`).
+
+The lookup. `lookup_rows(table, ids)` is (`table[ids]`, `table`), the third
+mover: the lookup's transpose adds the cotangent's T rows into the table's
+V, and the compiler's scatter-add of them is serial (15.8 ms for 16,384 rows
+of 2,560 into 37,984 where the bytes take 0.34; its time follows the table's
+rows and the width: docs/kernels.md). The backward pass here holds no
+scatter and no kernel, in plain `jnp`:
+
+1. `_lookup_plan`, from the ids alone, in the forward pass: the tokens sorted
+   by id, T padded to whole chunks of `LOOKUP_CHUNK` and one place more with
+   the id V, which sorts last. In a chunk the ids are sorted, so its distinct
+   ids are numbered by a running count of the places where the id changes:
+   a place's slot. A histogram of the ids (a product of two small one-hots of
+   v // 256 and v % 256: 2 T V operations on the MXU, no scatter either) and
+   two running sums over it say where every row's run ends and in which slot.
+2. `_lookup_grad`, behind the cotangent: its rows gathered in the sorted
+   order; a chunk's sums by slot as ONE batched product, onehot(slot)^T times
+   the chunk's rows, (C x C) (C x D), accumulated in float32 and rounded once
+   as it is written (a one-hot in bfloat16 is exact); a run crosses a chunk's
+   edge only through the chunk's first slot, so each chunk's first and last
+   slots are summed once more in float32 and a chunk's first slot takes what
+   the earlier chunks' last slots hold of its id (a (chunks x chunks) 0/1
+   product); then the table's gradient is one gather of V rows, row v from
+   the slot where v's run ends, a row no token asks for from a slot that is
+   always free. Exact for any multiplicity, and the work follows T + V.
+
+Two things the scatter-add gave the compiler's scheduler are kept, because
+three cells' compiled steps needed 2 to 4.6% more memory without them
+(docs/kernels.md, PERF.md section 6): the lookup's part is added onto a
+buffer that exists before it, behind `lax.optimization_barrier` (the
+gradient of whatever read the table after the lookup, which is why the
+table is handed back and a tied head reads that one; zeros otherwise), and
+the gather of the V rows stands in a loop of one trip.
+
+A cotangent row that is not finite reaches every slot of its chunk (0 x inf
+in the product), where the scatter-add spoils its own row alone.
 """
 
 from __future__ import annotations
@@ -474,3 +511,162 @@ def _sum_rows_bwd(k, indices, g):
 
 
 sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+# --------------------------------------------------------------------------
+# The lookup
+# --------------------------------------------------------------------------
+
+#: sorted tokens a chunk of `_lookup_grad`: the one-hot product's inner size
+LOOKUP_CHUNK = 256
+
+
+def _chunk_slots(s, chunk: int):
+    """For sorted ids `s` (chunks * chunk,): the slot of every place, its
+    chunk's distinct ids numbered from 0 by a running count of the places
+    where `s` changes, as (chunks, chunk) int32."""
+    rows = s.reshape(-1, chunk)
+    changes = jnp.concatenate(
+        [jnp.zeros((rows.shape[0], 1), _I32),
+         (rows[:, 1:] != rows[:, :-1]).astype(_I32)], axis=1)
+    return jnp.cumsum(changes, axis=1, dtype=_I32)
+
+
+def _exact(x):
+    """The precision at which a product with a 0/1 matrix adds x's values
+    as they are (bfloat16 operands are exact in one pass)."""
+    return None if x.dtype == jnp.bfloat16 else lax.Precision.HIGHEST
+
+
+def slot_share(ids: Sequence[int], chunk: Optional[int] = None) -> float:
+    """Slots of `_lookup_grad`'s chunks that hold a sum over the tokens, for
+    concrete ids: the distinct ids of each chunk of the sorted ids, summed
+    (1.0 where no id repeats inside a chunk: as many rows written as read;
+    chunks / tokens where every token has one id)."""
+    chunk = chunk or LOOKUP_CHUNK
+    s = sorted(ids)
+    return sum(len(set(s[at:at + chunk]))
+               for at in range(0, len(s), chunk)) / len(s)
+
+
+def _lookup_plan(ids, n_rows: int, chunk: int):
+    """What `_lookup_grad` needs of the ids (T,) alone, all int32: `order`
+    (padded,), the tokens by id with padding behind them; `slot` (chunks,
+    chunk), a place's slot in its chunk, -1 for padding; `joins` (chunks,
+    chunks), 1.0 where an earlier chunk's last id is the chunk's first;
+    `place` (n_rows,), where in the chunks' slots each row's sum stands, a
+    free slot for a row no token asks for."""
+    n_tokens = ids.size
+    ids = ids.astype(_I32)
+    ids = jnp.where(ids < 0, ids + n_rows, ids)      # as the lookup reads
+    # an id outside the table adds nothing: it is padding
+    ids = jnp.where(jnp.logical_and(ids >= 0, ids < n_rows), ids, n_rows)
+    # the tokens by id; at least one place is padding (the id `n_rows`,
+    # which sorts last), so the last slot of the last chunk is always free
+    n_chunks = n_tokens // chunk + 1
+    padded = n_chunks * chunk
+    s, order = lax.sort((ids, jnp.arange(n_tokens, dtype=_I32)), num_keys=1)
+    s = jnp.pad(s, (0, padded - n_tokens), constant_values=n_rows)
+    order = jnp.pad(order, (0, padded - n_tokens))
+    slot = jnp.where(s.reshape(n_chunks, chunk) < n_rows,
+                     _chunk_slots(s, chunk), -1)
+    first, last = s[::chunk], s[chunk - 1::chunk]
+    earlier = jnp.arange(n_chunks)[None, :] < jnp.arange(n_chunks)[:, None]
+    joins = jnp.logical_and(earlier, last[None, :] == first[:, None])
+    # where each row's run ends: tokens with an id <= v, from a histogram of
+    # the ids that is a product of two small one-hots (v = high * 256 +
+    # low), and the distinct ids among them
+    highs = -(-n_rows // 256)
+    hist = jnp.dot(
+        (s[:, None] // 256 == jnp.arange(highs, dtype=_I32)[None, :]
+         ).astype(jnp.bfloat16).T,
+        (s[:, None] % 256 == jnp.arange(256, dtype=_I32)[None, :]
+         ).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32).reshape(-1)[:n_rows].astype(_I32)
+    end = jnp.cumsum(hist, dtype=_I32) - 1
+    rank = jnp.cumsum((hist > 0).astype(_I32), dtype=_I32) - 1
+    at = jnp.maximum(end, 0) // chunk                 # the chunk it ends in
+    # the rank of a chunk's first id: its first place's own
+    base = rank[jnp.minimum(first, n_rows - 1)]
+    base_at = jnp.sum(jnp.where(
+        at[:, None] == jnp.arange(n_chunks, dtype=_I32)[None, :],
+        base[None, :], 0), axis=1)
+    place = jnp.where(hist > 0, at * chunk + rank - base_at, padded - 1)
+    return order, slot, joins.astype(jnp.float32), place
+
+
+def _lookup_grad(plan, g, dtype):
+    """The gradient of `table[ids]` for `_lookup_plan(ids, ...)` and the
+    cotangent g (T, D), for a table of `dtype`: row v the float32 sum of the
+    rows g[t] with ids[t] == v, rounded once; zero where no token asks for
+    v. No scatter: the tokens' rows gathered in the order of their ids, a
+    one-hot product a chunk, and a gather of the table's rows."""
+    order, slot, joins, place = plan
+    n_chunks, chunk = slot.shape
+    gs = g[order].reshape(n_chunks, chunk, g.shape[1])
+    # a chunk's sums by slot, one batched product, rounded once as it is
+    # written; padding has no slot
+    onehot = (slot[:, None, :] == jnp.arange(chunk, dtype=_I32)[None, :, None]
+              ).astype(g.dtype)
+    sums = jnp.einsum("cup,cpd->cud", onehot, gs, precision=_exact(g),
+                      preferred_element_type=jnp.float32).astype(dtype)
+    # a run that crosses chunks' edges: the chunk c in which it goes on
+    # holds it in slot 0, and every earlier chunk j whose last id is c's
+    # first holds its part in its last slot. Those two slots of each chunk
+    # once more, kept in float32
+    last_slot = jnp.maximum(slot[:, -1:], 0)
+    ends = jnp.stack([slot == 0, slot == last_slot], axis=1)
+    ends = jnp.einsum("cep,cpd->ced", ends.astype(g.dtype), gs,
+                      precision=_exact(g), preferred_element_type=jnp.float32)
+    heads = ends[:, 0] + jnp.dot(joins, ends[:, 1],
+                                 precision=lax.Precision.HIGHEST)
+    sums = lax.dynamic_update_slice(sums, heads.astype(dtype)[:, None],
+                                    (0, 0, 0)).reshape(-1, g.shape[1])
+    # The gather of the table's rows stands in a loop of one trip, which the
+    # compiler cannot count (a place is never negative) and so schedules as
+    # one instruction, as the scatter-add was. As a plain gather it changes
+    # what `lm-1chip`'s compiled step does with the loss's value, which
+    # nothing waits for: computed after the optimizer's updates, the logits
+    # kept until then, +0.25 GiB (PERF.md section 6, docs/kernels.md).
+    once = (place[0] >= 0).astype(_I32)
+    return lax.while_loop(
+        lambda carry: carry[0] < once,
+        lambda carry: (carry[0] + 1, sums[place]),
+        (jnp.zeros((), _I32), jnp.zeros((place.size, g.shape[1]), dtype)))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lookup(table, ids, chunk):
+    return table[ids], table
+
+
+def _lookup_fwd(table, ids, chunk):
+    # the ids' own work stands in the forward pass
+    return (table[ids], table), _lookup_plan(ids.reshape(-1), table.shape[0],
+                                             chunk)
+
+
+def _lookup_bwd(chunk, plan, cotangents):
+    g, onto = cotangents
+    # The lookup's part is added onto a buffer that exists before it: the
+    # gradient of whatever read the table after the lookup (a tied head), or
+    # zeros, as the compiler's scatter-add was. Without the barrier the
+    # compiler fuses a tied head's gradient product with this add and keeps
+    # the logits' cotangent for it until the end of the step
+    # (`phi4flash-1chip`: +0.52 GiB), and the untied steps' schedules change
+    # (`smallthinker-1chip`: +0.56 GiB; PERF.md).
+    onto = lax.optimization_barrier(onto)
+    return onto + _lookup_grad(plan, g.reshape(-1, g.shape[-1]),
+                               onto.dtype), None
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
+
+
+def lookup_rows(table, ids):
+    """(`table[ids]`, `table`) for a table (V, D) and ids of any shape, the
+    rows bit for bit. The backward pass is `_lookup_grad`, not the
+    scatter-add the compiler makes of a gather's transpose; a caller that
+    reads the table again (a tied head) reads the one handed back, and that
+    reader's gradient is what the lookup's is added to."""
+    return _lookup(table, ids, LOOKUP_CHUNK)

@@ -136,6 +136,19 @@ def test_phase_row_sum_pass(smoke, capsys):
 
 
 @one_chip
+def test_phase_embed_grad_pass(smoke, capsys):
+    chip_smoke.embed_grad_pass(smoke, shapes=((600, 128, 97), (64, 256, 40)))
+    out = capsys.readouterr().out
+    assert "[embed grad] bf16, 0 recompiles after a first call; 600 tokens " \
+        "x 128 into 97 rows: chunks of 256, scatters 0 (plain indexing's " \
+        "backward: 1), slot_share 0." in out
+    assert "; 64 tokens x 256 into 40 rows: chunks of 256, scatters 0 " in out
+    assert out.count("the float32 scatter-add rounded once, bit for bit") == 2
+    assert out.count(" ns a token), scatter-add ") == 2
+    assert "HBM rate" not in out     # no share of a peak off the TPU
+
+
+@one_chip
 def test_phase_ssd_scan_pass(smoke, capsys):
     chip_smoke.ssd_scan_pass(smoke, shape=(1, 48, 4, 8, 8), checked=32)
     out = capsys.readouterr().out

@@ -27,7 +27,10 @@ visits' groups. The three families of PR 45 were written on PR 44's commit
 reduction out of `models/transformer.py` and gave patterns and segments one
 runner (`write_fixture(only_new=True)`: the older entries untouched). The
 Granite family's is PR 46's own, the PR that brought its mixer and the
-multipliers, whose defaults leave the six older texts as they were.
+multipliers, whose defaults leave the six older texts as they were. PR 47
+took every entry anew: the embedding's lookup is `ops/row_gather.py`
+`lookup_rows`, whose backward pass is a sort, a batched product and a gather
+where the gather's transpose was a scatter-add, in every model's step.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
@@ -130,3 +133,18 @@ def test_the_lowered_step_is_the_parents(parents, name, dp):
                     f"{len(b)}; first difference at line {first + 1}:\n"
                     f"  now:    {a[first][:300] if first < len(a) else ''}\n"
                     f"  parent: {b[first][:300] if first < len(b) else ''}")
+
+
+@pytest.mark.parametrize("name", ["gpt2", "phi4_flash"],
+                         ids=["untied", "tied"])
+def test_the_lookup_leaves_the_step_one_scatter_fewer(monkeypatch, name):
+    """Plain indexing in the lookup's place puts one scatter (the gather's
+    transpose, a scatter-add of the tokens' rows into the table) and takes
+    one sort out of the lowered step; nothing else of the step is one."""
+    def count(what):
+        return lowered(name, 1).count(f'"stablehlo.{what}"(')
+
+    scatters, sorts = count("scatter"), count("sort")
+    monkeypatch.setattr(tfm, "lookup_rows",
+                        lambda table, ids: (table[ids], table))
+    assert (count("scatter"), count("sort")) == (scatters + 1, sorts - 1)

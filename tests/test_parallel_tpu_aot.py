@@ -157,3 +157,31 @@ def test_single_device_train_step_holds_no_collective_on_tpu():
     for kind in ("all-reduce", "all-gather", "reduce-scatter",
                  "collective-permute", "all-to-all"):
         assert kind not in txt, f"{kind} in the one-device train step"
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_no_scatter_under_the_lookup_in_the_compiled_step(tied, monkeypatch):
+    """The lookup's backward pass (ops/row_gather.py `lookup_rows`) in a bf16
+    flash + remat step on one rank: no instruction under `vocab.embed` is a
+    scatter, where plain indexing leaves one there (so the search would find
+    it), and the step holds the Mosaic kernels it held with plain indexing,
+    no more: the benchmark's flash readers sum every custom call of the LM
+    cells' program. A tied head adds its gradient to the lookup's."""
+    from horovod_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
+    cfg = tfm.TransformerConfig(
+        vocab=512, d_model=256, n_heads=2, d_ff=512, n_layers=2, max_seq=256,
+        attn="flash", dtype=jnp.bfloat16, remat=True, tied_head=tied)
+
+    def scatters_and_kernels():
+        txt = _compile(MeshSpec(), cfg, seq=256, batch=2)
+        return ([line.strip()[:160] for line in txt.splitlines()
+                 if " scatter(" in line and "vocab.embed" in line],
+                txt.count('custom_call_target="tpu_custom_call"'))
+
+    scatters, kernels = scatters_and_kernels()
+    monkeypatch.setattr(tfm, "lookup_rows",
+                        lambda table, ids: (table[ids], table))
+    indexed, kernels_indexed = scatters_and_kernels()
+    assert indexed and not scatters, scatters
+    assert kernels == kernels_indexed > 0

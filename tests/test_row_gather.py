@@ -6,7 +6,10 @@ duplicated rows; the two `custom_vjp`s' forward and backward passes; the
 counter `moved_share` by hand; and `moe_ffn` on the kernel path against the
 same call on the `jnp` forms. The token tile is 16 here, so that a few dozen
 tokens make several grid steps; tests/test_kernels_tpu_aot.py compiles the
-kernels at the benchmark's shapes and tiles.
+kernels at the benchmark's shapes and tiles. Then the third mover,
+`lookup_rows` (the embedding's lookup): its backward pass, a sort, a one-hot
+product a chunk of 16 sorted tokens here and a gather, against the float32
+scatter-add rounded once, and its lowered text, which holds no scatter.
 """
 
 import jax
@@ -279,3 +282,115 @@ def test_moe_ffn_on_the_kernel_is_moe_ffn_on_the_jnp_forms(monkeypatch,
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         _same(g, w)
     assert float(got[1][2]) == 0.0      # no held pair was left out
+
+
+# --------------------------------------------------------------------------
+# The lookup
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    monkeypatch.setattr(rg, "LOOKUP_CHUNK", 16)
+
+
+def _zipf_like(n_tokens, n_rows, seed=0):
+    """Ids as text has them: a few by the dozen, most once or never."""
+    draws = np.random.default_rng(seed).zipf(1.3, n_tokens) - 1
+    return np.minimum(draws, n_rows - 1)
+
+
+#: (ids, rows of the table) with chunks of 16: what each case is there for
+LOOKUPS = {
+    "uniform-tokens-no-chunk-divides": (
+        np.random.default_rng(1).integers(0, 37, 100), 37),
+    "uniform-whole-chunks": (np.random.default_rng(2).integers(0, 20, 64), 20),
+    "every-id-the-same": (np.full(70, 5), 11),
+    "zipf-like-a-run-over-several-chunks": (_zipf_like(200, 50), 50),
+    "runs-end-at-the-chunks-edges": (np.repeat(np.arange(4), 16), 20),
+    "a-run-of-two-whole-chunks-between-others": (
+        np.array([1] * 8 + [3] * 48 + [4] * 5 + [9] * 19), 12),
+    "fewer-tokens-than-a-chunk": (np.array([3, 0, 3, 8, 3]), 9),
+    "ids-at-0-and-at-the-last-row": (np.array([0] * 20 + [7] * 28), 8),
+    "every-row-asked-for-once": (np.random.default_rng(3).permutation(48),
+                                 48),
+    "one-token": (np.array([2]), 4),
+}
+
+
+def _cotangent(n_tokens, width, dtype, seed=4):
+    """Quarters between -8 and 8: every partial sum is exact in float32 in
+    whatever order it is added, and few of them are a bfloat16."""
+    return (jax.random.randint(jax.random.PRNGKey(seed), (n_tokens, width),
+                               -32, 33) / 4).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(LOOKUPS))
+def test_the_lookups_backward_is_the_float32_scatter_add_rounded_once(
+        small_chunks, case, dtype):
+    ids, n_rows = LOOKUPS[case]
+    ids = jnp.asarray(ids, jnp.int32)
+    table = _rows(n_rows, 128, dtype, seed=5)
+    g = _cotangent(ids.size, 128, dtype)
+    want = jnp.zeros((n_rows, 128), jnp.float32).at[ids].add(
+        g.astype(jnp.float32)).astype(dtype)
+    # through the `custom_vjp`, the ids in two dimensions as a batch has them
+    shape = (2, ids.size // 2) if ids.size % 2 == 0 else (1, ids.size)
+    out, vjp = jax.vjp(lambda t: rg.lookup_rows(t, ids.reshape(shape))[0],
+                       table)
+    _same(out, table[ids.reshape(shape)])
+    _same(vjp(g.reshape(shape + (128,)))[0], want)
+    assert rg.slot_share(np.asarray(ids)) <= 1.0
+
+
+def test_a_later_readers_gradient_is_what_the_lookups_is_added_to(
+        small_chunks):
+    """A tied head reads the table `lookup_rows` hands back: the table's
+    gradient is the head's plus the lookup's, each as plain indexing and a
+    plain product give them."""
+    ids = jnp.asarray(LOOKUPS["zipf-like-a-run-over-several-chunks"][0],
+                      jnp.int32)
+    table = _rows(50, 128, jnp.float32, seed=5)
+    g = _cotangent(ids.size, 128, jnp.float32)
+
+    def tied(lookup):
+        def loss(t):
+            rows, again = lookup(t, ids)
+            return jnp.sum(rows * g) + jnp.sum((rows @ again.T) ** 2)
+        return jax.grad(loss)(table)
+
+    np.testing.assert_allclose(
+        np.asarray(tied(rg.lookup_rows)),
+        np.asarray(tied(lambda t, i: (t[i], t))), rtol=1e-5, atol=1e-3)
+
+
+def test_the_lookups_backward_reads_odd_ids_as_the_lookup_does(small_chunks):
+    """A negative id counts from the table's end, as in `table[ids]`; one
+    past either end adds nothing."""
+    n_rows = 10
+    ids = jnp.asarray([-1, 3, -10, 9, 10, -11, 3, 25, 0, -4] * 3, jnp.int32)
+    g = _cotangent(ids.size, 128, jnp.float32)
+    table = _rows(n_rows, 128, jnp.float32)
+    want = jax.vjp(lambda t: t[ids], table)[1](g)[0]
+    _same(jax.vjp(lambda t: rg.lookup_rows(t, ids)[0], table)[1](g)[0], want)
+
+
+@pytest.mark.parametrize("ids,share", [
+    (np.arange(64), 1.0), (np.zeros(64, int), 4 / 64),
+    (np.repeat(np.arange(8), 8), 8 / 64), (np.array([5, 5, 7]), 2 / 3)])
+def test_slot_share_by_hand(ids, share):
+    assert rg.slot_share(ids, chunk=16) == share
+
+
+def test_the_lookups_backward_lowers_to_no_scatter(small_chunks):
+    """Plain indexing's does, so the search would find one."""
+    table = jax.ShapeDtypeStruct((37, 128), jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((2, 50), jnp.int32)
+
+    def lowered(lookup):
+        return jax.jit(jax.grad(lambda t, i: lookup(t, i)[0].astype(
+            jnp.float32).sum())).lower(table, ids).as_text()
+
+    assert "scatter" in lowered(lambda t, i: (t[i], t))
+    assert "scatter" not in lowered(rg.lookup_rows)
