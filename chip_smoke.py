@@ -55,6 +55,7 @@ from horovod_tpu.ops.flash_attention import (causal_tile_share,
                                              flash_attention,
                                              grid_step_share,
                                              masked_attention_reference,
+                                             row_strip_share,
                                              window_tile_share)
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from horovod_tpu.parallel.moe import held_rows
@@ -341,7 +342,9 @@ def _flash_against_reference(widths) -> None:
         f"causal_tile_share {causal_tile_share(shape[2]):.4f} (score "
         "entries computed over the causal half's), grid_step_share "
         f"{grid_step_share(shape[2]):.4f} (grid steps a head runs over the "
-        "steps that compute); "
+        "steps that compute), row_strip_share "
+        f"{row_strip_share(shape[2]):.4f} (the forward's score entries in "
+        "strips of 256 rows or fewer); "
         f"interpret={_pallas.interpret()}, {n_kernels} tpu_custom_call "
         "in the compiled program; the kernels alone (information only), "
         "ms an execution: "
@@ -943,6 +946,7 @@ def windowed_grouped_flash(log: CompileLog, shape=(1, 20, 10, 8192, 64, 128),
             (f"window {banded}: window_tile_share {share:.4f}" if banded else
              f"no window: causal_tile_share {share:.4f}")
             + f", grid_step_share {grid_step_share(seq, banded):.4f}"
+            + f", row_strip_share {row_strip_share(seq, banded):.4f}"
             + ", tpu_custom_call in the compiled "
             + ", ".join(f"{name} {n}" for name, n in kernels.items())
             + f", the first {checked} tokens from the mask written out "

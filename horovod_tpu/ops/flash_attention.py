@@ -11,19 +11,22 @@ attention ops); the kernel follows the standard FlashAttention-2
 recurrence. Row statistics ride in lane-replicated (block_q, 128) buffers
 to satisfy the TPU's (8, 128) tiling (same convention as stock Pallas TPU
 kernels). A causal kernel multiplies only what the causal half holds:
-blocks under the diagonal run unmasked, and a block on it is walked in row
-strips that stop at the diagonal's tile (`_for_each_strip`; docs/kernels.md
-has the timings that chose the form). With a `window` a query sees the
-`window` keys that end with its own: a block the band's lower edge crosses
-is walked in the same strips, each cut to the tiles the band holds of it
-(`_windowed_strips`). Blocks above the diagonal or wholly under the band
-are no grid steps at all: a kernel's grid is (heads, the block pairs the
-mask holds), a query block's key blocks one after the other (dk/dv: a key
-block's query blocks), and a step finds its pair from static tables
-(`_Walk`). Keys and values may have fewer heads than the queries (`group`
-query heads read one K/V head): the K/V blocks are found by the index map
-`head // group`, and the dk/dv kernel walks a group's query heads in turn
-and sums them.
+blocks under the diagonal run unmasked (in the forward kernel in strips of
+256 rows, so that a strip's product need not wait for the row maxima of the
+strip before it: `_whole_strips`), and a block on it is walked in row
+strips that stop at the diagonal's tile (`_walk_strips`; the forward
+kernel writes the next such strip's product ahead of a strip's softmax;
+docs/kernels.md has the timings that chose the forms). With a `window` a
+query sees the `window` keys that end with its own: a block the band's
+lower edge crosses is walked in the same strips, each cut to the tiles the
+band holds of it (`_windowed_strips`). Blocks above the diagonal or wholly
+under the band are no grid steps at all: a kernel's grid is (heads, the
+block pairs the mask holds), a query block's key blocks one after the other
+(dk/dv: a key block's query blocks), and a step finds its pair from static
+tables (`_Walk`). Keys and values may have fewer heads than the queries
+(`group` query heads read one K/V head): the K/V blocks are found by the
+index map `head // group`, and the dk/dv kernel walks a group's query heads
+in turn and sums them.
 Numerics are validated against
 `parallel.ring_attention.blockwise_attention_reference` (forward AND
 gradients) in tests/test_flash_attention.py.
@@ -109,33 +112,65 @@ def _windowed_strips(block_q: int, block_k: int, window: int, d: int,
     return strips
 
 
-def _for_each_strip(step, *, causal, iq, ik, block_q, block_k, window=None):
-    """Run `step(row0, rows, cols, masked[, col0])` over what attention
-    holds of block (iq, ik), one of those `held_blocks` lists: all of it,
-    unmasked, when not causal or wholly under the diagonal; `_crossed_strips`,
-    masked, when the diagonal crosses it. With a `window` (causal, square
-    blocks): `_windowed_strips` of a block `iq - ik` before the diagonal's."""
+#: Rows of a strip of a block no mask enters, in the forward kernel: of 512,
+#: 256 and 128 the fastest at every shape timed (docs/kernels.md, PR 49).
+_WHOLE_STRIP_ROWS = 256
+
+
+def _whole_strips(block_q: int, block_k: int,
+                  rows: int = _WHOLE_STRIP_ROWS):
+    """A block no mask enters (wholly under the diagonal, or not causal) as
+    the forward kernel walks it: static (row0, rows, cols) strips of `rows`
+    rows against all its columns. No row's maximum is known before its whole
+    product is out of the MXU and no `exp` starts before that; in strips,
+    one strip's product runs beside the `exp` of the one before. A block
+    `rows` does not divide is one strip."""
+    if block_q % rows:
+        rows = block_q
+    return [(row0, rows, block_k) for row0 in range(0, block_q, rows)]
+
+
+def _walk_strips(run, *, causal, iq, ik, block_q, block_k, window=None,
+                 whole=None):
+    """Hand `run` what attention holds of block (iq, ik), one of those
+    `held_blocks` lists, as a static list of (row0, rows, cols, masked, col0)
+    strips, under the condition that the block is of that kind: all of it,
+    unmasked, when not causal or wholly under the diagonal (as one strip, or
+    as the strips `whole` lists: the forward kernel's `_whole_strips`);
+    `_crossed_strips`, masked, when the diagonal crosses it. With a `window`
+    (causal, square blocks): `_windowed_strips` of a block `iq - ik` before
+    the diagonal's."""
     if window is not None:
         for d in range(window_back(block_q, window) + 1):
-            def _at_distance(d=d):
-                for row0, rows, col0, cols, masked in _windowed_strips(
-                        block_q, block_k, window, d):
-                    step(row0, rows, cols, masked, col0)
-            pl.when(iq - ik == d)(_at_distance)
+            pl.when(iq - ik == d)(lambda d=d: run(
+                [(row0, rows, cols, masked, col0)
+                 for row0, rows, col0, cols, masked in _windowed_strips(
+                     block_q, block_k, window, d)]))
         return
+
+    def _whole():
+        run([(row0, rows, cols, False, 0)
+             for row0, rows, cols in whole or [(0, block_q, block_k)]])
+
     if not causal:
-        step(0, block_q, block_k, False)
+        _whole()
         return
     under = ik * block_k + block_k - 1 <= iq * block_q
+    pl.when(under)(_whole)
+    pl.when(jnp.logical_not(under))(lambda: run(
+        [(row0, rows, cols, True, 0)
+         for row0, rows, cols in _crossed_strips(block_q, block_k)]))
 
-    @pl.when(under)
-    def _under():
-        step(0, block_q, block_k, False)
 
-    @pl.when(jnp.logical_not(under))
-    def _crossed():
-        for row0, rows, cols in _crossed_strips(block_q, block_k):
-            step(row0, rows, cols, True)
+def _for_each_strip(step, **block):
+    """`_walk_strips`, one strip after the other: run
+    `step(row0, rows, cols, masked, col0)` over what attention holds of the
+    block."""
+    def run(strips):
+        for strip in strips:
+            step(*strip)
+
+    _walk_strips(run, **block)
 
 
 def held_blocks(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
@@ -289,13 +324,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def step(row0, rows, cols, masked, col0=0):
-        """Online-softmax update of the strip's rows. m and l stay
-        lane-replicated as values too: sliced to a column and broadcast
-        again, each update cost more than the tiles a strip skips."""
+    scores = functools.partial(_scores, q_ref, k_ref, scale=scale, **block)
+
+    def update(s, row0, rows, cols, masked, col0):
+        """Online-softmax update of the strip's rows by its scores s. m and
+        l stay lane-replicated as values too: sliced to a column and
+        broadcast again, each update cost more than the tiles a strip
+        skips."""
         r = slice(row0, row0 + rows)
-        s = _scores(q_ref, k_ref, row0, rows, cols, masked, col0,
-                    scale=scale, **block)              # (rows, cols)
         v = v_ref[0, col0:col0 + cols, :].astype(jnp.float32)
         m_prev = m_ref[r, :]                           # (rows, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -308,7 +344,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32))
 
-    _for_each_strip(step, causal=causal, **block)
+    def run(strips):
+        """A strip's softmax waits for its whole q·kᵀ (no row's maximum is
+        known before), so the MXU and the vector unit take turns unless the
+        next strip's product is there to run beside it. Strips of one shape
+        (a block no mask enters) the scheduler overlaps by itself; those of
+        a block the diagonal or the band's edge crosses, each of another
+        width, it does not, so there strip i + 1's product is written ahead
+        of strip i's softmax (docs/kernels.md, PR 49: ahead everywhere lost
+        at S = 16,384)."""
+        if not any(masked for *_, masked, _ in strips):
+            for strip in strips:
+                update(scores(*strip), *strip)
+            return
+        s = scores(*strips[0])
+        for strip, then in zip(strips, strips[1:] + [None]):
+            s_then = scores(*then) if then else None
+            update(s, *strip)
+            s = s_then
+
+    _walk_strips(run, causal=causal, **block,
+                 whole=_whole_strips(block_q, block_k))
 
     @pl.when(last)
     def _finish():
@@ -667,6 +723,36 @@ def grid_step_share(S: int, window: Optional[int] = None,
     computing = sum(min(i, back) + 1 for i in range(n))
     return _Walk(held_blocks(n, n, block, block, True, window)).steps \
         / computing
+
+
+def row_strip_share(S: int, window: Optional[int] = None,
+                    block: Optional[int] = None,
+                    whole_rows: int = _WHOLE_STRIP_ROWS) -> float:
+    """Score entries a head of the causal forward kernel computes in strips
+    of at most `_WHOLE_STRIP_ROWS` rows over all it computes (1.0: no `exp`
+    waits for the product of a longer strip). `whole_rows` is the strip of
+    the blocks under the diagonal: the kernel's own by default; walked whole
+    (`whole_rows=block`, as until PR 49) they left 0.56 at S = 2,048, 0.29 at
+    4,096 and 0.077 at 16,384 in strips. The default block is the kernels'
+    own."""
+    block = block or _auto_block(S)
+    if window is not None and window >= S:
+        window = None
+    n = S // block
+    entries = {True: 0, False: 0}
+    for iq, (lo, _) in enumerate(held_blocks(n, n, block, block, True,
+                                             window)):
+        for d in range(iq - lo + 1):
+            if window is not None:
+                strips = [(rows, cols) for _, rows, _, cols, _ in
+                          _windowed_strips(block, block, window, d)]
+            else:
+                strips = [(rows, cols) for _, rows, cols in (
+                    _whole_strips(block, block, whole_rows) if d
+                    else _crossed_strips(block, block))]
+            for rows, cols in strips:
+                entries[rows <= _WHOLE_STRIP_ROWS] += rows * cols
+    return entries[True] / (entries[True] + entries[False])
 
 
 def masked_attention_reference(q, k, v, causal: bool = True,

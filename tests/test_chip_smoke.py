@@ -73,6 +73,25 @@ def test_phase_flash_kernel(smoke, capsys):
     assert "causal_tile_share 2.0000" in out
     # and no grid step that computes nothing, at either shape
     assert out.count("grid_step_share 1.0000 (grid steps a head runs") == 2
+    # one block of 64 rows: its one strip is no longer than 256
+    assert out.count("row_strip_share 1.0000 (the forward's score entries "
+                     "in strips of 256 rows or fewer)") == 2
+
+
+@one_chip
+def test_phase_flash_kernel_at_two_blocks_walks_the_whole_one_in_strips(
+        smoke, capsys):
+    """Two blocks of 1,024: the one under the diagonal is a whole block,
+    which the forward walks in four strips of 256 rows (`row_strip_share`
+    1.0, PR 49; 0.5556 while it was one strip), and the two on the diagonal
+    compute 9/8 of the causal half."""
+    chip_smoke.flash_kernel(smoke, shape=(1, 1, 2048, 8),
+                            wide=(1, 1, 2048, 16, 8))
+    out = capsys.readouterr().out
+    assert out.count("causal_tile_share 1.1250") == 2
+    assert out.count("grid_step_share 1.0000 (grid steps a head runs") == 2
+    assert out.count("row_strip_share 1.0000 (the forward's score entries "
+                     "in strips of 256 rows or fewer)") == 2
 
 
 @one_chip
@@ -187,7 +206,8 @@ def test_phase_windowed_grouped_flash(smoke, capsys):
         "8 | 16, bf16, interpret=True, 0 recompiles after a first call; " \
         "window 24: window_tile_share " in out
     assert "; no window: causal_tile_share " in out
-    assert out.count(", grid_step_share 1.0000, tpu_custom_call") == 2
+    assert out.count(", grid_step_share 1.0000, row_strip_share 1.0000, "
+                     "tpu_custom_call") == 2
     assert out.count("forward 0, forward + backward 0") == 2
     assert out.count(" dq ") == 2 and out.count(" dv ") == 2
 

@@ -86,6 +86,11 @@ def test_flash_smaller_blocks():
 #   tile-divides     S=512: one block, 256-tiles
 #   small-tile       S=256: one block, the smaller tile (128)
 #   no-tile-100/640  blocks no tile divides: one masked product
+#   no-tile-192      one block of a lane tile and a half: one masked product
+#   under-in-strips  S=1536 in 512 blocks: the three blocks under the diagonal
+#                    walked in two 256-row strips each by the forward kernel,
+#                    whole by the backward kernels
+#   not-causal-strips  the same blocks with no mask: every block in strips
 #   not-square       block_q != block_k: every crossed block one masked product
 #   non-causal       one unmasked product a block
 #   chunk-dlse       flash_attention_chunk, causal, with an lse cotangent
@@ -96,6 +101,9 @@ _PATHS = {
     "small-tile": dict(S=256),
     "no-tile-100": dict(S=100),
     "no-tile-640": dict(S=640),
+    "no-tile-192": dict(S=192),
+    "under-in-strips": dict(S=1536, block_q=512, block_k=512),
+    "not-causal-strips": dict(S=1024, block_q=512, block_k=512, causal=False),
     "not-square": dict(S=512, block_q=256, block_k=128),
     "non-causal": dict(S=512, causal=False),
     "chunk-dlse": dict(S=512, chunk=True),
@@ -468,3 +476,158 @@ def test_three_and_more_blocks_a_side_match_the_mask_written_out(case, what):
     assert got[at].shape == want[at].shape
     np.testing.assert_allclose(np.asarray(got[at]), np.asarray(want[at]),
                                rtol=2e-4, atol=2e-5)
+
+
+# ------------------------ whole blocks in row strips (PR 49, `_fwd_kernel`)
+
+#: (S, block, window, query heads, K/V heads, keys' width, values' width):
+#: blocks of 512 (a block under the diagonal is two strips of 256 rows in
+#: the forward kernel) and of 256 (one), three and four blocks a side; a
+#: window whose lower edge crosses a block; one query head a K/V head and
+#: seven; the cells' three pairs of widths
+_STRIPS = {
+    "g7-128-128": (1536, 512, None, 7, 1, 128, 128),
+    "g1-128-128-w300": (768, 256, 300, 2, 2, 128, 128),
+    "g1-192-128": (1536, 512, None, 2, 2, 192, 128),
+    "g7-192-128-w500": (1024, 256, 500, 7, 1, 192, 128),
+    "g7-64-128": (2048, 512, None, 7, 1, 64, 128),
+    "g1-64-128-w700": (1536, 512, 700, 2, 2, 64, 128),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _strips_case(case):
+    """Scores built so that every strip's update matters: key j's score is
+    raised by 8 for each block before its own, so a row's maximum rises in
+    every key block and last in its LAST one (the diagonal's), where all
+    that the strips before have summed is rescaled by a small alpha; and by
+    6 more in ONE lane tile of its block (another one each block), which
+    then carries e^6 of a strip's sum against 1 for each of the others."""
+    from horovod_tpu.ops import flash_attention as fa
+    S, block, window, H, G, dk, dv = _STRIPS[case]
+    ks = jax.random.split(jax.random.PRNGKey(49), 4)
+    q = jax.random.normal(ks[0], (1, H, S, dk), jnp.float32)
+    k = jax.random.normal(ks[1], (1, G, S, dk), jnp.float32)
+    v = jax.random.normal(ks[2], (1, G, S, dv), jnp.float32)
+    w = jax.random.normal(ks[3], (1, H, S, dv), jnp.float32)
+    col = jnp.arange(S)
+    tiles = block // 128
+    raised = 8.0 * (col // block) + 6.0 * (
+        col % block // 128 == col // block % tiles)
+    q = q.at[..., 0].set(dk ** 0.5)         # times scale: 1 a unit of k[0]
+    k = k.at[..., 0].set(raised)
+
+    def ours(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  block_q=block, block_k=block)
+
+    def theirs(q, k, v):
+        return _written_out(q, k, v, True, window)[0]
+
+    o, lse = fa._fwd(q[0], k[0], v[0], True, dk ** -0.5, block, block,
+                     window)
+    want_o, want_lse = _written_out(q, k, v, True, window)
+    got, want = [o[None], lse[None, ..., 0]], [want_o, want_lse]
+    for f, into in ((ours, got), (theirs, want)):
+        into.extend(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                             argnums=(0, 1, 2))(q, k, v))
+    return got, want
+
+
+@pytest.mark.parametrize("what", ["o", "lse", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", list(_STRIPS))
+def test_whole_blocks_in_strips_match_the_mask_written_out(case, what):
+    got, want = _strips_case(case)
+    at = "o lse dq dk dv".split().index(what)
+    assert got[at].shape == want[at].shape
+    np.testing.assert_allclose(np.asarray(got[at]), np.asarray(want[at]),
+                               rtol=2e-4, atol=2e-5)
+
+
+def test_whole_strips_cover_a_block_exactly_once():
+    """The strips of a block no mask enters hold each of its entries once,
+    256 rows a strip against all its columns where 256 divides the block's
+    rows, else the block as one strip."""
+    from horovod_tpu.ops.flash_attention import _whole_strips
+    for bq, bk in ((1024, 1024), (512, 512), (256, 256), (128, 128),
+                   (640, 640), (100, 100), (512, 128), (128, 512),
+                   (768, 256)):
+        seen = np.zeros((bq, bk), np.int32)
+        strips = _whole_strips(bq, bk)
+        for row0, rows, cols in strips:
+            assert cols == bk
+            seen[row0:row0 + rows, :cols] += 1
+        assert (seen == 1).all(), (bq, bk)
+        assert len(strips) == (bq // 256 if bq % 256 == 0 else 1), (bq, bk)
+    # the strip the timings did not choose, for `row_strip_share`'s parent
+    assert _whole_strips(1024, 1024, 1024) == [(0, 1024, 1024)]
+    assert len(_whole_strips(1024, 1024, 512)) == 2
+
+
+def test_only_the_forward_kernel_walks_whole_blocks_in_strips(monkeypatch):
+    """The backward kernels take a block under the diagonal as one strip, as
+    before PR 49: their steps are counted here by the walk they share."""
+    from horovod_tpu.ops import flash_attention as fa
+    calls = []
+    walk = fa._walk_strips
+
+    def counting(run, **kw):
+        calls.append(kw.get("whole"))
+        return walk(run, **kw)
+
+    monkeypatch.setattr(fa, "_walk_strips", counting)
+    q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=1, S=1024, dh=8)
+    jax.grad(lambda q: jnp.sum(fa.flash_attention(
+        q, k, v, causal=True, block_q=512, block_k=512)))(q)
+    assert calls.count(None) == 2               # dk/dv and dq
+    assert [w for w in calls if w] == [[(0, 256, 512), (256, 256, 512)]]
+
+
+def test_the_forward_writes_the_next_product_ahead_only_where_a_mask_enters(
+        monkeypatch):
+    """Order of the forward kernel's body as it is traced: a whole block's
+    strips one after the other (product, softmax, product, softmax), a
+    crossed block's with strip i + 1's product in front of strip i's
+    softmax. An update is seen by its two `_lanes` calls."""
+    from horovod_tpu.ops import flash_attention as fa
+    events = []
+    scores, lanes = fa._scores, fa._lanes
+
+    def seen_scores(q_ref, k_ref, row0, rows, cols, masked, *a, **kw):
+        events.append(("masked" if masked else "whole", row0))
+        return scores(q_ref, k_ref, row0, rows, cols, masked, *a, **kw)
+
+    def seen_lanes(x, n):
+        events.append("softmax")
+        return lanes(x, n)
+
+    monkeypatch.setattr(fa, "_scores", seen_scores)
+    monkeypatch.setattr(fa, "_lanes", seen_lanes)
+    q, k, v = _qkv(jax.random.PRNGKey(7), B=1, H=1, S=1024, dh=8)
+    fa._fwd(q[0], k[0], v[0], True, 1.0, 512, 512)
+    assert events == [
+        ("whole", 0), "softmax", "softmax",
+        ("whole", 256), "softmax", "softmax",
+        ("masked", 0), ("masked", 256), "softmax", "softmax",
+        "softmax", "softmax"]
+
+
+def test_row_strip_share_at_the_cells_shapes():
+    """Every score entry of the forward is computed in a strip of 256 rows
+    or fewer at the cells' shapes; with the blocks under the diagonal whole
+    (until PR 49) only the diagonal's blocks were: 10 tiles of 256² a
+    diagonal block over those and the 1,024² blocks under it."""
+    from horovod_tpu.ops.flash_attention import row_strip_share
+    for S, window in ((2048, None), (4096, None), (8192, None),
+                      (8192, 512), (16384, None), (16384, 4096)):
+        assert row_strip_share(S, window) == 1.0
+        under = 0 if window else (S // 1024) * (S // 1024 - 1) // 2
+        diagonal = (S // 1024) * 10 * 256 * 256
+        if not window:
+            assert row_strip_share(S, window, whole_rows=1024) == \
+                pytest.approx(diagonal / (diagonal + under * 1024 * 1024))
+    assert row_strip_share(16384, whole_rows=1024) == pytest.approx(1 / 13)
+    # a window's blocks were walked in 256-row strips before
+    assert row_strip_share(8192, 512, whole_rows=1024) == 1.0
+    # one block: all of it on the diagonal, in tiles or as one short strip
+    assert row_strip_share(1024) == row_strip_share(100) == 1.0

@@ -31,6 +31,10 @@ multipliers, whose defaults leave the six older texts as they were. PR 47
 took every entry anew: the embedding's lookup is `ops/row_gather.py`
 `lookup_rows`, whose backward pass is a sort, a batched product and a gather
 where the gather's transpose was a scatter-add, in every model's step.
+PR 49 (the flash forward walks a whole block in strips of 256 rows and writes
+a masked block's next product ahead of a strip's softmax) left every entry
+as it was: these models' 32 tokens are one block of 32 rows, which no strip
+divides and whose one strip has none after it, so nothing was taken anew.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
