@@ -38,7 +38,8 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
-from benchmark.families import granite_hybrid, olmo_hybrid, phi4_flash
+from benchmark.families import (granite_hybrid, kimi_linear, olmo_hybrid,
+                                phi4_flash)
 from benchmark.harness import peaks
 from benchmark.layer_metrics import gdn_scan_roofline
 from horovod_tpu import native
@@ -455,14 +456,19 @@ def _timed_without_recompiles(log: CompileLog, runs: dict, what: str,
 
 
 def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
-                     checked=256) -> None:
+                     checked=256, per_channel=False) -> None:
     """The gated delta rule's kernels (ops/gated_delta.py) alone at
     `olmohybrid-1chip`'s (batch, heads, tokens, key width, value width):
     its first `checked` tokens against the token-by-token recurrence, the
     Mosaic kernels the compiled forward and forward + backward hold (on the
     TPU: one, and two), then their times against the least time the
-    benchmark's `gdn_scan_roofline` counts (the family's `rule_work`)."""
+    benchmark's `gdn_scan_roofline` counts (the family's `rule_work`). With
+    `per_channel` the form with a decay a key channel (`kda_scan`)."""
     batch, heads, seq, dk, dv = shape
+    tag = "delta rule, a decay a channel" if per_channel \
+        else "gated delta rule"
+    family = kimi_linear if per_channel else olmo_hybrid
+    wide = (dk,) if per_channel else ()
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     bf16 = jnp.bfloat16
 
@@ -478,20 +484,22 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
     beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[3], (batch, heads, seq)))
     # decays as the model's initialisation draws them: A ~ U(0, 16) a head,
     # a step of 0.001 to 0.1 a token
-    rate = jax.random.uniform(ks[4], (1, heads, 1), minval=1e-3, maxval=16.0)
+    rate = jax.random.uniform(ks[4], (1, heads, 1) + (1,) * len(wide),
+                              minval=1e-3, maxval=16.0)
     g = -rate * jnp.exp(jax.random.uniform(
-        ks[5], (batch, heads, seq), minval=np.log(1e-3), maxval=np.log(0.1)))
+        ks[5], (batch, heads, seq) + wide, minval=np.log(1e-3),
+        maxval=np.log(0.1)))
     args = (q, k, v, g, beta)
     head = tuple(x[:, :, :checked] for x in args)
     got = np.asarray(jax.jit(gd.gated_delta_rule)(*head).astype(jnp.float32))
     want = np.asarray(jax.jit(gd.recurrent_gated_delta_rule)(
         *(x.astype(jnp.float32) for x in head)))
     if not np.all(np.isfinite(got)):
-        raise AssertionError("gated delta rule: non-finite output")
+        raise AssertionError(f"{tag}: non-finite output")
     err = _rms_off(got, want)
     if err > BF16_RTOL:
         raise AssertionError(
-            f"the chunked gated delta rule is {err:.3g} of its rms from "
+            f"the chunked {tag} is {err:.3g} of its rms from "
             f"the recurrence over {checked} tokens (tolerance "
             f"{BF16_RTOL:.3g})")
     cot = jax.random.normal(ks[0], v.shape, bf16)
@@ -504,27 +512,27 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                         args),
             "forward + backward": (jax.jit(both).lower(*args).compile(),
                                    args)}
-    kernels = _counted(runs, {"forward": 1, "forward + backward": 2},
-                       "gated delta rule")
+    kernels = _counted(runs, {"forward": 1, "forward + backward": 2}, tag)
     ms = _best_ms(runs, repeats=5)
     shares = ""
     if on_tpu():   # the shares are of the benchmark's table of peaks
         p = peaks.for_kind(jax.devices()[0].device_kind)
         fwd, bwd = (gdn_scan_roofline.least_seconds((1, *work), p)[0] * 1e3
-                    for work in olmo_hybrid.rule_work(batch * seq * heads,
-                                                      dk, dv))
+                    for work in family.rule_work(batch * seq * heads, dk,
+                                                 dv))
         shares = (f"; least time forward {fwd:.3f} ms "
                   f"({100 * fwd / ms['forward']:.2f}% of it), forward + "
                   f"backward {fwd + bwd:.3f} ms "
                   f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
-    a_step = gd.heads_a_step(heads, dk, dv)
-    say(f"[gated delta rule] {batch} x {seq} tokens x {heads} heads, "
+    a_step = gd.heads_a_step(heads, dk, dv, per_channel=per_channel)
+    say(f"[{tag}] {batch} x {seq} tokens x {heads} heads, "
         f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "
         f"{gd.chunks_of(seq)} chunks a sequence, the chunked form's "
         "multiply-adds "
         f"{gd.chunked_over_recurrent_macs(dk, dv):.2f} x the recurrent "
         f"form's; {a_step} heads a grid step "
-        f"({a_step * gd.step_bytes(dk, dv) / 2 ** 20:.2f} MiB of VMEM asked "
+        f"({a_step * gd.step_bytes(dk, dv, per_channel=per_channel) / 2 ** 20:.2f} "
+        "MiB of VMEM asked "
         "for its blocks and state); interpret="
         f"{_pallas.interpret()}, tpu_custom_call in the compiled "
         + ", ".join(f"{name} {n}" for name, n in kernels.items())
@@ -532,6 +540,15 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
         "from the token-by-token recurrence; alone (information only), ms "
         "an execution: "
         + ", ".join(f"{name} {t:.3f}" for name, t in ms.items()) + shares)
+
+
+def kda_scan(log: CompileLog, shape=(1, 32, 16384, 128, 128),
+             checked=256) -> None:
+    """`gated_delta_scan` with a decay per key channel, at
+    `kimilinear-1chip`'s shape: the kernels of Kimi Delta Attention's rule
+    against the recurrence, and their times against the least time
+    `kda_scan_roofline` counts."""
+    gated_delta_scan(log, shape, checked, per_channel=True)
 
 
 def causal_conv_pass(log: CompileLog, shape=(1, 30, 8192),
@@ -1174,7 +1191,7 @@ def single_controller_lm(log: CompileLog, cfg=FLAGSHIP, batch: int = 12,
 #: it is compared with.
 PHASES = {
     1: ((), (eager_api, flash_kernel, grouped_kernel, gated_delta_scan,
-             causal_conv_pass, selective_scan_pass, ssd_scan_pass,
+             kda_scan, causal_conv_pass, selective_scan_pass, ssd_scan_pass,
              windowed_grouped_flash, row_sum_pass, embed_grad_pass,
              flagship_lm, resnet50_eager)),
     4: ((launcher_one_process_per_chip,), (single_controller_lm,)),

@@ -1,9 +1,9 @@
 """The FFNs of a layer of `models/transformer.py`, each in one place: the
 leaves it has under a configuration, its `apply`, and what it refuses of a
 configuration and a mesh. `FFNS` holds them by `kind(cfg)`: routed experts
-with their router (and the shared experts beside them) where the layer has
-experts, else a dense MLP, gated (SiLU or ReLU, no biases) or GELU with
-biases.
+with their router (softmax or sigmoid scores, a selection bias) and the
+shared experts beside them where the layer has experts, else a dense MLP,
+gated (SiLU or ReLU, no biases) or GELU with biases.
 
 An `apply` takes (h: the normed post-attention state (B, S_loc, D), lp: the
 layer's leaves, cfg, arrived: the layer's input as it arrived, stacked: (the
@@ -93,6 +93,9 @@ def _expert_leaves(cfg) -> Dict[str, Leaf]:
               down: Leaf((held, F, D), fan_in("k", 6, F), over_ep)}
     if cfg.gate:
         leaves[gate] = Leaf((held, D, F), fan_in("k", 10, D), over_ep)
+    if cfg.router_bias:
+        # chooses and never weighs: no gradient reaches it (`moe.route`)
+        leaves["router_bias"] = Leaf((cfg.num_experts,), zeros)
     if cfg.shared_experts:
         leaves.update(_shared_leaves(cfg))
     return leaves
@@ -106,11 +109,14 @@ def _experts(h, lp: Dict[str, Any], cfg, arrived, stacked):
         *(lp.get(k) for k in EXPERT_LEAVES),
         top_k=cfg.experts_per_token, axis_name="ep",
         capacity_factor=cfg.capacity_factor,
+        held_factor=cfg.capacity_factor,
         first_expert=cfg.first_expert,
         sequences=B if cfg.balance_per_sequence else 0,
         router_input=arrived.reshape(B * S, D)
         if cfg.router_input == "layer" else None,
         renormalise=cfg.norm_topk, gate=cfg.gate or "silu",
+        scoring=cfg.router_scoring, selection_bias=lp.get("router_bias"),
+        weight_scale=cfg.routed_scale,
         stacks=tuple(stacks.get(k) for k in EXPERT_LEAVES), layer=layer)
     f = out.reshape(B, S, D)
     if cfg.shared_experts:
@@ -124,6 +130,9 @@ def _expert_checks(cfg, ax):
     return [
         (cfg.router_input in ("mlp", "layer"),
          f"router_input={cfg.router_input!r}: choose 'mlp' or 'layer'"),
+        (cfg.router_scoring in moe_mod.SCORINGS,
+         f"router_scoring={cfg.router_scoring!r}: choose from "
+         f"{sorted(moe_mod.SCORINGS)}"),
         (cfg.router_input == "mlp" or not cfg.post_norm,
          "router_input='layer' with post_norm (the layer's input is the "
          "attention's too)"),

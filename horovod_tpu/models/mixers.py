@@ -1,9 +1,9 @@
 """The token mixers of a layer of `models/transformer.py`, each in one place:
 the leaves it has under a configuration, its `apply`, and what it refuses of
 a configuration and a mesh. `MIXERS` holds them by the `attention` a layer's
-configuration states ("mha", "mla", "gdn" of `TransformerConfig`, and what
-the kinds of `transformer.LAYER_KINDS` make of it: "ssm", "mamba2", "gmu",
-"cross").
+configuration states ("mha", "mla", "gdn", "kda" of `TransformerConfig`, and
+what the kinds of `transformer.LAYER_KINDS` make of it: "ssm", "mamba2",
+"gmu", "cross").
 
 An `apply` takes (h: the normed residual (B, S_loc, D), lp: the layer's
 leaves, cfg, rope: the rotation's (cos, sin) or None, shared: what an earlier
@@ -21,7 +21,7 @@ from jax import lax
 
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models.leaves import (
-    Leaf, Part, fan_in, normal, ones, step_bias)
+    Leaf, Part, fan_in, normal, ones, step_bias, zeros)
 from horovod_tpu.parallel import ulysses as ulysses_mod
 from horovod_tpu.parallel.ring_attention import (
     blockwise_attention_reference, ring_attention)
@@ -268,11 +268,10 @@ def _mla_leaves(cfg) -> Dict[str, Leaf]:
 
 
 def _mla(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
-    """On the normed residual h: (B, S_loc, D): the rotary key is one per
-    token, shared by the heads."""
-    if rope_ is None:
-        raise HorovodTpuError("attention='mla' has a rotary part of its "
-                              "keys: it needs positions='rope'")
+    """On the normed residual h: (B, S_loc, D): the keys' second part is one
+    per token, shared by the heads; it and the queries' second part are
+    rotated, or with `rope_` None (a NoPE layer: a kind `cfg.unrotated`
+    names, or positions "none") left as they are projected."""
     nope, latent = cfg.qk_nope_dim, cfg.kv_latent
     with jax.named_scope("mla.project"):
         q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"])
@@ -281,10 +280,12 @@ def _mla(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
         kv = jnp.einsum("bsc,chk->bhsk", c, lp["wkv_b"])
         k_nope, v = kv[..., :nope], kv[..., nope:]
     with jax.named_scope("mla.rope"):
-        # one rotary key a token, shared by all heads
-        k_pe = rope(down[:, None, :, latent:], rope_)
-        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], rope_)],
-                            axis=-1)
+        # one such key a token, shared by all heads
+        k_pe = down[:, None, :, latent:]
+        if rope_ is not None:
+            k_pe = rope(k_pe, rope_)
+            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], rope_)],
+                                axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3]
                                       + k_pe.shape[3:])], axis=-1)
@@ -301,20 +302,24 @@ def _mla_checks(cfg, ax):
          "biases"),
         (cfg.attn in ("flash", "local"),
          "attention='mla' needs attn 'flash' or 'local'"),
+        (cfg.positions != "learned",
+         "attention='mla' with positions='learned': the shared part of its "
+         "keys is rotated (positions='rope') or left as it is (a kind "
+         "`unrotated` names, or positions='none')"),
         (ax["sp"] == 1, "attention='mla' requires sp=1")]
 
 
 # ---- "gdn": a Gated DeltaNet mixer (arXiv:2412.06464)
 
+def _decay_rate(keys, shape, dtype):
+    """A ~ U(0, 16), held as its logarithm (Gated DeltaNet's own draw)."""
+    return jnp.log(jax.random.uniform(
+        keys["g"][9], shape, jnp.float32, 1e-3, 16.0)).astype(dtype)
+
+
 def _gdn_leaves(cfg) -> Dict[str, Leaf]:
     D, H, dk, dv, taps = (cfg.d_model, cfg.gdn_heads, cfg.gdn_key_dim,
                           cfg.gdn_value_dim, cfg.gdn_conv)
-
-    def decay_rate(keys, shape, dtype):
-        # A ~ U(0, 16), held as its logarithm (Gated DeltaNet's own draw)
-        return jnp.log(jax.random.uniform(
-            keys["g"][9], shape, jnp.float32, 1e-3, 16.0)).astype(dtype)
-
     return {
         "gdn_wq": Leaf((D, H, dk), fan_in("g", 0, D)),
         "gdn_wk": Leaf((D, H, dk), fan_in("g", 1, D)),
@@ -322,7 +327,7 @@ def _gdn_leaves(cfg) -> Dict[str, Leaf]:
         "gdn_wz": Leaf((D, H, dv), fan_in("g", 3, D)),
         "gdn_wa": Leaf((D, H), fan_in("g", 4, D)),
         "gdn_wb": Leaf((D, H), fan_in("g", 5, D)),
-        "gdn_a_log": Leaf((H,), decay_rate),
+        "gdn_a_log": Leaf((H,), _decay_rate),
         "gdn_dt_bias": Leaf((H,), step_bias("g", 10)),
         "gdn_conv_q": Leaf((H, dk, taps), fan_in("g", 6, taps)),
         "gdn_conv_k": Leaf((H, dk, taps), fan_in("g", 7, taps)),
@@ -374,6 +379,78 @@ def _gdn_checks(cfg, ax):
         (ax["tp"] == 1,
          "linear-attention layers require tp=1 (their heads are not "
          "sharded)")]
+
+
+# ---- "kda": Kimi Delta Attention (arXiv:2510.26692 section 3), the delta
+# ---- rule with a decay per key channel
+
+def _kda_leaves(cfg) -> Dict[str, Leaf]:
+    """"gdn"'s heads and convolutions (`gdn_heads`, `gdn_key_dim`,
+    `gdn_value_dim`, `gdn_conv`); the decay's and the output gate's
+    projections go through a rank of `kda_rank`; a_log a head, dt_bias a
+    channel, drawn as Gated DeltaNet draws them."""
+    D, H, dk, dv, taps, R = (cfg.d_model, cfg.gdn_heads, cfg.gdn_key_dim,
+                             cfg.gdn_value_dim, cfg.gdn_conv, cfg.kda_rank)
+    return {
+        "kda_wq": Leaf((D, H, dk), fan_in("g", 0, D)),
+        "kda_wk": Leaf((D, H, dk), fan_in("g", 1, D)),
+        "kda_wv": Leaf((D, H, dv), fan_in("g", 2, D)),
+        "kda_wf_down": Leaf((D, R), fan_in("g", 4, D)),
+        "kda_wf_up": Leaf((R, H, dk), fan_in("x", 0, R)),
+        "kda_wg_down": Leaf((D, R), fan_in("g", 3, D)),
+        "kda_wg_up": Leaf((R, H, dv), fan_in("x", 1, R)),
+        "kda_bg": Leaf((H, dv), zeros),
+        "kda_wb": Leaf((D, H), fan_in("g", 5, D)),
+        "kda_a_log": Leaf((H,), _decay_rate),
+        "kda_dt_bias": Leaf((H, dk), step_bias("g", 10)),
+        "kda_conv_q": Leaf((H, dk, taps), fan_in("g", 6, taps)),
+        "kda_conv_k": Leaf((H, dk, taps), fan_in("g", 7, taps)),
+        "kda_conv_v": Leaf((H, dv, taps), fan_in("g", 8, taps)),
+        "kda_o_scale": Leaf((dv,), ones),
+        **_out_projection(cfg, H, dv)}
+
+
+def _kda(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
+    """On h: (B, S, D): the delta rule of `ops/gated_delta.py` with a decay
+    per key channel on convolved, normalised queries and keys, its output
+    normed per head, gated by a sigmoid and projected."""
+    from horovod_tpu.ops.causal_conv import causal_conv_silu
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+    f32 = jnp.float32
+    with jax.named_scope("kda.project"):
+        q = jnp.einsum("bsd,dhk->bhsk", h, lp["kda_wq"])
+        k = jnp.einsum("bsd,dhk->bhsk", h, lp["kda_wk"])
+        v = jnp.einsum("bsd,dhk->bhsk", h, lp["kda_wv"])
+        low_f = jnp.einsum("bsd,dr->bsr", h, lp["kda_wf_down"])
+        low_g = jnp.einsum("bsd,dr->bsr", h, lp["kda_wg_down"])
+        f = jnp.einsum("bsr,rhk->bhsk", low_f, lp["kda_wf_up"],
+                       preferred_element_type=f32)
+        z = jnp.einsum("bsr,rhk->bhsk", low_g, lp["kda_wg_up"])
+        b = jnp.einsum("bsd,dh->bhs", h, lp["kda_wb"],
+                       preferred_element_type=f32)
+    with jax.named_scope("kda.conv"):
+        q = causal_conv_silu(q, lp["kda_conv_q"],
+                             l2_scale=cfg.gdn_key_dim ** -0.5)
+        k = causal_conv_silu(k, lp["kda_conv_k"], l2_scale=1.0)
+        v = causal_conv_silu(v, lp["kda_conv_v"])
+    with jax.named_scope("kda.scan"):
+        rate = jnp.exp(lp["kda_a_log"].astype(f32))[None, :, None, None]
+        g = -rate * jax.nn.softplus(
+            f + lp["kda_dt_bias"].astype(f32)[None, :, None, :])
+        o = gated_delta_rule(q, k, v, g, jax.nn.sigmoid(b))
+    with jax.named_scope("kda.gate"):
+        gate = jax.nn.sigmoid(z.astype(f32)
+                              + lp["kda_bg"].astype(f32)[None, :, None, :])
+        o = (rms(o, lp["kda_o_scale"], cfg.rms_norm_eps).astype(f32)
+             * gate).astype(h.dtype)
+    with jax.named_scope("kda.out"):
+        return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"]), None
+
+
+def _kda_checks(cfg, ax):
+    return [(cfg.kda_rank > 0, "'kda' layers need kda_rank > 0"),
+            (cfg.gdn_heads > 0, "'kda' layers need gdn_heads > 0")] \
+        + _gdn_checks(cfg, ax)
 
 
 # ---- "ssm": a Mamba-1 mixer (arXiv:2312.00752), and "gmu": a Gated Memory
@@ -538,6 +615,7 @@ MIXERS = {
     "cross": Part(_mha_leaves, _mha, _mha_checks),
     "mla": Part(_mla_leaves, _mla, _mla_checks),
     "gdn": Part(_gdn_leaves, _gdn, _gdn_checks),
+    "kda": Part(_kda_leaves, _kda, _kda_checks),
     "ssm": Part(_ssm_leaves, _ssm),
     "mamba2": Part(_mamba2_leaves, _mamba2, _mamba2_checks),
     "gmu": Part(_gmu_leaves, _gmu),

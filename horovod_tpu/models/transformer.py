@@ -17,9 +17,10 @@ framework supports, in one compiled XLA program per train step:
 One block, whose architecture `TransformerConfig` states, in parts: a
 layer is norm, mixer, residual, norm, FFN, residual (`_layer`). The mixers
 lie in `models/mixers.py` (`MIXERS`: plain attention with its biases,
-QK-norm, fewer key heads and the differential form; cross, latent (MLA),
-gated-delta-rule, state-space (Mamba-1 and Mamba-2) and Gated-Memory-Unit
-mixers), the FFNs in
+QK-norm, fewer key heads and the differential form; cross, latent (MLA, with
+or without a rotation), delta-rule (one decay a head, or one a key channel:
+Kimi Delta Attention), state-space (Mamba-1 and Mamba-2) and
+Gated-Memory-Unit mixers), the FFNs in
 `models/ffns.py` (`FFNS`: dense GELU, dense gated, routed experts with their
 router and shared experts), each with the leaves it has, its `apply` and
 what it refuses; a leaf is declared once (`models/leaves.py`), and `init`,
@@ -31,9 +32,12 @@ The defaults are the GPT-2 block; the other fields make it OLMoE's
 (arXiv:2409.02060), DeepSeek-V2's (arXiv:2405.04434: its `first_k_dense`
 leading dense layers are a stack of their own, `params["dense_layers"]`, in
 front of `params["layers"]`), Olmo-Hybrid's, SmallThinker's
-(arXiv:2507.20984), SambaY's (arXiv:2507.06607) and the Granite 4.0 hybrids'
+(arXiv:2507.20984), SambaY's (arXiv:2507.06607), the Granite 4.0 hybrids'
 (Mamba-2 layers, arXiv:2405.21060, to one attention layer, and four scalar
-multipliers).
+multipliers) and Kimi Linear's (arXiv:2510.26692: delta-rule layers with a
+decay per key channel to one latent-attention layer without a rotation,
+sigmoid-scored experts with a selection bias, a leading dense layer inside
+the pattern).
 
 A model whose layers are not all of one kind states one period of its
 `layer_pattern`, which the stack repeats (`LAYER_KINDS`); its parameters lie
@@ -44,7 +48,11 @@ layers states `segments`, a sequence of (pattern, periods)
 hands its last "ssm" layer's scan output on to the "gmu" layers behind it
 and the "full" layer's keys and values to the "cross" layers. A pattern is
 one segment: one runner (`_run_segments`) scans both, a period's layers in
-order in its body.
+order in its body. Leading dense layers (`first_k_dense`) under a pattern
+are the pattern's first layers, and the stack behind them starts where
+they end: the rest of that period, the whole periods, and what is left of a
+last one are then a segment each (`_pattern_segments`), and
+`params["layers"]` a list of them.
 
 Everything is static-shape, scan-based, bf16-capable — MXU/XLA-friendly.
 """
@@ -77,8 +85,8 @@ from horovod_tpu.parallel.grad_reduce import (
 from horovod_tpu.parallel.mesh import mesh_axis_sizes
 
 #: The `jax.named_scope`s of the train step outside its older mixers'
-#: (`moe.*` of `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `ssm.*`,
-#: `gmu.*` below; a Mamba-2 layer's `ssd.*` are listed):
+#: (`moe.*` of `parallel/moe.py` and `moe.shared`, `mla.*`, `gdn.*`, `kda.*`,
+#: `ssm.*`, `gmu.*` below; a Mamba-2 layer's `ssd.*` are listed):
 #: a scope reaches the compiled program as a component of an instruction's
 #: `op_name`, through `jit`, remat, the layer scan and differentiation, and a
 #: profile shows it in the op's name. The tests hold the program to this
@@ -159,8 +167,10 @@ class TransformerConfig:
     # leading layers with a dense MLP of width d_ff_dense in place of experts
     first_k_dense: int = 0
     d_ff_dense: int = 0
-    # rows one rank may send one expert, as a multiple of an even share;
-    # means something only across ranks (ep > 1): see parallel/moe.py
+    # rows one rank may send one expert, as a multiple of an even share,
+    # across ranks (ep > 1); and where one rank holds a share of the experts
+    # (`experts_held`), its row buffer as a multiple of what an even routing
+    # sends the held experts: see parallel/moe.py
     capacity_factor: float = 2.0
     # loss = cross-entropy + load_balance_coef * load balance
     #        + router_z_coef * router z-loss, each averaged over the layers
@@ -171,6 +181,13 @@ class TransformerConfig:
     balance_per_sequence: bool = False
     # a token's k expert weights divided by their sum (`norm_topk_prob`)
     norm_topk: bool = False
+    # how the router's logits become weights (`parallel/moe.py`):
+    # "softmax" over all experts, or "sigmoid" of each; with `router_bias`
+    # a leaf that is added to the scores for the choice alone and takes no
+    # gradient; `routed_scale` multiplies the k weights
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    routed_scale: float = 1.0
     # what the router scores: "mlp", the experts' own input (the normed
     # post-attention state), or "layer", the layer's input as it arrives,
     # before its first norm and attention (SmallThinker's router, "placed
@@ -191,12 +208,13 @@ class TransformerConfig:
     # n_heads * d_head need not be d_model: attn "flash" or "local"
     d_head: int = 0
     # "mha": wq, wk, wv of one head width, `head_dim`.
-    # "gdn": see gdn_heads below.
+    # "gdn", "kda": see gdn_heads below.
     # "mla": DeepSeek-V2's latent attention. Queries (qk_nope_dim +
     # qk_rope_dim) a head; one down-projection to kv_latent + qk_rope_dim a
     # token, RMSNorm on the latent, an up-projection to (qk_nope_dim +
     # v_head_dim) a head; the rotary key is one per token, shared by the
-    # heads. Keys and values then differ in width: attn "flash" or "local".
+    # heads (not rotated in a layer without positions). Keys and values
+    # then differ in width: attn "flash" or "local".
     attention: str = "mha"
     kv_latent: int = 0
     qk_nope_dim: int = 0
@@ -216,6 +234,10 @@ class TransformerConfig:
     gdn_value_dim: int = 0
     gdn_conv: int = 4
     gdn_neg_eigval: bool = False
+    # attention="kda": the same heads, widths and convolutions with a decay
+    # per key channel, whose projection and the output gate's go through a
+    # rank of kda_rank (`mixers.py`)
+    kda_rank: int = 0
     # One period of layer kinds, repeated n_layers / len(layer_pattern)
     # times; () is a stack of one kind. "full": a layer as the other fields
     # state it; "linear": the same layer with attention="gdn"; the other
@@ -308,8 +330,11 @@ class TransformerConfig:
 
     @property
     def rope_dim(self) -> int:
-        """Width of what the rotary embedding turns, per head."""
-        return self.qk_rope_dim if self.attention == "mla" else self.head_dim
+        """Width of what the rotary embedding turns, per head: the one width
+        the model's rotated layers have (`validate_cfg_for_mesh` refuses
+        two)."""
+        widths = _rope_widths(self)
+        return widths.pop() if len(widths) == 1 else self.head_dim
 
     @property
     def score_scale(self) -> Optional[float]:
@@ -333,6 +358,9 @@ def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
     layers of a model without experts whose MLP has their width."""
     if stack == "layers":
         return cfg
+    if cfg.layer_pattern:     # the pattern's first layers: of its first kind
+        cfg = dataclasses.replace(_kind_cfg(cfg, cfg.layer_pattern[0]),
+                                  layer_pattern=())
     return dataclasses.replace(
         cfg, num_experts=0, shared_experts=0, experts_held=0, first_expert=0,
         d_ff=cfg.d_ff_dense, n_layers=cfg.first_k_dense, first_k_dense=0)
@@ -341,6 +369,8 @@ def _stack_cfg(cfg: TransformerConfig, stack: str) -> TransformerConfig:
 #: what a layer of each kind of a pattern changes of the configuration
 LAYER_KINDS = {"full": {"window": 0},
                "linear": {"attention": "gdn", "window": 0},
+               "kda": {"attention": "kda", "window": 0},
+               "mla": {"attention": "mla", "window": 0},
                "window": {},
                "ssm": {"attention": "ssm"},
                "mamba2": {"attention": "mamba2"},
@@ -390,13 +420,39 @@ def _hands_on(segments, index: int) -> bool:
         for kind in pattern)
 
 
-def _periods(cfg: TransformerConfig) -> int:
+def _pattern_segments(cfg: TransformerConfig):
+    """The layers of a patterned stack as segments ((kinds, periods), ...):
+    one, the pattern's whole periods, for a model without leading dense
+    layers; behind `first_k_dense` of them, which are the pattern's first
+    layers, the rest of the period they lie in, the whole periods, and what
+    is left of a last one, each a segment where it has layers."""
+    pattern, dense = cfg.layer_pattern, cfg.first_k_dense
     depth, period = _stack_depth(cfg), len(cfg.layer_pattern)
-    if depth % period:
+    if not dense:
+        if depth % period:
+            raise HorovodTpuError(
+                f"{depth} layers are no whole number of periods of the layer "
+                f"pattern {pattern}")
+        return ((pattern, depth // period),)
+    if dense >= period or len(set(pattern[:dense])) > 1 or depth <= 0:
         raise HorovodTpuError(
-            f"{depth} layers are no whole number of periods of the layer "
-            f"pattern {cfg.layer_pattern}")
-    return depth // period
+            f"first_k_dense={dense} under the layer pattern {pattern}: the "
+            "leading dense layers are the pattern's first layers, of one "
+            "kind, fewer than a period, with a layer behind them")
+    head = min(period - dense, depth)
+    whole, tail = divmod(depth - head, period)
+    return tuple((kinds, periods) for kinds, periods in (
+        (pattern[dense:dense + head], 1), (pattern, whole),
+        (pattern[:tail], 1)) if kinds and periods)
+
+
+def _segment_stacks(cfg: TransformerConfig, layers):
+    """`params["layers"]` of a patterned stack, or a tree laid out like it,
+    as a list, one entry a segment of `_pattern_segments`, with the path of
+    each below "layers": itself where the stack is one segment."""
+    if len(_pattern_segments(cfg)) == 1:
+        return [layers], [()]
+    return list(layers), [(at,) for at in range(len(layers))]
 
 
 def _layer_groups(cfg: TransformerConfig, tree: Dict[str, Any]):
@@ -407,7 +463,10 @@ def _layer_groups(cfg: TransformerConfig, tree: Dict[str, Any]):
     groups = {}
     for stack in STACKS:
         if stack == "layers" and cfg.layer_pattern:
-            groups.update({(stack, kind): 2 for kind in tree[stack]})
+            stacks, paths = _segment_stacks(cfg, tree[stack])
+            groups.update({(stack, *path, kind): 2
+                           for of_kind, path in zip(stacks, paths)
+                           for kind in of_kind})
         elif stack in tree:
             groups[(stack,)] = 1
     return groups
@@ -472,8 +531,11 @@ def _declared(cfg: TransformerConfig) -> Dict[str, Any]:
             per_kind(pattern, periods, (None, None), 100 + 10 * at)
             for at, (pattern, periods) in enumerate(cfg.segments)]
     elif cfg.layer_pattern:
-        tree["layers"] = per_kind(cfg.layer_pattern, _periods(cfg),
-                                  ("pp", None), 4)
+        stacks = [per_kind(pattern, periods, ("pp", None),
+                           4 if not at else 200 + 10 * at)
+                  for at, (pattern, periods) in enumerate(
+                      _pattern_segments(cfg))]
+        tree["layers"] = stacks[0] if len(stacks) == 1 else stacks
     else:
         tree["layers"] = layers(cfg, (_stack_depth(cfg),), ("pp",), None)
     return tree
@@ -680,14 +742,16 @@ def _forward_local(params, tokens, cfg: TransformerConfig,
         """`act` through the layers of one stack: (act, the layers' aux)."""
         layer_cfg = _stack_cfg(cfg, stack)
         if stack == "layers" and cfg.layer_pattern:
-            slots = {kind: of_kind for kind in stage_params if (
-                of_kind := (grad_slots or {}).get((stack, kind)))}
-            act, (aux,) = _run_segments(
-                cfg, ((cfg.layer_pattern, _periods(cfg)),), [stage_params],
-                act, rope, [slots],
-                lambda at, kind: partial(scatter, (stack, kind)))
+            stacks, paths = _segment_stacks(cfg, stage_params)
+            slots = [{kind: of_kind for kind in of_segment if (
+                of_kind := (grad_slots or {}).get((stack, *path, kind)))}
+                for of_segment, path in zip(stacks, paths)]
+            act, auxes = _run_segments(
+                cfg, _pattern_segments(cfg), stacks, act, rope, slots,
+                lambda at, kind: partial(scatter, (stack, *paths[at], kind)))
             # (periods, the layers of a period, .) -> (layers, .)
-            return act, aux if aux is None else aux.reshape(-1, aux.shape[-1])
+            return act, None if auxes[0] is None else jnp.concatenate(
+                [aux.reshape(-1, aux.shape[-1]) for aux in auxes])
 
         slots = (grad_slots or {}).get((stack,))
         # The experts' products read a layer's matrices in place in the
@@ -908,7 +972,11 @@ def build_loss_and_grads(cfg: TransformerConfig, mesh: Mesh, *,
         def of_stack(stack):
             if (stack,) in groups:
                 return completed((stack,))
-            return {kind: completed((stack, kind)) for kind in grads[stack]}
+            stacks, paths = _segment_stacks(cfg, grads[stack])
+            done = [{kind: completed((stack, *path, kind))
+                     for kind in of_kind}
+                    for of_kind, path in zip(stacks, paths)]
+            return done[0] if paths == [()] else done
 
         with reducing():
             stacks = {stack: of_stack(stack) for stack in STACKS
@@ -1020,13 +1088,21 @@ def _layer_cfgs(cfg: TransformerConfig):
         + [_stack_cfg(cfg, "dense_layers")] * bool(cfg.first_k_dense)
 
 
+def _rope_widths(cfg: TransformerConfig) -> set:
+    """The widths the rotated layers of the model turn: a latent-attention
+    layer's shared key part, a plain one's whole head."""
+    return {layer.qk_rope_dim if layer.attention == "mla" else layer.head_dim
+            for layer in _layer_cfgs(cfg) if layer.positions == "rope"
+            and layer.attention in ("mha", "cross", "mla")}
+
+
 def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
     """Refuses, by name, what `cfg` cannot run on `mesh`: what the stack and
     the mesh cannot do, here; what a mixer or an FFN cannot, in the
     `checks` of the parts the configuration uses."""
     ax = mesh_axis_sizes(mesh)
     if cfg.layer_pattern:
-        _periods(cfg)
+        _pattern_segments(cfg)
     kinds = [kind for pattern, _ in cfg.segments for kind in pattern]
     whole = ax["sp"] == ax["tp"] == ax["pp"] == 1
     checks = [
@@ -1068,6 +1144,9 @@ def validate_cfg_for_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
          or (cfg.attn in ("flash", "local") and whole),
          "n_kv_heads, window and diff_attention need attn 'flash' or "
          "'local' and sp=tp=pp=1"),
+        (len(_rope_widths(cfg)) <= 1,
+         "rotated layers of two widths (one table of angles is made a "
+         "step): name all but one kind in `unrotated`"),
         (not cfg.unrotated or cfg.positions == "rope",
          "unrotated names kinds that take no rotation: positions='rope'"),
         (set(cfg.unrotated) <= set(cfg.layer_pattern) | set(kinds),

@@ -1,36 +1,42 @@
-"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464) over a sequence,
-in its chunked form, as Pallas TPU kernels (forward + backward): the one
-scan over the sequence in this package.
+"""The gated delta rule over a sequence, in its chunked form, as Pallas TPU
+kernels (forward + backward): the one scan over the sequence in this package.
+Two forms of one rule: one log decay a head and token (Gated DeltaNet,
+arXiv:2412.06464) and one a key channel (Kimi Delta Attention,
+arXiv:2510.26692 section 3); `gated_delta_rule` takes either and tells them
+by g's shape.
 
 Per head, with keys k_t (dk wide), values v_t (dv wide), queries q_t, a log
-decay g_t <= 0 and a write strength beta_t, a float32 state S (dk x dv) that
-starts at zero goes through
+decay g_t <= 0 (a number, or dk numbers: then exp(g_t) below is Diag(exp(g_t))
+on the state's rows) and a write strength beta_t, a float32 state S (dk x dv)
+that starts at zero goes through
 
     S <- exp(g_t) S;  u_t = beta_t (v_t - S^T k_t);  S <- S + k_t u_t^T;
     o_t = S^T q_t
 
 (`recurrent_gated_delta_rule` below: one token at a time, for the tests).
-`gated_delta_rule` computes the same outputs C tokens at a time (section 3
-of the paper, the WY form). With b_i the running sum of g inside a chunk
-and S_0 the state the chunk starts from,
+`gated_delta_rule` computes the same outputs C tokens at a time (the WY form,
+section 3 of either paper). With b_i the running sum of g inside a chunk, S_0
+the state the chunk starts from and G[X, Y]_ij = sum over the channels c of
+X_ic exp(b_ic - b_jc) Y_jc (with one decay a head that is (X Y^T)_ij exp(b_i
+- b_j): the decay leaves the contraction),
 
-    A_ij = beta_i exp(b_i - b_j) k_i.k_j   for j < i, else 0
-    T    = (I + A)^-1                       unit lower triangular
-    W    = T (beta * exp(b) * K),  U_0 = T (beta * V)
+    A   = beta_i G[K, K]_ij                for j < i, else 0
+    T   = (I + A)^-1                        unit lower triangular
+    W   = T (beta * exp(b) * K),  U_0 = T (beta * V)
     u   = U_0 - W S_0
-    O   = (exp(b) * Q) S_0 + (Q K^T * M) u  M_ij = exp(b_i - b_j), j <= i
+    O   = (exp(b) * Q) S_0 + P u            P = G[Q, K]_ij for j <= i, else 0
     S_C = exp(b_C) S_0 + (exp(b_C - b) * K)^T u
 
-Two kernels, each a grid of (blocks of heads, chunks) that walks a head's
-chunks in sequence (the grid's last, "arbitrary" axis) with the state, or
-its cotangent, resident in VMEM: a scratch cleared at a head's first chunk.
-A grid step takes `heads_a_step` heads, whose chains of small dependent
+Each form has two kernels, each a grid of (blocks of heads, chunks) that walks
+a head's chunks in sequence (the grid's last, "arbitrary" axis) with the
+state, or its cotangent, resident in VMEM: a scratch cleared at a head's first
+chunk. A grid step takes `heads_a_step` heads, whose chains of small dependent
 products are independent and interleave; no operand of a chunk goes through
 HBM between its products.
 
-* `_forward_kernel`. First what does not depend on the state: K K^T, the
-  decays, A, T, W and U_0. T is made by forward substitution by rows, in
-  float32 on the vector unit (row j of T is final once rows 0..j-1 are
+* The forward kernels. First what does not depend on the state: G[K, K], G[Q,
+  K], the decays, A, T, W and U_0. T is made by forward substitution by rows,
+  in float32 on the vector unit (row j of T is final once rows 0..j-1 are
   subtracted from it: C - 1 steps, each one column of A times one row of
   T), never by the product (I - A)(I + A^2)(I + A^4)..: beta reaches 2, so
   A's powers grow before they vanish and float32 loses the result. T then
@@ -39,28 +45,47 @@ HBM between its products.
   that sum to it bit for bit, each a product with the bf16 K or V,
   accumulated in float32. Then the walk: u, O and the next state, four
   products, two of them on the chain from state to state.
-* `_backward_kernel`, the reverse walk with the state's cotangent resident,
+* The backward kernels, the reverse walk with the state's cotangent resident,
   and in the same grid step everything else of the chunk's gradient:
   through the scores, the decays and T (the cotangent of A is
   -(T^T dW) W^T - (T^T dU_0) U_0^T below the diagonal: products only, no
-  second solve). It writes dq, dk, dv, dbeta and the cotangent of b; g's is
+  second solve). They write dq, dk, dv, dbeta and the cotangent of b; g's is
   the reverse running sum of b's inside a chunk, in `jnp`.
 
-Every exponent is of a difference b_i - b_j with j <= i, or of b itself,
-masked before `exp`: none is positive, and nothing is divided by a decay,
-so a strong decay underflows to zero and does nothing worse.
+No exponent is positive and nothing is divided by a decay, so a strong decay
+underflows to zero and does nothing worse. With one decay a head every
+exponent is of a difference b_i - b_j with j <= i, or of b itself, masked
+before `exp`. With one a channel G cannot be had as (X * exp(b)) (Y *
+exp(-b))^T: exp(-b) overflows. A chunk is cut into blocks of `_SUBLANES`
+rows. A block of G below the diagonal is factored about the first row r of
+its (later) row block: exp(b_i - b_j) = exp(b_i - b_r) exp(b_r - b_j) with i
+>= r > j, both exponents <= 0, so it is the product (X_I * exp(b_I - b_r))
+(Y * exp(b_r - b) where the row is before r, else 0)^T, one matrix product a
+row block against all the columns before it. A block on the diagonal is made
+of explicit differences: for each of its rows j, exp(b_I - b_j) where i >= j
+(masked before `exp`), times X_I and y_j, summed over the channels. The
+backward kernel applies G's transpose-free forms the same way: what reaches
+row i from the columns before its block about the block's first row, what
+reaches row j from the rows behind its block about the next block's first
+row, the diagonal blocks by differences; b's cotangent through G is then
+elementwise, q * dq + k * (dk as a row's - dk as a column's).
 
 Precision: products take their operands in the inputs' type (bf16 in the
 benchmark's cells) and accumulate in float32; g, b, beta, every decay, the
 matrix A, its inverse T, the carried state and its cotangent are float32; u
 and the state are rounded to the inputs' type only as operands of a
-product. float32 inputs multiply at the MXU's full float32 precision.
+product, and so is a decayed X or Y of G's products. float32 inputs multiply
+at the MXU's full float32 precision.
 
-For the backward pass the forward kernel writes, besides o, W (inputs'
-type), U_0, T and each chunk's entry state (float32: dk x dv a chunk and
-head); the plain forward (no gradient asked, or the first pass under remat)
-writes o alone. Off the TPU the same kernels run in the
-Pallas interpreter (`ops/_pallas.interpret`).
+For the backward pass a forward kernel writes, besides o, W (inputs' type),
+U_0, T and each chunk's entry state (float32: dk x dv a chunk and head), and
+with a decay a channel P, which is dear to make again, beside T in T's array
+(float32; G[K, K] is not kept: beta's gradient through A is had from what
+A's cotangent sends to K's rows); the plain forward (no gradient asked, or
+the first pass under remat) writes o alone. The per-channel kernels hold the
+state transposed (dv x dk), so that a channel's decay runs along the lanes.
+Off the TPU the same kernels run in the Pallas interpreter
+(`ops/_pallas.interpret`).
 """
 
 from __future__ import annotations
@@ -108,25 +133,29 @@ def chunked_over_recurrent_macs(key_dim: int, value_dim: int,
 
 
 def step_bytes(key_dim: int, value_dim: int, chunk: int = CHUNK,
-               itemsize: int = 2) -> int:
+               itemsize: int = 2, per_channel: bool = False) -> int:
     """VMEM a head takes of the backward kernel's grid step, the larger of
     the two: its blocks (q, k, W, dq, dk; v, dO, dv; U_0, T and the entry
-    state in float32), each twice for the pipeline, and the resident
-    cotangent of the state."""
+    state in float32; with a decay a channel also b and its cotangent,
+    (c x dk) float32 each, and P beside T), each twice for the pipeline,
+    and the resident cotangent of the state."""
     dk, dv, c = key_dim, value_dim, _chunk_size(chunk)
     blocks = (5 * c * dk + 3 * c * dv) * itemsize \
         + 4 * (c * dv + c * c + dk * dv)
+    if per_channel:
+        blocks += 4 * (2 * c * dk + c * c)
     return 2 * blocks + 4 * dk * dv
 
 
 def heads_a_step(heads: int, key_dim: int, value_dim: int,
-                 chunk: int = CHUNK, itemsize: int = 2) -> int:
+                 chunk: int = CHUNK, itemsize: int = 2,
+                 per_channel: bool = False) -> int:
     """Heads a grid step takes: as many as `_VMEM_BUDGET` holds of
     `step_bytes`, at least one, at most all; a divisor of `heads` where one
     lies in the upper half of that range (no head is padded), else the most
     (the heads are padded to whole blocks with rows that do nothing)."""
     most = max(1, min(heads, _VMEM_BUDGET // step_bytes(
-        key_dim, value_dim, chunk, itemsize)))
+        key_dim, value_dim, chunk, itemsize, per_channel)))
     for h in range(most, most // 2, -1):
         if heads % h == 0:
             return h
@@ -217,6 +246,95 @@ def _chunk_terms(b, b_col, mask):
     last = b[:, :, -1:]
     return (jnp.exp(b_col), jnp.exp(last - b_col), jnp.exp(last),
             _decay(b_col - b, mask))
+
+
+def _row_blocks(c: int):
+    """The first rows of a chunk's blocks of `_SUBLANES` rows, with the
+    token of each of the chunk's rows as a column and of a block's rows."""
+    return (range(0, c, _SUBLANES),
+            lax.broadcasted_iota(jnp.int32, (c, 1), 0),
+            lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0))
+
+
+def _channel_grams(xs, k, b):
+    """Per head and for each x of `xs`, G[x, k]: (heads, c, c) float32, the
+    sum over the channels of x_ic exp(b_ic - b_jc) k_jc where j <= i, 0
+    elsewhere; x, k: (heads, c, dk), b: (heads, c, dk) float32, falling down
+    the rows. Row block by row block: the columns before the block about its
+    first row (one product for all of `xs`), its own by differences."""
+    dt, c = k.dtype, k.shape[1]
+    kf = k.astype(_F32)
+    xfs = [x.astype(_F32) for x in xs]
+    blocks, token, sub = _row_blocks(c)
+    lane = lax.broadcasted_iota(jnp.int32, (_SUBLANES, c), 1)
+    rows = [[] for _ in xs]
+    for r in blocks:
+        own = slice(r, r + _SUBLANES)
+        b_own, pivot = b[:, own], b[:, r:r + 1]
+        late = jnp.exp(b_own - pivot)
+        made = [jnp.zeros((k.shape[0], _SUBLANES, c), _F32) for _ in xs]
+        if r:
+            early = (kf * _decay(pivot - b, token < r)).astype(dt)
+            before = _mm(jnp.concatenate(
+                [xf[:, own] * late for xf in xfs], axis=1).astype(dt),
+                early, (1, 1))
+            made = [before[:, n * _SUBLANES:(n + 1) * _SUBLANES]
+                    for n in range(len(xs))]
+        for j in range(_SUBLANES):
+            since = _decay(b_own - b_own[:, j:j + 1], sub >= j) \
+                * kf[:, r + j:r + j + 1]
+            made = [m + jnp.where(lane == r + j, jnp.sum(
+                xf[:, own] * since, axis=2, keepdims=True), 0.0)
+                for m, xf in zip(made, xfs)]
+        for of_x, m in zip(rows, made):
+            of_x.append(m)
+    return [jnp.concatenate(of_x, axis=1) for of_x in rows]
+
+
+def _channel_grams_back(dp, dkk, scale, dp_t, dkk_t, q, k, b):
+    """The cotangents `dp` of G[q, k] and `dkk` * `scale` (a number a row)
+    of G[k, k], with their transposes, back to the rows: (what q's rows get,
+    what k's rows get as G's rows i BEFORE `scale`, what they get as G's
+    columns j), each (heads, c, dk) float32. Rows i take the columns before their block about the block's
+    first row; columns j take the rows behind their block about the next
+    block's first row; a block's own by differences."""
+    dt, c = k.dtype, k.shape[1]
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    blocks, token, sub = _row_blocks(c)
+    dq, dk_i, dk_j = [], [], []
+    for r in blocks:
+        own, end = slice(r, r + _SUBLANES), r + _SUBLANES
+        b_own, pivot = b[:, own], b[:, r:r + 1]
+        q_own, k_own = qf[:, own], kf[:, own]
+        to_q = to_k = as_j = jnp.zeros_like(b_own)
+        if r:
+            early = (kf * _decay(pivot - b, token < r)).astype(dt)
+            before = _mm(jnp.concatenate(
+                [dp[:, own], dkk[:, own]], axis=1).astype(dt), early, (1, 0))
+            late = jnp.exp(b_own - pivot)
+            to_q = late * before[:, :_SUBLANES]
+            to_k = late * before[:, _SUBLANES:]
+        if end < c:
+            after = b[:, end:end + 1]
+            since = _decay(b - after, token >= end)
+            as_j = jnp.exp(after - b_own) * (
+                _mm(dp_t[:, own].astype(dt), (qf * since).astype(dt), (1, 0))
+                + _mm(dkk_t[:, own].astype(dt), (kf * since).astype(dt),
+                      (1, 0)))
+        for j in range(_SUBLANES):
+            since = _decay(b_own - b_own[:, j:j + 1], sub >= j)
+            at = slice(r + j, r + j + 1)
+            col_p, col_k = dp[:, own, at], dkk[:, own, at]
+            to_j = since * kf[:, at]
+            to_q = to_q + col_p * to_j
+            to_k = to_k + col_k * to_j
+            as_j = as_j + jnp.where(sub == j, jnp.sum(
+                (col_p * q_own + col_k * scale[:, own] * k_own) * since,
+                axis=1, keepdims=True), 0.0)
+        dq.append(to_q)
+        dk_i.append(to_k)
+        dk_j.append(as_j)
+    return tuple(jnp.concatenate(x, axis=1) for x in (dq, dk_i, dk_j))
 
 
 # --------------------------------------------------------------------------
@@ -330,6 +448,124 @@ def _backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, t_ref, s0_ref,
     db_ref[:, 0] = db + jnp.where(lane == c - 1, at_last, 0.0)
 
 
+def _channel_terms(b):
+    """Of a chunk with a decay a channel, b: (heads, c, dk): exp(b),
+    exp(b_C - b), and exp(b_C): (heads, 1, dk), a row over the state's
+    lanes."""
+    last = b[:, -1:]
+    return jnp.exp(b), jnp.exp(last - b), jnp.exp(last)
+
+
+def _channel_forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref,
+                            *rest):
+    *saved, state = rest                     # the state: (heads, dv, dk)
+    dt = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first_chunk():
+        state[...] = jnp.zeros_like(state)
+
+    q, k, v, b = q_ref[...], k_ref[...], v_ref[...], b_ref[...]
+    beta = beta_ref[:, 0]                            # (heads, 1, c)
+    eye, below, _ = _masks(q.shape[1])
+    # what does not depend on the state
+    kk, p32 = _channel_grams((k, q), k, b)
+    kk = jnp.where(below, kk, 0.0)
+    t = _unit_lower_inverse(_as_column(beta, eye) * kk, eye)
+    grow, shrink, whole = _channel_terms(b)
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    scaled = t * beta
+    w = _mm_exact(scaled, (kf * grow).astype(dt), (1, 0)).astype(dt)
+    u0 = _mm_exact(scaled, v, (1, 0))
+    p = p32.astype(dt)
+    q_in = (qf * grow).astype(dt)
+    k_out = (kf * shrink).astype(dt)
+    # the walk: from the state the chunk starts from to the next chunk's
+    s = state[...]
+    s_op = s.astype(dt)
+    u = (u0 - _mm(w, s_op, (1, 1))).astype(dt)
+    o_ref[...] = (_mm(q_in, s_op, (1, 1)) + _mm(p, u, (1, 0))).astype(dt)
+    state[...] = whole * s + _mm(u, k_out, (0, 0))
+    if saved:
+        w_ref, u0_ref, tp_ref, s0_ref = saved
+        w_ref[...], u0_ref[...], s0_ref[:, 0] = w, u0, s
+        c = t.shape[-1]
+        tp_ref[:, :, :c], tp_ref[:, :, c:] = t, p32
+
+
+def _channel_backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, tp_ref,
+                             s0_ref, b_ref, beta_ref, do_ref,
+                             dq_ref, dk_ref, dv_ref, db_ref, dbeta_ref,
+                             d_state):
+    dt = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _last_chunk():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    q, k, v, w = q_ref[...], k_ref[...], v_ref[...], w_ref[...]
+    b, beta = b_ref[...], beta_ref[:, 0]
+    c = q.shape[1]
+    eye, below, upto = _masks(c)
+    grow, shrink, whole = _channel_terms(b)
+    beta_col = _as_column(beta, eye)
+    qf, kf, vf = (x.astype(_F32) for x in (q, k, v))
+    q_in32, k_in32, k_out32 = qf * grow, kf * grow, kf * shrink
+    q_in, k_out = q_in32.astype(dt), k_out32.astype(dt)
+    t, p = tp_ref[:, :, :c], tp_ref[:, :, c:].astype(dt)
+    s0 = s0_ref[:, 0]
+    s_op = s0.astype(dt)
+    u0 = u0_ref[...]
+    u = (u0 - _mm(w, s_op, (1, 1))).astype(dt)
+    ds = d_state[...]
+    ds_op = ds.astype(dt)
+    do = do_ref[...]
+
+    # the walk: what meets the state and its cotangent
+    du = (_mm(p, do, (0, 0)) + _mm(k_out, ds_op, (1, 1))).astype(dt)
+    dp = jnp.where(upto, _mm(do, u, (1, 1)), 0.0)
+    dp_t = jnp.where(below, 0.0, _mm(u, do, (1, 1)))
+    dq_in = _mm(do, s_op, (1, 0))
+    dw = -_mm(du, s_op, (1, 0))
+    dk_out = _mm(u, ds_op, (1, 0))
+    d_state[...] = whole * ds + _mm(do, q_in, (0, 0)) - _mm(du, w, (0, 0))
+
+    # through W = T (beta exp(b) K), U_0 = T (beta V) and T = (I + A)^-1
+    dkb = _mm_exact(t, dw.astype(dt), (0, 0))
+    dvb = _mm_exact(t, du, (0, 0))
+    dkb_op, dvb_op, u0_op = dkb.astype(dt), dvb.astype(dt), u0.astype(dt)
+    da = -jnp.where(below, _mm(dkb_op, w, (1, 1))
+                    + _mm(dvb_op, u0_op, (1, 1)), 0.0)
+    da_t = -jnp.where(upto, 0.0, _mm(w, dkb_op, (1, 1))
+                      + _mm(u0_op, dvb_op, (1, 1)))
+
+    # through G[Q, K] and G[K, K] = A over beta: A's cotangent reaches the
+    # rows of K through G[K, K]'s rows times beta, and beta through them
+    dq_g, through_a, dk_j = _channel_grams_back(
+        dp, da, beta_col, dp_t, da_t * beta, q, k, b)
+    dk_i = beta_col * through_a
+    dq_ref[...] = (grow * dq_in + dq_g).astype(dt)
+    dk_ref[...] = (shrink * dk_out + dk_i + dk_j
+                   + beta_col * grow * dkb).astype(dt)
+    dv_ref[...] = (beta_col * dvb).astype(dt)
+
+    def rows_of(x):
+        return jnp.sum(x, axis=2, keepdims=True)
+
+    through_kb = dkb * k_in32
+    dbeta_ref[:, 0] = _as_row(
+        rows_of(through_a * kf) + rows_of(through_kb) + rows_of(dvb * vf),
+        eye)
+    # b: inside G (elementwise, see the top), exp(b) on K and Q, and
+    # exp(b_C - b) on K with exp(b_C) on the state, which are b_C's
+    leaving = dk_out * k_out32
+    at_last = jnp.sum(leaving, axis=1, keepdims=True) + whole * jnp.sum(
+        ds * s0, axis=1, keepdims=True)
+    token = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    db_ref[...] = qf * dq_g + kf * (dk_i - dk_j) + beta_col * through_kb \
+        + dq_in * q_in32 - leaving + jnp.where(token == c - 1, at_last, 0.0)
+
+
 # --------------------------------------------------------------------------
 # The calls
 # --------------------------------------------------------------------------
@@ -363,25 +599,45 @@ _PARAMS = {"compiler_params": pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))}
 
 
+def _per_channel(b) -> bool:
+    """Whether b (or g) is a decay a channel, (B, H, S, dk), and not one a
+    head and token as the kernels hold it, (B H, n, 1, c)."""
+    return b.shape[2] != 1
+
+
+def _form(b, c: int, dk: int, dv: int, given, gates):
+    """What the two forms' calls differ in: (the forward kernel, the
+    backward one, b's block spec, the width of T's array, the state's
+    shape). With a decay a channel T and P lie side by side in one float32
+    array, (., S, 2c): 128 lanes at the chunk of 64, where each alone would
+    be padded to them; the state is transposed, (dv x dk)."""
+    if _per_channel(b):
+        return (_channel_forward_kernel, _channel_backward_kernel, given(dk),
+                2 * c, (dv, dk))
+    return _forward_kernel, _backward_kernel, gates, c, (dk, dv)
+
+
 def _forward(q, k, v, b, beta, *, c: int, heads: int, save: bool):
-    """q, k: (B, H, S, dk), v: (B, H, S, dv), b, beta: (B H, n, 1, c), H a
-    multiple of `heads` and S = n c. Returns o, and with `save` the
-    residuals of the backward pass: W, U_0, T and the entry states, batch
-    and heads one axis."""
+    """q, k: (B, H, S, dk), v: (B, H, S, dv), beta: (B H, n, 1, c), b like
+    beta or (B, H, S, dk), H a multiple of `heads` and S = n c. Returns o,
+    and with `save` the residuals of the backward pass: W, U_0, T (with a
+    decay a channel T | P) and the entry states, batch and heads one
+    axis."""
     batch, n_heads, seq, dk = q.shape
     dv, dt, n, n_all = v.shape[-1], v.dtype, seq // c, batch * n_heads
     given, tokens, gates, states = _specs(heads, n_heads // heads, c, n)
+    kernel, _, decays, kept, state = _form(b, c, dk, dv, given, gates)
     tall = jax.ShapeDtypeStruct
     o, *saved = pallas_call(
-        _forward_kernel, grid=(n_all // heads, n),
-        in_specs=[given(dk), given(dk), given(dv), gates, gates],
-        out_specs=[given(dv)] + [tokens(dk), tokens(dv), tokens(c),
-                                 states(dk, dv)] * save,
+        kernel, grid=(n_all // heads, n),
+        in_specs=[given(dk), given(dk), given(dv), decays, gates],
+        out_specs=[given(dv)] + [tokens(dk), tokens(dv), tokens(kept),
+                                 states(*state)] * save,
         out_shape=[tall(v.shape, dt)] + [
             tall((n_all, seq, dk), dt), tall((n_all, seq, dv), _F32),
-            tall((n_all, seq, c), _F32), tall((n_all, n, dk, dv), _F32)
+            tall((n_all, seq, kept), _F32), tall((n_all, n) + state, _F32)
         ] * save,
-        scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads,) + state, _F32)],
         **_PARAMS)(q, k, v, b, beta)
     return (o, tuple(saved)) if save else o
 
@@ -390,36 +646,48 @@ def _backward(q, k, v, b, beta, w, u0, t, s0, do, *, c: int, heads: int):
     dk, dv, n = q.shape[-1], v.shape[-1], q.shape[2] // c
     given, tokens, gates, states = _specs(heads, q.shape[1] // heads, c, n,
                                           reverse=True)
+    _, kernel, decays, kept, state = _form(b, c, dk, dv, given, gates)
     tall = jax.ShapeDtypeStruct
     return pallas_call(
-        _backward_kernel, grid=(b.shape[0] // heads, n),
+        kernel, grid=(beta.shape[0] // heads, n),
         in_specs=[given(dk), given(dk), given(dv), tokens(dk), tokens(dv),
-                  tokens(c), states(dk, dv), gates, gates, given(dv)],
-        out_specs=[given(dk), given(dk), given(dv), gates, gates],
+                  tokens(kept), states(*state), decays, gates, given(dv)],
+        out_specs=[given(dk), given(dk), given(dv), decays, gates],
         out_shape=[tall(q.shape, q.dtype), tall(k.shape, k.dtype),
                    tall(v.shape, v.dtype), tall(b.shape, _F32),
-                   tall(b.shape, _F32)],
-        scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
+                   tall(beta.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((heads,) + state, _F32)],
         **_PARAMS)(q, k, v, w, u0, t, s0, b, beta, do)
+
+
+def _running(g, c: int, reverse: bool = False):
+    """b: g's running sum inside each chunk of c tokens, along its token
+    axis: the last of (., n, 1, c), or the third of (B, H, S, dk). With
+    `reverse`, g's cotangent from b's: g_j gets every b_i of its chunk with
+    i >= j."""
+    summed = functools.partial(lax.cumsum, reverse=True) if reverse \
+        else jnp.cumsum
+    if not _per_channel(g):
+        return summed(g, axis=3)
+    chunks = g.reshape(g.shape[:2] + (-1, c) + g.shape[3:])
+    return summed(chunks, axis=3).reshape(g.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _rule(q, k, v, g, beta, c, heads):
-    return _forward(q, k, v, jnp.cumsum(g, axis=-1), beta, c=c, heads=heads,
+    return _forward(q, k, v, _running(g, c), beta, c=c, heads=heads,
                     save=False)
 
 
 def _rule_fwd(q, k, v, g, beta, c, heads):
-    b = jnp.cumsum(g, axis=-1)
+    b = _running(g, c)
     o, saved = _forward(q, k, v, b, beta, c=c, heads=heads, save=True)
     return o, (q, k, v, b, beta, *saved)
 
 
 def _rule_bwd(c, heads, saved, do):
     dq, dk, dv, db, dbeta = _backward(*saved, do, c=c, heads=heads)
-    # b is g's running sum inside a chunk: g_j gets every b_i with i >= j
-    dg = lax.cumsum(db, axis=db.ndim - 1, reverse=True)
-    return dq, dk, dv, dg, dbeta
+    return dq, dk, dv, _running(db, c, reverse=True), dbeta
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -429,8 +697,9 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
     """o_t = S_t^T q_t of the gated delta rule, S_0 = 0, in chunks of
     `chunk` tokens (a multiple of 8).
 
-    q, k: (B, H, S, dk); v: (B, H, S, dv); g (log decay, <= 0) and beta:
-    (B, H, S), float32. q and k come normalised and scaled as the caller
+    q, k: (B, H, S, dk); v: (B, H, S, dv); beta: (B, H, S) and g (log
+    decay, <= 0): (B, H, S), one a head and token, or (B, H, S, dk), one a
+    key channel; float32. q and k come normalised and scaled as the caller
     wants them. Returns (B, H, S, dv) in v's type. A length that is no
     multiple of the chunk is padded with rows of g = 0, beta = 0, which
     leave the state alone; heads that do not fill a grid step's block are
@@ -442,8 +711,9 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
         return gated_delta_rule(
             *(x.astype(_F32) for x in (q, k, v)), g, beta,
             chunk=chunk).astype(dt)
+    per_channel = g.ndim == 4
     n = chunks_of(S, c)
-    heads = heads_a_step(H, dk, v.shape[-1], c, dt.itemsize)
+    heads = heads_a_step(H, dk, v.shape[-1], c, dt.itemsize, per_channel)
     pad = ((0, 0), (0, -H % heads), (0, n * c - S))
 
     def whole(x):
@@ -451,20 +721,24 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
             if pad[1][1] or pad[2][1] else x
 
     q, k, v = (whole(x.astype(dt)) for x in (q, k, v))
-    g, beta = (whole(x.astype(_F32)).reshape(-1, n, 1, c)
+    g, beta = (whole(x.astype(_F32)) if x.ndim == 4
+               else whole(x.astype(_F32)).reshape(-1, n, 1, c)
                for x in (g, beta))
     return _rule(q, k, v, g, beta, c, heads)[:, :H, :S]
 
 
 def recurrent_gated_delta_rule(q, k, v, g, beta):
     """The same outputs one token at a time, all in float32: the recurrence
-    as it is written at the top, for the tests of the chunked form."""
+    as it is written at the top, for the tests of the chunked form; g: (B,
+    H, S) or (B, H, S, dk)."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if g.ndim == 3:
+        g = g[..., None]
 
     def step(state, xs):
         q_t, k_t, v_t, g_t, beta_t = xs
-        state = jnp.exp(g_t)[..., None, None] * state
+        state = jnp.exp(g_t)[..., None] * state
         u = beta_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state,
                                                   k_t))
         state = state + k_t[..., :, None] * u[..., None, :]

@@ -1,23 +1,33 @@
-"""Mixture-of-experts feed-forward: a softmax router over all experts, the
-top-k of them per token, one grouped matmul over rows sorted by expert.
+"""Mixture-of-experts feed-forward: a router over all experts, the top-k of
+them per token, one grouped matmul over rows sorted by expert.
 
 Not present in the reference (SURVEY.md §2.6 — `alltoall` is the substrate
 it exposes for users to build this). For a token `h` with router weights
-`Wr`:
+`Wr`, under one of two scoring rules (`SCORINGS`):
 
-    p   = softmax(r Wr)                 over all E experts, in float32
-    out = sum over the k largest p_e of w_e * expert_e(h)
+    "softmax":  p = softmax(r Wr)        over all E experts, in float32
+                chosen = the k largest p_e;  w_e = p_e
+    "sigmoid":  s = sigmoid(r Wr)        each expert's own, in float32
+                chosen = the k largest s_e + bias_e;  w_e = s_e
+    out = sum over the chosen e of w_e * expert_e(h)
+
+The selection bias (DeepSeek-V3's, arXiv:2412.19437 section 2.1.2; Kimi
+Linear's) is a leaf of E numbers that chooses and never weighs: it is added
+to the scores for the choice alone, under `stop_gradient`, so it is the one
+leaf of the layer that takes no gradient (upstream moves it by a balancing
+rule outside the loss; here it stays as it was seeded).
 
 `r` is `h` itself unless the caller hands the router an input of its own
 (`router_input`: SmallThinker, arXiv:2507.20984, scores the layer's input,
 before attention, and its experts read the normed post-attention state). The
-k weights `w_e` are the `p_e` as they are, or with `renormalise` divided by
-their sum over the k chosen (`norm_topk_prob`), the gradient through the sum
-included. `expert_e(h)` is `W_down,e (act(W_gate,e h) * (W_up,e h))` where
-gate weights are given, `act` being `gate`: "silu" or "relu"; and `W_down,e
-gelu(W_up,e h)` where they are not. Experts that every token goes through
-beside these (DeepSeek's shared experts) are a dense MLP of the caller's,
-added to this layer's result (`models/transformer.py`, scope `moe.shared`).
+k weights `w_e` are as above, or with `renormalise` divided by their sum over
+the k chosen (`norm_topk_prob`), the gradient through the sum included, and
+then times `weight_scale` (`routed_scaling_factor`). `expert_e(h)` is
+`W_down,e (act(W_gate,e h) * (W_up,e h))` where gate weights are given, `act`
+being `gate`: "silu" or "relu"; and `W_down,e gelu(W_up,e h)` where they are
+not. Experts that every token goes through beside these (DeepSeek's shared
+experts) are a dense MLP of the caller's, added to this layer's result
+(`models/transformer.py`, scope `moe.shared`).
 
 On one rank of the expert axis (`ep` = 1) routing is dropless and
 static-shaped: the T*k (token, expert) pairs are sorted by expert, their rows
@@ -54,7 +64,9 @@ and summed back as above; a pair routed elsewhere adds nothing, and its
 weight gets no gradient through the experts (the sum back copies only the
 rows that count: `ops/row_gather.py`'s kernel). Nothing stands in for the
 absent ranks. The row buffer is static and sized from the shapes alone:
-`held_rows`, twice the rows an even routing sends to the held experts. Its
+`held_rows`, twice the rows an even routing sends to the held experts (or
+`held_factor` times: a router that chooses far in its scores' tail, 8 of 256
+by sigmoid score, loads a seeded model's experts less evenly than twice). Its
 free rows are zero and lie in the last held expert's group, where they add
 nothing: the grouped matmuls' work is the buffer's, not the routing's, so a
 step takes the same time whatever the router has learnt (a chip of the
@@ -81,10 +93,11 @@ holds (OLMoE, arXiv:2409.02060 section 2):
                                           P_e: mean of p_e over the tokens
     router z     = mean over tokens of logsumexp(h Wr)^2
 
-or, where `route` is handed the number of sequences the tokens are
-(DeepSeek-V2's `seq_aux`, arXiv:2405.04434 section 2.1.3), the load balance
-is each sequence's own, f_e and P_e over that sequence's tokens, averaged
-over the sequences. Either way it is over all E experts, held or not.
+(p the sigmoid scores under that rule) or, where `route` is handed the number
+of sequences the tokens are (DeepSeek-V2's `seq_aux`, arXiv:2405.04434
+section 2.1.3), the load balance is each sequence's own, f_e and P_e over
+that sequence's tokens, averaged over the sequences. Either way it is over
+all E experts, held or not.
 """
 
 from __future__ import annotations
@@ -101,20 +114,37 @@ from horovod_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, visits
 from horovod_tpu.ops.row_gather import sum_rows, take_rows
 
 
+#: how the router's logits become scores
+SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+            "sigmoid": jax.nn.sigmoid}
+
+
 def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
-          renormalise: bool = False):
+          renormalise: bool = False, scoring: str = "softmax",
+          selection_bias: Optional[jax.Array] = None,
+          weight_scale: float = 1.0):
     """(weights (T, k) float32, experts (T, k) int32, rows per expert (E,)
     int32, [load balance, router z] float32) for the tokens x: (T, D), which
     are `sequences` sequences of equal length where the load balance is to
-    be each sequence's own (0: of all T tokens at once). With `renormalise`
-    a token's k weights add up to 1."""
+    be each sequence's own (0: of all T tokens at once). The scores are
+    `SCORINGS[scoring]` of the logits; the k experts are those of the
+    largest scores, or of the largest scores + `selection_bias` (E,), which
+    takes no gradient; the weights are the chosen experts' scores, with
+    `renormalise` made to add up to 1, times `weight_scale`."""
     with jax.named_scope("moe.route"):
         n_experts = router_w.shape[1]
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = lax.top_k(probs, top_k)
+        probs = SCORINGS[scoring](logits)
+        if selection_bias is None:
+            weights, experts = lax.top_k(probs, top_k)
+        else:
+            _, experts = lax.top_k(probs + lax.stop_gradient(
+                selection_bias.astype(jnp.float32)), top_k)
+            weights = jnp.take_along_axis(probs, experts, axis=-1)
         if renormalise:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if weight_scale != 1.0:
+            weights = weights * weight_scale
         if sequences:
             per_seq = jnp.sum(
                 jax.nn.one_hot(experts, n_experts, dtype=jnp.int32).reshape(
@@ -158,14 +188,15 @@ def _permute_bwd(indices, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def held_rows(pairs: int, n_local: int, n_experts: int) -> int:
+def held_rows(pairs: int, n_local: int, n_experts: int,
+              factor: float = 2.0) -> int:
     """Rows of the buffer that one rank holding `n_local` of `n_experts`
     experts sorts its held (token, expert) pairs into, of `pairs` routed:
-    twice what an even routing sends it, in whole row tiles, and never more
-    than all the pairs. (`ROW_TILE`: the rows a grouped matmul's kernel takes
-    at a time.)"""
+    `factor` times (twice) what an even routing sends it, in whole row
+    tiles, and never more than all the pairs. (`ROW_TILE`: the rows a
+    grouped matmul's kernel takes at a time.)"""
     even = pairs * n_local / n_experts
-    return min(pairs, math.ceil(2 * even / ROW_TILE) * ROW_TILE)
+    return min(pairs, math.ceil(factor * even / ROW_TILE) * ROW_TILE)
 
 
 #: what a gated expert applies to its gate's product
@@ -210,6 +241,9 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             capacity_factor: float = 1.25, first_expert: int = 0,
             sequences: int = 0, router_input: Optional[jax.Array] = None,
             renormalise: bool = False, gate: str = "silu",
+            scoring: str = "softmax",
+            selection_bias: Optional[jax.Array] = None,
+            weight_scale: float = 1.0, held_factor: float = 2.0,
             stacks=(None, None, None), layer=0
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k mixture-of-experts feed-forward on one shard's tokens.
@@ -225,6 +259,10 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
       router_input: (T, D), what the router scores where that is not x
       renormalise: a token's k weights divided by their sum
       gate: what a gated expert applies to its gate's product (`GATES`)
+      scoring, selection_bias, weight_scale: the router's rule (`route`)
+      held_factor: where one rank holds a share, its row buffer as a
+        multiple of what an even routing sends the held experts
+        (`held_rows`)
       stacks, layer: the (L, E_local, ...) stacks that w_up, w_down and
         w_gate are layer `layer` of (an int32, traced in a layer scan),
         where the caller has them: the experts' products then read the
@@ -253,12 +291,15 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             f"experts on {ranks} rank(s)")
     if gate not in GATES:
         raise HorovodTpuError(f"gate={gate!r}: choose from {sorted(GATES)}")
+    if scoring not in SCORINGS:
+        raise HorovodTpuError(
+            f"scoring={scoring!r}: choose from {sorted(SCORINGS)}")
     # one rank, holding some of the experts
     share = ranks == 1 and n_local < n_experts
 
     weights, experts, counts, aux = route(
         x if router_input is None else router_input, router_w, k, sequences,
-        renormalise)
+        renormalise, scoring, selection_bias, weight_scale)
 
     with jax.named_scope("moe.dispatch"):
         key = experts.reshape(-1)
@@ -273,7 +314,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         if ranks == 1:
             sizes, held_order = counts, order
             if share:
-                room = held_rows(T * k, n_local, n_experts)
+                room = held_rows(T * k, n_local, n_experts, held_factor)
                 sizes = lax.slice(counts, (first_expert,),
                                   (first_expert + n_local,))
                 start = jnp.cumsum(sizes, dtype=jnp.int32) - sizes

@@ -123,6 +123,21 @@ def test_phase_gated_delta_scan(smoke, capsys):
 
 
 @one_chip
+def test_phase_kda_scan(smoke, capsys):
+    chip_smoke.kda_scan(smoke, shape=(1, 2, 200, 16, 16), checked=100)
+    out = capsys.readouterr().out
+    assert "[delta rule, a decay a channel] 1 x 200 tokens x 2 heads, " \
+        "16 | 16" in out
+    assert "chunk 64, 4 chunks a sequence" in out
+    assert "2 heads a grid step (" in out
+    assert "interpret=True, tpu_custom_call in the compiled forward 0, " \
+        "forward + backward 0" in out
+    assert "from the token-by-token recurrence" in out
+    assert "least time" not in out     # no share of a peak off the TPU
+    assert chip_smoke.kda_scan in chip_smoke.PHASES[1][1]
+
+
+@one_chip
 def test_phase_causal_conv_pass(smoke, capsys):
     chip_smoke.causal_conv_pass(smoke, shape=(2, 3, 150),
                                 widths=((24, 24 ** -0.5), (7, None)))

@@ -585,7 +585,7 @@ def test_a_step_that_leaves_a_held_pair_out_counts_it(monkeypatch):
                     jax.tree_util.tree_leaves(plain_grads)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # room for 8 rows where the one expert layer's held experts get more
-    monkeypatch.setattr(moe, "held_rows", lambda pairs, held, n: 8)
+    monkeypatch.setattr(moe, "held_rows", lambda *sizes: 8)
     routes = np.asarray(reference.forward(
         family.reference_weights(params), tokens, TOP_K,
         first_expert=FIRST)[2])

@@ -1,0 +1,227 @@
+"""The delta rule with a decay per key channel (Kimi Delta Attention,
+`ops/gated_delta.py`'s second form) against the token-by-token recurrence:
+outputs and the gradient of every input, float32 and bf16, at lengths that
+are and are not whole chunks, heads that do and do not fill a grid step, and
+under a decay so strong that exp(-b) would overflow inside one chunk; the
+decayed products G block by block against their definition; the per-channel
+kernels against the scalar ones where g is constant over a head's channels;
+what the forward saves for the backward pass; and how many heads a grid step
+takes."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops.gated_delta import (gated_delta_rule, heads_a_step,
+                                         recurrent_gated_delta_rule,
+                                         step_bytes)
+
+
+def _inputs(seq, *, strong, dtype=jnp.float32, seed=0, batch=2, heads=2,
+            dk=8, dv=16):
+    """q and k normalised as the mixer hands them over; g a decay per
+    channel: mild, or up to e^-60 a token (b reaches -3,800 inside a chunk
+    of 64, where exp(-b) is far beyond float32)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    f32 = jnp.float32
+    q = jax.random.normal(ks[0], (batch, heads, seq, dk), f32)
+    k = jax.random.normal(ks[1], (batch, heads, seq, dk), f32)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (batch, heads, seq, dv), f32)
+    g = -jax.random.uniform(ks[3], (batch, heads, seq, dk), f32) \
+        * (60.0 if strong else 0.3)
+    if strong:      # some channels hardly decay beside those that vanish
+        g = g * (jnp.arange(dk) % 3 > 0)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (batch, heads, seq),
+                                                f32))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def _grads(fn, args, cot):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cot),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("seq, chunk, strong", [
+    (40, 8, False), (150, 64, False), (150, 64, True), (72, 16, True)],
+    ids=["5-chunks-of-8", "2-chunks-and-a-part", "strong-decay",
+         "strong-decay-chunks-of-16"])
+def test_the_per_channel_rule_equals_the_recurrence(seq, chunk, strong):
+    """Outputs and the gradient of every input (g's a number a channel),
+    finite under the strong decay and equal to the recurrence's there."""
+    with jax.enable_x64(False):
+        args = _inputs(seq, strong=strong)
+        got = gated_delta_rule(*args, chunk=chunk)
+        want = recurrent_gated_delta_rule(*args)
+        assert got.shape == want.shape == (2, 2, seq, 16)
+        assert bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+        cot = jax.random.normal(jax.random.PRNGKey(9), want.shape,
+                                jnp.float32)
+        ours = _grads(lambda *a: gated_delta_rule(*a, chunk=chunk), args,
+                      cot)
+        theirs = _grads(recurrent_gated_delta_rule, args, cot)
+    assert ours[3].shape == args[3].shape == (2, 2, seq, 8)
+    for name, g, w in zip("q k v g beta".split(), ours, theirs):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * scale, name
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+def test_the_rule_in_bf16_is_the_recurrence_on_the_rounded_inputs(strong):
+    """bf16 operands with float32 accumulation, decays and state: within a
+    few bf16 steps of the float32 recurrence on the same rounded inputs,
+    outputs and gradients, and finite under the strong decay."""
+    with jax.enable_x64(False):
+        args = _inputs(100, strong=strong, dtype=jnp.bfloat16)
+        got = gated_delta_rule(*args).astype(jnp.float32)
+        exact = tuple(x.astype(jnp.float32) for x in args)
+        want = recurrent_gated_delta_rule(*exact)
+        assert gated_delta_rule(*args).dtype == jnp.bfloat16
+        scale = float(jnp.max(jnp.abs(want)))
+        assert float(jnp.max(jnp.abs(got - want))) <= 2e-2 * scale
+        cot = jax.random.normal(jax.random.PRNGKey(9), want.shape,
+                                jnp.float32)
+        ours = _grads(gated_delta_rule, args, cot)
+        theirs = _grads(recurrent_gated_delta_rule, exact, cot)
+    for name, g, w in zip("q k v g beta".split(), ours, theirs):
+        g = g.astype(jnp.float32)
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        assert float(jnp.max(jnp.abs(g - w))) <= 3e-2 * scale, name
+
+
+def test_heads_that_do_not_fill_a_grid_step_are_padded(monkeypatch):
+    """Three heads in blocks of two: the fourth is padding that does
+    nothing, in the forward and in every gradient."""
+    monkeypatch.setattr(gated_delta, "_VMEM_BUDGET",
+                        2 * step_bytes(8, 16, itemsize=4, per_channel=True))
+    assert heads_a_step(3, 8, 16, itemsize=4, per_channel=True) == 2
+    with jax.enable_x64(False):
+        args = _inputs(70, strong=False, batch=1, heads=3)
+        got = gated_delta_rule(*args)
+        want = recurrent_gated_delta_rule(*args)
+        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+        cot = jnp.ones(want.shape, jnp.float32)
+        for name, g, w in zip("q k v g beta".split(),
+                              _grads(gated_delta_rule, args, cot),
+                              _grads(recurrent_gated_delta_rule, args, cot)):
+            scale = float(jnp.max(jnp.abs(w))) or 1.0
+            assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * scale, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_with_one_decay_a_head_it_is_the_scalar_kernels_rule(dtype):
+    """g constant over a head's channels is "gdn"'s rule: the per-channel
+    kernels against the scalar ones on the same inputs, outputs and
+    gradients; g's gradient a channel sums to the scalar's."""
+    with jax.enable_x64(False):
+        q, k, v, g, beta = _inputs(150, strong=False, dtype=dtype)
+        scalar = g[..., 0]
+        wide = jnp.broadcast_to(scalar[..., None], g.shape)
+        got = gated_delta_rule(q, k, v, wide, beta).astype(jnp.float32)
+        want = gated_delta_rule(q, k, v, scalar, beta).astype(jnp.float32)
+        tol = 3e-5 if dtype == jnp.float32 else 2e-2
+        scale = float(jnp.max(jnp.abs(want)))
+        assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
+        cot = jax.random.normal(jax.random.PRNGKey(9), want.shape,
+                                jnp.float32)
+        ours = list(_grads(gated_delta_rule, (q, k, v, wide, beta), cot))
+        theirs = _grads(gated_delta_rule, (q, k, v, scalar, beta), cot)
+        ours[3] = jnp.sum(ours[3], axis=-1)
+    for name, g_, w in zip("q k v g beta".split(), ours, theirs):
+        g_, w = g_.astype(jnp.float32), w.astype(jnp.float32)
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        assert float(jnp.max(jnp.abs(g_ - w))) <= 10 * tol * scale, name
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+def test_the_decayed_products_are_their_definition(strong):
+    """G[x, k]_ij = sum_c x_ic exp(b_ic - b_jc) k_jc for j <= i and exactly
+    0 above the diagonal, block by block, against the sum written out in
+    float64 with the exponent masked: under the strong decay too, where a
+    factor exp(-b) does not exist in float32."""
+    heads, c, dk = 2, 32, 8
+    _, k, _, g, _ = _inputs(c, strong=strong, batch=1, heads=heads, dk=dk)
+    x = jax.random.normal(jax.random.PRNGKey(4), (heads, c, dk), jnp.float32)
+    k, b = k[0], jnp.cumsum(g[0], axis=1)
+    if strong:
+        with np.errstate(over="ignore"):
+            assert not np.all(np.isfinite(np.exp(-np.asarray(b))))
+    with jax.enable_x64(False):
+        (got,) = gated_delta._channel_grams((x,), k, b)
+    b64, x64, k64 = (np.asarray(a, np.float64) for a in (b, x, k))
+    seen = np.arange(c)[:, None] >= np.arange(c)[None, :]
+    diff = np.where(seen[None, :, :, None],
+                    b64[:, :, None, :] - b64[:, None, :, :], -np.inf)
+    want = np.einsum("hic,hijc,hjc->hij", x64, np.exp(diff), k64)
+    assert np.all(np.asarray(got)[:, ~seen] == 0.0)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_what_the_forward_saves_for_the_backward_pass():
+    """W in the inputs' type; U_0, T beside P and each chunk's entry state
+    in float32, the state transposed (dv x dk) and equal to the recurrence's
+    at the same token; T unit lower triangular, P zero above the
+    diagonal."""
+    with jax.enable_x64(False):
+        q, k, v, g, beta = _inputs(192, strong=False, batch=1, heads=2,
+                                   dtype=jnp.bfloat16)
+        c, heads = 64, 2
+        b = gated_delta._running(g, c)
+        _, (w, u0, tp, s0) = gated_delta._forward(
+            q, k, v, b, beta.reshape(2, 3, 1, c), c=c, heads=heads,
+            save=True)
+    assert w.dtype == jnp.bfloat16
+    assert {u0.dtype, tp.dtype, s0.dtype} == {jnp.dtype("float32")}
+    assert s0.shape == (2, 3, 16, 8) and tp.shape == (2, 192, 128)
+    t, p = (np.asarray(tp).reshape(2, 3, 64, 2, 64)[:, :, :, i]
+            for i in (0, 1))
+    above = np.arange(64)[:, None] < np.arange(64)[None, :]
+    assert np.all(t[..., above] == 0) and np.all(p[..., above] == 0)
+    assert np.all(t[..., np.arange(64), np.arange(64)] == 1)
+    assert np.abs(p).max() > 0.1
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    state = jnp.zeros((2, 8, 16), jnp.float32)
+    for tok in range(128):
+        state = jnp.exp(g[0, :, tok])[:, :, None] * state
+        u = beta[0, :, tok, None] * (vf[0, :, tok] - jnp.einsum(
+            "hkv,hk->hv", state, kf[0, :, tok], precision="highest"))
+        state = state + kf[0, :, tok, :, None] * u[:, None, :]
+        if tok + 1 in (64, 128):
+            np.testing.assert_allclose(
+                s0[:, (tok + 1) // 64], jnp.swapaxes(state, 1, 2),
+                atol=2e-2, rtol=2e-2)      # u is a bf16 operand
+    assert float(jnp.max(jnp.abs(s0[:, 0]))) == 0.0
+
+
+def test_heads_a_grid_step_at_the_cells_widths():
+    """`kimilinear-1chip`: 32 heads of 128 | 128 in bf16: four a step (the
+    scalar form takes four there too); the per-channel blocks are b and its
+    cotangent and P more."""
+    assert heads_a_step(32, 128, 128, per_channel=True) == 4
+    assert heads_a_step(32, 128, 128) == 4
+    more = step_bytes(128, 128, per_channel=True) - step_bytes(128, 128)
+    assert more == 2 * 4 * (2 * 64 * 128 + 64 * 64)
+    assert step_bytes(128, 128, per_channel=True) * 4 \
+        <= gated_delta._VMEM_BUDGET
+
+
+def test_g_tells_the_form_by_its_shape(monkeypatch):
+    """(B, H, S) runs the scalar kernels, (B, H, S, dk) the per-channel
+    ones: no option chooses."""
+    ran = []
+    real = gated_delta.pallas_call
+    monkeypatch.setattr(gated_delta, "pallas_call", lambda kernel, **kw: (
+        ran.append(kernel.__name__) or real(kernel, **kw)))
+    with jax.enable_x64(False):
+        q, k, v, g, beta = _inputs(64, strong=False, batch=1, heads=1)
+        gated_delta_rule(q, k, v, g[..., 0], beta)
+        assert ran == ["_forward_kernel"]
+        gated_delta_rule(q, k, v, g, beta)
+    assert ran == ["_forward_kernel", "_channel_forward_kernel"]

@@ -395,6 +395,45 @@ def test_gated_delta_rule_compiles_for_v5e(monkeypatch, name):
                                              HYBRID_CELL) == {}
 
 
+#: `kimilinear-1chip`'s rule: (batch, heads, tokens, key width, value width)
+KIMI_RULE = (1, 32, 16384, 128, 128)
+
+
+@pytest.mark.parametrize("name", ["fwd", "bwd"])
+def test_the_per_channel_rule_compiles_for_v5e(monkeypatch, name):
+    """`ops/gated_delta.py` with a decay per key channel at
+    `kimilinear-1chip`'s shapes, 256 chunks, four heads a grid step: the
+    blocks of eight rows, their lane-offset stores of T | P and the
+    products of sixteen rows by the chunk compile for the chip; forward and
+    backward are Mosaic kernels of the scalar form's signatures (with a
+    gradient asked the forward writes W, U_0, T | P and the entry states;
+    the backward reads ten arrays) and nothing else walks the sequence;
+    none can be taken for a flash kernel of the cell's MLA layer."""
+    from benchmark.layer_metrics import flash_roofline
+    from horovod_tpu.ops.gated_delta import gated_delta_rule, heads_a_step
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    b, h, s, dk, dv = KIMI_RULE
+    assert heads_a_step(h, dk, dv, per_channel=True) == 4
+    wide = jax.ShapeDtypeStruct((b, h, s, dk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, h, s, dv), jnp.bfloat16)
+    decay = jax.ShapeDtypeStruct((b, h, s, dk), jnp.float32)
+    beta = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
+
+    def bwd(*args):
+        return jax.grad(lambda *a: gated_delta_rule(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*args)
+
+    want = GDN_SIGNATURES[name]
+    txt = compile_kernel_text(topo, {"fwd": gated_delta_rule, "bwd": bwd}[
+        name], (wide, wide, v, decay, beta), n_calls=len(want))
+    assert mosaic_signatures(txt) == want
+    assert " while(" not in txt
+    assert not set(want) & set(flash_roofline.SIGNATURES)
+
+
 #: (operands, results) of the convolution's Mosaic kernels: the forward (the
 #: rows of u before a tile, u, the taps -> y) and the backward (the rows
 #: before, u, the taps, dy -> du and the partial sums of dw)

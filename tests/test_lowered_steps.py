@@ -1,4 +1,4 @@
-"""The train step of seven tiny configurations of the kinds the benchmark's
+"""The train step of eight tiny configurations of the kinds the benchmark's
 LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's; since PR 45 the `CFG`s
 of `tests/test_olmo_hybrid.py`, a layer pattern, `tests/test_phi4_flash.py`,
 segments, and `tests/test_smallthinker.py`, a pattern with a share of the
@@ -35,6 +35,13 @@ PR 49 (the flash forward walks a whole block in strips of 256 rows and writes
 a masked block's next product ahead of a strip's softmax) left every entry
 as it was: these models' 32 tokens are one block of 32 rows, which no strip
 divides and whose one strip has none after it, so nothing was taken anew.
+PR 50 wrote the Kimi Linear family's entry (one rank) on its own tree
+(`write_fixture(only_new=True)`: `tests/test_kimi_linear.py`'s `CFG` as the
+cell runs it, a dense prefix inside a pattern whose stack is two segments,
+the per-channel rule's kernels, sigmoid scores with a selection bias) and left
+the thirteen older texts as they were: the scalar rule of `olmo_hybrid`
+lowers to the kernels it lowered to, the softmax router to the program it
+was.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
@@ -52,6 +59,7 @@ import pytest
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
 from test_granite_hybrid import CFG as GRANITE
+from test_kimi_linear import CFG as KIMI
 from test_olmo_hybrid import CFG as HYBRID
 from test_phi4_flash import CFG as PHI4_FLASH
 from test_smallthinker import CFG as SMALLTHINKER
@@ -88,11 +96,21 @@ CONFIGS = {
     "granite_hybrid": dataclasses.replace(
         GRANITE, attn="flash", dtype=jnp.bfloat16, remat=True,
         remat_policy="full"),
+    # Kimi Delta Attention layers to one latent-attention layer without a
+    # rotation behind a dense KDA layer, sigmoid-scored experts with a
+    # selection bias beside a shared one; as the cell runs it
+    "kimi_linear": dataclasses.replace(
+        KIMI, attn="flash", dtype=jnp.bfloat16, remat=True,
+        remat_policy="full"),
 }
 #: (a share of the experts is one rank's, with an expert axis; a data-parallel
 #: axis of two is the Granite and SmallThinker families' to show)
+#: (the Kimi Linear family's text is three times any other's, the rule's
+#: per-channel kernels unrolled in it: one rank holds it; `dp` = 2 of its
+#: two-segment stack is held to one rank's numbers in
+#: `tests/test_kimi_linear_stack.py`)
 CASES = [(name, dp) for name in CONFIGS for dp in (1, 2)
-         if not (name == "deepseek_v2" and dp == 2)]
+         if not (name in ("deepseek_v2", "kimi_linear") and dp == 2)]
 
 
 def lowered(name: str, dp: int) -> str:

@@ -1,6 +1,6 @@
 """The scopes of the compiled train step (`transformer.STEP_SCOPES` and the
-mixers' `moe.*`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`): for tiny configurations
-of the seven kinds the benchmark's LM cells run, compiled on the CPU, every scope the
+mixers' `moe.*`, `mla.*`, `gdn.*`, `kda.*`, `ssm.*`, `gmu.*`): for tiny
+configurations of the eight kinds the benchmark's LM cells run, compiled on the CPU, every scope the
 model has is in the compiled text's `op_name`s, in the forward pass and in
 the backward pass; the gradient reduction's only where something is
 reduced; and `DistributedOptimizer.step` records its two phases as spans of
@@ -47,6 +47,9 @@ HAS = {
                           "gmu.gate", "gmu.out", "mlp.dense") + VOCAB,
     "smallthinker": ATTN + ("attn.window",) + MOE + VOCAB,
     "granite_hybrid": ATTN + SSD + ("moe.shared",) + MOE + VOCAB,
+    "kimi_linear": ("kda.project", "kda.conv", "kda.scan", "kda.gate",
+                    "kda.out", "mla.project", "mla.rope", "mla.attend",
+                    "mla.out", "mlp.dense", "moe.shared") + MOE + VOCAB,
 }
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -74,7 +77,7 @@ def scopes_of(op_name: str) -> list:
     bare = (re.sub(r"^(?:[\w\-]+\()+", "", c).rstrip(")")
             for c in op_name.split("/"))
     return [c for c in bare if re.match(
-        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|ssm|ssd|gmu)\.", c)]
+        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|kda|ssm|ssd|gmu)\.", c)]
 
 
 def under(names, scope: str, backward: bool) -> list:
@@ -115,7 +118,8 @@ def test_the_reduction_has_its_scope_where_something_is_reduced(name):
 
 @pytest.mark.parametrize("name, dp", [
     ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
-    ("phi4_flash", 2), ("smallthinker", 2), ("granite_hybrid", 2)])
+    ("phi4_flash", 2), ("smallthinker", 2), ("granite_hybrid", 2),
+    ("kimi_linear", 1)])
 def test_no_instruction_lies_under_two_layers_scopes(name, dp):
     """`mlp.dense` is entered by `ffns`' two dense rows and not in `_mlp`,
     which the shared experts run under `moe.shared`; the reduction
@@ -129,19 +133,19 @@ def test_no_instruction_lies_under_two_layers_scopes(name, dp):
 def test_the_vocabulary_is_what_the_source_enters():
     """`STEP_SCOPES` is every scope `models/transformer.py` and its layer
     parts (`models/mixers.py`, `models/ffns.py`) enter outside the mixers'
-    own (`moe.shared`, `mla.*`, `gdn.*`, `ssm.*`, `gmu.*`; a Mamba-2
+    own (`moe.shared`, `mla.*`, `gdn.*`, `kda.*`, `ssm.*`, `gmu.*`; a Mamba-2
     layer's `ssd.*` are listed in it), no more and no less; and a part enters its scopes whatever the stack: the rows of
     `MIXERS` and `FFNS` know of no pattern."""
     parts = inspect.getsource(mixers) + inspect.getsource(ffns)
     entered = set(re.findall(r'named_scope[(,]\s*"([^"]+)"',
                              inspect.getsource(tfm) + parts))
     own = {s for s in entered
-           if s.startswith(("moe.", "mla.", "gdn.", "ssm.", "gmu."))}
+           if s.startswith(("moe.", "mla.", "gdn.", "kda.", "ssm.", "gmu."))}
     assert entered - own == set(tfm.STEP_SCOPES)
     assert len(set(tfm.STEP_SCOPES)) == len(tfm.STEP_SCOPES)
     assert "layer_pattern" not in parts + inspect.getsource(tfm._layer)
-    assert own >= {"mla.project", "gdn.scan", "ssm.scan", "gmu.gate",
-                   "moe.shared"}
+    assert own >= {"mla.project", "gdn.scan", "kda.scan", "ssm.scan",
+                   "gmu.gate", "moe.shared"}
     assert set(tfm.STEP_SCOPES) >= set(SSD)
 
 
