@@ -463,7 +463,8 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
     Mosaic kernels the compiled forward and forward + backward hold (on the
     TPU: one, and two), then their times against the least time the
     benchmark's `gdn_scan_roofline` counts (the family's `rule_work`). With
-    `per_channel` the form with a decay a key channel (`kda_scan`)."""
+    `per_channel` the form with a decay a key channel (`kda_scan`), which
+    also says what its decayed products cost (`channel_gram_work`)."""
     batch, heads, seq, dk, dv = shape
     tag = "delta rule, a decay a channel" if per_channel \
         else "gated delta rule"
@@ -525,12 +526,21 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                   f"backward {fwd + bwd:.3f} ms "
                   f"({100 * (fwd + bwd) / ms['forward + backward']:.2f}%)")
     a_step = gd.heads_a_step(heads, dk, dv, per_channel=per_channel)
+    halving = ""
+    if per_channel:    # the counter of a form that engages in every chunk
+        work = gd.channel_gram_work(gd.CHUNK, dk)
+        halving = (f"; its decayed products by {work['levels']} levels of a "
+                   "halving, forward / backward a chunk and head: "
+                   + ", ".join(f"{work[name][0]} / {work[name][1]} "
+                               + name.replace("_", " ") for name in (
+                                   "products", "exp_registers",
+                                   "lane_reductions", "lane_broadcasts")))
     say(f"[{tag}] {batch} x {seq} tokens x {heads} heads, "
         f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "
         f"{gd.chunks_of(seq)} chunks a sequence, the chunked form's "
         "multiply-adds "
         f"{gd.chunked_over_recurrent_macs(dk, dv):.2f} x the recurrent "
-        f"form's; {a_step} heads a grid step "
+        f"form's{halving}; {a_step} heads a grid step "
         f"({a_step * gd.step_bytes(dk, dv, per_channel=per_channel) / 2 ** 20:.2f} "
         "MiB of VMEM asked "
         "for its blocks and state); interpret="

@@ -53,22 +53,26 @@ HBM between its products.
   the reverse running sum of b's inside a chunk, in `jnp`.
 
 No exponent is positive and nothing is divided by a decay, so a strong decay
-underflows to zero and does nothing worse. With one decay a head every
-exponent is of a difference b_i - b_j with j <= i, or of b itself, masked
-before `exp`. With one a channel G cannot be had as (X * exp(b)) (Y *
-exp(-b))^T: exp(-b) overflows. A chunk is cut into blocks of `_SUBLANES`
-rows. A block of G below the diagonal is factored about the first row r of
-its (later) row block: exp(b_i - b_j) = exp(b_i - b_r) exp(b_r - b_j) with i
->= r > j, both exponents <= 0, so it is the product (X_I * exp(b_I - b_r))
-(Y * exp(b_r - b) where the row is before r, else 0)^T, one matrix product a
-row block against all the columns before it. A block on the diagonal is made
-of explicit differences: for each of its rows j, exp(b_I - b_j) where i >= j
-(masked before `exp`), times X_I and y_j, summed over the channels. The
-backward kernel applies G's transpose-free forms the same way: what reaches
-row i from the columns before its block about the block's first row, what
-reaches row j from the rows behind its block about the next block's first
-row, the diagonal blocks by differences; b's cotangent through G is then
-elementwise, q * dq + k * (dk as a row's - dk as a column's).
+underflows to zero and does nothing worse. With one decay a head every exponent
+is of a difference b_i - b_j with j <= i, or of b itself, masked before `exp`.
+With one a channel G cannot be had as (X * exp(b)) (Y * exp(-b))^T: exp(-b)
+overflows. But exp(b_i - b_j) = exp(b_i - b_p) exp(b_p - b_j) for any pivot row
+p with j < p <= i, both exponents <= 0 (b falls down the rows), and halving the
+chunk again and again gives every pair j < i a pivot: level s (c, c/2, ..., 2)
+cuts the chunk into groups of s rows and holds the pairs of one group with i in
+its later half and j in its earlier half, about the group's middle row; each
+pair is in one level, the diagonal (decay 1) is level 1. With e_s = exp(-|b -
+b_p|), p the middle row of the row's group, G[X, K] is the sum over the levels
+of (X * e_s)(K * e_s)^T where the level holds the pair: one whole-chunk `exp`
+and one matrix product a level (G[K, K]'s and G[Q, K]'s left operands stacked),
+no lane reduction and no column placed. The backward kernel takes G's
+cotangents back level by level the same way: that of (X * e_s) is the level's
+dG times (K * e_s), that of (K * e_s) its transpose times (X * e_s), then times
+e_s; b's cotangent through G is then elementwise, q * dq + k * (dk as a row's -
+dk as a column's). These helpers take one head's matrices and run a head after
+the other (`_each_head`): G's work is throughput that no other head's chain
+hides, and the four heads' operands side by side do not fit the vector
+registers.
 
 Precision: products take their operands in the inputs' type (bf16 in the
 benchmark's cells) and accumulate in float32; g, b, beta, every decay, the
@@ -130,6 +134,19 @@ def chunked_over_recurrent_macs(key_dim: int, value_dim: int,
     dk, dv, c = key_dim, value_dim, _chunk_size(chunk)
     chunked = 2 * c * dk + c * (dk + dv) / 2 + 3 * dk * dv + c * dv
     return chunked / (3 * dk * dv)
+
+
+def channel_gram_work(chunk: int = CHUNK, key_dim: int = 128) -> dict:
+    """What the decayed products G of the form with a decay a channel cost a
+    chunk and head, as (forward, backward): the halving's levels, matrix
+    products, float32 registers (8 rows x 128 lanes) that go through `exp`,
+    and lane reductions and lane broadcasts of a register, of which a level
+    has none."""
+    levels = len(_levels(_chunk_size(chunk)))
+    registers = (levels - 1) * (chunk // _SUBLANES) * -(-key_dim // 128)
+    return {"levels": levels, "products": (levels, 2 * levels),
+            "exp_registers": (registers, registers),
+            "lane_reductions": (0, 0), "lane_broadcasts": (0, 0)}
 
 
 def step_bytes(key_dim: int, value_dim: int, chunk: int = CHUNK,
@@ -248,93 +265,102 @@ def _chunk_terms(b, b_col, mask):
             _decay(b_col - b, mask))
 
 
-def _row_blocks(c: int):
-    """The first rows of a chunk's blocks of `_SUBLANES` rows, with the
-    token of each of the chunk's rows as a column and of a block's rows."""
-    return (range(0, c, _SUBLANES),
-            lax.broadcasted_iota(jnp.int32, (c, 1), 0),
-            lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0))
+def _levels(c: int) -> list[int]:
+    """The halving's levels of a chunk of c rows, s = 2^n >= c, ..., 2, 1:
+    level s holds the pairs of one group of s rows with i in its later half
+    and j in its earlier half, on either side of the group's middle row; a
+    pair j < i is in one level, the diagonal in level 1."""
+    top = 1 << (c - 1).bit_length()
+    return [top >> n for n in range(top.bit_length())]
 
 
-def _channel_grams(xs, k, b):
-    """Per head and for each x of `xs`, G[x, k]: (heads, c, c) float32, the
-    sum over the channels of x_ic exp(b_ic - b_jc) k_jc where j <= i, 0
-    elsewhere; x, k: (heads, c, dk), b: (heads, c, dk) float32, falling down
-    the rows. Row block by row block: the columns before the block about its
-    first row (one product for all of `xs`), its own by differences."""
-    dt, c = k.dtype, k.shape[1]
-    kf = k.astype(_F32)
-    xfs = [x.astype(_F32) for x in xs]
-    blocks, token, sub = _row_blocks(c)
-    lane = lax.broadcasted_iota(jnp.int32, (_SUBLANES, c), 1)
-    rows = [[] for _ in xs]
-    for r in blocks:
-        own = slice(r, r + _SUBLANES)
-        b_own, pivot = b[:, own], b[:, r:r + 1]
-        late = jnp.exp(b_own - pivot)
-        made = [jnp.zeros((k.shape[0], _SUBLANES, c), _F32) for _ in xs]
-        if r:
-            early = (kf * _decay(pivot - b, token < r)).astype(dt)
-            before = _mm(jnp.concatenate(
-                [xf[:, own] * late for xf in xfs], axis=1).astype(dt),
-                early, (1, 1))
-            made = [before[:, n * _SUBLANES:(n + 1) * _SUBLANES]
-                    for n in range(len(xs))]
-        for j in range(_SUBLANES):
-            since = _decay(b_own - b_own[:, j:j + 1], sub >= j) \
-                * kf[:, r + j:r + j + 1]
-            made = [m + jnp.where(lane == r + j, jnp.sum(
-                xf[:, own] * since, axis=2, keepdims=True), 0.0)
-                for m, xf in zip(made, xfs)]
-        for of_x, m in zip(rows, made):
-            of_x.append(m)
-    return [jnp.concatenate(of_x, axis=1) for of_x in rows]
+def _level_mask(shape, s: int):
+    """Whether level s holds (row, column) or (column, row), both counted
+    modulo c = min(shape): (c, c), or two such stacked or side by side."""
+    c = min(shape)
+    apart = (lax.broadcasted_iota(jnp.int32, shape, 0) % c) \
+        ^ (lax.broadcasted_iota(jnp.int32, shape, 1) % c)
+    return (apart >= s // 2) & (apart < s)
 
 
-def _channel_grams_back(dp, dkk, scale, dp_t, dkk_t, q, k, b):
-    """The cotangents `dp` of G[q, k] and `dkk` * `scale` (a number a row)
-    of G[k, k], with their transposes, back to the rows: (what q's rows get,
-    what k's rows get as G's rows i BEFORE `scale`, what they get as G's
-    columns j), each (heads, c, dk) float32. Rows i take the columns before their block about the block's
-    first row; columns j take the rows behind their block about the next
-    block's first row; a block's own by differences."""
-    dt, c = k.dtype, k.shape[1]
+def _level_decay(b, s: int):
+    """exp(-|b - b_p|) of a head's b: (c, dk), p the middle row of the row's
+    group of s rows: no exponent is positive. Whole registers take b_p as a
+    sublane broadcast; inside a register the earlier half takes the rows s/2
+    ahead and the bit below is folded away: sublane rolls and selects."""
+    c, half = b.shape[0], s // 2
+    if s == 1:
+        return 1.0
+    if s >= _SUBLANES:    # a last group with no later half keeps its rows
+        pivot = jnp.concatenate([
+            jnp.broadcast_to(b[r + half:r + half + 1], b[r:r + s].shape)
+            if r + half < c else b[r:] for r in range(0, c, s)])
+    else:
+        token = lax.broadcasted_iota(jnp.int32, b.shape, 0)
+        pivot = jnp.where(token & half != 0, b, pltpu.roll(b, c - half, 0))
+        if half == 2:
+            pivot = jnp.where(token & 1 != 0, pltpu.roll(pivot, 1, 0), pivot)
+    return jnp.exp(-jnp.abs(b - pivot))
+
+
+def _each_head(fn, *arrays):
+    """`fn` of a head's matrices, a head after the other, so that few
+    registers live at once; its results stacked."""
+    heads = [fn(*(a[h] for a in arrays)) for h in range(arrays[0].shape[0])]
+    return [jnp.stack(x) for x in zip(*heads)]
+
+
+def _dot(a, b, contract):
+    """`_mm` of one head's matrices."""
+    return _mm(a[None], b[None], contract)[0]
+
+
+def _channel_gram_level(q, k, b, s: int):
+    """Level s of a head's G[k, k] and G[q, k]: (x * e)(k * e)^T over the
+    level's pairs either way round, e its decay, 0 elsewhere: one product
+    for both, its operands rounded to the inputs' type (stacked in float32,
+    then cast: a packed array is dear to cut or to join)."""
+    dt, c, e = k.dtype, k.shape[0], _level_decay(b, s)
+    right = k.astype(_F32) * e
+    left = jnp.concatenate([right, q.astype(_F32) * e]).astype(dt)
+    both = jnp.where(_level_mask((2 * c, c), s),
+                     _dot(left, right.astype(dt), (1, 1)), 0.0)
+    return both[:c], both[c:]
+
+
+def _channel_grams(q, k, b):
+    """A head's G[k, k] and G[q, k]: (c, c) float32, sum_c x_ic exp(b_ic -
+    b_jc) k_jc where j <= i, else 0; q, k: (c, dk), b float32, falling down
+    the rows: the sum of the halving's levels, up to the diagonal."""
+    c = k.shape[0]
+    levels = [_channel_gram_level(q, k, b, s) for s in _levels(c)]
+    return [jnp.where(_masks(c)[2], functools.reduce(jnp.add, of_x), 0.0)
+            for of_x in zip(*levels)]
+
+
+def _channel_grams_back(dp, da, dp_t, dkk_t, q, k, b):
+    """A head's cotangents `dp` of G[q, k] and `da` of G[k, k] BEFORE its
+    rows' scale, with the transposes of dp and of the scaled one, back to
+    the rows: (what q's rows get, what k's rows get as G's rows before the
+    scale, what they get as G's columns), each (c, dk) float32. By levels:
+    the cotangent of (x * e) is the level's dG times (k * e), that of (k *
+    e) its transpose times (x * e)."""
+    dt, c = k.dtype, k.shape[0]
     qf, kf = q.astype(_F32), k.astype(_F32)
-    blocks, token, sub = _row_blocks(c)
-    dq, dk_i, dk_j = [], [], []
-    for r in blocks:
-        own, end = slice(r, r + _SUBLANES), r + _SUBLANES
-        b_own, pivot = b[:, own], b[:, r:r + 1]
-        q_own, k_own = qf[:, own], kf[:, own]
-        to_q = to_k = as_j = jnp.zeros_like(b_own)
-        if r:
-            early = (kf * _decay(pivot - b, token < r)).astype(dt)
-            before = _mm(jnp.concatenate(
-                [dp[:, own], dkk[:, own]], axis=1).astype(dt), early, (1, 0))
-            late = jnp.exp(b_own - pivot)
-            to_q = late * before[:, :_SUBLANES]
-            to_k = late * before[:, _SUBLANES:]
-        if end < c:
-            after = b[:, end:end + 1]
-            since = _decay(b - after, token >= end)
-            as_j = jnp.exp(after - b_own) * (
-                _mm(dp_t[:, own].astype(dt), (qf * since).astype(dt), (1, 0))
-                + _mm(dkk_t[:, own].astype(dt), (kf * since).astype(dt),
-                      (1, 0)))
-        for j in range(_SUBLANES):
-            since = _decay(b_own - b_own[:, j:j + 1], sub >= j)
-            at = slice(r + j, r + j + 1)
-            col_p, col_k = dp[:, own, at], dkk[:, own, at]
-            to_j = since * kf[:, at]
-            to_q = to_q + col_p * to_j
-            to_k = to_k + col_k * to_j
-            as_j = as_j + jnp.where(sub == j, jnp.sum(
-                (col_p * q_own + col_k * scale[:, own] * k_own) * since,
-                axis=1, keepdims=True), 0.0)
-        dq.append(to_q)
-        dk_i.append(to_k)
-        dk_j.append(as_j)
-    return tuple(jnp.concatenate(x, axis=1) for x in (dq, dk_i, dk_j))
+    d_rows = jnp.concatenate([dp, da])
+    d_cols = jnp.concatenate([dp_t, dkk_t], axis=1)
+    dq = dk_i = dk_j = jnp.zeros_like(b)
+    for s in _levels(c):
+        e = _level_decay(b, s)
+        right = kf * e
+        rows = _dot(jnp.where(_level_mask(d_rows.shape, s), d_rows, 0.0)
+                    .astype(dt), right.astype(dt), (1, 0))
+        cols = _dot(jnp.where(_level_mask(d_cols.shape, s), d_cols, 0.0)
+                    .astype(dt), jnp.concatenate([qf * e, right]).astype(dt),
+                    (1, 0))
+        dq, dk_i, dk_j = (dq + e * rows[:c], dk_i + e * rows[c:],
+                          dk_j + e * cols)
+    return dq, dk_i, dk_j
 
 
 # --------------------------------------------------------------------------
@@ -469,7 +495,7 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref,
     beta = beta_ref[:, 0]                            # (heads, 1, c)
     eye, below, _ = _masks(q.shape[1])
     # what does not depend on the state
-    kk, p32 = _channel_grams((k, q), k, b)
+    kk, p32 = _each_head(_channel_grams, q, k, b)
     kk = jnp.where(below, kk, 0.0)
     t = _unit_lower_inverse(_as_column(beta, eye) * kk, eye)
     grow, shrink, whole = _channel_terms(b)
@@ -541,8 +567,8 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, tp_ref,
 
     # through G[Q, K] and G[K, K] = A over beta: A's cotangent reaches the
     # rows of K through G[K, K]'s rows times beta, and beta through them
-    dq_g, through_a, dk_j = _channel_grams_back(
-        dp, da, beta_col, dp_t, da_t * beta, q, k, b)
+    dq_g, through_a, dk_j = _each_head(
+        _channel_grams_back, dp, da, dp_t, da_t * beta, q, k, b)
     dk_i = beta_col * through_a
     dq_ref[...] = (grow * dq_in + dq_g).astype(dt)
     dk_ref[...] = (shrink * dk_out + dk_i + dk_j

@@ -130,6 +130,9 @@ def test_phase_kda_scan(smoke, capsys):
         "16 | 16" in out
     assert "chunk 64, 4 chunks a sequence" in out
     assert "2 heads a grid step (" in out
+    assert "its decayed products by 7 levels of a halving, forward / " \
+        "backward a chunk and head: 7 / 14 products, 48 / 48 exp " \
+        "registers, 0 / 0 lane reductions, 0 / 0 lane broadcasts; " in out
     assert "interpret=True, tpu_custom_call in the compiled forward 0, " \
         "forward + backward 0" in out
     assert "from the token-by-token recurrence" in out

@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from horovod_tpu.ops import gated_delta
-from horovod_tpu.ops.gated_delta import (gated_delta_rule, heads_a_step,
+from horovod_tpu.ops.gated_delta import (channel_gram_work, gated_delta_rule,
+                                         heads_a_step,
                                          recurrent_gated_delta_rule,
                                          step_bytes)
 
@@ -140,28 +141,142 @@ def test_with_one_decay_a_head_it_is_the_scalar_kernels_rule(dtype):
         assert float(jnp.max(jnp.abs(g_ - w))) <= 10 * tol * scale, name
 
 
+def _defined(x, k, b, held):
+    """sum_c x_ic exp(b_ic - b_jc) k_jc over the pairs (i, j) that `held`
+    holds, 0 elsewhere, the exponent masked: the definition written out, in
+    float64."""
+    diff = jnp.where(held[None, :, :, None],
+                     b[:, :, None, :] - b[:, None, :, :], -jnp.inf)
+    return jnp.einsum("hic,hijc,hjc->hij", x, jnp.exp(diff), k)
+
+
+@pytest.mark.parametrize("side", ["levels", "cotangents"])
+@pytest.mark.parametrize("c", [8, 16, 64])
 @pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
-def test_the_decayed_products_are_their_definition(strong):
+def test_the_decayed_products_are_their_definition(strong, c, side):
     """G[x, k]_ij = sum_c x_ic exp(b_ic - b_jc) k_jc for j <= i and exactly
-    0 above the diagonal, block by block, against the sum written out in
-    float64 with the exponent masked: under the strong decay too, where a
-    factor exp(-b) does not exist in float32."""
-    heads, c, dk = 2, 32, 8
-    _, k, _, g, _ = _inputs(c, strong=strong, batch=1, heads=heads, dk=dk)
-    x = jax.random.normal(jax.random.PRNGKey(4), (heads, c, dk), jnp.float32)
-    k, b = k[0], jnp.cumsum(g[0], axis=1)
+    0 above the diagonal against the sum written out in float64 with the
+    exponent masked: under the strong decay too, where a factor exp(-b) does
+    not exist in float32. "levels": each level of the halving is the
+    definition over its own pairs, the levels' pairs are disjoint and
+    together the lower triangle, and their sum is G. "cotangents":
+    `_channel_grams_back` is `jax.vjp` of the definition for what q's rows
+    get, what k's rows get as G[k, k]'s rows before beta, and what they get
+    as the columns of both."""
+    heads, dk = 2, 8
+    _, k, _, g, beta = _inputs(c, strong=strong, batch=1, heads=heads, dk=dk)
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = jax.random.normal(keys[0], (heads, c, dk), jnp.float32)
+    k, b, scale = k[0], jnp.cumsum(g[0], axis=1), beta[0][:, :, None]
     if strong:
         with np.errstate(over="ignore"):
             assert not np.all(np.isfinite(np.exp(-np.asarray(b))))
+    b64, x64, k64 = (a.astype(jnp.float64) for a in (b, x, k))
+    upto = np.arange(c)[:, None] >= np.arange(c)[None, :]
+    below = upto & ~np.eye(c, dtype=bool)
+    levels = gated_delta._levels(c)
+    assert levels == [c >> n for n in range(c.bit_length())]
+    if side == "levels":
+        seen = np.zeros((c, c), int)
+        level = jax.jit(gated_delta._channel_gram_level, static_argnums=3)
+        with jax.enable_x64(False):
+            whole = gated_delta._each_head(
+                jax.jit(gated_delta._channel_grams), x, k, b)
+            parts = [gated_delta._each_head(
+                lambda x, k, b: level(x, k, b, s), x, k, b) for s in levels]
+        for s, part in zip(levels, parts):
+            either = np.asarray(gated_delta._level_mask((c, c), s))
+            held = either & upto
+            assert np.array_equal(either, held | held.T)
+            assert np.array_equal(gated_delta._level_mask((2 * c, c), s),
+                                  np.concatenate([either, either]))
+            assert np.array_equal(gated_delta._level_mask((c, 2 * c), s),
+                                  np.concatenate([either, either], axis=1))
+            i, j = np.nonzero(held)
+            assert np.all(i // s == j // s) and np.all(
+                (j % s < s // 2) & (i % s >= s // 2) if s > 1 else i == j)
+            seen += held
+            for got, y64 in zip(part, (k64, x64)):
+                got = np.asarray(got)
+                assert np.all(got[:, ~either] == 0.0)
+                np.testing.assert_allclose(
+                    got[:, held], _defined(y64, k64, b64, held)[:, held],
+                    atol=2e-5, rtol=2e-5)
+        assert np.array_equal(seen, upto.astype(int))
+        for got, y64 in zip(whole, (k64, x64)):
+            assert np.all(np.asarray(got)[:, ~upto] == 0.0)
+            np.testing.assert_allclose(got, _defined(y64, k64, b64, upto),
+                                       atol=2e-5, rtol=2e-5)
+        return
+    dp = jnp.where(upto, jax.random.normal(keys[1], (heads, c, c)), 0.0)
+    da = jnp.where(below, jax.random.normal(keys[2], (heads, c, c)), 0.0)
+    dp, da = dp.astype(jnp.float32), da.astype(jnp.float32)
     with jax.enable_x64(False):
-        (got,) = gated_delta._channel_grams((x,), k, b)
-    b64, x64, k64 = (np.asarray(a, np.float64) for a in (b, x, k))
-    seen = np.arange(c)[:, None] >= np.arange(c)[None, :]
-    diff = np.where(seen[None, :, :, None],
-                    b64[:, :, None, :] - b64[:, None, :, :], -np.inf)
-    want = np.einsum("hic,hijc,hjc->hij", x64, np.exp(diff), k64)
-    assert np.all(np.asarray(got)[:, ~seen] == 0.0)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        got = gated_delta._each_head(
+            jax.jit(gated_delta._channel_grams_back), dp, da,
+            jnp.swapaxes(dp, 1, 2), jnp.swapaxes(da * scale, 1, 2), x, k, b)
+
+    def grams(q, k_rows, k_cols):
+        return (_defined(q, k_cols, b64, upto),
+                _defined(k_rows, k_cols, b64, below))
+
+    _, vjp = jax.vjp(grams, x64, k64, k64)
+    dq, dk_i, _ = vjp((dp.astype(jnp.float64), da.astype(jnp.float64)))
+    dk_j = vjp((dp.astype(jnp.float64), (da * scale).astype(jnp.float64)))[2]
+    for name, ours, want in zip(("q", "k as rows", "k as columns"), got,
+                                (dq, dk_i, dk_j)):
+        assert ours.dtype == jnp.float32 and ours.shape == (heads, c, dk)
+        np.testing.assert_allclose(ours, want, atol=3e-5, rtol=3e-5,
+                                   err_msg=name)
+
+
+def _counted(jaxpr, counts):
+    """Of a traced helper, sub-jaxprs and all: matrix products, float32
+    registers through `exp`, and registers reduced or broadcast along the
+    lanes (the last axis)."""
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _counted(sub, counts)
+        shape = eqn.invars[0].aval.shape if eqn.invars else ()
+        registers = int(np.prod(shape[:-2], dtype=int)) * -(-shape[-2] // 8) \
+            * -(-shape[-1] // 128) if len(shape) >= 2 else 0
+        name = eqn.primitive.name
+        if name == "dot_general":
+            counts["products"] += 1
+        elif name == "exp":
+            counts["exp_registers"] += registers
+        elif name.startswith("reduce_") and len(shape) - 1 in eqn.params[
+                "axes"]:
+            counts["lane_reductions"] += registers
+        elif name == "broadcast_in_dim" and shape and shape[-1] == 1 \
+                and eqn.outvars[0].aval.shape[-1] > 1:
+            counts["lane_broadcasts"] += registers
+    return counts
+
+
+def test_what_the_decayed_products_cost_is_counted_from_the_helpers():
+    """`channel_gram_work` (what `chip_smoke.py` prints) against the traced
+    `_channel_grams` and `_channel_grams_back` of one head at the cell's
+    chunk and width: a product a level forward and two backward, one
+    whole-chunk `exp` a level but the diagonal's, no lane reduction and no
+    lane broadcast."""
+    c, dk = 64, 128
+    x = jax.ShapeDtypeStruct((c, dk), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct((c, dk), jnp.float32)
+    d = jax.ShapeDtypeStruct((c, c), jnp.float32)
+    zero = dict.fromkeys(("products", "exp_registers", "lane_reductions",
+                          "lane_broadcasts"), 0)
+    with jax.enable_x64(False):
+        forward = _counted(jax.make_jaxpr(gated_delta._channel_grams)(
+            x, x, b).jaxpr, dict(zero))
+        backward = _counted(jax.make_jaxpr(gated_delta._channel_grams_back)(
+            d, d, d, d, x, x, b).jaxpr, dict(zero))
+    said = channel_gram_work(c, dk)
+    assert said["levels"] == 7
+    assert {name: (forward[name], backward[name]) for name in zero} == {
+        name: said[name] for name in zero}
+    assert said["products"] == (7, 14) and said["exp_registers"] == (48, 48)
+    assert channel_gram_work(8, 16)["exp_registers"] == (3, 3)
 
 
 def test_what_the_forward_saves_for_the_backward_pass():

@@ -41,7 +41,9 @@ cell runs it, a dense prefix inside a pattern whose stack is two segments,
 the per-channel rule's kernels, sigmoid scores with a selection bias) and left
 the thirteen older texts as they were: the scalar rule of `olmo_hybrid`
 lowers to the kernels it lowered to, the softmax router to the program it
-was.
+was. PR 51 took the Kimi Linear family's entry anew (the per-channel rule's
+kernels make their decayed products by a halving of pivots, a product a
+level) and left the thirteen older texts byte for byte the parent's.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
