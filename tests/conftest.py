@@ -25,8 +25,20 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
+# What the suite compiles for the CPU are stand-ins that run for milliseconds,
+# and compiling them is what the suite pays: six workers on eight cores are
+# bound by CPU work, and LLVM's was a quarter of it (PR 52: 9,202
+# test-seconds a run with its optimizations, 7,166 without). The chip's
+# compiler, where a test compiles for a described chip, does not read the
+# flag: its text and its memory figures are the same either way. A module
+# that holds bits or a tolerance taken under the optimizations asks for them
+# back (`xla_optimizations` below).
+jax.config.update("jax_disable_most_optimizations", True)
 
 import pytest  # noqa: E402
+
+# (helpers whose `assert`s should read like a test's own)
+pytest.register_assert_rewrite("family", "step_cases")
 
 # Every test has this long for set-up, call and teardown together. A test
 # that overruns is not interrupted, it is ended: faulthandler's watchdog
@@ -52,6 +64,17 @@ def pytest_runtest_protocol(item, nextitem):
         return (yield)
     finally:
         faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture(scope="module")
+def xla_optimizations():
+    """XLA's default optimizations, for the module that asks
+    (`pytestmark = pytest.mark.usefixtures("xla_optimizations")`): LLVM
+    without them contracts no multiply-add, so the last bit differs from
+    what a fixture written with them holds."""
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", True)
 
 
 def pytest_addoption(parser):

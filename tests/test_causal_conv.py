@@ -1,15 +1,17 @@
 """`ops/causal_conv.py`: the Pallas kernels (interpreted here) against the
 plain `jnp` form, `reference_causal_conv_silu`: values, the gradients of u
 and of the taps, with and without the L2 normalisation; float32 and bf16;
-lengths below the tap count, off the tile and of several tiles (the halo on
-both sides), several sequences and heads, the cell's widths and an odd one.
-"""
+any tap count, what leaks where, the static numbers. The shapes that differ
+in kind (lengths below the tap count, off the tile and of several tiles,
+several sequences and heads, the cell's widths and an odd one):
+`tests/test_causal_conv_shapes.py`."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import olmo_hybrid as reference
 from horovod_tpu.ops import causal_conv
 from horovod_tpu.ops.causal_conv import (causal_conv_silu, heads_a_step,
                                          least_bytes,
@@ -51,36 +53,6 @@ def _value_and_grads(fn, u, w, cot, l2_scale):
 def _rel(got, want):
     got, want = (np.asarray(x.astype(F32)) for x in (got, want))
     return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
-
-
-#: (batch, heads, tokens, width), tile: fewer tokens than taps; one strip;
-#: a length off the strip; several tiles of one strip (the halo on both
-#: sides is another grid step's) and of two (a strip's halo inside a tile);
-#: several sequences and heads with a head count the block does not hold
-#: whole; the cell's two widths and an odd one
-SHAPES = [
-    pytest.param((1, 2, 3, 8), 1024, id="S3-below-the-taps"),
-    pytest.param((1, 1, 64, 8), 1024, id="S64-one-strip"),
-    pytest.param((2, 3, 20, 8), 1024, id="B2-H3-S20"),
-    pytest.param((2, 3, 200, 7), 64, id="S200-tiles-of-64-odd-width"),
-    pytest.param((1, 2, 300, 16), 128, id="S300-tiles-of-128"),
-    pytest.param((1, 1, 500, 8), 256, id="S500-tiles-of-two-strips"),
-    pytest.param((1, 2, 100, 16), 48, id="S100-tiles-of-48"),
-    pytest.param((2, 5, 130, 96), 64, id="B2-H5-S130-width-96"),
-    pytest.param((1, 2, 70, 192), 64, id="S70-width-192"),
-]
-
-
-@pytest.mark.parametrize("l2_scale", [None, 1.0, 96 ** -0.5],
-                         ids=["plain", "normed", "normed-scaled"])
-@pytest.mark.parametrize("shape,tile", SHAPES, indirect=["tile"])
-def test_values_and_gradients_match_the_jnp_form(shape, tile, l2_scale):
-    u, w, cot = _inputs(shape)
-    got = _value_and_grads(causal_conv_silu, u, w, cot, l2_scale)
-    want = _value_and_grads(reference_causal_conv_silu, u, w, cot, l2_scale)
-    for name, g, r in zip(("y", "du", "dw"), got, want):
-        assert g.shape == r.shape and g.dtype == r.dtype == F32, name
-        assert _rel(g, r) < 2e-6, name
 
 
 @pytest.mark.parametrize("l2_scale", [None, 96 ** -0.5],
@@ -127,6 +99,22 @@ def test_nothing_leaks_across_sequences_heads_or_backwards(tile):
             != causal_conv_silu(u.at[1, 2, 70].add(1.0), w,
                                 l2_scale=l2_scale)), axis=-1))
         assert changed.tolist() == [[1, 2, t] for t in (70, 71, 72, 73)]
+
+
+def test_the_convolution_is_causal():
+    """A change at token t moves nothing before t, in the mixer's output
+    and so in the model's logits; and the kernel is the Olmo-Hybrid
+    reference's shifted adds."""
+    u = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 20, 8), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(3), (3, 8, 4), jnp.float32)
+    base = causal_conv_silu(u, w)
+    moved = causal_conv_silu(u.at[:, :, 11].add(1.0), w)
+    changed = np.flatnonzero(np.any(np.asarray(base != moved),
+                                    axis=(0, 1, 3)))
+    assert changed.tolist() == [11, 12, 13, 14]       # four taps
+    # (the reference's layout is (B, S, H, d))
+    want = reference.causal_conv(u.transpose(0, 2, 1, 3), w)
+    np.testing.assert_allclose(base, want.transpose(0, 2, 1, 3), atol=1e-6)
 
 
 @tiles_of(64)

@@ -8,26 +8,28 @@ mixer alone, logits, loss and every leaf's gradient, `attn` "local" and
 "flash"; every planted fault refused by the family's limits; the shares of
 the experts adding up to the uncut layer with the shared MLP counted once,
 and the held heads' scan output the matching slice of the whole mixer's;
-`dp` = 2 against one rank; the scopes of the compiled step; the multipliers'
-defaults; and what `validate_cfg_for_mesh` refuses."""
+the multipliers' defaults; and what `validate_cfg_for_mesh` refuses. (Loss,
+gradients, `dp` = 2, the train step and remat:
+`tests/test_granite_hybrid_grads.py`; the scopes of the compiled step:
+`tests/test_step_scopes.py`.) Every program is `tests/family.py`'s, built
+once for the module."""
 
 import dataclasses
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import family as programs
 from benchmark.families import granite_hybrid as family
-from benchmark.harness import hlo, scope_time
 from benchmark.reference import granite_hybrid as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import mixers, transformer as tfm
 from horovod_tpu.ops import ssd_scan as ssd
-from horovod_tpu.parallel import MeshSpec, build_mesh, moe_ffn
+from family import mesh_of
+from horovod_tpu.parallel import moe_ffn
 
 KINDS = ("mamba2", "mamba2", "full", "mamba2")
 TOP_K, FIRST = 3, 2
@@ -51,15 +53,8 @@ SEQ = 32
 ATTNS = ("local", "flash")
 
 
-def mesh_of(**sizes):
-    spec = MeshSpec(**sizes)
-    return build_mesh(spec, jax.devices()[:spec.total])
-
-
 def _data(batch=2, seq=SEQ):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                CFG.vocab, jnp.int32)
-    return tokens, jnp.roll(tokens, -1, axis=1)
+    return programs.data(CFG.vocab, batch, seq)
 
 
 #: what `_lively` multiplies the drawn leaves by
@@ -87,27 +82,29 @@ def _lively(params):
 @pytest.fixture(scope="module")
 def params():
     with jax.enable_x64(False):
-        return _lively(tfm.init(jax.random.PRNGKey(0), CFG))
-
-
-@pytest.fixture(scope="module", params=ATTNS)
-def ours(request, params):
-    """(loss, gradients) of the program on one rank, by each algorithm."""
-    tokens, targets = _data()
-    cfg = dataclasses.replace(CFG, attn=request.param)
-    with jax.enable_x64(False):
-        return jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-            params, tokens, targets)
+        return _lively(programs.init(CFG))
 
 
 @pytest.fixture(scope="module")
-def theirs(params):
-    """(loss, gradients) of the reference, in the program's tree."""
-    tokens, targets = _data()
+def logits(params):
+    """The program's logits for `_data()`'s tokens, once."""
     with jax.enable_x64(False):
-        return jax.value_and_grad(lambda p: reference.loss(
-            family.reference_weights(p, KINDS), tokens, targets, KINDS,
-            TOP_K, FIRST))(params)
+        return programs.forward(CFG)(params, _data()[0])
+
+
+@pytest.fixture(scope="module")
+def their_logits(params):
+    with jax.enable_x64(False):
+        return reference.forward(family.reference_weights(params, KINDS),
+                                 _data()[0], KINDS, TOP_K, FIRST)
+
+
+@pytest.fixture(scope="module")
+def sound(params, logits):
+    """The family's comparison of `logits` with the sound reference."""
+    with jax.enable_x64(False):
+        return family.compare(params, _data()[0], logits, KINDS, TOP_K,
+                              FIRST)
 
 
 def test_the_tree_has_each_kinds_leaves_and_no_others(params):
@@ -131,21 +128,14 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
     assert mamba["router"].shape == (1, 3, 64, 8)      # the router is whole
     assert mamba["we_gate"].shape == (1, 3, 2, 64, 24)     # two are held
     assert mamba["ws1"].shape == (1, 3, 64, 48)            # shared: 2 x 24
-    specs, axes = tfm.param_specs(CFG), tfm.grad_reduce_axes(CFG)
-    structure = jax.tree_util.tree_structure(params)
-    assert jax.tree_util.tree_structure(specs) == structure
-    assert jax.tree_util.tree_structure(
-        jax.tree_util.tree_map(lambda x: 0, axes,
-                               is_leaf=lambda x: isinstance(x, tuple))) \
-        == structure
+    programs.assert_specs_cover(CFG, params)
 
 
 def test_the_seeded_leaves_are_mamba_2s_own():
     """A ~ U(1, 16) held as its logarithm, the step's bias the inverse
     softplus of log-U(0.001, 0.1), D = 1."""
-    with jax.enable_x64(False):
-        p = tfm.init(jax.random.PRNGKey(3), dataclasses.replace(
-            CFG, ssd_heads=64))["layers"]["mamba2"]
+    p = programs.init(dataclasses.replace(CFG, ssd_heads=64),
+                      3)["layers"]["mamba2"]
     rate = np.exp(np.asarray(p["ssd_a_log"]))
     assert rate.min() >= 1 and rate.max() <= 16 and rate.std() > 3
     step = np.asarray(jax.nn.softplus(p["ssd_dt_bias"]))
@@ -176,52 +166,22 @@ def test_the_mixer_alone_equals_the_references(params):
 
 
 @pytest.mark.parametrize("attn", ATTNS)
-def test_logits_equal_the_references(params, attn):
-    tokens, _ = _data()
-    cfg = dataclasses.replace(CFG, attn=attn)
+def test_logits_equal_the_references(params, their_logits, attn):
     with jax.enable_x64(False):
-        got = jax.jit(tfm.build_forward(cfg, mesh_of()))(params, tokens)
-        want = reference.forward(family.reference_weights(params, KINDS),
-                                 tokens, KINDS, TOP_K, FIRST)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
-
-
-def test_loss_equals_the_references(ours, theirs):
-    np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
-
-
-def _leaves(tree):
-    return {jax.tree_util.keystr(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-LEAVES = sorted(_leaves(jax.eval_shape(lambda k: tfm.init(k, CFG),
-                                       jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
-def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
-    """Among them `ssd_a_log` and `ssd_dt_bias`, whose gradients come
-    through the running sums of the chunked form, the two leaves of the
-    input projection, and the tied embedding's, read twice."""
-    got, want = _leaves(ours[1])[leaf], _leaves(theirs[1])[leaf]
-    size = float(jnp.max(jnp.abs(want)))
-    assert size > 1e-7, "nothing to compare"
-    np.testing.assert_allclose(got, want, rtol=2e-3,
-                               atol=2e-4 * size + 1e-8)
+        got = programs.forward(dataclasses.replace(CFG, attn=attn))(
+            params, _data()[0])
+    np.testing.assert_allclose(got, their_logits, atol=2e-5, rtol=2e-4)
 
 
 # --------------------------------------------------------------- the limits
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, fault):
+def test_the_limits_refuse_a_planted_fault(params, logits, sound, fault):
     """The program's logits against the reference computed with one
     mechanism wrong: by one of the family's limits it is not correct, and
     against the sound reference it is, with room."""
     tokens, _ = _data()
     with jax.enable_x64(False):
-        logits = jax.jit(tfm.build_forward(CFG, mesh_of()))(params, tokens)
-        sound = family.compare(params, tokens, logits, KINDS, TOP_K, FIRST)
         wrong = family.compare(params, tokens, logits, KINDS, TOP_K, FIRST,
                                fault=fault)
     assert all(family.within(*(float(x) for x in sound[:3])))
@@ -236,10 +196,9 @@ def test_the_limits_refuse_a_planted_fault(params, fault):
 
 @pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
                          ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, operands):
+def test_the_limits_refuse_an_8_bit_float(params, logits, operands):
     tokens, _ = _data()
     with jax.enable_x64(False):
-        logits = jax.jit(tfm.build_forward(CFG, mesh_of()))(params, tokens)
         rms, got, want, _ = family.compare(
             params, tokens, logits, KINDS, TOP_K, FIRST, operands=operands)
     assert not all(family.within(float(rms), float(got), float(want)))
@@ -266,7 +225,7 @@ def test_the_familys_comparison_reads_zero_for_the_reference(params):
         == [int(rows[:, e].sum()) for e in (0, 1)]
 
 
-def test_check_logits_knows_the_configuration_by_its_shapes(params):
+def test_check_logits_knows_the_configuration_by_its_shapes(params, logits):
     """What `check_logits` cannot read off an array it takes from the
     configuration `transformer_config` was asked about."""
     config = {
@@ -295,10 +254,8 @@ def test_check_logits_knows_the_configuration_by_its_shapes(params):
     assert cfg == CFG
     assert family.kinds(config) == KINDS == family.pattern(config)
     assert family.first_expert(config) == FIRST
-    tokens, _ = _data()
     with jax.enable_x64(False):
-        logits = jax.jit(tfm.build_forward(cfg, mesh_of()))(params, tokens)
-        found = family.check_logits(params, tokens, logits)
+        found = family.check_logits(params, _data()[0], logits)
     assert found["ok"], found
     assert "rows of the 2 held experts" in found["detail"]
     with pytest.raises(ValueError, match="no equations for"):
@@ -419,110 +376,11 @@ def test_the_held_heads_scan_is_the_whole_mixers_slice(params):
                                        rtol=2e-4, atol=2e-5)
 
 
-# ------------------------------------------------------ meshes, step, remat
-
-def test_dp2_equals_one_rank(params):
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        want_loss, want = jax.jit(tfm.build_loss_and_grads(CFG, mesh_of()))(
-            params, tokens, targets)
-        mesh = mesh_of(dp=2)
-        tfm.validate_cfg_for_mesh(CFG, mesh)
-        loss, grads = jax.jit(tfm.build_loss_and_grads(CFG, mesh))(
-            tfm.shard_params(params, CFG, mesh), tokens, targets)
-    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-    for (path, got), w in zip(
-            jax.tree_util.tree_flatten_with_path(grads)[0],
-            jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(
-            got, w, rtol=1e-4, atol=1e-6,
-            err_msg=jax.tree_util.keystr(path))
-
-
-def test_a_train_step_lowers_the_loss_and_counts_what_it_drops(params):
-    tokens, targets = _data()
-    mesh, opt = mesh_of(), optax.adamw(1e-2)
-    cfg = dataclasses.replace(CFG, remat=True, attn="flash")
-    with jax.enable_x64(False):
-        # (the step donates its state: a copy, not the fixture's arrays)
-        state = [tfm.shard_params(jax.tree_util.tree_map(jnp.copy, params),
-                                  cfg, mesh)]
-        state.append(tfm.init_opt_state(opt, state[0], mesh))
-        step = tfm.build_train_step(cfg, mesh, opt, metrics=True)
-        losses = []
-        for _ in range(3):
-            state[0], state[1], loss, counts = step(state[0], state[1],
-                                                    tokens, targets)
-            losses.append(float(loss))
-            assert int(counts["experts_dropped"]) == 0
-    assert losses[2] < losses[0], losses
-
-
-def test_remat_changes_no_result(params):
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        want_loss, want = jax.jit(tfm.build_loss_and_grads(CFG, mesh_of()))(
-            params, tokens, targets)
-    for policy in ("dots", "full"):
-        cfg = dataclasses.replace(CFG, remat=True, remat_policy=policy)
-        with jax.enable_x64(False):
-            loss, grads = jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-                params, tokens, targets)
-        np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-        for got, w in zip(jax.tree_util.tree_leaves(grads),
-                          jax.tree_util.tree_leaves(want)):
-            np.testing.assert_allclose(got, w, rtol=1e-4, atol=1e-7)
-
-
-# ------------------------------------------------- scopes and multipliers
-
-def _compiled_step(cfg):
-    opt = optax.adamw(1e-3)
-    with jax.enable_x64(False):   # as the benchmark runs
-        shapes = jax.eval_shape(lambda k: tfm.init(k, cfg),
-                                jax.random.PRNGKey(0))
-        state = jax.eval_shape(opt.init, shapes)
-        tokens = jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
-        return tfm.build_train_step(cfg, mesh_of(), opt, metrics=True).lower(
-            shapes, state, tokens, tokens).compile().as_text()
-
-
-def test_no_instruction_of_the_new_layer_lies_outside_a_scope():
-    """The two products of the input projection under `ssd.project`, the
-    shifted sums under `ssd.conv`, the softplus, the decays and the
-    kernels under `ssd.scan`, the gate and the norm's rsqrt under
-    `ssd.gate`, the output product under `ssd.out`; the renormalised
-    weights under `moe.route` and the shared MLP under `moe.shared`."""
-    text = _compiled_step(dataclasses.replace(CFG, attn="flash", remat=True))
-    table = hlo.index(text)
-    ops = dict(re.findall(r'%?([\w.\-]+) = [^\n]*op_name="([^"]*)"', text))
-
-    def under(prefix):
-        return {ops[name] for name in scope_time.names_under(text, table,
-                                                              prefix)}
-
-    assert set(tfm.STEP_SCOPES) >= {"ssd.project", "ssd.conv", "ssd.scan",
-                                    "ssd.gate", "ssd.out"}
-    assert any("ssd.project/" in op and op.endswith("/dot_general")
-               for op in under("ssd.project"))
-    assert under("ssd.conv") and under("ssd.out")
-    scan = under("ssd.scan")
-    assert any(op.endswith("/exp") for op in scan)     # the decays
-    assert any("softplus" in op or "log1p" in op or "logaddexp" in op
-               for op in scan)
-    assert any(op.endswith("rsqrt") for op in under("ssd.gate"))
-    assert any(op.endswith("moe.route/div") for op in under("moe.route"))
-    assert under("moe.shared")
-    # forward and backward, every scope
-    for scope in ("ssd.project", "ssd.conv", "ssd.scan", "ssd.gate",
-                  "ssd.out"):
-        assert any("transpose(" in op for op in under(scope)), scope
-        assert any("transpose(" not in op for op in under(scope)), scope
-
+# --------------------------------------------------------- the multipliers
 
 def test_the_multipliers_default_to_no_change():
     """(1, 1, none, 1): a configuration that states none lowers to the
-    program it lowered to before they existed (`tests/test_lowered_steps.py`
+    program it lowered to before they existed (`tests/test_step_scopes.py`
     holds the text), and `score_scale` is YaRN's alone."""
     plain = tfm.TransformerConfig()
     assert (plain.embed_scale, plain.residual_scale, plain.attn_scale,
@@ -543,20 +401,20 @@ def test_the_multipliers_default_to_no_change():
 @pytest.mark.parametrize("field, fault", [
     ("embed_scale", None), ("residual_scale", "unit_residual"),
     ("attn_scale", "sqrt_scale"), ("logit_scale", "unscaled_logits")])
-def test_each_multiplier_is_in_the_program(params, field, fault):
+def test_each_multiplier_is_in_the_program(params, their_logits, field,
+                                           fault):
     """Without one multiplier the program's logits are the reference's with
     the matching fault (the embedding's has none: they just differ)."""
     tokens, _ = _data()
     default = tfm.TransformerConfig.__dataclass_fields__[field].default
     cfg = dataclasses.replace(CFG, **{field: default})
     with jax.enable_x64(False):
-        got = jax.jit(tfm.build_forward(cfg, mesh_of()))(params, tokens)
-        weights = family.reference_weights(params, KINDS)
-        sound = reference.forward(weights, tokens, KINDS, TOP_K, FIRST)
-        assert float(jnp.max(jnp.abs(got - sound))) > 1e-2
+        got = programs.forward(cfg)(params, tokens)
+        assert float(jnp.max(jnp.abs(got - their_logits))) > 1e-2
         if fault:
-            want = reference.forward(weights, tokens, KINDS, TOP_K, FIRST,
-                                     fault=fault)
+            want = reference.forward(
+                family.reference_weights(params, KINDS), tokens, KINDS,
+                TOP_K, FIRST, fault=fault)
             np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-3)
 
 

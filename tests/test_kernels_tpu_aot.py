@@ -1,21 +1,10 @@
-"""Flash attention (with a window and grouped K/V too), the grouped matmul,
-the gated delta rule, the convolution in front of it and the selective scan
-compiled by the chip's own compiler, without a chip.
-
-The flagship LM's default attention is the Pallas flash kernel
-(ops/flash_attention.py). The interpreter runs of
-tests/test_flash_attention.py cover its numerics; these cases cover
-what the interpreter cannot: that Mosaic accepts the forward and the
-two backward kernels for a described v5e at the shapes the main path
-uses — the flagship LM's (B*H, S, dh) = (12*16, 1024, 128), the
-benchmark's LM cells' (4*16, 2048, 128) and `olmoe-1chip`'s
-(2*16, 4096, 128), whose diagonal blocks are walked in tiles (a tile
-shape Mosaic refuses fails here, not first on the chip), and the
-long-context S=8192 — that the three custom calls keep the operands and
-results the benchmark's readers know them by, and that `jax_enable_x64`
-(which this suite's conftest turns on) does not matter to a kernel
-compiled for the chip.
-The expert layer's grouped matmuls (ops/grouped_matmul.py) likewise: the
+"""The grouped matmul, the gated delta rule, the convolution in front of it
+and the scans compiled by the chip's own compiler, without a chip: what the
+interpreter cannot show, that Mosaic accepts each kernel for a described v5e
+at the shapes the main path uses and that its custom calls keep the operands
+and results the benchmark's readers know them by. (The flash kernels and
+`olmohybrid-1chip`'s whole step: `tests/test_flash_tpu_aot.py`.)
+The expert layer's grouped matmuls (ops/grouped_matmul.py): the
 three products at the two expert cells' shapes, each custom call with the
 operands and the result `benchmark/harness/scopes.py` tells a grouped
 matmul by. The sum of rows that count (ops/row_gather.py) at the three
@@ -32,72 +21,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
-
-FLAGSHIP = (12, 16, 1024, 128)   # chip_smoke.py flagship LM
-LM_CELLS = (4, 16, 2048, 128)    # lm-1chip, lm-dp4 per chip
-OLMOE_CELL = (2, 16, 4096, 128)  # olmoe-1chip
-LONG = (1, 16, 8192, 128)
-
-#: (operands, results) of the forward (q, k, v -> o, lse), dk/dv and dq
-#: (q, k, v, o, do, lse -> ...) custom calls.
-SIGNATURES = {"fwd": [(3, 2)], "bwd": [(3, 2), (6, 1), (6, 2)]}
-
-
-def _fwd(q, k, v):
-    return flash_attention(q, k, v, causal=True)
-
-
-def _bwd(q, k, v):
-    return jax.grad(
-        lambda q, k, v: _fwd(q, k, v).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))(q, k, v)
-
-
-CASES = [
-    pytest.param(name, shape, False, id=f"{name}-{sid}")
-    for shape, sid in ((FLAGSHIP, "S1024"), (LM_CELLS, "S2048"),
-                       (OLMOE_CELL, "S4096"), (LONG, "S8192"))
-    for name in ("fwd", "bwd")
-] + [
-    # x64 on: one case, the one that compiles all three kernels
-    pytest.param("bwd", FLAGSHIP, True, id="bwd-S1024-x64"),
-]
-
-
-@pytest.mark.parametrize("name,shape,x64", CASES)
-def test_flash_attention_compiles_for_v5e(monkeypatch, name, shape, x64):
-    from tpu_probe import (compile_kernel_text, mosaic_signatures,
-                           tpu_topology)
-
-    topo = tpu_topology(monkeypatch)
-    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    want = SIGNATURES[name]
-    with jax.enable_x64(x64):
-        txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
-                                  (q, q, q), n_calls=len(want))
-    assert mosaic_signatures(txt) == want
-
-
-@pytest.mark.parametrize("name", ["fwd", "bwd"])
-def test_flash_attention_at_unequal_widths_compiles_for_v5e(monkeypatch,
-                                                            name):
-    """`dsv2lite-1chip`'s latent attention: 192-wide queries and keys,
-    128-wide values (PR 30). The kernels take two lane tiles a row and are
-    given 32 MiB of VMEM (inside the train step the dk/dv kernel needs 17.2,
-    which `benchmark.aot_check` of the cell guards; alone it fits 16)."""
-    from tpu_probe import (compile_kernel_text, mosaic_signatures,
-                           tpu_topology)
-
-    topo = tpu_topology(monkeypatch)
-    wide = jax.ShapeDtypeStruct(OLMOE_CELL[:3] + (192,), jnp.bfloat16)
-    v = jax.ShapeDtypeStruct(OLMOE_CELL, jnp.bfloat16)
-    want = SIGNATURES[name]
-    txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
-                              (wide, wide, v), n_calls=len(want))
-    assert mosaic_signatures(txt) == want
-    assert "vmem_limit_bytes" in txt or "33554432" in txt
+from test_flash_tpu_aot import HYBRID_CELL, PHI4_FLASH
 
 
 #: (rows, D, F, experts) of the expert cells: all 64 experts and every
@@ -335,22 +260,7 @@ def test_the_layer_scan_copies_no_expert_leaf_out_of_its_stack(monkeypatch):
         "slices": 6, "adds": 0, "fills": 3}
 
 
-HYBRID_CELL = (1, 30, 8192, 128)   # olmohybrid-1chip's full layer
 HYBRID_RULE = (1, 30, 8192, 96, 192)   # its linear layers: keys | values
-
-
-@pytest.mark.parametrize("name", ["fwd", "bwd"])
-def test_flash_attention_at_the_hybrid_cells_shape_compiles_for_v5e(
-        monkeypatch, name):
-    from tpu_probe import (compile_kernel_text, mosaic_signatures,
-                           tpu_topology)
-
-    topo = tpu_topology(monkeypatch)
-    q = jax.ShapeDtypeStruct(HYBRID_CELL, jnp.bfloat16)
-    want = SIGNATURES[name]
-    txt = compile_kernel_text(topo, {"fwd": _fwd, "bwd": _bwd}[name],
-                              (q, q, q), n_calls=len(want))
-    assert mosaic_signatures(txt) == want
 
 
 #: (operands, results) of the gated delta rule's Mosaic kernels: the forward
@@ -480,37 +390,6 @@ def test_causal_conv_compiles_for_v5e(monkeypatch, width, l2_scale, name):
                                              HYBRID_CELL) == {}
 
 
-def test_the_hybrid_cells_step_compiles_at_full_size_and_fits(monkeypatch):
-    """`benchmark.aot_check olmohybrid-1chip` as a test: the whole train
-    step at the published widths, 1 x 8,192 tokens, for a described v5e: it
-    compiles, leaves `HEADROOM_GIB` of the chip's memory and holds the
-    full layer's flash kernels and the three linear layers' (half a minute
-    here)."""
-    from benchmark import aot_check
-    from benchmark.harness import peaks, spec
-    from tpu_probe import _no_persistent_cache, tpu_topology
-
-    topo = tpu_topology(monkeypatch)
-    cell = spec.load_cell("olmohybrid-1chip")
-    hbm = peaks.for_kind(aot_check.DEVICE_KIND).hbm_bytes
-    with jax.enable_x64(False), _no_persistent_cache():
-        found, problems = aot_check.check_cell(cell, topo.devices, hbm)
-    assert problems == [], found
-    # 4: the flash forward, its remat repeat (each layer of a period is its
-    # own checkpoint behind a barrier), dk/dv and dq; 9: for each of the
-    # three linear layers the gated delta rule's forward, its remat repeat
-    # (which writes the backward's residuals) and its backward kernel; 27:
-    # for each of them the convolution of q, of k and of v, each forward,
-    # repeated under remat and backward (`ops/causal_conv.py`, PR 35)
-    assert "40 tpu_custom_call" in found and "(1 chip(s))" in found
-    need = float(found.split("needs ")[1].split(" GiB")[0])
-    # over a quarter of the chip's memory, and what PERF.md says (14.29
-    # while the "dots" policy kept the `jnp` rule's products, until PR 33;
-    # 10.95 while the convolution's float32 passes went through HBM, until
-    # PR 35)
-    assert 0.25 * hbm / 2 ** 30 < need == pytest.approx(10.53, abs=0.15)
-
-
 def test_interpret_decision_is_shared_and_visible(monkeypatch):
     """One helper decides interpreter-vs-Mosaic for every kernel family;
     on the CPU suite it says "interpret", and flipping it flips the
@@ -528,8 +407,6 @@ def test_interpret_decision_is_shared_and_visible(monkeypatch):
 #: layer's scan, and one softmax of differential attention: (batch, query
 #: pairs, K/V pairs, seq, keys' width, values' width), a window of 512
 PHI4_SCAN = (1, 8192, 5120, 16)
-PHI4_FLASH = (1, 20, 10, 8192, 64, 128)
-PHI4_WINDOW = 512
 
 #: (operands, results) of the selective scan's Mosaic kernels: the forward
 #: (c, delta, A, B and C along the lanes, D -> y), with a gradient asked the
@@ -615,97 +492,3 @@ def test_ssd_scan_compiles_for_v5e(monkeypatch, name):
     assert mosaic_signatures(txt) == want
     assert " while(" not in txt
     assert scopes.grouped_kernels(hlo.index(txt)) == {}
-
-
-@pytest.mark.parametrize("window", [None, PHI4_WINDOW],
-                         ids=["full", "window512"])
-@pytest.mark.parametrize("name", ["fwd", "bwd"])
-def test_grouped_windowed_flash_compiles_for_v5e(monkeypatch, name, window):
-    """One softmax of `phi4flash-1chip`'s differential attention: 20 query
-    pairs of 64 over 10 K/V pairs, values 128 wide, 8,192 tokens, with and
-    without the 512-key window. The three custom calls keep the operands and
-    results the readers know a flash kernel by, dk and dv come out at the
-    K/V heads' count, and the cell's reader finds all of them by shape."""
-    from benchmark.harness import hlo
-    from benchmark.layer_metrics import diff_flash_roofline
-    from tpu_probe import (compile_kernel_text, mosaic_signatures,
-                           tpu_topology)
-
-    topo = tpu_topology(monkeypatch)
-    b, h, g, s, dk, dv = PHI4_FLASH
-    avals = (jax.ShapeDtypeStruct((b, h, s, dk), jnp.bfloat16),
-             jax.ShapeDtypeStruct((b, g, s, dk), jnp.bfloat16),
-             jax.ShapeDtypeStruct((b, g, s, dv), jnp.bfloat16))
-
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=window)
-
-    def bwd(q, k, v):
-        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
-                        argnums=(0, 1, 2))(q, k, v)
-
-    want = SIGNATURES[name]
-    txt = compile_kernel_text(topo, {"fwd": fwd, "bwd": bwd}[name], avals,
-                              n_calls=len(want))
-    assert mosaic_signatures(txt) == want
-    kinds = diff_flash_roofline.flash_kernels(hlo.index(txt), PHI4_FLASH)
-    assert sorted(kinds.values()) == {"fwd": ["forward"], "bwd": [
-        "dkdv", "dq", "forward"]}[name]
-
-
-#: The six shapes the benchmark's cells run the flash kernels at: (query
-#: heads, K/V heads, seq, keys' width, values' width, window), a chip's batch
-#: folded into the heads as the kernels see it
-CELL_FLASH = {
-    "lm-1chip": (64, 64, 2048, 128, 128, None),           # and lm-dp4
-    "olmoe-1chip": (32, 32, 4096, 128, 128, None),
-    "dsv2lite-1chip": (32, 32, 4096, 192, 128, None),
-    "olmohybrid-1chip": (30, 30, 8192, 128, 128, None),
-    "phi4flash-1chip-full": PHI4_FLASH[1:] + (None,),
-    "phi4flash-1chip-window": PHI4_FLASH[1:] + (PHI4_WINDOW,),
-}
-
-
-def _pallas_grids(jaxpr, found=None):
-    """The grid of every `pallas_call` in a jaxpr, in the order met."""
-    found = [] if found is None else found
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append(tuple(eqn.params["grid_mapping"].grid))
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)    # a ClosedJaxpr's
-                if hasattr(sub, "eqns"):
-                    _pallas_grids(sub, found)
-    return found
-
-
-@pytest.mark.parametrize("cell", list(CELL_FLASH))
-def test_the_cells_flash_grids_hold_only_the_blocks_the_mask_holds(
-        monkeypatch, cell):
-    """At each cell's shape the three kernels compile for v5e with the
-    operands and results the benchmark's readers find them by (3 | 6 | 6
-    and 2 | 2 | 1: the grid of held blocks took no scalar-prefetch operand),
-    and their grids are (heads, held pairs) and (K/V heads, held pairs x
-    group): 3 of 4 block pairs at 2,048, 10 of 16 at 4,096, 36 of 64 at
-    8,192, 15 of 64 under the 512-token window."""
-    from tpu_probe import (compile_kernel_text, mosaic_signatures,
-                           tpu_topology)
-
-    topo = tpu_topology(monkeypatch)
-    h, g, s, dk, dv, window = CELL_FLASH[cell]
-    avals = (jax.ShapeDtypeStruct((1, h, s, dk), jnp.bfloat16),
-             jax.ShapeDtypeStruct((1, g, s, dk), jnp.bfloat16),
-             jax.ShapeDtypeStruct((1, g, s, dv), jnp.bfloat16))
-
-    def bwd(q, k, v):
-        return jax.grad(lambda *a: flash_attention(
-            *a, causal=True, window=window).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-
-    txt = compile_kernel_text(topo, bwd, avals, n_calls=3)
-    assert mosaic_signatures(txt) == SIGNATURES["bwd"]
-    held = {(2048, None): 3, (4096, None): 10, (8192, None): 36,
-            (8192, PHI4_WINDOW): 15}[s, window]
-    assert sorted(_pallas_grids(jax.make_jaxpr(bwd)(*avals).jaxpr)) == \
-        sorted([(h, held), (h, held), (g, held * (h // g))])

@@ -18,11 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family as programs
 from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear as reference
 from horovod_tpu.models import mixers, transformer as tfm
 from horovod_tpu.ops import gated_delta
-from horovod_tpu.parallel import MeshSpec, build_mesh
 
 PATTERN = ("kda", "kda", "kda", "mla")
 KINDS = PATTERN + PATTERN[:1]       # five layers: the least the cell's rule leaves
@@ -59,22 +59,8 @@ def chunks_of_eight():
     gated_delta.gated_delta_rule = real
 
 
-def mesh_of(**sizes):
-    spec = MeshSpec(**sizes)
-    return build_mesh(spec, jax.devices()[:spec.total])
-
-
 def _data(batch=2, seq=SEQ):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                CFG.vocab, jnp.int32)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _init(cfg, seed=0):
-    """`tfm.init`'s tree, made by one compiled program."""
-    with jax.enable_x64(False):
-        return jax.jit(lambda key: tfm.init(key, cfg))(
-            jax.random.PRNGKey(seed))
+    return programs.data(CFG.vocab, batch, seq)
 
 
 #: what `_lively` multiplies the drawn leaves by
@@ -106,7 +92,7 @@ def _lively(params):
 
 @pytest.fixture(scope="module")
 def params():
-    return _lively(_init(CFG))
+    return _lively(programs.init(CFG))
 
 
 @pytest.fixture(scope="module", params=ATTNS)
@@ -115,15 +101,14 @@ def ours(request, params):
     tokens, targets = _data()
     cfg = dataclasses.replace(CFG, attn=request.param)
     with jax.enable_x64(False):
-        return jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-            params, tokens, targets)
+        return programs.loss_and_grads(cfg)(params, tokens, targets)
 
 
 @pytest.fixture(scope="module")
 def system_logits(params):
     """The program's logits for `_data()`'s tokens, once."""
     with jax.enable_x64(False):
-        return jax.jit(tfm.build_forward(CFG, mesh_of()))(params, _data()[0])
+        return programs.forward(CFG)(params, _data()[0])
 
 
 @pytest.fixture(scope="module")
@@ -169,21 +154,15 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
     assert first["mla"]["router_bias"].shape == (1, 1, 16)
     assert first["mla"]["we_gate"].shape == (1, 1, 4, 64, 24)   # four held
     assert first["mla"]["ws1"].shape == (1, 1, 64, 24)          # one shared
-    specs, axes = tfm.param_specs(CFG), tfm.grad_reduce_axes(CFG)
-    structure = jax.tree_util.tree_structure(params)
-    assert jax.tree_util.tree_structure(specs) == structure
-    assert jax.tree_util.tree_structure(
-        jax.tree_util.tree_map(lambda x: 0, axes,
-                               is_leaf=lambda x: isinstance(x, tuple))) \
-        == structure
+    programs.assert_specs_cover(CFG, params)
 
 
 def test_the_seeded_leaves_are_gated_deltanets_own():
     """A ~ U(0, 16) a head held as its logarithm, the step's bias a channel
     the inverse softplus of log-U(0.001, 0.1); the selection bias and the
     gate's bias zero, the norms' scales one."""
-    p = _init(dataclasses.replace(CFG, gdn_heads=64, n_layers=8),
-              seed=3)["layers"][1]["kda"]
+    p = programs.init(dataclasses.replace(CFG, gdn_heads=64, n_layers=8),
+                      3)["layers"][1]["kda"]
     rate = np.exp(np.asarray(p["kda_a_log"]))
     assert rate.min() >= 1e-3 * 0.999 and rate.max() <= 16 and rate.std() > 3
     step = np.asarray(jax.nn.softplus(p["kda_dt_bias"]))
@@ -224,22 +203,13 @@ def test_loss_equals_the_references(ours, theirs):
     np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
 
 
-def _leaves(tree):
-    return {jax.tree_util.keystr(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-LEAVES = sorted(_leaves(jax.eval_shape(lambda k: tfm.init(k, CFG),
-                                       jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
+@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
 def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
     """Among them `kda_a_log`, `kda_dt_bias` and the decay's two matrices,
     whose gradients come through the running sums of the chunked form and
     the decayed products, and the selection bias, which takes none on either
     side: it chooses and never weighs."""
-    got, want = _leaves(ours[1])[leaf], _leaves(theirs[1])[leaf]
+    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
     size = float(jnp.max(jnp.abs(want)))
     if "router_bias" in leaf:
         assert size == 0.0 == float(jnp.max(jnp.abs(got)))
@@ -365,10 +335,8 @@ def test_the_familys_counts_at_the_published_widths():
                            "kimi-linear-48b-a3b.json")) as f:
         config = json.load(f)
     cfg = family.transformer_config(config)
-    shapes = jax.eval_shape(lambda k: tfm.init(k, cfg),
-                            jax.random.PRNGKey(0))
-    count = {jax.tree_util.keystr(p): int(np.prod(x.shape)) for p, x in
-             jax.tree_util.tree_leaves_with_path(shapes)}
+    count = {name: int(np.prod(x.shape))
+             for name, x in programs.leaves(programs.shapes(cfg)).items()}
 
     def of(*parts, without=()):
         return sum(n for name, n in count.items()

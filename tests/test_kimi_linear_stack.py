@@ -14,13 +14,15 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import family as programs
 from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear as reference
+from family import mesh_of
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import moe_ffn
-from test_kimi_linear import (CFG, FIRST, PATTERN, TOP_K, _data, _init,
-                              _lively, chunks_of_eight, mesh_of)  # noqa: F401
+from test_kimi_linear import (CFG, FIRST, PATTERN, TOP_K, _data, _lively,
+                              chunks_of_eight)  # noqa: F401
 
 
 # ------------------------------------------------------------------ the stack
@@ -60,9 +62,9 @@ def test_a_stack_that_ends_elsewhere_equals_the_reference(layers):
     cfg = dataclasses.replace(CFG, n_layers=layers)
     kinds = (PATTERN * 2)[:layers]
     tokens, _ = _data()
-    p = _lively(_init(cfg))
+    p = _lively(programs.init(cfg))
     with jax.enable_x64(False):
-        got = jax.jit(tfm.build_forward(cfg, mesh_of()))(p, tokens)
+        got = programs.forward(cfg)(p, tokens)
         want = reference.forward(family.reference_weights(p, kinds), tokens,
                                  kinds, TOP_K, FIRST)
     np.testing.assert_allclose(got, want, atol=5e-3, rtol=5e-3)
@@ -163,13 +165,13 @@ def test_mla_takes_no_rotation_where_unrotated_names_it():
     (Four layers: the dense one and the rest of its period.)"""
     short = dataclasses.replace(CFG, n_layers=4)
     tokens, _ = _data()
-    p = _lively(_init(short))
+    p = _lively(programs.init(short))
 
     def logits(**changes):
         cfg = dataclasses.replace(short, **changes)
         tfm.validate_cfg_for_mesh(cfg, mesh_of())
         with jax.enable_x64(False):
-            return jax.jit(tfm.build_forward(cfg, mesh_of()))(p, tokens)
+            return programs.forward(cfg)(p, tokens)
 
     plain = logits(positions="rope", unrotated=("kda", "mla"))
     np.testing.assert_allclose(plain, logits(), atol=1e-6)
@@ -177,54 +179,6 @@ def test_mla_takes_no_rotation_where_unrotated_names_it():
     assert float(jnp.max(jnp.abs(rotated - plain))) > 1e-2
     assert tfm._kind_cfg(dataclasses.replace(
         CFG, positions="rope", unrotated=("mla",)), "mla").positions == "none"
-
-
-def test_dp_2_equals_one_rank():
-    """The layers' gradients are reduce-scattered inside the backward loop
-    segment by segment (a pattern of (kda, mla) behind its dense KDA layer:
-    a segment of the one MLA layer, then a whole period): loss and every
-    gradient as on one rank."""
-    cfg = dataclasses.replace(CFG, n_layers=4, layer_pattern=("kda", "mla"))
-    assert tfm._pattern_segments(cfg) == ((("mla",), 1),
-                                          (("kda", "mla"), 1))
-    tokens, targets = _data(batch=4)
-    p = _lively(_init(cfg))
-    with jax.enable_x64(False):
-        one = jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-            p, tokens, targets)
-        mesh = mesh_of(dp=2)
-        tfm.validate_cfg_for_mesh(cfg, mesh)
-        two = jax.jit(tfm.build_loss_and_grads(cfg, mesh))(
-            tfm.shard_params(p, cfg, mesh), tokens, targets)
-    assert isinstance(p["layers"], list) and len(p["layers"]) == 2
-    np.testing.assert_allclose(two[0], one[0], rtol=1e-5)
-    for (path, got), want in zip(
-            jax.tree_util.tree_flatten_with_path(two[1])[0],
-            jax.tree_util.tree_leaves(one[1])):
-        size = float(jnp.max(jnp.abs(want)))
-        np.testing.assert_allclose(got, want, rtol=2e-3,
-                                   atol=2e-4 * size + 1e-8,
-                                   err_msg=jax.tree_util.keystr(path))
-
-
-@pytest.mark.parametrize("mesh, changes, what", [
-    ({"sp": 2}, {}, "linear-attention layers require sp=1"),
-    ({"tp": 2}, {}, "linear-attention layers require tp=1"),
-    ({"pp": 2}, {"microbatches": 2}, "a layer pattern requires pp=1"),
-    ({"ep": 2}, {}, "ep > 1 with experts_held < num_experts"),
-    ({}, {"kda_rank": 0}, "'kda' layers need kda_rank > 0"),
-    ({}, {"router_scoring": "tanh"}, "router_scoring='tanh'"),
-    ({}, {"positions": "learned"}, "attention='mla' with positions="
-     "'learned'"),
-    ({}, {"attn": "ring"}, "attention='mla' needs attn 'flash' or 'local'"),
-    ({}, {"first_k_dense": 4, "n_layers": 8}, "pattern's first layers"),
-    ({}, {"unrotated": ("mla",)}, "positions='rope'"),
-])
-def test_what_the_mesh_check_refuses(mesh, changes, what):
-    cfg = dataclasses.replace(CFG, **changes)
-    with pytest.raises(HorovodTpuError) as refused:
-        tfm.validate_cfg_for_mesh(cfg, mesh_of(**mesh))
-    assert what in str(refused.value)
 
 
 def test_the_held_experts_buffer_has_the_room_it_is_given():
@@ -263,3 +217,45 @@ def test_the_held_experts_buffer_has_the_room_it_is_given():
     np.testing.assert_allclose(loose[0], want[0] / reference.
                                ROUTED_SCALING_FACTOR, rtol=3e-5, atol=3e-5)
     assert float(jnp.max(jnp.abs(tight[0] - loose[0]))) > 1e-2
+
+
+def test_dp_2_equals_one_rank():
+    """The layers' gradients are reduce-scattered inside the backward loop
+    segment by segment (a pattern of (kda, mla) behind its dense KDA layer:
+    a segment of the one MLA layer, then a whole period): loss and every
+    gradient as on one rank."""
+    cfg = dataclasses.replace(CFG, n_layers=4, layer_pattern=("kda", "mla"))
+    assert tfm._pattern_segments(cfg) == ((("mla",), 1),
+                                          (("kda", "mla"), 1))
+    tokens, targets = _data(batch=4)
+    p = _lively(programs.init(cfg))
+    with jax.enable_x64(False):
+        one = programs.loss_and_grads(cfg)(p, tokens, targets)
+        mesh = mesh_of(dp=2)
+        tfm.validate_cfg_for_mesh(cfg, mesh)
+        two = programs.loss_and_grads(cfg, dp=2)(
+            tfm.shard_params(p, cfg, mesh), tokens, targets)
+    assert isinstance(p["layers"], list) and len(p["layers"]) == 2
+    np.testing.assert_allclose(two[0], one[0], rtol=1e-5)
+    programs.assert_trees_close(two[1], one[1], rtol=2e-3, atol=1e-8,
+                                scaled=2e-4)
+
+
+@pytest.mark.parametrize("mesh, changes, what", [
+    ({"sp": 2}, {}, "linear-attention layers require sp=1"),
+    ({"tp": 2}, {}, "linear-attention layers require tp=1"),
+    ({"pp": 2}, {"microbatches": 2}, "a layer pattern requires pp=1"),
+    ({"ep": 2}, {}, "ep > 1 with experts_held < num_experts"),
+    ({}, {"kda_rank": 0}, "'kda' layers need kda_rank > 0"),
+    ({}, {"router_scoring": "tanh"}, "router_scoring='tanh'"),
+    ({}, {"positions": "learned"}, "attention='mla' with positions="
+     "'learned'"),
+    ({}, {"attn": "ring"}, "attention='mla' needs attn 'flash' or 'local'"),
+    ({}, {"first_k_dense": 4, "n_layers": 8}, "pattern's first layers"),
+    ({}, {"unrotated": ("mla",)}, "positions='rope'"),
+])
+def test_what_the_mesh_check_refuses(mesh, changes, what):
+    cfg = dataclasses.replace(CFG, **changes)
+    with pytest.raises(HorovodTpuError) as refused:
+        tfm.validate_cfg_for_mesh(cfg, mesh_of(**mesh))
+    assert what in str(refused.value)

@@ -15,18 +15,19 @@ import jax
 import numpy as np
 import pytest
 
+import family
 from horovod_tpu.models import transformer as tfm
-from test_lowered_steps import CONFIGS
+from step_cases import CONFIGS
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "fixtures", "init_digests.json")
+#: (the digests are of bits drawn by programs compiled with them)
+pytestmark = pytest.mark.usefixtures("xla_optimizations")
 
 
 def digests(name: str) -> dict:
     """{path of a leaf: shape, dtype and a digest of its bytes}."""
-    with jax.enable_x64(False):   # as the benchmark runs: one program
-        params = jax.jit(lambda key: tfm.init(key, CONFIGS[name]))(
-            jax.random.PRNGKey(0))
+    params = family.init(CONFIGS[name])   # as the benchmark runs
     return {jax.tree_util.keystr(path): "%s %s %s" % (
         "x".join(map(str, leaf.shape)), leaf.dtype, hashlib.sha256(
             np.asarray(leaf).tobytes()).hexdigest()[:16])
@@ -62,7 +63,7 @@ def test_init_draws_what_the_parent_drew(parents, name):
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_the_three_maps_are_one_tree_of_once_declared_leaves(name):
     cfg = CONFIGS[name]
-    params = jax.eval_shape(lambda k: tfm.init(k, cfg), jax.random.PRNGKey(0))
+    params = family.shapes(cfg)
     specs, axes = tfm.param_specs(cfg), tfm.grad_reduce_axes(cfg)
     shape = jax.tree_util.tree_structure(params)
     assert jax.tree_util.tree_structure(specs) == shape

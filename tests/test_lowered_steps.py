@@ -1,174 +1,18 @@
-"""The train step of eight tiny configurations of the kinds the benchmark's
-LM cells run (a GPT-2 block, OLMoE's, DeepSeek-V2's; since PR 45 the `CFG`s
-of `tests/test_olmo_hybrid.py`, a layer pattern, `tests/test_phi4_flash.py`,
-segments, and `tests/test_smallthinker.py`, a pattern with a share of the
-experts; since PR 46 `tests/test_granite_hybrid.py`'s, Mamba-2 layers to one
-attention layer with the four multipliers), lowered on the CPU and compared, as text, with what an earlier
-commit lowered for them: `tests/fixtures/hlo/lowered_steps.json.gz`. One
-rank, where nothing is reduced, and `dp` = 2, where the layers' gradients
-are reduce-scattered inside the backward loop (a segmented stack's are
-summed after it). A change to `models/` that is not meant to touch these
-models' programs leaves the text as it is; one that is meant to takes the
-fixture anew (`write_fixture()` below, on the tree whose programs are the
-new truth) and says so.
+"""The lowered train steps of `tests/step_cases.py`, a third of them: the
+GPT-2 block, OLMoE's, DeepSeek-V2's and the Kimi Linear stack, each lowered
+once in this process, its text held to the fixture's
+(`tests/fixtures/hlo/lowered_steps.json.gz`) and its compiled scopes read.
+The other families: `tests/test_hybrid_steps.py`, `tests/test_step_scopes.py`.
+What the tests are, whose text each entry of the fixture is and how it is
+taken anew (`write_fixture`): `tests/step_cases.py`."""
 
-Whose text each entry is: the GPT-2 block's is the commit's before the layer
-pattern (PR 31's, a31c4fe). PR 43 took the two expert models' anew: the row
-movers' two `custom_vjp`s moved from `parallel/moe.py` to
-`ops/row_gather.py` (the order in which the layer scan's constants are
-handed to its body changed), and where a share of the experts is held
-(`deepseek_v2`) a take's free rows are gathered from zero rows behind the
-source where a select cleared them; PR 44 took them anew again: the layer
-scan hands the experts' products their stacked leaves and the layer's number
-(`ops/grouped_matmul.py`), so the scan has the stacks as constants and the
-layers' numbers among its `xs`, and each product adds `layer * E` to its
-visits' groups. The three families of PR 45 were written on PR 44's commit
-(bf13d0e), before PR 45 moved the mixers, the FFNs and the gradient
-reduction out of `models/transformer.py` and gave patterns and segments one
-runner (`write_fixture(only_new=True)`: the older entries untouched). The
-Granite family's is PR 46's own, the PR that brought its mixer and the
-multipliers, whose defaults leave the six older texts as they were. PR 47
-took every entry anew: the embedding's lookup is `ops/row_gather.py`
-`lookup_rows`, whose backward pass is a sort, a batched product and a gather
-where the gather's transpose was a scatter-add, in every model's step.
-PR 49 (the flash forward walks a whole block in strips of 256 rows and writes
-a masked block's next product ahead of a strip's softmax) left every entry
-as it was: these models' 32 tokens are one block of 32 rows, which no strip
-divides and whose one strip has none after it, so nothing was taken anew.
-PR 50 wrote the Kimi Linear family's entry (one rank) on its own tree
-(`write_fixture(only_new=True)`: `tests/test_kimi_linear.py`'s `CFG` as the
-cell runs it, a dense prefix inside a pattern whose stack is two segments,
-the per-channel rule's kernels, sigmoid scores with a selection bias) and left
-the thirteen older texts as they were: the scalar rule of `olmo_hybrid`
-lowers to the kernels it lowered to, the softmax router to the program it
-was. PR 51 took the Kimi Linear family's entry anew (the per-channel rule's
-kernels make their decayed products by a halving of pivots, a product a
-level) and left the thirteen older texts byte for byte the parent's.
+from step_cases import (  # noqa: F401  (the tests, cut to FAMILIES)
+    parents, pytest_generate_tests, write_fixture,
+    test_a_scope_is_in_the_forward_and_in_the_backward_pass,
+    test_no_instruction_lies_under_two_layers_scopes,
+    test_the_lookup_leaves_the_step_one_scatter_fewer,
+    test_the_lowered_step_is_the_parents,
+    test_the_reduction_has_its_scope_where_something_is_reduced,
+    test_the_step_has_its_scopes_and_no_other)
 
-The text is JAX's StableHLO without locations, so it does not depend on
-where the checkout lies; it does depend on the JAX version (0.9.0)."""
-
-import dataclasses
-import gzip
-import json
-import os
-
-import jax
-import jax.numpy as jnp
-import optax
-import pytest
-
-from horovod_tpu.models import transformer as tfm
-from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
-from test_granite_hybrid import CFG as GRANITE
-from test_kimi_linear import CFG as KIMI
-from test_olmo_hybrid import CFG as HYBRID
-from test_phi4_flash import CFG as PHI4_FLASH
-from test_smallthinker import CFG as SMALLTHINKER
-
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "fixtures", "hlo", "lowered_steps.json.gz")
-
-CONFIGS = {
-    "gpt2": tfm.TransformerConfig(
-        vocab=96, d_model=64, n_heads=4, d_ff=128, n_layers=2, max_seq=32,
-        attn="flash", dtype=jnp.bfloat16, remat=True),
-    "olmoe": tfm.TransformerConfig(
-        vocab=96, d_model=64, n_heads=4, d_ff=32, n_layers=2, max_seq=32,
-        num_experts=4, experts_per_token=2, load_balance_coef=0.01,
-        router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
-        mlp="swiglu", attn="flash", dtype=jnp.bfloat16, remat=True),
-    "deepseek_v2": tfm.TransformerConfig(
-        vocab=96, d_model=64, n_heads=4, d_ff=32, n_layers=3, max_seq=32,
-        num_experts=8, experts_per_token=2, experts_held=2, first_expert=2,
-        shared_experts=2, first_k_dense=1, d_ff_dense=96,
-        load_balance_coef=0.002, balance_per_sequence=True, norm="rmsnorm",
-        rms_norm_eps=1e-6, positions="rope",
-        yarn=tfm.Yarn(factor=40, original_max=4096, beta_fast=32,
-                      beta_slow=1, mscale=0.707, mscale_all_dim=0.707),
-        attention="mla", kv_latent=24, qk_nope_dim=16, qk_rope_dim=8,
-        v_head_dim=16, mlp="swiglu", attn="flash", dtype=jnp.bfloat16,
-        remat=True),
-    # a pattern, segments, a pattern with a share of the experts
-    "olmo_hybrid": HYBRID,
-    "phi4_flash": PHI4_FLASH,
-    "smallthinker": SMALLTHINKER,
-    # a pattern of Mamba-2 layers and one attention layer, experts beside a
-    # shared MLP, a tied head, the four multipliers; as the cell runs it
-    "granite_hybrid": dataclasses.replace(
-        GRANITE, attn="flash", dtype=jnp.bfloat16, remat=True,
-        remat_policy="full"),
-    # Kimi Delta Attention layers to one latent-attention layer without a
-    # rotation behind a dense KDA layer, sigmoid-scored experts with a
-    # selection bias beside a shared one; as the cell runs it
-    "kimi_linear": dataclasses.replace(
-        KIMI, attn="flash", dtype=jnp.bfloat16, remat=True,
-        remat_policy="full"),
-}
-#: (a share of the experts is one rank's, with an expert axis; a data-parallel
-#: axis of two is the Granite and SmallThinker families' to show)
-#: (the Kimi Linear family's text is three times any other's, the rule's
-#: per-channel kernels unrolled in it: one rank holds it; `dp` = 2 of its
-#: two-segment stack is held to one rank's numbers in
-#: `tests/test_kimi_linear_stack.py`)
-CASES = [(name, dp) for name in CONFIGS for dp in (1, 2)
-         if not (name in ("deepseek_v2", "kimi_linear") and dp == 2)]
-
-
-def lowered(name: str, dp: int) -> str:
-    cfg = CONFIGS[name]
-    mesh = build_mesh(MeshSpec(dp=dp), devices=jax.devices()[:dp])
-    opt = optax.adamw(1e-3)
-    with jax.enable_x64(False):   # as the benchmark runs
-        params = jax.eval_shape(lambda k: tfm.init(k, cfg),
-                                jax.random.PRNGKey(0))
-        state = jax.eval_shape(opt.init, params)
-        tokens = jax.ShapeDtypeStruct((2 * dp, 32), jnp.int32)
-        return tfm.build_train_step(cfg, mesh, opt).lower(
-            params, state, tokens, tokens).as_text()
-
-
-def write_fixture(only_new: bool = False) -> None:
-    """Takes the fixture anew; with `only_new`, only the cases it lacks."""
-    texts = {}
-    if only_new:
-        with gzip.open(FIXTURE, "rt") as f:
-            texts = json.load(f)
-    texts.update({key: lowered(name, dp) for name, dp in CASES
-                  if (key := f"{name}-dp{dp}") not in texts})
-    with gzip.open(FIXTURE, "wt") as f:
-        json.dump(texts, f)
-
-
-@pytest.fixture(scope="module")
-def parents():
-    with gzip.open(FIXTURE, "rt") as f:
-        return json.load(f)
-
-
-@pytest.mark.parametrize("name, dp", CASES)
-def test_the_lowered_step_is_the_parents(parents, name, dp):
-    got, want = lowered(name, dp), parents[f"{name}-dp{dp}"]
-    if got != want:
-        a, b = got.splitlines(), want.splitlines()
-        first = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
-                     min(len(a), len(b)))
-        pytest.fail(f"{name} dp={dp}: {len(a)} lines against the parent's "
-                    f"{len(b)}; first difference at line {first + 1}:\n"
-                    f"  now:    {a[first][:300] if first < len(a) else ''}\n"
-                    f"  parent: {b[first][:300] if first < len(b) else ''}")
-
-
-@pytest.mark.parametrize("name", ["gpt2", "phi4_flash"],
-                         ids=["untied", "tied"])
-def test_the_lookup_leaves_the_step_one_scatter_fewer(monkeypatch, name):
-    """Plain indexing in the lookup's place puts one scatter (the gather's
-    transpose, a scatter-add of the tokens' rows into the table) and takes
-    one sort out of the lowered step; nothing else of the step is one."""
-    def count(what):
-        return lowered(name, 1).count(f'"stablehlo.{what}"(')
-
-    scatters, sorts = count("scatter"), count("sort")
-    monkeypatch.setattr(tfm, "lookup_rows",
-                        lambda table, ids: (table[ids], table))
-    assert (count("scatter"), count("sort")) == (scatters + 1, sorts - 1)
+FAMILIES = ("gpt2", "olmoe", "deepseek_v2", "kimi_linear")

@@ -12,11 +12,12 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import family as programs
 from benchmark.families import olmoe as family
 from benchmark.reference import olmoe as reference
 from horovod_tpu.models import transformer as tfm
-from horovod_tpu.common.exceptions import HorovodTpuError
-from horovod_tpu.parallel import MeshSpec, build_mesh, moe, moe_ffn
+from family import mesh_of
+from horovod_tpu.parallel import moe, moe_ffn
 
 TOP_K = 2
 CFG = tfm.TransformerConfig(
@@ -26,20 +27,13 @@ CFG = tfm.TransformerConfig(
     mlp="swiglu", attn="local", dtype=jnp.float32)
 
 
-def mesh_of(**sizes):
-    spec = MeshSpec(**sizes)
-    return build_mesh(spec, jax.devices()[:spec.total])
-
-
 def _data(batch=4, seq=16):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                CFG.vocab, jnp.int32)
-    return tokens, jnp.roll(tokens, -1, axis=1)
+    return programs.data(CFG.vocab, batch, seq)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init(jax.random.PRNGKey(0), CFG)
+    return programs.init(CFG)
 
 
 def test_the_block_has_the_leaves_the_architecture_has(params):
@@ -80,16 +74,14 @@ def test_the_expert_layer_routes_as_the_reference_does(params):
 
 def test_logits_and_loss_match_the_reference(params):
     tokens, targets = _data()
-    mesh = mesh_of()
-    logits = jax.jit(tfm.build_forward(CFG, mesh))(params, tokens)
+    logits = programs.forward(CFG)(params, tokens)
     weights = family.reference_weights(params)
     want, aux, _ = reference.forward(weights, tokens, TOP_K)
     # float32 logits this close imply the same routes; the expert layer's
     # are compared above
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
-    loss, _ = jax.jit(tfm.build_loss_and_grads(CFG, mesh))(
-        params, tokens, targets)
+    loss, _ = programs.loss_and_grads(CFG)(params, tokens, targets)
     full = reference.loss(weights, tokens, targets, TOP_K)
     np.testing.assert_allclose(float(loss), float(full), rtol=1e-5)
     # the auxiliary terms are in it: 0.01 x ~1 and 0.001 x ~ln(8)^2
@@ -122,7 +114,7 @@ def test_every_gradient_leaf_matches_the_reference(params, sizes):
     tokens, targets = _data()
     mesh = mesh_of(**sizes)
     tfm.validate_cfg_for_mesh(CFG, mesh)
-    loss, grads = jax.jit(tfm.build_loss_and_grads(CFG, mesh))(
+    loss, grads = programs.loss_and_grads(CFG, **sizes)(
         tfm.shard_params(params, CFG, mesh), tokens, targets)
 
     def ref_loss(p):
@@ -131,11 +123,8 @@ def test_every_gradient_leaf_matches_the_reference(params, sizes):
 
     want_loss, want = jax.value_and_grad(ref_loss)(params)
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(want)):
-        scale = float(jnp.max(jnp.abs(ref))) + 1e-12
-        assert float(jnp.max(jnp.abs(got - ref))) <= 2e-5 * scale, \
-            jax.tree_util.keystr(path)
+    programs.assert_trees_close(grads, want, rtol=0, atol=1e-17,
+                                scaled=2e-5)
 
 
 def test_pipeline_stages_carry_the_auxiliary_losses(params):
@@ -147,7 +136,7 @@ def test_pipeline_stages_carry_the_auxiliary_losses(params):
     for pp in (1, 2):
         mesh = mesh_of(pp=pp)
         tfm.validate_cfg_for_mesh(cfg, mesh)
-        losses[pp], _ = jax.jit(tfm.build_loss_and_grads(cfg, mesh))(
+        losses[pp], _ = programs.loss_and_grads(cfg, pp=pp)(
             tfm.shard_params(params, cfg, mesh), tokens, targets)
     np.testing.assert_allclose(float(losses[2]), float(losses[1]), rtol=1e-5)
     want = reference.loss(family.reference_weights(params), tokens, targets,
@@ -160,7 +149,7 @@ def test_a_gated_mlp_without_experts_is_a_dense_one():
     experts the block has a dense one, `tp`-sharded like the GELU MLP and
     without its biases (tests/test_deepseek_v2.py runs it)."""
     cfg = dataclasses.replace(CFG, num_experts=0)
-    layers = tfm.init(jax.random.PRNGKey(2), cfg)["layers"]
+    layers = programs.init(cfg, 2)["layers"]
     mlp = {"w_gate", "w1", "w2"}
     assert mlp <= set(layers)
     assert not set(layers) & {"b1", "b2", "router", "we1", "we2", "we_gate"}
@@ -385,10 +374,10 @@ def test_the_logits_limit_admits_bf16_and_refuses_an_8_bit_float():
     8-bit operands. The weights' seed is one that reads 1.9%.)"""
     _register("bfloat16")
     tokens, _ = _data(batch=8, seq=32)
-    params = tfm.init(jax.random.PRNGKey(3), CFG)
+    params = programs.init(CFG, 3)
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
     low = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
-    logits = jax.jit(tfm.build_forward(cfg, mesh_of()))(low, tokens)
+    logits = programs.forward(cfg)(low, tokens)
     rms, got, want, _ = family._compare(low, tokens, logits, TOP_K)
     assert family.within(float(rms), float(got), float(want))[0]
     eight = reference.logits(family.reference_weights(low), tokens, TOP_K,
@@ -418,9 +407,8 @@ def test_the_loss_limit_refuses_a_planted_fault(params, fault, monkeypatch):
     tokens, _ = _data(batch=8, seq=32)
     where, change = FAULTS[fault]
 
-    def verdict(logits_fault=lambda z: z):
-        logits = logits_fault(jax.jit(tfm.build_forward(CFG, mesh_of()))(
-            params, tokens))
+    def verdict(logits_fault=lambda z: z, build=programs.forward):
+        logits = logits_fault(build(CFG)(params, tokens))
         rms, got, want, _ = family._compare(params, tokens, logits, TOP_K)
         return (family.check_logits(params, tokens, logits)["ok"],
                 family.within(float(rms), float(got), float(want)))
@@ -436,7 +424,8 @@ def test_the_loss_limit_refuses_a_planted_fault(params, fault, monkeypatch):
         return (change(weights), *rest)
 
     monkeypatch.setattr(moe, "route", faulty)
-    ok, (_, loss_ok) = verdict()
+    # (built anew, past the memo, which holds the program as it is)
+    ok, (_, loss_ok) = verdict(build=programs.forward.__wrapped__)
     assert not ok and not loss_ok
 
 

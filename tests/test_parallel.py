@@ -14,16 +14,13 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+import family as programs
+from family import mesh_of
 from horovod_tpu.parallel import (
-    MeshSpec, build_mesh, moe_ffn, pipeline_apply, ring_attention,
-    ulysses_attention,
+    moe_ffn, pipeline_apply, ring_attention, ulysses_attention,
 )
 from horovod_tpu.parallel.ring_attention import blockwise_attention_reference
 from horovod_tpu.models import transformer as tfm
-
-
-def mesh_of(**sizes):
-    return build_mesh(MeshSpec(**sizes), jax.devices()[:MeshSpec(**sizes).total])
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -180,10 +177,7 @@ def _data(cfg, B=8, S=16):
 
 
 def _loss_single(cfg, params, tokens, targets):
-    m1 = build_mesh(MeshSpec(), jax.devices()[:1])
-    lg = tfm.build_loss_and_grads(cfg, m1)
-    loss, grads = jax.jit(lg)(params, tokens, targets)
-    return loss, grads
+    return programs.loss_and_grads(cfg)(params, tokens, targets)
 
 
 @pytest.mark.parametrize("spec", [
@@ -194,36 +188,29 @@ def _loss_single(cfg, params, tokens, targets):
 ])
 def test_transformer_loss_matches_single_device(spec):
     cfg = CFG
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    params = programs.init(cfg)
     tokens, targets = _data(cfg)
     loss1, grads1 = _loss_single(cfg, params, tokens, targets)
 
     m = mesh_of(**spec)
     tfm.validate_cfg_for_mesh(cfg, m)
-    lg = tfm.build_loss_and_grads(cfg, m)
-    loss, grads = jax.jit(lg)(params, tokens, targets)
+    loss, grads = programs.loss_and_grads(cfg, **spec)(params, tokens,
+                                                       targets)
     np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-4)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4),
-        grads, grads1)
+    programs.assert_trees_close(grads, grads1, rtol=5e-4, atol=5e-4)
 
 
 def test_transformer_pipeline_loss_matches():
     cfg = dataclasses_replace(CFG, microbatches=2)
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    params = programs.init(cfg)
     tokens, targets = _data(cfg)
     loss1, grads1 = _loss_single(
         dataclasses_replace(CFG, microbatches=1), params, tokens, targets)
 
-    m = mesh_of(pp=2, dp=2, sp=2)
-    lg = tfm.build_loss_and_grads(cfg, m)
-    loss, grads = jax.jit(lg)(params, tokens, targets)
+    loss, grads = programs.loss_and_grads(cfg, pp=2, dp=2, sp=2)(
+        params, tokens, targets)
     np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-4)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4),
-        grads, grads1)
+    programs.assert_trees_close(grads, grads1, rtol=5e-4, atol=5e-4)
 
 
 @pytest.mark.parametrize("remat,policy", [(True, "dots"), (True, "full"),
@@ -233,18 +220,14 @@ def test_transformer_grads_reduced_in_backward_match(remat, policy):
     (halving over dp x sp, the /tp rescale inside) whatever the layer scan
     saves: same loss and gradients as one device, leaf by leaf."""
     cfg = dataclasses_replace(CFG, remat=remat, remat_policy=policy)
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    params = programs.init(cfg)
     tokens, targets = _data(cfg)
     loss1, grads1 = _loss_single(cfg, params, tokens, targets)
-    m = mesh_of(dp=2, tp=2, sp=2)
-    assert tfm._reduces_in_backward(cfg, m)
-    loss, grads = jax.jit(tfm.build_loss_and_grads(cfg, m))(
+    assert tfm._reduces_in_backward(cfg, mesh_of(dp=2, tp=2, sp=2))
+    loss, grads = programs.loss_and_grads(cfg, dp=2, tp=2, sp=2)(
         params, tokens, targets)
     np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-4)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4),
-        grads, grads1)
+    programs.assert_trees_close(grads, grads1, rtol=5e-4, atol=5e-4)
 
 
 def _collectives(jaxpr):
@@ -288,9 +271,9 @@ def test_every_gradient_leaf_is_reduced_once_per_axis(spec, micro):
     m = mesh_of(**spec)
     tfm.validate_cfg_for_mesh(cfg, m)
     sizes = mesh_axis_sizes(m)
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    params = programs.init(cfg)
     tokens, targets = _data(cfg)
-    jaxpr = jax.make_jaxpr(tfm.build_loss_and_grads(cfg, m))(
+    jaxpr = jax.make_jaxpr(programs.loss_and_grads(cfg, **spec))(
         params, tokens, targets).jaxpr
 
     def shard_shape(x, pspec):
@@ -349,14 +332,14 @@ def test_every_gradient_leaf_is_reduced_once_per_axis(spec, micro):
 
 def test_transformer_moe_train_step_runs():
     cfg = dataclasses_replace(CFG, num_experts=4, attn="ring")
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    params = programs.init(cfg)
     tokens, targets = _data(cfg)
     m = mesh_of(dp=2, ep=2, sp=2)
     tfm.validate_cfg_for_mesh(cfg, m)
     opt = optax.sgd(1e-2)
     params = tfm.shard_params(params, cfg, m)
     before = jax.tree_util.tree_map(np.asarray, params)  # step donates params
-    step = tfm.build_train_step(cfg, m, opt)
+    step = programs.train_step(cfg, opt, dp=2, ep=2, sp=2)
     opt_state = opt.init(params)
     p2, _, loss = step(params, opt_state, tokens, targets)
     assert np.isfinite(float(loss))

@@ -5,21 +5,24 @@ cross-attention that read one layer's memory and keys and values, a tied
 head) against the plain reference `benchmark/reference/phi4_flash.py`, at a
 small size in float32: logits, loss and every leaf's gradient, the shared
 memory's and the shared keys' and values' among them; `dp` = 2 against one
-rank; and what `validate_cfg_for_mesh` refuses."""
+rank; and what `validate_cfg_for_mesh` refuses. Every program is
+`tests/family.py`'s, built once for the module."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
+import family as programs
 from benchmark.families import phi4_flash as family
 from benchmark.reference import phi4_flash as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.models.mixers import MIXERS
-from horovod_tpu.parallel import MeshSpec, build_mesh
+from family import mesh_of
 
 SEGMENTS = ((("ssm", "window"), 2), (("ssm", "full"), 1),
             (("gmu", "cross"), 2))
@@ -32,32 +35,29 @@ CFG = tfm.TransformerConfig(
     window=WINDOW, tied_head=True, attention_bias=True, diff_attention=True,
     ssm_state=4, ssm_conv=4, ssm_expand=2, attn="flash", dtype=jnp.float32)
 SEQ = 24          # three windows long: the band matters
-
-
-def mesh_of(**sizes):
-    spec = MeshSpec(**sizes)
-    return build_mesh(spec, jax.devices()[:spec.total])
+#: (remat against none at 1e-7: without them four entries in a thousand of
+#: one leaf differ by 1e-6)
+pytestmark = pytest.mark.usefixtures("xla_optimizations")
 
 
 def _data(batch=2, seq=SEQ):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                CFG.vocab, jnp.int32)
-    return tokens, jnp.roll(tokens, -1, axis=1)
+    return programs.data(CFG.vocab, batch, seq)
 
 
 @pytest.fixture(scope="module")
 def params():
+    return programs.init(CFG)
+
+
+def _one_rank(params, cfg=CFG):
     with jax.enable_x64(False):
-        return tfm.init(jax.random.PRNGKey(0), CFG)
+        return programs.loss_and_grads(cfg)(params, *_data())
 
 
 @pytest.fixture(scope="module")
 def ours(params):
     """(loss, gradients) of the program on one rank."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        return jax.jit(tfm.build_loss_and_grads(CFG, mesh_of()))(
-            params, tokens, targets)
+    return _one_rank(params)
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +68,13 @@ def theirs(params):
         return jax.value_and_grad(lambda p: reference.loss(
             family.reference_weights(p, KINDS), tokens, targets, KINDS,
             WINDOW))(params)
+
+
+@pytest.fixture(scope="module")
+def their_logits(params):
+    with jax.enable_x64(False):
+        return reference.forward(family.reference_weights(params, KINDS),
+                                 _data()[0], KINDS, WINDOW)
 
 
 def test_the_tree_has_each_kinds_leaves_and_no_others(params):
@@ -93,44 +100,26 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
     assert first["ssm"]["ssm_a_log"].shape == (2, 1, 64, 4)
     assert middle["full"]["wk"].shape == (1, 1, 32, 4, 4)
     assert middle["full"]["wq"].shape == (1, 1, 32, 8, 4)
-    specs, axes = tfm.param_specs(CFG), tfm.grad_reduce_axes(CFG)
-    structure = jax.tree_util.tree_structure(params)
-    assert jax.tree_util.tree_structure(specs) == structure
-    assert jax.tree_util.tree_structure(
-        jax.tree_util.tree_map(lambda x: 0, axes,
-                               is_leaf=lambda x: isinstance(x, tuple))) \
-        == structure
+    programs.assert_specs_cover(CFG, params)
 
 
-def test_logits_equal_the_references(params):
-    tokens, _ = _data()
+def test_logits_equal_the_references(params, their_logits):
     with jax.enable_x64(False):
-        got = jax.jit(tfm.build_forward(CFG, mesh_of()))(params, tokens)
-        want = reference.forward(family.reference_weights(params, KINDS),
-                                 tokens, KINDS, WINDOW)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+        got = programs.forward(CFG)(params, _data()[0])
+    np.testing.assert_allclose(got, their_logits, atol=2e-5, rtol=2e-4)
 
 
 def test_loss_equals_the_references(ours, theirs):
     np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
 
 
-def _leaves(tree):
-    return {jax.tree_util.keystr(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-LEAVES = sorted(_leaves(jax.eval_shape(lambda k: tfm.init(k, CFG),
-                                       jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
+@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
 def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
     """Among them `wk`, `wv`, `bk`, `bv` of the "full" layer, which every
     "cross" layer reads, the memory's "ssm" layer (segment 1), which every
     "gmu" layer reads, and the tied embedding, read at both ends: a reader's
     cotangent that did not arrive is a gradient that differs."""
-    got, want = _leaves(ours[1])[leaf], _leaves(theirs[1])[leaf]
+    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
     size = float(jnp.max(jnp.abs(want)))
     # (a key bias moves every score of a query alike and the softmax does
     # not see it: its gradient is zero on both sides, up to rounding)
@@ -140,15 +129,13 @@ def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
 
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_mechanism_left_out_moves_the_logits(params, fault):
+def test_a_mechanism_left_out_moves_the_logits(params, their_logits, fault):
     """Each fault the chip's limits must refuse changes the reference's
     logits at this size too: the mechanisms are in the function computed."""
-    tokens, _ = _data()
-    weights = family.reference_weights(params, KINDS)
+    sound = their_logits
     with jax.enable_x64(False):
-        sound = reference.forward(weights, tokens, KINDS, WINDOW)
-        wrong = reference.forward(weights, tokens, KINDS, WINDOW,
-                                  fault=fault)
+        wrong = reference.forward(family.reference_weights(params, KINDS),
+                                  _data()[0], KINDS, WINDOW, fault=fault)
     off = float(jnp.sqrt(jnp.mean(jnp.square(wrong - sound))
                          / jnp.mean(jnp.square(sound))))
     assert off > 1e-3, off
@@ -172,48 +159,26 @@ def test_the_familys_comparison_reads_zero_for_the_reference(params):
 def test_dp2_equals_one_rank(params, ours):
     tokens, targets = _data()
     with jax.enable_x64(False):
-        mesh = mesh_of(dp=2)
-        loss, grads = jax.jit(tfm.build_loss_and_grads(CFG, mesh))(
-            tfm.shard_params(params, CFG, mesh), tokens, targets)
+        loss, grads = programs.loss_and_grads(CFG, dp=2)(
+            tfm.shard_params(params, CFG, mesh_of(dp=2)), tokens, targets)
     np.testing.assert_allclose(loss, ours[0], rtol=1e-6)
-    for (path, got), want in zip(
-            jax.tree_util.tree_flatten_with_path(grads)[0],
-            jax.tree_util.tree_leaves(ours[1])):
-        np.testing.assert_allclose(
-            got, want, rtol=1e-4, atol=1e-6,
-            err_msg=jax.tree_util.keystr(path))
+    programs.assert_trees_close(grads, ours[1], rtol=1e-4, atol=1e-6)
 
 
 def test_a_train_step_lowers_the_loss(params):
-    import optax
-    tokens, targets = _data()
-    mesh, opt = mesh_of(), optax.adamw(1e-2)
     cfg = dataclasses.replace(CFG, remat=True)
     with jax.enable_x64(False):
-        # (the step donates its state: a copy, not the fixture's arrays)
-        state = [tfm.shard_params(jax.tree_util.tree_map(jnp.copy, params),
-                                  cfg, mesh)]
-        state.append(tfm.init_opt_state(opt, state[0], mesh))
-        step = tfm.build_train_step(cfg, mesh, opt)
-        losses = []
-        for _ in range(3):
-            state[0], state[1], loss = step(state[0], state[1], tokens,
-                                            targets)
-            losses.append(float(loss))
+        losses = [float(loss) for loss, in programs.train(
+            cfg, optax.adamw(1e-2), params, _data(), 3)]
     assert losses[2] < losses[0], losses
 
 
 def test_remat_changes_no_result(params, ours):
-    tokens, targets = _data()
     for policy in ("dots", "full"):
-        cfg = dataclasses.replace(CFG, remat=True, remat_policy=policy)
-        with jax.enable_x64(False):
-            loss, grads = jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-                params, tokens, targets)
+        loss, grads = _one_rank(params, dataclasses.replace(
+            CFG, remat=True, remat_policy=policy))
         np.testing.assert_allclose(loss, ours[0], rtol=1e-6)
-        for got, want in zip(jax.tree_util.tree_leaves(grads),
-                             jax.tree_util.tree_leaves(ours[1])):
-            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+        programs.assert_trees_close(grads, ours[1], rtol=1e-4, atol=1e-7)
 
 
 REFUSED = [
@@ -255,11 +220,11 @@ def test_grouped_windowed_attention_without_the_difference():
     local = dataclasses.replace(cfg, attn="local")
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 64)
     with jax.enable_x64(False):
-        p = tfm.init(jax.random.PRNGKey(3), cfg)
+        p = programs.init(cfg, 3)
         assert p["layers"]["wk"].shape == (2, 32, 2, 8)
-        a = jax.jit(tfm.build_forward(cfg, mesh_of()))(p, tokens)
-        b = jax.jit(tfm.build_forward(local, mesh_of()))(p, tokens)
-        whole = jax.jit(tfm.build_forward(
-            dataclasses.replace(local, window=0), mesh_of()))(p, tokens)
+        a = programs.forward(cfg)(p, tokens)
+        b = programs.forward(local)(p, tokens)
+        whole = programs.forward(dataclasses.replace(local, window=0))(
+            p, tokens)
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4)
     assert float(jnp.max(jnp.abs(b - whole))) > 1e-3

@@ -60,6 +60,7 @@ def test_scale_32_ranks(tmp_path):
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     out = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=420)
+                         capture_output=True, text=True,
+                         timeout=240)   # under `TEST_LIMIT_S` (conftest.py)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
     assert "SCALE32_OK" in out.stdout

@@ -6,11 +6,12 @@ whose top-k weights are renormalised, a share of the experts held) against
 the plain reference `benchmark/reference/smallthinker.py`, at a small size in
 float32: logits, loss and every leaf's gradient, `attn` "local" and "flash";
 every planted fault refused by the family's limits; the four shares of the
-experts adding up to the uncut layer; `dp` = 2 against one rank; the scopes
-of the compiled step; and what `validate_cfg_for_mesh` refuses."""
+experts adding up to the uncut layer; `dp` = 2 against one rank; and what
+`validate_cfg_for_mesh` refuses. (The scopes of the compiled step:
+`tests/test_step_scopes.py`.) Every program is `tests/family.py`'s, built
+once for the module."""
 
 import dataclasses
-import re
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +20,13 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import family as programs
 from benchmark.families import smallthinker as family
-from benchmark.harness import hlo, scope_time
 from benchmark.reference import smallthinker as reference
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
-from horovod_tpu.parallel import MeshSpec, build_mesh, moe_ffn
+from family import mesh_of
+from horovod_tpu.parallel import moe_ffn
 
 KINDS = ("full", "window", "window", "window")
 WINDOW, TOP_K, FIRST = 8, 3, 2
@@ -41,31 +43,25 @@ SEQ = 32          # four windows long: the band matters
 ATTNS = ("local", "flash")
 
 
-def mesh_of(**sizes):
-    spec = MeshSpec(**sizes)
-    return build_mesh(spec, jax.devices()[:spec.total])
-
-
 def _data(batch=2, seq=SEQ):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                CFG.vocab, jnp.int32)
-    return tokens, jnp.roll(tokens, -1, axis=1)
+    return programs.data(CFG.vocab, batch, seq)
 
 
 @pytest.fixture(scope="module")
 def params():
+    return programs.init(CFG)
+
+
+def _one_rank(params, cfg=CFG):
+    """(loss, gradients) of the program on one rank."""
     with jax.enable_x64(False):
-        return tfm.init(jax.random.PRNGKey(0), CFG)
+        return programs.loss_and_grads(cfg)(params, *_data())
 
 
 @pytest.fixture(scope="module", params=ATTNS)
 def ours(request, params):
-    """(loss, gradients) of the program on one rank, by each algorithm."""
-    tokens, targets = _data()
-    cfg = dataclasses.replace(CFG, attn=request.param)
-    with jax.enable_x64(False):
-        return jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-            params, tokens, targets)
+    """`_one_rank` by each algorithm."""
+    return _one_rank(params, dataclasses.replace(CFG, attn=request.param))
 
 
 @pytest.fixture(scope="module")
@@ -76,6 +72,21 @@ def theirs(params):
         return jax.value_and_grad(lambda p: reference.loss(
             family.reference_weights(p, KINDS), tokens, targets, KINDS,
             WINDOW, TOP_K, FIRST))(params)
+
+
+@pytest.fixture(scope="module")
+def logits(params):
+    """The program's logits for `_data()`'s tokens, once."""
+    with jax.enable_x64(False):
+        return programs.forward(CFG)(params, _data()[0])
+
+
+@pytest.fixture(scope="module")
+def sound(params, logits):
+    """The family's comparison of `logits` with the sound reference."""
+    with jax.enable_x64(False):
+        return family.compare(params, _data()[0], logits, KINDS, WINDOW,
+                              TOP_K, FIRST)
 
 
 def test_the_tree_has_each_kinds_leaves_and_no_others(params):
@@ -94,13 +105,7 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
     assert window["we_gate"].shape == (1, 3, 2, 64, 48)    # two are held
     assert CFG.head_dim == 32 and CFG.rope_dim == 32
     assert dataclasses.replace(CFG, d_head=0).head_dim == 16
-    specs, axes = tfm.param_specs(CFG), tfm.grad_reduce_axes(CFG)
-    structure = jax.tree_util.tree_structure(params)
-    assert jax.tree_util.tree_structure(specs) == structure
-    assert jax.tree_util.tree_structure(
-        jax.tree_util.tree_map(lambda x: 0, axes,
-                               is_leaf=lambda x: isinstance(x, tuple))) \
-        == structure
+    programs.assert_specs_cover(CFG, params)
 
 
 @pytest.mark.parametrize("attn", ATTNS)
@@ -108,7 +113,7 @@ def test_logits_equal_the_references(params, attn):
     tokens, _ = _data()
     cfg = dataclasses.replace(CFG, attn=attn)
     with jax.enable_x64(False):
-        got = jax.jit(tfm.build_forward(cfg, mesh_of()))(params, tokens)
+        got = programs.forward(cfg)(params, tokens)
         want = reference.forward(family.reference_weights(params, KINDS),
                                  tokens, KINDS, WINDOW, TOP_K, FIRST)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
@@ -118,21 +123,12 @@ def test_loss_equals_the_references(ours, theirs):
     np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
 
 
-def _leaves(tree):
-    return {jax.tree_util.keystr(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-LEAVES = sorted(_leaves(jax.eval_shape(lambda k: tfm.init(k, CFG),
-                                       jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
+@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
 def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
     """Among them the routers', whose gradient comes through the layer's
     input and the renormalised weights, and `wk`, `wv`, summed over a
     group's two query heads."""
-    got, want = _leaves(ours[1])[leaf], _leaves(theirs[1])[leaf]
+    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
     size = float(jnp.max(jnp.abs(want)))
     assert size > 1e-6, "nothing to compare"
     np.testing.assert_allclose(got, want, rtol=2e-3,
@@ -145,13 +141,12 @@ def test_two_periods_stack_by_kind_and_equal_the_reference():
     cfg = dataclasses.replace(CFG, n_layers=8, load_balance_coef=0.01)
     tokens, targets = _data()
     with jax.enable_x64(False):
-        p = tfm.init(jax.random.PRNGKey(5), cfg)
+        p = programs.init(cfg, 5)
         assert p["layers"]["window"]["wq"].shape == (2, 3, 64, 4, 32)
-        got = jax.jit(tfm.build_forward(cfg, mesh_of()))(p, tokens)
+        got = programs.forward(cfg)(p, tokens)
         want = reference.forward(family.reference_weights(p, KINDS * 2),
                                  tokens, KINDS * 2, WINDOW, TOP_K, FIRST)
-        loss, _ = jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-            p, tokens, targets)
+        loss, _ = programs.loss_and_grads(cfg)(p, tokens, targets)
     np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-4)
     # the balance term of all eight layers is in the loss (>= 1 a layer)
     plain = float(reference.next_token_loss(want, targets))
@@ -161,15 +156,12 @@ def test_two_periods_stack_by_kind_and_equal_the_reference():
 # --------------------------------------------------------------- the limits
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, fault):
+def test_the_limits_refuse_a_planted_fault(params, logits, sound, fault):
     """The program's logits against the reference computed with one
     mechanism wrong: by one of the family's limits it is not correct, and
     against the sound reference it is, with room."""
     tokens, _ = _data()
     with jax.enable_x64(False):
-        logits = jax.jit(tfm.build_forward(CFG, mesh_of()))(params, tokens)
-        sound = family.compare(params, tokens, logits, KINDS, WINDOW, TOP_K,
-                               FIRST)
         wrong = family.compare(params, tokens, logits, KINDS, WINDOW, TOP_K,
                                FIRST, fault=fault)
     assert all(family.within(*(float(x) for x in sound[:3])))
@@ -184,10 +176,9 @@ def test_the_limits_refuse_a_planted_fault(params, fault):
 
 @pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
                          ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, operands):
+def test_the_limits_refuse_an_8_bit_float(params, logits, operands):
     tokens, _ = _data()
     with jax.enable_x64(False):
-        logits = jax.jit(tfm.build_forward(CFG, mesh_of()))(params, tokens)
         rms, got, want, _ = family.compare(
             params, tokens, logits, KINDS, WINDOW, TOP_K, FIRST,
             operands=operands)
@@ -261,102 +252,32 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 # ------------------------------------------------------ meshes, step, remat
 
 def test_dp2_equals_one_rank(params):
-    tokens, targets = _data()
+    want_loss, want = _one_rank(params)
     with jax.enable_x64(False):
-        want_loss, want = jax.jit(tfm.build_loss_and_grads(CFG, mesh_of()))(
-            params, tokens, targets)
         mesh = mesh_of(dp=2)
         tfm.validate_cfg_for_mesh(CFG, mesh)
-        loss, grads = jax.jit(tfm.build_loss_and_grads(CFG, mesh))(
-            tfm.shard_params(params, CFG, mesh), tokens, targets)
+        loss, grads = programs.loss_and_grads(CFG, dp=2)(
+            tfm.shard_params(params, CFG, mesh), *_data())
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-    for (path, got), w in zip(
-            jax.tree_util.tree_flatten_with_path(grads)[0],
-            jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(
-            got, w, rtol=1e-4, atol=1e-6,
-            err_msg=jax.tree_util.keystr(path))
+    programs.assert_trees_close(grads, want, rtol=1e-4, atol=1e-6)
 
 
 def test_a_train_step_lowers_the_loss_and_counts_what_it_drops(params):
-    tokens, targets = _data()
-    mesh, opt = mesh_of(), optax.adamw(1e-2)
+    opt = optax.adamw(1e-2)
     cfg = dataclasses.replace(CFG, remat=True)
     with jax.enable_x64(False):
-        # (the step donates its state: a copy, not the fixture's arrays)
-        state = [tfm.shard_params(jax.tree_util.tree_map(jnp.copy, params),
-                                  cfg, mesh)]
-        state.append(tfm.init_opt_state(opt, state[0], mesh))
-        step = tfm.build_train_step(cfg, mesh, opt, metrics=True)
-        losses = []
-        for _ in range(3):
-            state[0], state[1], loss, counts = step(state[0], state[1],
-                                                    tokens, targets)
-            losses.append(float(loss))
-            assert int(counts["experts_dropped"]) == 0
-    assert losses[2] < losses[0], losses
+        results = programs.train(cfg, opt, params, _data(), 3, metrics=True)
+    assert all(int(counts["experts_dropped"]) == 0 for _, counts in results)
+    assert float(results[2][0]) < float(results[0][0]), results
 
 
 def test_remat_changes_no_result(params):
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        want_loss, want = jax.jit(tfm.build_loss_and_grads(CFG, mesh_of()))(
-            params, tokens, targets)
+    want_loss, want = _one_rank(params)
     for policy in ("dots", "full"):
-        cfg = dataclasses.replace(CFG, remat=True, remat_policy=policy)
-        with jax.enable_x64(False):
-            loss, grads = jax.jit(tfm.build_loss_and_grads(cfg, mesh_of()))(
-                params, tokens, targets)
+        loss, grads = _one_rank(params, dataclasses.replace(
+            CFG, remat=True, remat_policy=policy))
         np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-        for got, w in zip(jax.tree_util.tree_leaves(grads),
-                          jax.tree_util.tree_leaves(want)):
-            np.testing.assert_allclose(got, w, rtol=1e-4, atol=1e-7)
-
-
-# ------------------------------------------------------------------ scopes
-
-def _compiled_step(cfg):
-    opt = optax.adamw(1e-3)
-    with jax.enable_x64(False):   # as the benchmark runs
-        shapes = jax.eval_shape(lambda k: tfm.init(k, cfg),
-                                jax.random.PRNGKey(0))
-        state = jax.eval_shape(opt.init, shapes)
-        tokens = jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
-        return tfm.build_train_step(cfg, mesh_of(), opt, metrics=True).lower(
-            shapes, state, tokens, tokens).compile().as_text()
-
-
-def test_no_instruction_of_the_new_layer_lies_outside_a_scope():
-    """The scores (renormalisation included) under `moe.route`, the rotation
-    under `attn.project` in rotating layers only, the windowed kernels under
-    `attn.attend/attn.window`, the full layer's under `attn.attend` alone,
-    the ReLU gate under `moe.experts`."""
-    cfg = dataclasses.replace(CFG, attn="flash", remat=True)
-    text = _compiled_step(cfg)
-    table = hlo.index(text)
-    ops = dict(re.findall(r'%?([\w.\-]+) = [^\n]*op_name="([^"]*)"', text))
-
-    def under(prefix):
-        return {ops[name] for name in scope_time.names_under(text, table,
-                                                              prefix)}
-
-    route = under("moe.route")
-    assert any(op.endswith("moe.route/div") for op in route)   # w / sum w
-    assert any(op.endswith("moe.route/top_k") for op in route)
-    assert any(op.endswith("moe.route/dot_general") for op in route)
-    assert any("moe.experts/jit(relu)/max" in op for op in under("moe."))
-    assert not any("silu" in op or "logistic" in op for op in under("moe."))
-    # rotate-half: the two halves joined again, in `attn.project`
-    assert any(op.endswith("attn.project/concatenate")
-               for op in under("attn.project"))
-    windowed, attended = under("attn.window"), under("attn.attend")
-    assert windowed and windowed < attended
-    assert all("attn.attend/attn.window" in op for op in windowed)
-    # a stack that rotates no kind has no rotation under `attn.project`
-    none = _compiled_step(dataclasses.replace(
-        cfg, unrotated=("full", "window")))
-    assert "attn.project/concatenate" not in none
-    assert "attn.project/concatenate" in text
+        programs.assert_trees_close(grads, want, rtol=1e-4, atol=1e-7)
 
 
 # -------------------------------------------------------------- refusals

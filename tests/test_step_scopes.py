@@ -1,14 +1,11 @@
-"""The scopes of the compiled train step (`transformer.STEP_SCOPES` and the
-mixers' `moe.*`, `mla.*`, `gdn.*`, `kda.*`, `ssm.*`, `gmu.*`): for tiny
-configurations of the eight kinds the benchmark's LM cells run, compiled on the CPU, every scope the
-model has is in the compiled text's `op_name`s, in the forward pass and in
-the backward pass; the gradient reduction's only where something is
-reduced; and `DistributedOptimizer.step` records its two phases as spans of
-the JAX profiler. That the scopes change nothing but names is
-`tests/test_lowered_steps.py`'s to show: its fixture is untouched."""
+"""The lowered train steps of `tests/step_cases.py`, a third of them: the
+SmallThinker pattern and the Granite 4.0-H one, as
+`tests/test_lowered_steps.py` holds its families'; what their layers put
+under which scope, read off the same compiled steps; the vocabulary of
+scopes against the source; and `DistributedOptimizer.step`'s two phases as
+spans of the JAX profiler."""
 
 import dataclasses
-import functools
 import glob
 import inspect
 import re
@@ -16,118 +13,83 @@ import re
 import jax
 import jax.numpy as jnp
 import optax
-import pytest
 from jax.profiler import ProfileData
 
+import family
+from benchmark.harness import hlo, scope_time
 from horovod_tpu.models import ffns, mixers, transformer as tfm
-from horovod_tpu.parallel.mesh import MeshSpec, build_mesh
-from test_lowered_steps import CONFIGS
-from test_olmo_hybrid import CFG as HYBRID
-from test_phi4_flash import CFG as PHI4_FLASH
-from test_smallthinker import CFG as SMALLTHINKER
+from step_cases import (  # noqa: F401  (the tests, cut to FAMILIES)
+    SCOPED, SSD, compiled_text, parents, pytest_generate_tests,
+    test_a_scope_is_in_the_forward_and_in_the_backward_pass,
+    test_no_instruction_lies_under_two_layers_scopes,
+    test_the_lowered_step_is_the_parents,
+    test_the_reduction_has_its_scope_where_something_is_reduced,
+    test_the_step_has_its_scopes_and_no_other)
 
-CONFIGS = dict(CONFIGS, olmo_hybrid=HYBRID, phi4_flash=PHI4_FLASH,
-               smallthinker=dataclasses.replace(SMALLTHINKER, attn="flash"))
-SSD = ("ssd.project", "ssd.conv", "ssd.scan", "ssd.gate", "ssd.out")
-
-ATTN = ("attn.project", "attn.attend", "attn.out")
-VOCAB = ("vocab.embed", "vocab.head", "vocab.loss")
-MOE = ("moe.route", "moe.dispatch", "moe.experts", "moe.combine")
-#: the scopes each model's step has, but the two of no model's own
-#: (`opt.update`, `grad.reduce`)
-HAS = {
-    "gpt2": ATTN + ("mlp.dense",) + VOCAB,
-    "olmoe": ATTN + MOE + VOCAB,
-    "deepseek_v2": ("mla.project", "mla.rope", "mla.attend", "mla.out",
-                    "mlp.dense", "moe.shared") + MOE + VOCAB,
-    "olmo_hybrid": ATTN + ("gdn.project", "gdn.conv", "gdn.scan", "gdn.gate",
-                           "gdn.out", "mlp.dense") + VOCAB,
-    "phi4_flash": ATTN + ("attn.window", "ssm.project", "ssm.conv",
-                          "ssm.scan", "ssm.gate", "ssm.out", "gmu.project",
-                          "gmu.gate", "gmu.out", "mlp.dense") + VOCAB,
-    "smallthinker": ATTN + ("attn.window",) + MOE + VOCAB,
-    "granite_hybrid": ATTN + SSD + ("moe.shared",) + MOE + VOCAB,
-    "kimi_linear": ("kda.project", "kda.conv", "kda.scan", "kda.gate",
-                    "kda.out", "mla.project", "mla.rope", "mla.attend",
-                    "mla.out", "mlp.dense", "moe.shared") + MOE + VOCAB,
-}
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
+FAMILIES = ("smallthinker", "granite_hybrid")
 
 
-@functools.lru_cache(maxsize=None)
-def op_names(name: str, dp: int) -> frozenset:
-    """The `op_name`s of `name`'s train step compiled for `dp` CPU devices."""
-    cfg = CONFIGS[name]
-    mesh = build_mesh(MeshSpec(dp=dp), devices=jax.devices()[:dp])
-    opt = optax.adamw(1e-3)
-    with jax.enable_x64(False):   # as the benchmark runs
-        params = jax.eval_shape(lambda k: tfm.init(k, cfg),
-                                jax.random.PRNGKey(0))
-        state = jax.eval_shape(opt.init, params)
-        tokens = jax.ShapeDtypeStruct((2 * dp, 32), jnp.int32)
-        text = tfm.build_train_step(cfg, mesh, opt).lower(
-            params, state, tokens, tokens).compile().as_text()
-    return frozenset(_OP_NAME.findall(text))
+def _ops_under(text):
+    """prefix -> the `op_name`s of `text`'s instructions under that scope,
+    as the benchmark's reader finds them."""
+    table = hlo.index(text)
+    ops = dict(re.findall(r'%?([\w.\-]+) = [^\n]*op_name="([^"]*)"', text))
+    return lambda prefix: {ops[name] for name in scope_time.names_under(
+        text, table, prefix)}
 
 
-def scopes_of(op_name: str) -> list:
-    """The components of an `op_name` that are scopes of the step, with the
-    wrappers of the transformations applied around them taken off
-    (`transpose(jvp(vocab.head))`)."""
-    bare = (re.sub(r"^(?:[\w\-]+\()+", "", c).rstrip(")")
-            for c in op_name.split("/"))
-    return [c for c in bare if re.match(
-        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|kda|ssm|ssd|gmu)\.", c)]
+def test_no_instruction_of_granites_layers_lies_outside_a_scope():
+    """The two products of the input projection under `ssd.project`, the
+    shifted sums under `ssd.conv`, the softplus, the decays and the
+    kernels under `ssd.scan`, the gate and the norm's rsqrt under
+    `ssd.gate`, the output product under `ssd.out`; the renormalised
+    weights under `moe.route` and the shared MLP under `moe.shared`."""
+    under = _ops_under(compiled_text("granite_hybrid", 1))
+    assert set(tfm.STEP_SCOPES) >= set(SSD)
+    assert any("ssd.project/" in op and op.endswith("/dot_general")
+               for op in under("ssd.project"))
+    assert under("ssd.conv") and under("ssd.out")
+    scan = under("ssd.scan")
+    assert any(op.endswith("/exp") for op in scan)     # the decays
+    assert any("softplus" in op or "log1p" in op or "logaddexp" in op
+               for op in scan)
+    assert any(op.endswith("rsqrt") for op in under("ssd.gate"))
+    assert any(op.endswith("moe.route/div") for op in under("moe.route"))
+    assert under("moe.shared")
+    # forward and backward, every scope
+    for scope in SSD:
+        assert any("transpose(" in op for op in under(scope)), scope
+        assert any("transpose(" not in op for op in under(scope)), scope
 
 
-def under(names, scope: str, backward: bool) -> list:
-    return [n for n in names if scope in scopes_of(n)
-            and ("transpose(" in n) == backward]
-
-
-@pytest.mark.parametrize("name, scope", [
-    (name, scope) for name, scopes in HAS.items() for scope in scopes])
-def test_a_scope_is_in_the_forward_and_in_the_backward_pass(name, scope):
-    names = op_names(name, 1)
-    assert under(names, scope, backward=False), (name, scope)
-    assert under(names, scope, backward=True), (name, scope)
-
-
-@pytest.mark.parametrize("name", sorted(HAS))
-def test_the_step_has_its_scopes_and_no_other(name):
-    found = {s for n in op_names(name, 1) for s in scopes_of(n)}
-    assert found == set(HAS[name]) | {"opt.update"}
-    assert under(op_names(name, 1), "opt.update", backward=False)
-
-
-@pytest.mark.parametrize("name", ["gpt2", "olmoe", "olmo_hybrid",
-                                  "phi4_flash", "smallthinker",
-                                  "granite_hybrid"])
-def test_the_reduction_has_its_scope_where_something_is_reduced(name):
-    """On one rank nothing is reduced and the scope is absent; at `dp` = 2
-    the halving inside the backward loop and the sums after it have it (a
-    segmented stack's gradients are all summed after it)."""
-    assert not [n for n in op_names(name, 1) if "grad.reduce" in n]
-    names = op_names(name, 2)
-    if not CONFIGS[name].segments:
-        assert under(names, "grad.reduce", backward=True)    # in the loop
-    assert under(names, "grad.reduce", backward=False)   # after it
-    found = {s for n in names for s in scopes_of(n)}
-    assert found == set(HAS[name]) | {"opt.update", "grad.reduce"}
-
-
-@pytest.mark.parametrize("name, dp", [
-    ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
-    ("phi4_flash", 2), ("smallthinker", 2), ("granite_hybrid", 2),
-    ("kimi_linear", 1)])
-def test_no_instruction_lies_under_two_layers_scopes(name, dp):
-    """`mlp.dense` is entered by `ffns`' two dense rows and not in `_mlp`,
-    which the shared experts run under `moe.shared`; the reduction
-    inside the backward loop is no part of the layer whose gradient it
-    sums."""
-    for n in op_names(name, dp):
-        layers = {s.split(".")[0] for s in scopes_of(n)}
-        assert len(layers) <= 1, n
+def test_no_instruction_of_smallthinkers_layers_lies_outside_a_scope():
+    """The scores (renormalisation included) under `moe.route`, the rotation
+    under `attn.project` in rotating layers only, the windowed kernels under
+    `attn.attend/attn.window`, the full layer's under `attn.attend` alone,
+    the ReLU gate under `moe.experts`."""
+    text = compiled_text("smallthinker", 1)
+    under = _ops_under(text)
+    route = under("moe.route")
+    assert any(op.endswith("moe.route/div") for op in route)   # w / sum w
+    assert any(op.endswith("moe.route/top_k") for op in route)
+    assert any(op.endswith("moe.route/dot_general") for op in route)
+    assert any("moe.experts/jit(relu)/max" in op for op in under("moe."))
+    assert not any("silu" in op or "logistic" in op for op in under("moe."))
+    # rotate-half: the two halves joined again, in `attn.project`
+    assert any(op.endswith("attn.project/concatenate")
+               for op in under("attn.project"))
+    windowed, attended = under("attn.window"), under("attn.attend")
+    assert windowed and windowed < attended
+    assert all("attn.attend/attn.window" in op for op in windowed)
+    # a stack that rotates no kind has no rotation under `attn.project`:
+    # not in what is lowered, so in nothing compiled from it
+    none = family.lowered_step(dataclasses.replace(
+        SCOPED["smallthinker"], unrotated=("full", "window"))).as_text(
+            debug_info=True)
+    assert "attn.project/concatenate" not in none
+    assert "attn.project/concatenate" in text
+    assert "attn.project/concatenate" in family.lowered_step(
+        SCOPED["smallthinker"]).as_text(debug_info=True)
 
 
 def test_the_vocabulary_is_what_the_source_enters():
