@@ -464,7 +464,8 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
     TPU: one, and two), then their times against the least time the
     benchmark's `gdn_scan_roofline` counts (the family's `rule_work`). With
     `per_channel` the form with a decay a key channel (`kda_scan`), which
-    also says what its decayed products cost (`channel_gram_work`)."""
+    also says what its decayed products cost (`channel_gram_work`); both say
+    what the solve costs (`solve_work`)."""
     batch, heads, seq, dk, dv = shape
     tag = "delta rule, a decay a channel" if per_channel \
         else "gated delta rule"
@@ -535,12 +536,16 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                                + name.replace("_", " ") for name in (
                                    "products", "exp_registers",
                                    "lane_reductions", "lane_broadcasts")))
+    solve = gd.solve_work(gd.CHUNK)   # engages in every chunk, both forms
     say(f"[{tag}] {batch} x {seq} tokens x {heads} heads, "
         f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "
         f"{gd.chunks_of(seq)} chunks a sequence, the chunked form's "
         "multiply-adds "
         f"{gd.chunked_over_recurrent_macs(dk, dv):.2f} x the recurrent "
-        f"form's{halving}; {a_step} heads a grid step "
+        f"form's{halving}; the solve by {solve['panels']} panels, a chunk "
+        f"and head: {solve['products']} exact products "
+        f"({solve['bf16_passes']} bf16 passes), {solve['lane_broadcasts']} "
+        f"lane broadcasts, {solve['steps']} steps; {a_step} heads a grid step "
         f"({a_step * gd.step_bytes(dk, dv, per_channel=per_channel) / 2 ** 20:.2f} "
         "MiB of VMEM asked "
         "for its blocks and state); interpret="

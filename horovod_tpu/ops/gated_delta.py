@@ -35,10 +35,13 @@ products are independent and interleave; no operand of a chunk goes through
 HBM between its products.
 
 * The forward kernels. First what does not depend on the state: G[K, K], G[Q,
-  K], the decays, A, T, W and U_0. T is made by forward substitution by rows,
-  in float32 on the vector unit (row j of T is final once rows 0..j-1 are
-  subtracted from it: C - 1 steps, each one column of A times one row of
-  T), never by the product (I - A)(I + A^2)(I + A^4)..: beta reaches 2, so
+  K], the decays, A, T, W and U_0. T is made by forward substitution in
+  panels of 16 rows, in float32: a panel's rows start from I - A[panel,
+  before] T[before], one product on the MXU at float32 precision (three
+  bf16 pieces an operand, six passes), and inside the panel row j is final
+  once rows 0..j-1 of the panel are subtracted from it on the vector unit,
+  each step one column of A times one row of T. No power of A is formed,
+  as the product (I - A)(I + A^2)(I + A^4).. would: beta reaches 2, so
   A's powers grow before they vanish and float32 loses the result. T then
   multiplies K and V exactly: its columns are scaled (float32), and where
   the inputs are bf16 the scaled matrix is split into three bf16 pieces
@@ -111,6 +114,12 @@ _SUBLANES = 8
 #: double-buffered: it decides how many heads a grid step takes
 _VMEM_BUDGET = 4 * 2 ** 20
 _F32 = jnp.float32
+#: rows of a panel of the solve T = (I + A)^-1: what lies before a panel
+#: reaches it by one product, what lies inside it by substitution
+_PANEL = 16
+#: (piece of x, piece of y) of the bf16 products that make a float32 x y:
+#: the pairs of three pieces each whose orders sum to 2 or less
+_PASSES = [(i, j) for i in range(3) for j in range(3 - i)]
 
 
 def _chunk_size(chunk: int) -> int:
@@ -147,6 +156,21 @@ def channel_gram_work(chunk: int = CHUNK, key_dim: int = 128) -> dict:
     return {"levels": levels, "products": (levels, 2 * levels),
             "exp_registers": (registers, registers),
             "lane_reductions": (0, 0), "lane_broadcasts": (0, 0)}
+
+
+def solve_work(chunk: int = CHUNK) -> dict:
+    """What the solve T = (I + A)^-1 costs a chunk and head, from the loops
+    of `_unit_lower_inverse`: its panels, the exact products of a panel with
+    the rows before it and their bf16 passes, the lane broadcasts of a
+    register (a column of A times a row, a register the step touches) and
+    the substitution's steps."""
+    panels = _panels(_chunk_size(chunk))
+    products = sum(1 for lo, _ in panels if lo)
+    touched = [(rows - first) // _SUBLANES for _, rows in panels
+               for _, first in _panel_steps(rows)]
+    return {"panels": len(panels), "products": products,
+            "bf16_passes": products * len(_PASSES),
+            "lane_broadcasts": sum(touched), "steps": len(touched)}
 
 
 def step_bytes(key_dim: int, value_dim: int, chunk: int = CHUNK,
@@ -237,23 +261,56 @@ def _decay(exponent, mask):
     return jnp.exp(jnp.where(mask, exponent, -jnp.inf))
 
 
+def _panels(c: int) -> list[tuple[int, int]]:
+    """(first row, rows) of the solve's panels of a chunk of c rows: of
+    `_PANEL` rows, the last one of what is left."""
+    return [(lo, min(_PANEL, c - lo)) for lo in range(0, c, _PANEL)]
+
+
+def _panel_steps(rows: int):
+    """The substitution's steps inside a panel of `rows` rows: (j, the
+    first row step j touches: that of row j's register, or of the next one
+    where row j is its register's last)."""
+    return [(j, (j + 1) // _SUBLANES * _SUBLANES) for j in range(rows - 1)]
+
+
+def _mm_exact32(x, y):
+    """x y per head, both float32, at float32 precision whatever runs the
+    kernel: each in three bf16 pieces, the `_PASSES` products of a piece of
+    x with a piece of y (what the other three would add is below float32's
+    last bit)."""
+    xs, ys = (_pieces(z, jnp.bfloat16) for z in (x, y))
+    return functools.reduce(jnp.add, (_mm(xs[i], ys[j], (1, 0))
+                                      for i, j in _PASSES))
+
+
 def _unit_lower_inverse(a, eye):
     """(I + a)^-1 for a: (heads, c, c) float32, zero on and above the
-    diagonal, by forward substitution on the rows of the identity: after
-    step j, which takes a's column j times row j from every later row, row
-    j + 1 is final. Rows are held in registers of `_SUBLANES`: a step
-    touches the rows from row j's register on, or from the next one where
-    row j is its register's last."""
-    c = a.shape[-1]
-    done, first = [], 0        # the registers that are final; the next row
-    rest = jnp.broadcast_to(eye.astype(_F32), a.shape)
-    for j in range(c - 1):
-        row = rest[:, j - first:j - first + 1, :]
-        if (j + 1) % _SUBLANES == 0:
-            done.append(rest[:, :_SUBLANES])
-            rest, first = rest[:, _SUBLANES:], j + 1
-        rest = rest - a[:, first:, j:j + 1] * row
-    return jnp.concatenate(done + [rest], axis=1)
+    diagonal, by forward substitution in panels of `_PANEL` rows. A panel's
+    rows start from the identity's less a[panel, before] times the finished
+    rows before it, ONE exact product (the rows not yet made stand in it as
+    zeros: the contraction stays c and every slice a register's); then, inside
+    the panel, step j takes a's column j times row j from the panel's later
+    rows, after which row j + 1 is final. Rows are held in registers of
+    `_SUBLANES`: a step touches those from row j's on, or from the next one
+    where row j is its register's last."""
+    heads, c = a.shape[0], a.shape[-1]
+    unit = eye.astype(_F32)
+    done = []                        # the rows that are final, in registers
+    for lo, rows in _panels(c):
+        rest, top = jnp.broadcast_to(unit[lo:lo + rows], (heads, rows, c)), 0
+        if lo:
+            before = jnp.concatenate(
+                done + [jnp.zeros((heads, c - lo, c), _F32)], axis=1)
+            rest = rest - _mm_exact32(a[:, lo:lo + rows], before)
+        for j, first in _panel_steps(rows):
+            row = rest[:, j - top:j - top + 1]
+            if first > top:
+                done.append(rest[:, :first - top])
+                rest, top = rest[:, first - top:], first
+            rest = rest - a[:, lo + first:lo + rows, lo + j:lo + j + 1] * row
+        done.append(rest)
+    return jnp.concatenate(done, axis=1)
 
 
 def _chunk_terms(b, b_col, mask):

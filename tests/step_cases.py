@@ -57,7 +57,10 @@ it lowered to, the softmax router to the program it was. PR 51 took the Kimi
 Linear family's entry anew (the per-channel rule's kernels make their
 decayed products by a halving of pivots, a product a level) and left the
 thirteen older texts byte for byte the parent's. PR 52 moved these tests
-here and changed no program: no entry was taken anew.
+here and changed no program: no entry was taken anew. PR 53 took the OLMo
+Hybrid family's two entries and the Kimi Linear family's anew (both forms of
+the rule make T = (I + A)^-1 by panels of 16 rows: `_unit_lower_inverse`)
+and left the eleven others byte for byte the parent's.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""

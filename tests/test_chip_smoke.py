@@ -114,7 +114,9 @@ def test_phase_gated_delta_scan(smoke, capsys):
     out = capsys.readouterr().out
     assert "[gated delta rule] 1 x 200 tokens x 2 heads, 16 | 32" in out
     assert "chunk 64, 4 chunks a sequence" in out
-    assert "2 heads a grid step (0.19 MiB of VMEM asked" in out
+    assert "the solve by 4 panels, a chunk and head: 3 exact products (18 " \
+        "bf16 passes), 88 lane broadcasts, 60 steps; 2 heads a grid step " \
+        "(0.19 MiB of VMEM asked" in out
     assert "interpret=True, tpu_custom_call in the compiled forward 0, " \
         "forward + backward 0" in out
     assert "from the token-by-token recurrence" in out
@@ -132,7 +134,9 @@ def test_phase_kda_scan(smoke, capsys):
     assert "2 heads a grid step (" in out
     assert "its decayed products by 7 levels of a halving, forward / " \
         "backward a chunk and head: 7 / 14 products, 48 / 48 exp " \
-        "registers, 0 / 0 lane reductions, 0 / 0 lane broadcasts; " in out
+        "registers, 0 / 0 lane reductions, 0 / 0 lane broadcasts; the " \
+        "solve by 4 panels, a chunk and head: 3 exact products (18 bf16 " \
+        "passes), 88 lane broadcasts, 60 steps; 2 heads a grid step (" in out
     assert "interpret=True, tpu_custom_call in the compiled forward 0, " \
         "forward + backward 0" in out
     assert "from the token-by-token recurrence" in out

@@ -15,7 +15,8 @@ from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (chunked_over_recurrent_macs,
                                          chunks_of, gated_delta_rule,
                                          heads_a_step,
-                                         recurrent_gated_delta_rule)
+                                         recurrent_gated_delta_rule,
+                                         solve_work)
 
 
 def _rule_inputs(seq, *, strong, seed=0, batch=2, heads=3, dk=8, dv=16):
@@ -159,6 +160,66 @@ def test_the_kernels_inverse_is_the_triangular_solve_at_strong_beta(dtype):
             power = power @ power
     assert float(jnp.max(jnp.abs(power))) > 1e9 * float(
         jnp.max(jnp.abs(solved[..., :c])))
+
+
+def _solve_a(c, strong):
+    """A of one chunk of c rows, two heads, no decay. Strong: every key
+    nearly its neighbour's (a token said twice) and beta all but 2, so that
+    the entries beside the diagonal reach 1.9 and the rest half of it; mild:
+    keys in general position and beta under 1."""
+    ks = jax.random.split(jax.random.PRNGKey(c), 3)
+    f32 = jnp.float32
+    k = jax.random.normal(ks[0], (2, c, 16), f32)
+    beta = jax.random.uniform(ks[2], (2, 1, 1, c), f32)
+    if strong:
+        k = jnp.repeat(k[:, ::2], 2, axis=1) \
+            + 0.1 * jax.random.normal(ks[1], k.shape, f32)
+        beta = 2.0 - 0.05 * beta
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return _a_of(k, jnp.zeros_like(beta), beta)
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+@pytest.mark.parametrize("c", [8, 16, 24, 64])
+def test_the_solve_is_the_inverse(c, strong):
+    """`_unit_lower_inverse` called as the kernels call it, against the
+    inverse in float64: one panel and no product (8, 16), a last panel of 8
+    rows (24), the cells' chunk (64)."""
+    a = _solve_a(c, strong)
+    assert (float(jnp.max(jnp.abs(a))) > 1.8) == strong
+    t = jax.jit(gated_delta._unit_lower_inverse)(
+        a, jnp.eye(c, dtype=bool))
+    assert t.dtype == jnp.float32 and t.shape == a.shape
+    want = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+    assert np.abs(np.asarray(t) - want).max() <= 1e-6 * np.abs(want).max()
+    assert not np.triu(np.asarray(t), 1).any()
+    assert (np.diagonal(np.asarray(t), axis1=1, axis2=2) == 1.0).all()
+
+
+@pytest.mark.parametrize("c, panels, products, broadcasts, steps", [
+    (8, 1, 0, 7, 7), (16, 1, 0, 22, 15), (24, 2, 1, 29, 22),
+    (64, 4, 3, 88, 60)])
+def test_what_the_solve_costs_is_counted_from_its_loops(
+        c, panels, products, broadcasts, steps):
+    """`solve_work` against the traced helper of one head: its bf16 matrix
+    products (six a float32 product) and its multiplications, one a step,
+    each a column of A broadcast over the lanes times a row of T, a lane
+    broadcast for every register of rows it covers; no other kind of
+    product, nothing divided."""
+    work = solve_work(c)
+    assert work == {"panels": panels, "products": products,
+                    "bf16_passes": 6 * products,
+                    "lane_broadcasts": broadcasts, "steps": steps}
+    a = jax.ShapeDtypeStruct((1, c, c), jnp.float32)
+    traced = jax.make_jaxpr(gated_delta._unit_lower_inverse)(
+        a, jnp.eye(c, dtype=bool))
+    ops = [eqn.primitive.name for eqn in traced.jaxpr.eqns]
+    assert ops.count("dot_general") == work["bf16_passes"]
+    covered = [eqn.outvars[0].aval.shape[1] // gated_delta._SUBLANES
+               for eqn in traced.jaxpr.eqns if eqn.primitive.name == "mul"]
+    assert len(covered) == work["steps"]
+    assert sum(covered) == work["lane_broadcasts"]
+    assert "div" not in ops and "triangular_solve" not in ops
 
 
 def test_the_saved_states_are_the_recurrences_in_float32():
