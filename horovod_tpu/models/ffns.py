@@ -114,7 +114,8 @@ def _experts(h, lp: Dict[str, Any], cfg, arrived, stacked):
         sequences=B if cfg.balance_per_sequence else 0,
         router_input=arrived.reshape(B * S, D)
         if cfg.router_input == "layer" else None,
-        renormalise=cfg.norm_topk, gate=cfg.gate or "silu",
+        renormalise=cfg.norm_topk, renormalise_eps=cfg.norm_topk_eps,
+        gate=cfg.gate or "silu",
         scoring=cfg.router_scoring, selection_bias=lp.get("router_bias"),
         weight_scale=cfg.routed_scale,
         stacks=tuple(stacks.get(k) for k in EXPERT_LEAVES), layer=layer)

@@ -3,7 +3,7 @@ the leaves it has under a configuration, its `apply`, and what it refuses of
 a configuration and a mesh. `MIXERS` holds them by the `attention` a layer's
 configuration states ("mha", "mla", "gdn", "kda" of `TransformerConfig`, and
 what the kinds of `transformer.LAYER_KINDS` make of it: "ssm", "mamba2",
-"gmu", "cross").
+"gmu", "cross", "shortconv").
 
 An `apply` takes (h: the normed residual (B, S_loc, D), lp: the layer's
 leaves, cfg, rope: the rotation's (cos, sin) or None, shared: what an earlier
@@ -151,7 +151,11 @@ def _mha_leaves(cfg) -> Dict[str, Leaf]:
         if own_keys:
             leaves["bk"] = Leaf((G, d), normal("m", 8, 0.02), _PER_HEAD)
             leaves["bv"] = Leaf((G, d), normal("m", 9, 0.02), _PER_HEAD)
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":     # one scale of a head's width for all heads
+        leaves["q_scale"] = Leaf((d,), ones)
+        if own_keys:
+            leaves["k_scale"] = Leaf((d,), ones)
+    elif cfg.qk_norm:
         leaves["q_scale"] = Leaf((H, d), ones, _PER_HEAD)
         if own_keys:
             leaves["k_scale"] = Leaf((H, d), ones, _PER_HEAD)
@@ -226,8 +230,10 @@ def _mha(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
         k = _projected(h, lp, "wk", "bk")
         v = _projected(h, lp, "wv", "bv")
         if cfg.qk_norm:
-            q = _qk_norm(q, lp["q_scale"], cfg.rms_norm_eps)
-            k = _qk_norm(k, lp["k_scale"], cfg.rms_norm_eps)
+            # "head": each head over its own width, one scale for all
+            normed = rms if cfg.qk_norm == "head" else _qk_norm
+            q = normed(q, lp["q_scale"], cfg.rms_norm_eps)
+            k = normed(k, lp["k_scale"], cfg.rms_norm_eps)
         if rope_ is not None:
             q, k = rope(q, rope_), rope(k, rope_)
     with jax.named_scope("attn.attend"):
@@ -249,7 +255,14 @@ def _mha_checks(cfg, ax):
         (not odd_width or ax["sp"] == ax["tp"] == ax["pp"] == 1,
          "d_head * n_heads != d_model requires sp=tp=pp=1 (no mesh test "
          "shards heads of a width of their own)"),
-        (cfg.n_heads % cfg.kv_heads == 0, "n_heads % n_kv_heads")]
+        (cfg.n_heads % cfg.kv_heads == 0, "n_heads % n_kv_heads"),
+        (cfg.qk_norm in (False, True, "head"),
+         f"qk_norm={cfg.qk_norm!r}: choose False, True (over the whole "
+         "projected vector) or 'head' (each head's own)"),
+        # each head norms itself, so heads could be sharded; no mesh test
+        # shards them yet
+        (cfg.qk_norm != "head" or ax["tp"] == 1,
+         "qk_norm='head' requires tp=1 (no mesh test shards its heads)")]
 
 
 # ---- "mla": DeepSeek-V2's latent attention
@@ -592,6 +605,61 @@ def _mamba2_checks(cfg, ax):
          "pattern, which the pipeline schedule does not place)")]
 
 
+# ---- "shortconv": a gated short convolution (the `conv` layers of the
+# ---- `lfm2` and `lfm2_moe` families): two gates around a depthwise causal
+# ---- convolution of a few taps, no activation, no state beyond their reach
+
+def _shortconv_leaves(cfg) -> Dict[str, Leaf]:
+    """The input projection to [B | C | X], each d_model wide, the taps a
+    channel (the last on the token itself) and the output projection."""
+    D, taps = cfg.d_model, cfg.shortconv_taps
+    return {"sc_w_in": Leaf((D, 3 * D), fan_in("m", 0, D)),
+            "sc_conv": Leaf((D, taps), fan_in("m", 1, taps)),
+            "sc_w_out": Leaf((D, D), fan_in("m", 5, D))}
+
+
+def short_conv_mix(bcx, taps):
+    """C * conv(B * X) for bcx: (B, S, 3 E) = [B | C | X] and taps: (E, K):
+    c_t = sum_j taps[:, j] z_(t - (K - 1) + j) with z = B * X and zeros
+    before the sequence's start: K shifted multiply-adds over the token
+    axis, token-major as the projection leaves it, in float32 and rounded
+    once; no bias, no activation."""
+    f32 = jnp.float32
+    (width, taps_n), seq = taps.shape, bcx.shape[1]
+    b, c, x = (bcx[..., i * width:(i + 1) * width].astype(f32)
+               for i in range(3))
+    padded = jnp.pad(b * x, ((0, 0), (taps_n - 1, 0), (0, 0)))
+    conv = sum(padded[:, j:j + seq] * taps[:, j].astype(f32)
+               for j in range(taps_n))
+    return (c * conv).astype(bcx.dtype)
+
+
+def _shortconv(h, lp: Dict[str, Any], cfg, rope_, shared, depth):
+    """On h: (B, S, D): [B | C | X] = h W_in; (C * conv(B * X)) W_out."""
+    with jax.named_scope("shortconv.project"):
+        bcx = jnp.einsum("bsd,de->bse", h, lp["sc_w_in"])
+    with jax.named_scope("shortconv.mix"):
+        y = short_conv_mix(bcx, lp["sc_conv"])
+    with jax.named_scope("shortconv.out"):
+        return jnp.einsum("bse,ed->bsd", y, lp["sc_w_out"]), None
+
+
+def _shortconv_checks(cfg, ax):
+    # a token's convolution reads the tokens before it and the layer holds
+    # all its channels: neither crosses shards or stages yet
+    return [
+        (cfg.shortconv_taps > 0, "'shortconv' layers need shortconv_taps > 0"),
+        (ax["sp"] == 1,
+         "short-convolution layers require sp=1 (a shard's first tokens "
+         "read the last tokens of the shard before it)"),
+        (ax["tp"] == 1,
+         "short-convolution layers require tp=1 (their channels are not "
+         "sharded)"),
+        (ax["pp"] == 1,
+         "short-convolution layers require pp=1 (they come in a layer "
+         "pattern, which the pipeline schedule does not place)")]
+
+
 def _gmu_leaves(cfg) -> Dict[str, Leaf]:
     D, E = cfg.d_model, cfg.ssm_channels
     return {"gmu_w1": Leaf((D, E), fan_in("m", 0, D)),
@@ -619,4 +687,5 @@ MIXERS = {
     "ssm": Part(_ssm_leaves, _ssm),
     "mamba2": Part(_mamba2_leaves, _mamba2, _mamba2_checks),
     "gmu": Part(_gmu_leaves, _gmu),
+    "shortconv": Part(_shortconv_leaves, _shortconv, _shortconv_checks),
 }

@@ -19,8 +19,8 @@ layer is norm, mixer, residual, norm, FFN, residual (`_layer`). The mixers
 lie in `models/mixers.py` (`MIXERS`: plain attention with its biases,
 QK-norm, fewer key heads and the differential form; cross, latent (MLA, with
 or without a rotation), delta-rule (one decay a head, or one a key channel:
-Kimi Delta Attention), state-space (Mamba-1 and Mamba-2) and
-Gated-Memory-Unit mixers), the FFNs in
+Kimi Delta Attention), state-space (Mamba-1 and Mamba-2),
+Gated-Memory-Unit and gated short-convolution mixers), the FFNs in
 `models/ffns.py` (`FFNS`: dense GELU, dense gated, routed experts with their
 router and shared experts), each with the leaves it has, its `apply` and
 what it refuses; a leaf is declared once (`models/leaves.py`), and `init`,
@@ -34,10 +34,13 @@ leading dense layers are a stack of their own, `params["dense_layers"]`, in
 front of `params["layers"]`), Olmo-Hybrid's, SmallThinker's
 (arXiv:2507.20984), SambaY's (arXiv:2507.06607), the Granite 4.0 hybrids'
 (Mamba-2 layers, arXiv:2405.21060, to one attention layer, and four scalar
-multipliers) and Kimi Linear's (arXiv:2510.26692: delta-rule layers with a
+multipliers), Kimi Linear's (arXiv:2510.26692: delta-rule layers with a
 decay per key channel to one latent-attention layer without a rotation,
 sigmoid-scored experts with a selection bias, a leading dense layer inside
-the pattern).
+the pattern) and LFM2's (`model_type` lfm2_moe: three gated
+short-convolution layers to one grouped-query attention layer whose queries
+and keys are normed per head, sigmoid-scored experts behind a leading dense
+layer, a tied head).
 
 A model whose layers are not all of one kind states one period of its
 `layer_pattern`, which the stack repeats (`LAYER_KINDS`); its parameters lie
@@ -179,8 +182,10 @@ class TransformerConfig:
     # the load balance of each sequence, averaged (DeepSeek-V2's `seq_aux`),
     # not of all of a shard's tokens at once
     balance_per_sequence: bool = False
-    # a token's k expert weights divided by their sum (`norm_topk_prob`)
+    # a token's k expert weights divided by their sum (`norm_topk_prob`),
+    # or by their sum + norm_topk_eps (LFM2's 1e-6)
     norm_topk: bool = False
+    norm_topk_eps: float = 0.0
     # how the router's logits become weights (`parallel/moe.py`):
     # "softmax" over all experts, or "sigmoid" of each; with `router_bias`
     # a leaf that is added to the scores for the choice alone and takes no
@@ -220,9 +225,10 @@ class TransformerConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
-    # RMSNorm on the projected queries and keys, over the whole projected
-    # vector (all heads), before it is split into heads and rotated
-    qk_norm: bool = False
+    # RMSNorm on the projected queries and keys before they are rotated:
+    # True, over the whole projected vector (all heads; OLMoE's), or "head",
+    # each head over its own width with one scale for all heads (LFM2's)
+    qk_norm: Any = False
     # attention="gdn": no attention but a gated delta rule (`mixers.py`):
     # gdn_heads heads with keys and queries gdn_key_dim wide and values
     # gdn_value_dim, a depthwise causal convolution of gdn_conv taps on
@@ -279,6 +285,10 @@ class TransformerConfig:
     ssd_head_dim: int = 64
     ssd_state: int = 128
     ssd_conv: int = 4
+    # a "shortconv" layer (`mixers.py`): [B | C | X] = h W_in, each d_model
+    # wide; C * conv(B * X), a depthwise causal convolution of
+    # shortconv_taps taps without bias or activation; W_out
+    shortconv_taps: int = 3
     # Scalar multipliers (the Granite family's): on the embedding, on each
     # sub-layer's output before its residual add, on the attention scores
     # in place of (the keys' width)^-1/2 (None: that), and on the logits
@@ -374,6 +384,7 @@ LAYER_KINDS = {"full": {"window": 0},
                "window": {},
                "ssm": {"attention": "ssm"},
                "mamba2": {"attention": "mamba2"},
+               "shortconv": {"attention": "shortconv", "window": 0},
                "gmu": {"attention": "gmu"},
                "cross": {"attention": "cross", "window": 0}}
 #: what a layer of a kind hands on, where its segment does (`_hands_on`), and

@@ -21,8 +21,9 @@ rule outside the loss; here it stays as it was seeded).
 (`router_input`: SmallThinker, arXiv:2507.20984, scores the layer's input,
 before attention, and its experts read the normed post-attention state). The
 k weights `w_e` are as above, or with `renormalise` divided by their sum over
-the k chosen (`norm_topk_prob`), the gradient through the sum included, and
-then times `weight_scale` (`routed_scaling_factor`). `expert_e(h)` is
+the k chosen (`norm_topk_prob`; LFM2 divides by the sum + 1e-6:
+`renormalise_eps`), the gradient through the sum included, and then times
+`weight_scale` (`routed_scaling_factor`). `expert_e(h)` is
 `W_down,e (act(W_gate,e h) * (W_up,e h))` where gate weights are given, `act`
 being `gate`: "silu" or "relu"; and `W_down,e gelu(W_up,e h)` where they are
 not. Experts that every token goes through beside these (DeepSeek's shared
@@ -122,7 +123,7 @@ SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
 def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
           renormalise: bool = False, scoring: str = "softmax",
           selection_bias: Optional[jax.Array] = None,
-          weight_scale: float = 1.0):
+          weight_scale: float = 1.0, renormalise_eps: float = 0.0):
     """(weights (T, k) float32, experts (T, k) int32, rows per expert (E,)
     int32, [load balance, router z] float32) for the tokens x: (T, D), which
     are `sequences` sequences of equal length where the load balance is to
@@ -130,7 +131,8 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
     `SCORINGS[scoring]` of the logits; the k experts are those of the
     largest scores, or of the largest scores + `selection_bias` (E,), which
     takes no gradient; the weights are the chosen experts' scores, with
-    `renormalise` made to add up to 1, times `weight_scale`."""
+    `renormalise` divided by (their sum + `renormalise_eps`), times
+    `weight_scale`."""
     with jax.named_scope("moe.route"):
         n_experts = router_w.shape[1]
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
@@ -142,7 +144,10 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int, sequences: int = 0,
                 selection_bias.astype(jnp.float32)), top_k)
             weights = jnp.take_along_axis(probs, experts, axis=-1)
         if renormalise:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            if renormalise_eps:   # (no add of a zero: the programs without
+                total = total + renormalise_eps   # one stay as lowered)
+            weights = weights / total
         if weight_scale != 1.0:
             weights = weights * weight_scale
         if sequences:
@@ -244,6 +249,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             scoring: str = "softmax",
             selection_bias: Optional[jax.Array] = None,
             weight_scale: float = 1.0, held_factor: float = 2.0,
+            renormalise_eps: float = 0.0,
             stacks=(None, None, None), layer=0
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k mixture-of-experts feed-forward on one shard's tokens.
@@ -257,7 +263,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
       sequences: how many sequences the T tokens are, where the load
         balance is each sequence's own (`route`)
       router_input: (T, D), what the router scores where that is not x
-      renormalise: a token's k weights divided by their sum
+      renormalise: a token's k weights divided by their sum (+
+        `renormalise_eps`)
       gate: what a gated expert applies to its gate's product (`GATES`)
       scoring, selection_bias, weight_scale: the router's rule (`route`)
       held_factor: where one rank holds a share, its row buffer as a
@@ -299,7 +306,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
     weights, experts, counts, aux = route(
         x if router_input is None else router_input, router_w, k, sequences,
-        renormalise, scoring, selection_bias, weight_scale)
+        renormalise, scoring, selection_bias, weight_scale, renormalise_eps)
 
     with jax.named_scope("moe.dispatch"):
         key = experts.reshape(-1)

@@ -1,10 +1,10 @@
-"""The train step of eight tiny configurations of the kinds the benchmark's
+"""The train step of nine tiny configurations of the kinds the benchmark's
 LM cells run, lowered ONCE a (family, `dp`) on the CPU (`family.lowered_step`)
 and read twice: its text against what an earlier commit lowered
 (`tests/fixtures/hlo/lowered_steps.json.gz`), and the scopes of the compiled
 step (`transformer.STEP_SCOPES` and the mixers' `moe.*`, `mla.*`, `gdn.*`,
-`kda.*`, `ssm.*`, `gmu.*`) in the compiled text's `op_name`s. The tests stand
-here and run in three files, `tests/test_lowered_steps.py`,
+`kda.*`, `ssm.*`, `gmu.*`, `shortconv.*`) in the compiled text's `op_name`s.
+The tests stand here and run in three files, `tests/test_lowered_steps.py`,
 `tests/test_hybrid_steps.py` and `tests/test_step_scopes.py`, each of which
 imports them and names its families in `FAMILIES`: `pytest_generate_tests`
 below cuts a test's cases to them. A family's cases stand in one file so that
@@ -16,7 +16,8 @@ The configurations: a GPT-2 block, OLMoE's, DeepSeek-V2's; since PR 45 the
 `tests/test_phi4_flash.py`, segments, and `tests/test_smallthinker.py`, a
 pattern with a share of the experts; since PR 46
 `tests/test_granite_hybrid.py`'s, Mamba-2 layers to one attention layer with
-the four multipliers; since PR 50 `tests/test_kimi_linear.py`'s. One rank,
+the four multipliers; since PR 50 `tests/test_kimi_linear.py`'s; since PR 54
+`tests/test_lfm2_moe.py`'s. One rank,
 where nothing is reduced, and `dp` = 2, where the layers' gradients are
 reduce-scattered inside the backward loop (a segmented stack's are summed
 after it).
@@ -60,7 +61,14 @@ thirteen older texts byte for byte the parent's. PR 52 moved these tests
 here and changed no program: no entry was taken anew. PR 53 took the OLMo
 Hybrid family's two entries and the Kimi Linear family's anew (both forms of
 the rule make T = (I + A)^-1 by panels of 16 rows: `_unit_lower_inverse`)
-and left the eleven others byte for byte the parent's.
+and left the eleven others byte for byte the parent's. PR 54 wrote the LFM2
+family's entry (one rank) on its own tree (`write_fixture(only_new=True)`:
+`tests/test_lfm2_moe.py`'s `CFG` as the cell runs it: gated short-convolution
+layers to one grouped-query attention layer with QK-norm per head behind a
+dense layer, sigmoid scores renormalised over their sum + 1e-6, a tied head)
+and left the fourteen older texts byte for byte the parent's: QK-norm over the
+whole vector is the code it was, and a renormalisation whose epsilon is 0
+divides by the sum alone.
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""
@@ -79,6 +87,7 @@ import family
 from horovod_tpu.models import transformer as tfm
 from test_granite_hybrid import CFG as GRANITE
 from test_kimi_linear import CFG as KIMI
+from test_lfm2_moe import TIMED as LFM2
 from test_olmo_hybrid import CFG as HYBRID
 from test_phi4_flash import CFG as PHI4_FLASH
 from test_smallthinker import CFG as SMALLTHINKER
@@ -121,6 +130,11 @@ CONFIGS = {
     "kimi_linear": dataclasses.replace(
         KIMI, attn="flash", dtype=jnp.bfloat16, remat=True,
         remat_policy="full"),
+    # gated short-convolution layers to one grouped-query attention layer
+    # with QK-norm per head behind a dense layer, a share of sigmoid-scored
+    # experts, a tied head; as the cell runs it
+    "lfm2_moe": dataclasses.replace(LFM2, dtype=jnp.bfloat16,
+                                    remat_policy="dots"),
 }
 #: the scopes are read off the flash kernels' step (`attn.window`); the text
 #: the fixture holds for SmallThinker is its `CFG`'s own, `attn` "local"
@@ -131,9 +145,11 @@ SCOPED = dict(CONFIGS, smallthinker=dataclasses.replace(SMALLTHINKER,
 #: (the Kimi Linear family's text is three times any other's, the rule's
 #: per-channel kernels unrolled in it: one rank holds it; `dp` = 2 of its
 #: two-segment stack is held to one rank's numbers in
-#: `tests/test_kimi_linear_stack.py`)
+#: `tests/test_kimi_linear_stack.py`, the LFM2 family's in
+#: `tests/test_lfm2_moe_stack.py`)
 LOWERED = [(name, dp) for name in CONFIGS for dp in (1, 2)
-           if not (name in ("deepseek_v2", "kimi_linear") and dp == 2)]
+           if not (name in ("deepseek_v2", "kimi_linear", "lfm2_moe")
+                   and dp == 2)]
 
 SSD = ("ssd.project", "ssd.conv", "ssd.scan", "ssd.gate", "ssd.out")
 ATTN = ("attn.project", "attn.attend", "attn.out")
@@ -156,6 +172,8 @@ HAS = {
     "kimi_linear": ("kda.project", "kda.conv", "kda.scan", "kda.gate",
                     "kda.out", "mla.project", "mla.rope", "mla.attend",
                     "mla.out", "mlp.dense", "moe.shared") + MOE + VOCAB,
+    "lfm2_moe": ATTN + ("shortconv.project", "shortconv.mix",
+                        "shortconv.out", "mlp.dense") + MOE + VOCAB,
 }
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -175,7 +193,7 @@ CASES = {
     "test_no_instruction_lies_under_two_layers_scopes": ("name, dp", [
         ("gpt2", 2), ("olmoe", 2), ("deepseek_v2", 1), ("olmo_hybrid", 2),
         ("phi4_flash", 2), ("smallthinker", 2), ("granite_hybrid", 2),
-        ("kimi_linear", 1)]),
+        ("kimi_linear", 1), ("lfm2_moe", 1)]),
 }
 
 
@@ -264,7 +282,8 @@ def scopes_of(op_name: str) -> list:
     bare = (re.sub(r"^(?:[\w\-]+\()+", "", c).rstrip(")")
             for c in op_name.split("/"))
     return [c for c in bare if re.match(
-        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|kda|ssm|ssd|gmu)\.", c)]
+        r"(attn|mlp|vocab|grad|opt|moe|mla|gdn|kda|ssm|ssd|gmu|shortconv)\.",
+        c)]
 
 
 def under(names, scope: str, backward: bool) -> list:

@@ -21,6 +21,12 @@ from horovod_tpu.ops.causal_conv import (causal_conv_silu, heads_a_step,
 F32, BF16 = jnp.float32, jnp.bfloat16
 
 
+def _grad(fun, **kw):
+    """`jax.grad` as one compiled program: eagerly a kernel's forward and
+    backward passes are a trace and a compile an operation."""
+    return jax.jit(jax.grad(fun, **kw))
+
+
 def _inputs(shape, taps=4, dtype=F32, seed=0):
     batch, heads, seq, width = shape
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -47,7 +53,7 @@ def _value_and_grads(fn, u, w, cot, l2_scale):
         return jnp.sum(fn(u, w, l2_scale=l2_scale).astype(F32) * cot)
 
     return (fn(u, w, l2_scale=l2_scale),
-            *jax.grad(weighed, argnums=(0, 1))(u, w))
+            *_grad(weighed, argnums=(0, 1))(u, w))
 
 
 def _rel(got, want):
@@ -125,7 +131,7 @@ def test_the_gradient_of_an_early_token_holds_the_later_tiles_rows(tile):
     u, w, _ = _inputs((1, 1, 128, 8), seed=4)
     cot = jnp.zeros(u.shape, F32).at[0, 0, 64].set(1.0)
     for fn in (causal_conv_silu, reference_causal_conv_silu):
-        du = jax.grad(lambda u: jnp.sum(fn(u, w) * cot))(u)
+        du = _grad(lambda u: jnp.sum(fn(u, w) * cot))(u)
         rows = np.flatnonzero(np.any(np.asarray(du != 0), axis=(0, 1, 3)))
         assert rows.tolist() == [61, 62, 63, 64]
 

@@ -18,6 +18,12 @@ from horovod_tpu.parallel.ring_attention import (
     blockwise_attention_reference)
 
 
+def _grad(fun, **kw):
+    """`jax.grad` as one compiled program: eagerly a kernel's forward and
+    backward passes are a trace and a compile an operation."""
+    return jax.jit(jax.grad(fun, **kw))
+
+
 def _qkv(key, B=2, H=2, S=256, dh=64, dtype=jnp.float32):
     ks = jax.random.split(key, 3)
     mk = lambda k: jax.random.normal(k, (B, H, S, dh), dtype)  # noqa: E731
@@ -65,8 +71,8 @@ def test_flash_gradients_match_reference():
         o = blockwise_attention_reference(q, k, v, causal=True)
         return jnp.sum(jnp.sin(o))
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = _grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = _grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4,
@@ -267,9 +273,9 @@ def _banded_case(case):
     def theirs(q, k, v):
         return _banded_reference(q, k, v, window)
 
-    out = [f(q, k, v) for f in (ours, theirs)]
-    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
-                      argnums=(0, 1, 2))(q, k, v) for f in (ours, theirs)]
+    out = [jax.jit(f)(q, k, v) for f in (ours, theirs)]
+    grads = [_grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                   argnums=(0, 1, 2))(q, k, v) for f in (ours, theirs)]
     return out, grads
 
 
@@ -362,12 +368,18 @@ def test_flash_kernels_at_unequal_widths_match_plain_attention(dqk, dv,
         return blockwise_attention_reference(q, k, v, causal=True,
                                              scale=scale)
 
-    out, vjp = jax.vjp(flash, q, k, v)
-    want, want_vjp = jax.vjp(plain, q, k, v)
+    def with_gradients(attn):   # one compiled program a side
+        def both(q, k, v, do):
+            out, vjp = jax.vjp(attn, q, k, v)
+            return out, vjp(do)
+        return jax.jit(both)(q, k, v, do)
+
+    (out, grads), (want, want_grads) = (with_gradients(flash),
+                                        with_gradients(plain))
     assert out.shape == v.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4,
                                atol=2e-4)
-    for name, got, ref in zip("qkv", vjp(do), want_vjp(do)):
+    for name, got, ref in zip("qkv", grads, want_grads):
         assert got.shape == ref.shape, name
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=5e-4, atol=5e-4, err_msg=name)

@@ -63,21 +63,31 @@ def test_the_grid_steps_are_the_block_pairs_the_mask_holds_each_once(
             if _mask_holds(i, j, block_q, block_k, causal, window)]
     rows = held_blocks(nq, nk, block_q, block_k, causal, window)
 
-    walk = _Walk(rows)
-    steps = [(int(walk.run(s)[0]), int(walk.at(s)))
-             for s in map(np.int32, range(walk.steps))]   # as program_id is
+    def walked(walk):
+        """Every step of `walk` as a kernel finds it: [(run, pass, at)], and
+        whether it is its run's first and last; the steps int32 scalars as
+        `program_id` is, all of them in one traced call."""
+        run, at, where = jax.vmap(
+            lambda s: (walk.run(s), walk.at(s), walk.where(s)[2:]))(
+                jnp.arange(walk.steps, dtype=jnp.int32))
+        n = walk.steps
+        (i, g), at, ends = jax.tree_util.tree_map(
+            lambda x: np.broadcast_to(np.asarray(x), (n,)).tolist(),
+            (run, at, where))
+        return list(zip(i, g, at)), list(zip(*ends))
+
+    visited, ends = walked(_Walk(rows))
+    steps = [(i, j) for i, _, j in visited]
     assert steps == held            # each once, row-major
     for s, (i, j) in enumerate(steps):
-        first, last = (bool(x) for x in walk.where(np.int32(s))[2:])
+        first, last = ends[s]
         assert first == (s == 0 or steps[s - 1][0] != i)
         assert last == (s == len(steps) - 1 or steps[s + 1][0] != i)
     assert {i for i, _ in steps} == set(range(nq))     # every o block
 
-    walk = _Walk(_by_key_block(rows, nk), heads=group)
-    steps = [(*map(int, walk.run(s)), int(walk.at(s)))
-             for s in map(np.int32, range(walk.steps))]
+    steps, ends = walked(_Walk(_by_key_block(rows, nk), heads=group))
     for s, (j, _, _) in enumerate(steps):
-        first, last = (bool(x) for x in walk.where(np.int32(s))[2:])
+        first, last = ends[s]
         assert first == (s == 0 or steps[s - 1][0] != j)
         assert last == (s == len(steps) - 1 or steps[s + 1][0] != j)
     assert steps == sorted((j, g, i) for i, j in held for g in range(group))
@@ -143,13 +153,14 @@ def _many_blocks_case(case):
         return jnp.sum(o * w) + jnp.sum(lse * wl)
 
     # the forward kernel's own second result, whichever entry point ran it
-    o, lse = fa._fwd(q[0], k[0], v[0], causal, 8 ** -0.5, bq, bk, window)
-    want_o, want_lse = theirs(q, k, v)
+    o, lse = jax.jit(lambda q, k, v: fa._fwd(
+        q[0], k[0], v[0], causal, 8 ** -0.5, bq, bk, window))(q, k, v)
+    want_o, want_lse = jax.jit(theirs)(q, k, v)
     got = [o[None], lse[None, ..., 0]]
     want = [want_o, want_lse]
     for f, into in ((ours, got), (theirs, want)):
-        into.extend(jax.grad(functools.partial(loss, f),
-                             argnums=(0, 1, 2))(q, k, v))
+        into.extend(jax.jit(jax.grad(functools.partial(loss, f),
+                                     argnums=(0, 1, 2)))(q, k, v))
     return got, want
 
 
@@ -209,13 +220,14 @@ def _strips_case(case):
     def theirs(q, k, v):
         return _written_out(q, k, v, True, window)[0]
 
-    o, lse = fa._fwd(q[0], k[0], v[0], True, dk ** -0.5, block, block,
-                     window)
-    want_o, want_lse = _written_out(q, k, v, True, window)
+    o, lse = jax.jit(lambda q, k, v: fa._fwd(
+        q[0], k[0], v[0], True, dk ** -0.5, block, block, window))(q, k, v)
+    want_o, want_lse = jax.jit(
+        lambda q, k, v: _written_out(q, k, v, True, window))(q, k, v)
     got, want = [o[None], lse[None, ..., 0]], [want_o, want_lse]
     for f, into in ((ours, got), (theirs, want)):
-        into.extend(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
-                             argnums=(0, 1, 2))(q, k, v))
+        into.extend(jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                                     argnums=(0, 1, 2)))(q, k, v))
     return got, want
 
 

@@ -6,6 +6,8 @@ triangular solve, the saved states, bf16, and the static numbers of the
 chunked form. The per-channel rule is `tests/test_kda.py`'s; the model that
 runs this one, `tests/test_olmo_hybrid.py`'s."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,8 +43,23 @@ def _rule_inputs(seq, *, strong, seed=0, batch=2, heads=3, dk=8, dv=16):
 
 
 def _grads(rule, args, cot):
-    return jax.grad(lambda *a: jnp.sum(rule(*a) * cot),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+    """(One compiled program: eagerly the rule's forward and backward
+    passes are a trace and a compile an operation.)"""
+    return jax.jit(jax.grad(lambda *a: jnp.sum(rule(*a) * cot),
+                            argnums=(0, 1, 2, 3, 4)))(*args)
+
+
+@functools.lru_cache(maxsize=None)
+def _programs(chunk):
+    """(The chunked rule at `chunk`, the recurrence), each as (outputs,
+    gradients of every input under a cotangent): compiled once a length,
+    shared by the cases that differ in their numbers alone."""
+    def both(rule):
+        return (jax.jit(rule),
+                jax.jit(jax.grad(lambda cot, *a: jnp.sum(rule(*a) * cot),
+                                 argnums=(1, 2, 3, 4, 5))))
+    return (both(functools.partial(gated_delta_rule, chunk=chunk)),
+            both(recurrent_gated_delta_rule))
 
 
 @pytest.mark.parametrize("strong", [False, True], ids=["weak", "strong"])
@@ -52,15 +69,13 @@ def test_the_chunked_rule_is_the_recurrence(chunk, seq, strong):
     """Outputs and the gradient of every input, at lengths that are and are
     not multiples of the chunk (the padding rows leave the state alone)."""
     args = _rule_inputs(seq, strong=strong)
-    got = gated_delta_rule(*args, chunk=chunk)
-    want = recurrent_gated_delta_rule(*args)
+    (ours, our_grads), (theirs, their_grads) = _programs(chunk)
+    got, want = ours(*args), theirs(*args)
     assert got.shape == want.shape == (2, 3, seq, 16)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     cot = jax.random.normal(jax.random.PRNGKey(9), want.shape, jnp.float32)
-    for name, g, w in zip("q k v g beta".split(),
-                          _grads(lambda *a: gated_delta_rule(*a, chunk=chunk),
-                                 args, cot),
-                          _grads(recurrent_gated_delta_rule, args, cot)):
+    for name, g, w in zip("q k v g beta".split(), our_grads(cot, *args),
+                          their_grads(cot, *args)):
         scale = float(jnp.max(jnp.abs(w))) or 1.0
         assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale, name
 

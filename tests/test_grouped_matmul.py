@@ -33,8 +33,12 @@ def _operands(n_rows, k, n, n_groups, dtype, seed=0):
 
 
 def _three_products(fn, rows, weights, cotangent):
-    out, vjp = jax.vjp(fn, rows, weights)
-    return (out,) + vjp(cotangent)
+    """The product and its two gradients as ONE compiled program (eagerly
+    each is a trace and a compile of its own, a dozen a case)."""
+    def three(rows, weights, cotangent):
+        out, vjp = jax.vjp(fn, rows, weights)
+        return (out,) + vjp(cotangent)
+    return jax.jit(three)(rows, weights, cotangent)
 
 
 def _close(got, want, dtype):
@@ -234,9 +238,11 @@ def test_the_depth_one_form_is_the_kernels_called_as_they_were(
     rows, weights, cotangent = _operands(n_rows, k, n, sizes.shape[0],
                                          jnp.bfloat16)
     plan = gm.visits(sizes, n_rows)
-    want = (gm._rows_product(rows, weights, plan, transposed=False),
-            gm._rows_product(cotangent, weights, plan, transposed=True),
-            gm._weights_product(rows, cotangent, plan, sizes.shape[0]))
+    want = jax.jit(lambda rows, weights, cotangent: (
+        gm._rows_product(rows, weights, plan, transposed=False),
+        gm._rows_product(cotangent, weights, plan, transposed=True),
+        gm._weights_product(rows, cotangent, plan, sizes.shape[0])))(
+            rows, weights, cotangent)
     _bits(_three_products(lambda r, w: gm.grouped_matmul(r, w, sizes),
                           rows, weights, cotangent), want)
     _bits(_three_products(

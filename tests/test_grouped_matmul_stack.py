@@ -4,11 +4,14 @@ file shares): a layer read in place in the stack gives its own products'
 bits, the stack takes no cotangent, and the model's layer scan hands the
 stack over."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family as programs
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.parallel import MeshSpec, build_mesh, moe
@@ -33,6 +36,27 @@ def _stack(depth, n_groups, k, n, dtype):
     return (w / k ** 0.5).astype(dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def _products(case):
+    """(The three products of `case` with the kernels reading `stack[layer]`
+    in place, the same over a layer's own matrices), each one compiled
+    program a depth: the layer is an operand, so the cases that differ in
+    it alone share the program (traced under the case's tiles and budget,
+    which every call of it holds)."""
+    sizes = jnp.asarray(STACKED[case][3], jnp.int32)
+
+    def in_the_stack(rows, weights, stack, layer, cotangent):
+        return _three_products(
+            lambda r, w: gm.grouped_matmul(r, w, sizes, stack, layer),
+            rows, weights, cotangent)
+
+    def alone(rows, weights, cotangent):
+        return _three_products(lambda r, w: gm.grouped_matmul(r, w, sizes),
+                               rows, weights, cotangent)
+
+    return jax.jit(in_the_stack), jax.jit(alone)
+
+
 @pytest.mark.parametrize("depth, layer", [(1, 0), (3, 0), (3, 1), (3, 2)])
 @pytest.mark.parametrize("case", STACKED)
 def test_a_layer_read_in_the_stack_gives_its_own_products_bits(
@@ -48,17 +72,10 @@ def test_a_layer_read_in_the_stack_gives_its_own_products_bits(
     rows, _, cotangent = _operands(n_rows, k, n, sizes.shape[0],
                                    jnp.bfloat16)
     stack = _stack(depth, sizes.shape[0], k, n, jnp.bfloat16)
-
-    @jax.jit
-    def in_the_stack(rows, weights, stack, layer, cotangent):
-        return _three_products(
-            lambda r, w: gm.grouped_matmul(r, w, sizes, stack, layer),
-            rows, weights, cotangent)
-
+    in_the_stack, alone = _products(case)
     got = in_the_stack(rows, stack[layer], stack, jnp.int32(layer),
                        cotangent)
-    want = _three_products(lambda r, w: gm.grouped_matmul(r, w, sizes),
-                           rows, stack[layer], cotangent)
+    want = alone(rows, stack[layer], cotangent)
     _bits(got, want)
     # the other layers' numbers were never read: they may be anything
     other = jnp.full_like(stack, jnp.nan).at[layer].set(stack[layer])
@@ -126,8 +143,7 @@ def test_the_layer_scan_hands_the_stack_over_and_the_bits_stay(
         router_z_coef=0.001, norm="rmsnorm", positions="rope", qk_norm=True,
         mlp="swiglu", attn="local", dtype=jnp.bfloat16, remat=remat)
     mesh = build_mesh(MeshSpec(dp=dp), jax.devices()[:dp])
-    params = tfm.shard_params(tfm.init(jax.random.PRNGKey(0), cfg), cfg,
-                              mesh)
+    params = tfm.shard_params(programs.init(cfg), cfg, mesh)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab,
                                 jnp.int32)
     grouped_matmul = moe.grouped_matmul

@@ -8,6 +8,8 @@ kernels against the scalar ones where g is constant over a head's channels;
 what the forward saves for the backward pass; and how many heads a grid step
 takes."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,9 +43,23 @@ def _inputs(seq, *, strong, dtype=jnp.float32, seed=0, batch=2, heads=2,
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
+@functools.lru_cache(maxsize=None)
+def _grad_program(fn):
+    """The gradients of every input of `fn` under a cotangent, as one
+    compiled program a shape (eagerly the rule's forward and backward passes
+    are a trace and a compile an operation), kept for the cases that call
+    the same rule at the same shapes with other numbers."""
+    return jax.jit(jax.grad(
+        lambda cot, *a: jnp.sum(fn(*a).astype(jnp.float32) * cot),
+        argnums=(1, 2, 3, 4, 5)))
+
+
 def _grads(fn, args, cot):
-    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cot),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+    return _grad_program(fn)(cot, *args)
+
+
+_rule = jax.jit(gated_delta_rule)
+_recurrence = jax.jit(recurrent_gated_delta_rule)
 
 
 @pytest.mark.parametrize("seq, chunk, strong", [
@@ -55,8 +71,8 @@ def test_the_per_channel_rule_equals_the_recurrence(seq, chunk, strong):
     finite under the strong decay and equal to the recurrence's there."""
     with jax.enable_x64(False):
         args = _inputs(seq, strong=strong)
-        got = gated_delta_rule(*args, chunk=chunk)
-        want = recurrent_gated_delta_rule(*args)
+        got = jax.jit(lambda *a: gated_delta_rule(*a, chunk=chunk))(*args)
+        want = _recurrence(*args)
         assert got.shape == want.shape == (2, 2, seq, 16)
         assert bool(jnp.all(jnp.isfinite(got)))
         np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
@@ -79,10 +95,11 @@ def test_the_rule_in_bf16_is_the_recurrence_on_the_rounded_inputs(strong):
     outputs and gradients, and finite under the strong decay."""
     with jax.enable_x64(False):
         args = _inputs(100, strong=strong, dtype=jnp.bfloat16)
-        got = gated_delta_rule(*args).astype(jnp.float32)
+        got = _rule(*args)
+        assert got.dtype == jnp.bfloat16
+        got = got.astype(jnp.float32)
         exact = tuple(x.astype(jnp.float32) for x in args)
-        want = recurrent_gated_delta_rule(*exact)
-        assert gated_delta_rule(*args).dtype == jnp.bfloat16
+        want = _recurrence(*exact)
         scale = float(jnp.max(jnp.abs(want)))
         assert float(jnp.max(jnp.abs(got - want))) <= 2e-2 * scale
         cot = jax.random.normal(jax.random.PRNGKey(9), want.shape,
@@ -125,8 +142,8 @@ def test_with_one_decay_a_head_it_is_the_scalar_kernels_rule(dtype):
         q, k, v, g, beta = _inputs(150, strong=False, dtype=dtype)
         scalar = g[..., 0]
         wide = jnp.broadcast_to(scalar[..., None], g.shape)
-        got = gated_delta_rule(q, k, v, wide, beta).astype(jnp.float32)
-        want = gated_delta_rule(q, k, v, scalar, beta).astype(jnp.float32)
+        got = _rule(q, k, v, wide, beta).astype(jnp.float32)
+        want = _rule(q, k, v, scalar, beta).astype(jnp.float32)
         tol = 3e-5 if dtype == jnp.float32 else 2e-2
         scale = float(jnp.max(jnp.abs(want)))
         assert float(jnp.max(jnp.abs(got - want))) <= tol * scale

@@ -1,9 +1,9 @@
-"""The parameter tree of the eight tiny family configurations of
+"""The parameter tree of the nine tiny family configurations of
 `tests/test_lowered_steps.py`: `init` draws, leaf by leaf, the numbers the
 commit before the layer parts (PR 44's, bf13d0e) drew
 (`tests/fixtures/init_digests.json`, written there by `write_fixture()`; the
 Granite family's entry on PR 46's own tree, the Kimi Linear family's on
-PR 50's, `write_fixture(only_new=True)`);
+PR 50's, the LFM2 family's on PR 54's, `write_fixture(only_new=True)`);
 and what the parts of `models/mixers.py` and `models/ffns.py` declare is one
 tree, each leaf of a layer declared by one part."""
 

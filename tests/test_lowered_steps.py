@@ -1,5 +1,6 @@
 """The lowered train steps of `tests/step_cases.py`, a third of them: the
-GPT-2 block, OLMoE's, DeepSeek-V2's and the Kimi Linear stack, each lowered
+GPT-2 block, OLMoE's, DeepSeek-V2's, the Kimi Linear stack and the LFM2 one,
+each lowered
 once in this process, its text held to the fixture's
 (`tests/fixtures/hlo/lowered_steps.json.gz`) and its compiled scopes read.
 The other families: `tests/test_hybrid_steps.py`, `tests/test_step_scopes.py`.
@@ -15,4 +16,4 @@ from step_cases import (  # noqa: F401  (the tests, cut to FAMILIES)
     test_the_reduction_has_its_scope_where_something_is_reduced,
     test_the_step_has_its_scopes_and_no_other)
 
-FAMILIES = ("gpt2", "olmoe", "deepseek_v2", "kimi_linear")
+FAMILIES = ("gpt2", "olmoe", "deepseek_v2", "kimi_linear", "lfm2_moe")

@@ -12,6 +12,8 @@ product a chunk of 16 sorted tokens here and a gather, against the float32
 scatter-add rounded once, and its lowered text, which holds no scatter.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -184,6 +186,22 @@ def test_moved_share_at_the_cells_loads(cell, n_tokens, k, n_experts,
         assert got == held / back.size
 
 
+@functools.lru_cache(maxsize=None)
+def _movers(k, held):
+    """`take_rows` and `sum_rows` with their backward passes as one compiled
+    program a (k, whether a share is held): `n_valid` is an operand, so the
+    cases that differ in it alone share the program (every test that calls
+    it holds the small tiles)."""
+    def movers(x, ys, token_of, inverse, n_valid):
+        valid = n_valid if held else None
+        taken, take_vjp = jax.vjp(
+            lambda x: rg.take_rows(x, token_of, inverse, k, valid), x)
+        summed, sum_vjp = jax.vjp(
+            lambda ys: rg.sum_rows(ys, inverse, token_of, k, valid), ys)
+        return taken, take_vjp(ys)[0], summed, sum_vjp(x)[0]
+    return jax.jit(movers)
+
+
 @pytest.mark.parametrize("n_valid", [None, 0, 1, 16, 30, 48])
 @pytest.mark.parametrize("k", [1, 6])
 def test_the_two_movers_and_their_backward_passes(small_tiles, k, n_valid):
@@ -201,17 +219,12 @@ def test_the_two_movers_and_their_backward_passes(small_tiles, k, n_valid):
         room = n_tokens * k
         token_of = (order // k).astype(jnp.int32)
         ys = _rows(room, width, jnp.bfloat16, seed=3)
-    valid = None if n_valid is None else jnp.int32(n_valid)
-
-    taken, take_vjp = jax.vjp(
-        lambda x: rg.take_rows(x, token_of, inverse, k, valid), x)
+    taken, d_take, summed, d_sum = _movers(k, n_valid is not None)(
+        x, ys, token_of, inverse, jnp.int32(n_valid or 0))
     _same(taken, _masked_take(x, token_of, n_valid))
-    _same(take_vjp(ys)[0], rg.reference_sum(ys, inverse, k, n_valid))
-
-    summed, sum_vjp = jax.vjp(
-        lambda ys: rg.sum_rows(ys, inverse, token_of, k, valid), ys)
+    _same(d_take, rg.reference_sum(ys, inverse, k, n_valid))
     _same(summed, rg.reference_sum(ys, inverse, k, n_valid))
-    _same(sum_vjp(x)[0], _masked_take(x, token_of, n_valid))
+    _same(d_sum, _masked_take(x, token_of, n_valid))
 
 
 def test_which_sums_the_kernel_takes(monkeypatch):
@@ -337,10 +350,16 @@ def test_the_lookups_backward_is_the_float32_scatter_add_rounded_once(
         g.astype(jnp.float32)).astype(dtype)
     # through the `custom_vjp`, the ids in two dimensions as a batch has them
     shape = (2, ids.size // 2) if ids.size % 2 == 0 else (1, ids.size)
-    out, vjp = jax.vjp(lambda t: rg.lookup_rows(t, ids.reshape(shape))[0],
-                       table)
+    # (one compiled program a case: eagerly the backward pass's sort, its
+    # products and its gather are a compile each)
+    def both(table, g):
+        out, vjp = jax.vjp(
+            lambda t: rg.lookup_rows(t, ids.reshape(shape))[0], table)
+        return out, vjp(g)[0]
+
+    out, got = jax.jit(both)(table, g.reshape(shape + (128,)))
     _same(out, table[ids.reshape(shape)])
-    _same(vjp(g.reshape(shape + (128,)))[0], want)
+    _same(got, want)
     assert rg.slot_share(np.asarray(ids)) <= 1.0
 
 
@@ -358,7 +377,7 @@ def test_a_later_readers_gradient_is_what_the_lookups_is_added_to(
         def loss(t):
             rows, again = lookup(t, ids)
             return jnp.sum(rows * g) + jnp.sum((rows @ again.T) ** 2)
-        return jax.grad(loss)(table)
+        return jax.jit(jax.grad(loss))(table)
 
     np.testing.assert_allclose(
         np.asarray(tied(rg.lookup_rows)),
@@ -373,7 +392,8 @@ def test_the_lookups_backward_reads_odd_ids_as_the_lookup_does(small_chunks):
     g = _cotangent(ids.size, 128, jnp.float32)
     table = _rows(n_rows, 128, jnp.float32)
     want = jax.vjp(lambda t: t[ids], table)[1](g)[0]
-    _same(jax.vjp(lambda t: rg.lookup_rows(t, ids)[0], table)[1](g)[0], want)
+    _same(jax.jit(lambda table, g: jax.vjp(
+        lambda t: rg.lookup_rows(t, ids)[0], table)[1](g)[0])(table, g), want)
 
 
 @pytest.mark.parametrize("ids,share", [

@@ -16,6 +16,12 @@ from horovod_tpu.ops.selective_scan import (chunk_of,
 NAMES = ("c", "delta", "A", "B", "C", "D_skip")
 
 
+def _grad(fun, **kw):
+    """`jax.grad` as one compiled program: eagerly a kernel's forward and
+    backward passes are a trace and a compile an operation."""
+    return jax.jit(jax.grad(fun, **kw))
+
+
 def _inputs(batch=2, seq=32, channels=24, states=4, dtype=jnp.float32,
             seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -69,12 +75,12 @@ def gradients():
     weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     found = {}
     with jax.enable_x64(False):
-        want = jax.grad(lambda *a: jnp.sum(
+        want = _grad(lambda *a: jnp.sum(
             reference_selective_scan(*a) * weight), argnums=range(6))(*args)
         for chunk in (8, 32):
             with pytest.MonkeyPatch.context() as patch:
                 patch.setattr(ss, "CHUNK", chunk)
-                found[chunk] = (jax.grad(lambda *a: jnp.sum(
+                found[chunk] = (_grad(lambda *a: jnp.sum(
                     selective_scan(*a) * weight),
                     argnums=range(6))(*args), want)
     return found
@@ -102,9 +108,9 @@ def test_a_channel_block_walked_in_pieces_and_several_blocks(monkeypatch):
         got = selective_scan(*args)
         want = reference_selective_scan(*args)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-        ours = jax.grad(lambda *a: jnp.sum(selective_scan(*a) ** 2),
-                        argnums=range(6))(*args)
-        theirs = jax.grad(lambda *a: jnp.sum(
+        ours = _grad(lambda *a: jnp.sum(selective_scan(*a) ** 2),
+                     argnums=range(6))(*args)
+        theirs = _grad(lambda *a: jnp.sum(
             reference_selective_scan(*a) ** 2), argnums=range(6))(*args)
     for name, a, b in zip(NAMES, ours, theirs):
         np.testing.assert_allclose(
@@ -120,7 +126,7 @@ def test_bf16_operands_round_once(monkeypatch):
     with jax.enable_x64(False):
         got = selective_scan(*args)
         want = reference_selective_scan(*args)
-        grads = jax.grad(lambda *a: jnp.sum(selective_scan(
+        grads = _grad(lambda *a: jnp.sum(selective_scan(
             *a).astype(jnp.float32)), argnums=range(6))(*args)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=2 ** -7,

@@ -17,6 +17,12 @@ from horovod_tpu.ops.ssd_scan import (chunked_ssd_scan, recurrent_ssd_scan,
 NAMES = ("x", "dt", "a_log", "b", "c", "d_skip")
 
 
+def _grad(fun, **kw):
+    """`jax.grad` as one compiled program: eagerly a kernel's forward and
+    backward passes are a trace and a compile an operation."""
+    return jax.jit(jax.grad(fun, **kw))
+
+
 def _inputs(batch=2, seq=40, heads=4, width=8, states=16,
             dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -87,8 +93,8 @@ def gradients():
             weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
 
             def grads(fn):
-                return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
-                                argnums=tuple(range(6)))(*args)
+                return _grad(lambda *a: jnp.sum(fn(*a) * weight),
+                             argnums=tuple(range(6)))(*args)
 
             found[seq, chunk] = (
                 grads(lambda *a: ssd_scan(*a, chunk=chunk)),
@@ -125,10 +131,10 @@ def test_blocks_of_heads_and_tiles_of_every_kind(heads, width, a_step,
         got = ssd_scan(*args, chunk=8)
         want = recurrent_ssd_scan(*args)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-        ours = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=8) ** 2),
-                        argnums=tuple(range(6)))(*args)
-        theirs = jax.grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a) ** 2),
-                          argnums=tuple(range(6)))(*args)
+        ours = _grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=8) ** 2),
+                     argnums=tuple(range(6)))(*args)
+        theirs = _grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a) ** 2),
+                       argnums=tuple(range(6)))(*args)
     for name, a, b in zip(NAMES, ours, theirs):
         np.testing.assert_allclose(
             a, b, rtol=5e-4, atol=5e-5 * float(jnp.max(jnp.abs(b))),
@@ -143,10 +149,10 @@ def test_bf16_operands_round_once():
     with jax.enable_x64(False):
         got = ssd_scan(*args, chunk=16)
         want = recurrent_ssd_scan(*(a.astype(jnp.float32) for a in args))
-        ours = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=16).astype(
+        ours = _grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=16).astype(
             jnp.float32)), argnums=tuple(range(6)))(*args)
-        theirs = jax.grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a)),
-                          argnums=tuple(range(6)))(
+        theirs = _grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a)),
+                       argnums=tuple(range(6)))(
             *(a.astype(jnp.float32) for a in args))
     assert got.dtype == jnp.bfloat16
     scale = float(jnp.sqrt(jnp.mean(jnp.square(want))))
@@ -170,10 +176,10 @@ def test_the_decays_gradient_survives_bf16_at_the_cells_widths():
     exact = tuple(a.astype(jnp.float32) for a in args)
     with jax.enable_x64(False):
         weight = jax.random.normal(jax.random.PRNGKey(5), args[0].shape)
-        ours = jax.grad(lambda *a: jnp.sum(ssd_scan(*a).astype(jnp.float32)
-                                           * weight), argnums=(1, 2))(*args)
-        theirs = jax.grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a)
-                                             * weight), argnums=(1, 2))(*exact)
+        ours = _grad(lambda *a: jnp.sum(ssd_scan(*a).astype(jnp.float32)
+                                        * weight), argnums=(1, 2))(*args)
+        theirs = _grad(lambda *a: jnp.sum(recurrent_ssd_scan(*a)
+                                          * weight), argnums=(1, 2))(*exact)
     for name, a, b in zip(("dt", "a_log"), ours, theirs):
         off = float(jnp.sqrt(jnp.mean(jnp.square(a - b))))
         assert off < 0.02 * float(jnp.sqrt(jnp.mean(jnp.square(b)))), name

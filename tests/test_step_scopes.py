@@ -95,19 +95,20 @@ def test_no_instruction_of_smallthinkers_layers_lies_outside_a_scope():
 def test_the_vocabulary_is_what_the_source_enters():
     """`STEP_SCOPES` is every scope `models/transformer.py` and its layer
     parts (`models/mixers.py`, `models/ffns.py`) enter outside the mixers'
-    own (`moe.shared`, `mla.*`, `gdn.*`, `kda.*`, `ssm.*`, `gmu.*`; a Mamba-2
-    layer's `ssd.*` are listed in it), no more and no less; and a part enters its scopes whatever the stack: the rows of
+    own (`moe.shared`, `mla.*`, `gdn.*`, `kda.*`, `ssm.*`, `gmu.*`,
+    `shortconv.*`; a Mamba-2 layer's `ssd.*` are listed in it), no more and no less; and a part enters its scopes whatever the stack: the rows of
     `MIXERS` and `FFNS` know of no pattern."""
     parts = inspect.getsource(mixers) + inspect.getsource(ffns)
     entered = set(re.findall(r'named_scope[(,]\s*"([^"]+)"',
                              inspect.getsource(tfm) + parts))
     own = {s for s in entered
-           if s.startswith(("moe.", "mla.", "gdn.", "kda.", "ssm.", "gmu."))}
+           if s.startswith(("moe.", "mla.", "gdn.", "kda.", "ssm.", "gmu.",
+                            "shortconv."))}
     assert entered - own == set(tfm.STEP_SCOPES)
     assert len(set(tfm.STEP_SCOPES)) == len(tfm.STEP_SCOPES)
     assert "layer_pattern" not in parts + inspect.getsource(tfm._layer)
     assert own >= {"mla.project", "gdn.scan", "kda.scan", "ssm.scan",
-                   "gmu.gate", "moe.shared"}
+                   "gmu.gate", "moe.shared", "shortconv.mix"}
     assert set(tfm.STEP_SCOPES) >= set(SSD)
 
 
