@@ -52,7 +52,8 @@ from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops import row_gather as rg
 from horovod_tpu.ops import selective_scan as ss
 from horovod_tpu.ops import ssd_scan as ssd
-from horovod_tpu.ops.flash_attention import (causal_tile_share,
+from horovod_tpu.ops.flash_attention import (backward_products,
+                                             causal_tile_share,
                                              flash_attention,
                                              grid_step_share,
                                              masked_attention_reference,
@@ -254,9 +255,11 @@ def _best_ms(runs: dict, repeats: int = 10) -> dict:
 
 def _kernels_alone_ms(q, k, v, repeats: int = 10) -> dict:
     """Milliseconds an execution of each flash kernel takes alone (best of
-    three batches of `repeats`), causal, at q, k: (B, H, S, dqk) and
-    v: (B, H, S, dv). Information only: alone the kernels read ~10% over
-    their time inside a train step (PERF.md, PR 27)."""
+    three batches of `repeats`; the backward is one kernel where its
+    accumulators fit VMEM, `backward_products`, else dq and dk/dv together),
+    causal, at q, k: (B, H, S, dqk) and v: (B, H, S, dv). Information only:
+    alone the kernels read ~10% over their time inside a train step
+    (PERF.md, PR 27)."""
     from horovod_tpu.ops import flash_attention as fa
     seq = q.shape[2]
     block = fa._auto_block(seq)
@@ -270,8 +273,7 @@ def _kernels_alone_ms(q, k, v, repeats: int = 10) -> dict:
 
     o, lse = fwd(*flat)
     runs = {"forward": (fwd, flat),
-            "dq": (jax.jit(lambda *a: bwd(*a)[0]), (*flat, o, lse, o)),
-            "dk/dv": (jax.jit(lambda *a: bwd(*a)[1:]), (*flat, o, lse, o))}
+            "backward": (jax.jit(bwd), (*flat, o, lse, o))}
     return _best_ms(runs, repeats)
 
 
@@ -281,7 +283,7 @@ def flash_kernel(log: CompileLog, shape=(12, 16, 1024, 128),
     at the flagship's (B, H, S, dh) and at `wide` (B, H, S, dqk, dv): keys
     wider than values, the latent attention's shape (`dsv2lite-1chip`). On
     the TPU the kernel must be compiled by Mosaic, not interpreted, and the
-    program must hold it. Then each of the three kernels alone, at both."""
+    program must hold it. Then the forward and the backward alone, at both."""
     if _pallas.interpret() is on_tpu():
         raise AssertionError(
             f"Pallas interpret={_pallas.interpret()} on platform "
@@ -312,10 +314,13 @@ def _flash_against_reference(widths) -> None:
         argnums=(0, 1, 2), has_aux=True))
     compiled = flash.lower(q, k, v, w).compile()
     n_kernels = compiled.as_text().count("tpu_custom_call")
-    if on_tpu() and n_kernels < 3:  # forward, dk/dv, dq
+    products, resident = backward_products(shape[2], dqk, dv)
+    want = 2 if products == 5 else 3    # forward; backward, or dk/dv and dq
+    if on_tpu() and n_kernels != want:
         raise AssertionError(
             f"compiled flash program holds {n_kernels} Mosaic custom "
-            "calls, expected the forward and both backward kernels")
+            f"calls, expected the forward and {want - 1} of the backward "
+            f"pass ({products} products a block pair)")
     (_, o), grads = flash(q, k, v, w)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     with jax.default_matmul_precision("highest"):
@@ -345,7 +350,10 @@ def _flash_against_reference(widths) -> None:
         f"{grid_step_share(shape[2]):.4f} (grid steps a head runs over the "
         "steps that compute), row_strip_share "
         f"{row_strip_share(shape[2]):.4f} (the forward's score entries in "
-        "strips of 256 rows or fewer); "
+        f"strips of 256 rows or fewer), backward_products {products} "
+        "(score-sized products a block pair of the backward pass: 5 in one "
+        f"kernel, {resident / 2 ** 20:.1f} MiB of accumulators and output "
+        "blocks resident; 7 in two); "
         f"interpret={_pallas.interpret()}, {n_kernels} tpu_custom_call "
         "in the compiled program; the kernels alone (information only), "
         "ms an execution: "
@@ -928,7 +936,8 @@ def windowed_grouped_flash(log: CompileLog, shape=(1, 20, 10, 8192, 64, 128),
     (batch, query heads, K/V heads, tokens, keys' width, values' width) with
     and without the window. Output and the three gradients over the first
     `checked` tokens against the mask written out, the Mosaic kernels the
-    compiled programs hold (forward one, with the gradients three), no
+    compiled programs hold (forward one, with the gradients two: the
+    backward pass is one kernel, `backward_products`), no
     compile request after a first call, and the times of the windowed call
     beside the full one's."""
     batch, heads, kv_heads, seq, dk, dv = shape
@@ -969,8 +978,10 @@ def windowed_grouped_flash(log: CompileLog, shape=(1, 20, 10, 8192, 64, 128),
             "forward + backward": (jax.jit(functools.partial(
                 both, flash, banded)).lower(cot, q, k, v).compile(),
                 (cot, q, k, v))}
-        kernels = _counted(runs, {"forward": 1, "forward + backward": 3},
-                           "grouped flash attention")
+        fused = backward_products(seq, dk, dv, heads // kv_heads)[0] == 5
+        kernels = _counted(
+            runs, {"forward": 1, "forward + backward": 2 if fused else 3},
+            "grouped flash attention")
         ms = _timed_without_recompiles(log, runs, "grouped flash", 10)
         share = window_tile_share(seq, banded) if banded and banded < seq \
             else causal_tile_share(seq)

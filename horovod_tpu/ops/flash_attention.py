@@ -22,11 +22,19 @@ lower edge crosses is walked in the same strips, each cut to the tiles the
 band holds of it (`_windowed_strips`). Blocks above the diagonal or wholly
 under the band are no grid steps at all: a kernel's grid is (heads, the
 block pairs the mask holds), a query block's key blocks one after the other
-(dk/dv: a key block's query blocks), and a step finds its pair from static
-tables (`_Walk`). Keys and values may have fewer heads than the queries
-(`group` query heads read one K/V head): the K/V blocks are found by the
-index map `head // group`, and the dk/dv kernel walks a group's query heads
-in turn and sums them.
+(the backward: a key block's query blocks), and a step finds its pair from
+static tables (`_Walk`). Keys and values may have fewer heads than the
+queries (`group` query heads read one K/V head): the K/V blocks are found by
+the index map `head // group`, and the backward walks a group's query heads
+in turn and sums their dk and dv.
+The backward pass is ONE kernel (`_bwd_fused_kernel`, PR 55): a walk by key
+blocks that makes a block pair's probabilities and score gradient once and
+adds into dv, dk and the query block's rows of a float32 dq accumulator that
+stays in VMEM for a query head's whole walk: five score-sized products a
+block pair and one pass of `exp`, where a dq and a dk/dv kernel made seven
+and two. Those two stay for shapes whose accumulators over the sequence pass
+the VMEM budget (`_fused_params`, from the static shapes alone; no cell of
+the benchmark: `backward_products`).
 Numerics are validated against
 `parallel.ring_attention.blockwise_attention_reference` (forward AND
 gradients) in tests/test_flash_attention.py.
@@ -39,6 +47,7 @@ path is testable on the CPU mesh (tests/conftest.py); the decision is
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -199,18 +208,23 @@ class _Walk:
     pairs (r, first[r]) ... (r, last[r]), a grid step each, gone through
     `heads` times in turn, run after run (forward and dq: a query block's
     key blocks; dk/dv: a key block's query blocks, once for each query head
-    of the K/V head's group). Index maps and kernel bodies find the pair of
+    of the K/V head's group), or `head_major`, all the runs once a head (the
+    fused backward: a query head's whole walk by key blocks, then the
+    group's next head's). Index maps and kernel bodies find the pair of
     step s in static tables, a scalar compare and select a pass (passes of
     one length: a division), so the grid needs no scalar-prefetch operand
     and no step is idle."""
 
-    def __init__(self, runs, heads: int = 1):
+    def __init__(self, runs, heads: int = 1, head_major: bool = False):
         self.heads = heads
-        self.first, self.last = zip(*(run for run in runs
-                                      for _ in range(heads)))
+        self.head_major = head_major and heads > 1
+        self.first, self.last = zip(*(
+            run for run in runs
+            for _ in range(1 if self.head_major else heads)))
         lengths = [hi - lo + 1 for lo, hi in zip(self.first, self.last)]
         self.starts = [sum(lengths[:n]) for n in range(len(lengths))]
-        self.steps = sum(lengths)
+        self.period = sum(lengths)      # the steps the tables hold
+        self.steps = self.period * (heads if self.head_major else 1)
         self.length = lengths[0] if len(set(lengths)) == 1 else None
 
     def _of_pass(self, s, values):
@@ -223,15 +237,25 @@ class _Walk:
                                  np.int32(value), out)
         return out
 
+    def _split(self, s):
+        """(Which head's going-through of the tables step s is of, s within
+        it): a head-major walk goes through them once a head."""
+        if self.head_major:
+            return s // self.period, s % self.period
+        return 0, s
+
     def run(self, s):
         """(The run of step s, which of the `heads` passes over it)."""
+        head, s = self._split(s)
         n = s // self.length if self.length is not None else \
             self._of_pass(s, range(len(self.starts)))
-        return (n, 0) if self.heads == 1 else (n // self.heads,
-                                               n % self.heads)
+        if self.heads > 1 and not self.head_major:
+            return n // self.heads, n % self.heads
+        return n, head
 
     def at(self, s):
         """Where in its run step s stands: first[r] <= at <= last[r]."""
+        s = self._split(s)[1]
         if self.length is not None:
             return self._of_pass(s, self.first) + s % self.length
         return s - self._of_pass(
@@ -242,12 +266,20 @@ class _Walk:
         whether it is the first of the run's steps, and the last: where an
         accumulator over the run is zeroed and written out)."""
         (run, head), at = self.run(s), self.at(s)
-        first = at == self._of_pass(s, self.first)
-        last = at == self._of_pass(s, self.last)
+        t = self._split(s)[1]
+        first = at == self._of_pass(t, self.first)
+        last = at == self._of_pass(t, self.last)
         if self.heads > 1:
             first = jnp.logical_and(first, head == 0)
             last = jnp.logical_and(last, head == self.heads - 1)
         return run, at, first, last
+
+    def head_ends(self, s):
+        """Whether step s is the first of a head's going-through of all the
+        runs, and the last: where an accumulator over a query head's whole
+        walk is zeroed and written out."""
+        t = self._split(s)[1]
+        return t == 0, t == self.period - 1
 
 
 def _block_maps(walk: _Walk, group: int, by_key_block: bool = False):
@@ -286,17 +318,21 @@ def _scores(q_ref, k_ref, row0, rows, cols, masked, col0=0, *,
     return s
 
 
-def _compiler_params(dh: int, dhv: int) -> dict:
+def _compiler_params(dh: int, dhv: int, resident: int = 0) -> dict:
     """`pallas_call` arguments that give a kernel the VMEM its blocks need.
     Up to 128 lanes a head the 1024² blocks fit the compiler's default
     scope (16 MiB). A wider head takes two lane tiles a row in every q/k
     block and accumulator: at (192 | 128) the dk/dv kernel needs 17.2 MiB
     inside a train step (the TPU compiler's own count, PR 30), so such
-    kernels are given twice the default; v5e has 128 MiB."""
-    if max(dh, dhv) <= _LANES:
+    kernels are given twice the default; v5e has 128 MiB. `resident`: the
+    bytes a kernel keeps beside its working blocks (the fused backward's
+    accumulators and output blocks over the sequence,
+    `fused_backward_bytes`), asked for on top."""
+    wide = max(dh, dhv) > _LANES
+    if not wide and not resident:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=32 * 2 ** 20)}
+        vmem_limit_bytes=(32 if wide else 16) * 2 ** 20 + resident)}
 
 
 # --------------------------------------------------------------------------
@@ -509,16 +545,123 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_dlse,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
+def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, has_dlse,
+                      walk, window=None):
+    """dq, dk and dv in one walk by key blocks: a step makes `_bwd_strip`'s
+    (p, ds) once and adds pᵀ·do into dv, dsᵀ·q into dk and ds·k into the
+    query block's rows of dq's accumulator, which stays in VMEM for a query
+    head's whole walk. dk's and dv's accumulators and output blocks hold the
+    run's key block, or where a group's query heads walk the keys one after
+    the other (`walk.heads` > 1), the keys' whole sequence."""
+    ins = refs[:6] + (refs[6] if has_dlse else None,)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[6 + has_dlse:]
+    q_ref, k_ref, do_ref = ins[0], ins[1], ins[4]
+    step_id = pl.program_id(1)
+    ik, iq, first, last = walk.where(step_id)
+    head_first, head_last = walk.head_ends(step_id)
+    block = dict(iq=iq, ik=ik, block_q=block_q, block_k=block_k,
+                 window=window)
+
+    def rows_of(acc, index, block, row0, rows):
+        """Rows [row0, row0 + rows) of block `index` of an accumulator over
+        a sequence (of its one block: static)."""
+        if acc.shape[0] == block:
+            return pl.ds(row0, rows)
+        return pl.ds(pl.multiple_of(index * block + row0,
+                                    math.gcd(block, row0)), rows)
+
+    # the key block's rows of dk's and dv's accumulators and output blocks
+    held = rows_of(dk_acc, ik, block_k, 0, block_k)
+
+    @pl.when(head_first)
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first)
+    def _init_dkdv():
+        dk_acc[held, :] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
+        dv_acc[held, :] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
+
+    def step(row0, rows, cols, masked, col0=0):
+        p, ds = _bwd_strip(ins, row0, rows, cols, masked, col0,
+                           scale=scale, **block)
+        r = slice(row0, row0 + rows)
+        c = rows_of(dk_acc, ik, block_k, col0, cols)
+        rq = rows_of(dq_acc, iq, block_q, row0, rows)
+        # dv += pᵀ · do ;  dk += dsᵀ · q   (the strip's columns only)
+        dv_acc[c, :] = dv_acc[c, :] + jax.lax.dot_general(
+            p, do_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[c, :] = dk_acc[c, :] + jax.lax.dot_general(
+            ds, q_ref[0, r, :].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # dq += ds · k   (the strip's rows of the head's sequence)
+        dq_acc[rq, :] = dq_acc[rq, :] + jax.lax.dot_general(
+            ds, k_ref[0, col0:col0 + cols, :].astype(jnp.float32),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _for_each_strip(step, causal=causal, **block)
+
+    @pl.when(last)
+    def _finish_dkdv():
+        dk_ref[0, held, :] = dk_acc[held, :].astype(dk_ref.dtype)
+        dv_ref[0, held, :] = dv_acc[held, :].astype(dv_ref.dtype)
+
+    @pl.when(head_last)
+    def _finish_dq():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+#: The VMEM a fused backward kernel may ask for, of v5e's 128 MiB.
+_FUSED_VMEM_LIMIT = 100 * 2 ** 20
+
+
+def _vmem_bytes(rows: int, width: int, itemsize: int) -> int:
+    """Bytes of a (rows, width) block in VMEM: a row takes whole lane
+    tiles."""
+    return rows * -(-width // _LANES) * _LANES * itemsize
+
+
+def fused_backward_bytes(sq: int, sk: int, dh: int, dhv: int, group: int,
+                         block_k: int, itemsize: int = 2) -> int:
+    """VMEM the fused backward kernel keeps beside its working blocks, from
+    the static shapes: dq's float32 accumulator over a query head's sequence
+    and its output block (two buffers); dk's and dv's likewise, over the
+    keys' sequence where a K/V head's `group` query heads walk it one after
+    the other, over one key block where group == 1."""
+    rows_k = sk if group > 1 else block_k
+    each = 4 + 2 * itemsize
+    return sum(_vmem_bytes(rows, width, each) for rows, width in (
+        (sq, dh), (rows_k, dh), (rows_k, dhv)))
+
+
+def _fused_params(sq, sk, dh, dhv, group, block_k, itemsize):
+    """`_compiler_params` of the fused backward kernel at these shapes; None
+    where what it keeps resident passes `_FUSED_VMEM_LIMIT` (sequences far
+    past the cells' 16,384) and the dq and dk/dv kernels stay."""
+    params = _compiler_params(dh, dhv, fused_backward_bytes(
+        sq, sk, dh, dhv, group, block_k, itemsize))
+    limit = params["compiler_params"].vmem_limit_bytes
+    return params if limit <= _FUSED_VMEM_LIMIT else None
+
+
 def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k,
          window=None):
     """dlse=None compiles lse-cotangent-free kernels (the plain
-    flash_attention path never pays for a zero dlse buffer)."""
+    flash_attention path never pays for a zero dlse buffer). One kernel
+    (`_bwd_fused_kernel`: five products a block pair) where dq's accumulator
+    over the sequence fits VMEM, which the static shapes decide
+    (`_fused_params`); else the dq and the dk/dv kernel (seven)."""
     bh, sq, dh = q.shape            # dq and dk follow q's width,
     sk, dhv = v.shape[1:]           # o, do and dv follow v's
     has_dlse = dlse is not None
     group = bh // k.shape[0]
     rows = held_blocks(sq // block_q, sk // block_k, block_q, block_k,
                        causal, window)
+    by_key = _by_key_block(rows, sk // block_k)
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, has_dlse=has_dlse, window=window)
 
     def specs(walk, by_key_block=False):
         """(by_q, by_k, the in_specs of `operands`) over `walk`'s grid."""
@@ -532,20 +675,49 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k,
                             lse_spec] + [lse_spec] * has_dlse
 
     operands = [q, k, v, o, do, lse] + [dlse] * has_dlse
-    walk = _Walk(_by_key_block(rows, sk // block_k), heads=group)
+    out_shape = [jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
+                 jax.ShapeDtypeStruct((bh // group, sk, dh), q.dtype),
+                 jax.ShapeDtypeStruct((bh // group, sk, dhv), q.dtype)]
+
+    fused = _fused_params(sq, sk, dh, dhv, group, block_k, q.dtype.itemsize)
+    if fused is not None:
+        walk = _Walk(by_key, heads=group, head_major=True)
+        _, by_k, in_specs = specs(walk, by_key_block=True)
+        # dk and dv: a group's sum over the keys' sequence, or a key block
+        rows_k, of_keys = (sk, lambda b, s: (b, 0, 0)) if group > 1 else (
+            block_k, by_k)
+        return tuple(pallas_call(
+            functools.partial(_bwd_fused_kernel, walk=walk, **static),
+            grid=(bh // group, walk.steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, sq, dh), lambda b, s: (
+                    b * group + walk.run(s)[1], 0, 0)),
+                pl.BlockSpec((1, rows_k, dh), of_keys),
+                pl.BlockSpec((1, rows_k, dhv), of_keys)],
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((sq, dh), jnp.float32),
+                            pltpu.VMEM((rows_k, dh), jnp.float32),
+                            pltpu.VMEM((rows_k, dhv), jnp.float32)],
+            # dq takes do's buffer where their shapes agree: a head's do
+            # blocks are all read before its dq block is written (at the
+            # head's last step), and the three results of one kernel are
+            # alive together, where dq could be consumed before dk and dv
+            # were made: with it no cell's step needs more HBM than it did
+            input_output_aliases={4: 0} if do.shape == q.shape
+            and do.dtype == q.dtype else {},
+            **fused,
+        )(*operands))
+
+    walk = _Walk(by_key, heads=group)
     _, by_k, in_specs = specs(walk, by_key_block=True)
     dk, dv = pallas_call(
-        functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          has_dlse=has_dlse, walk=walk, window=window),
+        functools.partial(_bwd_dkdv_kernel, walk=walk, **static),
         grid=(bh // group, walk.steps),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, block_k, dh), by_k),
                    pl.BlockSpec((1, block_k, dhv), by_k)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh // group, sk, dh), q.dtype),
-            jax.ShapeDtypeStruct((bh // group, sk, dhv), q.dtype),
-        ],
+        out_shape=out_shape[1:],
         scratch_shapes=[
             pltpu.VMEM((block_k, dh), jnp.float32),
             pltpu.VMEM((block_k, dhv), jnp.float32),
@@ -556,13 +728,11 @@ def _bwd(q, k, v, o, lse, do, dlse, causal, scale, block_q, block_k,
     walk = _Walk(rows)
     by_q, _, in_specs = specs(walk)
     dq = pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          has_dlse=has_dlse, walk=walk, window=window),
+        functools.partial(_bwd_dq_kernel, walk=walk, **static),
         grid=(bh, walk.steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, dh), by_q),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
+        out_shape=out_shape[0],
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         **_compiler_params(dh, dhv),
     )(*operands)
@@ -753,6 +923,24 @@ def row_strip_share(S: int, window: Optional[int] = None,
             for rows, cols in strips:
                 entries[rows <= _WHOLE_STRIP_ROWS] += rows * cols
     return entries[True] / (entries[True] + entries[False])
+
+
+def backward_products(S: int, dh: int, dv: Optional[int] = None,
+                      group: int = 1, Sk: Optional[int] = None,
+                      itemsize: int = 2):
+    """(Score-sized matrix products the backward pass makes a block pair,
+    the VMEM bytes `fused_backward_bytes` reckons for these shapes): 5 where
+    the fused kernel runs (q·kᵀ, do·vᵀ, pᵀ·do, dsᵀ·q, ds·k, and one pass of
+    `exp` and the bracket), 7 where those bytes pass `_FUSED_VMEM_LIMIT` and
+    the dq and the dk/dv kernel each make q·kᵀ, `exp` and do·vᵀ again. The
+    choice is `_bwd`'s own, from the static shapes alone: S queries against
+    Sk keys (S by default) of width dh, values dv wide (dh by default),
+    `group` query heads a K/V head, in the kernels' own blocks."""
+    dv, Sk = dv or dh, Sk or S
+    block_k = _auto_block(Sk)
+    fused = _fused_params(S, Sk, dh, dv, group, block_k, itemsize)
+    return (7 if fused is None else 5,
+            fused_backward_bytes(S, Sk, dh, dv, group, block_k, itemsize))
 
 
 def masked_attention_reference(q, k, v, causal: bool = True,

@@ -147,6 +147,18 @@ _PINNED_BY_PR_46 = {
         "PR 46; the harness's file is no cell PR's to edit",
 }
 
+#: One more of the kind, met by PR 55: the tiny LM cells' step held four
+#: Mosaic kernels (the flash forward, its remat repeat, dk/dv and dq) and
+#: holds three since the backward pass is one kernel. Both cases run, every
+#: line of them and the new count, in `tests/test_kernels_tpu_aot.py`
+#: (`test_the_tiny_lm_cells_steps_hold_three_flash_kernels`).
+_PINNED_BY_PR_55 = {
+    "test_benchmark_aot.py::test_each_paths_step_compiles_for_the_described_"
+    f"v5e[{case}]":
+        "holds the tiny LM step to four Mosaic kernels; the flash backward "
+        "pass is one kernel since PR 55, so it has three"
+    for case in ("tiny-lm-1chip-4-False", "tiny-lm-dp4-4-True")}
+
 
 def pytest_collection_modifyitems(config, items):
     skips = []
@@ -171,7 +183,7 @@ def pytest_collection_modifyitems(config, items):
                 reason="pins BENCHMARK.json's last eight per-layer metrics "
                        "to PR 34's; PR 36 appended six after them",
                 strict=False))
-        for pinned, why in _PINNED_BY_PR_46.items():
+        for pinned, why in {**_PINNED_BY_PR_46, **_PINNED_BY_PR_55}.items():
             if item.nodeid.endswith(pinned):
                 item.add_marker(pytest.mark.xfail(reason=why, strict=False))
 

@@ -68,7 +68,12 @@ layers to one grouped-query attention layer with QK-norm per head behind a
 dense layer, sigmoid scores renormalised over their sum + 1e-6, a tied head)
 and left the fourteen older texts byte for byte the parent's: QK-norm over the
 whole vector is the code it was, and a renormalisation whose epsilon is 0
-divides by the sum alone.
+divides by the sum alone. PR 55 took the thirteen entries whose models run
+the flash kernels anew (every family but `smallthinker`, whose two stay byte
+for byte the parent's): the backward pass of `ops/flash_attention.py` is one
+`pallas_call` where it was two, in each of those steps (700 to 4,000 lines
+fewer of the interpreted kernels' text an entry; nothing else of a step
+differs).
 
 The text is JAX's StableHLO without locations, so it does not depend on
 where the checkout lies; it does depend on the JAX version (0.9.0)."""

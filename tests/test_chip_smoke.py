@@ -63,10 +63,13 @@ def test_phase_flash_kernel(smoke, capsys):
     chip_smoke.flash_kernel(smoke, shape=(1, 2, 64, 32),
                             wide=(1, 2, 64, 48, 32))
     out = capsys.readouterr().out
-    # both shapes, each with its three kernels alone
+    # both shapes, each with its forward and its one backward kernel alone
     assert "shape (1, 2, 64, 32) bf16" in out
     assert "shape (1, 2, 64, 48, 32) bf16" in out
-    assert out.count(", dk/dv ") == out.count(": forward ") == 2
+    assert out.count(", backward ") == out.count(": forward ") == 2
+    # the backward pass makes q.k, exp and do.v once a block pair
+    assert out.count("backward_products 5 (score-sized products a block "
+                     "pair of the backward pass: 5 in one kernel, ") == 2
     # off the TPU the kernel is interpreted, and the phase says so
     assert "interpret=True, 0 tpu_custom_call" in out
     # S=64 is one block no tile divides: all of it computed, half of it used

@@ -93,6 +93,25 @@ def test_the_grid_steps_are_the_block_pairs_the_mask_holds_each_once(
     assert steps == sorted((j, g, i) for i, j in held for g in range(group))
     assert {j for j, _, _ in steps} == set(range(nk))  # every dk, dv block
 
+    # the fused backward: the same pairs by key block, a query head's whole
+    # walk before the group's next head's; dk and dv zeroed at a key block's
+    # first step of the first head and written at its last of the last, dq
+    # at the first and last step of each head's walk
+    fused = _Walk(_by_key_block(rows, nk), heads=group, head_major=True)
+    steps, ends = walked(fused)
+    assert steps == sorted(((j, g, i) for i, j in held for g in range(group)),
+                           key=lambda step: (step[1], step[0], step[2]))
+    head_ends = jax.vmap(fused.head_ends)(
+        jnp.arange(fused.steps, dtype=jnp.int32))
+    head_first, head_last = (np.asarray(x).tolist() for x in head_ends)
+    for s, (j, g, _) in enumerate(steps):
+        first, last = ends[s]
+        assert first == (g == 0 and (s == 0 or steps[s - 1][0] != j))
+        assert last == (g == group - 1 and (
+            s == len(steps) - 1 or steps[s + 1][0] != j))
+        assert head_first[s] == (s == 0 or steps[s - 1][1] != g)
+        assert head_last[s] == (s == len(steps) - 1 or steps[s + 1][1] != g)
+
     if causal and block_q == block_k:
         assert grid_step_share(S, window, block_q) == 1.0
 
@@ -261,9 +280,12 @@ def test_whole_strips_cover_a_block_exactly_once():
     assert len(_whole_strips(1024, 1024, 512)) == 2
 
 
-def test_only_the_forward_kernel_walks_whole_blocks_in_strips(monkeypatch):
+@pytest.mark.parametrize("form", ["fused", "split"])
+def test_only_the_forward_kernel_walks_whole_blocks_in_strips(monkeypatch,
+                                                              form):
     """The backward kernels take a block under the diagonal as one strip, as
-    before PR 49: their steps are counted here by the walk they share."""
+    before PR 49: their steps are counted here by the walk they share. The
+    fused backward kernel walks once where dk/dv and dq walked once each."""
     from horovod_tpu.ops import flash_attention as fa
     calls = []
     walk = fa._walk_strips
@@ -273,10 +295,13 @@ def test_only_the_forward_kernel_walks_whole_blocks_in_strips(monkeypatch):
         return walk(run, **kw)
 
     monkeypatch.setattr(fa, "_walk_strips", counting)
+    if form == "split":
+        monkeypatch.setattr(fa, "_FUSED_VMEM_LIMIT", 0)
     q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=1, S=1024, dh=8)
     jax.grad(lambda q: jnp.sum(fa.flash_attention(
         q, k, v, causal=True, block_q=512, block_k=512)))(q)
-    assert calls.count(None) == 2               # dk/dv and dq
+    # the one backward kernel, or dk/dv and dq
+    assert calls.count(None) == {"fused": 1, "split": 2}[form]
     assert [w for w in calls if w] == [[(0, 256, 512), (256, 256, 512)]]
 
 

@@ -492,3 +492,66 @@ def test_ssd_scan_compiles_for_v5e(monkeypatch, name):
     assert mosaic_signatures(txt) == want
     assert " while(" not in txt
     assert scopes.grouped_kernels(hlo.index(txt)) == {}
+
+
+def test_the_fused_flash_backwards_signature_is_no_readers(monkeypatch):
+    """The flash backward as one kernel (PR 55) takes q, k, v, o, do, lse
+    (and the lse cotangent of a ring hop's chunk) and returns dq, dk, dv:
+    (6, 3) (`test_flash_tpu_aot.SIGNATURES`), or (7, 3), compiled here. Neither is a signature a reader of the benchmark
+    tells a kernel by today (`flash_roofline.SIGNATURES`, the grouped
+    matmul's, its metadata's) nor a row mover's, so no reader takes it for
+    another kernel and `flash_roofline.read` gives None where it meets it.
+    (6, 3) IS the state-space dual scan's forward-with-residuals kernel's
+    (`SSD_SIGNATURES`, `granite4h-1chip`): a reader that learns it must tell
+    flash's by its first result's rows and width too (PERF.md §7)."""
+    from horovod_tpu.ops.flash_attention import flash_attention_chunk
+    from test_flash_tpu_aot import LM_CELLS, SIGNATURES
+    from tpu_probe import (compile_kernel_text, mosaic_signatures,
+                           tpu_topology)
+
+    topo = tpu_topology(monkeypatch)
+    q = jax.ShapeDtypeStruct(LM_CELLS, jnp.bfloat16)
+
+    def chunk_bwd(q, k, v):
+        def loss(q, k, v):
+            o, lse = flash_attention_chunk(q, k, v, causal=True)
+            return o.astype(jnp.float32).sum() + lse.sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    txt = compile_kernel_text(topo, chunk_bwd, (q, q, q), n_calls=2)
+    assert mosaic_signatures(txt) == [(3, 2), (7, 3)]
+    for backward in ((6, 3), (7, 3)):
+        assert backward not in _readers_signatures()
+        assert backward not in ROW_SIGNATURES + [GROUPED_SIGNATURE]
+    assert SIGNATURES["bwd"] == [(3, 2), (6, 3)]
+    assert (6, 3) in SSD_SIGNATURES["bwd"]
+
+
+@pytest.mark.parametrize("name, reduces", [("tiny-lm-1chip", False),
+                                           ("tiny-lm-dp4", True)])
+def test_the_tiny_lm_cells_steps_hold_three_flash_kernels(monkeypatch, name,
+                                                          reduces):
+    """`tests/benchmark/test_benchmark_aot.py`'s two LM cases, line for line
+    (that file is the benchmark's and holds the step to the FOUR kernels it
+    had until PR 55; `tests/conftest.py` expects those two to fail): each
+    path's `abstract_step` of the tests' tiny cells compiles for the
+    described v5e without a problem, with three Mosaic kernels (the flash
+    forward, its remat repeat and the one backward kernel), an all-reduce
+    across the four chips and none on one."""
+    import os
+
+    from benchmark import aot_check
+    from benchmark.harness import peaks, spec
+    from tpu_probe import _no_persistent_cache, tpu_topology
+
+    topo = tpu_topology(monkeypatch)
+    tiny = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "fixtures", "tiny")
+    cell = spec.load_cell(name, root=tiny)
+    hbm = peaks.for_kind(aot_check.DEVICE_KIND).hbm_bytes
+    with jax.enable_x64(False), _no_persistent_cache():
+        found, problems = aot_check.check_cell(cell, topo.devices, hbm)
+    assert problems == [], found
+    assert "3 tpu_custom_call" in found
+    assert ("all-reduces 0 bytes" not in found) == reduces
+    assert f"({cell.chips} chip(s))" in found

@@ -184,4 +184,7 @@ def test_no_scatter_under_the_lookup_in_the_compiled_step(tied, monkeypatch):
                         lambda table, ids: (table[ids], table))
     indexed, kernels_indexed = scatters_and_kernels()
     assert indexed and not scatters, scatters
-    assert kernels == kernels_indexed > 0
+    # three a flash layer, one loop body for the two layers: the forward,
+    # its remat repeat and the one backward kernel (dq and dk/dv: four,
+    # until PR 55)
+    assert kernels == kernels_indexed == 3
