@@ -16,6 +16,7 @@ builds anew and leaves nothing behind.
 No configuration lives here: each family's file keeps its `CFG`, its data
 shapes and its tolerances."""
 
+import contextlib
 import functools
 
 import jax
@@ -62,20 +63,32 @@ def train_step(cfg, opt, metrics=False, **sizes):
     return tfm.build_train_step(cfg, mesh_of(**sizes), opt, metrics=metrics)
 
 
-def train(cfg, opt, params, batch, steps, metrics=False):
-    """What each of `steps` train steps on the one `batch` returns behind
-    its state: (loss,), and with `metrics` (loss, counts). The step donates
-    its state, so it starts from copies, not from the caller's arrays."""
-    mesh = mesh_of()
-    placed = tfm.shard_params(jax.tree_util.tree_map(jnp.copy, params), cfg,
-                              mesh)
-    state, results = (placed, tfm.init_opt_state(opt, placed, mesh)), []
-    step = train_step(cfg, opt, metrics)
-    for _ in range(steps):
-        stepped = step(*state, *batch)
-        state = stepped[:2]
-        results.append(stepped[2:])
-    return results
+@contextlib.contextmanager
+def counted_builds():
+    """`tfm.build_loss_and_grads` wrapped for the block, with the memo of
+    `loss_and_grads` emptied before and after it (nothing of the wrapper
+    stays): (built, traced), each the (configuration, `dp`) of a program in
+    the order it was built or traced."""
+    built, traced = [], []
+    build = tfm.build_loss_and_grads
+
+    def counting(cfg, mesh, **options):
+        key = (cfg, mesh.shape["dp"])
+        built.append(key)
+        program = build(cfg, mesh, **options)
+
+        def traced_once(*args):
+            traced.append(key)
+            return program(*args)
+        return traced_once
+
+    tfm.build_loss_and_grads = counting
+    loss_and_grads.cache_clear()
+    try:
+        yield built, traced
+    finally:
+        tfm.build_loss_and_grads = build
+        loss_and_grads.cache_clear()
 
 
 def data(vocab, batch, seq):
@@ -116,8 +129,10 @@ def leaves(tree):
 
 
 def leaf_names(cfg):
-    """The paths of `tfm.init`'s tree, sorted, without making it."""
-    return sorted(leaves(shapes(cfg)))
+    """The paths of `tfm.init`'s tree, sorted, as `models/leaves.py`
+    declares them for `cfg`: nothing made and nothing traced (a module asks
+    when it is collected, in every worker)."""
+    return sorted(leaves(tfm.param_specs(cfg)))
 
 
 def assert_trees_close(got, want, rtol, atol=0.0, scaled=0.0):

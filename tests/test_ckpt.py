@@ -546,7 +546,7 @@ def test_stall_watchdog_rearms_while_peer_restores(monkeypatch):
     monkeypatch.setattr(resume, "peer_restore_active",
                         lambda kv=None: restoring["on"])
     wd = collectives.StallWatchdog(PyStallInspector(10.0, 0.0),
-                                   warn_sec=0.05, shutdown_sec=0.15,
+                                   warn_sec=0.05, shutdown_sec=0.6,
                                    poll_interval=0.01)
     release = threading.Event()
 
@@ -555,9 +555,13 @@ def test_stall_watchdog_rearms_while_peer_restores(monkeypatch):
         return "done"
 
     # stop "restoring" well past the bare shutdown window, then let
-    # the wait finish inside the re-armed window: no raise.
-    threading.Timer(0.5, lambda: restoring.update(on=False)).start()
-    threading.Timer(0.6, release.set).start()
+    # the wait finish inside the re-armed window: no raise. (The deadline
+    # re-arms at 0.6 and 1.2 s, so it stands at 1.8 s when the signal
+    # clears at 1.3 and the wait ends at 1.4, 0.4 s before it. With a
+    # window of 0.15 s, the signal cleared at 0.5 s and the end at 0.6 s,
+    # the last deadline WAS 0.6 s, and a loaded run failed now and then.)
+    threading.Timer(1.3, lambda: restoring.update(on=False)).start()
+    threading.Timer(1.4, release.set).start()
     assert wd.guard("resume_bcast", blocked) == "done"
 
     # without the signal the same wait raises within the window

@@ -2,7 +2,10 @@
 keys wider than values, YaRN, a leading dense gated MLP, shared experts and a
 share of the routed experts with a per-sequence balance loss) against the
 plain reference `benchmark/reference/deepseek_v2.py`, at a small size in
-float32; the family's limits; and what is refused. (The share of
+float32: the family's statement for `tests/family_cases.py` (`FAMILY`) and of
+the shared cases the loss and every leaf's gradient of one rank as the cell
+runs it (under remat) and `dp` = 2 without remat against it; `dp` = 2 x `tp`
+= 2; the family's limits; and what is refused. (The share of
 `parallel/moe.py` and its row buffer: `tests/test_deepseek_v2_share.py`; the
 flash kernels at unequal widths: `tests/test_flash_attention.py`.) Every
 program of the model is `tests/family.py`'s, built once for the module."""
@@ -20,6 +23,11 @@ from jax.sharding import PartitionSpec as P
 import family as programs
 from benchmark.families import deepseek_v2 as family
 from benchmark.reference import deepseek_v2 as reference
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, ours, params, pytest_generate_tests, stated, theirs,
+    test_dp_2_without_remat_equals_one_rank_under_remat,
+    test_every_leafs_gradient_equals_the_references,
+    test_loss_equals_the_references)
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import mixers, transformer as tfm
 from family import mesh_of
@@ -41,22 +49,18 @@ CFG = tfm.TransformerConfig(
 WHOLE = dataclasses.replace(CFG, experts_held=0, first_expert=0)
 
 
+#: the cell's remat (the program's default policy); the gradients to a
+#: leaf's largest entry, as they were held before the shared cases. (The
+#: older refusals of this file stand below, a test each.)
+FAMILY = Family(
+    cfg=CFG, timed=dataclasses.replace(CFG, remat=True), family=family,
+    reference=reference, weights=(), args=(TOP_K,),
+    kwargs={"first_expert": FIRST}, data=(4, 32), refused=(), leaf_rtol=0,
+    leaf_atol=3e-5)
+
+
 def _data(batch=4, seq=32, vocab=CFG.vocab):
     return programs.data(vocab, batch, seq)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return programs.init(CFG)
-
-
-@pytest.fixture(scope="module")
-def theirs(params):
-    """(loss, gradients) of the reference, in the program's tree."""
-    tokens, targets = _data()
-    return jax.value_and_grad(lambda p: reference.loss(
-        family.reference_weights(p), tokens, targets, TOP_K,
-        first_expert=FIRST))(params)
 
 
 # -------------------------------------------------------------- the block
@@ -103,7 +107,7 @@ def test_a_configuration_without_the_new_fields_keeps_its_leaves():
     assert "dense_layers" not in gpt
 
 
-def test_logits_and_loss_match_the_reference(params):
+def test_logits_and_loss_match_the_reference(params, ours):
     tokens, targets = _data()
     logits = programs.forward(CFG)(params, tokens)
     weights = family.reference_weights(params)
@@ -111,22 +115,21 @@ def test_logits_and_loss_match_the_reference(params):
     assert logits.shape == (4, 32, CFG.vocab)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
-    loss, _ = programs.loss_and_grads(CFG)(params, tokens, targets)
     want_loss = reference.loss(weights, tokens, targets, TOP_K,
                                first_expert=FIRST)
-    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    np.testing.assert_allclose(float(ours[0]), float(want_loss), rtol=1e-5)
     # the balance term is in it, per sequence
     bare = reference.next_token_loss(want, targets)
     assert float(want_loss) - float(bare) > 1e-4
 
 
-@pytest.mark.parametrize("sizes", [{}, {"dp": 2}, {"dp": 2, "tp": 2}],
-                         ids=["one-rank", "dp2", "dp2-tp2"])
+@pytest.mark.parametrize("sizes", [{"dp": 2, "tp": 2}], ids=["dp2-tp2"])
 def test_every_gradient_leaf_matches_the_reference(params, theirs, sizes):
     """`build_loss_and_grads` against `jax.grad` of the reference's loss, the
     balance term included: it is each sequence's own, so a data-parallel
     mesh changes nothing. On a mesh that reduces, both stacks' gradients go
-    through `grad_reduce.scattered_in_backward`."""
+    through `grad_reduce.scattered_in_backward`; here with the heads cut
+    over `tp` as well. (One rank and `dp` = 2 alone: the shared cases.)"""
     tokens, targets = _data()
     mesh = mesh_of(**sizes)
     tfm.validate_cfg_for_mesh(CFG, mesh)
@@ -138,14 +141,6 @@ def test_every_gradient_leaf_matches_the_reference(params, theirs, sizes):
     assert all(float(jnp.max(jnp.abs(ref))) > 0
                for ref in jax.tree_util.tree_leaves(want))
     programs.assert_trees_close(grads, want, rtol=0, scaled=3e-5)
-
-
-def test_remat_changes_no_gradient(params):
-    tokens, targets = _data()
-    plain = programs.loss_and_grads(CFG)(params, tokens, targets)
-    remat = programs.loss_and_grads(dataclasses.replace(CFG, remat=True))(
-        params, tokens, targets)
-    programs.assert_trees_close(remat, plain, rtol=1e-5, atol=1e-6)
 
 
 def test_attn_local_takes_the_plain_path_and_agrees_with_flash(params):
@@ -185,12 +180,11 @@ def test_a_pipeline_with_leading_dense_layers_is_refused():
 
 
 def test_microbatches_without_stages_run_the_prefix_on_the_whole_batch(
-        params):
+        params, ours):
     tokens, targets = _data()
-    one, _ = programs.loss_and_grads(CFG)(params, tokens, targets)
     two, _ = programs.loss_and_grads(dataclasses.replace(
         CFG, microbatches=2))(params, tokens, targets)
-    np.testing.assert_allclose(float(two), float(one), rtol=1e-5)
+    np.testing.assert_allclose(float(two), float(ours[0]), rtol=1e-5)
 
 
 # ------------------------------------------------------------------- YaRN

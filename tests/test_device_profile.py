@@ -5,12 +5,17 @@ scripts/trace_resnet.py."""
 
 import pytest
 
-pytest.importorskip("tensorflow")
+from horovod_tpu.profiler.device_profile import aggregate_xspace, classify
 
-from tensorflow.tsl.profiler.protobuf import xplane_pb2  # noqa: E402
 
-from horovod_tpu.profiler.device_profile import (  # noqa: E402
-    aggregate_xspace, classify)
+@pytest.fixture(scope="module", autouse=True)
+def _xplane():
+    """TensorFlow's xplane protocol as this module's global, imported when
+    the first test here runs and not when the file is collected: every
+    worker collects every file, one runs this one. Without TensorFlow the
+    file's tests are skipped (`tests/test_device_profile_no_tf.py` runs)."""
+    globals()["xplane_pb2"] = pytest.importorskip(
+        "tensorflow.tsl.profiler.protobuf.xplane_pb2")
 
 
 def _make_xspace():

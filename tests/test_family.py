@@ -15,43 +15,27 @@ CFG = tfm.TransformerConfig(vocab=32, d_model=16, n_heads=2, d_ff=32,
                             dtype=jnp.float32)
 
 
-def test_equal_configurations_share_a_program_traced_once(monkeypatch):
-    built, traced = [], []
-    build = tfm.build_loss_and_grads
-
-    def counting(cfg, mesh, **options):
-        built.append(cfg)
-        program = build(cfg, mesh, **options)
-
-        def traced_once(*args):
-            traced.append(cfg)
-            return program(*args)
-        return traced_once
-
-    monkeypatch.setattr(tfm, "build_loss_and_grads", counting)
-    family.loss_and_grads.cache_clear()
-    try:
+def test_equal_configurations_share_a_program_traced_once():
+    with family.counted_builds() as (built, traced):
         equal = dataclasses.replace(CFG)
         assert equal == CFG and equal is not CFG
         one, two = family.loss_and_grads(CFG), family.loss_and_grads(equal)
-        assert one is two and built == [CFG]
+        assert one is two and built == [(CFG, 1)]
         params = family.init(CFG)
         tokens = jnp.zeros((2, 8), jnp.int32)
         first, _ = one(params, tokens, tokens)
         again, _ = two(params, tokens, tokens)
-        assert traced == [CFG] and float(first) == float(again)
+        assert traced == [(CFG, 1)] and float(first) == float(again)
         # one field apart, on the same mesh: a program of its own
         other = dataclasses.replace(CFG, d_ff=48)
         assert family.loss_and_grads(other) is not one
-        assert built == [CFG, other]
+        assert built == [(CFG, 1), (other, 1)]
         # and another mesh of the same configuration is another program
         assert family.loss_and_grads(CFG, dp=2) is not one
-        assert built == [CFG, other, CFG]
+        assert built == [(CFG, 1), (other, 1), (CFG, 2)]
         # past the memo, for a test that swaps a part of the model out
         assert family.loss_and_grads.__wrapped__(CFG) is not one
         assert family.loss_and_grads(CFG) is one
-    finally:
-        family.loss_and_grads.cache_clear()   # nothing of `counting` stays
 
 
 def test_init_is_the_eager_tree_as_one_program():

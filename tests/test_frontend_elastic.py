@@ -4,7 +4,14 @@ TorchState + sampler.py ElasticSampler; tensorflow/elastic.py)."""
 import numpy as np
 import pytest
 
-torch = pytest.importorskip("torch")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _torch():
+    """`torch` as this module's global, imported when the first test here
+    runs and not when the file is collected (`tests/test_torch_frontend.py`
+    says why). Without it the file's tests are skipped."""
+    globals()["torch"] = pytest.importorskip("torch")
 
 
 def test_torch_state_commit_restore(hvd):

@@ -4,13 +4,14 @@ and value heads than query heads, every layer followed by SiLU-gated experts
 with a renormalised top-k, of which a share is held, beside a shared MLP, a
 tied head, and the four scalar multipliers) against the plain reference
 `benchmark/reference/granite_hybrid.py`, at a small size in float32: the
-mixer alone, logits, loss and every leaf's gradient, `attn` "local" and
-"flash"; every planted fault refused by the family's limits; the shares of
-the experts adding up to the uncut layer with the shared MLP counted once,
-and the held heads' scan output the matching slice of the whole mixer's;
-the multipliers' defaults; and what `validate_cfg_for_mesh` refuses. (Loss,
-gradients, `dp` = 2, the train step and remat:
-`tests/test_granite_hybrid_grads.py`; the scopes of the compiled step:
+family's statement for `tests/family_cases.py` (`FAMILY`) and of the shared
+cases the logits, `attn` "local" and "flash", every planted fault refused by
+the family's limits and what `validate_cfg_for_mesh` refuses; the mixer
+alone; the shares of the experts adding up to the uncut layer with the
+shared MLP counted once, and the held heads' scan output the matching slice
+of the whole mixer's; the multipliers' defaults. (Loss and gradients as the
+cell runs them and `dp` = 2: `tests/test_granite_hybrid_grads.py`; the
+compiled step, its scopes and three steps of it:
 `tests/test_step_scopes.py`.) Every program is `tests/family.py`'s, built
 once for the module."""
 
@@ -25,7 +26,15 @@ from jax.sharding import PartitionSpec as P
 import family as programs
 from benchmark.families import granite_hybrid as family
 from benchmark.reference import granite_hybrid as reference
-from horovod_tpu.common.exceptions import HorovodTpuError
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, lively, logits, params, pytest_generate_tests, sound, stated,
+    their_logits, test_an_unknown_fault_is_refused,
+    test_logits_equal_the_references,
+    test_the_familys_comparison_reads_zero_for_the_reference,
+    test_the_limits_refuse_a_planted_fault,
+    test_the_limits_refuse_an_8_bit_float,
+    test_validate_accepts_the_model_where_it_runs,
+    test_validate_refuses_by_name)
 from horovod_tpu.models import mixers, transformer as tfm
 from horovod_tpu.ops import ssd_scan as ssd
 from family import mesh_of
@@ -50,61 +59,35 @@ CFG = tfm.TransformerConfig(
     logit_scale=1.0 / reference.LOGITS_SCALING, attn="local",
     dtype=jnp.float32)
 SEQ = 32
-ATTNS = ("local", "flash")
 
-
-def _data(batch=2, seq=SEQ):
-    return programs.data(CFG.vocab, batch, seq)
-
-
-#: what `_lively` multiplies the drawn leaves by
-_LOUDER = {"wq": 16.0, "wo": 4.0, "we2": 4.0, "ssd_w_out": 2.0}
-
-
-def _lively(params):
-    """`init`'s tree with the leaves it draws as ones moved (the norms'
-    scales, D), and the parts whose faults are planted made loud enough to
-    show at this size: scores of order one under the 1/128 multiplier, the
-    attention layer's, the routed experts' and the scans' outputs a larger
-    share of the residual stream."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(11), 64))
-
-    def moved(path, leaf):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("_scale']", "ssd_d_skip']")):
-            return leaf * (1 + 0.3 * jax.random.normal(next(keys),
-                                                       leaf.shape))
-        return leaf * _LOUDER.get(path[-1].key, 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    with jax.enable_x64(False):
-        return _lively(programs.init(CFG))
-
-
-@pytest.fixture(scope="module")
-def logits(params):
-    """The program's logits for `_data()`'s tokens, once."""
-    with jax.enable_x64(False):
-        return programs.forward(CFG)(params, _data()[0])
-
-
-@pytest.fixture(scope="module")
-def their_logits(params):
-    with jax.enable_x64(False):
-        return reference.forward(family.reference_weights(params, KINDS),
-                                 _data()[0], KINDS, TOP_K, FIRST)
-
-
-@pytest.fixture(scope="module")
-def sound(params, logits):
-    """The family's comparison of `logits` with the sound reference."""
-    with jax.enable_x64(False):
-        return family.compare(params, _data()[0], logits, KINDS, TOP_K,
-                              FIRST)
+#: `init`'s tree with the norms' scales and D moved off one, and scores of
+#: order one under the 1/128 multiplier, the attention layer's, the routed
+#: experts' and the scans' outputs a larger share of the residual stream
+_lively = lively({"wq": 16.0, "wo": 4.0, "we2": 4.0, "ssd_w_out": 2.0},
+                 moved=("_scale", "ssd_d_skip"), keys=64)
+#: what `validate_cfg_for_mesh` refuses: (mesh, changed fields, its words)
+REFUSED = (
+    (dict(sp=2), {"attn": "local"},
+     "state-space dual layers require sp=1"),
+    (dict(tp=2), {}, "state-space dual layers require tp=1"),
+    (dict(pp=2), {"microbatches": 2},
+     "state-space dual layers require pp=1"),
+    ({}, {"ssd_heads": 0}, "'mamba2' layers need ssd_heads > 0"),
+    ({}, {"attn": "ring"}, "cannot run them|needs attn"),
+    ({}, {"layer_pattern": KINDS[:3] + ("mamba",)}, "names the kind 'mamba'"),
+    ({}, {"n_layers": 6}, "no whole number of periods"),
+)
+#: the cell's algorithm and remat policy; (among the leaves: `ssd_a_log` and
+#: `ssd_dt_bias`, whose gradients come through the running sums of the
+#: chunked form, the two leaves of the input projection, and the tied
+#: embedding's, read twice)
+FAMILY = Family(
+    cfg=CFG, family=family, reference=reference,
+    timed=dataclasses.replace(CFG, attn="flash", remat=True,
+                              remat_policy="dots"),
+    weights=(KINDS,), args=(KINDS, TOP_K, FIRST), data=(2, SEQ),
+    refused=REFUSED, lively=_lively,
+    accepted=(({"attn": "flash"}, {"dp": 4}),))
 
 
 def test_the_tree_has_each_kinds_leaves_and_no_others(params):
@@ -165,66 +148,6 @@ def test_the_mixer_alone_equals_the_references(params):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
 
 
-@pytest.mark.parametrize("attn", ATTNS)
-def test_logits_equal_the_references(params, their_logits, attn):
-    with jax.enable_x64(False):
-        got = programs.forward(dataclasses.replace(CFG, attn=attn))(
-            params, _data()[0])
-    np.testing.assert_allclose(got, their_logits, atol=2e-5, rtol=2e-4)
-
-
-# --------------------------------------------------------------- the limits
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, logits, sound, fault):
-    """The program's logits against the reference computed with one
-    mechanism wrong: by one of the family's limits it is not correct, and
-    against the sound reference it is, with room."""
-    tokens, _ = _data()
-    with jax.enable_x64(False):
-        wrong = family.compare(params, tokens, logits, KINDS, TOP_K, FIRST,
-                               fault=fault)
-    assert all(family.within(*(float(x) for x in sound[:3])))
-    assert float(sound[0]) < 1e-5
-    assert not all(family.within(*(float(x) for x in wrong[:3]))), \
-        [float(x) for x in wrong[:3]]
-    with pytest.raises(ValueError, match="choose from"):
-        reference.final_hidden(family.reference_weights(params, KINDS),
-                               tokens, KINDS, TOP_K, FIRST,
-                               fault="no_such_fault")
-
-
-@pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
-                         ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, logits, operands):
-    tokens, _ = _data()
-    with jax.enable_x64(False):
-        rms, got, want, _ = family.compare(
-            params, tokens, logits, KINDS, TOP_K, FIRST, operands=operands)
-    assert not all(family.within(float(rms), float(got), float(want)))
-
-
-def test_the_familys_comparison_reads_zero_for_the_reference(params):
-    """`family.compare` (the reference's head a block of tokens at a time)
-    against the reference's whole forward pass and its blockwise loss; its
-    count of the held experts' rows against the routes themselves."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        weights = family.reference_weights(params, KINDS)
-        logits = reference.forward(weights, tokens, KINDS, TOP_K, FIRST)
-        _, routes = reference.final_hidden(weights, tokens, KINDS, TOP_K,
-                                           FIRST)
-        rms, got, want, rows = family.compare(params, tokens, logits, KINDS,
-                                              TOP_K, FIRST)
-        loss = reference.loss(weights, tokens, targets, KINDS, TOP_K, FIRST)
-    assert float(rms) < 1e-6
-    np.testing.assert_allclose([float(got), float(want)], float(loss),
-                               rtol=1e-6)
-    assert rows.shape == (4, 2)
-    assert [int(np.sum(np.asarray(routes) == FIRST + e)) for e in (0, 1)] \
-        == [int(rows[:, e].sum()) for e in (0, 1)]
-
-
 def test_check_logits_knows_the_configuration_by_its_shapes(params, logits):
     """What `check_logits` cannot read off an array it takes from the
     configuration `transformer_config` was asked about."""
@@ -255,7 +178,7 @@ def test_check_logits_knows_the_configuration_by_its_shapes(params, logits):
     assert family.kinds(config) == KINDS == family.pattern(config)
     assert family.first_expert(config) == FIRST
     with jax.enable_x64(False):
-        found = family.check_logits(params, _data()[0], logits)
+        found = family.check_logits(params, FAMILY.batch[0], logits)
     assert found["ok"], found
     assert "rows of the 2 held experts" in found["detail"]
     with pytest.raises(ValueError, match="no equations for"):
@@ -405,7 +328,7 @@ def test_each_multiplier_is_in_the_program(params, their_logits, field,
                                            fault):
     """Without one multiplier the program's logits are the reference's with
     the matching fault (the embedding's has none: they just differ)."""
-    tokens, _ = _data()
+    tokens, _ = FAMILY.batch
     default = tfm.TransformerConfig.__dataclass_fields__[field].default
     cfg = dataclasses.replace(CFG, **{field: default})
     with jax.enable_x64(False):
@@ -416,32 +339,3 @@ def test_each_multiplier_is_in_the_program(params, their_logits, field,
                 family.reference_weights(params, KINDS), tokens, KINDS,
                 TOP_K, FIRST, fault=fault)
             np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-3)
-
-
-# -------------------------------------------------------------- refusals
-
-REFUSED = [
-    (dict(sp=2), {"attn": "local"},
-     "state-space dual layers require sp=1"),
-    (dict(tp=2), {}, "state-space dual layers require tp=1"),
-    (dict(pp=2), {"microbatches": 2},
-     "state-space dual layers require pp=1"),
-    ({}, {"ssd_heads": 0}, "'mamba2' layers need ssd_heads > 0"),
-    ({}, {"attn": "ring"}, "cannot run them|needs attn"),
-    ({}, {"layer_pattern": KINDS[:3] + ("mamba",)}, "names the kind 'mamba'"),
-    ({}, {"n_layers": 6}, "no whole number of periods"),
-]
-
-
-@pytest.mark.parametrize("mesh, changed, message", REFUSED)
-def test_validate_refuses_by_name(mesh, changed, message):
-    cfg = dataclasses.replace(CFG, **changed)
-    with pytest.raises(HorovodTpuError, match=message):
-        tfm.validate_cfg_for_mesh(cfg, mesh_of(**mesh))
-
-
-def test_validate_accepts_the_model_where_it_runs():
-    tfm.validate_cfg_for_mesh(CFG, mesh_of())
-    tfm.validate_cfg_for_mesh(CFG, mesh_of(dp=2))
-    tfm.validate_cfg_for_mesh(dataclasses.replace(CFG, attn="flash"),
-                              mesh_of(dp=4))

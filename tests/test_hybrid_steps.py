@@ -9,6 +9,7 @@ from step_cases import (  # noqa: F401  (the tests, cut to FAMILIES)
     test_the_lookup_leaves_the_step_one_scatter_fewer,
     test_the_lowered_step_is_the_parents,
     test_the_reduction_has_its_scope_where_something_is_reduced,
-    test_the_step_has_its_scopes_and_no_other)
+    test_the_step_has_its_scopes_and_no_other,
+    test_three_steps_lower_the_loss)
 
 FAMILIES = ("olmo_hybrid", "phi4_flash")

@@ -4,12 +4,15 @@ latent-attention layer without a rotation; a leading dense layer that is the
 pattern's first layer; sigmoid-scored experts with a selection bias, a
 renormalised and scaled top-k, of which a share is held, beside one shared
 expert; an untied head) against the plain reference
-`benchmark/reference/kimi_linear.py`, at a small size in float32: each mixer
-alone, logits, loss and every leaf's gradient, `attn` "local" and "flash";
-every planted fault refused by the family's limits;
-and the family's counts at the published widths. The stack, the share of the
-experts, the router's rule, `unrotated`, `dp` = 2 and the refusals:
-`tests/test_kimi_linear_stack.py`."""
+`benchmark/reference/kimi_linear.py`, at a small size in float32: the family's
+statement for `tests/family_cases.py` (`FAMILY`), each mixer alone, and of the
+shared cases the logits (`attn` "local"), every planted fault and an 8-bit
+float refused by the family's limits; the family's counts at the published
+widths; and the expert layer alone (the sixteen shares adding up to the uncut
+layer with the shared expert counted once, the router's sigmoid rule, the
+held experts' row buffer); "mla" under `unrotated`. The loss and every leaf's
+gradient (`attn` "flash" under remat "full", as the cell runs it), `dp` = 2,
+the stack and the refusals: `tests/test_kimi_linear_stack.py`."""
 
 import dataclasses
 
@@ -17,12 +20,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import family as programs
 from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear as reference
+from family import mesh_of
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, lively, logits, params, pytest_generate_tests, sound, stated,
+    their_logits, test_an_unknown_fault_is_refused,
+    test_logits_equal_the_references,
+    test_the_familys_comparison_reads_zero_for_the_reference,
+    test_the_limits_refuse_a_planted_fault,
+    test_the_limits_refuse_an_8_bit_float)
 from horovod_tpu.models import mixers, transformer as tfm
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.parallel import moe_ffn
 
 PATTERN = ("kda", "kda", "kda", "mla")
 KINDS = PATTERN + PATTERN[:1]       # five layers: the least the cell's rule leaves
@@ -40,10 +53,11 @@ CFG = tfm.TransformerConfig(
     mlp="swiglu", attention="kda", gdn_heads=2, gdn_key_dim=8,
     gdn_value_dim=8, gdn_conv=4, kda_rank=8, kv_latent=16, qk_nope_dim=8,
     qk_rope_dim=8, v_head_dim=8, attn="local", dtype=jnp.float32)
+#: the cell's algorithm and remat: the loss and the gradients go through it
+#: (and without remat `dp` = 2); the logits through `CFG`'s own
+TIMED = dataclasses.replace(CFG, attn="flash", remat=True,
+                            remat_policy="full")
 SEQ = 32
-#: the loss and the gradients go through the cell's algorithm; the logits
-#: (`system_logits`) through the other
-ATTNS = ("flash",)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -59,66 +73,38 @@ def chunks_of_eight():
     gated_delta.gated_delta_rule = real
 
 
-def _data(batch=2, seq=SEQ):
-    return programs.data(CFG.vocab, batch, seq)
-
-
-#: what `_lively` multiplies the drawn leaves by
-_LOUDER = {"wq": 6.0, "wkv_b": 2.0, "wo": 3.0, "we2": 3.0, "router": 4.0,
-           "kda_wf_up": 4.0, "kda_wg_up": 4.0, "w2": 2.0}
-
-
-@jax.jit
-def _lively(params):
-    """`init`'s tree with the leaves it draws as ones or zeros moved (the
-    norms' scales, the selection bias, the gate's bias), and the parts whose
-    faults are planted made loud enough to show at this size: attention
-    scores of order one, a decay that differs from channel to channel, a
-    gate away from its middle, router scores away from one half."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(11), 128))
-
-    def moved(path, leaf):
-        name = path[-1].key
-        if name.endswith("_scale"):
-            return leaf * (1 + 0.3 * jax.random.normal(next(keys),
-                                                       leaf.shape))
-        if name in ("router_bias", "kda_bg"):
-            return leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)
-        return leaf * _LOUDER.get(name, 1.0)
-
-    with jax.enable_x64(False):
-        return jax.tree_util.tree_map_with_path(moved, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return _lively(programs.init(CFG))
-
-
-@pytest.fixture(scope="module", params=ATTNS)
-def ours(request, params):
-    """(loss, gradients) of the program on one rank, by each algorithm."""
-    tokens, targets = _data()
-    cfg = dataclasses.replace(CFG, attn=request.param)
-    with jax.enable_x64(False):
-        return programs.loss_and_grads(cfg)(params, tokens, targets)
-
-
-@pytest.fixture(scope="module")
-def system_logits(params):
-    """The program's logits for `_data()`'s tokens, once."""
-    with jax.enable_x64(False):
-        return programs.forward(CFG)(params, _data()[0])
-
-
-@pytest.fixture(scope="module")
-def theirs(params):
-    """(loss, gradients) of the reference, in the program's tree."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        return jax.value_and_grad(lambda p: reference.loss(
-            family.reference_weights(p, KINDS), tokens, targets, KINDS,
-            TOP_K, FIRST))(params)
+#: `init`'s tree with the norms' scales moved off one, the selection bias
+#: and the gate's bias off zero, and attention scores of order one, a decay
+#: that differs from channel to channel, a gate away from its middle, router
+#: scores away from one half
+_lively = lively({"wq": 6.0, "wkv_b": 2.0, "wo": 3.0, "we2": 3.0,
+                  "router": 4.0, "kda_wf_up": 4.0, "kda_wg_up": 4.0,
+                  "w2": 2.0}, shifted=("router_bias", "kda_bg"))
+#: what `validate_cfg_for_mesh` refuses: (mesh, changed fields, its words)
+REFUSED = (
+    ({"sp": 2}, {}, "linear-attention layers require sp=1"),
+    ({"tp": 2}, {}, "linear-attention layers require tp=1"),
+    ({"pp": 2}, {"microbatches": 2}, "a layer pattern requires pp=1"),
+    ({"ep": 2}, {}, "ep > 1 with experts_held < num_experts"),
+    ({}, {"kda_rank": 0}, "'kda' layers need kda_rank > 0"),
+    ({}, {"router_scoring": "tanh"}, "router_scoring='tanh'"),
+    ({}, {"positions": "learned"}, "attention='mla' with positions="
+     "'learned'"),
+    ({}, {"attn": "ring"}, "attention='mla' needs attn 'flash' or 'local'"),
+    ({}, {"first_k_dense": 4, "n_layers": 8}, "pattern's first layers"),
+    ({}, {"unrotated": ("mla",)}, "positions='rope'"),
+)
+#: (among the leaves: `kda_a_log`, `kda_dt_bias` and the decay's two
+#: matrices, whose gradients come through the running sums of the chunked
+#: form and the decayed products, and the selection bias, which takes none on
+#: either side: it chooses and never weighs)
+FAMILY = Family(
+    cfg=CFG, timed=TIMED, family=family, reference=reference,
+    weights=(KINDS,), args=(KINDS, TOP_K, FIRST), data=(2, SEQ),
+    refused=REFUSED, lively=_lively, attns=("local",),
+    logits_tol=(5e-3, 5e-3), sound_below=2e-4, leaf_atol=3e-4,
+    no_gradient=("router_bias",), two_ranks_loss=1e-5,
+    two_ranks={"rtol": 2e-3, "atol": 1e-8, "scaled": 2e-4})
 
 
 # ------------------------------------------------------------------ the tree
@@ -191,90 +177,6 @@ def test_a_mixer_alone_equals_the_references(params, kind, at):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
 
 
-def test_logits_equal_the_references(params, system_logits):
-    """(`attn` "local"; "flash" is held by the loss and the gradients.)"""
-    with jax.enable_x64(False):
-        want = reference.forward(family.reference_weights(params, KINDS),
-                                 _data()[0], KINDS, TOP_K, FIRST)
-    np.testing.assert_allclose(system_logits, want, atol=5e-3, rtol=5e-3)
-
-
-def test_loss_equals_the_references(ours, theirs):
-    np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
-
-
-@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
-def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
-    """Among them `kda_a_log`, `kda_dt_bias` and the decay's two matrices,
-    whose gradients come through the running sums of the chunked form and
-    the decayed products, and the selection bias, which takes none on either
-    side: it chooses and never weighs."""
-    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
-    size = float(jnp.max(jnp.abs(want)))
-    if "router_bias" in leaf:
-        assert size == 0.0 == float(jnp.max(jnp.abs(got)))
-        return
-    assert size > 1e-7, "nothing to compare"
-    np.testing.assert_allclose(got, want, rtol=2e-3,
-                               atol=3e-4 * size + 1e-8)
-
-
-# --------------------------------------------------------------- the limits
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, system_logits, fault):
-    """The program's logits against the reference computed with one
-    mechanism wrong: by one of the family's limits it is not correct, and
-    against the sound reference it is, with room."""
-    tokens, logits = _data()[0], system_logits
-    with jax.enable_x64(False):
-        sound = family.compare(params, tokens, logits, KINDS, TOP_K, FIRST)
-        wrong = family.compare(params, tokens, logits, KINDS, TOP_K, FIRST,
-                               fault=fault)
-    assert all(family.within(*(float(x) for x in sound[:3])))
-    assert float(sound[0]) < 2e-4
-    assert not all(family.within(*(float(x) for x in wrong[:3]))), \
-        [float(x) for x in wrong[:3]]
-    with pytest.raises(ValueError, match="choose from"):
-        reference.final_hidden(family.reference_weights(params, KINDS),
-                               tokens, KINDS, TOP_K, FIRST,
-                               fault="no_such_fault")
-
-
-@pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
-                         ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, system_logits, operands):
-    with jax.enable_x64(False):
-        rms, got, want, _ = family.compare(
-            params, _data()[0], system_logits, KINDS, TOP_K, FIRST,
-            operands=operands)
-    assert not all(family.within(float(rms), float(got), float(want)))
-
-
-def test_the_familys_comparison_reads_zero_for_the_reference(params):
-    """`family.compare` (the reference's head a block of tokens at a time)
-    against the reference's whole forward pass and its blockwise loss; its
-    count of the held experts' rows against the routes themselves, of the
-    four expert layers (the dense layer routes nothing)."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        weights = family.reference_weights(params, KINDS)
-        logits = reference.forward(weights, tokens, KINDS, TOP_K, FIRST)
-        _, routes = reference.final_hidden(weights, tokens, KINDS, TOP_K,
-                                           FIRST)
-        rms, got, want, rows = family.compare(params, tokens, logits, KINDS,
-                                              TOP_K, FIRST)
-        loss = reference.loss(weights, tokens, targets, KINDS, TOP_K, FIRST)
-    assert float(rms) < 1e-6
-    np.testing.assert_allclose([float(got), float(want)], float(loss),
-                               rtol=1e-6)
-    assert routes.shape == (4, 2, SEQ, TOP_K) and rows.shape == (4, 4)
-    assert [int(np.sum(np.asarray(routes) == FIRST + e)) for e in range(4)] \
-        == [int(rows[:, e].sum()) for e in range(4)]
-    assert "router" not in weights["layers"][0]
-    assert weights["layers"][0]["w_up"].shape == (64, 216)
-
-
 def _config():
     return {
         "vocab_size": 96, "hidden_size": 64, "num_attention_heads": 4,
@@ -302,8 +204,7 @@ def _config():
                     "router_z_coef": 0.0, "held_capacity": 2.0}}
 
 
-def test_check_logits_knows_the_configuration_by_its_shapes(params,
-                                                            system_logits):
+def test_check_logits_knows_the_configuration_by_its_shapes(params, logits):
     """What `check_logits` cannot read off an array it takes from the
     configuration `transformer_config` was asked about."""
     config = _config()
@@ -313,7 +214,7 @@ def test_check_logits_knows_the_configuration_by_its_shapes(params,
     assert family.pattern(config) == PATTERN
     assert family.first_expert(config) == FIRST
     with jax.enable_x64(False):
-        found = family.check_logits(params, _data()[0], system_logits)
+        found = family.check_logits(params, FAMILY.batch[0], logits)
     assert found["ok"], found
     assert "rows of the 4 held experts" in found["detail"]
     with pytest.raises(ValueError, match="no equations for"):
@@ -366,3 +267,152 @@ def test_the_familys_counts_at_the_published_widths():
     assert flops["kda_rule"] == layers * 2 * 3 * 32 * 128 * 128
     assert family.flops_per_sample(config, traffic) == 3 * sum(
         flops.values())
+
+
+# --------------------------------------------------------- NoPE by `unrotated`
+
+def test_mla_takes_no_rotation_where_unrotated_names_it():
+    """positions "rope" with both kinds named in `unrotated` is positions
+    "none"; with "mla" left out its layer is rotated and the logits move.
+    (Four layers: the dense one and the rest of its period.)"""
+    short = dataclasses.replace(CFG, n_layers=4)
+    tokens, _ = FAMILY.batch
+    p = _lively(programs.init(short))
+
+    def logits(**changes):
+        cfg = dataclasses.replace(short, **changes)
+        tfm.validate_cfg_for_mesh(cfg, mesh_of())
+        with jax.enable_x64(False):
+            return programs.forward(cfg)(p, tokens)
+
+    plain = logits(positions="rope", unrotated=("kda", "mla"))
+    np.testing.assert_allclose(plain, logits(), atol=1e-6)
+    rotated = logits(positions="rope", unrotated=("kda",))
+    assert float(jnp.max(jnp.abs(rotated - plain))) > 1e-2
+    assert tfm._kind_cfg(dataclasses.replace(
+        CFG, positions="rope", unrotated=("mla",)), "mla").positions == "none"
+
+
+# --------------------------------------------------------------- the share
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer():
+    """One expert of 16 on each of sixteen chips, each scoring all 16 with
+    the sigmoid, choosing on score + bias and renormalising over all four
+    chosen: the routed parts that `moe_ffn` gives, with the shared expert,
+    which every chip computes alike, counted ONCE, add up to what the
+    reference's layer gives with every expert held."""
+    d, f, tokens, n = 64, 24, 48, 16
+    ks = jax.random.split(jax.random.PRNGKey(7), 10)
+    rows = jax.random.normal(ks[1], (1, tokens, d), jnp.float32)
+    w = {"router": jax.random.normal(ks[2], (d, n), jnp.float32) / 4,
+         "bias": 0.4 * jax.random.normal(ks[8], (n,), jnp.float32),
+         "w_gate": jax.random.normal(ks[3], (n, d, f), jnp.float32) / 8,
+         "w_up": jax.random.normal(ks[4], (n, d, f), jnp.float32) / 8,
+         "w_down": jax.random.normal(ks[5], (n, f, d), jnp.float32) / 5,
+         "ws_gate": jax.random.normal(ks[6], (d, f), jnp.float32) / 8,
+         "ws_up": jax.random.normal(ks[7], (d, f), jnp.float32) / 8,
+         "ws_down": jax.random.normal(ks[0], (f, d), jnp.float32) / 5}
+
+    def share(first):
+        held = slice(first, first + 1)
+        return jax.jit(jax.shard_map(
+            lambda x, r, b, up, down, gate: moe_ffn(
+                x, r, up, down, gate, top_k=TOP_K, first_expert=first,
+                renormalise=True, scoring="sigmoid", selection_bias=b,
+                weight_scale=reference.ROUTED_SCALING_FACTOR)[:2],
+            mesh=mesh_of(), in_specs=P(), out_specs=P(), check_vma=False))(
+                rows[0], w["router"], w["bias"], w["w_up"][held],
+                w["w_down"][held], w["w_gate"][held])
+
+    with jax.enable_x64(False), jax.default_matmul_precision("highest"):
+        parts = [share(first) for first in range(n)]
+        shared = reference.gated_mlp(rows[0], w["ws_gate"], w["ws_up"],
+                                     w["ws_down"])
+        whole, routes = reference.moe(rows, w, TOP_K)
+        one, _ = reference.moe(rows, dict(w, **{
+            k: w[k][5:6] for k in ("w_gate", "w_up", "w_down")}), TOP_K,
+            first_expert=5)
+    assert all(float(aux[2]) == 0 for _, aux in parts)   # nothing left out
+    np.testing.assert_allclose(sum(out for out, _ in parts) + shared,
+                               whole[0], rtol=3e-5, atol=3e-5)
+    # a chip's own result holds the shared expert whole, as the reference's
+    np.testing.assert_allclose(parts[5][0] + shared, one[0], rtol=3e-5,
+                               atol=3e-5)
+    # the bias moved the choice: without it other experts are chosen
+    _, plain = reference.router_weights(
+        jnp.einsum("nd,de->ne", rows[0], w["router"]), 0.0, TOP_K)
+    assert np.any(np.sort(np.asarray(plain)) != np.sort(
+        np.asarray(routes[0])))
+
+
+def test_the_routers_rule():
+    """`route` with sigmoid scores: the choice on score + bias, the weights
+    the chosen SCORES over their sum times the scale; no gradient to the
+    bias, one to the router through the weights."""
+    from horovod_tpu.parallel.moe import route
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(ks[0], (20, 16), jnp.float32)
+    w = jax.random.normal(ks[1], (16, 8), jnp.float32) / 2
+    bias = jnp.zeros((8,), jnp.float32).at[3].set(5.0)     # always chosen
+    with jax.enable_x64(False):
+        weights, experts, counts, _ = route(
+            x, w, 2, renormalise=True, scoring="sigmoid",
+            selection_bias=bias, weight_scale=2.446)
+        scores = jax.nn.sigmoid(x @ w)
+        assert int(counts[3]) == 20 and bool(jnp.all(experts[:, 0] == 3))
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+        np.testing.assert_allclose(
+            weights, 2.446 * chosen / chosen.sum(-1, keepdims=True),
+            rtol=1e-6)
+        np.testing.assert_allclose(weights.sum(-1), 2.446, rtol=1e-6)
+
+        def total(w, bias):
+            return jnp.sum(route(x, w, 2, renormalise=True,
+                                 scoring="sigmoid", selection_bias=bias,
+                                 weight_scale=2.446)[0][:, 0])
+
+        dw, dbias = jax.grad(total, argnums=(0, 1))(w, bias)
+    assert float(jnp.max(jnp.abs(dbias))) == 0.0
+    assert float(jnp.max(jnp.abs(dw))) > 0.0
+    # the softmax rule is what it was: its defaults change nothing
+    plain = route(x, w, 2)
+    top, _ = jax.lax.top_k(jax.nn.softmax(x @ w, axis=-1), 2)
+    np.testing.assert_allclose(plain[0], top, rtol=1e-6)
+
+
+def test_the_held_experts_buffer_has_the_room_it_is_given():
+    """A selection bias that sends every token to the one held expert: 4,096
+    held pairs where an even routing sends 1,024. At the default room, twice
+    the even load, 2,048 find none, are counted and add nothing; at
+    `held_factor` 4 (`TransformerConfig.capacity_factor`, the cell's
+    `held_capacity`) every pair is computed."""
+    from horovod_tpu.parallel.moe import held_rows
+    tokens, d, f, n = 4096, 16, 8, 8
+    assert held_rows(tokens * 2, 1, n) == 2048
+    assert held_rows(tokens * 2, 1, n, 4.0) == 4096
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(ks[0], (tokens, d), jnp.float32)
+    router = jax.random.normal(ks[1], (d, n), jnp.float32) / 4
+    bias = jnp.zeros((n,), jnp.float32).at[0].set(9.0)
+    up, gate = (jax.random.normal(k, (1, d, f), jnp.float32) / 4
+                for k in ks[2:4])
+    down = jax.random.normal(ks[4], (1, f, d), jnp.float32) / 3
+
+    def layer(factor):
+        return jax.jit(jax.shard_map(
+            lambda x, r, b, up, down, gate: moe_ffn(
+                x, r, up, down, gate, top_k=2, renormalise=True,
+                scoring="sigmoid", selection_bias=b, held_factor=factor)[:2],
+            mesh=mesh_of(), in_specs=P(), out_specs=P(), check_vma=False))(
+                x, router, bias, up, down, gate)
+
+    with jax.enable_x64(False), jax.default_matmul_precision("highest"):
+        tight, loose = layer(2.0), layer(4.0)
+        w = {"router": router, "bias": bias, "w_gate": gate, "w_up": up,
+             "w_down": down, "ws_gate": jnp.zeros((d, f)),
+             "ws_up": jnp.zeros((d, f)), "ws_down": jnp.zeros((f, d))}
+        want, _ = reference.moe(x[None], w, 2)
+    assert float(tight[1][2]) == 2048 and float(loose[1][2]) == 0
+    np.testing.assert_allclose(loose[0], want[0] / reference.
+                               ROUTED_SCALING_FACTOR, rtol=3e-5, atol=3e-5)
+    assert float(jnp.max(jnp.abs(tight[0] - loose[0]))) > 1e-2

@@ -4,11 +4,12 @@ and keys are normed per head and rotated; a leading dense layer that is the
 pattern's first layer; sigmoid-scored experts with a selection bias, a top-k
 renormalised over its sum + 1e-6, of which a share is held, no shared
 expert; a tied head) against the plain reference
-`benchmark/reference/lfm2_moe.py`, at a small size in float32: each mixer
-alone, the logits (`attn` "local"), every planted fault and an 8-bit float
-refused by the family's limits. The loss and every leaf's gradient (`attn`
-"flash" under remat, as the cell runs it), the stack behind the dense layer,
-the eight shares of an expert layer, `dp` = 2 and remat, a train step, the
+`benchmark/reference/lfm2_moe.py`, at a small size in float32: the family's
+statement for `tests/family_cases.py` (`FAMILY`), each mixer alone, and of
+the shared cases the logits (`attn` "local"), every planted fault and an
+8-bit float refused by the family's limits. The loss and every leaf's
+gradient (`attn` "flash" under remat "dots", as the cell runs it), the stack
+behind the dense layer, the eight shares of an expert layer, `dp` = 2, the
 refusals and the family's counts at the published widths:
 `tests/test_lfm2_moe_stack.py`."""
 
@@ -22,6 +23,13 @@ import pytest
 import family as programs
 from benchmark.families import lfm2_moe as family
 from benchmark.reference import lfm2_moe as reference
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, lively, logits, params, pytest_generate_tests, sound, stated,
+    their_logits, test_an_unknown_fault_is_refused,
+    test_logits_equal_the_references,
+    test_the_familys_comparison_reads_zero_for_the_reference,
+    test_the_limits_refuse_a_planted_fault,
+    test_the_limits_refuse_an_8_bit_float)
 from horovod_tpu.models import mixers, transformer as tfm
 
 PATTERN = ("shortconv", "full", "shortconv", "shortconv")
@@ -39,54 +47,41 @@ CFG = tfm.TransformerConfig(
     layer_pattern=PATTERN, mlp="swiglu", tied_head=True, shortconv_taps=3,
     attn="local", dtype=jnp.float32)
 #: the cell's algorithm and remat: the loss and the gradients go through it
-#: (and `dp` = 2, and the train step); the logits (`system_logits`) through
-#: `CFG`'s own
+#: (and without remat `dp` = 2); the logits through `CFG`'s own
 TIMED = dataclasses.replace(CFG, attn="flash", remat=True,
-                            remat_policy="full")
+                            remat_policy="dots")
 SEQ = 32
 
-
-def _data(batch=2, seq=SEQ):
-    return programs.data(CFG.vocab, batch, seq)
-
-
-#: what `_lively` multiplies the drawn leaves by
-_LOUDER = {"wo": 3.0, "we2": 3.0, "router": 4.0, "sc_w_out": 3.0, "w2": 2.0,
-           "embed": 20.0}
-
-
-@jax.jit
-def _lively(params):
-    """`init`'s tree with the leaves it draws as ones or zeros moved (the
-    norms' scales, the per-head scales of q and k, the selection bias), and
-    the parts whose faults are planted made loud enough to show at this
-    size: router scores away from one half, mixers that weigh against the
-    residual stream (a tied embedding is drawn small)."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(11), 128))
-
-    def moved(path, leaf):
-        name = path[-1].key
-        if name.endswith("_scale"):
-            return leaf * (1 + 0.3 * jax.random.normal(next(keys),
-                                                       leaf.shape))
-        if name == "router_bias":
-            return leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)
-        return leaf * _LOUDER.get(name, 1.0)
-
-    with jax.enable_x64(False):
-        return jax.tree_util.tree_map_with_path(moved, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return _lively(programs.init(CFG))
-
-
-@pytest.fixture(scope="module")
-def system_logits(params):
-    """The program's logits for `_data()`'s tokens, once."""
-    with jax.enable_x64(False):
-        return programs.forward(CFG)(params, _data()[0])
+#: `init`'s tree with the norms' scales and the per-head scales of q and k
+#: moved off one, the selection bias off zero, and router scores away from
+#: one half, mixers that weigh against the residual stream (a tied embedding
+#: is drawn small)
+_lively = lively({"wo": 3.0, "we2": 3.0, "router": 4.0, "sc_w_out": 3.0,
+                  "w2": 2.0, "embed": 20.0}, shifted=("router_bias",))
+#: what `validate_cfg_for_mesh` refuses: (mesh, changed fields, its words)
+REFUSED = (
+    ({"sp": 2}, {"attn": "ring", "n_kv_heads": 0},
+     "short-convolution layers require sp=1"),
+    ({"tp": 2}, {"n_kv_heads": 0}, "short-convolution layers require tp=1"),
+    ({"pp": 2}, {"microbatches": 2, "n_kv_heads": 0}, "require pp=1"),
+    ({"tp": 2}, {"layer_pattern": (), "first_k_dense": 0, "n_kv_heads": 0},
+     "qk_norm='head' requires tp=1"),
+    ({"ep": 2}, {}, "ep > 1 with experts_held < num_experts"),
+    ({}, {"shortconv_taps": 0}, "'shortconv' layers need shortconv_taps"),
+    ({}, {"qk_norm": "heads"}, "qk_norm='heads'"),
+    ({}, {"first_k_dense": 2}, "pattern's first layers"),
+)
+#: (among the leaves: the taps', whose gradients come through the shifted
+#: sums, the per-head scales', summed over the heads, the tied table's, which
+#: the lookup and the head both reach, and the selection bias, which takes
+#: none on either side: it chooses and never weighs)
+FAMILY = Family(
+    cfg=CFG, timed=TIMED, family=family, reference=reference,
+    weights=(KINDS,), args=(KINDS, TOP_K, FIRST), data=(2, SEQ),
+    refused=REFUSED, lively=_lively, attns=("local",),
+    logits_tol=(5e-3, 5e-3), sound_below=2e-4, leaf_atol=3e-4,
+    no_gradient=("router_bias",), two_ranks_loss=1e-5,
+    two_ranks={"rtol": 2e-3, "atol": 1e-8, "scaled": 2e-4})
 
 
 # ------------------------------------------------------------------ the tree
@@ -145,80 +140,6 @@ def test_a_mixer_alone_equals_the_references(params, kind, at):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
 
 
-def test_logits_equal_the_references(params, system_logits):
-    """(`attn` "local"; "flash" is held by the loss and the gradients:
-    `tests/test_lfm2_moe_stack.py`.)"""
-    with jax.enable_x64(False):
-        want = reference.forward(family.reference_weights(params, KINDS),
-                                 _data()[0], KINDS, TOP_K, FIRST)
-    np.testing.assert_allclose(system_logits, want, atol=5e-3, rtol=5e-3)
-
-
-# --------------------------------------------------------------- the limits
-
-@pytest.fixture(scope="module")
-def sound(params, system_logits):
-    with jax.enable_x64(False):
-        return [float(x) for x in family.compare(
-            params, _data()[0], system_logits, KINDS, TOP_K, FIRST)[:3]]
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, system_logits, sound,
-                                           fault):
-    """The program's logits against the reference computed with one
-    mechanism wrong: by one of the family's limits it is not correct, and
-    against the sound reference it is, with room."""
-    with jax.enable_x64(False):
-        wrong = family.compare(params, _data()[0], system_logits, KINDS,
-                               TOP_K, FIRST, fault=fault)
-    assert all(family.within(*sound)) and sound[0] < 2e-4
-    assert not all(family.within(*(float(x) for x in wrong[:3]))), \
-        [float(x) for x in wrong[:3]]
-
-
-def test_an_unknown_fault_is_refused(params):
-    with pytest.raises(ValueError, match="choose from"):
-        reference.final_hidden(family.reference_weights(params, KINDS),
-                               _data()[0], KINDS, TOP_K, FIRST,
-                               fault="no_such_fault")
-
-
-@pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
-                         ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, system_logits, operands):
-    with jax.enable_x64(False):
-        rms, got, want, _ = family.compare(
-            params, _data()[0], system_logits, KINDS, TOP_K, FIRST,
-            operands=operands)
-    assert not all(family.within(float(rms), float(got), float(want)))
-
-
-def test_the_familys_comparison_reads_zero_for_the_reference(params):
-    """`family.compare` (the reference's head a block of tokens at a time)
-    against the reference's whole forward pass and its blockwise loss; its
-    count of the held experts' rows against the routes themselves, of the
-    four expert layers (the dense layer routes nothing)."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        weights = family.reference_weights(params, KINDS)
-        logits = reference.forward(weights, tokens, KINDS, TOP_K, FIRST)
-        _, routes = reference.final_hidden(weights, tokens, KINDS, TOP_K,
-                                           FIRST)
-        rms, got, want, rows = family.compare(params, tokens, logits, KINDS,
-                                              TOP_K, FIRST)
-        loss = reference.loss(weights, tokens, targets, KINDS, TOP_K, FIRST)
-    assert float(rms) < 1e-6
-    np.testing.assert_allclose([float(got), float(want)], float(loss),
-                               rtol=1e-6)
-    assert routes.shape == (4, 2, SEQ, TOP_K) and rows.shape == (4, 4)
-    assert [int(np.sum(np.asarray(routes) == FIRST + e)) for e in range(4)] \
-        == [int(rows[:, e].sum()) for e in range(4)]
-    assert "router" not in weights["layers"][0]
-    assert weights["layers"][0]["w_up"].shape == (64, 184)
-    assert "head" not in weights          # tied: the table is read twice
-
-
 def _config():
     return {
         "vocab_size": 96, "hidden_size": 64, "num_attention_heads": 4,
@@ -237,8 +158,7 @@ def _config():
                     "router_z_coef": 0.0, "held_capacity": 2.0}}
 
 
-def test_check_logits_knows_the_configuration_by_its_shapes(params,
-                                                            system_logits):
+def test_check_logits_knows_the_configuration_by_its_shapes(params, logits):
     """What `check_logits` cannot read off an array it takes from the
     configuration `transformer_config` was asked about."""
     config = _config()
@@ -249,7 +169,7 @@ def test_check_logits_knows_the_configuration_by_its_shapes(params,
     assert family.first_expert(config) == FIRST
     assert family.dense_layers(config) == 1
     with jax.enable_x64(False):
-        found = family.check_logits(params, _data()[0], system_logits)
+        found = family.check_logits(params, FAMILY.batch[0], logits)
     assert found["ok"], found
     assert "rows of the 4 held experts" in found["detail"]
     for changed in ({"conv_bias": True}, {"norm_topk_prob": False},

@@ -1,11 +1,12 @@
 """The LFM2 stack of `models/transformer.py` beside `tests/test_lfm2_moe.py`
-(whose tiny `CFG` this file shares), as the cell runs it (`attn` "flash",
-remat): the loss and every leaf's gradient against the plain reference's;
-the dense layer inside the layer pattern and the segments behind it; the
-eight shares of an expert layer adding up to the uncut layer; the
-renormalisation's epsilon; `dp` = 2 without remat against one rank under
-it; a train step; what `validate_cfg_for_mesh` refuses; and the family's
-counts at the published widths."""
+(whose statement `FAMILY` this file shares), as the cell runs it (`attn`
+"flash", remat "dots"). Of `tests/family_cases.py`: the loss and every
+leaf's gradient against the plain reference's, `dp` = 2 without remat against
+one rank under it, what `validate_cfg_for_mesh` refuses (the train step:
+`tests/test_lowered_steps.py`). Its own: the dense layer inside the layer pattern and the segments behind it;
+the eight shares of an expert layer adding up to the uncut layer; the
+renormalisation's epsilon; and the family's counts at the published
+widths."""
 
 import dataclasses
 import json
@@ -14,65 +15,21 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-import pytest
 from jax.sharding import PartitionSpec as P
 
 import family as programs
 from benchmark.families import lfm2_moe as family
 from benchmark.reference import lfm2_moe as reference
 from family import mesh_of
-from horovod_tpu.common.exceptions import HorovodTpuError
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    ours, params, pytest_generate_tests, stated, theirs,
+    test_dp_2_without_remat_equals_one_rank_under_remat,
+    test_every_leafs_gradient_equals_the_references,
+    test_loss_equals_the_references, test_validate_accepts_the_model_where_it_runs,
+    test_validate_refuses_by_name)
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import moe_ffn
-from test_lfm2_moe import (CFG, FIRST, KINDS, PATTERN, TIMED, TOP_K, _data,
-                           _lively)
-
-OPT = optax.adamw(1e-2)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return _lively(programs.init(CFG))
-
-
-@pytest.fixture(scope="module")
-def ours(params):
-    """(loss, gradients) of the program on one rank, as the cell runs it."""
-    with jax.enable_x64(False):
-        return programs.loss_and_grads(TIMED)(params, *_data())
-
-
-@pytest.fixture(scope="module")
-def theirs(params):
-    """(loss, gradients) of the reference, in the program's tree."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        return jax.value_and_grad(lambda p: reference.loss(
-            family.reference_weights(p, KINDS), tokens, targets, KINDS,
-            TOP_K, FIRST))(params)
-
-
-# ---------------------------------------------- the program and the reference
-
-def test_loss_equals_the_references(ours, theirs):
-    np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
-
-
-@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
-def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
-    """Among them the taps', which come through the shifted sums, the
-    per-head scales', summed over the heads, the tied table's, which the
-    lookup and the head both reach, and the selection bias, which takes none
-    on either side: it chooses and never weighs."""
-    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
-    size = float(jnp.max(jnp.abs(want)))
-    if "router_bias" in leaf:
-        assert size == 0.0 == float(jnp.max(jnp.abs(got)))
-        return
-    assert size > 1e-7, "nothing to compare"
-    np.testing.assert_allclose(got, want, rtol=2e-3,
-                               atol=3e-4 * size + 1e-8)
+from test_lfm2_moe import CFG, FAMILY, PATTERN, TOP_K  # noqa: F401
 
 
 # ------------------------------------------------------------------ the stack
@@ -157,59 +114,6 @@ def test_the_renormalisations_epsilon():
     np.testing.assert_allclose(plain[0].sum(-1), 1.0, rtol=1e-6)
     np.testing.assert_allclose(
         big[0], scores / (scores.sum(-1, keepdims=True) + 0.5), rtol=1e-6)
-
-
-# ------------------------------------------------- meshes, remat, the step
-
-def test_dp_2_without_remat_equals_one_rank_under_remat(params, ours):
-    """`ours` is one rank under remat "full". The same model WITHOUT remat
-    on two ranks, a sequence a rank: the layers' gradients reduce-scattered
-    inside the backward loop segment by segment, nothing recomputed; loss
-    and every gradient the same. (One program for both questions: a tiny
-    model's program is 15 s of compiling and nothing of running.)"""
-    cfg = dataclasses.replace(TIMED, remat=False)
-    with jax.enable_x64(False):
-        mesh = mesh_of(dp=2)
-        tfm.validate_cfg_for_mesh(cfg, mesh)
-        two = programs.loss_and_grads(cfg, dp=2)(
-            tfm.shard_params(params, cfg, mesh), *_data())
-    np.testing.assert_allclose(two[0], ours[0], rtol=1e-5)
-    programs.assert_trees_close(two[1], ours[1], rtol=2e-3, atol=1e-8,
-                                scaled=2e-4)
-
-
-def test_a_train_step_lowers_the_loss_and_counts_what_it_drops(params):
-    """Three steps on one batch: the loss falls, and the held pairs that
-    found no room in a row buffer are counted (none: 64 pairs a layer are
-    expected here and the buffer is a whole row tile; a buffer that
-    overflows is `tests/test_kimi_linear_stack.py`'s)."""
-    with jax.enable_x64(False):
-        results = programs.train(TIMED, OPT, params, _data(), 3,
-                                 metrics=True)
-    assert all(int(c["experts_dropped"]) == 0 for _, c in results)
-    assert float(results[2][0]) < float(results[0][0]), results
-
-
-@pytest.mark.parametrize("mesh, changes, what", [
-    ({"sp": 2}, {"attn": "ring", "n_kv_heads": 0},
-     "short-convolution layers require sp=1"),
-    ({"tp": 2}, {"n_kv_heads": 0}, "short-convolution layers require tp=1"),
-    ({"pp": 2}, {"microbatches": 2, "n_kv_heads": 0},
-     "require pp=1"),
-    ({"tp": 2}, {"layer_pattern": (), "first_k_dense": 0, "n_kv_heads": 0},
-     "qk_norm='head' requires tp=1"),
-    ({"ep": 2}, {}, "ep > 1 with experts_held < num_experts"),
-    ({}, {"shortconv_taps": 0}, "'shortconv' layers need shortconv_taps"),
-    ({}, {"qk_norm": "heads"}, "qk_norm='heads'"),
-    ({}, {"first_k_dense": 2}, "pattern's first layers"),
-])
-def test_what_the_mesh_check_refuses(mesh, changes, what):
-    cfg = dataclasses.replace(CFG, **changes)
-    with pytest.raises(HorovodTpuError) as refused:
-        tfm.validate_cfg_for_mesh(cfg, mesh_of(**mesh))
-    assert what in str(refused.value)
-    tfm.validate_cfg_for_mesh(CFG, mesh_of())
-    tfm.validate_cfg_for_mesh(TIMED, mesh_of(dp=2))
 
 
 # ------------------------------------------------------- the published widths

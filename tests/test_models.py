@@ -1,15 +1,15 @@
-"""Model zoo smoke + correctness tests (reference analog: the synthetic
-benchmark models, examples/pytorch/pytorch_synthetic_benchmark.py)."""
+"""Model zoo smoke + correctness tests, the image models (reference analog:
+the synthetic benchmark models,
+examples/pytorch/pytorch_synthetic_benchmark.py). The transformer's and the
+graft entry: `tests/test_models_lm.py` (two files, so that the two long
+passes, Inception V3's and the entry's dry run, end in parallel: a file of
+few tests is handed out last and is the run's tail)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import mlp, resnet
-from horovod_tpu.models import transformer as tfm
-from horovod_tpu.parallel import MeshSpec, build_mesh
 
 
 def test_mlp_trains():
@@ -45,64 +45,13 @@ def test_resnet_eval_mode_uses_running_stats():
     params, stats = resnet.init(jax.random.PRNGKey(0), depth=50,
                                 num_classes=10)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3), jnp.float32)
-    logits, ns = resnet.apply(params, stats, x, depth=50, train=False)
+    logits, ns = jax.jit(lambda p, s: resnet.apply(
+        p, s, x, depth=50, train=False))(params, stats)
     assert logits.shape == (2, 10)
     # Eval mode must not mutate stats.
     same = jax.tree_util.tree_map(
         lambda a, b: np.array_equal(np.asarray(a), np.asarray(b)), stats, ns)
     assert all(jax.tree_util.tree_leaves(same))
-
-
-def test_transformer_forward_shapes():
-    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, d_ff=64,
-                                n_layers=2, max_seq=64)
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
-    mesh = build_mesh(MeshSpec(), jax.devices()[:1])
-    fwd = jax.jit(tfm.build_forward(cfg, mesh))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
-    logits = fwd(params, tokens)
-    assert logits.shape == (2, 16, cfg.vocab)
-    assert np.all(np.isfinite(np.asarray(logits)))
-
-
-def test_transformer_flash_attention_matches_local():
-    """attn='flash' (Pallas kernel, ops/flash_attention.py) must produce
-    the same logits and gradients as the exact 'local' attention."""
-    import jax.numpy as jnp
-    mk = lambda attn: tfm.TransformerConfig(  # noqa: E731
-        vocab=64, d_model=32, n_heads=4, d_ff=64, n_layers=2, max_seq=64,
-        attn=attn)
-    params = tfm.init(jax.random.PRNGKey(0), mk("local"))
-    mesh = build_mesh(MeshSpec(), jax.devices()[:1])
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 64)
-
-    out = {}
-    for attn in ("local", "flash"):
-        fwd = jax.jit(tfm.build_forward(mk(attn), mesh))
-        out[attn] = np.asarray(fwd(params, tokens))
-    np.testing.assert_allclose(out["flash"], out["local"],
-                               rtol=2e-4, atol=2e-4)
-
-    grads = {}
-    for attn in ("local", "flash"):
-        cfg = mk(attn)
-        fwd = tfm.build_forward(cfg, mesh)
-
-        def loss(p):
-            return jnp.mean(jnp.square(fwd(p, tokens)))
-        grads[attn] = jax.jit(jax.grad(loss))(params)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4),
-        grads["flash"], grads["local"])
-
-
-def test_graft_entry_hooks():
-    import __graft_entry__ as ge
-    fn, args = ge.entry()
-    out = jax.jit(fn)(*args)
-    assert out.ndim == 3
-    ge.dryrun_multichip(8)
 
 
 def test_vgg16_forward_backward():
@@ -115,9 +64,10 @@ def test_vgg16_forward_backward():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 32, 32, 3)),
                     jnp.float32)
     y = jnp.asarray([1, 7])
-    logits = vgg.apply(params, x, depth=16)
+    # (jitted: op by op each layer's operations compile on their own)
+    logits = jax.jit(lambda p: vgg.apply(p, x, depth=16))(params)
     assert logits.shape == (2, 10)
-    g = jax.grad(lambda p: vgg.loss_fn(p, (x, y), depth=16))(params)
+    g = jax.jit(jax.grad(lambda p: vgg.loss_fn(p, (x, y), depth=16)))(params)
     gn = sum(float(jnp.sum(jnp.abs(a)))
              for a in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0

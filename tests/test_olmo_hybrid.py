@@ -1,25 +1,38 @@
 """The Olmo-Hybrid pattern of `models/transformer.py` (three gated-delta-rule
 linear-attention layers to one full-attention layer, post-sub-layer norms,
 no rotary embedding) against the plain reference
-`benchmark/reference/olmo_hybrid.py`, at a small size in float32: logits,
-loss and every leaf's gradient under each remat policy; `dp` = 2 against one
-rank; and what `validate_cfg_for_mesh` refuses. (The chunked rule alone:
-`tests/test_gated_delta.py`; the convolution: `tests/test_causal_conv.py`.)
-Every program is `tests/family.py`'s, built once for the module."""
+`benchmark/reference/olmo_hybrid.py`, at a small size in float32: the family's
+statement for `tests/family_cases.py` (`FAMILY`) and the shared cases (logits;
+loss and every leaf's gradient of one rank as the cell runs it, under remat
+"dots"; `dp` = 2 without remat against it; what `validate_cfg_for_mesh`
+refuses; the train step: `tests/test_hybrid_steps.py`); and THE witness of the remat policies
+against each other, for every family: the gradients without remat, under
+"dots" and under "full" against the reference's (the policy is
+`jax.checkpoint`'s around the layer scan in `models/transformer.py`, no
+family's own code; this family's delta rule is a Pallas kernel with a
+`custom_vjp`, so the witness holds a kernel under both). (The chunked rule
+alone: `tests/test_gated_delta.py`; the convolution:
+`tests/test_causal_conv.py`.) Every program is `tests/family.py`'s, built
+once for the module."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 import family as programs
 from benchmark.families import olmo_hybrid as family
 from benchmark.reference import olmo_hybrid as reference
-from family import mesh_of
-from horovod_tpu.common.exceptions import HorovodTpuError
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, logits, ours, params, pytest_generate_tests, stated,
+    their_logits, theirs,
+    test_dp_2_without_remat_equals_one_rank_under_remat,
+    test_every_leafs_gradient_equals_the_references,
+    test_logits_equal_the_references, test_loss_equals_the_references,
+    test_validate_accepts_the_model_where_it_runs,
+    test_validate_refuses_by_name)
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.models.mixers import MIXERS
 
@@ -32,14 +45,8 @@ CFG = tfm.TransformerConfig(
     attn="flash", dtype=jnp.float32)
 
 
-def _data(batch=4, seq=40):
-    return programs.data(CFG.vocab, batch, seq)
-
-
-@pytest.fixture(scope="module")
-def params():
-    p = programs.init(CFG)
-    # norm scales off their initial ones, so that a misplaced one shows
+def _moved(params):
+    """Norm scales off their initial ones, so that a misplaced one shows."""
     keys = iter(jax.random.split(jax.random.PRNGKey(5), 64))
 
     def moved(path, x):
@@ -47,29 +54,45 @@ def params():
         if "scale" in name:
             return x + 0.3 * jax.random.normal(next(keys), x.shape, x.dtype)
         return x
-    return jax.tree_util.tree_map_with_path(moved, p)
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+#: what `validate_cfg_for_mesh` refuses: (mesh, changed fields, its words)
+REFUSED = (
+    ({"sp": 2}, {}, "linear-attention layers require sp=1"),
+    ({"tp": 3}, {}, "linear-attention layers require tp=1"),
+    ({"pp": 2}, {"microbatches": 2}, "a layer pattern requires pp=1"),
+    # a pattern's layers may be expert layers since PR 38, and may be
+    # windowed: what is still refused is a window of no keys
+    ({}, {"layer_pattern": ("linear", "window")},
+     "'window' layers need window > 0"),
+    ({}, {"n_layers": 6}, "no whole number of periods"),
+    ({}, {"layer_pattern": ("linear", "sparse")}, "names the kind 'sparse'"),
+    # a kind since PR 36, which a pattern alone cannot hold
+    ({}, {"layer_pattern": ("linear", "ssm")}, "need segments"),
+    ({"sp": 2}, {"layer_pattern": (), "attention": "gdn"},
+     "linear-attention layers require sp=1"),
+)
+#: the cell's remat policy. (`dp` = 2 reduces the new leaves inside the
+#: backward loop like any layer's; in float32 the rule's running sums of the
+#: log decay leave ~1e-4 of a leaf's largest entry between two batch shapes,
+#: which the Kimi Linear and LFM2 families' tolerance admits.)
+FAMILY = Family(
+    cfg=CFG, family=family, reference=reference,
+    timed=dataclasses.replace(CFG, remat=True, remat_policy="dots"),
+    weights=(PATTERN,), args=(), data=(4, 40), refused=REFUSED,
+    lively=_moved, accepted=(({}, {"dp": 2, "ep": 2}),), attns=("flash",),
+    logits_tol=(1e-3, 1e-3), leaf_atol=2e-3, two_ranks_loss=1e-5,
+    two_ranks={"rtol": 2e-3, "atol": 1e-8, "scaled": 2e-4})
 
 
 # ----------------------------------------------------------- the mixer
 
-@pytest.fixture(scope="module")
-def logits(params):
-    """The program's logits for `_data()`'s tokens, once."""
-    return programs.forward(CFG)(params, _data()[0])
-
-
-@pytest.fixture(scope="module")
-def theirs(params):
-    """The gradients of the reference's loss, in the program's tree."""
-    tokens, targets = _data()
-    return jax.grad(lambda p: reference.loss(
-        family.reference_weights(p, PATTERN), tokens, targets))(params)
-
-
 def test_a_later_token_moves_no_earlier_logit(params, logits):
-    tokens, _ = _data()
-    moved = programs.forward(CFG)(
-        params, tokens.at[0, 25].set((tokens[0, 25] + 1) % 96))
+    tokens, _ = FAMILY.batch
+    with jax.enable_x64(False):
+        moved = programs.forward(CFG)(
+            params, tokens.at[0, 25].set((tokens[0, 25] + 1) % 96))
     np.testing.assert_array_equal(logits[:, :25], moved[:, :25])
     np.testing.assert_array_equal(logits[1:], moved[1:])
     assert float(jnp.max(jnp.abs(logits[:, 25:] - moved[:, 25:]))) > 1e-3
@@ -106,6 +129,11 @@ def test_the_pattern_has_the_leaves_each_kind_has(params):
     step = jax.nn.softplus(layers["linear"]["gdn_dt_bias"])
     assert 0 < float(rate.min()) and float(rate.max()) <= 16
     assert 1e-3 <= float(step.min()) and float(step.max()) <= 0.1 + 1e-6
+    # and the reference's weights are a layer's each, in the pattern's order
+    weights = family.reference_weights(params, PATTERN)
+    assert len(weights["layers"]) == 8
+    assert ["a_log" in w for w in weights["layers"]] == \
+        [True, True, True, False] * 2
 
 
 def test_a_stack_of_one_kind_keeps_its_leaves():
@@ -124,29 +152,25 @@ def test_a_stack_of_one_kind_keeps_its_leaves():
 
 # ------------------------------------------- the model and the reference
 
-def test_logits_and_loss_match_the_reference(params, logits):
-    tokens, targets = _data()
-    weights = family.reference_weights(params, PATTERN)
-    assert len(weights["layers"]) == 8
-    assert ["a_log" in w for w in weights["layers"]] == \
-        [True, True, True, False] * 2
-    want = reference.forward(weights, tokens)
-    np.testing.assert_allclose(logits, want, atol=1e-3, rtol=1e-3)
-    loss, _ = programs.loss_and_grads(CFG)(params, tokens, targets)
-    assert float(loss) == pytest.approx(
-        float(reference.loss(weights, tokens, targets)), rel=1e-5)
-
-
 @pytest.mark.parametrize("remat_policy", [None, "dots", "full"])
 def test_every_gradient_leaf_matches_the_reference(params, theirs,
                                                    remat_policy):
-    cfg = CFG if remat_policy is None else dataclasses.replace(
-        CFG, remat=True, remat_policy=remat_policy)
-    _, grads = programs.loss_and_grads(cfg)(params, *_data())
+    """The witness of the policies, with no program of its own but "full":
+    "dots" is `ours`, and without remat the model runs on two ranks (the
+    shared `dp` = 2 case's program), held to the reference here."""
+    cfg = dataclasses.replace(FAMILY.timed, remat=remat_policy is not None,
+                              remat_policy=remat_policy or "dots")
+    with jax.enable_x64(False):
+        if cfg.remat:      # (with the counts, as `ours` is built)
+            grads = programs.loss_and_grads(cfg, metrics=True)(
+                params, *FAMILY.batch)[1]
+        else:
+            grads = programs.loss_and_grads(cfg, dp=2)(tfm.shard_params(
+                params, cfg, programs.mesh_of(dp=2)), *FAMILY.batch)[1]
     assert len(programs.leaves(grads)) == 3 + 11 + 18
     assert all(float(jnp.max(jnp.abs(w))) > 0
-               for w in jax.tree_util.tree_leaves(theirs))
-    programs.assert_trees_close(grads, theirs, rtol=0, scaled=2e-3)
+               for w in jax.tree_util.tree_leaves(theirs[1]))
+    programs.assert_trees_close(grads, theirs[1], rtol=0, scaled=2e-3)
 
 
 def test_the_limits_refuse_lower_precisions():
@@ -156,7 +180,7 @@ def test_the_limits_refuse_lower_precisions():
     cfg = dataclasses.replace(CFG, d_model=96, n_heads=3, d_ff=160,
                               n_layers=4)
     p = programs.init(cfg, 4)
-    tokens, _ = _data(batch=1, seq=64)
+    tokens, _ = programs.data(CFG.vocab, 1, 64)
     weights = family.reference_weights(p, PATTERN)
     want = reference.forward(weights, tokens)
 
@@ -174,69 +198,15 @@ def test_the_limits_refuse_lower_precisions():
 
 # ------------------------------------------------------------ the meshes
 
-def test_two_data_parallel_ranks_equal_one(params):
-    """`dp` = 2 reduces the new leaves inside the backward loop like any
-    layer's: loss and every gradient leaf as on one rank. (In float32 the
-    rule's running sums of the log decay leave ~1e-4 between two batch
-    shapes; the comparison is made in float64.)"""
-    cfg = dataclasses.replace(CFG, dtype=jnp.float64)
-    p64 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float64), params)
-    tokens, targets = _data()
-    out = {}
-    for dp in (1, 2):
-        mesh = mesh_of(dp=dp)
-        tfm.validate_cfg_for_mesh(cfg, mesh)
-        out[dp] = programs.loss_and_grads(cfg, dp=dp)(
-            tfm.shard_params(p64, cfg, mesh), tokens, targets)
-    assert float(out[2][0]) == pytest.approx(float(out[1][0]), rel=1e-6)
-    programs.assert_trees_close(out[2][1], out[1][1], rtol=0, scaled=1e-5)
-
-
-def test_two_ranks_scatter_the_new_leaves_in_the_backward_loop():
-    cfg = dataclasses.replace(CFG, remat=True)
-    tokens = jax.ShapeDtypeStruct((4, 40), jnp.int32)
-    text = programs.loss_and_grads(cfg, dp=2).lower(
-        programs.shapes(cfg), tokens, tokens).as_text()
+def test_two_ranks_scatter_the_new_leaves_in_the_backward_loop(params):
+    """(The shared `dp` = 2 case's program and arguments, lowered: remat or
+    none, the exchanges are the same.)"""
+    cfg = dataclasses.replace(FAMILY.timed, remat=False)
+    with jax.enable_x64(False):
+        text = programs.loss_and_grads(cfg, dp=2).lower(tfm.shard_params(
+            params, cfg, programs.mesh_of(dp=2)), *FAMILY.batch).as_text()
     # one exchange inside the loop for each leaf that is no vector: 13 of a
     # linear layer's 18 leaves (all but the two norms' scales, the gated
     # norm's, A_log and dt_bias) and 9 of a full layer's 11; the vectors
     # are psum'd after the loop
     assert text.count("collective_permute") == 13 + 9
-
-
-def test_the_train_step_learns_the_fixed_batch(params):
-    losses = [float(loss) for loss, in programs.train(
-        dataclasses.replace(CFG, remat=True), optax.adamw(3e-3), params,
-        _data(), 4)]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-
-
-@pytest.mark.parametrize("sizes, cfg, message", [
-    ({"sp": 2}, CFG, "linear-attention layers require sp=1"),
-    ({"tp": 3}, CFG, "linear-attention layers require tp=1"),
-    ({"pp": 2}, dataclasses.replace(CFG, microbatches=2),
-     "a layer pattern requires pp=1"),
-    # a pattern's layers may be expert layers since PR 38, and may be
-    # windowed: what is still refused is a window of no keys
-    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "window")),
-     "'window' layers need window > 0"),
-    ({}, dataclasses.replace(CFG, n_layers=6),
-     "no whole number of periods"),
-    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "sparse")),
-     "names the kind 'sparse'"),
-    # a kind since PR 36, which a pattern alone cannot hold
-    ({}, dataclasses.replace(CFG, layer_pattern=("linear", "ssm")),
-     "need segments"),
-    ({"sp": 2}, dataclasses.replace(CFG, layer_pattern=(), attention="gdn"),
-     "linear-attention layers require sp=1"),
-])
-def test_what_linear_layers_cannot_do_yet_is_refused_by_name(sizes, cfg,
-                                                             message):
-    with pytest.raises(HorovodTpuError, match=message):
-        tfm.validate_cfg_for_mesh(cfg, mesh_of(**sizes))
-
-
-def test_the_pattern_validates_on_the_meshes_it_runs_on():
-    tfm.validate_cfg_for_mesh(CFG, mesh_of())
-    tfm.validate_cfg_for_mesh(CFG, mesh_of(dp=2))
-    tfm.validate_cfg_for_mesh(CFG, mesh_of(dp=2, ep=2))

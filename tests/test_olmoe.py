@@ -1,8 +1,12 @@
 """The OLMoE block of `models/transformer.py` (RMSNorm, rotary positions,
 QK-norm, top-k of E gated-SiLU experts, auxiliary losses) against the plain
-reference `benchmark/reference/olmoe.py`, at a small size in float32; and the
-routing of `parallel/moe.py`: dropless on one rank, capacity-bounded across
-ranks."""
+reference `benchmark/reference/olmoe.py`, at a small size in float32: the
+family's statement for `tests/family_cases.py` (`FAMILY`) and of the shared
+cases the loss and every leaf's gradient of one rank as the cell runs it
+(`attn` "flash" under remat); the meshes, where each shard of the batch takes
+the auxiliary terms over its own tokens, so that two ranks are held to the
+reference given the same shards and not to one rank; and the routing of
+`parallel/moe.py`: dropless on one rank, capacity-bounded across ranks."""
 
 import dataclasses
 
@@ -15,6 +19,10 @@ from jax.sharding import PartitionSpec as P
 import family as programs
 from benchmark.families import olmoe as family
 from benchmark.reference import olmoe as reference
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, ours, params, pytest_generate_tests, stated, theirs,
+    test_every_leafs_gradient_equals_the_references,
+    test_loss_equals_the_references)
 from horovod_tpu.models import transformer as tfm
 from family import mesh_of
 from horovod_tpu.parallel import moe, moe_ffn
@@ -27,13 +35,17 @@ CFG = tfm.TransformerConfig(
     mlp="swiglu", attn="local", dtype=jnp.float32)
 
 
+#: the cell's algorithm and remat (the program's default policy); the
+#: gradients to a leaf's largest entry, as they were held before the shared
+#: cases
+FAMILY = Family(
+    cfg=CFG, timed=dataclasses.replace(CFG, attn="flash", remat=True),
+    family=family, reference=reference, weights=(), args=(TOP_K,),
+    data=(4, 16), refused=(), leaf_rtol=0, leaf_atol=2e-5)
+
+
 def _data(batch=4, seq=16):
     return programs.data(CFG.vocab, batch, seq)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return programs.init(CFG)
 
 
 def test_the_block_has_the_leaves_the_architecture_has(params):
@@ -72,7 +84,7 @@ def test_the_expert_layer_routes_as_the_reference_does(params):
                                rtol=1e-5)
 
 
-def test_logits_and_loss_match_the_reference(params):
+def test_logits_and_loss_match_the_reference(params, ours):
     tokens, targets = _data()
     logits = programs.forward(CFG)(params, tokens)
     weights = family.reference_weights(params)
@@ -81,9 +93,8 @@ def test_logits_and_loss_match_the_reference(params):
     # are compared above
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
-    loss, _ = programs.loss_and_grads(CFG)(params, tokens, targets)
     full = reference.loss(weights, tokens, targets, TOP_K)
-    np.testing.assert_allclose(float(loss), float(full), rtol=1e-5)
+    np.testing.assert_allclose(float(ours[0]), float(full), rtol=1e-5)
     # the auxiliary terms are in it: 0.01 x ~1 and 0.001 x ~ln(8)^2
     plain = reference.next_token_loss(want, targets)
     balance, z = np.mean(np.asarray(aux), axis=0)
@@ -104,13 +115,13 @@ def test_reference_held_to_given_routes_uses_them(params):
     assert float(jnp.max(jnp.abs(moved - free))) > 1e-3
 
 
-@pytest.mark.parametrize("sizes", [{}, {"dp": 2}, {"dp": 2, "tp": 2}],
-                         ids=["one-rank", "dp2", "dp2-tp2"])
+@pytest.mark.parametrize("sizes", [{"dp": 2}, {"dp": 2, "tp": 2}],
+                         ids=["dp2", "dp2-tp2"])
 def test_every_gradient_leaf_matches_the_reference(params, sizes):
     """`build_loss_and_grads` against `jax.grad` of the reference's loss,
     auxiliary terms included. On a mesh each shard of the batch takes the
     auxiliary terms over its own tokens and the reference is given the same
-    shards."""
+    shards. (One rank: the shared cases, leaf by leaf.)"""
     tokens, targets = _data()
     mesh = mesh_of(**sizes)
     tfm.validate_cfg_for_mesh(CFG, mesh)

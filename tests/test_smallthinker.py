@@ -4,25 +4,38 @@ heads than query heads, a head width that is not d_model / n_heads, every
 layer a layer of ReLU-gated experts whose router reads the layer's input and
 whose top-k weights are renormalised, a share of the experts held) against
 the plain reference `benchmark/reference/smallthinker.py`, at a small size in
-float32: logits, loss and every leaf's gradient, `attn` "local" and "flash";
-every planted fault refused by the family's limits; the four shares of the
-experts adding up to the uncut layer; `dp` = 2 against one rank; and what
-`validate_cfg_for_mesh` refuses. (The scopes of the compiled step:
-`tests/test_step_scopes.py`.) Every program is `tests/family.py`'s, built
-once for the module."""
+float32: the family's statement for `tests/family_cases.py` (`FAMILY`) and
+the shared cases (logits, `attn` "local" and "flash"; loss and every leaf's
+gradient of one rank as the cell runs it, `attn` "flash" under remat "dots";
+`dp` = 2 without remat against it; every planted fault refused by the
+family's limits; what `validate_cfg_for_mesh` refuses); two periods; the four
+shares of the experts adding up to the uncut layer. (The compiled step, its
+scopes and three steps of it: `tests/test_step_scopes.py`.) Every program is
+`tests/family.py`'s, built once for the module."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
 import family as programs
 from benchmark.families import smallthinker as family
 from benchmark.reference import smallthinker as reference
+from family_cases import (  # noqa: F401  (the fixtures, the shared tests)
+    Family, logits, ours, params, pytest_generate_tests, sound, stated,
+    their_logits, theirs,
+    test_an_unknown_fault_is_refused,
+    test_dp_2_without_remat_equals_one_rank_under_remat,
+    test_every_leafs_gradient_equals_the_references,
+    test_logits_equal_the_references, test_loss_equals_the_references,
+    test_the_familys_comparison_reads_zero_for_the_reference,
+    test_the_limits_refuse_a_planted_fault,
+    test_the_limits_refuse_an_8_bit_float,
+    test_validate_accepts_the_model_where_it_runs,
+    test_validate_refuses_by_name)
 from horovod_tpu.common.exceptions import HorovodTpuError
 from horovod_tpu.models import transformer as tfm
 from family import mesh_of
@@ -40,53 +53,43 @@ CFG = tfm.TransformerConfig(
     layer_pattern=KINDS, unrotated=("full",), window=WINDOW, mlp="reglu",
     attn="local", dtype=jnp.float32)
 SEQ = 32          # four windows long: the band matters
-ATTNS = ("local", "flash")
-
-
-def _data(batch=2, seq=SEQ):
-    return programs.data(CFG.vocab, batch, seq)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return programs.init(CFG)
-
-
-def _one_rank(params, cfg=CFG):
-    """(loss, gradients) of the program on one rank."""
-    with jax.enable_x64(False):
-        return programs.loss_and_grads(cfg)(params, *_data())
-
-
-@pytest.fixture(scope="module", params=ATTNS)
-def ours(request, params):
-    """`_one_rank` by each algorithm."""
-    return _one_rank(params, dataclasses.replace(CFG, attn=request.param))
-
-
-@pytest.fixture(scope="module")
-def theirs(params):
-    """(loss, gradients) of the reference, in the program's tree."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        return jax.value_and_grad(lambda p: reference.loss(
-            family.reference_weights(p, KINDS), tokens, targets, KINDS,
-            WINDOW, TOP_K, FIRST))(params)
-
-
-@pytest.fixture(scope="module")
-def logits(params):
-    """The program's logits for `_data()`'s tokens, once."""
-    with jax.enable_x64(False):
-        return programs.forward(CFG)(params, _data()[0])
-
-
-@pytest.fixture(scope="module")
-def sound(params, logits):
-    """The family's comparison of `logits` with the sound reference."""
-    with jax.enable_x64(False):
-        return family.compare(params, _data()[0], logits, KINDS, WINDOW,
-                              TOP_K, FIRST)
+#: what `validate_cfg_for_mesh` refuses: (mesh, changed fields, its words)
+REFUSED = (
+    ({}, {"attn": "ring"}, "d_head \\* n_heads != d_model needs attn"),
+    ({}, {"attn": "ulysses"}, "d_head \\* n_heads != d_model needs attn"),
+    ({}, {"attn": "ring", "n_kv_heads": 0, "window": 0,
+          "layer_pattern": (), "unrotated": ()},
+     "d_head \\* n_heads != d_model needs attn"),
+    (dict(tp=2), {"n_kv_heads": 0, "window": 0, "layer_pattern": (),
+                  "unrotated": ()},
+     "d_head \\* n_heads != d_model requires sp=tp=pp=1"),
+    (dict(sp=2), {"n_kv_heads": 0, "window": 0, "layer_pattern": (),
+                  "unrotated": ()},
+     "d_head \\* n_heads != d_model requires sp=tp=pp=1"),
+    (dict(tp=2), {"d_head": 0},
+     "need attn 'flash' or 'local' and sp=tp=pp=1"),
+    ({}, {"attention": "mla"}, "d_head is plain attention's head width"),
+    ({}, {"positions": "none"}, "unrotated names kinds that take no "
+                                "rotation"),
+    ({}, {"unrotated": ("linear",)}, "unrotated names a kind the pattern "
+                                     "lacks"),
+    ({}, {"router_input": "attention"}, "router_input='attention'"),
+    ({}, {"router_input": "layer", "post_norm": True},
+     "router_input='layer' with post_norm"),
+    ({}, {"mlp": "geglu"}, "mlp='geglu'"),
+    ({}, {"window": 0}, "'window' layers need window > 0"),
+    (dict(pp=2), {"microbatches": 2}, "requires sp=tp=pp=1"),
+    ({}, {"n_layers": 6}, "no whole number of periods"),
+)
+#: the cell's algorithm and remat policy; (among the leaves: the routers',
+#: whose gradient comes through the layer's input and the renormalised
+#: weights, and `wk`, `wv`, summed over a group's two query heads)
+FAMILY = Family(
+    cfg=CFG, family=family, reference=reference,
+    timed=dataclasses.replace(CFG, attn="flash", remat=True,
+                              remat_policy="dots"),
+    weights=(KINDS,), args=(KINDS, WINDOW, TOP_K, FIRST), data=(2, SEQ),
+    refused=REFUSED, least=1e-6)
 
 
 def test_the_tree_has_each_kinds_leaves_and_no_others(params):
@@ -108,38 +111,11 @@ def test_the_tree_has_each_kinds_leaves_and_no_others(params):
     programs.assert_specs_cover(CFG, params)
 
 
-@pytest.mark.parametrize("attn", ATTNS)
-def test_logits_equal_the_references(params, attn):
-    tokens, _ = _data()
-    cfg = dataclasses.replace(CFG, attn=attn)
-    with jax.enable_x64(False):
-        got = programs.forward(cfg)(params, tokens)
-        want = reference.forward(family.reference_weights(params, KINDS),
-                                 tokens, KINDS, WINDOW, TOP_K, FIRST)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
-
-
-def test_loss_equals_the_references(ours, theirs):
-    np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-5)
-
-
-@pytest.mark.parametrize("leaf", programs.leaf_names(CFG))
-def test_every_leafs_gradient_equals_the_references(ours, theirs, leaf):
-    """Among them the routers', whose gradient comes through the layer's
-    input and the renormalised weights, and `wk`, `wv`, summed over a
-    group's two query heads."""
-    got, want = (programs.leaves(x[1])[leaf] for x in (ours, theirs))
-    size = float(jnp.max(jnp.abs(want)))
-    assert size > 1e-6, "nothing to compare"
-    np.testing.assert_allclose(got, want, rtol=2e-3,
-                               atol=2e-4 * size + 1e-7)
-
-
 def test_two_periods_stack_by_kind_and_equal_the_reference():
     """Eight layers: each kind's leaves over (2 periods, its layers in one),
     the periods' auxiliary numbers gathered layer by layer."""
     cfg = dataclasses.replace(CFG, n_layers=8, load_balance_coef=0.01)
-    tokens, targets = _data()
+    tokens, targets = FAMILY.batch
     with jax.enable_x64(False):
         p = programs.init(cfg, 5)
         assert p["layers"]["window"]["wq"].shape == (2, 3, 64, 4, 32)
@@ -151,60 +127,6 @@ def test_two_periods_stack_by_kind_and_equal_the_reference():
     # the balance term of all eight layers is in the loss (>= 1 a layer)
     plain = float(reference.next_token_loss(want, targets))
     assert float(loss) - plain >= 0.01 * 0.9
-
-
-# --------------------------------------------------------------- the limits
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_limits_refuse_a_planted_fault(params, logits, sound, fault):
-    """The program's logits against the reference computed with one
-    mechanism wrong: by one of the family's limits it is not correct, and
-    against the sound reference it is, with room."""
-    tokens, _ = _data()
-    with jax.enable_x64(False):
-        wrong = family.compare(params, tokens, logits, KINDS, WINDOW, TOP_K,
-                               FIRST, fault=fault)
-    assert all(family.within(*(float(x) for x in sound[:3])))
-    assert float(sound[0]) < 1e-5
-    assert not all(family.within(*(float(x) for x in wrong[:3]))), \
-        [float(x) for x in wrong[:3]]
-    with pytest.raises(ValueError, match="choose from"):
-        reference.final_hidden(family.reference_weights(params, KINDS),
-                               tokens, KINDS, WINDOW, TOP_K, FIRST,
-                               fault="no_such_fault")
-
-
-@pytest.mark.parametrize("operands", [jnp.float8_e4m3fn, jnp.float8_e5m2],
-                         ids=["e4m3", "e5m2"])
-def test_the_limits_refuse_an_8_bit_float(params, logits, operands):
-    tokens, _ = _data()
-    with jax.enable_x64(False):
-        rms, got, want, _ = family.compare(
-            params, tokens, logits, KINDS, WINDOW, TOP_K, FIRST,
-            operands=operands)
-    assert not all(family.within(float(rms), float(got), float(want)))
-
-
-def test_the_familys_comparison_reads_zero_for_the_reference(params):
-    """`family.compare` (the reference's head a block of tokens at a time)
-    against the reference's whole forward pass; its count of the held
-    experts' rows against the routes themselves."""
-    tokens, targets = _data()
-    with jax.enable_x64(False):
-        weights = family.reference_weights(params, KINDS)
-        logits = reference.forward(weights, tokens, KINDS, WINDOW, TOP_K,
-                                   FIRST)
-        _, routes = reference.final_hidden(weights, tokens, KINDS, WINDOW,
-                                           TOP_K, FIRST)
-        rms, got, want, rows = family.compare(params, tokens, logits, KINDS,
-                                              WINDOW, TOP_K, FIRST)
-        loss = reference.next_token_loss(logits, targets)
-    assert float(rms) < 1e-6
-    np.testing.assert_allclose([float(got), float(want)], float(loss),
-                               rtol=1e-6)
-    assert rows.shape == (4, 2)
-    assert [int(np.sum(np.asarray(routes) == FIRST + e)) for e in (0, 1)] \
-        == [int(rows[:, e].sum()) for e in (0, 1)]
 
 
 # --------------------------------------------------------------- the share
@@ -249,79 +171,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert min(held) > 0 and sum(held) == tokens * TOP_K
 
 
-# ------------------------------------------------------ meshes, step, remat
-
-def test_dp2_equals_one_rank(params):
-    want_loss, want = _one_rank(params)
-    with jax.enable_x64(False):
-        mesh = mesh_of(dp=2)
-        tfm.validate_cfg_for_mesh(CFG, mesh)
-        loss, grads = programs.loss_and_grads(CFG, dp=2)(
-            tfm.shard_params(params, CFG, mesh), *_data())
-    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-    programs.assert_trees_close(grads, want, rtol=1e-4, atol=1e-6)
-
-
-def test_a_train_step_lowers_the_loss_and_counts_what_it_drops(params):
-    opt = optax.adamw(1e-2)
-    cfg = dataclasses.replace(CFG, remat=True)
-    with jax.enable_x64(False):
-        results = programs.train(cfg, opt, params, _data(), 3, metrics=True)
-    assert all(int(counts["experts_dropped"]) == 0 for _, counts in results)
-    assert float(results[2][0]) < float(results[0][0]), results
-
-
-def test_remat_changes_no_result(params):
-    want_loss, want = _one_rank(params)
-    for policy in ("dots", "full"):
-        loss, grads = _one_rank(params, dataclasses.replace(
-            CFG, remat=True, remat_policy=policy))
-        np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-        programs.assert_trees_close(grads, want, rtol=1e-4, atol=1e-7)
-
-
-# -------------------------------------------------------------- refusals
-
-REFUSED = [
-    ({}, {"attn": "ring"}, "d_head \\* n_heads != d_model needs attn"),
-    ({}, {"attn": "ulysses"}, "d_head \\* n_heads != d_model needs attn"),
-    ({}, {"attn": "ring", "n_kv_heads": 0, "window": 0,
-          "layer_pattern": (), "unrotated": ()},
-     "d_head \\* n_heads != d_model needs attn"),
-    (dict(tp=2), {"n_kv_heads": 0, "window": 0, "layer_pattern": (),
-                  "unrotated": ()},
-     "d_head \\* n_heads != d_model requires sp=tp=pp=1"),
-    (dict(sp=2), {"n_kv_heads": 0, "window": 0, "layer_pattern": (),
-                  "unrotated": ()},
-     "d_head \\* n_heads != d_model requires sp=tp=pp=1"),
-    (dict(tp=2), {"d_head": 0},
-     "need attn 'flash' or 'local' and sp=tp=pp=1"),
-    ({}, {"attention": "mla"}, "d_head is plain attention's head width"),
-    ({}, {"positions": "none"}, "unrotated names kinds that take no "
-                                "rotation"),
-    ({}, {"unrotated": ("linear",)}, "unrotated names a kind the pattern "
-                                     "lacks"),
-    ({}, {"router_input": "attention"}, "router_input='attention'"),
-    ({}, {"router_input": "layer", "post_norm": True},
-     "router_input='layer' with post_norm"),
-    ({}, {"mlp": "geglu"}, "mlp='geglu'"),
-    ({}, {"window": 0}, "'window' layers need window > 0"),
-    (dict(pp=2), {"microbatches": 2}, "requires sp=tp=pp=1"),
-    ({}, {"n_layers": 6}, "no whole number of periods"),
-]
-
-
-@pytest.mark.parametrize("mesh, changed, message", REFUSED)
-def test_validate_refuses_by_name(mesh, changed, message):
-    cfg = dataclasses.replace(CFG, **changed)
-    with pytest.raises(HorovodTpuError, match=message):
-        tfm.validate_cfg_for_mesh(cfg, mesh_of(**mesh))
-
-
-def test_validate_accepts_the_model_where_it_runs():
-    tfm.validate_cfg_for_mesh(CFG, mesh_of())
-    tfm.validate_cfg_for_mesh(CFG, mesh_of(dp=2))
-    # a head width that happens to be d_model / n_heads is no width apart
+def test_a_head_width_that_is_d_model_over_heads_is_no_width_apart():
     tfm.validate_cfg_for_mesh(tfm.TransformerConfig(d_head=64, attn="ring"),
                               mesh_of(sp=2))
 

@@ -4,7 +4,14 @@ test_tensorflow.py — collective semantics through the TF API surface)."""
 import numpy as np
 import pytest
 
-tf = pytest.importorskip("tensorflow")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _tensorflow():
+    """TensorFlow as this module's global `tf`, imported when the first test
+    here runs and not when the file is collected: every worker collects
+    every file, one runs this one. Without it the file's tests are skipped."""
+    globals()["tf"] = pytest.importorskip("tensorflow")
 
 
 def test_tf_allreduce_roundtrip(hvd):

@@ -4,7 +4,14 @@ collective semantics through the torch API surface)."""
 import numpy as np
 import pytest
 
-torch = pytest.importorskip("torch")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _torch():
+    """`torch` as this module's global, imported when the first test here
+    runs and not when the file is collected: every worker collects every
+    file, one runs this one. Without it the file's tests are skipped."""
+    globals()["torch"] = pytest.importorskip("torch")
 
 
 def test_torch_allreduce_roundtrip(hvd):
