@@ -472,7 +472,8 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
     TPU: one, and two), then their times against the least time the
     benchmark's `gdn_scan_roofline` counts (the family's `rule_work`). With
     `per_channel` the form with a decay a key channel (`kda_scan`), which
-    also says what its decayed products cost (`channel_gram_work`); both say
+    also says what its decayed products cost (`channel_gram_work`) and what
+    g's running sums inside its kernels do (`running_sum_work`); both say
     what the solve costs (`solve_work`)."""
     batch, heads, seq, dk, dv = shape
     tag = "delta rule, a decay a channel" if per_channel \
@@ -544,6 +545,12 @@ def gated_delta_scan(log: CompileLog, shape=(1, 30, 8192, 96, 192),
                                + name.replace("_", " ") for name in (
                                    "products", "exp_registers",
                                    "lane_reductions", "lane_broadcasts")))
+        sums = gd.running_sum_work(gd.CHUNK, dk)
+        halving += ("; g's running sums inside the kernels, "
+                    f"{sums['steps'][0]} / {sums['steps'][1]} doubling steps, "
+                    f"{sums['rolled_registers'][0]} / "
+                    f"{sums['rolled_registers'][1]} rolled registers, "
+                    f"{sums['products'][0]} / {sums['products'][1]} products")
     solve = gd.solve_work(gd.CHUNK)   # engages in every chunk, both forms
     say(f"[{tag}] {batch} x {seq} tokens x {heads} heads, "
         f"{dk} | {dv}, bf16: chunk {gd.CHUNK}, "

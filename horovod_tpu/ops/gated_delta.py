@@ -52,8 +52,14 @@ HBM between its products.
   and in the same grid step everything else of the chunk's gradient:
   through the scores, the decays and T (the cotangent of A is
   -(T^T dW) W^T - (T^T dU_0) U_0^T below the diagonal: products only, no
-  second solve). They write dq, dk, dv, dbeta and the cotangent of b; g's is
-  the reverse running sum of b's inside a chunk, in `jnp`.
+  second solve). The scalar one writes dq, dk, dv, dbeta and the cotangent
+  of b; g's is the reverse running sum of b's inside a chunk, in `jnp`.
+* With a decay a channel b is never in HBM: both kernels take g, (c x dk)
+  float32 a chunk and head, and sum it down the rows of the block they hold
+  (`_running_sum`: six doubling steps of a sublane roll, a select and an
+  add; the same code in both, so both passes hold the same bits), and the
+  backward one sums b's cotangent from the chunk's last row the same way
+  and writes g's.
 
 No exponent is positive and nothing is divided by a decay, so a strong decay
 underflows to zero and does nothing worse. With one decay a head every exponent
@@ -173,11 +179,26 @@ def solve_work(chunk: int = CHUNK) -> dict:
             "lane_broadcasts": sum(touched), "steps": len(touched)}
 
 
+def running_sum_work(chunk: int = CHUNK, key_dim: int = 128) -> dict:
+    """What g's running sum inside a chunk costs a chunk and head of the
+    form with a decay a channel, as (forward, backward), from
+    `_running_sum`'s loop: its doubling steps (each a sublane roll, a
+    select and an add of the chunk's float32 registers), the registers that
+    go through a roll, and matrix products, of which it has none. The
+    backward kernel sums g again and sums b's cotangent from the last row:
+    twice the forward's."""
+    steps = (_chunk_size(chunk) - 1).bit_length()
+    registers = steps * (chunk // _SUBLANES) * -(-key_dim // 128)
+    return {"steps": (steps, 2 * steps),
+            "rolled_registers": (registers, 2 * registers),
+            "products": (0, 0)}
+
+
 def step_bytes(key_dim: int, value_dim: int, chunk: int = CHUNK,
                itemsize: int = 2, per_channel: bool = False) -> int:
     """VMEM a head takes of the backward kernel's grid step, the larger of
     the two: its blocks (q, k, W, dq, dk; v, dO, dv; U_0, T and the entry
-    state in float32; with a decay a channel also b and its cotangent,
+    state in float32; with a decay a channel also g and its cotangent,
     (c x dk) float32 each, and P beside T), each twice for the pipeline,
     and the resident cotangent of the state."""
     dk, dv, c = key_dim, value_dim, _chunk_size(chunk)
@@ -531,6 +552,25 @@ def _backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, t_ref, s0_ref,
     db_ref[:, 0] = db + jnp.where(lane == c - 1, at_last, 0.0)
 
 
+def _running_sum(g, reverse: bool = False):
+    """b = L g of a head's g: (c, dk) float32, L the (c x c) lower triangle
+    of ones, diagonal included: g's running sum down the rows of a chunk. By
+    doubling, on the vector unit: step s = 1, 2, 4, ... adds to every row
+    the row s above it (a sublane roll, the rows that have none above masked),
+    after which a row holds the sum of the 2 s rows that end in it: a tree
+    of float32 additions. With `reverse` L^T g, the sum from a row to the
+    chunk's last: g's cotangent from b's."""
+    c = g.shape[0]
+    token = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    step = 1
+    while step < c:
+        shift, has_one = (c - step, token < c - step) if reverse \
+            else (step, token >= step)
+        g = g + jnp.where(has_one, pltpu.roll(g, shift, 0), 0.0)
+        step *= 2
+    return g
+
+
 def _channel_terms(b):
     """Of a chunk with a decay a channel, b: (heads, c, dk): exp(b),
     exp(b_C - b), and exp(b_C): (heads, 1, dk), a row over the state's
@@ -539,7 +579,7 @@ def _channel_terms(b):
     return jnp.exp(b), jnp.exp(last - b), jnp.exp(last)
 
 
-def _channel_forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref,
+def _channel_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
                             *rest):
     *saved, state = rest                     # the state: (heads, dv, dk)
     dt = q_ref.dtype
@@ -548,7 +588,8 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref,
     def _first_chunk():
         state[...] = jnp.zeros_like(state)
 
-    q, k, v, b = q_ref[...], k_ref[...], v_ref[...], b_ref[...]
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]
+    b = jnp.stack([_running_sum(g_ref[h]) for h in range(q.shape[0])])
     beta = beta_ref[:, 0]                            # (heads, 1, c)
     eye, below, _ = _masks(q.shape[1])
     # what does not depend on the state
@@ -577,8 +618,8 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, b_ref, beta_ref, o_ref,
 
 
 def _channel_backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, tp_ref,
-                             s0_ref, b_ref, beta_ref, do_ref,
-                             dq_ref, dk_ref, dv_ref, db_ref, dbeta_ref,
+                             s0_ref, g_ref, beta_ref, do_ref,
+                             dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
                              d_state):
     dt = q_ref.dtype
 
@@ -587,7 +628,8 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, tp_ref,
         d_state[...] = jnp.zeros_like(d_state)
 
     q, k, v, w = q_ref[...], k_ref[...], v_ref[...], w_ref[...]
-    b, beta = b_ref[...], beta_ref[:, 0]
+    b = jnp.stack([_running_sum(g_ref[h]) for h in range(q.shape[0])])
+    beta = beta_ref[:, 0]
     c = q.shape[1]
     eye, below, upto = _masks(c)
     grow, shrink, whole = _channel_terms(b)
@@ -645,8 +687,10 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, w_ref, u0_ref, tp_ref,
     at_last = jnp.sum(leaving, axis=1, keepdims=True) + whole * jnp.sum(
         ds * s0, axis=1, keepdims=True)
     token = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    db_ref[...] = qf * dq_g + kf * (dk_i - dk_j) + beta_col * through_kb \
+    db = qf * dq_g + kf * (dk_i - dk_j) + beta_col * through_kb \
         + dq_in * q_in32 - leaving + jnp.where(token == c - 1, at_last, 0.0)
+    for h in range(db.shape[0]):
+        dg_ref[h] = _running_sum(db[h], reverse=True)
 
 
 # --------------------------------------------------------------------------
@@ -683,17 +727,18 @@ _PARAMS = {"compiler_params": pltpu.CompilerParams(
 
 
 def _per_channel(b) -> bool:
-    """Whether b (or g) is a decay a channel, (B, H, S, dk), and not one a
-    head and token as the kernels hold it, (B H, n, 1, c)."""
+    """Whether g is a decay a channel, (B, H, S, dk), and not one a head and
+    token as the kernels hold it or its running sum b, (B H, n, 1, c)."""
     return b.shape[2] != 1
 
 
 def _form(b, c: int, dk: int, dv: int, given, gates):
     """What the two forms' calls differ in: (the forward kernel, the
-    backward one, b's block spec, the width of T's array, the state's
-    shape). With a decay a channel T and P lie side by side in one float32
-    array, (., S, 2c): 128 lanes at the chunk of 64, where each alone would
-    be padded to them; the state is transposed, (dv x dk)."""
+    backward one, the block spec of b, or of g with a decay a channel, the
+    width of T's array, the state's shape). With a decay a channel T and P
+    lie side by side in one float32 array, (., S, 2c): 128 lanes at the
+    chunk of 64, where each alone would be padded to them; the state is
+    transposed, (dv x dk)."""
     if _per_channel(b):
         return (_channel_forward_kernel, _channel_backward_kernel, given(dk),
                 2 * c, (dv, dk))
@@ -702,10 +747,10 @@ def _form(b, c: int, dk: int, dv: int, given, gates):
 
 def _forward(q, k, v, b, beta, *, c: int, heads: int, save: bool):
     """q, k: (B, H, S, dk), v: (B, H, S, dv), beta: (B H, n, 1, c), b like
-    beta or (B, H, S, dk), H a multiple of `heads` and S = n c. Returns o,
-    and with `save` the residuals of the backward pass: W, U_0, T (with a
-    decay a channel T | P) and the entry states, batch and heads one
-    axis."""
+    beta, or in its place g: (B, H, S, dk) with a decay a channel, H a
+    multiple of `heads` and S = n c. Returns o, and with `save` the
+    residuals of the backward pass: W, U_0, T (with a decay a channel T |
+    P) and the entry states, batch and heads one axis."""
     batch, n_heads, seq, dk = q.shape
     dv, dt, n, n_all = v.shape[-1], v.dtype, seq // c, batch * n_heads
     given, tokens, gates, states = _specs(heads, n_heads // heads, c, n)
@@ -743,34 +788,37 @@ def _backward(q, k, v, b, beta, w, u0, t, s0, do, *, c: int, heads: int):
         **_PARAMS)(q, k, v, w, u0, t, s0, b, beta, do)
 
 
-def _running(g, c: int, reverse: bool = False):
-    """b: g's running sum inside each chunk of c tokens, along its token
-    axis: the last of (., n, 1, c), or the third of (B, H, S, dk). With
-    `reverse`, g's cotangent from b's: g_j gets every b_i of its chunk with
-    i >= j."""
+def _running(g, reverse: bool = False):
+    """b of one decay a head and token, (., n, 1, c): g's running sum inside
+    each chunk. With `reverse`, g's cotangent from b's: g_j gets every b_i
+    of its chunk with i >= j."""
     summed = functools.partial(lax.cumsum, reverse=True) if reverse \
         else jnp.cumsum
-    if not _per_channel(g):
-        return summed(g, axis=3)
-    chunks = g.reshape(g.shape[:2] + (-1, c) + g.shape[3:])
-    return summed(chunks, axis=3).reshape(g.shape)
+    return summed(g, axis=3)
+
+
+def _decays(g, reverse: bool = False):
+    """What the kernels take in g's place: b (`_running`), or with a decay a
+    channel g itself, which those kernels sum in VMEM (`_running_sum`: b is
+    never in HBM). With `reverse`, g's cotangent from what they hand
+    back."""
+    return g if _per_channel(g) else _running(g, reverse)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _rule(q, k, v, g, beta, c, heads):
-    return _forward(q, k, v, _running(g, c), beta, c=c, heads=heads,
-                    save=False)
+    return _forward(q, k, v, _decays(g), beta, c=c, heads=heads, save=False)
 
 
 def _rule_fwd(q, k, v, g, beta, c, heads):
-    b = _running(g, c)
+    b = _decays(g)
     o, saved = _forward(q, k, v, b, beta, c=c, heads=heads, save=True)
     return o, (q, k, v, b, beta, *saved)
 
 
 def _rule_bwd(c, heads, saved, do):
     dq, dk, dv, db, dbeta = _backward(*saved, do, c=c, heads=heads)
-    return dq, dk, dv, _running(db, c, reverse=True), dbeta
+    return dq, dk, dv, _decays(db, reverse=True), dbeta
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)

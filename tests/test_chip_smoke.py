@@ -137,7 +137,9 @@ def test_phase_kda_scan(smoke, capsys):
     assert "2 heads a grid step (" in out
     assert "its decayed products by 7 levels of a halving, forward / " \
         "backward a chunk and head: 7 / 14 products, 48 / 48 exp " \
-        "registers, 0 / 0 lane reductions, 0 / 0 lane broadcasts; the " \
+        "registers, 0 / 0 lane reductions, 0 / 0 lane broadcasts; g's " \
+        "running sums inside the kernels, 6 / 12 doubling steps, 48 / 96 " \
+        "rolled registers, 0 / 0 products; the " \
         "solve by 4 panels, a chunk and head: 3 exact products (18 bf16 " \
         "passes), 88 lane broadcasts, 60 steps; 2 heads a grid step (" in out
     assert "interpret=True, tpu_custom_call in the compiled forward 0, " \

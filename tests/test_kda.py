@@ -3,10 +3,11 @@
 outputs and the gradient of every input, float32 and bf16, at lengths that
 are and are not whole chunks, heads that do and do not fill a grid step, and
 under a decay so strong that exp(-b) would overflow inside one chunk; the
-decayed products G block by block against their definition; the per-channel
-kernels against the scalar ones where g is constant over a head's channels;
-what the forward saves for the backward pass; and how many heads a grid step
-takes."""
+decayed products G block by block against their definition; g's running sums
+inside a chunk, made in the kernels, against float64's, and that no pass
+outside the kernels makes them; the per-channel kernels against the scalar
+ones where g is constant over a head's channels; what the forward saves for
+the backward pass; and how many heads a grid step takes."""
 
 import functools
 
@@ -16,10 +17,11 @@ import numpy as np
 import pytest
 
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops._pallas import pallas_call
 from horovod_tpu.ops.gated_delta import (channel_gram_work, gated_delta_rule,
                                          heads_a_step,
                                          recurrent_gated_delta_rule,
-                                         step_bytes)
+                                         running_sum_work, step_bytes)
 
 
 def _inputs(seq, *, strong, dtype=jnp.float32, seed=0, batch=2, heads=2,
@@ -296,18 +298,128 @@ def test_what_the_decayed_products_cost_is_counted_from_the_helpers():
     assert channel_gram_work(8, 16)["exp_registers"] == (3, 3)
 
 
+@pytest.mark.parametrize("reverse", [False, True], ids=["L g", "L^T db"])
+@pytest.mark.parametrize("c", [8, 16, 64])
+def test_the_running_sums_in_a_kernel_are_float64s(c, reverse):
+    """`_running_sum` inside a kernel, as both per-channel kernels call it
+    (a head at a time on the block's (c, dk) float32): b = L g under the
+    strong decay (up to 60 a token: b to -3,800 at 64 rows) against
+    float64's running sum within float32's rounding of the chunk's largest
+    |b| (a tree of log2(c) additions a row: half a unit in the last place
+    each; the rows of a padded tail, g = 0, hold b to that rounding and not
+    to the bit, each row being a tree of its own), a channel that does not
+    decay exactly 0; and L^T db, the sum from a row to the last, of
+    cotangents of both signs."""
+    heads, dk = 2, 8
+    _, _, _, g, _ = _inputs(c, strong=True, batch=1, heads=heads, dk=dk)
+    x = g[0].at[:, c - 3:].set(0.0)
+    if reverse:
+        x = x * jax.random.normal(jax.random.PRNGKey(5), x.shape,
+                                  jnp.float32)
+
+    def kernel(x_ref, y_ref):
+        for h in range(heads):
+            y_ref[h] = gated_delta._running_sum(x_ref[h], reverse)
+
+    with jax.enable_x64(False):
+        got = np.asarray(pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32))(x))
+    x64 = np.asarray(x, np.float64)
+    want = np.flip(np.cumsum(np.flip(x64, 1), axis=1), 1) if reverse \
+        else np.cumsum(x64, axis=1)
+    largest = np.cumsum(np.abs(x64), axis=1).max()
+    if c == 64 and not reverse:
+        assert want.min() < -1500
+    steps = (c - 1).bit_length()
+    assert np.abs(got - want).max() <= steps * 2.0 ** -24 * largest
+    if not reverse:
+        assert np.all(got[..., ::3] == 0.0)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of what it calls, but a kernel's
+    body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def test_no_pass_outside_the_kernels_sums_g():
+    """The gradient of the rule with a decay a channel, traced: no `cumsum`
+    and no `reduce_window`, and of (B, H, S, dk) float32 arrays only g,
+    which comes in, and its cotangent, which the backward kernel writes: b
+    and db are never in HBM. The kernels keep their operand and result
+    counts, g where b stood. The scalar form still sums in `jnp`."""
+    with jax.enable_x64(False):
+        q, k, v, g, beta = _inputs(128, strong=False, dtype=jnp.bfloat16)
+        assert g.shape == (2, 2, 128, 8) and g.dtype == jnp.float32
+
+        def traced(g):
+            return list(_equations(jax.make_jaxpr(jax.grad(
+                lambda *a: jnp.sum(gated_delta_rule(*a).astype(jnp.float32)),
+                argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta).jaxpr))
+
+        per_channel, scalar = traced(g), traced(g[..., 0])
+    summing = ("cumsum", "cumlogsumexp", "cumprod", "reduce_window",
+               "reduce_window_sum")
+    assert not [e for e in per_channel if e.primitive.name in summing]
+    assert sum(e.primitive.name == "cumsum" for e in scalar) == 2
+    wide = [(e.primitive.name, i) for e in per_channel
+            for i, out in enumerate(e.outvars)
+            if out.aval.shape == g.shape and out.aval.dtype == jnp.float32]
+    assert wide == [("pallas_call", 3)]
+    kernels = [e for e in per_channel if e.primitive.name == "pallas_call"]
+    assert [(len(e.invars), len(e.outvars)) for e in kernels] == [(5, 5),
+                                                                  (10, 5)]
+    for e in kernels:
+        assert g.shape in [x.aval.shape for x in e.invars
+                           if x.aval.dtype == jnp.float32]
+
+
+def test_what_the_running_sums_cost_is_counted_from_the_helper():
+    """`running_sum_work` (what `chip_smoke.py` prints) against the traced
+    `_running_sum` of one head at the cell's chunk and width: six doubling
+    steps, each one roll of the chunk's eight registers, forward; the
+    backward kernel sums g again and b's cotangent in reverse; no product."""
+    c, dk = 64, 128
+    g = jax.ShapeDtypeStruct((c, dk), jnp.float32)
+    zero = dict.fromkeys(("steps", "rolled_registers", "products"), 0)
+
+    def counted(reverse, counts):
+        with jax.enable_x64(False):
+            jaxpr = jax.make_jaxpr(functools.partial(
+                gated_delta._running_sum, reverse=reverse))(g).jaxpr
+        for eqn in _equations(jaxpr):
+            if eqn.primitive.name == "roll":
+                rows, lanes = eqn.invars[0].aval.shape
+                counts["steps"] += 1
+                counts["rolled_registers"] += -(-rows // 8) * -(-lanes // 128)
+            counts["products"] += eqn.primitive.name == "dot_general"
+        return counts
+
+    forward = counted(False, dict(zero))
+    backward = counted(True, counted(False, dict(zero)))
+    said = running_sum_work(c, dk)
+    assert {name: (forward[name], backward[name]) for name in zero} == said
+    assert said == {"steps": (6, 12), "rolled_registers": (48, 96),
+                    "products": (0, 0)}
+    assert running_sum_work(8, 16)["steps"] == (3, 6)
+    assert running_sum_work(24, 256)["rolled_registers"] == (30, 60)
+
+
 def test_what_the_forward_saves_for_the_backward_pass():
     """W in the inputs' type; U_0, T beside P and each chunk's entry state
     in float32, the state transposed (dv x dk) and equal to the recurrence's
     at the same token; T unit lower triangular, P zero above the
-    diagonal."""
+    diagonal. The kernel takes g itself: b is its own to make."""
     with jax.enable_x64(False):
         q, k, v, g, beta = _inputs(192, strong=False, batch=1, heads=2,
                                    dtype=jnp.bfloat16)
         c, heads = 64, 2
-        b = gated_delta._running(g, c)
         _, (w, u0, tp, s0) = gated_delta._forward(
-            q, k, v, b, beta.reshape(2, 3, 1, c), c=c, heads=heads,
+            q, k, v, g, beta.reshape(2, 3, 1, c), c=c, heads=heads,
             save=True)
     assert w.dtype == jnp.bfloat16
     assert {u0.dtype, tp.dtype, s0.dtype} == {jnp.dtype("float32")}
